@@ -1,7 +1,8 @@
 """AOT serialized-executable cache: warm a fresh process from disk.
 
-The third cache layer (see platform_boot.arm_compile_cache's taxonomy).
-The persistent XLA *module* cache skips the HLO->binary compile but a
+The second cache layer (see platform_boot.arm_compile_cache's list),
+off unless PADDLE_TPU_AOT_CACHE=1 asks for it. jax's persistent
+compilation cache skips the HLO->binary compile but a
 restarted process still pays the full Python trace of every (program,
 shapes) key before it can even ASK the module cache; the tuning table
 skips re-benchmarking but not compilation. This layer removes both: on
@@ -24,18 +25,23 @@ with identical content share entries. Any mismatch — different jaxlib,
 different chip, corrupted file — falls back to a live compile with an
 ``aot_fallback`` flight event; the cache can only ever cost a read.
 
+The key does NOT cover the lowering code: change an ``ops/`` lowering
+and the Program IR — hence the fingerprint — is unchanged, so a cache
+directory shared between two commits serves the old commit's
+executable to the new one. That is why this layer is opt-in on every
+backend, and why a measurement must never share its directory across
+commits (ROADMAP D4).
+
 Knobs::
 
-    PADDLE_TPU_AOT_CACHE      auto (default: TPU backends only) | 1 | 0
-    PADDLE_TPU_AOT_CACHE_DIR  cache directory (default: per-user tmp)
-
-'auto' mirrors the compile_cache flag's rationale: XLA:CPU AOT
-artifacts can embed host-CPU feature sets that SIGILL on a different
-machine, so CPU opts in explicitly (tests and single-machine serving
-do; the warm-start e2e proves the win on CPU CI).
+    PADDLE_TPU_AOT_CACHE      1 turns the layer on; anything else is off
+    PADDLE_TPU_AOT_CACHE_DIR  cache directory (default: ``aot/`` under
+                              platform_boot.cache_root())
 
 Only single-device programs are cached (``program.mesh is None``) —
-sharded executables embed device assignments that do not relocate.
+sharded executables embed device assignments that do not relocate. A
+cached executable is loaded onto the process's first device only
+(``execution_devices``), whatever the host's device count.
 """
 
 import hashlib
@@ -52,26 +58,16 @@ _SUFFIX = '.jaot'
 
 def enabled(environ=None):
     env = os.environ if environ is None else environ
-    raw = (env.get('PADDLE_TPU_AOT_CACHE') or 'auto').strip().lower()
-    if raw in ('1', 'true', 'yes', 'on'):
-        return True
-    if raw in ('0', 'false', 'no', 'off'):
-        return False
-    from .platform_boot import is_tpu_backend
-    return is_tpu_backend()
+    raw = (env.get('PADDLE_TPU_AOT_CACHE') or '').strip().lower()
+    return raw in ('1', 'true', 'yes', 'on')
 
 
 def cache_dir():
     d = os.environ.get('PADDLE_TPU_AOT_CACHE_DIR')
     if d:
         return d
-    try:
-        import getpass
-        user = getpass.getuser()
-    except Exception:
-        user = str(os.getuid()) if hasattr(os, 'getuid') else 'default'
-    return os.path.join(tempfile.gettempdir(),
-                        'paddle_tpu_aot_cache_%s' % user)
+    from .platform_boot import cache_root
+    return os.path.join(cache_root(), 'aot')
 
 
 def backend_fingerprint():
@@ -129,10 +125,14 @@ def load(fp):
             _obs.flight_event('aot_fallback', reason='mismatch',
                               fields=','.join(bad), path=path)
             return None, 'mismatch'
+        import jax
         from jax.experimental import serialize_executable as _se
-        loaded = _se.deserialize_and_load(blob['payload'],
-                                          blob['in_tree'],
-                                          blob['out_tree'])
+        # without execution_devices jax loads onto EVERY local device
+        # and the one-device executable then fails at dispatch, where
+        # the except below cannot see it
+        loaded = _se.deserialize_and_load(
+            blob['payload'], blob['in_tree'], blob['out_tree'],
+            execution_devices=jax.devices()[:1])
         return loaded, 'loaded'
     except Exception as e:
         _obs.inc('executor.aot_fallback_total', reason='error')

@@ -22,18 +22,9 @@ class Place(object):
         return hash((type(self).__name__, self.device_id))
 
     def jax_device(self):
-        """Resolve to a concrete jax device, or None to use the default."""
+        """Resolve to a concrete jax device of this place's platform."""
         import jax
-        kind = self.device_kind
-        devs = [d for d in jax.devices() if d.platform == kind]
-        if not devs:
-            if kind == 'tpu':
-                # Fall back to whatever the default backend offers (e.g. the
-                # 8-virtual-device CPU mesh used in tests).
-                devs = jax.devices()
-            else:
-                devs = jax.devices('cpu')
-        return devs[self.device_id % len(devs)]
+        return jax.devices(self.device_kind)[self.device_id]
 
 
 class CPUPlace(Place):
@@ -41,8 +32,28 @@ class CPUPlace(Place):
 
 
 class TPUPlace(Place):
-    """The TPU analog of the reference's CUDAPlace (platform/place.h:60)."""
+    """The TPU analog of the reference's CUDAPlace (platform/place.h:60).
+
+    Resolves to a TPU device, or raises. The one exception is a process
+    that asked for the host CPU by name (``JAX_PLATFORMS=cpu``, as the
+    test suite does): there TPUPlace(i) is CPU device i, so programs
+    written for the chip run unchanged on the test meshes. A machine
+    that merely has no TPU is never silently used instead."""
     device_kind = 'tpu'
+
+    def jax_device(self):
+        import jax
+        devs = jax.devices()
+        platform = devs[0].platform
+        if platform != 'tpu':
+            # the first platform listed is the one jax makes the default
+            asked = (jax.config.jax_platforms or '').split(',')[0]
+            if asked != 'cpu':
+                raise RuntimeError(
+                    '%r: jax found no TPU (default platform is %r). To '
+                    'run on the host CPU ask for it by name: '
+                    'JAX_PLATFORMS=cpu' % (self, platform))
+        return devs[self.device_id]
 
 
 # Alias kept for scripts written against the reference's naming.

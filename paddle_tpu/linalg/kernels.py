@@ -50,7 +50,6 @@ import jax
 import jax.numpy as jnp
 
 from ..parallel.collective import broadcast, quantized_all_reduce
-from ..parallel.mesh import compat_shard_map
 
 __all__ = ['summa_matmul', 'blocked_cholesky', 'blocked_qr',
            'power_iter_step', 'matmul_reference', 'cholesky_reference',
@@ -269,7 +268,11 @@ def summa_matmul(a, b, mesh, panel, row_axis='dp', col_axis='tp'):
             return ap, bp
 
         ap0, bp0 = fetch(0)
-        acc0 = jnp.zeros((a_loc.shape[0], b_loc.shape[1]), jnp.float32)
+        # the product of two sharded blocks varies over both grid axes;
+        # an unvarying zero init would mismatch the scan carry's type
+        acc0 = jax.lax.pcast(
+            jnp.zeros((a_loc.shape[0], b_loc.shape[1]), jnp.float32),
+            (row_axis, col_axis), to='varying')
 
         def step(carry, t):
             acc, ap, bp = carry
@@ -286,7 +289,7 @@ def summa_matmul(a, b, mesh, panel, row_axis='dp', col_axis='tp'):
             jnp.arange(n_steps, dtype=jnp.int32))
         return acc.astype(a_loc.dtype)
 
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(row_axis, col_axis), P(row_axis, col_axis)),
         out_specs=P(row_axis, col_axis))
@@ -348,7 +351,7 @@ def blocked_cholesky(a, mesh, block, axis='dp'):
             s = s - jnp.where(below & trail, pan @ pan_full.T, 0.0)
         return l_out.astype(a_loc.dtype)
 
-    fn = compat_shard_map(body, mesh=mesh, in_specs=(P(axis, None),),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(axis, None),),
                           out_specs=P(axis, None))
     return fn(a)
 
@@ -409,7 +412,7 @@ def blocked_qr(a, mesh, block, axis='dp'):
     # check_vma off: R is assembled from all-gathered panels and psum
     # projections — identical on every device by construction, but the
     # replication checker cannot infer it through the gathered-panel QR
-    fn = compat_shard_map(body, mesh=mesh, in_specs=(P(axis, None),),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(axis, None),),
                           out_specs=(P(axis, None), P(None, None)),
                           check_vma=False)
     return fn(a)
@@ -464,7 +467,7 @@ def power_iter_step(a, v, mesh, axis='dp', quantized=False, qblock=256,
     # check_vma off: the quantized allreduce ends in an all_gather of
     # already-rounded shards — identical on every device by
     # construction, but not provably replicated to the checker
-    fn = compat_shard_map(body, mesh=mesh,
+    fn = jax.shard_map(body, mesh=mesh,
                           in_specs=(P(None, axis), P(None)),
                           out_specs=(P(None), P(None)),
                           check_vma=False)

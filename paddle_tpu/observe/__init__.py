@@ -44,7 +44,7 @@ nothing else. Turn it on with::
     observe.disable()          # final snapshot + trace export
 
 or ``PADDLE_TPU_METRICS_JSONL=... PADDLE_TPU_TRACE_JSON=...`` with
-``observe.enable_from_env()`` (bench.py and tools/onchip_watcher.py do
+``observe.enable_from_env()`` (bench.py does
 exactly this). See docs/observability.md for the metric catalog.
 """
 

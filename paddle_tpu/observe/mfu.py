@@ -14,53 +14,40 @@ clock recompiling after preemptions has 0.9 goodput no matter how fast
 its steps are.
 """
 
-import os
 import time
 
 __all__ = ['PEAK_TFLOPS_BF16', 'device_peak_flops', 'cost_analysis_flops',
            'overlap_fraction', 'GoodputTracker']
 
-# bf16 dense peak per chip generation (TFLOP/s per chip). Matmul peak
-# from public TPU specs; override with PADDLE_TPU_PEAK_TFLOPS (or the
-# bench's BENCH_PEAK_TFLOPS) for exotic SKUs.
+# bf16 dense matmul peak per chip (TFLOP/s), keyed by the exact
+# ``device_kind`` string jax reports on that chip. An entry is added
+# when a chip has printed its string, with the source of the figure.
 PEAK_TFLOPS_BF16 = {
-    'v2': 45.0,
-    'v3': 123.0,
-    'v4': 275.0,
-    'v5e': 197.0,
-    'v5litepod': 197.0,
-    'v5p': 459.0,
-    'v6e': 918.0,
+    # TPU v5e. Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16.
+    # The chip prints this string, not 'v5e' (chip_smoke.py, PR 21).
+    'TPU v5 lite': 197.0,
 }
 
 
 def device_peak_flops(device=None):
-    """Peak FLOP/s of `device` (default: jax's first device), or None
-    when unknown (e.g. cpu) and no env override is set."""
-    for var in ('PADDLE_TPU_PEAK_TFLOPS', 'BENCH_PEAK_TFLOPS'):
-        v = os.environ.get(var)
-        if v:
-            return float(v) * 1e12
+    """Peak bf16 FLOP/s of `device` (default: jax's first device) from
+    the table above; None off-TPU (a CPU has no peak worth a
+    utilization). A TPU whose device_kind is not in the table is an
+    error, not a default."""
     if device is None:
         import sys
         jax = sys.modules.get('jax')
         if jax is None:
             return None
-        try:
-            devs = jax.devices()
-        except Exception:
-            return None
-        if not devs:
-            return None
-        device = devs[0]
-    kind = (getattr(device, 'device_kind', '') or '').lower()
-    for key, tf in sorted(PEAK_TFLOPS_BF16.items(), key=lambda kv: -len(
-            kv[0])):
-        if key in kind.replace(' ', '').replace('tpu', ''):
-            return tf * 1e12
-    if 'tpu' in kind:
-        return PEAK_TFLOPS_BF16['v5e'] * 1e12  # conservative default
-    return None
+        device = jax.devices()[0]
+    if device.platform != 'tpu':
+        return None
+    kind = device.device_kind
+    if kind not in PEAK_TFLOPS_BF16:
+        raise KeyError(
+            'no peak FLOP/s recorded for TPU device_kind %r; add it to '
+            'observe.mfu.PEAK_TFLOPS_BF16 with its source' % kind)
+    return PEAK_TFLOPS_BF16[kind] * 1e12
 
 
 def overlap_fraction(step_seconds, compute_seconds, comm_seconds):
@@ -88,17 +75,14 @@ def overlap_fraction(step_seconds, compute_seconds, comm_seconds):
 
 
 def cost_analysis_flops(compiled):
-    """FLOPs per execution from an XLA Compiled/cost-analysis result.
-    Accepts a jax Compiled object, a cost-analysis dict, or a list of
-    dicts (jax returns either depending on version). None on failure."""
+    """FLOPs per execution from a jax Compiled object or its
+    cost-analysis dict. None when XLA reports none."""
     ca = compiled
     if hasattr(ca, 'cost_analysis'):
         try:
             ca = ca.cost_analysis()
         except Exception:
             return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     if not isinstance(ca, dict):
         return None
     flops = float(ca.get('flops', 0.0) or 0.0)

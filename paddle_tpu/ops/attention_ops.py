@@ -87,21 +87,11 @@ def _ring_dispatch(q, k, v, mesh, causal, key_length=None):
             return ring_attention(q_, k_, v_, axis_name='sp',
                                   causal=causal, kv_len=l_)
 
-    from ..parallel.mesh import compat_shard_map
     kwargs = dict(in_specs=in_specs, out_specs=spec)
-    # jax.sharding.get_abstract_mesh is not exported on every jax this
-    # repo supports; fall back to the internal home it has always had
-    _get_ctx = getattr(jax.sharding, 'get_abstract_mesh', None)
-    if _get_ctx is None:
-        from jax._src import mesh as _mesh_lib
-        _get_ctx = getattr(_mesh_lib, 'get_abstract_mesh', lambda: None)
-    ctx = _get_ctx()
-    manual = getattr(getattr(jax.sharding, 'AxisType', None),
-                     'Manual', None)
-    if not (manual is not None and any(
-            t == manual for t in getattr(ctx, 'axis_types', ()))):
+    ctx = jax.sharding.get_abstract_mesh()
+    if not any(t == jax.sharding.AxisType.Manual for t in ctx.axis_types):
         kwargs['mesh'] = mesh
-    return compat_shard_map(fn, **kwargs)(*args)
+    return jax.shard_map(fn, **kwargs)(*args)
 
 
 def _sp_size(mesh):

@@ -82,8 +82,13 @@ def _fp8_vjp_fwd(x, y):
 def _fp8_vjp_bwd(res, g):
     x, y = res
     gf = g.astype(jnp.float32)
-    dx = jnp.matmul(gf, y.astype(jnp.float32).T).astype(x.dtype)
-    dy = jnp.matmul(x.astype(jnp.float32).T, gf).astype(y.dtype)
+    # the contractions jax's own matmul vjp emits (no materialized
+    # transpose): XLA:CPU sums a transposed operand in another order,
+    # and the gradients must equal the f32 rule's bit for bit
+    dx = jax.lax.dot_general(gf, y.astype(jnp.float32),
+                             (((1,), (1,)), ((), ()))).astype(x.dtype)
+    dy = jax.lax.dot_general(x.astype(jnp.float32), gf,
+                             (((0,), (0,)), ((), ()))).astype(y.dtype)
     return dx, dy
 
 

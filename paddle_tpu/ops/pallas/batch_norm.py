@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 
 from . import interpret_mode
-from . import tpu_compiler_params
 
 DEFAULT_BLOCK_R = 512
 
@@ -139,7 +138,7 @@ def _fused_bn_fwd(x2, scale, bias, eps, block_r):
             pltpu.VMEM((8, bc), jnp.float32),
             pltpu.VMEM((2, bc), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'arbitrary', 'arbitrary')),
         interpret=interpret_mode(),
     )(x2, scale.reshape(1, c), bias.reshape(1, c))

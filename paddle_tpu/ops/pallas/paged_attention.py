@@ -42,7 +42,6 @@ import jax.numpy as jnp
 
 from . import interpret_mode
 from . import pallas_enabled
-from . import tpu_compiler_params
 
 _NEG_INF = -1e9
 
@@ -99,7 +98,7 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(pi * bs < seq_len)
     def _body():
-        q = q_ref[0]                                   # [1, d]
+        q = q_ref[0, 0]                                # [1, d]
         k = k_ref[0, 0]                                # [bs, d]
         v = v_ref[0, 0]
         s = jax.lax.dot_general(
@@ -126,7 +125,7 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     def _finish():
         denom = l_scr[:][:, :1]
         denom = jnp.where(denom == 0.0, 1.0, denom)
-        o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[:] / denom).astype(o_ref.dtype)
 
 
 def _paged_pallas(q, k_pages, v_pages, block_tables, seq_lens, sm_scale):
@@ -143,9 +142,12 @@ def _paged_pallas(q, k_pages, v_pages, block_tables, seq_lens, sm_scale):
         num_scalar_prefetch=2,        # block tables, lengths
         grid=(b, h, p),
         in_specs=[
-            # q [B, H, D]: one (1, d) row per (b, h); page axis constant
-            pl.BlockSpec((1, 1, d),
-                         lambda bi, hi, pi, bt, ln: (bi, hi, 0)),
+            # q rides as [B, H, 1, D] so a (b, h) row is a (1, d) block
+            # equal to the array's last two dims — a (1, 1, d) block
+            # over [B, H, D] has a second-minor of 1 that is neither H
+            # nor a multiple of 8, which the TPU lowering refuses
+            pl.BlockSpec((1, 1, 1, d),
+                         lambda bi, hi, pi, bt, ln: (bi, hi, 0, 0)),
             # pages: the physical page id comes from the prefetched
             # block table — the ragged gather IS the index map
             pl.BlockSpec((1, 1, bs, d),
@@ -153,8 +155,8 @@ def _paged_pallas(q, k_pages, v_pages, block_tables, seq_lens, sm_scale):
             pl.BlockSpec((1, 1, bs, dv),
                          lambda bi, hi, pi, bt, ln: (bt[bi, pi], hi, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, dv),
-                               lambda bi, hi, pi, bt, ln: (bi, hi, 0)),
+        out_specs=pl.BlockSpec((1, 1, 1, dv),
+                               lambda bi, hi, pi, bt, ln: (bi, hi, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1, 128), jnp.float32),
             pltpu.VMEM((1, 128), jnp.float32),
@@ -163,14 +165,15 @@ def _paged_pallas(q, k_pages, v_pages, block_tables, seq_lens, sm_scale):
     )
     kernel = functools.partial(_paged_kernel, bs=bs, num_pages=p,
                                sm_scale=sm_scale)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
-        compiler_params=tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((b, h, 1, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary')),
         interpret=interpret_mode(),
-    )(tables, lens, q, k_pages, v_pages)
+    )(tables, lens, q.reshape(b, h, 1, d), k_pages, v_pages)
+    return out.reshape(b, h, dv)
 
 
 def _use_pallas(q, k_pages, v_pages, block_tables):

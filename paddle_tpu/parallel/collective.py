@@ -65,19 +65,6 @@ def assign_grad_buckets(items, target_bytes):
     return buckets
 
 
-def _axis_size(axis_name):
-    """Concrete size of a named axis inside shard_map/pmap. Newer jax
-    has jax.lax.axis_size; elsewhere a psum of a python literal
-    constant-folds to the axis extent."""
-    size = getattr(jax.lax, 'axis_size', None)
-    if size is not None:
-        return size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-axis_size = _axis_size
-
-
 def all_reduce(x, axis_name='dp', op='sum'):
     if op == 'sum':
         return jax.lax.psum(x, axis_name)
@@ -122,7 +109,7 @@ def quantized_all_reduce(x, axis_name='dp', op='sum', block=256,
     if op not in ('sum', 'mean'):
         raise ValueError('quantized_all_reduce supports sum/mean, got '
                          '%r' % op)
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     orig_dtype, orig_shape = x.dtype, x.shape
     flat = x.astype(jnp.float32).reshape(-1)
@@ -194,7 +181,7 @@ def broadcast(x, axis_name, root=0):
     device and paid a full N-way reduction tree for what is pure
     data movement."""
     import jax.numpy as jnp
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = jax.lax.axis_index(axis_name)

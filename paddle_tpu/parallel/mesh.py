@@ -71,39 +71,6 @@ def make_mesh(dp=None, pp=1, sp=1, tp=1, ep=1, devices=None):
     return Mesh(dev_array, AXES)
 
 
-def compat_shard_map(fn, mesh=None, in_specs=None, out_specs=None,
-                     axis_names=None, check_vma=None):
-    """jax.shard_map across the jax versions this repo supports.
-
-    Newer jax exports ``jax.shard_map`` (optional mesh, partial-manual
-    via ``axis_names``, varying-axis checking via ``check_vma``);
-    older jax only has ``jax.experimental.shard_map.shard_map`` with a
-    required mesh, ``auto`` as the complement of the manual axis set,
-    and ``check_rep`` as the checker knob. One wrapper so callers
-    never branch on version."""
-    import jax
-    sm = getattr(jax, 'shard_map', None)
-    if sm is not None:
-        kwargs = dict(in_specs=in_specs, out_specs=out_specs)
-        if mesh is not None:
-            kwargs['mesh'] = mesh
-        if axis_names is not None:
-            kwargs['axis_names'] = frozenset(axis_names)
-        if check_vma is not None:
-            kwargs['check_vma'] = check_vma
-        return sm(fn, **kwargs)
-    from jax.experimental.shard_map import shard_map as _esm
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if check_vma is not None:
-        kwargs['check_rep'] = check_vma
-    if axis_names is not None and mesh is not None:
-        auto = frozenset(str(a) for a in dict(mesh.shape)) \
-            - frozenset(axis_names)
-        if auto:
-            kwargs['auto'] = auto
-    return _esm(fn, **kwargs)
-
-
 def single_axis_mesh(axis='dp', devices=None):
     kwargs = {a: 1 for a in AXES if a != axis}
     return make_mesh(**{axis: None if axis == 'dp' else None}, **kwargs) \

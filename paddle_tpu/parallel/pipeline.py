@@ -39,8 +39,7 @@ def pipeline(stage_fn, stage_params, microbatches, axis_name='pp',
     the last shard, or psum-mask as convenient); with_aux returns
     (outputs, aux_sum).
     """
-    from .collective import axis_size
-    n_stages = axis_size(axis_name)
+    n_stages = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     n_micro = microbatches.shape[0]
     total = n_micro + n_stages - 1
@@ -63,16 +62,9 @@ def pipeline(stage_fn, stage_params, microbatches, axis_name='pp',
         return (nxt, aux_acc), y
 
     # mark the carry varying over pp (ppermute outputs are varying; an
-    # unvarying init would make the scan carry types mismatch).
-    # pcast(to='varying') is the post-0.9 spelling of pvary; fall back
-    # for older jax so the module imports everywhere. Pre-vma jax has
-    # neither and needs no marking — the carry types already match.
+    # unvarying init would make the scan carry types mismatch)
     def _mark_varying(x):
-        if hasattr(jax.lax, 'pcast'):
-            return jax.lax.pcast(x, (axis_name,), to='varying')
-        if hasattr(jax.lax, 'pvary'):
-            return jax.lax.pvary(x, (axis_name,))
-        return x
+        return jax.lax.pcast(x, (axis_name,), to='varying')
 
     buf0 = _mark_varying(jnp.zeros_like(microbatches[0]))
     aux0 = _mark_varying(jnp.zeros((), jnp.float32))
@@ -111,14 +103,12 @@ def pipelined_apply(stage_fn, stacked_params, x, n_micro, mesh,
         out = pipeline(stage_fn, params, mb, axis_name)
         # emit only the last stage's result; zeros elsewhere so a psum
         # over pp reconstructs the true output on every device.
-        from .collective import axis_size
         is_last = jax.lax.axis_index(axis_name) == \
-            axis_size(axis_name) - 1
+            jax.lax.axis_size(axis_name) - 1
         out = jnp.where(is_last, out, jnp.zeros_like(out))
         return jax.lax.psum(out, axis_name)
 
-    from .mesh import compat_shard_map
-    mapped = compat_shard_map(
+    mapped = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(param_specs, P(*mb_axes)),
         out_specs=P(*mb_axes), check_vma=False)
@@ -209,8 +199,7 @@ def pipeline_layer_scan(make_body, x, xs, mesh, n_micro, extras=(),
         return out
 
     out_specs = (P(), P()) if aux else P()
-    from .mesh import compat_shard_map
-    mapped = compat_shard_map(
+    mapped = jax.shard_map(
         inner, mesh=mesh, axis_names=frozenset({axis_name}),
         in_specs=(param_specs, P(), jax.tree.map(lambda _: P(),
                                                  mb_extras)),

@@ -33,8 +33,7 @@ def ring_attention(q, k, v, axis_name='sp', causal=False, scale=None,
     bias, so variable-length batches stay exact under sequence
     parallelism too. Returns [batch, heads, t_local, d].
     """
-    from .collective import axis_size
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     if scale is None:
         scale = q.shape[-1] ** -0.5

@@ -101,36 +101,3 @@ def test_profiler_context():
         x = fluid.layers.data(name='x', shape=[4], dtype='float32')
         out = fluid.layers.fc(input=x, size=2)
         run_startup_and({'x': rand(2, 4)}, [out])
-
-
-def test_compile_cache_env_override_and_optout(monkeypatch, tmp_path):
-    """arm_compile_cache honors JAX_COMPILATION_CACHE_DIR and the
-    compile_cache flag opt-out (PADDLE_TPU_COMPILE_CACHE=false)."""
-    import jax
-
-    from paddle_tpu.core import platform_boot as pb
-    from paddle_tpu.core.flags import FLAGS, get_flag
-    prev_dir = jax.config.jax_compilation_cache_dir
-    try:
-        # explicit flag opt-in: CPU backends only arm when the flag is
-        # explicitly true (the XLA:CPU AOT cache is unsafe on
-        # feature-mismatched hosts — see arm_compile_cache)
-        monkeypatch.delenv('PADDLE_TPU_COMPILE_CACHE', raising=False)
-        get_flag('compile_cache')  # populate FLAGS before setitem
-        monkeypatch.setitem(FLAGS, 'compile_cache', True)
-        monkeypatch.setattr(pb, '_cache_armed', False)
-        monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR',
-                           str(tmp_path / 'c'))
-        pb.arm_compile_cache()
-        assert jax.config.jax_compilation_cache_dir == str(tmp_path / 'c')
-        # opt-out: with the flag False a fresh arm leaves config alone
-        monkeypatch.setattr(pb, '_cache_armed', False)
-        monkeypatch.setitem(FLAGS, 'compile_cache', False)
-        monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR',
-                           str(tmp_path / 'd'))
-        pb.arm_compile_cache()
-        assert jax.config.jax_compilation_cache_dir == str(tmp_path / 'c')
-    finally:
-        # jax.config state is session-global; restore it (monkeypatch
-        # only unwinds env vars and attrs)
-        jax.config.update('jax_compilation_cache_dir', prev_dir)

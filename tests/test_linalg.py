@@ -5,13 +5,9 @@ ops end to end through the Executor on dp in {1, 2, 4} CPU meshes
 (numpy parity, residuals), the dyadic-exact case proving SUMMA's
 result is bit-identical across mesh widths, the O(N^2/P) memory
 contract, panel/block resolution precedence (attr > env > tuner >
-default), the autotuner's linalg op family under injected timings,
-the blocked-layout analysis pass, and the bench QUEUE <-> argparse
-consistency lock.
+default), the autotuner's linalg op family under injected timings, and
+the blocked-layout analysis pass.
 """
-
-import os
-import sys
 
 import numpy as np
 import pytest
@@ -21,8 +17,6 @@ import jax
 import paddle_tpu as fluid
 from paddle_tpu import analysis, linalg, observe, tuning
 from paddle_tpu.parallel.mesh import make_mesh
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -334,25 +328,3 @@ def test_linalg_pass_checks_factorization_and_powit_layouts():
     codes = [d.code for d in analysis.run_passes(
         prog, fetch_names=[vout, lam], passes=['linalg'])]
     assert codes == ['implicit-full-gather']
-
-
-# -------------------------------------------------- bench consistency
-def test_every_queue_workload_is_an_argparse_choice():
-    """The PR 13 bug class: a watcher QUEUE entry whose workload is
-    not an accepted --workload choice fails only when the watcher
-    drains on chip. Lock QUEUE (and the bench child dispatch) to
-    WORKLOAD_CHOICES."""
-    sys.path.insert(0, REPO)
-    sys.path.insert(0, os.path.join(REPO, 'tools'))
-    try:
-        import bench
-        import onchip_watcher
-    finally:
-        sys.path.pop(0)
-        sys.path.pop(0)
-    choices = set(bench.WORKLOAD_CHOICES)
-    for key, workload, _env, _timeout in onchip_watcher.QUEUE:
-        assert workload in choices, \
-            'QUEUE entry %r runs workload %r which bench.py rejects' \
-            % (key, workload)
-    assert 'linalg' in choices

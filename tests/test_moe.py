@@ -7,8 +7,6 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu.parallel.mesh import make_mesh
 from paddle_tpu.parallel.transpiler import ParallelStrategy, transpile
-# old-jax SPMD capability gate shared with the other pp suites
-from test_parallel import requires_modern_spmd
 
 
 def _numpy_switch_moe(x2, gate_w, w1, b1, w2, b2, capacity, k=1):
@@ -225,7 +223,6 @@ def _train_moe_pp(mesh=None, strategy=None, aux_weight=0.0, steps=3,
 
 
 @pytest.mark.parametrize('top_k', [1, 2])
-@requires_modern_spmd
 def test_moe_pipeline_ep_matches_single_device(top_k):
     """Program-path pipelining of the MoE stack (pp x ep): stage-sharded
     layers, expert weights still 'ep'-split inside the stage (GSPMD
@@ -244,7 +241,6 @@ def test_moe_pipeline_ep_matches_single_device(top_k):
     assert tuple(spec)[:2] == ('pp', 'ep'), spec
 
 
-@requires_modern_spmd
 def test_moe_pipeline_four_axis_matches_single_device():
     """pp x sp x ep (+ the causal ring nested inside the stage): the MoE
     stack's attention dispatches ring attention under pipelining while
@@ -260,7 +256,6 @@ def test_moe_pipeline_four_axis_matches_single_device():
     np.testing.assert_allclose(four, base, rtol=2e-4, atol=1e-5)
 
 
-@requires_modern_spmd
 def test_moe_pipeline_with_aux_trains():
     """dp x pp x ep with the load-balancing aux on: the pipelined aux is
     the mean of per-microbatch means (documented semantic difference),

@@ -390,12 +390,26 @@ def test_metrics_report_cli(tmp_path):
 
 
 # ------------------------------------------------------------- mfu
-def test_mfu_and_goodput_accounting(monkeypatch):
-    from paddle_tpu import observe
-    from paddle_tpu.observe.mfu import GoodputTracker, device_peak_flops
+def test_device_peak_flops_is_a_table_lookup(monkeypatch):
+    """Keyed by the device_kind string the chip prints; an unknown TPU
+    is an error, a CPU has no peak, and no env var overrides either."""
+    import collections
 
+    from paddle_tpu.observe.mfu import device_peak_flops
+
+    Dev = collections.namedtuple('Dev', 'platform device_kind')
     monkeypatch.setenv('PADDLE_TPU_PEAK_TFLOPS', '100')
-    assert device_peak_flops() == 100e12
+    monkeypatch.setenv('BENCH_PEAK_TFLOPS', '100')
+    assert device_peak_flops(Dev('tpu', 'TPU v5 lite')) == 197e12
+    with pytest.raises(KeyError, match='TPU v9'):
+        device_peak_flops(Dev('tpu', 'TPU v9'))
+    assert device_peak_flops(Dev('cpu', 'cpu')) is None
+    assert device_peak_flops() is None       # the suite runs on CPU
+
+
+def test_mfu_and_goodput_accounting():
+    from paddle_tpu import observe
+    from paddle_tpu.observe.mfu import GoodputTracker
 
     gp = GoodputTracker()
     gp.begin()
@@ -414,6 +428,5 @@ def test_cost_analysis_flops_forms():
     from paddle_tpu.observe.mfu import cost_analysis_flops
 
     assert cost_analysis_flops({'flops': 12.0}) == 12.0
-    assert cost_analysis_flops([{'flops': 7.0}]) == 7.0
     assert cost_analysis_flops({}) is None
     assert cost_analysis_flops('garbage') is None

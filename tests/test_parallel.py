@@ -14,34 +14,6 @@ from paddle_tpu.parallel.transpiler import ParallelStrategy, transpile
 from util import rand
 
 
-def modern_spmd_supported():
-    """Version/capability probe for the pipeline-parallel SPMD tests.
-
-    jax builds that export ``jax.shard_map`` lower the partial-manual
-    stage map (manual over 'pp', GSPMD managing dp/tp/sp inside the
-    stage) correctly. Older builds with only the experimental
-    shard_map hit genuine XLA SPMD limits on those programs:
-    ``PartitionId instruction is not supported for SPMD partitioning``
-    at dispatch, ``shard_map._SpecError`` on unreduced outputs, and
-    scan-carry replication-type mismatches (PR 14 review notes). A
-    LIVE compile probe is not an option — one of the failure modes is
-    a hard C++ CHECK abort (spmd_partitioner.cc) that would take the
-    whole pytest process down — so this is a version gate, with
-    ``PADDLE_TPU_FORCE_PP_TESTS=1`` to run the guarded tests anyway
-    (e.g. to revalidate a backported fix)."""
-    import os
-    if os.environ.get('PADDLE_TPU_FORCE_PP_TESTS') == '1':
-        return True
-    return hasattr(jax, 'shard_map')
-
-
-requires_modern_spmd = pytest.mark.skipif(
-    not modern_spmd_supported(),
-    reason='pipeline-parallel programs need a jax build with modern '
-           'SPMD support (jax.shard_map); this one hits PartitionId/'
-           '_SpecError — set PADDLE_TPU_FORCE_PP_TESTS=1 to run anyway')
-
-
 def _build_mlp_loss():
     x = fluid.layers.data(name='x', shape=[6], dtype='float32')
     y = fluid.layers.data(name='y', shape=[1], dtype='int64')
@@ -144,7 +116,7 @@ def test_row_sharded_embedding_matches_unsharded():
 def test_ring_attention_equals_full_attention():
     from paddle_tpu.parallel.ring_attention import ring_attention
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from paddle_tpu.parallel.mesh import compat_shard_map as shard_map
+    from jax import shard_map
 
     b, h, t, d, n_shards = 2, 2, 32, 8, 8
     rng = np.random.RandomState(0)
@@ -180,7 +152,7 @@ def test_ring_attention_equals_full_attention():
 def test_collectives_roundtrip():
     from paddle_tpu.parallel import collective
     from jax.sharding import Mesh, PartitionSpec as P
-    from paddle_tpu.parallel.mesh import compat_shard_map as shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:4]).reshape(4), ('dp',))
     x = np.arange(8, dtype='float32').reshape(4, 2)
@@ -262,7 +234,6 @@ def test_accumulator_sharding_survives_colliding_names():
             assert sh[vname] == sh[pname], (pname, vname)
 
 
-@requires_modern_spmd
 def test_dryrun_multichip_entrypoint():
     import importlib
     import __graft_entry__
@@ -367,7 +338,6 @@ def _train_scan_transformer(mesh=None, strategy=None, steps=3,
         for _ in range(steps)]
 
 
-@requires_modern_spmd
 def test_program_pipeline_matches_single_device():
     """Program-level pipeline parallelism: a fluid-built transformer
     (scan_layers=True) transpiled with pipeline_parallel trains through
@@ -389,7 +359,6 @@ def test_program_pipeline_matches_single_device():
     np.testing.assert_allclose(pp_dp, base, rtol=2e-4, atol=1e-5)
 
 
-@requires_modern_spmd
 def test_program_pipeline_composes_with_tp():
     """pp x tp (the scaling-book large-model config): the shard_map is
     manual over pp only, so GSPMD manages the intra-stage Megatron
@@ -409,7 +378,6 @@ def test_program_pipeline_composes_with_tp():
     assert tuple(spec_o) == ('pp', 'tp', None), spec_o
 
 
-@requires_modern_spmd
 def test_program_pipeline_composes_with_sp():
     """pp x sp: the ring-attention dispatch nests as an sp-manual inner
     shard_map inheriting the pp-manual context mesh — long-context
@@ -442,7 +410,6 @@ def test_program_pipeline_composes_with_run_steps():
     np.testing.assert_allclose(windowed, per_step, rtol=2e-4, atol=1e-5)
 
 
-@requires_modern_spmd
 def test_program_pipeline_composes_with_grad_accum():
     """GradientAccumulator's gated updates under a pipelined program:
     the accumulator state and phase counter live OUTSIDE the pp
@@ -515,7 +482,6 @@ def test_program_pipeline_indivisible_layers_raises():
                   ParallelStrategy(pipeline_parallel=True))
 
 
-@requires_modern_spmd
 def test_checkpoint_portable_across_meshes(tmp_path):
     """A checkpoint saved while training on a dp x pp x tp mesh (params
     sharded: stage-split stacks, Megatron tp splits) loads on a single
@@ -795,7 +761,7 @@ def test_ring_attention_masked_equals_reference():
     masked reference, including rows whose length falls inside an
     earlier shard's block."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from paddle_tpu.parallel.mesh import compat_shard_map as shard_map
+    from jax import shard_map
     from paddle_tpu.parallel.ring_attention import ring_attention
     from paddle_tpu.ops.attention_ops import reference_attention
 

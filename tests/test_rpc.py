@@ -478,19 +478,3 @@ def test_metrics_report_fleet_merges_worker_processes(tmp_path, capsys):
     rc = metrics_report.main([str(tmp_path), '--fleet'])
     assert rc == 0
     assert 'worker processes' in capsys.readouterr().out
-
-
-def test_crosshost_workload_is_wired():
-    """QUEUE <-> argparse choices lock extends to the new workload."""
-    sys.path.insert(0, REPO)
-    sys.path.insert(0, os.path.join(REPO, 'tools'))
-    try:
-        import bench
-        import onchip_watcher
-    finally:
-        sys.path.pop(0)
-        sys.path.pop(0)
-    assert 'crosshost' in bench.WORKLOAD_CHOICES
-    assert any(w == 'crosshost'
-               for _k, w, _e, _t in onchip_watcher.QUEUE)
-    assert callable(bench.bench_crosshost)

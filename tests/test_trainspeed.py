@@ -172,14 +172,14 @@ def test_fp8_matmul_parity_and_straight_through_grads():
     rel = np.linalg.norm(got - ref) / np.linalg.norm(ref)
     assert rel < 0.05, rel
     assert got.dtype == ref.dtype
-    # straight-through vjp: gradients are the EXACT f32 matmul vjp —
-    # fp8 quantization error must not leak into the backward
+    # straight-through vjp: gradients are the f32 matmul vjp — fp8
+    # quantization error (~4e-2) must not leak into the backward
     gx, gy = jax.grad(lambda x, y: fp8_matmul(x, y).sum(),
                       argnums=(0, 1))(a, b)
     rx, ry = jax.grad(lambda x, y: jnp.matmul(x, y).sum(),
                       argnums=(0, 1))(a, b)
-    assert np.array_equal(np.asarray(gx), np.asarray(rx))
-    assert np.array_equal(np.asarray(gy), np.asarray(ry))
+    np.testing.assert_allclose(np.asarray(gx), np.asarray(rx), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(gy), np.asarray(ry), rtol=1e-6)
 
 
 def test_fp8_dispatch_table_and_env_gate(tmp_path, monkeypatch):

@@ -287,13 +287,6 @@ def test_aot_tampered_cache_falls_back(tmp_path, monkeypatch):
     np.testing.assert_allclose(r1[0], r2[0])       # live compile worked
 
 
-def test_aot_cache_disabled_by_default_on_cpu():
-    from paddle_tpu.core import aot_cache
-    assert not aot_cache.enabled({})               # auto = TPU only
-    assert aot_cache.enabled({'PADDLE_TPU_AOT_CACHE': '1'})
-    assert not aot_cache.enabled({'PADDLE_TPU_AOT_CACHE': '0'})
-
-
 def test_aot_fingerprint_content_not_identity(tmp_path, monkeypatch):
     """Two Program OBJECTS with identical content share a fingerprint;
     different content (one extra layer) does not."""
@@ -384,7 +377,7 @@ def test_cold_then_warm_subprocess_e2e(tmp_path):
     evidence trail)."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     cmd = [sys.executable, os.path.join(repo, 'bench.py'),
-           '--workload', 'autotune_child', '--backend', 'cpu']
+           '--workload', 'autotune_child']
 
     def run(tag):
         env = dict(os.environ)

@@ -1,11 +1,11 @@
 """Summarize a paddle_tpu.observe metrics JSONL.
 
 Reads the snapshot/summary lines written by ``observe.enable(jsonl=...)``
-(one JSON object per line; bench.py and tools/onchip_watcher.py children
-append here, pid-tagged) and prints a human summary: p50/p95/max per
+(one JSON object per line; bench.py workloads append here,
+pid-tagged) and prints a human summary: p50/p95/max per
 histogram, final counter/gauge values, and the MFU/goodput headline.
 
-    python tools/metrics_report.py ONCHIP_r05_metrics.jsonl
+    python tools/metrics_report.py run.jsonl
     python tools/metrics_report.py run.jsonl --json | jq .mfu
 
 By default the newest ``kind: "summary"`` line is reported (the

@@ -7,10 +7,10 @@ the runner-up), whether the entry was measured in-process or recorded
 for replay, and the writer's jax version. Runs on a bastion host with
 nothing but python3 — the same contract as ``tools/ckpt_inspect.py``.
 
-    python tools/tuning_inspect.py /tmp/paddle_tpu_tuning_me.json
+    python tools/tuning_inspect.py .jax_cache/tuning.json
     python tools/tuning_inspect.py TABLE --json | jq .tables
     python tools/tuning_inspect.py TABLE --op flash_attention
-    python tools/tuning_inspect.py TABLE --device-kind 'TPU v5e'
+    python tools/tuning_inspect.py TABLE --device-kind 'TPU v5 lite'
 
 Schema: paddle_tpu/tuning/table.py (format_version 1). Companion of
 ``tools/ckpt_inspect.py`` (checkpoints), ``tools/flight_report.py``
