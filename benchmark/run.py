@@ -1,0 +1,353 @@
+#!/usr/bin/env python3
+"""One run of one benchmark cell; the last line of stdout is the result.
+
+    python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s>
+                             --trace <0|1> [--rehearsal]
+
+The cell, its configuration, traffic, runner, per-layer metrics and
+their readers are all found by name through BENCHMARK.json (see
+manifest.py and README.md). This file knows no cell: it checks the
+device, arms the compile cache, hands the runner a Context that keeps
+the window, the spans, the compile counter and the profiler, reduces
+the trace, and prints the contract's JSON line.
+
+Without a TPU, or with fewer chips than the cell asks for, it exits 2
+before building anything. ``--rehearsal`` (explicit, never automatic)
+asks for the host CPU by name and takes the tiny sizes of the config's
+and the traffic's ``rehearsal`` blocks: it exists to debug the harness
+without a chip, stamps ``platform=cpu`` and reports every time, rate
+and share as null.
+"""
+
+import time
+T_PROCESS = time.perf_counter()        # set-up is clocked from here
+
+import argparse            # noqa: E402
+import contextlib          # noqa: E402
+import json                # noqa: E402
+import os                  # noqa: E402
+import shutil              # noqa: E402
+import sys                 # noqa: E402
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+if os.path.dirname(HERE) not in sys.path:
+    sys.path.insert(0, os.path.dirname(HERE))
+
+from benchmark import manifest, tracelib            # noqa: E402
+
+TRACE_SECONDS = 3.0        # the traced tail of the window
+WINDOW_SPAN = 'bench.window'
+GAP_LABELS = ('bench.dispatch', 'bench.wait_oldest')
+COMPILE_EVENTS = ('/jax/compilation_cache/cache_hits',
+                  '/jax/compilation_cache/cache_misses')
+COMPILE_DURATION = '/jax/core/compile/backend_compile_duration'
+
+
+class Context(object):
+    """What a runner is given: the cell's data, and the window."""
+
+    def __init__(self, resolved, args, root):
+        self.cell = resolved['cell']
+        self.config = resolved['config']
+        self.traffic = resolved['traffic']
+        self.reference = manifest.load_module(resolved['reference'])
+        self.seed = int(args.seed)
+        self.seconds = float(args.seconds)
+        self.trace = bool(args.trace)
+        self.rehearsal = bool(args.rehearsal)
+        self.root = root
+        self.spans = {}
+        self.samples = {}
+        self.sources = {}
+        self.setup_s = None
+        self.t_window = None
+        self.t_trace = None            # perf_counter when tracing began
+        self._trace_dir = os.path.join(root, '.bench_trace')
+        self._window_span = None
+        self._compiles = 0
+        self.compiles_in_window = None
+        self.registry = [None, None]
+        self.memory = None             # memory_stats() as the window ends
+
+    def sized(self, block):
+        """A config's or traffic's block with its ``rehearsal`` overrides
+        laid over it under --rehearsal."""
+        out = {k: v for k, v in block.items() if k != 'rehearsal'}
+        if self.rehearsal:
+            out.update(block.get('rehearsal', {}))
+        return out
+
+    # -------------------------------------------------------------- spans
+    @contextlib.contextmanager
+    def span(self, name):
+        """A host span of the benchmark's own: its duration is kept for
+        the span readers, and while the profiler runs it is also written
+        into the trace, where the idle gaps are named after it."""
+        note = None
+        if self.t_trace is not None:
+            import jax
+            note = jax.profiler.TraceAnnotation(name)
+            note.__enter__()
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            self.spans.setdefault(name, []).append(
+                time.perf_counter() - t0)
+            if note is not None:
+                note.__exit__(None, None, None)
+
+    # ------------------------------------------------------------- window
+    def on_compile(self, event, *_, **__):
+        if event in COMPILE_EVENTS or event == COMPILE_DURATION:
+            self._compiles += 1
+
+    def begin_window(self):
+        """Everything before this instant is set-up."""
+        self.spans.clear()
+        self.samples.clear()
+        self.registry[0] = self._snapshot()
+        self._compiles = 0
+        self.t_window = time.perf_counter()
+        self.setup_s = self.t_window - T_PROCESS
+        return self.t_window
+
+    def window_left(self):
+        return self.t_window + self.seconds - time.perf_counter()
+
+    def tick(self):
+        """Called by the runner inside its loop: starts the profiler when
+        the traced tail of the window begins."""
+        if not self.trace or self.t_trace is not None:
+            return
+        tail = min(TRACE_SECONDS, self.seconds / 2.0)
+        if self.window_left() > tail:
+            return
+        import jax
+        shutil.rmtree(self._trace_dir, ignore_errors=True)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.enable_hlo_proto = False
+        jax.profiler.start_trace(self._trace_dir, profiler_options=options)
+        self.t_trace = time.perf_counter()
+        self._window_span = jax.profiler.TraceAnnotation(WINDOW_SPAN)
+        self._window_span.__enter__()
+
+    def end_window(self):
+        self.compiles_in_window = self._compiles
+        self.registry[1] = self._snapshot()
+        import jax
+        self.memory = [d.memory_stats() or {}
+                       for d in jax.devices()[:self.cell['chips']]]
+        if self._window_span is not None:
+            import jax
+            self._window_span.__exit__(None, None, None)
+            self._window_span = None
+            jax.profiler.stop_trace()
+
+    def _snapshot(self):
+        if not self.trace:
+            return None
+        from paddle_tpu import observe
+        return observe.snapshot()
+
+    def take_trace(self):
+        """The reduced trace, or None; the directory is removed."""
+        try:
+            path = tracelib.find_xplane(self._trace_dir)
+            return tracelib.read_xplane(path) if path else None
+        finally:
+            shutil.rmtree(self._trace_dir, ignore_errors=True)
+
+
+def say(tag, **fields):
+    print('%s %s' % (tag, json.dumps(fields, sort_keys=True)), flush=True)
+
+
+def device_stamp(chips, rehearsal):
+    """jax's first device and the count, or exit 2 with no result."""
+    import jax
+    try:
+        devs = jax.devices()
+    except RuntimeError as e:
+        sys.stderr.write('benchmark: jax found no device: %s\n' % e)
+        sys.exit(2)
+    dev = devs[0]
+    stamp = {'platform': dev.platform, 'kind': dev.device_kind,
+             'count': len(devs)}
+    if not rehearsal and (dev.platform != 'tpu' or len(devs) < chips):
+        sys.stderr.write(
+            'benchmark: the cell needs %d TPU chip(s), jax found platform '
+            '%r (%s x%d). Nothing was run.\n'
+            % (chips, dev.platform, dev.device_kind, len(devs)))
+        sys.exit(2)
+    return stamp
+
+
+def memory_fields(stats):
+    """The line's memory keys from each used chip's ``memory_stats()``
+    as the window ended (what a check allocates after the window is not
+    the cell's). The allocator's ``bytes_in_use`` holds arrays only; a
+    program's scratch is reserved apart, out of what the arrays leave
+    free, and stays reserved between steps (the runtime said so when a
+    program did not load: "Attempting to reserve 13.12G at the bottom of
+    memory ... There are 12.17G free", with 4.7 GB of arrays on a
+    16.9 GB chip; PERF.md, PR 24). ``memory_peak_bytes`` is the two read
+    at one instant and added: what the chip holds while the cell runs.
+    The allocator's own two peaks, which need not coincide (set-up's
+    arrays, the window's scratch), are given beside it."""
+    for i, one in enumerate(stats):
+        say('MEMORY', device=i, **one)
+    full = [s for s in stats if 'bytes_in_use' in s]
+    if not full:
+        return {'memory_peak_bytes': None}
+    top = max(full, key=lambda s: s['bytes_in_use']
+              + s.get('bytes_reserved', 0))
+    return {'memory_peak_bytes': int(top['bytes_in_use'])
+            + int(top.get('bytes_reserved', 0)),
+            'memory_arrays_peak_bytes': top.get('peak_bytes_in_use'),
+            'memory_reserved_peak_bytes': top.get('peak_bytes_reserved'),
+            'memory_limit_bytes': top.get('bytes_limit')}
+
+
+def reduce_trace(ctx, chips):
+    """(device fields, breakdown, trace for the readers) of the run's
+    trace; all None where no device line was recorded."""
+    trace = ctx.take_trace()
+    if not trace:
+        return {}, None, None
+    say('TRACE_LINES', **trace['lines'])
+    window = tracelib.window_of(trace, WINDOW_SPAN)
+    used = sorted(trace['devices'])[:chips]
+    if window is None or not used:
+        return {}, None, trace
+    lo, hi = window
+    busy = [tracelib.busy_ns(trace['devices'][i], lo, hi) for i in used]
+    fields = {'busy_s': sum(busy) / len(busy) / 1e9,
+              'window_s': (hi - lo) / 1e9}
+    first = trace['devices'][used[0]]
+    breakdown = {
+        'device_ops': tracelib.top_ops(first, lo, hi, 10),
+        'idle_gaps': tracelib.idle_gaps(first, trace['host'], lo, hi,
+                                        GAP_LABELS, 5)}
+    trace['window'] = (lo, hi)
+    trace['first'] = first
+    return fields, breakdown, trace
+
+
+def main(argv=None, root=None):
+    ap = argparse.ArgumentParser(description=__doc__.split('\n')[0])
+    ap.add_argument('--workload', required=True)
+    ap.add_argument('--seed', type=int, default=0)
+    ap.add_argument('--seconds', type=float, required=True)
+    ap.add_argument('--trace', type=int, choices=(0, 1), default=0)
+    ap.add_argument('--rehearsal', action='store_true',
+                    help='tiny sizes on the host CPU; times are null')
+    args = ap.parse_args(argv)
+
+    root = root or os.path.dirname(HERE)
+    m = manifest.load(root)
+    resolved = manifest.resolve(m, args.workload)
+    chips = resolved['cell']['chips']
+    if root not in sys.path:
+        sys.path.insert(0, root)
+
+    # importing the package does not start jax's backend
+    from paddle_tpu import observe
+    from paddle_tpu.core.platform_boot import (arm_compile_cache,
+                                               force_host_cpu)
+    # the XLA cost probe would compile every program a second time
+    os.environ.setdefault('PADDLE_TPU_OBSERVE_COST', '0')
+    if args.rehearsal:
+        force_host_cpu(8)
+    import jax
+    device = device_stamp(chips, args.rehearsal)
+    if args.rehearsal:
+        print('REHEARSAL platform=%s' % device['platform'], flush=True)
+    say('DEVICE', **device)
+
+    peaks = manifest.read_json(os.path.join(m['_dir'], 'peaks.json'))
+    if device['kind'] in peaks['devices']:
+        peaks = peaks['devices'][device['kind']]
+    elif args.rehearsal:
+        peaks = None
+    else:
+        sys.stderr.write('benchmark: no peaks for device kind %r in '
+                         'peaks.json\n' % device['kind'])
+        return 2
+
+    arm_compile_cache()
+    say('COMPILE_CACHE', dir=jax.config.jax_compilation_cache_dir)
+    if args.trace:
+        observe.enable()
+    ctx = Context(resolved, args, root)
+    jax.monitoring.register_event_listener(ctx.on_compile)
+    jax.monitoring.register_event_duration_secs_listener(ctx.on_compile)
+
+    runner = manifest.load_module(resolved['runner'])
+    result = runner.run(ctx)
+    if ctx.setup_s is None or ctx.compiles_in_window is None:
+        raise RuntimeError('runner %s never opened or closed its window'
+                           % resolved['runner'])
+
+    measured = dict(result['end_to_end'], setup_s=ctx.setup_s)
+    correct = bool(result['correct']) and ctx.compiles_in_window == 0
+    say('WINDOW', setup_s=ctx.setup_s,
+        compiles_in_window=ctx.compiles_in_window,
+        **result.get('notes', {}))
+    if not args.rehearsal:
+        # everything the runner measured, also what the manifest does not
+        # name for this cell: the spreads of candidates are read from it
+        say('MEASURED', **measured)
+
+    device.update(memory_fields(ctx.memory or []))
+    out = {'correct': correct, 'attempted': int(result['attempted']),
+           'failed': int(result['failed']), 'metrics': {},
+           'device': device}
+    if args.trace:
+        fields, breakdown, trace = reduce_trace(ctx, chips)
+        device.update(fields)
+        if breakdown:
+            out['breakdown'] = breakdown
+        sources = dict(
+            ctx.sources, spans=ctx.spans, samples=ctx.samples,
+            registry_before=ctx.registry[0], registry_after=ctx.registry[1],
+            trace=trace, measured=measured, config=ctx.config,
+            traffic=ctx.traffic, cell=ctx.cell, peaks=peaks,
+            bench_dir=m['_dir'])
+        for metric in resolved['per_layer']:
+            value = manifest.load_module(metric['reader']).read(
+                metric['spec'].get('args', {}), sources)
+            if value is not None:
+                out['metrics'][metric['entry']['name']] = {
+                    'value': value, 'unit': metric['entry']['unit'],
+                    'source': metric['entry']['source']}
+    else:
+        for entry in resolved['end_to_end']:
+            out['metrics'][entry['name']] = {
+                'value': measured[entry['name']], 'unit': entry['unit'],
+                'source': entry['source']}
+    if args.rehearsal:
+        # a CPU's clock is never written under a device metric's name
+        out['rehearsal'] = True
+        for metric in out['metrics'].values():
+            if metric['source'] != 'program_counter':
+                metric['value'] = None
+    for metric in out['metrics'].values():
+        del metric['source']
+    print(json.dumps(out), flush=True)
+    return 0
+
+
+if __name__ == '__main__':
+    try:
+        sys.exit(main())
+    except Exception:
+        # a program the chip refuses to load leaves the TPU runtime unable
+        # to shut down: the interpreter then hangs at exit until the
+        # caller's limit (PERF.md, PR 24). Say what failed and leave.
+        import traceback
+        traceback.print_exc()
+        sys.stdout.flush()
+        sys.stderr.flush()
+        os._exit(1)

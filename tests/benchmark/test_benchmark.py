@@ -1,0 +1,521 @@
+"""The benchmark's own arithmetic and its by-name resolution, off the
+chip: the manifest is sound, every cell resolves to files, a new cell
+is found as files alone, the trace reduction's interval arithmetic, the
+load generator's schedule and clocking, percentiles, and the command's
+behaviour without a TPU. No test here describes a TPU topology."""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+import time
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from benchmark import loadgen, manifest, stats, tracelib  # noqa: E402
+from benchmark import run as bench                         # noqa: E402
+
+MANIFEST = manifest.load(REPO)
+CELLS = [c['name'] for c in MANIFEST['workloads']]
+RUN = os.path.join(REPO, 'benchmark', 'run.py')
+
+
+# ------------------------------------------------------------ manifest
+def test_manifest_meets_the_contract():
+    assert manifest.problems(MANIFEST) == []
+    assert set(MANIFEST) - {'_root', '_dir'} == {
+        'command', 'paths', 'run_seconds', 'configs', 'workloads',
+        'end_to_end', 'per_layer'}
+    assert 1 <= MANIFEST['run_seconds'] <= 51
+    assert os.path.getsize(os.path.join(REPO, 'BENCHMARK.json')) < 65536
+
+
+def test_problems_are_found_when_planted():
+    bad = json.loads(json.dumps({k: v for k, v in MANIFEST.items()
+                                 if not k.startswith('_')}))
+    bad['per_layer'][0]['moves'] = 'no_such_metric'
+    bad['end_to_end'][0]['unit'] = 'tokens per second'
+    bad['workloads'][0]['name'] = 'has space'
+    found = ' '.join(manifest.problems(bad))
+    assert 'no_such_metric' in found and 'bad unit' in found \
+        and 'has space' in found
+
+
+@pytest.mark.parametrize('cell', CELLS)
+def test_cell_resolves_to_files_by_name(cell):
+    r = manifest.resolve(MANIFEST, cell)
+    assert os.path.isfile(r['runner']) and os.path.isfile(r['reference'])
+    assert r['config']['runner'] in ('train', 'serve')
+    assert r['config']['reference']
+    assert r['config']['reduced'] == [] and r['config']['assumed']
+    assert 'rehearsal' in r['config'] and 'rehearsal' in r['traffic']
+    assert {e['name'] for e in r['end_to_end']} >= {'setup_s'}
+    assert len(r['end_to_end']) >= 2 and r['per_layer']
+    for metric in r['per_layer']:
+        assert callable(manifest.load_module(metric['reader']).read)
+
+
+def test_unknown_cell_is_an_error_that_names_the_cells():
+    with pytest.raises(manifest.ManifestError, match=CELLS[0]):
+        manifest.resolve(MANIFEST, 'no.such_cell')
+
+
+def test_a_new_cell_metric_reader_and_runner_are_found_as_files(
+        tmp_path, capsys):
+    """What a later PR does: new files, new entries, no edit."""
+    root = str(tmp_path)
+    shutil.copy(os.path.join(REPO, 'BENCHMARK.json'), root)
+    bdir = os.path.join(root, 'benchmark')
+    shutil.copytree(os.path.join(REPO, 'benchmark'), bdir,
+                    ignore=shutil.ignore_patterns('__pycache__'))
+    before = {p: open(os.path.join(dp, p), 'rb').read()
+              for dp, _, fs in os.walk(bdir) for p in fs}
+
+    def write(rel, text):
+        with open(os.path.join(bdir, rel), 'w') as f:
+            f.write(text)
+
+    write('configs/toy.json', json.dumps({
+        'name': 'toy', 'runner': 'noop', 'reduced': [], 'model': {}}))
+    write('traffic/idle.json', json.dumps({'kind': 'idle', 'naps': 3}))
+    write('references/toy.py', 'ANSWER = 3\n')
+    write('runners/noop.py',
+          'def run(ctx):\n'
+          '    ctx.begin_window()\n'
+          '    with ctx.span("bench.nap"):\n'
+          '        pass\n'
+          '    ctx.samples["naps"] = [ctx.traffic["naps"]]\n'
+          '    ctx.end_window()\n'
+          '    right = ctx.traffic["naps"] == ctx.reference.ANSWER\n'
+          '    return {"correct": right, "attempted": 1, "failed": 0,\n'
+          '            "end_to_end": {"naps_per_s": 1.0}}\n')
+    write('layer_metrics/toy.naps.json', json.dumps(
+        {'reader': 'first_sample', 'args': {'gauge': 'naps'}}))
+    write('readers/first_sample.py',
+          'def read(args, sources):\n'
+          '    return sources["samples"][args["gauge"]][0]\n')
+    path = os.path.join(root, 'BENCHMARK.json')
+    m = manifest.read_json(path)
+    m['configs'].append({'name': 'toy', 'source': 'none', 'reduced': [],
+                         'file': 'benchmark/configs/toy.json',
+                         'why': 'throw-away'})
+    m['workloads'].append({'name': 'toy.idle', 'config': 'toy',
+                           'traffic': 'idle', 'chips': 1, 'why': 'x'})
+    m['end_to_end'].append({'name': 'naps_per_s', 'unit': 'naps/s',
+                            'better': 'higher', 'bound': 0.05,
+                            'source': 'host_clock',
+                            'workloads': ['toy.idle']})
+    m['per_layer'].append({'name': 'toy.naps', 'unit': 'count',
+                           'better': 'higher', 'layer': 'toy',
+                           'source': 'program_counter',
+                           'moves': 'naps_per_s',
+                           'workloads': ['toy.idle']})
+    with open(path, 'w') as f:
+        json.dump(m, f)
+
+    grown = manifest.load(root)
+    assert manifest.problems(grown) == []
+    r = manifest.resolve(grown, 'toy.idle')
+    assert r['runner'].endswith('runners/noop.py')
+    assert r['reference'].endswith('references/toy.py')
+    assert [p['entry']['name'] for p in r['per_layer']] == ['toy.naps']
+    for trace, want in ((0, {'naps_per_s', 'setup_s'}), (1, {'toy.naps'})):
+        assert bench.main(['--workload', 'toy.idle', '--seconds', '1',
+                           '--trace', str(trace), '--rehearsal'],
+                          root=root) == 0
+        last = json.loads(capsys.readouterr().out.splitlines()[-1])
+        assert set(last['metrics']) == want and last['correct'] is True
+    assert last['metrics']['toy.naps'] == {'value': 3, 'unit': 'count'}
+    # nothing that was there was edited
+    after = {p: open(os.path.join(dp, p), 'rb').read()
+             for dp, _, fs in os.walk(bdir) for p in fs
+             if '__pycache__' not in dp}
+    assert all(after[p] == before[p] for p in before)
+    # an old cell still resolves beside the new one
+    assert manifest.resolve(grown, CELLS[0])['cell']['name'] == CELLS[0]
+
+
+# --------------------------------------------------- interval arithmetic
+EVENTS = [('fusion.1', 0, 40), ('all-reduce.3', 30, 30),
+          ('fusion.2', 50, 20), ('all-gather.7', 90, 10)]
+
+
+def test_union_and_idle_share():
+    assert tracelib.merged([(5, 9), (0, 3), (2, 4), (9, 9)]) == \
+        [(0, 4), (5, 9)]
+    assert tracelib.busy_ns(EVENTS, 0, 100) == 80          # [0,70)+[90,100)
+    assert tracelib.idle_share(EVENTS, 0, 100) == pytest.approx(0.2)
+    assert tracelib.busy_ns(EVENTS, 60, 95) == 15          # clipped
+    assert tracelib.idle_share([], 0, 100) == 1.0
+
+
+def test_subtract_keeps_what_is_not_covered():
+    assert tracelib.subtract([(0, 10), (20, 30)],
+                             [(2, 4), (8, 22), (29, 40)]) == \
+        [(0, 2), (4, 8), (22, 29)]
+    assert tracelib.subtract([(0, 10)], []) == [(0, 10)]
+
+
+def test_exposed_time_is_the_part_nothing_else_covers():
+    rx = ['all-reduce', 'all-gather']
+    assert tracelib.busy_ns(tracelib.matching(EVENTS, rx), 0, 100) == 40
+    # all-reduce [30,60): fusion.1 covers to 40, fusion.2 from 50 -> 10;
+    # all-gather [90,100) runs alone -> 10
+    assert tracelib.exposed_ns(EVENTS, rx, 0, 100) == 20
+    assert tracelib.exposed_ns(EVENTS, rx, 0, 50) == 10
+    d = os.path.join(REPO, 'benchmark', 'readers')
+    src = {'trace': {'window': (0, 100), 'first': EVENTS}, 'trace_steps': 2}
+    share = manifest.load_module(os.path.join(d, 'trace_share.py'))
+    # busy 0-70 and 90-100 = 80; the fusions cover 0-40 and 50-70 = 60
+    assert share.read({'match': ['^fusion']}, src) == pytest.approx(75.0)
+    assert share.read({'match': ['^fusion']}, {'trace': None}) is None
+    # the committed patterns meet the names a chip's trace prints
+    real = [('%copy.115 = f32[64,24,16,32,64]{4,3,2,1,0} copy(f32[64,24,16,'
+             '32,64]{4,2,3,1,0} %bitcast.161)', 0, 30),
+            ('%fusion.148 = f32[786432,64]{1,0} fusion(f32[1536,16,32,64]'
+             '{3,2,1,0} %copy.1)', 30, 10),
+            ('%slice-done.2 = f32[128,1024]{1,0} slice-done(%slice-start.2)',
+             50, 10)]
+    metrics = os.path.join(REPO, 'benchmark', 'layer_metrics')
+    spec = manifest.read_json(os.path.join(
+        metrics, 'serve.copy_busy_share.json'))
+    assert share.read(spec['args'], {'trace': {
+        'window': (0, 100), 'first': real}}) == pytest.approx(60.0)
+    spec = manifest.read_json(os.path.join(metrics, 'train.copy_ms.json'))
+    ops = manifest.load_module(os.path.join(d, 'trace_ops.py'))
+    assert ops.read(spec['args'], {'trace': {
+        'window': (0, 100), 'first': real}, 'trace_steps': 1}) == \
+        pytest.approx(40 / 1e6)
+    assert ops.read({'match': rx, 'mode': 'total'}, src) == \
+        pytest.approx(40 / 1e6 / 2)
+    assert ops.read({'match': rx, 'mode': 'exposed'}, src) == \
+        pytest.approx(20 / 1e6 / 2)
+
+
+def test_top_ops_and_idle_gaps_are_named():
+    nested = EVENTS + [('while.5', 0, 70)]     # covers its body
+    assert tracelib.top_ops(nested, 0, 100, 2) == \
+        [['fusion.1', 40e-9], ['all-reduce.3', 30e-9]]
+    host = [('bench.wait_oldest', 68, 25), ('other', 0, 100)]
+    gaps = tracelib.idle_gaps(EVENTS, host, 0, 120,
+                              ('bench.wait_oldest',), 5)
+    assert gaps == [['bench.wait_oldest', 20e-9],
+                    ['unattributed', 20e-9]]
+
+
+def test_reads_a_trace_recorded_here(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation(bench.WINDOW_SPAN):
+        with jax.profiler.TraceAnnotation('bench.dispatch'):
+            jnp.ones((64, 64)).sum().block_until_ready()
+        time.sleep(0.01)
+    jax.profiler.stop_trace()
+    trace = tracelib.read_xplane(tracelib.find_xplane(str(tmp_path)))
+    assert trace['devices'] == {}               # a CPU has no device plane
+    assert tracelib.HOST_PLANE in trace['lines']
+    lo, hi = tracelib.window_of(trace, bench.WINDOW_SPAN)
+    assert hi - lo >= 10e6                      # the 10 ms nap, in ns
+    inner = [ev for ev in trace['host'] if ev[0] == 'bench.dispatch']
+    assert len(inner) == 1 and lo <= inner[0][1] < hi
+    assert tracelib.find_xplane(str(tmp_path / 'nothing')) is None
+
+
+# ---------------------------------------------------------- statistics
+def test_percentile_is_nearest_rank():
+    values = list(range(1, 101))
+    assert stats.percentile(values, 95) == 95
+    assert stats.percentile(values, 50) == 50
+    assert stats.percentile([3.0, 1.0, 2.0], 95) == 3.0
+    assert stats.percentile([7.0], 50) == 7.0
+    assert stats.percentile([], 95) is None
+    assert stats.spread([98, 99, 100, 101, 102, 103]) == \
+        pytest.approx(3.5 / 100.5)
+
+
+def test_registry_readers_take_deltas_not_totals():
+    before = {'counters': {'executor.cache_miss_total{kind=single}': 9},
+              'histograms': {'decode.step_seconds':
+                             {'sum': 1.0, 'count': 10}}}
+    after = {'counters': {'executor.cache_miss_total{kind=single}': 9,
+                          'executor.cache_miss_total{kind=multi}': 2,
+                          'executor.cache_miss_total_x': 50},
+             'histograms': {'decode.step_seconds':
+                            {'sum': 1.6, 'count': 30},
+                            'decode.step_seconds_x': {'sum': 9, 'count': 9}}}
+    src = {'registry_before': before, 'registry_after': after}
+    d = os.path.join(REPO, 'benchmark', 'readers')
+    delta = manifest.load_module(os.path.join(d, 'registry_delta.py'))
+    mean = manifest.load_module(os.path.join(d, 'registry_mean.py'))
+    assert delta.read({'counter': 'executor.cache_miss_total'}, src) == 2
+    assert mean.read({'histogram': 'decode.step_seconds', 'scale': 1000},
+                     src) == pytest.approx(30.0)
+    assert mean.read({'histogram': 'decode.none'}, src) is None
+    off = {'registry_before': None, 'registry_after': None}
+    assert delta.read({'counter': 'x'}, off) is None
+
+
+def test_shape_functions_count_what_the_shapes_need():
+    d = os.path.join(REPO, 'benchmark', 'shape_fns')
+    flops = manifest.load_module(
+        os.path.join(d, 'transformer_train_flops.py'))
+    nmt = manifest.resolve(MANIFEST, CELLS[0])['config']['model']
+    per_token = flops.step_flops(
+        1, 128, 128, nmt['vocab_size'], nmt['n_layer'], nmt['n_head'],
+        nmt['d_key'], nmt['d_model'], nmt['d_inner']) / 128
+    # 6 x the matmul parameters a target token meets (encoder counted
+    # per source token, 1:1) + attention: 1.28 GFLOP
+    assert per_token == pytest.approx(1.2819e9, rel=1e-3)
+    peaks = manifest.read_json(os.path.join(REPO, 'benchmark',
+                                            'peaks.json'))['devices']
+    assert peaks['TPU v5 lite']['flops_bf16'] == 197e12
+    src = {'measured': {'train_tokens_per_s': 76840.0}, 'cell':
+           {'chips': 1}, 'config': {'model': nmt},
+           'traffic': {'seq_len': 128}, 'peaks': peaks['TPU v5 lite'],
+           'bench_dir': os.path.join(REPO, 'benchmark')}
+    reader = manifest.load_module(os.path.join(
+        REPO, 'benchmark', 'readers', 'shape_fn.py'))
+    assert reader.read({'function': 'transformer_train_flops',
+                        'peak': 'flops_bf16'}, src) == \
+        pytest.approx(50.0, rel=1e-3)
+    assert reader.read({'function': 'transformer_train_flops',
+                        'peak': 'flops_bf16'},
+                       dict(src, peaks=None)) is None
+    live = manifest.load_module(os.path.join(d, 'decode_live_bytes.py'))
+    lm = manifest.read_json(os.path.join(
+        REPO, 'benchmark', 'configs', 'tbig_nmt.json'))['model']
+    assert live.kv_bytes_per_token(lm) == 49152
+    assert live.weight_bytes(lm) == 4 * (6 * (4 * 1024 * 1024
+                                              + 2 * 1024 * 4096)
+                                         + 2 * 32000 * 1024)
+
+
+# ------------------------------------------------------ load generator
+TRAFFIC = {'rate_rps': 20.0, 'prompt_len': [16, 512], 'preroll_s': 2,
+           'answer_len': [16, 256], 'alpha': 1.3, 'pool_seed': 24}
+
+
+def test_schedule_is_a_pure_function_of_the_seed():
+    a = loadgen.schedule(TRAFFIC, 3000000007, 8.0)
+    assert a == loadgen.schedule(TRAFFIC, 3000000007, 8.0)
+    b = loadgen.schedule(TRAFFIC, 5, 8.0)
+    assert a != b and len(a) == len(b) == 200
+    # every seed sends the same lengths at the same instants; only the
+    # prompts' tokens differ. 2 s of pre-roll, then the 8 s window
+    assert [r[:4] for r in a] == [r[:4] for r in b]
+    assert [r.token_seed for r in a] != [r.token_seed for r in b]
+    assert sum(1 for r in a if r.due < 2.0) == 40
+    assert a[0].due == 0.0 and a[40].due == 2.0 and all(
+        x.due <= y.due for x, y in zip(a, a[1:])) and a[-1].due < 10.0
+    assert [r.index for r in a] == list(range(200))
+    assert all(16 <= r.prompt_len <= 512 and 16 <= r.answer_len <= 256
+               for r in a)
+    assert loadgen.prompt_tokens(a[3], 32000) == \
+        loadgen.prompt_tokens(a[3], 32000)
+    assert len(loadgen.prompt_tokens(a[3], 32000)) == a[3].prompt_len
+
+
+class FakeStream(object):
+    """Two tokens, 20 ms apart, the first 30 ms after submit."""
+
+    def __init__(self):
+        self.born = time.perf_counter()
+        self.given = 0
+
+
+def fake_poll(stream):
+    age = time.perf_counter() - stream.born
+    ready = (age >= 0.03) + (age >= 0.05)
+    tokens = [11, 12][stream.given:ready]
+    stream.given = ready
+    return tokens, ready == 2, None
+
+
+def test_latency_is_clocked_from_due_and_lateness_is_reported():
+    requests = [loadgen.Request(0, 0.00, 4, 2, 1),
+                loadgen.Request(1, 0.01, 4, 2, 2),
+                loadgen.Request(2, 0.02, 4, 2, 3)]
+
+    def submit(request):
+        if request.index == 0:
+            time.sleep(0.05)            # a stall in the system's intake
+        if request.index == 2:
+            raise RuntimeError('queue full')
+        return FakeStream()
+
+    t0 = time.perf_counter()
+    client = loadgen.drive(submit, fake_poll, requests, t0)
+    assert client.live                  # one thread: nothing read yet
+    assert client.finish(time.perf_counter() + 5) == 0
+    first, second, third = client.records
+    assert first.complete and second.complete
+    assert first.tokens == second.tokens == [11, 12]
+    # the second request was due at 10 ms but sent after the 50 ms stall:
+    # the lateness is reported, and its latency counts from when it was
+    # due, so it includes the wait the stall imposed
+    late = second.sent_at - second.due_at
+    assert late >= 0.035
+    assert second.ttft >= late + 0.03 - 1e-3
+    assert second.ttft == second.token_at[0] - (t0 + 0.01)
+    # tokens are stamped when polled, every 2 ms
+    assert 0.02 - 3e-3 <= second.gaps[0] <= 0.02 + 10e-3
+    assert third.refused and not third.complete and third.ttft is None
+
+
+# ------------------------------------------------------ the command
+def _run(*args, **env):
+    return subprocess.run(
+        [sys.executable, RUN] + list(args), cwd=REPO, timeout=900,
+        capture_output=True, text=True,
+        env=dict(os.environ, JAX_PLATFORMS='cpu', **env))
+
+
+def test_without_a_tpu_and_without_rehearsal_nothing_runs(capsys):
+    with pytest.raises(SystemExit) as stop:
+        bench.main(['--workload', CELLS[0], '--seed', '1', '--seconds', '1',
+                    '--trace', '0'])
+    assert stop.value.code == 2
+    said = capsys.readouterr()
+    assert "found platform 'cpu'" in said.err
+    assert '"metrics"' not in said.out and 'WINDOW' not in said.out
+
+
+def test_rehearsal_prints_the_contract_line_and_no_cpu_time(tmp_path):
+    r = _run('--workload', CELLS[0], '--seed', '3000000001', '--seconds',
+             '2', '--trace', '1', '--rehearsal', BENCH_RUN='7')
+    assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
+    lines = r.stdout.splitlines()
+    assert lines[0] == 'REHEARSAL platform=cpu'
+    last = json.loads(lines[-1])
+    assert {'correct', 'attempted', 'failed', 'metrics', 'device'} <= \
+        set(last)
+    assert last['rehearsal'] is True and last['correct'] is True
+    window = json.loads([ln for ln in lines
+                         if ln.startswith('WINDOW ')][-1][7:])
+    # the inference clone agrees with the plain reference (bf16 amp)
+    assert window['reference_agrees'] is True
+    assert 0 < window['reference_logits_rel_rms'] < 0.02
+    assert last['device']['platform'] == 'cpu'
+    assert last['device']['memory_peak_bytes'] is None
+    assert last['metrics']['train.recompiles'] == \
+        {'value': 0, 'unit': 'count'}
+    assert last['metrics']['train.dispatch_ms']['value'] is None
+    assert 'train.mfu' not in last['metrics']   # no peak for a CPU
+    assert not os.path.exists(os.path.join(REPO, '.bench_trace'))
+
+
+def test_serve_cell_rehearses_in_process(capsys):
+    cell = 'tbig_lm.chat_steady'
+    if cell not in CELLS:
+        pytest.skip('the manifest has no %s' % cell)
+    assert bench.main(['--workload', cell, '--seed', '4', '--seconds',
+                       '2', '--trace', '0', '--rehearsal']) == 0
+    last = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert last['attempted'] > 10 and last['failed'] == 0
+    assert last['correct'] is True      # also: served tokens = reference's
+    assert set(last['metrics']) == {
+        e['name'] for e in MANIFEST['end_to_end']
+        if manifest.applies(e, cell)} > {'setup_s'}
+    assert all(m['value'] is None for m in last['metrics'].values())
+
+
+# ------------------------------------------------------- the references
+def _reference(config):
+    return manifest.load_module(os.path.join(
+        REPO, 'benchmark', 'references', config + '.py'))
+
+
+def test_lm_reference_holds_served_tokens_and_finds_a_dropped_sublayer():
+    import jax
+    from paddle_tpu.serving.decode import DecodeEngine, LMSpec
+    ref = _reference('tbig_lm')
+    engine = DecodeEngine(LMSpec(64, 2, 2, 8, 8, 16, 32), max_batch=2,
+                          block_size=8, pages_per_seq=4, num_blocks=8,
+                          max_prompt_len=16, prefix_cache=False)
+    try:
+        engine.warmup()
+        engine.start()
+        prompt = [5, 9, 33, 2, 17, 40, 8]
+        answer = engine.generate(prompt, max_new_tokens=12, timeout=120)
+        weights = jax.device_put(engine.export_weights())
+    finally:
+        engine.shutdown(drain=False)
+    gaps, deviation = ref.token_gaps(weights, 2, prompt, answer, 32)
+    assert len(gaps) == 12 and max(gaps) < 1e-4 and deviation > 0.1
+    # the same tokens against a model without its attention sublayers
+    broken = dict(weights)
+    broken['lm_stack_slf_o.w'] = 0 * weights['lm_stack_slf_o.w']
+    gaps, _ = ref.token_gaps(broken, 2, prompt, answer, 32)
+    assert max(gaps) > 0.05
+
+
+def test_nmt_reference_tolerance_tells_a_dropped_sublayer():
+    import jax
+    import numpy as np
+    ref = _reference('tbig_nmt')
+    tol = manifest.resolve(MANIFEST, 'tbig_nmt.train_seq128')[
+        'config']['reference']['logits_rel_rms_tol']
+    rng = np.random.RandomState(0)
+    d, inner, vocab = 16, 32, 64
+
+    def mat(*shape):
+        return (rng.randn(*shape) / np.sqrt(shape[0])).astype('float32')
+    w = {'out_proj.w': mat(d, vocab)}
+    for side, subs in (('enc', ('slf',)), ('dec', ('slf', 'cross'))):
+        w[side[:3].replace('enc', 'src').replace('dec', 'trg') + '_emb'] \
+            = mat(vocab, d)
+        for sub in subs:
+            for m in 'qkv':
+                w['%s_0_%s_%s.w' % (side, sub, m)] = mat(d, d)
+            w['%s_0_%s_out.w' % (side, sub)] = mat(d, d)
+        for i in range(len(subs) + 1):
+            w['%s_0_pp%d_ln.w' % (side, i + 1)] = np.ones(d, 'float32')
+            w['%s_0_pp%d_ln.b' % (side, i + 1)] = np.zeros(d, 'float32')
+        w['%s_0_ffn_1.w' % side], w['%s_0_ffn_1.b' % side] = \
+            mat(d, inner), np.zeros(inner, 'float32')
+        w['%s_0_ffn_2.w' % side], w['%s_0_ffn_2.b' % side] = \
+            mat(inner, d), np.zeros(d, 'float32')
+    for name in ('src_emb', 'trg_emb'):
+        w[name + '_pos_enc'] = mat(8, d)
+    batch = {'src_word': rng.randint(1, vocab, (2, 8)),
+             'src_length': np.array([8, 5]),
+             'trg_word': rng.randint(1, vocab, (2, 8)),
+             'lbl_word': rng.randint(1, vocab, (2, 8)),
+             'lbl_weight': np.ones((2, 8), 'float32')}
+    forward = jax.jit(ref.forward, static_argnums=(2, 3, 4, 5))
+    logits, loss = forward(w, batch, 1, 2, 0.3, 0.1)
+    assert logits.shape == (2, 8, vocab) and np.isfinite(float(loss))
+    # causal: a later target token does not move an earlier position
+    later = dict(batch, trg_word=batch['trg_word'].copy())
+    later['trg_word'][:, -1] = 1
+    moved = np.asarray(forward(w, later, 1, 2, 0.3, 0.1)[0] - logits)
+    assert np.abs(moved[:, :-1]).max() == 0 and np.abs(moved[:, -1]).max() > 0
+    # keys past src_length are masked
+    padded = dict(batch, src_word=batch['src_word'].copy())
+    padded['src_word'][1, 5:] = 7
+    assert np.abs(np.asarray(
+        forward(w, padded, 1, 2, 0.3, 0.1)[0] - logits)).max() == 0
+    # dropping one sublayer is far outside the tolerance
+    broken = dict(w, **{'dec_0_cross_out.w': 0 * w['dec_0_cross_out.w']})
+    off = np.asarray(forward(broken, batch, 1, 2, 0.3, 0.1)[0] - logits)
+    assert np.sqrt((off ** 2).mean()) / np.asarray(logits).std() > 5 * tol
+
+
+def test_memory_is_arrays_plus_reserved_at_one_instant(capsys):
+    got = bench.memory_fields([
+        {'bytes_in_use': 10, 'bytes_reserved': 5, 'peak_bytes_in_use': 11,
+         'peak_bytes_reserved': 5, 'bytes_limit': 100},
+        {'bytes_in_use': 12, 'bytes_reserved': 7, 'peak_bytes_in_use': 20,
+         'peak_bytes_reserved': 8, 'bytes_limit': 100}])
+    assert got == {'memory_peak_bytes': 19, 'memory_arrays_peak_bytes': 20,
+                   'memory_reserved_peak_bytes': 8,
+                   'memory_limit_bytes': 100}
+    assert bench.memory_fields([{}]) == {'memory_peak_bytes': None}
+    assert capsys.readouterr().out.count('MEMORY') == 3
