@@ -452,8 +452,6 @@ class Executor(object):
                     if compiled.flops:
                         _obs.set_gauge('executor.step_flops',
                                        compiled.flops)
-                        _obs.set_gauge('executor.step_flops_by_key',
-                                       compiled.flops, key=kid)
         except Exception as e:
             _obs.flight_event('aot_save_failed', kind=kind, key=kid,
                               error='%s: %s' % (type(e).__name__, e))
@@ -470,8 +468,14 @@ class Executor(object):
     def run(self, program=None, feed=None, fetch_list=None, scope=None,
             return_numpy=True, use_program_cache=True,
             return_handle=False):
-        import jax
+        with _obs.span('executor.run', record='executor.run_seconds',
+                       kind='single') as run_span:
+            return self._run(run_span, program, feed, fetch_list, scope,
+                             return_numpy, use_program_cache,
+                             return_handle)
 
+    def _run(self, run_span, program, feed, fetch_list, scope,
+             return_numpy, use_program_cache, return_handle):
         _ensure_ops_imported()
         program = program if program is not None else default_main_program()
         feed = feed or {}
@@ -479,41 +483,42 @@ class Executor(object):
         scope = scope if scope is not None else global_scope()
         block = program.global_block()
 
-        fetch_names = [f.name if isinstance(f, Variable) else f
-                       for f in fetch_list]
+        with _obs.span('executor.lookup', record='executor.lookup_seconds'):
+            fetch_names = [f.name if isinstance(f, Variable) else f
+                           for f in fetch_list]
 
-        feed_vals = self._normalize_feed(block, feed)
-        feed_sig = tuple(sorted((n, v.shape, str(v.dtype))
-                                for n, v in feed_vals.items()))
-        # read per call and folded into the cache key: flipping the
-        # PADDLE_TPU_QUANT_ALLREDUCE knob mid-process recompiles
-        # instead of silently reusing the other mode's executable
-        from ..parallel.collective import grad_bucket_policy
-        from ..quant.core import grad_allreduce_policy
-        qpolicy = grad_allreduce_policy(program)
-        bpolicy = grad_bucket_policy(program)
-        key = (id(program), program._version, program.amp,
-               program.remat_policy, qpolicy, bpolicy, feed_sig,
-               tuple(fetch_names))
-        self._maybe_verify('single', key, program, feed_vals,
-                           fetch_names)
-        self.last_warm_from_disk = False
-        compiled, missed = self._lookup_or_compile(
-            'single', key, use_program_cache,
-            lambda: self._compile(program, sorted(feed_vals),
-                                  fetch_names, quant_allreduce=qpolicy,
-                                  grad_bucket=bpolicy),
-            program=program,
-            aot_parts=('single', program.amp, program.remat_policy,
-                       qpolicy, bpolicy, feed_sig, tuple(fetch_names)))
-        self.last_cache_miss = missed
-        if not missed and _obs.enabled():
-            _obs.inc('executor.cache_hit_total', kind='single',
-                     key=_obs.key_id(key))
+            feed_vals = self._normalize_feed(block, feed)
+            feed_sig = tuple(sorted((n, v.shape, str(v.dtype))
+                                    for n, v in feed_vals.items()))
+            # read per call and folded into the cache key: flipping the
+            # PADDLE_TPU_QUANT_ALLREDUCE knob mid-process recompiles
+            # instead of silently reusing the other mode's executable
+            from ..parallel.collective import grad_bucket_policy
+            from ..quant.core import grad_allreduce_policy
+            qpolicy = grad_allreduce_policy(program)
+            bpolicy = grad_bucket_policy(program)
+            key = (id(program), program._version, program.amp,
+                   program.remat_policy, qpolicy, bpolicy, feed_sig,
+                   tuple(fetch_names))
+            self._maybe_verify('single', key, program, feed_vals,
+                               fetch_names)
+            self.last_warm_from_disk = False
+            compiled, missed = self._lookup_or_compile(
+                'single', key, use_program_cache,
+                lambda: self._compile(program, sorted(feed_vals),
+                                      fetch_names, quant_allreduce=qpolicy,
+                                      grad_bucket=bpolicy),
+                program=program,
+                aot_parts=('single', program.amp, program.remat_policy,
+                           qpolicy, bpolicy, feed_sig, tuple(fetch_names)))
+            self.last_cache_miss = missed
+            kid = self._account_lookup('single', key, missed, run_span)
 
         with self._dispatch_lock:
-            scope_vals, feed_vals = self._prepare_inputs(
-                'Executor.run', program, compiled, scope, feed_vals)
+            with _obs.span('executor.prepare',
+                           record='executor.prepare_seconds'):
+                scope_vals, feed_vals = self._prepare_inputs(
+                    'Executor.run', program, compiled, scope, feed_vals)
             if compiled.aot_state == 'save':
                 self._aot_save('single', key, compiled, scope_vals,
                                feed_vals)
@@ -521,29 +526,14 @@ class Executor(object):
                 self._cost_account(compiled, key, scope_vals, feed_vals)
 
             step_i = self._next_steps(1)
-            if _obs.enabled() and self.last_cache_miss:
-                # first dispatch of this key = XLA compile + one step; a
-                # near-free compile-time signal even when the AOT cost
-                # probe is off (PADDLE_TPU_OBSERVE_COST=0)
-                t0 = time.perf_counter()
-                fetches, new_scope = compiled.fn(scope_vals, feed_vals,
-                                                 step_i)
-                _obs.record('executor.first_dispatch_seconds',
-                            time.perf_counter() - t0, kind='single',
-                            key=_obs.key_id(key))
-            else:
-                fetches, new_scope = compiled.fn(scope_vals, feed_vals,
-                                                 step_i)
+            fetches, new_scope = self._enqueue(
+                'single', kid, missed, compiled, scope_vals, feed_vals,
+                step_i)
 
             for name, value in new_scope.items():
                 scope.set(name, value)
 
-        if return_handle:
-            return StepHandle(list(fetches), steps=1,
-                              cache_miss=self.last_cache_miss)
-        if return_numpy:
-            return [np.asarray(v) for v in fetches]
-        return list(fetches)
+        return self._hand_back(fetches, 1, return_numpy, return_handle)
 
     # ---------------------------------------------------------- multi-step
     def run_steps(self, steps, program=None, feed=None, fetch_list=None,
@@ -568,96 +558,64 @@ class Executor(object):
         Reference analog: the trainer's inner batch loop
         (python/paddle/v2/trainer.py:1 train loop); TPU-first, the loop
         itself compiles into the program."""
-        import jax
-        import jax.numpy as jnp
+        with _obs.span('executor.run', record='executor.run_seconds',
+                       kind='multi') as run_span:
+            return self._run_steps(run_span, steps, program, feed,
+                                   fetch_list, scope, return_numpy,
+                                   stacked_feed, return_handle)
 
+    def _run_steps(self, run_span, steps, program, feed, fetch_list, scope,
+                   return_numpy, stacked_feed, return_handle):
         _ensure_ops_imported()
         program = program if program is not None else default_main_program()
         fetch_list = fetch_list or []
         scope = scope if scope is not None else global_scope()
         block = program.global_block()
-        fetch_names = [f.name if isinstance(f, Variable) else f
-                       for f in fetch_list]
+        with _obs.span('executor.lookup', record='executor.lookup_seconds'):
+            fetch_names = [f.name if isinstance(f, Variable) else f
+                           for f in fetch_list]
 
-        feed_vals = self._normalize_feed(block, feed or {})
-        if stacked_feed:
-            for name, arr in feed_vals.items():
-                if arr.shape[0] != steps:
-                    raise ValueError(
-                        'run_steps(stacked_feed=True): feed %r leading '
-                        'dim %d != steps %d' % (name, arr.shape[0], steps))
+            feed_vals = self._normalize_feed(block, feed or {})
+            if stacked_feed:
+                for name, arr in feed_vals.items():
+                    if arr.shape[0] != steps:
+                        raise ValueError(
+                            'run_steps(stacked_feed=True): feed %r '
+                            'leading dim %d != steps %d'
+                            % (name, arr.shape[0], steps))
 
-        sig_shape = {n: (v.shape[1:] if stacked_feed else v.shape)
-                     for n, v in feed_vals.items()}
-        feed_sig = tuple(sorted((n, sig_shape[n], str(v.dtype))
-                                for n, v in feed_vals.items()))
-        from ..parallel.collective import grad_bucket_policy
-        from ..quant.core import grad_allreduce_policy
-        qpolicy = grad_allreduce_policy(program)
-        bpolicy = grad_bucket_policy(program)
-        key = ('multi', id(program), program._version, program.amp,
-               program.remat_policy, qpolicy, bpolicy, feed_sig,
-               tuple(fetch_names), steps, stacked_feed)
-        self._maybe_verify('multi', key, program, feed_vals, fetch_names)
-
-        def _build_multi():
-            base = self._compile(program, sorted(feed_vals), fetch_names,
-                                 quant_allreduce=qpolicy,
-                                 grad_bucket=bpolicy)
-
-            # state that is read each step chains through the scan carry;
-            # written-only persistables (no reader) are ALSO carried —
-            # seeded with zeros of their traced shape and overwritten
-            # every step — so only their final value occupies memory
-            # (stacking them in the ys would cost steps x size).
-            written_only = [n for n in base.scope_out_names
-                            if n not in set(base.scope_in_names)]
-
-            def multi_fn(scope_vals, feeds, step0):
-                f0 = {n: v[0] for n, v in feeds.items()} \
-                    if stacked_feed else feeds
-                _, ns_shapes = jax.eval_shape(base.raw_fn, scope_vals,
-                                              f0, step0)
-                wo0 = {n: jnp.zeros(ns_shapes[n].shape,
-                                    ns_shapes[n].dtype)
-                       for n in written_only if n in ns_shapes}
-
-                def body(carry, t):
-                    sc, wo = carry
-                    f = {n: v[t] for n, v in feeds.items()} \
-                        if stacked_feed else feeds
-                    fetches, new_scope = base.raw_fn(sc, f, step0 + t)
-                    return ({n: new_scope[n] for n in sc},
-                            {n: new_scope[n] for n in wo}), fetches
-
-                (final_sc, final_wo), stacked = jax.lax.scan(
-                    body, (scope_vals, wo0),
-                    jnp.arange(steps, dtype=jnp.int32))
-                final_scope = dict(final_sc)
-                final_scope.update(final_wo)
-                return stacked, final_scope
-
-            jit_multi = jax.jit(multi_fn, donate_argnums=(0,))
-            return _Compiled(jit_multi, base.raw_fn,
-                             base.scope_in_names, base.scope_out_names,
-                             base.feed_names, base.fetch_names)
-
-        self.last_warm_from_disk = False
-        compiled, missed = self._lookup_or_compile(
-            'multi', key, True, _build_multi,
-            program=program,
-            aot_parts=('multi', program.amp, program.remat_policy,
-                       qpolicy, bpolicy, feed_sig, tuple(fetch_names),
-                       steps, stacked_feed))
-        self.last_cache_miss = missed
-        if not missed and _obs.enabled():
-            _obs.inc('executor.cache_hit_total', kind='multi',
-                     key=_obs.key_id(key))
+            sig_shape = {n: (v.shape[1:] if stacked_feed else v.shape)
+                         for n, v in feed_vals.items()}
+            feed_sig = tuple(sorted((n, sig_shape[n], str(v.dtype))
+                                    for n, v in feed_vals.items()))
+            from ..parallel.collective import grad_bucket_policy
+            from ..quant.core import grad_allreduce_policy
+            qpolicy = grad_allreduce_policy(program)
+            bpolicy = grad_bucket_policy(program)
+            key = ('multi', id(program), program._version, program.amp,
+                   program.remat_policy, qpolicy, bpolicy, feed_sig,
+                   tuple(fetch_names), steps, stacked_feed)
+            self._maybe_verify('multi', key, program, feed_vals,
+                               fetch_names)
+            self.last_warm_from_disk = False
+            compiled, missed = self._lookup_or_compile(
+                'multi', key, True,
+                lambda: self._compile_multi(
+                    program, sorted(feed_vals), fetch_names, qpolicy,
+                    bpolicy, steps, stacked_feed),
+                program=program,
+                aot_parts=('multi', program.amp, program.remat_policy,
+                           qpolicy, bpolicy, feed_sig, tuple(fetch_names),
+                           steps, stacked_feed))
+            self.last_cache_miss = missed
+            kid = self._account_lookup('multi', key, missed, run_span)
 
         with self._dispatch_lock:
-            scope_vals, feed_vals = self._prepare_inputs(
-                'Executor.run_steps', program, compiled, scope, feed_vals,
-                feed_stack_axis=stacked_feed)
+            with _obs.span('executor.prepare',
+                           record='executor.prepare_seconds'):
+                scope_vals, feed_vals = self._prepare_inputs(
+                    'Executor.run_steps', program, compiled, scope,
+                    feed_vals, feed_stack_axis=stacked_feed)
             if compiled.aot_state == 'save':
                 self._aot_save('multi', key, compiled, scope_vals,
                                feed_vals)
@@ -666,26 +624,99 @@ class Executor(object):
                     if stacked_feed else feed_vals
                 self._cost_account(compiled, key, scope_vals, one_feed)
             step0 = self._next_steps(steps)
-            if _obs.enabled() and self.last_cache_miss:
-                t0 = time.perf_counter()
-                fetches, new_scope = compiled.fn(scope_vals, feed_vals,
-                                                 step0)
-                _obs.record('executor.first_dispatch_seconds',
-                            time.perf_counter() - t0, kind='multi',
-                            key=_obs.key_id(key))
-            else:
-                fetches, new_scope = compiled.fn(scope_vals, feed_vals,
-                                                 step0)
+            fetches, new_scope = self._enqueue(
+                'multi', kid, missed, compiled, scope_vals, feed_vals,
+                step0)
             for name, value in new_scope.items():
                 scope.set(name, value)
+        return self._hand_back(fetches, steps, return_numpy, return_handle)
+
+    def _compile_multi(self, program, feed_names, fetch_names, qpolicy,
+                       bpolicy, steps, stacked_feed):
+        """The single-step function of ``_compile`` wrapped in a
+        lax.scan over ``steps``."""
+        import jax
+        import jax.numpy as jnp
+        base = self._compile(program, feed_names, fetch_names,
+                             quant_allreduce=qpolicy,
+                             grad_bucket=bpolicy)
+
+        # state that is read each step chains through the scan carry;
+        # written-only persistables (no reader) are ALSO carried —
+        # seeded with zeros of their traced shape and overwritten
+        # every step — so only their final value occupies memory
+        # (stacking them in the ys would cost steps x size).
+        written_only = [n for n in base.scope_out_names
+                        if n not in set(base.scope_in_names)]
+
+        def multi_fn(scope_vals, feeds, step0):
+            f0 = {n: v[0] for n, v in feeds.items()} \
+                if stacked_feed else feeds
+            _, ns_shapes = jax.eval_shape(base.raw_fn, scope_vals,
+                                          f0, step0)
+            wo0 = {n: jnp.zeros(ns_shapes[n].shape,
+                                ns_shapes[n].dtype)
+                   for n in written_only if n in ns_shapes}
+
+            def body(carry, t):
+                sc, wo = carry
+                f = {n: v[t] for n, v in feeds.items()} \
+                    if stacked_feed else feeds
+                fetches, new_scope = base.raw_fn(sc, f, step0 + t)
+                return ({n: new_scope[n] for n in sc},
+                        {n: new_scope[n] for n in wo}), fetches
+
+            (final_sc, final_wo), stacked = jax.lax.scan(
+                body, (scope_vals, wo0),
+                jnp.arange(steps, dtype=jnp.int32))
+            final_scope = dict(final_sc)
+            final_scope.update(final_wo)
+            return stacked, final_scope
+
+        multi_fn.__name__ = multi_fn.__qualname__ = '%s_x%d' % (
+            base.raw_fn.__name__, steps)
+        jit_multi = jax.jit(multi_fn, donate_argnums=(0,))
+        return _Compiled(jit_multi, base.raw_fn,
+                         base.scope_in_names, base.scope_out_names,
+                         base.feed_names, base.fetch_names)
+
+    # -------------------------------------------------------------- helpers
+    def _account_lookup(self, kind, key, missed, run_span):
+        """With observe on: count a cache hit, put the key's id on the
+        call's ``executor.run`` span, and return the id."""
+        if not _obs.enabled():
+            return None
+        kid = _obs.key_id(key)
+        if not missed:
+            _obs.inc('executor.cache_hit_total', kind=kind, key=kid)
+        if run_span is not None:
+            run_span.attrs['key'] = kid
+        return kid
+
+    def _enqueue(self, kind, kid, missed, compiled, scope_vals, feed_vals,
+                 step_i):
+        """Hand the step to the device. The first dispatch of a key is
+        the XLA compile plus one step: a near-free compile-time signal
+        even when the AOT cost probe is off (PADDLE_TPU_OBSERVE_COST=0),
+        so it is recorded apart from the enqueues of a warm key."""
+        if missed:
+            record, labels = ('executor.first_dispatch_seconds',
+                              {'kind': kind, 'key': kid})
+        else:
+            record, labels = 'executor.enqueue_seconds', None
+        with _obs.span('executor.enqueue', record=record, labels=labels):
+            return compiled.fn(scope_vals, feed_vals, step_i)
+
+    def _hand_back(self, fetches, steps, return_numpy, return_handle):
         if return_handle:
             return StepHandle(list(fetches), steps=steps,
                               cache_miss=self.last_cache_miss)
         if return_numpy:
-            return [np.asarray(v) for v in fetches]
+            with _obs.span('executor.fetch',
+                           record='executor.fetch_seconds'):
+                return [np.asarray(v) for v in fetches]
         return list(fetches)
 
-    # -------------------------------------------------------------- helpers
     def _observed_compile(self, kind, key, compile_fn):
         """Trace/prune/compile with telemetry: cache-miss counter, a
         span, and per-key trace seconds. The XLA compile itself happens
@@ -730,8 +761,6 @@ class Executor(object):
             compiled.flops = 0.0   # tried; never retry per key
         if compiled.flops:
             _obs.set_gauge('executor.step_flops', compiled.flops)
-            _obs.set_gauge('executor.step_flops_by_key', compiled.flops,
-                           key=kid)
 
     def _normalize_feed(self, block, feed):
         """Normalize feed values to arrays with the declared
@@ -803,7 +832,6 @@ class Executor(object):
         ops = _prune_ops(block, all_ops, fetch_names, reads_cache)
         if _obs.enabled():
             _obs.inc('executor.ops_pruned_total', len(all_ops) - len(ops))
-            _obs.inc('executor.ops_lowered_total', len(ops))
 
         # Data vars actually consumed must be fed.
         consumed = set()
@@ -929,7 +957,11 @@ class Executor(object):
                                                                 False)),
                                       amp=amp)
                 try:
-                    get_lowering(op.type)(ctx)
+                    # every HLO instruction's op_name carries the Fluid
+                    # op it came from, forward and (through jax's
+                    # transpose(jvp(...)) wrapping) backward
+                    with _jax.named_scope(op.type):
+                        get_lowering(op.type)(ctx)
                 except KeyError as e:
                     raise RuntimeError(
                         'While lowering op %r: missing input %s. '
@@ -1122,6 +1154,10 @@ class Executor(object):
             new_scope = {n: env[n] for n in scope_out_all if n in env}
             return fetches, new_scope
 
+        # the XLA module is jit_<name>: a trace's module line and a load
+        # error say which program it is
+        step_fn.__name__ = step_fn.__qualname__ = program.name or (
+            'train_step' if marker_idx is not None else 'infer_step')
         jit_fn = jax.jit(step_fn, donate_argnums=(0,))
         return _Compiled(jit_fn, step_fn, scope_in, scope_out_all,
                          needed_feeds, fetch_names)

@@ -280,6 +280,11 @@ class Program(object):
         self.current_block_idx = 0
         self._version = 0
         self._seed = None
+        # What the compiled XLA module is called (``jit_<name>`` in a
+        # trace's module line and in a load error). None: the executor
+        # says ``train_step`` or ``infer_step``. Read when a signature
+        # compiles; not part of any cache key.
+        self.name = None
         # The startup Program that holds this program's param-init ops
         # (recorded by LayerHelper.create_parameter; used by
         # optimizer.minimize when no startup_program is passed).

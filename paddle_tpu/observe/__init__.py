@@ -263,28 +263,37 @@ _NULL = _NullCtx()
 
 
 class _SpanCtx(object):
-    __slots__ = ('name', 'attrs', '_sp')
+    __slots__ = ('name', 'attrs', 'hist', 'labels', '_sp')
 
-    def __init__(self, name, attrs):
+    def __init__(self, name, attrs, hist, labels):
         self.name = name
         self.attrs = attrs
+        self.hist = hist
+        self.labels = labels
 
     def __enter__(self):
         self._sp = _SPANS.begin(self.name, self.attrs or None)
         return self._sp
 
     def __exit__(self, *exc):
-        _SPANS.end(self._sp)
+        seconds = _SPANS.end(self._sp)
+        if self.hist is not None and seconds is not None:
+            _REG.histogram(self.hist).observe(seconds,
+                                              **(self.labels or {}))
         return False
 
 
-def span(name, **attrs):
+def span(name, record=None, labels=None, **attrs):
     """Context manager recording one nested host span (and, when jax is
-    loaded, a jax.profiler.TraceAnnotation of the same name). No-op
-    singleton when disabled."""
+    loaded, a jax.profiler.TraceAnnotation of the same name). ``record``
+    names a histogram that takes the span's duration on exit, under
+    ``labels``: one pair of clock readings gives both the span (the
+    profiler's clock, a traced window) and the histogram (the whole
+    run). ``attrs`` carry identifiers (step, bucket, request id); they
+    never go into the name. No-op singleton when disabled."""
     if not _enabled:
         return _NULL
-    return _SpanCtx(name, attrs)
+    return _SpanCtx(name, attrs, record, labels)
 
 
 def key_id(key):

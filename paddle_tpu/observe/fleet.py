@@ -84,15 +84,22 @@ class ClockOffsetEstimator(object):
     remote timestamp maps onto the local clock as ``t_remote − offset``.
     Samples whose round-trip time is much worse than the best seen so
     far are down-weighted (asymmetric network delay is the dominant
-    error term); the first sample seeds the EWMA directly."""
+    error term); the first sample seeds the EWMA directly. A seed can
+    be a bad one: the first exchange with a fresh server takes tens of
+    milliseconds where later ones take under one, and its error is
+    bounded by its round trip. So a sample whose round trip is under a
+    quarter of the seed's replaces the estimate, and is the new seed,
+    instead of being averaged into an error many times its own."""
 
-    __slots__ = ('alpha', '_offset', '_rtt', '_best_rtt', 'samples')
+    __slots__ = ('alpha', '_offset', '_rtt', '_best_rtt', '_seed_rtt',
+                 'samples')
 
     def __init__(self, alpha=0.25):
         self.alpha = float(alpha)
         self._offset = None
         self._rtt = None
         self._best_rtt = None
+        self._seed_rtt = None
         self.samples = 0
 
     def update(self, t0, t1, t2, t3):
@@ -103,8 +110,9 @@ class ClockOffsetEstimator(object):
         self._rtt = rtt
         if self._best_rtt is None or rtt < self._best_rtt:
             self._best_rtt = rtt
-        if self._offset is None:
+        if self._offset is None or rtt < 0.25 * self._seed_rtt:
             self._offset = offset
+            self._seed_rtt = rtt
         else:
             a = self.alpha
             if self._best_rtt > 0 and rtt > 4.0 * self._best_rtt:

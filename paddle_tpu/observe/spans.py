@@ -26,6 +26,7 @@ lands on the observing thread's track with exact bounds, and
 ``add_instant`` records zero-duration marks (per-token events).
 """
 
+import collections
 import json
 import os
 import sys
@@ -34,8 +35,9 @@ import time
 
 __all__ = ['SpanRecorder', 'FlowHandle', 'MAX_EVENTS']
 
-# bound memory in unbounded runs: keep the first MAX_EVENTS spans and
-# count the rest (dropped count is recorded in the export metadata)
+# bound memory in unbounded runs: a ring of the newest MAX_EVENTS events
+# (a long-lived server exports its last minutes, not its start-up); the
+# count of those pushed out is recorded in the export metadata
 MAX_EVENTS = 200000
 
 
@@ -66,7 +68,7 @@ class FlowHandle(object):
 class SpanRecorder(object):
     def __init__(self):
         self._lock = threading.Lock()
-        self._events = []
+        self._events = collections.deque()
         self._dropped = 0
         # observe.__init__ points this at the registry's
         # spans_dropped_total counter, so a truncated trace is visible
@@ -87,7 +89,10 @@ class SpanRecorder(object):
             jax = sys.modules.get('jax')
             if jax is not None:
                 try:
-                    sp.ann = jax.profiler.TraceAnnotation(name)
+                    # attrs become the event's stats in the profiler's
+                    # trace, not part of its name
+                    sp.ann = jax.profiler.TraceAnnotation(
+                        name, **(attrs or {}))
                     sp.ann.__enter__()
                 except Exception:
                     sp.ann = None
@@ -99,10 +104,13 @@ class SpanRecorder(object):
         return sp
 
     def end(self, sp=None):
+        """Close the innermost open span of this thread (or unwind to
+        ``sp``); returns its duration in seconds, None if none was
+        open."""
         t1 = time.perf_counter()
         stack = getattr(self._tls, 'stack', None)
         if not stack:
-            return
+            return None
         top = stack.pop()
         if sp is not None and top is not sp:
             # mismatched end (generator-based caller): unwind to sp
@@ -120,15 +128,16 @@ class SpanRecorder(object):
         if top.attrs:
             ev['args'] = top.attrs
         self._append(ev)
+        return t1 - top.t0
 
     def _append(self, ev):
         with self._lock:
-            if len(self._events) < MAX_EVENTS:
-                self._events.append(ev)
-                cb = None
-            else:
+            cb = None
+            if len(self._events) >= MAX_EVENTS:
+                self._events.popleft()   # the oldest falls off the ring
                 self._dropped += 1
                 cb = self.on_drop
+            self._events.append(ev)
         if cb is not None:
             try:
                 cb(1)
@@ -219,7 +228,7 @@ class SpanRecorder(object):
 
     def clear(self):
         with self._lock:
-            self._events = []
+            self._events.clear()
             self._dropped = 0
             self._proc_labels = set()
 

@@ -65,6 +65,15 @@ __all__ = ['DecodeEngine', 'LMSpec']
 
 _ENGINE_IDS = itertools.count(1)
 
+# the worker thread's four states: their spans feed one histogram whose
+# label sums partition the thread's wall time between start() and
+# shutdown()
+_WORKER_SECONDS = 'decode.worker_seconds'
+_IDLE = {'state': 'idle'}
+_ADMIT = {'state': 'admit'}
+_PREFILL = {'state': 'prefill'}
+_STEP = {'state': 'step'}
+
 
 class DecodeEngine(object):
     """Continuous-batching decode server over a paged KV cache.
@@ -139,6 +148,11 @@ class DecodeEngine(object):
                 self._progs.verify,
                 fetch_names=[self._progs.verify_fetch],
                 label='decode_spec_verify')
+        # the XLA modules' names: jit_decode_step, jit_spec_verify and
+        # (set per dispatch, _run_prefill) jit_prefill_<bucket>
+        self._progs.decode.name = 'decode_step'
+        if self._progs.verify is not None:
+            self._progs.verify.name = 'spec_verify'
         self.capacity = self._progs.capacity
         self.max_prompt_len = int(max_prompt_len) if max_prompt_len \
             else self.capacity - 1
@@ -185,7 +199,10 @@ class DecodeEngine(object):
         self._broken = None
         self._thread = None
         self._health_name = None
+        self._step_no = 0
         self.warmup_signatures = 0
+        self.warmup_total_seconds = 0.0
+        self.warmup_aot_load_seconds = 0.0
 
     # ----------------------------------------------------------- weights
     def load_weights(self, weights):
@@ -486,23 +503,23 @@ class DecodeEngine(object):
             _obs.record('decode.warmup_seconds',
                         time.perf_counter() - t0, kind='prefill', bucket=b)
         t0 = time.perf_counter()
-        self._run_decode(
+        np.asarray(self._dispatch_decode(
             np.zeros((mb,), 'int64'),
             np.zeros((mb,), 'int32'),
             np.full((mb, pps), nb, 'int32'),
             np.zeros((mb,), 'float32'),
-            np.zeros((mb,), 'int32'))
+            np.zeros((mb,), 'int32')))
         _obs.record('decode.warmup_seconds', time.perf_counter() - t0,
                     kind='decode', bucket='')
         self.warmup_signatures = len(self.prompt_buckets) + 1
         if self.spec_k > 0:
             t0 = time.perf_counter()
-            self._run_verify(
+            np.asarray(self._dispatch_verify(
                 np.zeros((mb, self.spec_k + 1), 'int64'),
                 np.zeros((mb,), 'int32'),
                 np.full((mb, pps), nb, 'int32'),
                 np.zeros((mb,), 'float32'),
-                np.zeros((mb,), 'int32'))
+                np.zeros((mb,), 'int32')))
             _obs.record('decode.warmup_seconds',
                         time.perf_counter() - t0, kind='spec_verify',
                         bucket='')
@@ -529,14 +546,12 @@ class DecodeEngine(object):
                         time.perf_counter() - t0, kind='handoff',
                         bucket='')
         self._warmed = True
-        _obs.set_gauge('decode.warmup_signatures', self.warmup_signatures)
-        _obs.set_gauge('decode.warmup_total_seconds',
-                       time.perf_counter() - t_all)
         st = self._exe.aot_stats
+        self.warmup_total_seconds = time.perf_counter() - t_all
+        self.warmup_aot_load_seconds = \
+            st['load_seconds'] - aot0['load_seconds']
         _obs.set_gauge('decode.warmup_warm_from_disk',
                        st['hits'] - aot0['hits'])
-        _obs.set_gauge('decode.warmup_aot_load_seconds',
-                       st['load_seconds'] - aot0['load_seconds'])
         return self.warmup_signatures
 
     def drain(self, timeout=None):
@@ -599,13 +614,20 @@ class DecodeEngine(object):
             self._request_done(n)
 
     # ------------------------------------------------------------ worker
+    def _idle_wait(self, timeout=None):
+        """One wait on the engine's condition (held by the caller):
+        nothing to run, or head-of-line blocked on pages."""
+        with _obs.span('decode.idle', record=_WORKER_SECONDS,
+                       labels=_IDLE):
+            self._mu.wait(timeout)
+
     def _worker(self):
         try:
             while True:
                 with self._mu:
                     while not self._closed and \
                             self._sched.counts() == (0, 0):
-                        self._mu.wait()
+                        self._idle_wait()
                     waiting, running = self._sched.counts()
                     if self._closed and (
                             not self._draining or
@@ -613,14 +635,17 @@ class DecodeEngine(object):
                         return
                 self._admit()
                 if self._sched.running:
-                    self._decode_step()
+                    self._step_no += 1
+                    with _obs.span('decode.step', record=_WORKER_SECONDS,
+                                   labels=_STEP, step=self._step_no):
+                        self._decode_step()
                 elif self._sched.waiting:
                     # head-of-line blocked on pages with nothing running
                     # to free them — only another submit/shutdown can
                     # change that; avoid a hot spin
                     with self._mu:
                         if not self._closed:
-                            self._mu.wait(0.05)
+                            self._idle_wait(0.05)
         except BaseException as e:  # fail fast, loudly, and visibly
             self._broken = e
             _obs.inc('decode.worker_errors_total')
@@ -629,21 +654,28 @@ class DecodeEngine(object):
 
     def _admit(self):
         while True:
-            seq = self._sched.pop_admittable()
-            if seq is None:
-                return
-            _obs.record('decode.queue_seconds',
-                        seq.t_admit - seq.t_submit,
-                        exemplar=seq.ctx.exemplar() if seq.ctx
-                        else None)
-            if seq.ctx is not None and seq.ctx.sampled:
-                # began on the submit thread, ends here on the worker
-                seq.ctx.stage('queue_wait', seq.t_submit, seq.t_admit)
-                seq.ctx.flow_step()
-            self._prefill(seq)
+            with _obs.span('decode.admit', record=_WORKER_SECONDS,
+                           labels=_ADMIT):
+                seq = self._sched.pop_admittable()
+                if seq is None:
+                    return
+                _obs.record('decode.queue_seconds',
+                            seq.t_admit - seq.t_submit,
+                            exemplar=seq.ctx.exemplar() if seq.ctx
+                            else None)
+                if seq.ctx is not None and seq.ctx.sampled:
+                    # began on the submit thread, ends here on the worker
+                    seq.ctx.stage('queue_wait', seq.t_submit, seq.t_admit)
+                    seq.ctx.flow_step()
+            with _obs.span('decode.prefill', record=_WORKER_SECONDS,
+                           labels=_PREFILL, request_id=seq.request_id):
+                self._prefill(seq)
 
     # ----------------------------------------------------------- dispatch
     def _run_prefill(self, ids, length, cached, table, temp, seed):
+        # one Program, one XLA module per bucket: the name is read when a
+        # bucket's signature compiles
+        self._progs.prefill.name = 'prefill_%d' % ids.shape[1]
         with self._arena_mu, scope_guard(self._scope):
             out = self._exe.run(
                 program=self._progs.prefill,
@@ -656,25 +688,27 @@ class DecodeEngine(object):
                 fetch_list=[self._progs.prefill_fetch])
         return int(np.asarray(out[0]).reshape(-1)[0])
 
-    def _run_verify(self, tokens, lens, tables, temps, seeds):
+    def _dispatch_verify(self, tokens, lens, tables, temps, seeds):
+        """Enqueue one spec-verify step; its fetch stays on the device."""
         with self._arena_mu, scope_guard(self._scope):
-            out = self._exe.run(
+            return self._exe.run(
                 program=self._progs.verify,
                 feed={'sv_tokens': tokens, 'sv_lens': lens,
                       'sv_tables': tables, 'sv_temps': temps,
                       'sv_seeds': seeds},
-                fetch_list=[self._progs.verify_fetch])
-        return np.asarray(out[0]).reshape(tokens.shape)
+                fetch_list=[self._progs.verify_fetch],
+                return_numpy=False)[0]
 
-    def _run_decode(self, tokens, lens, tables, temps, seeds):
+    def _dispatch_decode(self, tokens, lens, tables, temps, seeds):
+        """Enqueue one decode step; its fetch stays on the device."""
         with self._arena_mu, scope_guard(self._scope):
-            out = self._exe.run(
+            return self._exe.run(
                 program=self._progs.decode,
                 feed={'dec_tokens': tokens, 'dec_lens': lens,
                       'dec_tables': tables, 'dec_temps': temps,
                       'dec_seeds': seeds},
-                fetch_list=[self._progs.decode_fetch])
-        return np.asarray(out[0]).reshape(-1)
+                fetch_list=[self._progs.decode_fetch],
+                return_numpy=False)[0]
 
     def _bucket(self, n):
         for b in self.prompt_buckets:
@@ -695,33 +729,36 @@ class DecodeEngine(object):
         on a hit (the hit's pages are already mapped in the block
         table; the suffix bucket, not the prompt bucket, sets the
         dispatch cost — that is the TTFT win)."""
-        prefix = seq.prefix()
-        s = len(prefix)
-        cached = seq.cached_len
-        suffix = prefix[cached:]
-        bucket = self._bucket(len(suffix))
-        ids = np.zeros((1, bucket), 'int64')
-        ids[0, :len(suffix)] = suffix
-        t0 = time.perf_counter()
-        tok = self._run_prefill(ids, len(suffix), cached,
-                                self._table_row(seq)[None, :],
-                                seq.temperature, seq.seed)
-        t1 = time.perf_counter()
+        with _obs.span('decode.prefill.build'):
+            prefix = seq.prefix()
+            s = len(prefix)
+            cached = seq.cached_len
+            suffix = prefix[cached:]
+            bucket = self._bucket(len(suffix))
+            ids = np.zeros((1, bucket), 'int64')
+            ids[0, :len(suffix)] = suffix
+            table = self._table_row(seq)[None, :]
+        with _obs.span('decode.prefill.run', bucket=bucket):
+            t0 = time.perf_counter()
+            tok = self._run_prefill(ids, len(suffix), cached, table,
+                                    seq.temperature, seq.seed)
+            t1 = time.perf_counter()
         _obs.record('decode.prefill_seconds', t1 - t0, bucket=bucket)
         _obs.inc('decode.prefills_total')
-        if cached:
-            _obs.flight_event('decode_prefix_hit',
-                              request_id=seq.request_id,
-                              cached_tokens=cached, prefix_tokens=s)
-        if seq.ctx is not None and seq.ctx.sampled:
-            seq.ctx.stage('prefill', t0, t1, bucket=bucket,
-                          prefix_tokens=s, cached_tokens=cached)
-        seq.cache_len = s
-        self._maybe_publish(seq)
-        self._emit(seq, tok, time.perf_counter())
-        reason = seq.finished()
-        if reason:
-            self._finish(seq, reason)
+        with _obs.span('decode.prefill.emit'):
+            if cached:
+                _obs.flight_event('decode_prefix_hit',
+                                  request_id=seq.request_id,
+                                  cached_tokens=cached, prefix_tokens=s)
+            if seq.ctx is not None and seq.ctx.sampled:
+                seq.ctx.stage('prefill', t0, t1, bucket=bucket,
+                              prefix_tokens=s, cached_tokens=cached)
+            seq.cache_len = s
+            self._maybe_publish(seq)
+            self._emit(seq, tok, time.perf_counter())
+            reason = seq.finished()
+            if reason:
+                self._finish(seq, reason)
 
     def _maybe_publish(self, seq):
         """Offer every newly frozen (full) page to the prefix cache.
@@ -737,41 +774,72 @@ class DecodeEngine(object):
                                       priority=seq.priority)
             seq.published_pages = full
 
-    def _decode_step(self):
-        if self.spec_k > 0 and self._spec_step():
-            return
-        for seq in list(self._sched.running):
-            if seq.state is not RUNNING:
-                continue   # preempted as a victim earlier in this pass
-            self._sched.ensure_growth(seq)
-        batch = list(self._sched.running)
-        if not batch:
-            return
+    def _step_feeds(self, batch):
+        """``(lens, tables, temps, seeds)`` of one step over ``batch`` at
+        the fixed [max_batch] signature; the rows past the batch point
+        every page beyond the pool, so their writes drop."""
         mb, pps, nb = self.max_batch, self.pages_per_seq, self.num_blocks
-        tokens = np.zeros((mb,), 'int64')
         lens = np.zeros((mb,), 'int32')
         tables = np.full((mb, pps), nb, 'int32')
         temps = np.zeros((mb,), 'float32')
         seeds = np.zeros((mb,), 'int32')
         for i, seq in enumerate(batch):
-            tokens[i] = seq.pending_token
             lens[i] = seq.cache_len
             tables[i] = self._table_row(seq)
             temps[i] = seq.temperature
             seeds[i] = seq.seed
+        if _obs.enabled():
+            # the KV positions this step attends over
+            _obs.record('decode.step_live_tokens', int(lens.sum()))
+        return lens, tables, temps, seeds
+
+    def _timed_step(self, dispatch, tokens, feeds, rows):
+        """Enqueue one step, then block on its fetch: ``(tokens on the
+        host, the instant they arrived)``. ``decode.step_seconds`` is
+        dispatch + fetch; the two spans split it where the enqueue
+        returns, so the fetch span ends on the profiler's clock at the
+        worker's wake-up after the device's last op."""
         t0 = time.perf_counter()
-        nxt = self._run_decode(tokens, lens, tables, temps, seeds)
+        with _obs.span('decode.step.dispatch',
+                       record='decode.step_dispatch_seconds', batch=rows):
+            out = dispatch(tokens, *feeds)
+        with _obs.span('decode.step.fetch',
+                       record='decode.step_fetch_seconds'):
+            out = np.asarray(out)
         now = time.perf_counter()
         _obs.record('decode.step_seconds', now - t0)
-        _obs.record('decode.batch_occupancy', len(batch) / float(mb))
+        _obs.record('decode.batch_occupancy', rows / float(self.max_batch))
         _obs.inc('decode.steps_total')
-        for i, seq in enumerate(batch):
-            seq.cache_len += 1
-            self._maybe_publish(seq)
-            self._emit(seq, int(nxt[i]), now)
-            reason = seq.finished()
-            if reason:
-                self._finish(seq, reason)
+        return out, now
+
+    def _decode_step(self):
+        if self.spec_k > 0 and self._spec_step():
+            return
+        with _obs.span('decode.step.build',
+                       record='decode.step_build_seconds'):
+            for seq in list(self._sched.running):
+                if seq.state is not RUNNING:
+                    continue   # preempted as a victim earlier in this pass
+                self._sched.ensure_growth(seq)
+            batch = list(self._sched.running)
+            if not batch:
+                return
+            tokens = np.zeros((self.max_batch,), 'int64')
+            for i, seq in enumerate(batch):
+                tokens[i] = seq.pending_token
+            feeds = self._step_feeds(batch)
+        nxt, now = self._timed_step(self._dispatch_decode, tokens, feeds,
+                                    len(batch))
+        nxt = nxt.reshape(-1)
+        with _obs.span('decode.step.emit',
+                       record='decode.step_emit_seconds'):
+            for i, seq in enumerate(batch):
+                seq.cache_len += 1
+                self._maybe_publish(seq)
+                self._emit(seq, int(nxt[i]), now)
+                reason = seq.finished()
+                if reason:
+                    self._finish(seq, reason)
 
     def _spec_step(self):
         """Draft-and-verify decode: the draft proposes up to k tokens
@@ -784,55 +852,49 @@ class DecodeEngine(object):
         live proposal — the plain decode step is the cheaper warmed
         signature for that case."""
         k = self.spec_k
-        pairs = [(s, list(self.draft.propose(s.prefix(), k))[:k])
-                 for s in self._sched.running if s.state is RUNNING]
-        if not any(d for _, d in pairs):
-            return False
-        for seq, _ in pairs:
-            if seq.state is not RUNNING:
-                continue   # preempted as a victim earlier in this pass
-            self._sched.ensure_growth(
-                seq, min(seq.cache_len + k + 1, self.capacity))
-        # ensure_growth may have preempted members of this very batch
-        pairs = [(s, d) for s, d in pairs if s.state is RUNNING]
-        if not pairs:
-            return True
-        mb, pps, nb = self.max_batch, self.pages_per_seq, self.num_blocks
-        tokens = np.zeros((mb, k + 1), 'int64')
-        lens = np.zeros((mb,), 'int32')
-        tables = np.full((mb, pps), nb, 'int32')
-        temps = np.zeros((mb,), 'float32')
-        seeds = np.zeros((mb,), 'int32')
-        drafts = []
-        for i, (seq, d) in enumerate(pairs):
-            _obs.inc('decode.spec_draft_tokens_total', len(d))
-            d = d + [0] * (k - len(d))  # padded rows verify for free
-            drafts.append(d)
-            tokens[i, 0] = seq.pending_token
-            tokens[i, 1:] = d
-            lens[i] = seq.cache_len
-            tables[i] = self._table_row(seq)
-            temps[i] = seq.temperature
-            seeds[i] = seq.seed
-        t0 = time.perf_counter()
-        nxt = self._run_verify(tokens, lens, tables, temps, seeds)
-        now = time.perf_counter()
-        _obs.record('decode.step_seconds', now - t0)
-        _obs.record('decode.batch_occupancy', len(pairs) / float(mb))
-        _obs.inc('decode.steps_total')
+        with _obs.span('decode.step.build',
+                       record='decode.step_build_seconds'):
+            pairs = [(s, list(self.draft.propose(s.prefix(), k))[:k])
+                     for s in self._sched.running if s.state is RUNNING]
+            if not any(d for _, d in pairs):
+                return False
+            for seq, _ in pairs:
+                if seq.state is not RUNNING:
+                    continue   # preempted as a victim earlier in this pass
+                self._sched.ensure_growth(
+                    seq, min(seq.cache_len + k + 1, self.capacity))
+            # ensure_growth may have preempted members of this very batch
+            pairs = [(s, d) for s, d in pairs if s.state is RUNNING]
+            if not pairs:
+                return True
+            tokens = np.zeros((self.max_batch, k + 1), 'int64')
+            drafts = []
+            for i, (seq, d) in enumerate(pairs):
+                _obs.inc('decode.spec_draft_tokens_total', len(d))
+                d = d + [0] * (k - len(d))  # padded rows verify for free
+                drafts.append(d)
+                tokens[i, 0] = seq.pending_token
+                tokens[i, 1:] = d
+            feeds = self._step_feeds([seq for seq, _ in pairs])
+        nxt, now = self._timed_step(self._dispatch_verify, tokens, feeds,
+                                    len(pairs))
+        nxt = nxt.reshape(tokens.shape)
         _obs.inc('decode.spec_steps_total')
-        for i, (seq, _) in enumerate(pairs):
-            emit = accept_drafts(drafts[i], nxt[i])
-            _obs.record('decode.spec_accepted_len', len(emit) - 1)
-            _obs.inc('decode.spec_accepted_tokens_total', len(emit) - 1)
-            for tok in emit:
-                seq.cache_len += 1
-                self._maybe_publish(seq)
-                self._emit(seq, int(tok), now)
-                reason = seq.finished()
-                if reason:
-                    self._finish(seq, reason)
-                    break
+        with _obs.span('decode.step.emit',
+                       record='decode.step_emit_seconds'):
+            for i, (seq, _) in enumerate(pairs):
+                emit = accept_drafts(drafts[i], nxt[i])
+                _obs.record('decode.spec_accepted_len', len(emit) - 1)
+                _obs.inc('decode.spec_accepted_tokens_total',
+                         len(emit) - 1)
+                for tok in emit:
+                    seq.cache_len += 1
+                    self._maybe_publish(seq)
+                    self._emit(seq, int(tok), now)
+                    reason = seq.finished()
+                    if reason:
+                        self._finish(seq, reason)
+                        break
         return True
 
     def _emit(self, seq, token, now):
@@ -865,7 +927,6 @@ class DecodeEngine(object):
         _obs.record('decode.request_seconds',
                     time.perf_counter() - seq.t_submit,
                     exemplar=seq.ctx.exemplar() if seq.ctx else None)
-        _obs.record('decode.request_tokens', len(seq.generated))
         if seq.ctx is not None and seq.ctx.sampled:
             seq.ctx.event('finish', reason=reason,
                           tokens=len(seq.generated))

@@ -126,6 +126,24 @@ def test_clock_offset_estimator_converges_under_skew():
     assert est.rtt() == pytest.approx(0.6)
 
 
+def test_clock_offset_reseeds_after_a_slow_first_exchange():
+    # a fresh server: 24 ms for the first exchange, all of it on the way
+    # out (the estimate is off by 12 ms), then four of 0.7 ms. Averaged
+    # in at alpha 0.25 the seed's error would still be 3.8 ms.
+    skew = 0.05
+    est = ClockOffsetEstimator()
+    est.update(100.0, 100.024 + skew, 100.024 + skew, 100.024)
+    assert est.offset() == pytest.approx(skew + 0.012)
+    for i in range(1, 5):
+        t = 100.0 + i
+        est.update(t, t + 0.00035 + skew, t + 0.00035 + skew, t + 0.0007)
+        assert abs(est.offset() - skew) <= est.rtt()
+    assert est.offset() == pytest.approx(skew, abs=1e-6)
+    # a slow exchange later is still only averaged in, down-weighted
+    est.update(200.0, 200.024 + skew, 200.024 + skew, 200.024)
+    assert est.offset() == pytest.approx(skew, abs=0.0002)
+
+
 def test_clockz_endpoint_feeds_estimator():
     observe.enable()
     srv = observe.serve(port=0)
