@@ -890,17 +890,21 @@ def bench_quant(dp=8, steps=150, hidden=256, in_dim=64,
     # agreement between fp32/int8 engines on identical prompts
     rng = np.random.RandomState(7)
     nb, h_, bs, d = 8, 2, kv_block_size, 16
-    kf = rng.randn(nb, h_, bs, d).astype('float32')
-    vf = rng.randn(nb, h_, bs, d).astype('float32')
+    kf = rng.randn(1, nb, bs, h_, d).astype('float32')  # per-head rows
+    vf = rng.randn(1, nb, bs, h_, d).astype('float32')
     kq, ks = quant.quantize_rows(jnp.asarray(kf), 'int8')
     vq, vs = quant.quantize_rows(jnp.asarray(vf), 'int8')
+
+    def arena(x):                  # -> the engine's [L, NB, bs, H*D]
+        return np.asarray(x).reshape(1, nb, bs, h_ * d)
     q = rng.randn(3, h_, d).astype('float32')
     tables = np.array([[0, 1, 2, 7], [3, 4, 8, 8], [5, 6, 8, 8]],
                       'int32')
     lens = np.array([4 * bs - 2, 2 * bs, bs + 3], 'int32')
-    ref = np.asarray(paged_attention_reference(q, kf, vf, tables, lens))
+    ref = np.asarray(paged_attention_reference(q, arena(kf), arena(vf),
+                                               tables, lens))
     got = np.asarray(paged_attention_reference(
-        q, np.asarray(kq), np.asarray(vq), tables, lens,
+        q, arena(kq), arena(vq), tables, lens,
         k_scales=np.asarray(ks), v_scales=np.asarray(vs)))
     cos = float((ref * got).sum() /
                 (np.linalg.norm(ref) * np.linalg.norm(got) + 1e-12))

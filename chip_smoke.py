@@ -8,8 +8,10 @@ at Transformer-base width on one TPU:
              (vocab 32,000, 6+6 layers, d_model 512, batch 64 x seq 64,
              Adam, bf16 amp) through Executor(TPUPlace(0)): startup,
              per-step exe.run, one run_steps window
-  serve      DecodeEngine at the same width: warmup(), start(),
-             concurrent submit()s, then the same prompts one at a time
+  serve      DecodeEngine at the same width: warmup(), the compiled
+             decode step and top prefill bucket read for arena-sized
+             copies (none allowed), start(), concurrent submit()s, then
+             the same prompts one at a time
   kernels    every Pallas kernel in the tree, compiled for the chip
              (interpret=False) and compared with its jnp reference
   four_chip  the train leg over make_mesh(dp=2, tp=2) with ZeRO-1, when
@@ -223,6 +225,8 @@ def leg_four_chip(cfg, platform, cache, one_chip_first_loss):
 def leg_serve(cfg, cache):
     from paddle_tpu import observe
     from paddle_tpu.serving.decode import DecodeEngine, LMSpec
+    from paddle_tpu.serving.decode.hlo_check import (
+        arena_sized_instructions)
     vocab = cfg['vocab']
 
     def misses():
@@ -242,6 +246,22 @@ def leg_serve(cfg, cache):
         t0 = time.perf_counter()
         signatures = engine.warmup()
         warmup_s = time.perf_counter() - t0
+        # the arenas are written in place: in the programs the compiler
+        # hands back, nothing of a layer's arena size is copied or
+        # re-laid outside the attention gather (a compile-cache hit
+        # after warmup; the reader needs no trace)
+        layer_elements = (cfg['engine']['num_blocks'] *
+                          cfg['engine']['block_size'] *
+                          cfg['model']['n_head'] * cfg['model']['d_key'])
+        arena_copies = {}
+        for which in ('decode', engine.prompt_buckets[-1]):
+            found = arena_sized_instructions(
+                engine.trace_program(which).lower().compile().as_text(),
+                layer_elements)
+            arena_copies[str(which)] = len(found)
+            assert not found, \
+                '%s copies or re-lays an arena: %s' % (
+                    which, [(i.name, i.shape) for i in found[:8]])
         engine.start()
         warm_misses = misses()
 
@@ -278,7 +298,8 @@ def leg_serve(cfg, cache):
            prompt_lens=[len(p) for p in prompts], new_tokens=max_new,
            concurrent_s=round(concurrent_s, 3),
            one_at_a_time_request_s=round(alone_s / len(prompts), 4),
-           misses_after_warmup=live_misses)
+           misses_after_warmup=live_misses,
+           arena_sized_copies=arena_copies)
 
 
 # -------------------------------------------------------------- kernels
@@ -376,8 +397,8 @@ def check_paged(model, eng):
     h, d, dv = model['n_head'], model['d_key'], model['d_value']
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.randn(b, h, d), jnp.float32)
-    kp = jnp.asarray(rng.randn(nb, h, bs, d), jnp.float32)
-    vp = jnp.asarray(rng.randn(nb, h, bs, dv), jnp.float32)
+    kp = jnp.asarray(rng.randn(1, nb, bs, h * d), jnp.float32)
+    vp = jnp.asarray(rng.randn(1, nb, bs, h * dv), jnp.float32)
     # every row owns distinct pages; lengths from 1 token to capacity
     tables = jnp.asarray(rng.permutation(nb)[:b * p].reshape(b, p),
                          jnp.int32)

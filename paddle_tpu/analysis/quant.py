@@ -14,7 +14,7 @@ pairing statically:
   use site — anything else breaks the weight-only contract).
 - every paged decode op (``paged_prefill`` / ``paged_decode_step`` /
   ``paged_spec_verify``) whose K/V arena is int8 or fp8 must carry
-  ``KScale``/``VScale`` arenas of dtype fp32 shaped ``[L, NB, H, bs]``
+  ``KScale``/``VScale`` arenas of dtype fp32 shaped ``[L, NB, bs, H]``
   (one scale per stored row) — and must both be written back
   (``KScaleOut``/``VScaleOut``), or the donation contract silently
   drops the scales of every new token.
@@ -119,14 +119,16 @@ def _check_paged_op(ctx, i, op):
                       'scale arena %r has dtype %s; must be float32'
                       % (sname, svar.dtype),
                       op=op, op_index=i, var=sname)
-        if cvar.shape is not None and svar.shape is not None and \
-                tuple(svar.shape) != tuple(cvar.shape[:4]):
+        want = None if cvar.shape is None else \
+            tuple(cvar.shape[:3]) + (op.attr('n_head', 1),)
+        if want is not None and svar.shape is not None and \
+                tuple(svar.shape) != want:
             ctx.error('kv-scale-shape',
                       'scale arena %r has shape %s; arena %r %s needs '
-                      'per-row scales shaped %s (one per [L, NB, H, '
-                      'bs] slot)'
+                      'per-row scales shaped %s (one per [L, NB, bs, '
+                      'H] row)'
                       % (sname, list(svar.shape), cname,
-                         list(cvar.shape), list(cvar.shape[:4])),
+                         list(cvar.shape), list(want)),
                       op=op, op_index=i, var=sname)
         out_slot = scale_slot + 'Out'
         if op.output(out_slot) is None:

@@ -13,8 +13,9 @@ cache lives in a paged pool (ops/pallas/paged_attention.py layouts):
   bucketed by the engine so the signature set is small and warmable.
 - ``paged_decode_step`` — one token for EVERY slot of a fixed-size
   decode batch [B]: append each sequence's K/V at its own position
-  (scatter through the block table; rows whose table entry is >= NB
-  drop their write, which is how empty slots ride along for free),
+  (one row written in place through the block table; rows whose table
+  entry is >= NB drop their write, which is how empty slots ride along
+  for free),
   ragged paged attention at per-sequence true lengths, then greedy or
   temperature sampling per row. ONE feed signature regardless of which
   sequences occupy which slots — the continuous-batching scheduler
@@ -34,7 +35,25 @@ cache lives in a paged pool (ops/pallas/paged_attention.py layouts):
 Per-row math mirrors the incremental-decode path in
 transformer_ops.py (``_incremental_layer_scan``): the layer stack is
 one ``lax.scan`` over [L, ...]-stacked weights, residual+LN via
-``fused_layer_norm``. Every per-row computation is independent of the
+``fused_layer_norm``.
+
+How the arenas travel: the stacked K/V arenas [L, NB, bs, H*D] (and
+the [L, NB, bs, H] scale arenas of the quantized dtypes) are the
+scan's CARRY, beside ``h``; only the weights and the layer index are
+scanned. A layer writes its new rows with ``dynamic_update_slice`` at
+(layer, page, slot, 0) and attends through a gather at (layer, table),
+so no program slices a layer out, stacks one back or hands an arena to
+a scatter: the op's KCacheOut/VCacheOut are the final carry, which the
+executor's donation aliases to the inputs, and the only instruction
+that touches arena-sized data is the attention gather
+(``serving/decode/hlo_check.py`` counts the others in a compiled
+program; chip_smoke.py fails above zero). The shape is what makes that
+possible on a TPU: a token's row is H*D contiguous lane-dense elements,
+so the compiler keeps the arena row-major and a row is a whole number
+of tiles (with D minor-most it would lay the page axis minor and
+re-lay the arena at every program's entry and exit).
+
+Every per-row computation is independent of the
 other rows — and all three ops attend through the same
 ``paged_attention`` gather over the same [P*bs] extent — so a
 sequence's token stream is bit-identical whether it decodes alone,
@@ -51,11 +70,14 @@ counter.
 Quantized arenas (docs/quantization.md): when the K/V arenas are int8
 or fp8, ``_extend_rows`` quantizes each written row independently
 (one fp32 scale per (token, head) row into the KScale/VScale arenas,
-deterministic rounding) and the attention gather dequantizes through
+carried and written like the pages; deterministic rounding) and the
+attention gather dequantizes through
 the same table indices — so every invariant above, including
 bit-consistency across batching/speculation/caching, holds unchanged
 at the quantized dtypes.
 """
+
+import collections
 
 import jax
 import jax.numpy as jnp
@@ -82,21 +104,77 @@ def _ffn(h, p):
         p['ffn_b2']
 
 
-def _write_positions(pages, new, phys, off):
-    """Scatter per-position K/V rows into the page arena.
-    pages [NB, H, bs, D]; new [N, H, D]; phys/off [N] int32 — rows with
-    phys >= NB are dropped (empty batch slots / padded prompt tail)."""
-    n_head = new.shape[1]
-    return pages.at[phys[:, None], jnp.arange(n_head)[None, :],
-                    off[:, None]].set(new, mode='drop')
+# Where an op's N new rows land in an arena [L, NB, bs, W], as n runs
+# of r consecutive slots of one page: run i covers (phys[i],
+# off[i]..off[i]+r-1) and writes slot j only where ok[i, j].
+# ``blocks(rows)`` lays the op's rows [N, W] out as those runs,
+# [n, r, W]. Which of the two builders below an op uses rests on what
+# it knows statically about its rows.
+_Placement = collections.namedtuple('_Placement',
+                                    ['phys', 'off', 'ok', 'blocks'])
 
 
-def _write_scales(scales, new, phys, off):
-    """Scatter per-row scales beside a quantized arena write.
-    scales [NB, H, bs]; new [N, H]; same drop semantics as the pages."""
-    n_head = new.shape[1]
-    return scales.at[phys[:, None], jnp.arange(n_head)[None, :],
-                     off[:, None]].set(new, mode='drop')
+def _single_rows(tables, pos, nb, bs):
+    """Single tokens of many tables (decode step, spec verify): row i
+    is one slot, position ``pos[i]`` of ``tables[i]``. A row past the
+    table's capacity, or whose table entry is not a page (>= NB: empty
+    batch slots), writes nothing."""
+    p_cap = tables.shape[1]
+    logical = jnp.clip(pos // bs, 0, p_cap - 1)
+    phys = jnp.take_along_axis(tables, logical[:, None], axis=1)[:, 0]
+    ok = (pos < p_cap * bs) & (phys >= 0) & (phys < nb)
+    return _Placement(jnp.clip(phys, 0, nb - 1), pos % bs, ok[:, None],
+                      lambda rows: rows[:, None, :])
+
+
+def _page_runs(table, cached, length, n_rows, nb, bs):
+    """Consecutive positions of one table (prefill): row t sits at
+    position ``cached + t``, so the ``n_rows`` rows fill whole pages but
+    the first and the last, and are written page by page — at most
+    n_rows / bs + 1 updates instead of n_rows. Rows t >= ``length``
+    (the padded tail), pages past the table's capacity and table
+    entries that are not a page write nothing."""
+    p_cap = table.shape[0]
+    n_pages = -(-n_rows // bs) + 1
+    shift = cached % bs                 # row 0's slot in the first page
+    logical = cached // bs + jnp.arange(n_pages, dtype=jnp.int32)
+    phys = jnp.take(table, jnp.clip(logical, 0, p_cap - 1))
+    page_ok = (logical < p_cap) & (phys >= 0) & (phys < nb)
+    t = jnp.arange(n_pages * bs, dtype=jnp.int32).reshape(n_pages, bs) \
+        - shift                         # the row each slot would hold
+    ok = page_ok[:, None] & (t >= 0) & (t < length)
+
+    def blocks(rows):
+        # shift + n_rows <= n_pages * bs: the rows always fit
+        flat = jnp.zeros((n_pages * bs, rows.shape[1]), rows.dtype)
+        flat = jax.lax.dynamic_update_slice(flat, rows, (shift, 0))
+        return flat.reshape(n_pages, bs, -1)
+    return _Placement(jnp.clip(phys, 0, nb - 1),
+                      jnp.zeros((n_pages,), jnp.int32), ok, blocks)
+
+
+def _write_in_place(arenas, rows, layer, place):
+    """Write each ``rows[a]`` [N, W] into ``arenas[a]`` [L, NB, bs, W]
+    at ``layer`` where ``place`` says, run by run with
+    ``dynamic_update_slice`` (which clamps and never drops, so a slot
+    that must not be written gets the value that is there). Runs go in
+    order, each reading the arena the one before it left. Nothing here
+    is of arena size: a scatter would have the TPU re-lay its whole
+    operand."""
+    blocks = [place.blocks(r) for r in rows]
+
+    def one(i, arenas):
+        out = []
+        for arena, new in zip(arenas, blocks):
+            at = (layer, place.phys[i], place.off[i], 0)
+            run = (1, 1) + new.shape[1:]
+            there = jax.lax.dynamic_slice(arena, at, run)
+            mine = jax.lax.dynamic_index_in_dim(new, i, keepdims=False)
+            keep = place.ok[i].reshape(1, 1, -1, 1)
+            out.append(jax.lax.dynamic_update_slice(
+                arena, jnp.where(keep, mine.reshape(run), there), at))
+        return tuple(out)
+    return jax.lax.fori_loop(0, place.phys.shape[0], one, tuple(arenas))
 
 
 def _arena_kv_dtype(kc):
@@ -122,7 +200,7 @@ def _lm_inputs(ctx):
     wout = ctx.input('OutProj')
     params = {s: ctx.env[ctx.op.input(_slot_to_input(s))]
               for s in LM_SLOTS}
-    kc = ctx.input('KCache')            # [L, NB, H, bs, dk]
+    kc = ctx.input('KCache')            # [L, NB, bs, H*dk]
     vc = ctx.input('VCache')
     ks = ctx.input('KScale') if ctx.has_input('KScale') else None
     vs = ctx.input('VScale') if ctx.has_input('VScale') else None
@@ -150,10 +228,10 @@ def _paged_decode_step(ctx):
 
     # one new token per row at position lens (empty slots feed all->NB
     # tables, so phys lands out of bounds and every write drops)
-    live = jnp.ones(lens.shape, dtype=bool)
+    place = _single_rows(tables, lens, kcs.shape[1], kcs.shape[2])
     logits, kcs, vcs, kss, vss = _extend_rows(
         emb, pos_enc, wout, params, kcs, vcs, n_head,
-        tokens, lens, live, tables, kss, vss)
+        tokens, lens, tables, place, kss, vss)
     nxt = jax.vmap(_sample_token)(logits, seeds, lens + 1, temps)
     ctx.set_output('NextTokens',
                    nxt.astype(ctx.out_dtype('NextTokens', 'int64')))
@@ -161,74 +239,62 @@ def _paged_decode_step(ctx):
 
 
 def _extend_rows(emb, pos_enc, wout, params, kcs, vcs, n_head,
-                 tokens, pos, live, tables, kscales=None, vscales=None):
-    """Shared core of prefill and spec-verify: write N new tokens'
-    K/V at absolute positions ``pos`` through per-row block
-    ``tables`` [N, P], attend each row at its own ragged length
-    (``pos + 1``), and return fp32 logits [N, V] plus the updated
-    arenas. Rows that are not ``live``, sit past the table's capacity,
-    or hit a table entry >= NB drop their writes (padded tails /
-    empty batch slots).
+                 tokens, pos, tables, place, kscales=None, vscales=None):
+    """Shared core of all three ops: write N new tokens' K/V at
+    absolute positions ``pos`` where ``place`` (a _Placement over the
+    same rows) says, attend each row at its own ragged length
+    (``pos + 1``) through per-row block ``tables`` [N, P], and return
+    fp32 logits [N, V] plus the updated arenas. The arenas are carried
+    through the layer loop and written in place (module docstring).
 
-    Quantized arenas (``kscales``/``vscales`` [L, NB, H, bs] given):
+    Quantized arenas (``kscales``/``vscales`` [L, NB, bs, H] given):
     each new K/V row is quantized independently (one fp32 scale per
     (token, head) row, deterministic rounding — quant.core
-    quantize_rows) before the scatter, and the attention gather
+    quantize_rows) before the write, and the attention gather
     dequantizes through the same table indices. Because rows quantize
     independently, every path (prefill, decode, spec-verify, cache
     hits) stores identical bits for identical tokens — the
     concurrent == sequential invariant survives at int8/fp8."""
     from ..quant.core import quantize_rows
     from .pallas.paged_attention import paged_attention
-    bs = kcs.shape[3]
-    nb = kcs.shape[1]
+    n = tokens.shape[0]
     d_model = emb.shape[-1]
-    p_cap = tables.shape[1]
     kv_q = _arena_kv_dtype(kcs)
     quantized = kv_q is not None
-
-    logical = jnp.clip(pos // bs, 0, p_cap - 1)
-    phys = jnp.take_along_axis(tables, logical[:, None], axis=1)[:, 0]
-    phys = jnp.where((pos < p_cap * bs) & live, phys, nb)
-    off = pos % bs
 
     x = jnp.take(emb, tokens, axis=0) * (d_model ** 0.5) + \
         jnp.take(pos_enc, pos, axis=0, mode='clip')
     att_lens = pos + 1
 
-    def body(h, sl):
+    def body(carry, sl):
+        h, arenas = carry
+        p, layer = sl
+        k_new = h @ p['slf_k']       # [N, H*dk]: a row as the arena holds it
+        v_new = h @ p['slf_v']
         if quantized:
-            p, kc, vc, ksc, vsc = sl
+            kq, ks_row = quantize_rows(_split_heads(k_new, n_head), kv_q)
+            vq, vs_row = quantize_rows(_split_heads(v_new, n_head), kv_q)
+            rows = (kq.reshape(n, -1), vq.reshape(n, -1), ks_row, vs_row)
         else:
-            p, kc, vc = sl
-            ksc = vsc = None
-        k_new = _split_heads(h @ p['slf_k'], n_head)       # [N, H, dk]
-        v_new = _split_heads(h @ p['slf_v'], n_head)
-        if quantized:
-            kq, ks_row = quantize_rows(k_new, kv_q)
-            vq, vs_row = quantize_rows(v_new, kv_q)
-            kc = _write_positions(kc, kq, phys, off)
-            vc = _write_positions(vc, vq, phys, off)
-            ksc = _write_scales(ksc, ks_row, phys, off)
-            vsc = _write_scales(vsc, vs_row, phys, off)
-        else:
-            kc = _write_positions(kc, k_new.astype(kc.dtype), phys, off)
-            vc = _write_positions(vc, v_new.astype(vc.dtype), phys, off)
+            rows = (k_new.astype(kcs.dtype), v_new.astype(vcs.dtype))
+        arenas = _write_in_place(arenas, rows, layer, place)
         q = _split_heads(h @ p['slf_q'], n_head)
-        attn = paged_attention(q, kc, vc, tables, att_lens,
-                               k_scales=ksc, v_scales=vsc)
-        h = _ln(h + attn.reshape(h.shape[0], -1).astype(h.dtype)
+        attn = paged_attention(q, arenas[0], arenas[1], tables, att_lens,
+                               k_scales=arenas[2] if quantized else None,
+                               v_scales=arenas[3] if quantized else None,
+                               layer=layer)
+        h = _ln(h + attn.reshape(n, -1).astype(h.dtype)
                 @ p['slf_o'], p, 'ln1')
         h = _ln(h + _ffn(h, p), p, 'ln2')
-        if quantized:
-            return h, (kc, vc, ksc, vsc)
-        return h, (kc, vc)
+        return (h, arenas), None
 
+    arenas = (kcs, vcs, kscales, vscales) if quantized else (kcs, vcs)
+    layers = jnp.arange(kcs.shape[0], dtype=jnp.int32)
+    (h, arenas), _ = jax.lax.scan(body, (x, arenas), (params, layers))
     if quantized:
-        h, (kcs, vcs, kscales, vscales) = jax.lax.scan(
-            body, x, (params, kcs, vcs, kscales, vscales))
+        kcs, vcs, kscales, vscales = arenas
     else:
-        h, (kcs, vcs) = jax.lax.scan(body, x, (params, kcs, vcs))
+        kcs, vcs = arenas
     return (h @ wout).astype(jnp.float32), kcs, vcs, kscales, vscales
 
 
@@ -248,12 +314,13 @@ def _paged_prefill(ctx):
     # suffix position t lives at absolute position cached + t; its
     # query attends to everything at or below it — the cached pages
     # plus this step's own earlier writes — through the table gather
-    t_idx = jnp.arange(s, dtype=jnp.int32)
-    pos = cached + t_idx
+    pos = cached + jnp.arange(s, dtype=jnp.int32)
     tables = jnp.broadcast_to(table, (s, table.shape[0]))
+    place = _page_runs(table, cached, length, s, kcs.shape[1],
+                       kcs.shape[2])
     logits, kcs, vcs, kss, vss = _extend_rows(
         emb, pos_enc, wout, params, kcs, vcs, n_head,
-        ids, pos, t_idx < length, tables, kss, vss)
+        ids, pos, tables, place, kss, vss)
 
     logits_last = jax.lax.dynamic_index_in_dim(
         logits, jnp.maximum(length - 1, 0), keepdims=False)     # [V]
@@ -284,10 +351,10 @@ def _paged_spec_verify(ctx):
     j = jnp.arange(k1, dtype=jnp.int32)
     pos = (lens[:, None] + j[None, :]).reshape(-1)         # [B*K1]
     tables_rep = jnp.repeat(tables, k1, axis=0)            # [B*K1, P]
-    live = jnp.ones(pos.shape, dtype=bool)
+    place = _single_rows(tables_rep, pos, kcs.shape[1], kcs.shape[2])
     logits, kcs, vcs, kss, vss = _extend_rows(
         emb, pos_enc, wout, params, kcs, vcs, n_head,
-        tokens.reshape(-1), pos, live, tables_rep, kss, vss)
+        tokens.reshape(-1), pos, tables_rep, place, kss, vss)
 
     nxt = jax.vmap(_sample_token)(
         logits, jnp.repeat(seeds, k1), pos + 1, jnp.repeat(temps, k1))

@@ -13,9 +13,13 @@ not Tmax slots.
 Two backends, selected like ops/pallas/flash_attention.py:
 
 - **XLA gather path** (default, and the CPU/tier-1 path): gather the
-  per-sequence pages through the block table into [B, H, P*bs, D],
-  mask columns >= seq_len, fp32 softmax. XLA fuses the gather into the
-  attention chain; on small decode shapes this is already near-optimal.
+  per-sequence pages through the block table at (layer, table) into
+  [B, P, bs, H*D], read it as [B, P*bs, H, D] (no transpose), mask
+  columns >= seq_len, fp32 softmax. The gather is the only
+  instruction that reads the arena. What follows it is sized by the
+  batch's tables, not by the pool — and on the TPU the split of H*D
+  into [H, D] is still a re-tiling of the gathered pages when D is
+  under a lane tile (PERF.md section 5; ROADMAP S3b/S3c).
 - **Pallas kernel** (PADDLE_TPU_USE_PALLAS=1): the block table rides
   scalar prefetch (pltpu.PrefetchScalarGridSpec) so each grid step's
   page index map reads table[b, page] — the kernel DMAs exactly the
@@ -29,9 +33,20 @@ tests/test_pallas_kernels.py (kernel, interpret mode).
 
 Layouts:
     q            [B, H, D]      one query token per sequence
-    k/v_pages    [NB, H, bs, D] the pooled page arena (one layer)
+    k/v_pages    [L, NB, bs, H*D]  the pooled page arena, all layers:
+                 token-major inside a page, heads and head width merged
+                 into one lane-dense minor axis (a token's K row is
+                 H*D contiguous elements), so the TPU keeps the arena
+                 row-major and a row can be written in place
+                 (ops/paged_decode_ops.py). ``layer`` picks the layer.
+    k/v_scales   [L, NB, bs, H] per-row fp32 scales (quantized arenas)
     block_tables [B, P] int32   physical page ids; >= NB means "no page"
     seq_lens     [B]  int32     live tokens (this token included)
+
+The Pallas kernel wants one layer's pages head-major, [NB, H, bs, D]:
+``_paged_pallas`` cuts its layer out of the arena and re-lays it on its
+own path (it is gated off by default; the kernel written for the
+token-major arena is ROADMAP S3c).
 """
 
 import functools
@@ -46,40 +61,47 @@ from . import pallas_enabled
 _NEG_INF = -1e9
 
 
+def _gather_pages(arena, layer, tables, n_head):
+    """arena [L, NB, bs, H*W] read at (layer, table) -> [B, P*bs, H, W]
+    (W = 1 for the scale arenas' [L, NB, bs, H]). One gather whose
+    slices are whole pages; the reshape splits and merges adjacent
+    axes only, so nothing is transposed."""
+    b, p = tables.shape
+    bs = arena.shape[2]
+    pages = arena[layer, tables]                   # [B, P, bs, H*W]
+    return pages.reshape(b, p * bs, n_head, -1)
+
+
 def paged_attention_reference(q, k_pages, v_pages, block_tables, seq_lens,
                               sm_scale=None, k_scales=None,
-                              v_scales=None):
+                              v_scales=None, layer=0):
     """XLA gather path. Bit-stable contract with the Pallas kernel's
     masking: columns >= seq_lens[b] contribute exactly 0 (exp of a
     large-negative underflows), so the result is independent of the
     garbage content of unowned/partial pages.
 
-    Quantized arenas: ``k_scales``/``v_scales`` [NB, H, bs] carry one
-    fp32 scale per stored (page, head, slot) K/V row; the gather
+    Quantized arenas: ``k_scales``/``v_scales`` [L, NB, bs, H] carry
+    one fp32 scale per stored (page, slot, head) K/V row; the gather
     dequantizes to fp32 through the same table indices before the
     attention math (fp32 accumulation — int8/fp8 only ever live in
     HBM)."""
-    nb, h, bs, d = k_pages.shape
+    nb, bs = k_pages.shape[1], k_pages.shape[2]
     b, p = block_tables.shape
+    h, d = q.shape[1], q.shape[2]
     scale = sm_scale if sm_scale is not None else d ** -0.5
     tables = jnp.clip(block_tables.astype(jnp.int32), 0, nb - 1)
-    # [B, P, H, bs, D] -> [B, H, P*bs, D]
-    k = jnp.transpose(k_pages[tables], (0, 2, 1, 3, 4)) \
-        .reshape(b, h, p * bs, d)
-    v = jnp.transpose(v_pages[tables], (0, 2, 1, 3, 4)) \
-        .reshape(b, h, p * bs, v_pages.shape[-1])
+    k = _gather_pages(k_pages, layer, tables, h)   # [B, P*bs, H, D]
+    v = _gather_pages(v_pages, layer, tables, h)
     if k_scales is not None:
-        ks = jnp.transpose(k_scales[tables], (0, 2, 1, 3)) \
-            .reshape(b, h, p * bs)
-        vs = jnp.transpose(v_scales[tables], (0, 2, 1, 3)) \
-            .reshape(b, h, p * bs)
-        k = k.astype(jnp.float32) * ks[..., None]
-        v = v.astype(jnp.float32) * vs[..., None]
-    logits = jnp.einsum('bhd,bhkd->bhk', (q * scale), k)
+        k = k.astype(jnp.float32) * _gather_pages(k_scales, layer,
+                                                  tables, h)
+        v = v.astype(jnp.float32) * _gather_pages(v_scales, layer,
+                                                  tables, h)
+    logits = jnp.einsum('bhd,bkhd->bhk', (q * scale), k)
     mask = jnp.arange(p * bs)[None, :] < seq_lens.reshape(-1, 1)
     logits = jnp.where(mask[:, None, :], logits, _NEG_INF)
     w = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    return jnp.einsum('bhk,bhkd->bhd', w.astype(v.dtype), v)
+    return jnp.einsum('bhk,bkhd->bhd', w.astype(v.dtype), v)
 
 
 def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -128,12 +150,26 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0, 0] = (acc_scr[:] / denom).astype(o_ref.dtype)
 
 
-def _paged_pallas(q, k_pages, v_pages, block_tables, seq_lens, sm_scale):
+def _layer_pages_head_major(arena, layer, n_head):
+    """[L, NB, bs, H*D] at ``layer`` -> [NB, H, bs, D]: the kernel's
+    page tile is (bs, D), which the token-major arena cannot hand out
+    as a block (D alone is under a lane tile). A copy of one layer's
+    pages, private to the Pallas path."""
+    nb, bs = arena.shape[1], arena.shape[2]
+    pages = jax.lax.dynamic_index_in_dim(arena, layer, keepdims=False)
+    return jnp.transpose(pages.reshape(nb, bs, n_head, -1), (0, 2, 1, 3))
+
+
+def _paged_pallas(q, k_pages, v_pages, block_tables, seq_lens, sm_scale,
+                  layer=0):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    nb, h, bs, d = k_pages.shape
-    b, p = block_tables.shape
+    b, h, d = q.shape
+    k_pages = _layer_pages_head_major(k_pages, layer, h)
+    v_pages = _layer_pages_head_major(v_pages, layer, h)
+    nb, bs = k_pages.shape[0], k_pages.shape[2]
+    p = block_tables.shape[1]
     dv = v_pages.shape[-1]
     tables = jnp.clip(block_tables.astype(jnp.int32), 0, nb - 1)
     lens = seq_lens.astype(jnp.int32)
@@ -184,7 +220,8 @@ def _use_pallas(q, k_pages, v_pages, block_tables):
     PADDLE_TPU_AUTOTUNE=on — the per-shape tuning table (this is the
     dispatch the decode engine's ops/paged_decode_ops.py hot loop rides
     through), then the pallas_enabled() default (off)."""
-    nb, h, bs, d = k_pages.shape
+    bs = k_pages.shape[2]
+    h, d = q.shape[1], q.shape[2]
     aligned = bs % 8 == 0 and d % 8 == 0
     env = os.environ.get('PADDLE_TPU_PAGED_PALLAS')
     if env is not None:
@@ -194,28 +231,29 @@ def _use_pallas(q, k_pages, v_pages, block_tables):
             not tuning.env_gate_set('PADDLE_TPU_USE_PALLAS'):
         b, p = block_tables.shape
         picked = tuning.decide_paged_attention(
-            b, p, h, bs, d, v_pages.shape[-1], str(q.dtype))
+            b, p, h, bs, d, v_pages.shape[-1] // h, str(q.dtype))
         if picked is not None:
             return picked.get('impl') == 'pallas' and aligned
     return pallas_enabled() and aligned
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
-                    sm_scale=None, k_scales=None, v_scales=None):
+                    sm_scale=None, k_scales=None, v_scales=None, layer=0):
     """Ragged paged attention: one query per sequence against its paged
-    KV cache. q [B, H, D]; pages [NB, H, bs, D*]; block_tables [B, P]
-    int32 (entries >= NB mean "no page" and are never read); seq_lens
-    [B] int32. Quantized arenas pass their per-row fp32 scale arenas
-    as ``k_scales``/``v_scales`` [NB, H, bs] and take the gather path
-    (which dequantizes inline; the Pallas kernel stays fp32/bf16).
-    Returns [B, H, Dv]."""
-    nb, h, bs, d = k_pages.shape
+    KV cache. q [B, H, D]; pages [L, NB, bs, H*D*] read at ``layer``
+    (a traced scalar inside the decode ops' layer loop); block_tables
+    [B, P] int32 (entries >= NB mean "no page" and are never read);
+    seq_lens [B] int32. Quantized arenas pass their per-row fp32 scale
+    arenas as ``k_scales``/``v_scales`` [L, NB, bs, H] and take the
+    gather path (which dequantizes inline; the Pallas kernel stays
+    fp32/bf16). Returns [B, H, Dv]."""
+    d = q.shape[2]
     scale = sm_scale if sm_scale is not None else d ** -0.5
     if k_scales is None and str(k_pages.dtype) in ('float32', 'bfloat16') \
             and _use_pallas(q, k_pages, v_pages, block_tables):
         return _paged_pallas(q, k_pages, v_pages, block_tables, seq_lens,
-                             scale)
+                             scale, layer=layer)
     return paged_attention_reference(q, k_pages, v_pages, block_tables,
                                      seq_lens, sm_scale=scale,
                                      k_scales=k_scales,
-                                     v_scales=v_scales)
+                                     v_scales=v_scales, layer=layer)

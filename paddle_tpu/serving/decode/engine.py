@@ -489,8 +489,6 @@ class DecodeEngine(object):
         point every block-table entry past the pool (all writes drop),
         so device state is untouched. Returns the signature count."""
         t_all = time.perf_counter()
-        nb = self.num_blocks
-        mb, pps = self.max_batch, self.pages_per_seq
         # AOT warm start: every warmup dispatch consults the serialized-
         # executable cache (core/aot_cache.py); a restarted replica
         # deserializes its prefill buckets + decode key instead of
@@ -498,28 +496,17 @@ class DecodeEngine(object):
         aot0 = dict(self._exe.aot_stats)
         for b in self.prompt_buckets:
             t0 = time.perf_counter()
-            self._run_prefill(np.zeros((1, b), 'int64'), 1, 0,
-                              np.full((1, pps), nb, 'int32'), 0.0, 0)
+            self._run_prefill(*self._warm_args(b))
             _obs.record('decode.warmup_seconds',
                         time.perf_counter() - t0, kind='prefill', bucket=b)
         t0 = time.perf_counter()
-        np.asarray(self._dispatch_decode(
-            np.zeros((mb,), 'int64'),
-            np.zeros((mb,), 'int32'),
-            np.full((mb, pps), nb, 'int32'),
-            np.zeros((mb,), 'float32'),
-            np.zeros((mb,), 'int32')))
+        np.asarray(self._dispatch_decode(*self._warm_args('decode')))
         _obs.record('decode.warmup_seconds', time.perf_counter() - t0,
                     kind='decode', bucket='')
         self.warmup_signatures = len(self.prompt_buckets) + 1
         if self.spec_k > 0:
             t0 = time.perf_counter()
-            np.asarray(self._dispatch_verify(
-                np.zeros((mb, self.spec_k + 1), 'int64'),
-                np.zeros((mb,), 'int32'),
-                np.full((mb, pps), nb, 'int32'),
-                np.zeros((mb,), 'float32'),
-                np.zeros((mb,), 'int32')))
+            np.asarray(self._dispatch_verify(*self._warm_args('verify')))
             _obs.record('decode.warmup_seconds',
                         time.perf_counter() - t0, kind='spec_verify',
                         bucket='')
@@ -672,6 +659,36 @@ class DecodeEngine(object):
                 self._prefill(seq)
 
     # ----------------------------------------------------------- dispatch
+    @staticmethod
+    def _prefill_feed(ids, length, cached, table, temp, seed):
+        return {'pf_ids': ids,
+                'pf_len': np.asarray([length], 'int32'),
+                'pf_cached': np.asarray([cached], 'int32'),
+                'pf_table': table,
+                'pf_temp': np.asarray([temp], 'float32'),
+                'pf_seed': np.asarray([seed], 'int32')}
+
+    @staticmethod
+    def _step_feed(prefix, tokens, lens, tables, temps, seeds):
+        """The decode step's ('dec') or the spec-verify step's ('sv')
+        five feeds."""
+        return {prefix + '_tokens': tokens, prefix + '_lens': lens,
+                prefix + '_tables': tables, prefix + '_temps': temps,
+                prefix + '_seeds': seeds}
+
+    def _warm_args(self, which):
+        """What ``warmup()`` dispatches ``which`` ('decode', 'verify' or
+        a prefill bucket's size) with: the shapes live traffic uses,
+        every block-table entry past the pool so nothing is written."""
+        nb, mb, pps = self.num_blocks, self.max_batch, self.pages_per_seq
+        if which in ('decode', 'verify'):
+            toks = (mb,) if which == 'decode' else (mb, self.spec_k + 1)
+            return (np.zeros(toks, 'int64'), np.zeros((mb,), 'int32'),
+                    np.full((mb, pps), nb, 'int32'),
+                    np.zeros((mb,), 'float32'), np.zeros((mb,), 'int32'))
+        return (np.zeros((1, int(which)), 'int64'), 1, 0,
+                np.full((1, pps), nb, 'int32'), 0.0, 0)
+
     def _run_prefill(self, ids, length, cached, table, temp, seed):
         # one Program, one XLA module per bucket: the name is read when a
         # bucket's signature compiles
@@ -679,12 +696,8 @@ class DecodeEngine(object):
         with self._arena_mu, scope_guard(self._scope):
             out = self._exe.run(
                 program=self._progs.prefill,
-                feed={'pf_ids': ids,
-                      'pf_len': np.asarray([length], 'int32'),
-                      'pf_cached': np.asarray([cached], 'int32'),
-                      'pf_table': table,
-                      'pf_temp': np.asarray([temp], 'float32'),
-                      'pf_seed': np.asarray([seed], 'int32')},
+                feed=self._prefill_feed(ids, length, cached, table, temp,
+                                        seed),
                 fetch_list=[self._progs.prefill_fetch])
         return int(np.asarray(out[0]).reshape(-1)[0])
 
@@ -693,9 +706,8 @@ class DecodeEngine(object):
         with self._arena_mu, scope_guard(self._scope):
             return self._exe.run(
                 program=self._progs.verify,
-                feed={'sv_tokens': tokens, 'sv_lens': lens,
-                      'sv_tables': tables, 'sv_temps': temps,
-                      'sv_seeds': seeds},
+                feed=self._step_feed('sv', tokens, lens, tables, temps,
+                                     seeds),
                 fetch_list=[self._progs.verify_fetch],
                 return_numpy=False)[0]
 
@@ -704,11 +716,38 @@ class DecodeEngine(object):
         with self._arena_mu, scope_guard(self._scope):
             return self._exe.run(
                 program=self._progs.decode,
-                feed={'dec_tokens': tokens, 'dec_lens': lens,
-                      'dec_tables': tables, 'dec_temps': temps,
-                      'dec_seeds': seeds},
+                feed=self._step_feed('dec', tokens, lens, tables, temps,
+                                     seeds),
                 fetch_list=[self._progs.decode_fetch],
                 return_numpy=False)[0]
+
+    def trace_program(self, which):
+        """jax's ``Traced`` (``.jaxpr``, ``.lower().compile()``) for one
+        of this engine's programs as the executor jits it — same step
+        function, same module name, the scope donated — at the feeds
+        ``warmup()`` compiles: ``which`` is ``'decode'``, ``'verify'``
+        or a prefill bucket's size. For reading what the compiler made
+        of a program (tests, chip_smoke.py); nothing is dispatched.
+        Where a compile cache is armed, compiling it after ``warmup()``
+        is a cache hit."""
+        import jax
+        args = self._warm_args(which)
+        if which == 'decode':
+            prog, fetch = self._progs.decode, self._progs.decode_fetch
+            feed = self._step_feed('dec', *args)
+        elif which == 'verify':
+            prog, fetch = self._progs.verify, self._progs.verify_fetch
+            feed = self._step_feed('sv', *args)
+        else:
+            prog, fetch = self._progs.prefill, self._progs.prefill_fetch
+            prog.name = 'prefill_%d' % which
+            feed = self._prefill_feed(*args)
+        with self._arena_mu:
+            step_fn, scope_vals, feed_vals = self._exe.compile_step(
+                program=prog, feed=feed, fetch_list=[fetch],
+                scope=self._scope)
+            return jax.jit(step_fn, donate_argnums=(0,)).trace(
+                scope_vals, feed_vals, np.int32(0))
 
     def _bucket(self, n):
         for b in self.prompt_buckets:

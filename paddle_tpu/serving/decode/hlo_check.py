@@ -1,0 +1,156 @@
+"""Does a compiled paged program move an arena? Read from its HLO.
+
+The paged ops (ops/paged_decode_ops.py) write the KV arenas in place:
+in the program the compiler hands back, the only instruction that may
+touch arena-sized data is the attention gather. Whether that holds is
+a property of the *optimised* HLO (a layout the TPU picks, a scatter
+it re-lays its operand for, a loop output it double-buffers all show
+up there as ``copy`` instructions and nowhere in the jaxpr), so this
+module reads ``compiled.as_text()`` — on the CPU for a described chip,
+or on the chip itself (chip_smoke.py) — and needs no trace.
+
+``arena_sized_instructions(text, min_elements)`` lists the
+instructions that materialise ``min_elements`` or more (callers pass a
+layer's arena elements, NB * bs * H * D):
+
+- every ``copy`` (and ``copy-done``) of that size, wherever it is;
+- every other instruction of that size, except those that move
+  nothing (parameters, tuples and their elements, bitcasts, loops), an
+  in-place ``dynamic-update-slice`` (alone or as a fusion), the gather
+  itself, and what only consumes the gather's result: the attention's
+  own read, whose extent is the batch's page tables and not the pool.
+
+Instructions inside a fusion never reach memory and are skipped; a
+fusion counts by what it calls.
+"""
+
+import collections
+import re
+
+__all__ = ['Instruction', 'arena_sized_instructions']
+
+Instruction = collections.namedtuple(
+    'Instruction', ['name', 'opcode', 'shape', 'elements', 'computation'])
+
+# nothing is moved by these: they name, group or alias buffers
+_MOVES_NOTHING = frozenset([
+    'parameter', 'tuple', 'get-tuple-element', 'bitcast', 'constant',
+    'while', 'conditional', 'call', 'copy-start', 'opt-barrier'])
+
+_COMPUTATION = re.compile(
+    r'^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$')
+_ASSIGN = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$')
+_ARRAY = re.compile(r'\b[a-z]+[0-9]*[a-z0-9]*\[([0-9,]*)\]')
+_OPCODE = re.compile(r'^\s*([\w\-]+)\(')
+_OPERAND = re.compile(r'%?([A-Za-z_][\w.\-]*)')
+_CALLS = re.compile(r'\bcalls=%?([\w.\-]+)')
+
+
+def _balanced(s, start):
+    """Index just past the parenthesis that closes ``s[start]``."""
+    depth = 0
+    for i in range(start, len(s)):
+        if s[i] == '(':
+            depth += 1
+        elif s[i] == ')':
+            depth -= 1
+            if depth == 0:
+                return i + 1
+    return len(s)
+
+
+def _elements(shape):
+    """Largest array of a (possibly tuple) shape string."""
+    best = 0
+    for dims in _ARRAY.findall(shape):
+        n = 1
+        for d in dims.split(','):
+            if d:
+                n *= int(d)
+        best = max(best, n)
+    return best
+
+
+def _parse(text):
+    """{computation: [(name, opcode, shape, operands, callee)]} in
+    program order."""
+    comps = collections.OrderedDict()
+    cur = None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+            continue
+        if cur is None:
+            continue
+        if line.startswith('}'):
+            cur = None
+            continue
+        m = _ASSIGN.match(line)
+        if not m:
+            continue
+        name, rhs = m.groups()
+        end = _balanced(rhs, 0) if rhs.startswith('(') else \
+            (rhs.find(' ') if ' ' in rhs else len(rhs))
+        shape, rest = rhs[:end], rhs[end:]
+        op = _OPCODE.match(rest)
+        if not op:
+            continue
+        args_at = rest.index('(')
+        args_end = _balanced(rest, args_at)
+        operands = _OPERAND.findall(re.sub(
+            r'/\*.*?\*/', '', rest[args_at + 1:args_end - 1]))
+        callee = _CALLS.search(rest[args_end:])
+        cur.append((name, op.group(1), shape, operands,
+                    callee.group(1) if callee else None))
+    return comps
+
+
+def arena_sized_instructions(hlo_text, min_elements):
+    """The instructions of an optimised HLO module that copy or
+    re-lay ``min_elements`` or more outside the attention gather (see
+    the module docstring); empty when the arenas are written in
+    place. Returns ``Instruction`` tuples in program order."""
+    comps = _parse(hlo_text)
+    fused = {callee for body in comps.values()
+             for _, opcode, _, _, callee in body
+             if opcode == 'fusion' and callee}
+
+    def kind(opcode, callee):
+        """What a fusion is, by the instructions it calls."""
+        if opcode != 'fusion':
+            return opcode
+        inner = comps.get(callee, ())
+        if any(op == 'gather' for _, op, _, _, _ in inner):
+            return 'gather'
+        if any(op == 'dynamic-update-slice'
+               and _elements(shape) >= min_elements
+               for _, op, shape, _, _ in inner):
+            return 'dynamic-update-slice'
+        return 'fusion'
+
+    found = []
+    for cname, body in comps.items():
+        if cname in fused:
+            continue
+        size = {}
+        from_gather = set()
+        for name, opcode, shape, operands, callee in body:
+            n = size[name] = _elements(shape)
+            if n < min_elements:
+                continue
+            what = kind(opcode, callee)
+            if what == 'gather':
+                from_gather.add(name)
+                continue
+            if what in ('copy', 'copy-done'):
+                found.append(Instruction(name, opcode, shape, n, cname))
+                continue
+            if what in _MOVES_NOTHING or what == 'dynamic-update-slice':
+                continue
+            big = [o for o in operands if size.get(o, 0) >= min_elements]
+            if big and all(o in from_gather for o in big):
+                from_gather.add(name)
+                continue
+            found.append(Instruction(name, opcode, shape, n, cname))
+    return found

@@ -1,6 +1,6 @@
 """Paged KV-cache pool: host-side bookkeeping for the HBM page arena.
 
-The device side is a preallocated arena ``[L, NB, H, bs, D]`` (one
+The device side is a preallocated arena ``[L, NB, bs, H*D]`` (one
 fixed tensor per K and V, living in the engine's scope and updated in
 place through executor donation). This module owns the *map* of that
 arena: which physical pages are free, which sequence holds which pages

@@ -7,7 +7,7 @@ Scope, all sharing one parameter namespace (prefix ``lm_``):
   (models.transformer._stacked_layer_params layout, ENC_SLOTS — causal
   self-attention + FFN + 2 LNs per layer), token embedding, sinusoid
   position table, output projection, and the two zeroed KV page arenas
-  ``[L, NB, H, bs, d]``. Arenas are persistable scope state: every
+  ``[L, NB, bs, H*d]``. Arenas are persistable scope state: every
   prefill/decode run reads them from scope and writes them back
   through executor donation — in-place HBM updates, the same
   whole-program-state contract the trainer uses for params.
@@ -130,18 +130,23 @@ def _lm_params(spec, capacity):
 
 
 def _arenas(spec, num_blocks, block_size, kv_dtype='float32'):
-    """K/V page arenas at ``kv_dtype``; quantized dtypes (int8 / fp8)
-    additionally get per-(page, head, slot) fp32 scale arenas — one
+    """K/V page arenas ``[L, NB, bs, H*d]`` at ``kv_dtype``: token-major
+    inside a page, heads and head width merged into one lane-dense
+    minor axis, which is what lets the paged ops write a row in place
+    (ops/paged_decode_ops.py). Axes 0 and 1 are layer and page for
+    every arena — all that read_pages/write_pages and the handoff
+    index by. Quantized dtypes (int8 / fp8) additionally get
+    per-(page, slot, head) fp32 scale arenas ``[L, NB, bs, H]`` — one
     scale per written K/V row, so a page's stored bits are a pure
     function of the tokens written into it (the bit-consistency
     invariant) and prefix-cache sharing carries the scales for free
     (same physical page index)."""
     from ...quant.core import kv_quantized
     shapes = {
-        'lm_kcache': [spec.n_layer, num_blocks, spec.n_head, block_size,
-                      spec.d_key],
-        'lm_vcache': [spec.n_layer, num_blocks, spec.n_head, block_size,
-                      spec.d_value],
+        'lm_kcache': [spec.n_layer, num_blocks, block_size,
+                      spec.n_head * spec.d_key],
+        'lm_vcache': [spec.n_layer, num_blocks, block_size,
+                      spec.n_head * spec.d_value],
     }
     out = {}
     for name, shape in shapes.items():
@@ -151,7 +156,7 @@ def _arenas(spec, num_blocks, block_size, kv_dtype='float32'):
                            trainable=False))
     ks = vs = None
     if kv_quantized(kv_dtype):
-        sshape = [spec.n_layer, num_blocks, spec.n_head, block_size]
+        sshape = [spec.n_layer, num_blocks, block_size, spec.n_head]
         ks, vs = [layers.create_parameter(
             shape=sshape, dtype='float32', name=name,
             attr=ParamAttr(name=name, initializer=Constant(1.0),

@@ -263,8 +263,8 @@ def decide_paged_attention(b, p, h, bs, d, dv, dtype):
 
     def mk_inputs():
         q = jnp.ones((b, h, d), dtype)
-        kp = jnp.ones((b * p, h, bs, d), dtype)
-        vp = jnp.ones((b * p, h, bs, dv), dtype)
+        kp = jnp.ones((1, b * p, bs, h * d), dtype)
+        vp = jnp.ones((1, b * p, bs, h * dv), dtype)
         tables = jnp.arange(b * p, dtype=jnp.int32).reshape(b, p)
         lens = jnp.full((b,), p * bs - 1, jnp.int32)
         return q, kp, vp, tables, lens

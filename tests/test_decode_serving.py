@@ -146,19 +146,21 @@ def test_paged_attention_matches_dense_masked_reference():
     dense_v = rng.randn(b, h, p * bs, d).astype('f')
     q = rng.randn(b, h, d).astype('f')
 
-    # scatter the dense sequences into shuffled physical pages
-    k_pages = rng.randn(nb, h, bs, d).astype('f')  # garbage elsewhere
-    v_pages = rng.randn(nb, h, bs, d).astype('f')
+    # scatter the dense sequences into shuffled physical pages of a
+    # two-layer arena [L, NB, bs, H*D]; layer 0 stays garbage
+    k_pages = rng.randn(2, nb, bs, h * d).astype('f')  # garbage elsewhere
+    v_pages = rng.randn(2, nb, bs, h * d).astype('f')
     perm = rng.permutation(nb)[:b * p].reshape(b, p)
     for i in range(b):
         for j in range(p):
-            k_pages[perm[i, j]] = dense_k[i, :, j * bs:(j + 1) * bs, :]
-            v_pages[perm[i, j]] = dense_v[i, :, j * bs:(j + 1) * bs, :]
+            for pages, dense in ((k_pages, dense_k), (v_pages, dense_v)):
+                pages[1, perm[i, j]] = dense[i, :, j * bs:(j + 1) * bs] \
+                    .transpose(1, 0, 2).reshape(bs, h * d)
 
     got = paged_attention(jnp.asarray(q), jnp.asarray(k_pages),
                           jnp.asarray(v_pages),
                           jnp.asarray(perm, jnp.int32),
-                          jnp.asarray(lens))
+                          jnp.asarray(lens), layer=1)
     want = reference_attention(jnp.asarray(q)[:, :, None, :],
                                jnp.asarray(dense_k),
                                jnp.asarray(dense_v),

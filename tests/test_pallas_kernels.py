@@ -253,8 +253,9 @@ def test_batch_norm_ir_pallas_matches_default(monkeypatch):
 # ------------------------------------------------- ragged paged attention
 def _paged_case(b=3, h=2, nb=16, bs=8, p=4, d=16, seed=5):
     rng = np.random.RandomState(seed)
-    k_pages = jnp.asarray(rng.randn(nb, h, bs, d), jnp.float32)
-    v_pages = jnp.asarray(rng.randn(nb, h, bs, d), jnp.float32)
+    # the engine's arena shape [L, NB, bs, H*D], one layer
+    k_pages = jnp.asarray(rng.randn(1, nb, bs, h * d), jnp.float32)
+    v_pages = jnp.asarray(rng.randn(1, nb, bs, h * d), jnp.float32)
     q = jnp.asarray(rng.randn(b, h, d), jnp.float32)
     # distinct physical pages per sequence, deliberately out of order
     perm = rng.permutation(nb)[:b * p].reshape(b, p)
@@ -286,7 +287,7 @@ def test_paged_attention_kernel_ignores_unowned_pages(monkeypatch):
     base = np.asarray(paged_attention(q, kp, vp, tables, lens))
     # scribble over every table entry beyond the owned pages
     t2 = np.asarray(tables).copy()
-    nb, bs = kp.shape[0], kp.shape[2]
+    nb, bs = kp.shape[1], kp.shape[2]
     for i, n in enumerate(np.asarray(lens)):
         owned = (int(n) + bs - 1) // bs
         t2[i, owned:] = nb + 7

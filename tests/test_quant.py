@@ -473,19 +473,23 @@ def test_paged_attention_quantized_parity():
         paged_attention, paged_attention_reference)
     rng = np.random.RandomState(7)
     nb, h, bs, d = 6, 2, 4, 8
-    kf = rng.randn(nb, h, bs, d).astype('float32')
-    vf = rng.randn(nb, h, bs, d).astype('float32')
+    kf = rng.randn(1, nb, bs, h, d).astype('float32')   # per-head rows
+    vf = rng.randn(1, nb, bs, h, d).astype('float32')
+
+    def arena(x):                    # [L, NB, bs, H, D] -> [L, NB, bs, H*D]
+        return np.asarray(x).reshape(1, nb, bs, h * d)
     q = rng.randn(3, h, d).astype('float32')
     tables = np.array([[0, 1, 2, 6], [3, 4, 6, 6], [5, 6, 6, 6]],
                       'int32')
     lens = np.array([11, 8, 3], 'int32')
-    ref = np.asarray(paged_attention_reference(q, kf, vf, tables, lens))
+    ref = np.asarray(paged_attention_reference(q, arena(kf), arena(vf),
+                                               tables, lens))
     for dt in ('int8',) + \
             (('float8_e4m3fn',) if qcore.kv_fp8_supported() else ()):
         kq, ks = qcore.quantize_rows(jnp.asarray(kf), dt)
         vq, vs = qcore.quantize_rows(jnp.asarray(vf), dt)
         got = np.asarray(paged_attention(
-            q, np.asarray(kq), np.asarray(vq), tables, lens,
+            q, arena(kq), arena(vq), tables, lens,
             k_scales=np.asarray(ks), v_scales=np.asarray(vs)))
         cos = float((ref * got).sum() /
                     (np.linalg.norm(ref) * np.linalg.norm(got)))
