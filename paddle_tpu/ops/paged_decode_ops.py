@@ -1,6 +1,6 @@
 """Paged incremental-decode ops for the decode-serving engine.
 
-Three IR ops over a decoder-only (GPT-block) transformer whose KV
+Three IR ops over a decoder-only transformer whose KV
 cache lives in a paged pool (ops/pallas/paged_attention.py layouts):
 
 - ``paged_prefill`` — extend a sequence whose first ``Cached`` tokens
@@ -32,12 +32,18 @@ cache lives in a paged pool (ops/pallas/paged_attention.py layouts):
   many become real (rejected positions sit above the advanced
   ``cache_len`` and are overwritten before they can be read).
 
-Per-row math mirrors the incremental-decode path in
-transformer_ops.py (``_incremental_layer_scan``): the layer stack is
-one ``lax.scan`` over [L, ...]-stacked weights, residual+LN via
-``fused_layer_norm``.
+What differs between model families is a *block* object
+(``_PostLNBlock``: the 2017 decoder block, per-row math as in
+transformer_ops.py's ``_incremental_layer_scan``; ``_ParallelMoEBlock``:
+cohere2_moe, LMSpec block='parallel_moe'): embedding, the q/k/v
+projections, what follows attention, the per-layer lower bound on the
+columns a row sees, and the logits. Everything else — placement, the
+in-place arena writes, the one ``lax.scan`` over [L, ...]-stacked
+weights with the arenas as carry, the attention gather — is shared
+through ``_extend_rows``. A layer's kind (window, rotary) is scanned
+data beside the weights, never a second program.
 
-How the arenas travel: the stacked K/V arenas [L, NB, bs, H*D] (and
+How the arenas travel: the stacked K/V arenas [L, NB, bs, Hkv*D] (and
 the [L, NB, bs, H] scale arenas of the quantized dtypes) are the
 scan's CARRY, beside ``h``; only the weights and the layer index are
 scanned. A layer writes its new rows with ``dynamic_update_slice`` at
@@ -102,6 +108,167 @@ def _ln(h, p, slot):
 def _ffn(h, p):
     return jax.nn.relu(h @ p['ffn_w1'] + p['ffn_b1']) @ p['ffn_w2'] + \
         p['ffn_b2']
+
+
+def _stacked_weights(ctx, slots):
+    return {s: ctx.env[ctx.op.input(_slot_to_input(s))] for s in slots}
+
+
+class _PostLNBlock(object):
+    """The 2017 decoder block: embedding scaled by sqrt(d_model) plus a
+    position table, serial residual with LayerNorm after each sublayer,
+    ReLU FFN, an output table of its own. One KV head per query head."""
+
+    slots = LM_SLOTS
+    stats = False
+
+    def __init__(self, ctx):
+        self.emb = ctx.input('Emb')
+        self.pos_enc = ctx.input('PosEnc')
+        self.wout = ctx.input('OutProj')
+        self.n_head = ctx.attr('n_head', 1)
+        self.params = _stacked_weights(ctx, self.slots)
+
+    def embed(self, tokens, pos):
+        return jnp.take(self.emb, tokens, axis=0) * \
+            (self.emb.shape[-1] ** 0.5) + \
+            jnp.take(self.pos_enc, pos, axis=0, mode='clip')
+
+    def pre(self, h, p):
+        return h
+
+    def kv(self, n, p, pos):
+        # [N, H*dk]: a row as the arena holds it
+        return n @ p['slf_k'], n @ p['slf_v']
+
+    def q(self, n, p, pos):
+        return _split_heads(n @ p['slf_q'], self.n_head)
+
+    def lower_bound(self, p, pos):
+        return None
+
+    def finish(self, h, n, attn, p, valid):
+        h = _ln(h + attn.reshape(h.shape[0], -1).astype(h.dtype)
+                @ p['slf_o'], p, 'ln1')
+        return _ln(h + _ffn(h, p), p, 'ln2'), None
+
+    def logits(self, h):
+        return (h @ self.wout).astype(jnp.float32)
+
+
+# the parallel block's stacked weights, by op input slot
+MOE_SLOTS = ('ln_w', 'slf_q', 'slf_k', 'slf_v', 'slf_o', 'router',
+             'exp_gate', 'exp_up', 'exp_down',
+             'shr_gate', 'shr_up', 'shr_down')
+
+
+def _mm(x, w):
+    """x at the weight's dtype times w, accumulated in float32."""
+    return jnp.matmul(x.astype(w.dtype), w,
+                      preferred_element_type=jnp.float32)
+
+
+def _rope_gptj(x, pos, theta):
+    """x [N, heads, D] float32 at positions ``pos`` [N]: interleaved
+    pairs (2i, 2i+1) turned by pos * theta^(-2i/D)."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = pos.astype(jnp.float32)[:, None] * inv[None, :]
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    pairs = x.reshape(x.shape[:-1] + (d // 2, 2))
+    even, odd = pairs[..., 0], pairs[..., 1]
+    return jnp.stack([even * cos - odd * sin, even * sin + odd * cos],
+                     axis=-1).reshape(x.shape)
+
+
+class _ParallelMoEBlock(object):
+    """The cohere2_moe block (LMSpec block='parallel_moe'): one
+    bias-free LayerNorm feeding attention and the expert FFN side by
+    side, y = x + attn + experts; grouped KV heads; per layer a window
+    and a rotary flag (sliding layers rotate q and k and see the last
+    ``window`` keys, full layers carry no position and see all), both
+    scanned beside the weights, so one program serves either kind;
+    sigmoid top-k routing over every published expert with the ones
+    held here computed (ops/moe_held_ops.py) and the shared experts'
+    mean added; a tied embedding, unscaled, behind a final LayerNorm.
+    The residual stream, the norms' statistics, the router, the softmax
+    and the logits are float32; products take their operands at the
+    weights' dtype."""
+
+    slots = MOE_SLOTS
+    stats = True
+
+    def __init__(self, ctx):
+        self.emb = ctx.input('Emb')
+        self.final_ln = ctx.input('FinalLN')
+        self.n_head = ctx.attr('n_head', 1)
+        self.eps = float(ctx.attr('norm_eps', 1e-5))
+        self.theta = float(ctx.attr('rope_theta', 10000.0))
+        self.top_k = int(ctx.attr('top_k', 1))
+        self.first = int(ctx.attr('first_expert', 0))
+        self.logit_scale = float(ctx.attr('logit_scale', 1.0))
+        self.params = _stacked_weights(ctx, self.slots)
+        # the layer kind, scanned beside the weights
+        self.params['window'] = jnp.asarray(
+            [int(w) for w in ctx.attr('windows')], jnp.int32)
+        self.params['rotary'] = jnp.asarray(
+            [bool(r) for r in ctx.attr('rotary')], bool)
+
+    def _norm(self, x, gain):
+        mean = jnp.mean(x, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
+        return (x - mean) * jax.lax.rsqrt(var + self.eps) * \
+            gain.astype(jnp.float32)
+
+    def embed(self, tokens, pos):
+        return jnp.take(self.emb, tokens, axis=0).astype(jnp.float32)
+
+    def pre(self, h, p):
+        return self._norm(h, p['ln_w'])
+
+    def _turned(self, x, p, pos):
+        return jnp.where(p['rotary'], _rope_gptj(x, pos, self.theta), x)
+
+    def kv(self, n, p, pos):
+        k = _mm(n, p['slf_k'])
+        d = p['slf_q'].shape[-1] // self.n_head
+        k = self._turned(k.reshape(k.shape[0], -1, d), p, pos)
+        return k.reshape(k.shape[0], -1), _mm(n, p['slf_v'])
+
+    def q(self, n, p, pos):
+        return self._turned(_split_heads(_mm(n, p['slf_q']), self.n_head),
+                            p, pos)
+
+    def lower_bound(self, p, pos):
+        # a query at position pos sees keys pos - window < j <= pos
+        return jnp.where(p['window'] > 0,
+                         jnp.maximum(pos + 1 - p['window'], 0), 0)
+
+    def finish(self, h, n, attn, p, valid):
+        from . import moe_held_ops as moe
+        a = _mm(attn.reshape(h.shape[0], -1), p['slf_o'])
+        chosen, weight = moe.route_sigmoid_topk(n, p['router'], self.top_k)
+        gate, hit = moe.held_gates(chosen, weight, self.first,
+                                   p['exp_gate'].shape[0])
+        m = moe.gated_experts(n, gate, p['exp_gate'], p['exp_up'],
+                              p['exp_down'])
+        n_shared = p['shr_gate'].shape[0]
+        m += moe.gated_experts(
+            n, jnp.full((n.shape[0], n_shared), 1.0 / n_shared),
+            p['shr_gate'], p['shr_up'], p['shr_down'])
+        return h + a + m, moe.load_stats(hit, valid)
+
+    def logits(self, h):
+        y = self._norm(h, self.final_ln).astype(self.emb.dtype)
+        return jax.lax.dot_general(
+            y, self.emb, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * self.logit_scale
+
+
+def _block_of(ctx):
+    if ctx.attr('block', 'post_ln') == 'parallel_moe':
+        return _ParallelMoEBlock(ctx)
+    return _PostLNBlock(ctx)
 
 
 # Where an op's N new rows land in an arena [L, NB, bs, W], as n runs
@@ -195,16 +362,12 @@ def _sample_token(logits, seed, pos, temp):
 
 
 def _lm_inputs(ctx):
-    emb = ctx.input('Emb')
-    pos_enc = ctx.input('PosEnc')
-    wout = ctx.input('OutProj')
-    params = {s: ctx.env[ctx.op.input(_slot_to_input(s))]
-              for s in LM_SLOTS}
-    kc = ctx.input('KCache')            # [L, NB, bs, H*dk]
+    """(the op's block, K arena, V arena, K scales, V scales)."""
+    kc = ctx.input('KCache')            # [L, NB, bs, Hkv*dk]
     vc = ctx.input('VCache')
     ks = ctx.input('KScale') if ctx.has_input('KScale') else None
     vs = ctx.input('VScale') if ctx.has_input('VScale') else None
-    return emb, pos_enc, wout, params, kc, vc, ks, vs
+    return _block_of(ctx), kc, vc, ks, vs
 
 
 def _set_arena_outputs(ctx, kcs, vcs, kss, vss):
@@ -217,8 +380,7 @@ def _set_arena_outputs(ctx, kcs, vcs, kss, vss):
 
 @register('paged_decode_step')
 def _paged_decode_step(ctx):
-    emb, pos_enc, wout, params, kcs, vcs, kss, vss = _lm_inputs(ctx)
-    n_head = ctx.attr('n_head', 1)
+    block, kcs, vcs, kss, vss = _lm_inputs(ctx)
 
     tokens = ctx.input('Tokens').reshape(-1).astype(jnp.int32)     # [B]
     lens = ctx.input('SeqLens').reshape(-1).astype(jnp.int32)      # [B]
@@ -229,23 +391,31 @@ def _paged_decode_step(ctx):
     # one new token per row at position lens (empty slots feed all->NB
     # tables, so phys lands out of bounds and every write drops)
     place = _single_rows(tables, lens, kcs.shape[1], kcs.shape[2])
-    logits, kcs, vcs, kss, vss = _extend_rows(
-        emb, pos_enc, wout, params, kcs, vcs, n_head,
-        tokens, lens, tables, place, kss, vss)
-    nxt = jax.vmap(_sample_token)(logits, seeds, lens + 1, temps)
+    h, kcs, vcs, kss, vss, stats = _extend_rows(
+        block, kcs, vcs, tokens, lens, tables, place, kss, vss,
+        valid=place.ok[:, 0])
+    nxt = jax.vmap(_sample_token)(block.logits(h), seeds, lens + 1, temps)
     ctx.set_output('NextTokens',
                    nxt.astype(ctx.out_dtype('NextTokens', 'int64')))
+    if stats is not None:
+        ctx.set_output('MoeStats', stats)               # [L, 3] int32
     _set_arena_outputs(ctx, kcs, vcs, kss, vss)
 
 
-def _extend_rows(emb, pos_enc, wout, params, kcs, vcs, n_head,
-                 tokens, pos, tables, place, kscales=None, vscales=None):
+def _extend_rows(block, kcs, vcs, tokens, pos, tables, place,
+                 kscales=None, vscales=None, valid=None):
     """Shared core of all three ops: write N new tokens' K/V at
     absolute positions ``pos`` where ``place`` (a _Placement over the
     same rows) says, attend each row at its own ragged length
-    (``pos + 1``) through per-row block ``tables`` [N, P], and return
-    fp32 logits [N, V] plus the updated arenas. The arenas are carried
-    through the layer loop and written in place (module docstring).
+    (``pos + 1``) through per-row block ``tables`` [N, P] (or, for
+    consecutive rows of one sequence, its one table [P], gathered
+    once), and return the last hidden rows [N, D] plus the updated
+    arenas and the block's per-layer statistics (None where it keeps
+    none; ``valid`` [N] says which rows count). The arenas are carried
+    through the layer loop and written in place (module docstring);
+    ``block`` is what differs between model families: embedding, the
+    projections, what follows attention, and the per-layer bound on the
+    columns a row sees.
 
     Quantized arenas (``kscales``/``vscales`` [L, NB, bs, H] given):
     each new K/V row is quantized independently (one fp32 scale per
@@ -256,52 +426,58 @@ def _extend_rows(emb, pos_enc, wout, params, kcs, vcs, n_head,
     hits) stores identical bits for identical tokens — the
     concurrent == sequential invariant survives at int8/fp8."""
     from ..quant.core import quantize_rows
-    from .pallas.paged_attention import paged_attention
+    from .pallas.paged_attention import (paged_attention,
+                                         paged_attention_one_table)
     n = tokens.shape[0]
-    d_model = emb.shape[-1]
     kv_q = _arena_kv_dtype(kcs)
     quantized = kv_q is not None
 
-    x = jnp.take(emb, tokens, axis=0) * (d_model ** 0.5) + \
-        jnp.take(pos_enc, pos, axis=0, mode='clip')
+    x = block.embed(tokens, pos)
     att_lens = pos + 1
 
     def body(carry, sl):
         h, arenas = carry
         p, layer = sl
-        k_new = h @ p['slf_k']       # [N, H*dk]: a row as the arena holds it
-        v_new = h @ p['slf_v']
+        nrm = block.pre(h, p)
+        k_new, v_new = block.kv(nrm, p, pos)
         if quantized:
+            n_head = block.n_head
             kq, ks_row = quantize_rows(_split_heads(k_new, n_head), kv_q)
             vq, vs_row = quantize_rows(_split_heads(v_new, n_head), kv_q)
             rows = (kq.reshape(n, -1), vq.reshape(n, -1), ks_row, vs_row)
         else:
             rows = (k_new.astype(kcs.dtype), v_new.astype(vcs.dtype))
         arenas = _write_in_place(arenas, rows, layer, place)
-        q = _split_heads(h @ p['slf_q'], n_head)
-        attn = paged_attention(q, arenas[0], arenas[1], tables, att_lens,
-                               k_scales=arenas[2] if quantized else None,
-                               v_scales=arenas[3] if quantized else None,
-                               layer=layer)
-        h = _ln(h + attn.reshape(n, -1).astype(h.dtype)
-                @ p['slf_o'], p, 'ln1')
-        h = _ln(h + _ffn(h, p), p, 'ln2')
-        return (h, arenas), None
+        q = block.q(nrm, p, pos)
+        lo = block.lower_bound(p, pos)
+        if tables.ndim == 1:
+            attn = paged_attention_one_table(
+                q, arenas[0], arenas[1], tables,
+                jnp.zeros_like(pos) if lo is None else lo, att_lens,
+                layer=layer)
+        else:
+            attn = paged_attention(
+                q, arenas[0], arenas[1], tables, att_lens,
+                k_scales=arenas[2] if quantized else None,
+                v_scales=arenas[3] if quantized else None,
+                layer=layer, lo=lo)
+        h, stats = block.finish(h, nrm, attn, p, valid)
+        return (h, arenas), stats
 
     arenas = (kcs, vcs, kscales, vscales) if quantized else (kcs, vcs)
     layers = jnp.arange(kcs.shape[0], dtype=jnp.int32)
-    (h, arenas), _ = jax.lax.scan(body, (x, arenas), (params, layers))
+    (h, arenas), stats = jax.lax.scan(body, (x, arenas),
+                                      (block.params, layers))
     if quantized:
         kcs, vcs, kscales, vscales = arenas
     else:
         kcs, vcs = arenas
-    return (h @ wout).astype(jnp.float32), kcs, vcs, kscales, vscales
+    return h, kcs, vcs, kscales, vscales, stats
 
 
 @register('paged_prefill')
 def _paged_prefill(ctx):
-    emb, pos_enc, wout, params, kcs, vcs, kss, vss = _lm_inputs(ctx)
-    n_head = ctx.attr('n_head', 1)
+    block, kcs, vcs, kss, vss = _lm_inputs(ctx)
 
     ids = ctx.input('Ids').reshape(-1).astype(jnp.int32)   # [S] (padded)
     length = ctx.input('Len').reshape(()).astype(jnp.int32)
@@ -315,15 +491,23 @@ def _paged_prefill(ctx):
     # query attends to everything at or below it — the cached pages
     # plus this step's own earlier writes — through the table gather
     pos = cached + jnp.arange(s, dtype=jnp.int32)
-    tables = jnp.broadcast_to(table, (s, table.shape[0]))
     place = _page_runs(table, cached, length, s, kcs.shape[1],
                        kcs.shape[2])
-    logits, kcs, vcs, kss, vss = _extend_rows(
-        emb, pos_enc, wout, params, kcs, vcs, n_head,
-        ids, pos, tables, place, kss, vss)
-
-    logits_last = jax.lax.dynamic_index_in_dim(
-        logits, jnp.maximum(length - 1, 0), keepdims=False)     # [V]
+    last = jnp.maximum(length - 1, 0)
+    if ctx.attr('one_table', False):
+        # the sequence's pages gathered once for the whole chunk, and
+        # the one row that is sampled projected onto the vocabulary
+        h, kcs, vcs, kss, vss, _ = _extend_rows(
+            block, kcs, vcs, ids, pos, table, place, kss, vss,
+            valid=jnp.arange(s) < length)
+        logits_last = block.logits(jax.lax.dynamic_slice_in_dim(
+            h, last, 1))[0]                                     # [V]
+    else:
+        tables = jnp.broadcast_to(table, (s, table.shape[0]))
+        h, kcs, vcs, kss, vss, _ = _extend_rows(
+            block, kcs, vcs, ids, pos, tables, place, kss, vss)
+        logits_last = jax.lax.dynamic_index_in_dim(
+            block.logits(h), last, keepdims=False)              # [V]
     nxt = _sample_token(logits_last, seed, cached + length, temp)
     ctx.set_output('NextToken',
                    nxt.reshape(1).astype(ctx.out_dtype('NextToken',
@@ -333,8 +517,7 @@ def _paged_prefill(ctx):
 
 @register('paged_spec_verify')
 def _paged_spec_verify(ctx):
-    emb, pos_enc, wout, params, kcs, vcs, kss, vss = _lm_inputs(ctx)
-    n_head = ctx.attr('n_head', 1)
+    block, kcs, vcs, kss, vss = _lm_inputs(ctx)
 
     tokens = ctx.input('Tokens').astype(jnp.int32)         # [B, K1]
     lens = ctx.input('SeqLens').reshape(-1).astype(jnp.int32)   # [B]
@@ -352,12 +535,13 @@ def _paged_spec_verify(ctx):
     pos = (lens[:, None] + j[None, :]).reshape(-1)         # [B*K1]
     tables_rep = jnp.repeat(tables, k1, axis=0)            # [B*K1, P]
     place = _single_rows(tables_rep, pos, kcs.shape[1], kcs.shape[2])
-    logits, kcs, vcs, kss, vss = _extend_rows(
-        emb, pos_enc, wout, params, kcs, vcs, n_head,
-        tokens.reshape(-1), pos, tables_rep, place, kss, vss)
+    h, kcs, vcs, kss, vss, _ = _extend_rows(
+        block, kcs, vcs, tokens.reshape(-1), pos, tables_rep, place,
+        kss, vss)
 
     nxt = jax.vmap(_sample_token)(
-        logits, jnp.repeat(seeds, k1), pos + 1, jnp.repeat(temps, k1))
+        block.logits(h), jnp.repeat(seeds, k1), pos + 1,
+        jnp.repeat(temps, k1))
     ctx.set_output('NextTokens',
                    nxt.reshape(b, k1).astype(
                        ctx.out_dtype('NextTokens', 'int64')))

@@ -74,7 +74,7 @@ def _gather_pages(arena, layer, tables, n_head):
 
 def paged_attention_reference(q, k_pages, v_pages, block_tables, seq_lens,
                               sm_scale=None, k_scales=None,
-                              v_scales=None, layer=0):
+                              v_scales=None, layer=0, lo=None):
     """XLA gather path. Bit-stable contract with the Pallas kernel's
     masking: columns >= seq_lens[b] contribute exactly 0 (exp of a
     large-negative underflows), so the result is independent of the
@@ -84,7 +84,16 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, seq_lens,
     one fp32 scale per stored (page, slot, head) K/V row; the gather
     dequantizes to fp32 through the same table indices before the
     attention math (fp32 accumulation — int8/fp8 only ever live in
-    HBM)."""
+    HBM).
+
+    Grouped heads: where the arena's row holds fewer heads than ``q``
+    has (row width = Hkv * D), query head h reads KV head
+    h // (H / Hkv). ``lo`` [B] int32 is a lower bound on the columns a
+    row sees (a sliding window: columns < lo[b] contribute exactly 0);
+    None sees every column below ``seq_lens``."""
+    if k_pages.shape[-1] != q.shape[1] * q.shape[2]:
+        return _grouped_reference(q, k_pages, v_pages, block_tables,
+                                  seq_lens, sm_scale, layer, lo)
     nb, bs = k_pages.shape[1], k_pages.shape[2]
     b, p = block_tables.shape
     h, d = q.shape[1], q.shape[2]
@@ -98,10 +107,117 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, seq_lens,
         v = v.astype(jnp.float32) * _gather_pages(v_scales, layer,
                                                   tables, h)
     logits = jnp.einsum('bhd,bkhd->bhk', (q * scale), k)
-    mask = jnp.arange(p * bs)[None, :] < seq_lens.reshape(-1, 1)
+    mask = _seen(p * bs, lo, seq_lens)
     logits = jnp.where(mask[:, None, :], logits, _NEG_INF)
     w = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     return jnp.einsum('bhk,bkhd->bhd', w.astype(v.dtype), v)
+
+
+def _seen(n_cols, lo, hi):
+    """[B, n_cols] bool: columns lo[b] <= j < hi[b] (lo None: 0)."""
+    cols = jnp.arange(n_cols)[None, :]
+    mask = cols < hi.reshape(-1, 1)
+    if lo is not None:
+        mask &= cols >= lo.reshape(-1, 1)
+    return mask
+
+
+def _grouped_reference(q, k_pages, v_pages, block_tables, seq_lens,
+                       sm_scale, layer, lo):
+    """The gather path for grouped KV heads (unquantized arenas): q
+    [B, H, D] against rows of Hkv * D, scores and softmax in float32."""
+    nb, bs = k_pages.shape[1], k_pages.shape[2]
+    b, p = block_tables.shape
+    h, d = q.shape[1], q.shape[2]
+    n_kv = k_pages.shape[-1] // d
+    scale = sm_scale if sm_scale is not None else d ** -0.5
+    tables = jnp.clip(block_tables.astype(jnp.int32), 0, nb - 1)
+    k = k_pages[layer, tables].reshape(b, p * bs, n_kv * d)
+    v = v_pages[layer, tables].reshape(b, p * bs, n_kv * d)
+    qg = (q * scale).astype(k.dtype).reshape(b, n_kv, h // n_kv, d)
+    mask = _seen(p * bs, lo, seq_lens)[:, None, :]
+    out = []
+    # KV head by KV head on slices of the gathered rows' minor axis: a
+    # head is a whole number of lane tiles there (D = 128), so a slice
+    # feeds the product as it lies; splitting the axis into [Hkv, D]
+    # and batching over Hkv had the compiler re-lay the gathered pages
+    # head-major first (0.4 GB each for K and V a layer at 32 x 6,656
+    # tokens; v5e compile, PR 28)
+    for n in range(n_kv):
+        kn = k[:, :, n * d:(n + 1) * d]
+        vn = v[:, :, n * d:(n + 1) * d]
+        logits = jnp.einsum('bgd,bkd->bgk', qg[:, n], kn,
+                            preferred_element_type=jnp.float32)
+        w = jax.nn.softmax(jnp.where(mask, logits, _NEG_INF), axis=-1)
+        out.append(jnp.einsum('bgk,bkd->bgd', w.astype(vn.dtype), vn,
+                              preferred_element_type=jnp.float32))
+    return jnp.stack(out, axis=1).reshape(b, h, d)
+
+
+def paged_attention_one_table(q, k_pages, v_pages, table, lo, hi,
+                              sm_scale=None, layer=0, block_cols=512):
+    """Consecutive rows of ONE sequence (a prefill chunk) against that
+    sequence's pages, gathered once: q [S, H, D], ``table`` [P], row s
+    sees columns lo[s] <= j < hi[s]. The per-row form above gathers
+    the table once per row ([S, P, bs, W]), which is what kept prompts
+    at 512 (benchmark/configs/tbig_lm.json); here the gather is [P, bs,
+    W] whatever S is.
+
+    The columns go in blocks of whole pages (about ``block_cols``), from
+    the block that holds the smallest ``lo`` to the one that holds the
+    largest ``hi`` and no further, under a running softmax (maximum,
+    normaliser and weighted sum carried from block to block, float32):
+    a chunk at the start of a prompt multiplies one block and not the
+    table's whole extent, a chunk deep in a sliding layer its window's
+    blocks, and the scores alive at a time are [H, S, block], not
+    [H, S, P * bs]. Which blocks run depends on the chunk's place in
+    its own sequence only, so a row's result does not depend on what
+    else the engine holds. Grouped heads as in
+    ``paged_attention_reference``; unquantized arenas only."""
+    nb, bs = k_pages.shape[1], k_pages.shape[2]
+    s, h, d = q.shape
+    n_kv = k_pages.shape[-1] // d
+    group = h // n_kv
+    scale = sm_scale if sm_scale is not None else d ** -0.5
+    table = jnp.clip(table.astype(jnp.int32), 0, nb - 1)
+    n_pages = table.shape[0]
+    cols = n_pages * bs
+    # whole pages a block, a divisor of the table so every block is full
+    per_block = max(p for p in range(1, n_pages + 1)
+                    if n_pages % p == 0 and p * bs <= max(block_cols, bs))
+    bk = per_block * bs
+    k = k_pages[layer, table].reshape(cols, n_kv, d)
+    v = v_pages[layer, table].reshape(cols, n_kv, d)
+    qg = jnp.transpose((q * scale).astype(k.dtype).reshape(
+        s, n_kv, group, d), (1, 2, 0, 3))                  # [Hkv, G, S, D]
+    first = jnp.clip(jnp.min(lo) // bk, 0, cols // bk - 1)
+    last = jnp.clip((jnp.max(hi) - 1) // bk, 0, cols // bk - 1)
+
+    def block(j, state):
+        top, norm, acc = state
+        kb = jax.lax.dynamic_slice_in_dim(k, j * bk, bk, 0)  # [bk, Hkv, D]
+        vb = jax.lax.dynamic_slice_in_dim(v, j * bk, bk, 0)
+        at = j * bk + jnp.arange(bk)
+        seen = (at[None, :] >= lo[:, None]) & (at[None, :] < hi[:, None])
+        scores = jnp.einsum('ngsd,knd->ngsk', qg, kb,
+                            preferred_element_type=jnp.float32)
+        scores = jnp.where(seen[None, None], scores, _NEG_INF)
+        new_top = jnp.maximum(top, jnp.max(scores, axis=-1))
+        w = jnp.where(seen[None, None],
+                      jnp.exp(scores - new_top[..., None]), 0.0)
+        keep = jnp.exp(top - new_top)
+        norm = keep * norm + jnp.sum(w, axis=-1)
+        acc = keep[..., None] * acc + jnp.einsum(
+            'ngsk,knd->ngsd', w.astype(vb.dtype), vb,
+            preferred_element_type=jnp.float32)
+        return new_top, norm, acc
+
+    init = (jnp.full((n_kv, group, s), _NEG_INF, jnp.float32),
+            jnp.zeros((n_kv, group, s), jnp.float32),
+            jnp.zeros((n_kv, group, s, d), jnp.float32))
+    _, norm, acc = jax.lax.fori_loop(first, last + 1, block, init)
+    out = acc / jnp.where(norm == 0.0, 1.0, norm)[..., None]
+    return jnp.transpose(out, (2, 0, 1, 3)).reshape(s, h, d)
 
 
 def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -238,22 +354,27 @@ def _use_pallas(q, k_pages, v_pages, block_tables):
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
-                    sm_scale=None, k_scales=None, v_scales=None, layer=0):
+                    sm_scale=None, k_scales=None, v_scales=None, layer=0,
+                    lo=None):
     """Ragged paged attention: one query per sequence against its paged
     KV cache. q [B, H, D]; pages [L, NB, bs, H*D*] read at ``layer``
     (a traced scalar inside the decode ops' layer loop); block_tables
     [B, P] int32 (entries >= NB mean "no page" and are never read);
-    seq_lens [B] int32. Quantized arenas pass their per-row fp32 scale
+    seq_lens [B] int32. Grouped KV heads (rows of Hkv * D) and a lower
+    column bound ``lo`` [B] (a sliding window) take the gather path.
+    Quantized arenas pass their per-row fp32 scale
     arenas as ``k_scales``/``v_scales`` [L, NB, bs, H] and take the
     gather path (which dequantizes inline; the Pallas kernel stays
     fp32/bf16). Returns [B, H, Dv]."""
     d = q.shape[2]
     scale = sm_scale if sm_scale is not None else d ** -0.5
-    if k_scales is None and str(k_pages.dtype) in ('float32', 'bfloat16') \
+    plain = lo is None and k_pages.shape[-1] == q.shape[1] * d
+    if plain and k_scales is None \
+            and str(k_pages.dtype) in ('float32', 'bfloat16') \
             and _use_pallas(q, k_pages, v_pages, block_tables):
         return _paged_pallas(q, k_pages, v_pages, block_tables, seq_lens,
                              scale, layer=layer)
     return paged_attention_reference(q, k_pages, v_pages, block_tables,
                                      seq_lens, sm_scale=scale,
                                      k_scales=k_scales,
-                                     v_scales=v_scales, layer=layer)
+                                     v_scales=v_scales, layer=layer, lo=lo)

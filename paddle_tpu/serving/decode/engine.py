@@ -36,12 +36,21 @@ workload — over a decoder-only LM with a paged KV cache:
   scores every proposal; longest-accepted-prefix acceptance emits
   up to k+1 tokens per step, bit-identical to plain decode.
 
+- **block families** (model.LMSpec ``block``): the 2017 post-LN block
+  and the parallel routed-expert block (grouped KV heads, sliding and
+  full layers in one cache, the experts held here of those the router
+  scores) run through this same engine; for the latter the prefix
+  cache, speculation and quantized arenas raise rather than run
+  untested. A prefix longer than the top prompt bucket is prefilled in
+  chunks of it (``prefill_chunk``).
+
 Per-row device math is batch-composition-independent, so each
 request's token stream is bit-identical to running it alone —
 continuous batching, prefix caching, and speculation are pure
 throughput wins, never a correctness trade.
 """
 
+import contextlib
 import itertools
 import threading
 import time
@@ -97,7 +106,8 @@ class DecodeEngine(object):
     def __init__(self, spec, max_batch=8, block_size=16, num_blocks=64,
                  pages_per_seq=8, max_queue_depth=64, max_prompt_len=None,
                  place=None, weights=None, prefix_cache=None, spec_k=None,
-                 draft=None, kv_dtype=None, name=None):
+                 draft=None, kv_dtype=None, name=None, prefill_chunk=None,
+                 min_prompt_bucket=1):
         from ...quant.core import resolve_kv_dtype
         from .model import kv_bytes_per_token
         self.spec = spec
@@ -119,6 +129,11 @@ class DecodeEngine(object):
         # unquantized engine; int8/fp8 halve-to-quarter bytes/token,
         # which is more resident sequences per chip at equal HBM).
         self.prefix_cache_on = prefix_cache_enabled(prefix_cache)
+        if self.prefix_cache_on and spec.block == 'parallel_moe':
+            # shared pages under a window have no test against this
+            # block's reference yet
+            raise NotImplementedError(
+                "block='parallel_moe' runs without the prefix cache")
         self.spec_k = spec_k_from_env(spec_k)
         self.kv_dtype = resolve_kv_dtype(kv_dtype)
         self.kv_bytes_per_token = kv_bytes_per_token(spec, self.kv_dtype)
@@ -156,7 +171,14 @@ class DecodeEngine(object):
         self.capacity = self._progs.capacity
         self.max_prompt_len = int(max_prompt_len) if max_prompt_len \
             else self.capacity - 1
-        self.prompt_buckets = pow2_ladder(self.max_prompt_len)
+        # a prefix longer than the top bucket is prefilled in chunks of
+        # it; ``min_prompt_bucket`` drops the rungs below it (fewer
+        # programs to compile, a short tail padded further)
+        self.prefill_chunk = min(int(prefill_chunk or self.max_prompt_len),
+                                 self.max_prompt_len)
+        self.prompt_buckets = pow2_ladder(
+            self.prefill_chunk,
+            min(int(min_prompt_bucket), self.prefill_chunk))
 
         self._scope = Scope()
         self._exe = Executor(place if place is not None else TPUPlace(0))
@@ -200,6 +222,7 @@ class DecodeEngine(object):
         self._thread = None
         self._health_name = None
         self._step_no = 0
+        self._step_stats = None
         self.warmup_signatures = 0
         self.warmup_total_seconds = 0.0
         self.warmup_aot_load_seconds = 0.0
@@ -207,16 +230,30 @@ class DecodeEngine(object):
     # ----------------------------------------------------------- weights
     def load_weights(self, weights):
         """Install a {param name: array} dict (names per
-        model.DecodePrograms.param_names)."""
+        model.DecodePrograms.param_names). Each parameter keeps the
+        dtype it was declared with; a jax array is cast on its device
+        and never copied through the host."""
+        import jax
+        import jax.numpy as jnp
         unknown = sorted(set(weights) - set(self._progs.param_names))
         if unknown:
             raise ValueError('unknown param names %s (expected a subset '
                              'of %s)' % (unknown, self._progs.param_names))
         for name, arr in weights.items():
-            self._scope.set(name, np.asarray(arr, dtype='float32'))
+            dtype = self._scope.get(name).dtype
+            if isinstance(arr, jax.Array):
+                self._scope.set(name, arr.astype(dtype))
+            else:
+                self._scope.set(name, jnp.asarray(np.asarray(arr), dtype))
 
     def export_weights(self):
         return {n: self._scope.numpy(n) for n in self._progs.param_names}
+
+    def device_weights(self):
+        """{param name: the array the programs read}, where it lives:
+        nothing is copied. The arrays are replaced, not written, by
+        ``load_weights``."""
+        return {n: self._scope.get(n) for n in self._progs.param_names}
 
     # ------------------------------------------------------------ intake
     def submit(self, prompt_ids, max_new_tokens=16, temperature=0.0,
@@ -329,6 +366,7 @@ class DecodeEngine(object):
         s = self.spec
         return {
             'n_layer': s.n_layer, 'n_head': s.n_head,
+            'n_kv_head': s.n_kv_head,
             'd_key': s.d_key, 'd_value': s.d_value,
             'block_size': self.block_size, 'kv_dtype': self.kv_dtype,
             'arena_names': tuple(self._progs.arena_names),
@@ -689,7 +727,11 @@ class DecodeEngine(object):
         return (np.zeros((1, int(which)), 'int64'), 1, 0,
                 np.full((1, pps), nb, 'int32'), 0.0, 0)
 
-    def _run_prefill(self, ids, length, cached, table, temp, seed):
+    def _run_prefill(self, ids, length, cached, table, temp, seed,
+                     wait=True):
+        """One prefill dispatch. ``wait=False`` (a chunk that is not the
+        prefix's last) leaves the sampled token on the device unread, so
+        the next chunk is enqueued behind it without a round trip."""
         # one Program, one XLA module per bucket: the name is read when a
         # bucket's signature compiles
         self._progs.prefill.name = 'prefill_%d' % ids.shape[1]
@@ -698,8 +740,9 @@ class DecodeEngine(object):
                 program=self._progs.prefill,
                 feed=self._prefill_feed(ids, length, cached, table, temp,
                                         seed),
-                fetch_list=[self._progs.prefill_fetch])
-        return int(np.asarray(out[0]).reshape(-1)[0])
+                fetch_list=[self._progs.prefill_fetch],
+                return_numpy=wait)
+        return int(np.asarray(out[0]).reshape(-1)[0]) if wait else None
 
     def _dispatch_verify(self, tokens, lens, tables, temps, seeds):
         """Enqueue one spec-verify step; its fetch stays on the device."""
@@ -712,14 +755,20 @@ class DecodeEngine(object):
                 return_numpy=False)[0]
 
     def _dispatch_decode(self, tokens, lens, tables, temps, seeds):
-        """Enqueue one decode step; its fetch stays on the device."""
+        """Enqueue one decode step; its fetch stays on the device. A
+        block that keeps router statistics hands them back beside the
+        tokens; they are left in ``_step_stats`` for the step's emit."""
+        fetch = [self._progs.decode_fetch]
+        if self._progs.stats_fetch is not None:
+            fetch.append(self._progs.stats_fetch)
         with self._arena_mu, scope_guard(self._scope):
-            return self._exe.run(
+            out = self._exe.run(
                 program=self._progs.decode,
                 feed=self._step_feed('dec', tokens, lens, tables, temps,
                                      seeds),
-                fetch_list=[self._progs.decode_fetch],
-                return_numpy=False)[0]
+                fetch_list=fetch, return_numpy=False)
+        self._step_stats = out[1] if len(out) > 1 else None
+        return out[0]
 
     def trace_program(self, which):
         """jax's ``Traced`` (``.jaxpr``, ``.lower().compile()``) for one
@@ -767,23 +816,40 @@ class DecodeEngine(object):
         prefix on a cache miss, only the tokens past the matched span
         on a hit (the hit's pages are already mapped in the block
         table; the suffix bucket, not the prompt bucket, sets the
-        dispatch cost — that is the TTFT win)."""
+        dispatch cost — that is the TTFT win). A suffix longer than the
+        top bucket goes in chunks of it, back to back, each one
+        dispatch of the bucket's program with ``pf_cached`` at the
+        chunk's start; only the last chunk's token is read."""
         with _obs.span('decode.prefill.build'):
             prefix = seq.prefix()
             s = len(prefix)
             cached = seq.cached_len
-            suffix = prefix[cached:]
-            bucket = self._bucket(len(suffix))
-            ids = np.zeros((1, bucket), 'int64')
-            ids[0, :len(suffix)] = suffix
+            top = self.prefill_chunk
+            starts = list(range(cached, s, top))
             table = self._table_row(seq)[None, :]
-        with _obs.span('decode.prefill.run', bucket=bucket):
+            # the largest program this prefill runs (its first chunk's):
+            # the label of its span, its time and its trace stage
+            bucket = self._bucket(min(top, s - cached))
+        with _obs.span('decode.prefill.run', bucket=bucket,
+                       chunks=len(starts)):
             t0 = time.perf_counter()
-            tok = self._run_prefill(ids, len(suffix), cached, table,
-                                    seq.temperature, seq.seed)
+            for start in starts:
+                piece = prefix[start:start + top]
+                rung = self._bucket(len(piece))
+                ids = np.zeros((1, rung), 'int64')
+                ids[0, :len(piece)] = piece
+                # a prefix of one chunk is the span above and no more
+                chunk_span = _obs.span(
+                    'decode.prefill.chunk', bucket=rung, start=start) \
+                    if len(starts) > 1 else contextlib.nullcontext()
+                with chunk_span:
+                    tok = self._run_prefill(
+                        ids, len(piece), start, table, seq.temperature,
+                        seq.seed, wait=start == starts[-1])
             t1 = time.perf_counter()
         _obs.record('decode.prefill_seconds', t1 - t0, bucket=bucket)
         _obs.inc('decode.prefills_total')
+        _obs.inc('decode.prefill_chunks', len(starts))
         with _obs.span('decode.prefill.emit'):
             if cached:
                 _obs.flight_event('decode_prefix_hit',
@@ -791,7 +857,8 @@ class DecodeEngine(object):
                                   cached_tokens=cached, prefix_tokens=s)
             if seq.ctx is not None and seq.ctx.sampled:
                 seq.ctx.stage('prefill', t0, t1, bucket=bucket,
-                              prefix_tokens=s, cached_tokens=cached)
+                              prefix_tokens=s, cached_tokens=cached,
+                              chunks=len(starts))
             seq.cache_len = s
             self._maybe_publish(seq)
             self._emit(seq, tok, time.perf_counter())
@@ -830,6 +897,15 @@ class DecodeEngine(object):
         if _obs.enabled():
             # the KV positions this step attends over
             _obs.record('decode.step_live_tokens', int(lens.sum()))
+            _obs.inc('decode.step_rows', len(batch))
+            window = self.spec.sliding_window
+            if window:
+                # rows whose sliding layers no longer see their first
+                # keys, and the positions such a layer attends over
+                _obs.inc('decode.step_window_rows',
+                         int((lens[:len(batch)] >= window).sum()))
+                _obs.record('decode.step_window_tokens',
+                            int(np.minimum(lens, window).sum()))
         return lens, tables, temps, seeds
 
     def _timed_step(self, dispatch, tokens, feeds, rows):
@@ -872,6 +948,8 @@ class DecodeEngine(object):
         nxt = nxt.reshape(-1)
         with _obs.span('decode.step.emit',
                        record='decode.step_emit_seconds'):
+            if self._step_stats is not None and _obs.enabled():
+                self._record_moe(np.asarray(self._step_stats), len(batch))
             for i, seq in enumerate(batch):
                 seq.cache_len += 1
                 self._maybe_publish(seq)
@@ -879,6 +957,24 @@ class DecodeEngine(object):
                 reason = seq.finished()
                 if reason:
                     self._finish(seq, reason)
+
+    def _record_moe(self, stats, rows):
+        """One decode step's router statistics ([n_layer, 3]: choices
+        that landed on an expert held here, rows on the busiest of
+        them, experts any row chose) into the counters the benchmark
+        reads: of rows x experts_per_token choices a layer, the local
+        ones; the busiest expert's load against the mean, per
+        layer-step."""
+        held = self.spec.experts_held
+        _obs.inc('decode.moe_assignments',
+                 rows * self.spec.experts_per_token * len(stats))
+        _obs.inc('decode.moe_local_assignments', int(stats[:, 0].sum()))
+        _obs.inc('decode.moe_experts_touched', int(stats[:, 2].sum()))
+        _obs.inc('decode.moe_layer_steps', len(stats))
+        for local, busiest, _ in stats:
+            if local:
+                _obs.record('decode.moe_load_max_over_mean',
+                            busiest * held / float(local))
 
     def _spec_step(self):
         """Draft-and-verify decode: the draft proposes up to k tokens

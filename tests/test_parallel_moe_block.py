@@ -1,0 +1,510 @@
+"""The parallel_moe block (LMSpec block='parallel_moe': cohere2_moe)
+against its plain reference, at a tiny size on the CPU in float32:
+window 8, 4 query heads over 2 KV heads, 8 experts of which 4 are held,
+3 per token, 2 shared, three sliding layers and a full one.
+
+The comparisons are of logits, not tokens. Tolerance: both sides are
+float32 on the CPU; they differ in the order of their sums (the block
+sums the experts inside one product and pads its attention to the page
+table's extent, the reference loops), which at these widths gives
+differences of a few 1e-6 on logits of order 1. 2e-5 leaves a margin of
+about five and is three orders under what a wrong mask, a missing
+rotation or a misrouted expert gives (1e-2 and more, checked below by
+breaking each)."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu as fluid
+from paddle_tpu.models.reference import command_a_plus as ref
+from paddle_tpu.ops import moe_held_ops as moe
+from paddle_tpu.ops import paged_decode_ops as pdo
+from paddle_tpu.serving.decode import DecodeEngine, LMSpec, random_weights
+from paddle_tpu.serving.decode.model import (arena_bytes,
+                                             kv_bytes_per_token,
+                                             moe_param_shapes)
+
+TOL = 2e-5
+BS, PAGES, NB = 4, 10, 24            # 40 positions a sequence
+
+
+def _spec(**over):
+    kw = dict(vocab_size=64, n_layer=4, n_head=4, n_kv_head=2, d_key=8,
+              d_value=8, d_model=16, d_inner=24, block='parallel_moe',
+              layer_types=['sliding_attention'] * 3 + ['full_attention'],
+              sliding_window=8, rope_theta=50000.0, n_experts=8,
+              experts_held=4, first_expert=2, experts_per_token=3,
+              n_shared_experts=2)
+    kw.update(over)
+    return LMSpec(**kw)
+
+
+SPEC = _spec()
+WEIGHTS = random_weights(SPEC, seed=5)
+
+
+_arch, _held = ref.arch_of, ref.held_of
+
+
+class _Op(object):
+    def __init__(self, slots):
+        self._slots = slots
+
+    def input(self, slot):
+        return self._slots[slot]
+
+
+class _Ctx(object):
+    """What a paged op's lowering reads of its context, for driving the
+    block's row function without a Program."""
+
+    def __init__(self, spec, weights):
+        from paddle_tpu.serving.decode.model import _block_attrs
+        self._attrs = _block_attrs(spec, BS)
+        self.env = {}
+        slots = {}
+        for name, (_, _, slot) in moe_param_shapes(spec).items():
+            self.env[name] = jnp.asarray(weights[name])
+            slots[slot] = name
+        self.op = _Op(slots)
+
+    def attr(self, name, default=None):
+        return self._attrs.get(name, default)
+
+    def input(self, slot):
+        return self.env[self.op.input(slot)]
+
+
+def _block(spec=SPEC, weights=WEIGHTS):
+    return pdo._ParallelMoEBlock(_Ctx(spec, weights))
+
+
+def _arenas(spec=SPEC):
+    shape = (spec.n_layer, NB, BS, spec.n_kv_head * spec.d_key)
+    return jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32)
+
+
+def _prefill_chunk(block, kc, vc, table, tokens, start):
+    """One chunk of one sequence through the one-table path, as the
+    paged_prefill op runs it: logits of every row."""
+    s = len(tokens)
+    pos = start + jnp.arange(s, dtype=jnp.int32)
+    place = pdo._page_runs(table, jnp.int32(start), jnp.int32(s), s, NB, BS)
+    h, kc, vc, _, _, _ = pdo._extend_rows(
+        block, kc, vc, jnp.asarray(tokens, jnp.int32), pos, table, place,
+        valid=jnp.ones((s,), bool))
+    return block.logits(h), kc, vc
+
+
+def _decode(block, kc, vc, tables, tokens, lens):
+    place = pdo._single_rows(tables, lens, NB, BS)
+    h, kc, vc, _, _, stats = pdo._extend_rows(
+        block, kc, vc, tokens, lens, tables, place, valid=place.ok[:, 0])
+    return block.logits(h), kc, vc, stats
+
+
+def _reference_logits(tokens, spec=SPEC, weights=WEIGHTS):
+    return np.asarray(ref.logits(weights, np.asarray(tokens, np.int32),
+                                 _arch(spec), _held(spec)))
+
+
+# ------------------------------------------------------------- the router
+def test_router_hand_worked_case():
+    """Scores are sigmoids of the row's products, the k largest are
+    kept, and the weights are normalised over all that were kept."""
+    x = jnp.asarray([[1.0, 0.0], [0.0, 2.0]])
+    router = jnp.asarray([[0.0, 1.0, -1.0, 2.0],
+                          [1.0, 0.0, 0.5, -0.5]])
+    chosen, weight = moe.route_sigmoid_topk(x, router, 2)
+    sig = lambda z: 1.0 / (1.0 + np.exp(-z))
+    # row 0 scores experts by (0, 1, -1, 2): keeps 3 and 1
+    # row 1 scores them by (2, 0, 1, -1): keeps 0 and 2
+    assert chosen.tolist() == [[3, 1], [0, 2]]
+    want = [[sig(2.0), sig(1.0)], [sig(2.0), sig(1.0)]]
+    want = np.asarray(want) / np.sum(want, axis=1, keepdims=True)
+    np.testing.assert_allclose(np.asarray(weight), want, rtol=1e-6)
+    # the experts held are 1 and 2: row 0 gives 1 its weight, row 1
+    # gives 2 its weight; normalisation was over the absent ones too
+    gate, hit = moe.held_gates(chosen, weight, 1, 2)
+    np.testing.assert_allclose(
+        np.asarray(gate), [[want[0, 1], 0.0], [0.0, want[1, 1]]], rtol=1e-6)
+    assert hit.tolist() == [[True, False], [False, True]]
+    stats = moe.load_stats(hit, jnp.asarray([True, True]))
+    assert stats.tolist() == [2, 1, 2]
+    assert moe.load_stats(hit, jnp.asarray([True, False])).tolist() == \
+        [1, 1, 1]
+
+
+def test_reference_router_is_the_same_rule():
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.randn(9, 16), jnp.float32)
+    router = jnp.asarray(rng.randn(16, 8), jnp.float32)
+    got = moe.route_sigmoid_topk(x, router, 3)
+    want = ref.route(x, router, 3)
+    assert np.array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_allclose(np.asarray(got[1]), np.asarray(want[1]),
+                               rtol=1e-6)
+
+
+# ----------------------------------------------------- shares add up
+def test_shares_add_up_to_the_uncut_layer():
+    """What every share of the experts gives, the shared experts counted
+    once, is the whole layer's expert sum: in the reference, and between
+    the block's product and the reference."""
+    whole = _spec(experts_held=8, first_expert=0)
+    w = random_weights(whole, seed=11)
+    rng = np.random.RandomState(1)
+    n = jnp.asarray(rng.randn(7, whole.d_model), jnp.float32)
+    arch = _arch(whole)
+    layer = 1
+    uncut = np.asarray(ref.experts(n, w, layer, arch, (0, 8)))
+
+    def cut(first, count):
+        out = dict(w)
+        for part in ('gate', 'up', 'down'):
+            name = 'lm_stack_exp_%s.w' % part
+            out[name] = w[name][:, first:first + count]
+        return out
+
+    n_shared = whole.n_shared_experts
+    shared = sum(np.asarray(ref.expert(
+        n, w['lm_stack_shr_gate.w'][layer, j],
+        w['lm_stack_shr_up.w'][layer, j],
+        w['lm_stack_shr_down.w'][layer, j])) for j in range(n_shared)) \
+        / n_shared
+    from_reference = shared.copy()
+    from_block = shared.copy()
+    for first in (0, 4):
+        share = cut(first, 4)
+        from_reference += np.asarray(
+            ref.experts(n, share, layer, arch, (first, 4))) - shared
+        chosen, weight = moe.route_sigmoid_topk(
+            n, share['lm_stack_router.w'][layer], whole.experts_per_token)
+        gate, _ = moe.held_gates(chosen, weight, first, 4)
+        from_block += np.asarray(moe.gated_experts(
+            n, gate, *(jnp.asarray(share['lm_stack_exp_%s.w' % p][layer])
+                       for p in ('gate', 'up', 'down'))))
+    np.testing.assert_allclose(from_reference, uncut, atol=TOL)
+    np.testing.assert_allclose(from_block, uncut, atol=TOL)
+    # and a share alone is not the layer
+    assert np.abs(np.asarray(ref.experts(n, cut(0, 4), layer, arch,
+                                         (0, 4))) - uncut).max() > 1e-2
+
+
+# ------------------------------------------------- attention, alone
+@pytest.mark.parametrize('start,window,block_cols', [
+    (0, 0, 8), (17, 0, 8), (17, 6, 8), (26, 9, 12), (5, 3, 512)])
+def test_one_table_attention_in_blocks_is_the_dense_softmax(start, window,
+                                                            block_cols):
+    """8 consecutive rows of one sequence from ``start``, grouped heads,
+    a window or none: the blocked running softmax over the blocks that
+    hold lo..hi gives what a dense masked softmax over the whole table
+    gives, and a column outside a row's bounds has no say (the pages
+    past it hold garbage)."""
+    from paddle_tpu.ops.pallas.paged_attention import \
+        paged_attention_one_table
+    rng = np.random.RandomState(start + window)
+    heads, kv_heads, d, rows = 4, 2, 8, 8
+    table = jnp.asarray(rng.permutation(NB)[:PAGES], jnp.int32)
+    k_arena = jnp.asarray(rng.randn(2, NB, BS, kv_heads * d), jnp.float32)
+    v_arena = jnp.asarray(rng.randn(2, NB, BS, kv_heads * d), jnp.float32)
+    q = jnp.asarray(rng.randn(rows, heads, d), jnp.float32)
+    pos = start + np.arange(rows)
+    hi = pos + 1
+    lo = np.maximum(hi - window, 0) if window else np.zeros_like(pos)
+    got = paged_attention_one_table(
+        q, k_arena, v_arena, table, jnp.asarray(lo, jnp.int32),
+        jnp.asarray(hi, jnp.int32), layer=1, block_cols=block_cols)
+    k = np.asarray(k_arena)[1][np.asarray(table)].reshape(-1, kv_heads, d)
+    v = np.asarray(v_arena)[1][np.asarray(table)].reshape(-1, kv_heads, d)
+    want = np.zeros((rows, heads, d), 'float32')
+    for r in range(rows):
+        for h in range(heads):
+            n = h // (heads // kv_heads)
+            sc = k[lo[r]:hi[r], n] @ np.asarray(q)[r, h] * d ** -0.5
+            w = np.exp(sc - sc.max())
+            want[r, h] = (w / w.sum()) @ v[lo[r]:hi[r], n]
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-6)
+
+
+# ------------------------------------- prefill in chunks, then decode
+@pytest.mark.parametrize('prompt_len,chunk', [(13, 8), (21, 16), (6, 8)])
+def test_chunked_prefill_then_decode_matches_full_forward(prompt_len,
+                                                          chunk):
+    """A sequence that crosses the window (8): its prompt prefilled in
+    chunks through the arenas, then decoded a token at a time, row by
+    row against the reference's one full forward."""
+    rng = np.random.RandomState(prompt_len)
+    total = prompt_len + 12
+    tokens = rng.randint(0, SPEC.vocab_size, total)
+    want = _reference_logits(tokens)
+    block = _block()
+    kc, vc = _arenas()
+    table = jnp.asarray(rng.permutation(NB)[:PAGES], jnp.int32)
+    for start in range(0, prompt_len, chunk):
+        piece = tokens[start:min(start + chunk, prompt_len)]
+        got, kc, vc = _prefill_chunk(block, kc, vc, table, piece, start)
+        np.testing.assert_allclose(
+            np.asarray(got), want[start:start + len(piece)], atol=TOL)
+    for t in range(prompt_len, total):
+        got, kc, vc, _ = _decode(
+            block, kc, vc, table[None, :],
+            jnp.asarray(tokens[t:t + 1], jnp.int32),
+            jnp.asarray([t], jnp.int32))
+        np.testing.assert_allclose(np.asarray(got)[0], want[t], atol=TOL)
+
+
+def test_padded_chunk_rows_write_nothing():
+    """A chunk padded to its bucket: the rows past ``length`` leave the
+    arenas as they were, and the real rows' logits do not move."""
+    rng = np.random.RandomState(3)
+    tokens = rng.randint(0, SPEC.vocab_size, 5)
+    block = _block()
+    kc, vc = _arenas()
+    table = jnp.arange(PAGES, dtype=jnp.int32)
+    exact, kc1, vc1 = _prefill_chunk(block, kc, vc, table, tokens, 0)
+    padded = np.concatenate([tokens, np.zeros(3, tokens.dtype)])
+    pos = jnp.arange(8, dtype=jnp.int32)
+    place = pdo._page_runs(table, jnp.int32(0), jnp.int32(5), 8, NB, BS)
+    h, kc2, vc2, _, _, _ = pdo._extend_rows(
+        block, kc, vc, jnp.asarray(padded, jnp.int32), pos, table, place,
+        valid=pos < 5)
+    np.testing.assert_allclose(np.asarray(block.logits(h))[:5],
+                               np.asarray(exact), atol=TOL)
+    assert np.array_equal(np.asarray(kc1), np.asarray(kc2))
+    assert np.array_equal(np.asarray(vc1), np.asarray(vc2))
+
+
+def test_decode_batch_of_mixed_lengths_matches_reference():
+    """Four sequences of lengths on both sides of the window in one
+    decode batch, an empty slot among them: every row's logits are the
+    reference's for that sequence, and the router statistics count the
+    live rows only."""
+    rng = np.random.RandomState(7)
+    lengths = [3, 9, 17, 30]
+    seqs = [rng.randint(0, SPEC.vocab_size, n + 1) for n in lengths]
+    block = _block()
+    kc, vc = _arenas()
+    pages = rng.permutation(NB)
+    tables = np.full((5, PAGES), NB, np.int32)
+    used = 0
+    for i, seq in enumerate(seqs):
+        need = -(-len(seq) // BS)
+        tables[i, :need] = pages[used:used + need]
+        used += need
+        _, kc, vc = _prefill_chunk(block, kc, vc, jnp.asarray(tables[i]),
+                                   seq[:-1], 0)
+    got, kc, vc, stats = _decode(
+        block, kc, vc, jnp.asarray(tables),
+        jnp.asarray([s[-1] for s in seqs] + [0], jnp.int32),
+        jnp.asarray(lengths + [0], jnp.int32))
+    for i, seq in enumerate(seqs):
+        np.testing.assert_allclose(
+            np.asarray(got)[i], _reference_logits(seq)[-1], atol=TOL)
+    stats = np.asarray(stats)
+    assert stats.shape == (SPEC.n_layer, 3)
+    # 4 live rows x 3 choices a layer bound the local ones; the busiest
+    # expert holds at most every live row; at most 4 experts are held
+    assert (stats[:, 0] <= 12).all() and (stats[:, 1] <= 4).all()
+    assert (stats[:, 2] <= SPEC.experts_held).all()
+    assert (stats[:, 1] <= stats[:, 0]).all()
+    # by the reference's router: the choices of the live rows that fall
+    # on experts 2..5, layer 0 (its input is the embedding's norm)
+    x = ref.layer_norm(
+        jnp.asarray(WEIGHTS['lm_emb'])[jnp.asarray([s[-1] for s in seqs])],
+        WEIGHTS['lm_stack_ln.w'][0], SPEC.norm_eps)
+    chosen, _ = ref.route(x, WEIGHTS['lm_stack_router.w'][0], 3)
+    chosen = np.asarray(chosen)
+    assert stats[0, 0] == int(((chosen >= 2) & (chosen < 6)).sum())
+
+
+@pytest.mark.parametrize('broken', ['window', 'rotary', 'first_expert'])
+def test_the_tolerance_catches_a_wrong_layer(broken):
+    """What the tolerance is for: a window one key short, a full layer
+    rotated, the wrong experts held, each moves logits by far more."""
+    over = {'window': dict(sliding_window=7),
+            'rotary': dict(layer_types=['sliding_attention'] * 4),
+            'first_expert': dict(first_expert=3)}[broken]
+    rng = np.random.RandomState(2)
+    tokens = rng.randint(0, SPEC.vocab_size, 20)
+    got, _, _ = _prefill_chunk(_block(_spec(**over)), *_arenas(),
+                               jnp.arange(PAGES, dtype=jnp.int32),
+                               tokens, 0)
+    assert np.abs(np.asarray(got) - _reference_logits(tokens)).max() > 1e-2
+
+
+def test_the_tolerance_catches_a_narrower_state():
+    """The configuration states float32 for the residual stream, the
+    router, the softmax and the logits. Served tokens cannot tell
+    (on the chip the reference with those in bfloat16 lies as close to
+    itself as the sound engine does: PERF.md section 6), so that half
+    of the stated precision is held here, by logits: in bfloat16 they
+    move by a hundred times the tolerance and more."""
+    rng = np.random.RandomState(4)
+    tokens = rng.randint(0, SPEC.vocab_size, 20)
+    narrow = ref.logits(WEIGHTS, tokens, dict(_arch(SPEC),
+                                              state_dtype='bfloat16'),
+                        _held(SPEC))
+    moved = np.abs(np.asarray(narrow) - _reference_logits(tokens))
+    assert moved.max() > 100 * TOL
+
+
+# ------------------------------------------------------------ the engine
+def _engine(spec=SPEC, **kw):
+    kw.setdefault('max_batch', 4)
+    kw.setdefault('block_size', BS)
+    kw.setdefault('num_blocks', 64)
+    kw.setdefault('pages_per_seq', PAGES)
+    kw.setdefault('prefill_chunk', 8)
+    kw.setdefault('min_prompt_bucket', 4)
+    kw.setdefault('weights', WEIGHTS)
+    kw.setdefault('place', fluid.CPUPlace())
+    return DecodeEngine(spec, **kw)
+
+
+def _requests(n=6, seed=0):
+    rng = np.random.RandomState(seed)
+    return [(rng.randint(0, SPEC.vocab_size,
+                         int(rng.randint(2, 27))).tolist(),
+             int(rng.randint(3, 12))) for _ in range(n)]
+
+
+def test_engine_batched_equals_one_at_a_time_and_the_reference():
+    """Through DecodeEngine (scheduler, pool, executor, chunked prefill
+    above 8 tokens): the tokens of six requests served together are the
+    tokens of each served alone, no signature compiles after warmup, the
+    pool drains, and every served token is the reference's choice up to
+    a logit gap of TOL."""
+    from paddle_tpu import observe
+    requests = _requests()
+    assert max(len(p) for p, _ in requests) > 16    # three chunks
+    assert max(len(p) + n for p, n in requests) > SPEC.sliding_window
+    alone = []
+    eng = _engine()
+    assert eng.prompt_buckets == [4, 8]
+    eng.warmup()
+    eng.start()
+    try:
+        for prompt, n in requests:
+            alone.append(eng.generate(prompt, max_new_tokens=n,
+                                      timeout=120))
+        observe.enable()
+        before = observe.snapshot()
+        streams = [eng.submit(p, max_new_tokens=n) for p, n in requests]
+        together = [s.result(120) for s in streams]
+        after = observe.snapshot()
+    finally:
+        eng.shutdown()
+        observe.disable()
+        observe.reset()
+    assert together == alone
+    assert eng.free_pages() == 64
+
+    def grown(name):
+        return sum(v for k, v in after['counters'].items()
+                   if k.startswith(name)) - \
+            sum(v for k, v in before['counters'].items()
+                if k.startswith(name))
+    assert grown('executor.cache_miss_total') == 0
+    chunks = sum(-(-len(p) // 8) for p, _ in requests)
+    assert grown('decode.prefill_chunks') == chunks
+    assert grown('decode.prefills_total') == len(requests)
+    # a prefill's whole time lies under its largest program's bucket: the
+    # top one wherever it was chunked, never the short last chunk's
+    for rung in eng.prompt_buckets:
+        want = sum(1 for p, _ in requests
+                   if eng._bucket(min(len(p), 8)) == rung)
+        key = 'decode.prefill_seconds{bucket=%d}' % rung
+        got = after['histograms'].get(key, {}).get('count', 0) - \
+            before['histograms'].get(key, {}).get('count', 0)
+        assert got == want, (rung, got, want)
+    assert grown('decode.moe_assignments') == \
+        grown('decode.step_rows') * 3 * SPEC.n_layer
+    assert 0 < grown('decode.moe_local_assignments') < \
+        grown('decode.moe_assignments')
+    assert grown('decode.step_window_rows') > 0
+    for (prompt, _), answer in zip(requests, together):
+        gaps, _ = ref.token_gaps(WEIGHTS, _arch(SPEC), _held(SPEC),
+                                 prompt, answer, 8)
+        assert max(gaps) <= TOL
+
+
+def test_engine_keeps_declared_dtypes_and_device_arrays():
+    spec = _spec(dtype='bfloat16')
+    eng = _engine(spec, weights=None, kv_dtype='bfloat16')
+    held = eng.device_weights()
+    assert str(held['lm_stack_exp_gate.w'].dtype) == 'bfloat16'
+    assert str(held['lm_stack_ln.w'].dtype) == 'float32'
+    assert str(held['lm_emb'].dtype) == 'bfloat16'
+    # a device array of the right dtype is taken as it is
+    mine = jnp.ones(held['lm_stack_router.w'].shape, jnp.bfloat16)
+    eng.load_weights({'lm_stack_router.w': mine})
+    assert eng.device_weights()['lm_stack_router.w'] is mine
+    # a host array is cast to the declared dtype, not to float32
+    eng.load_weights({'lm_emb': np.zeros(held['lm_emb'].shape, 'float32')})
+    assert str(eng.device_weights()['lm_emb'].dtype) == 'bfloat16'
+    with pytest.raises(ValueError, match='unknown param'):
+        eng.load_weights({'lm_out_proj.w': np.zeros((2, 2))})
+    # bf16 end to end: a request runs
+    eng.start()
+    try:
+        assert len(eng.generate([1, 2, 3, 4, 5, 6, 7, 8, 9],
+                                max_new_tokens=4, timeout=120)) == 4
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize('kw,what', [
+    (dict(prefix_cache=True), 'prefix cache'),
+    (dict(spec_k=2), 'speculation'),
+    (dict(kv_dtype='int8'), 'unquantized')])
+def test_what_the_block_does_not_run_raises(kw, what):
+    with pytest.raises(NotImplementedError, match=what):
+        _engine(**kw)
+
+
+def test_spec_refuses_what_it_cannot_describe():
+    with pytest.raises(ValueError, match='KV heads'):
+        _spec(n_kv_head=3)
+    with pytest.raises(ValueError, match='layer_types'):
+        _spec(layer_types=['full_attention'])
+    with pytest.raises(ValueError, match='experts'):
+        _spec(first_expert=6)
+    with pytest.raises(ValueError, match='one KV head'):
+        LMSpec(vocab_size=8, n_head=4, n_kv_head=2)
+    assert _spec().windows() == [8, 8, 8, 0]
+    assert _spec().rotary() == [True, True, True, False]
+
+
+def test_kv_bytes_count_kv_heads():
+    # 4 layers x 2 KV heads x (8 + 8) x 4 B, not the 4 query heads
+    assert kv_bytes_per_token(SPEC) == 4 * 2 * 16 * 4
+    assert kv_bytes_per_token(SPEC, 'bfloat16') == 4 * 2 * 16 * 2
+    assert arena_bytes(SPEC, 10, 4) == 4 * 2 * 16 * 4 * 40
+    dense = LMSpec(vocab_size=8, n_layer=2, n_head=2, d_key=8, d_value=8)
+    assert kv_bytes_per_token(dense) == 2 * 2 * 16 * 4
+    assert _engine().kv_geometry()['n_kv_head'] == 2
+
+
+def test_long_prefix_of_the_dense_block_prefills_in_chunks():
+    """The post-LN block takes the same chunked feed: a prompt above the
+    top bucket gives the tokens of an engine whose bucket holds it."""
+    spec = LMSpec(vocab_size=60, n_layer=2, n_head=2, d_key=8, d_value=8,
+                  d_model=16, d_inner=32)
+    weights = random_weights(spec, seed=3)
+    prompt = list(np.random.RandomState(4).randint(0, 60, 21))
+    out = []
+    for kw in (dict(max_prompt_len=32), dict(max_prompt_len=32,
+                                             prefill_chunk=8)):
+        eng = DecodeEngine(spec, max_batch=2, block_size=4, num_blocks=32,
+                           pages_per_seq=10, weights=weights,
+                           place=fluid.CPUPlace(), **kw)
+        eng.start()
+        try:
+            out.append(eng.generate(prompt, max_new_tokens=6, timeout=120))
+        finally:
+            eng.shutdown()
+    assert out[0] == out[1]
