@@ -44,7 +44,10 @@ FULL = {
     'model': dict(n_layer=6, n_head=8, d_key=64, d_value=64, d_model=512,
                   d_inner=2048),
     'vocab': 32000, 'batch': 64, 'seq': 64, 'steps': 5, 'window': 10,
-    'engine': dict(max_batch=16, block_size=32, num_blocks=512,
+    # a pool whose layer (2,048 x 32 x 512) is larger than the largest
+    # weight (32,000 x 512): the compiler prefetches a weight into fast
+    # memory with a copy, and that is not a copy of an arena
+    'engine': dict(max_batch=16, block_size=32, num_blocks=2048,
                    pages_per_seq=16, max_prompt_len=64),
     'requests': 8, 'max_new': 32,
     'flash': [(64, 8, 64, 64), (2, 8, 1024, 64)],   # [B, H, T, D]
@@ -54,7 +57,9 @@ REHEARSAL = {
     'model': dict(n_layer=1, n_head=2, d_key=8, d_value=8, d_model=16,
                   d_inner=32),
     'vocab': 64, 'batch': 8, 'seq': 8, 'steps': 5, 'window': 3,
-    'engine': dict(max_batch=4, block_size=8, num_blocks=32,
+    # a pool larger than a row block's column block (8 tables x 4
+    # pages), so that a block's pages are not of a layer's arena size
+    'engine': dict(max_batch=4, block_size=8, num_blocks=64,
                    pages_per_seq=4, max_prompt_len=8),
     'requests': 8, 'max_new': 6,
     'flash': [(2, 2, 16, 8)],

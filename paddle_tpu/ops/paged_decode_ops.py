@@ -8,8 +8,9 @@ cache lives in a paged pool (ops/pallas/paged_attention.py layouts):
   the cold case) by a padded suffix [1, S]: write each suffix
   position's K/V into the sequence's pages through its block table,
   attend each suffix query against the table at its own absolute
-  length (one ragged paged-attention pass — S queries, per-query
-  lengths cached+1 .. cached+S), and emit the next token. S is
+  length (the one-table form: S queries over the blocks of the table
+  the chunk can see, per-query lengths cached+1 .. cached+Len; the
+  padded rows see nothing), and emit the next token. S is
   bucketed by the engine so the signature set is small and warmable.
 - ``paged_decode_step`` — one token for EVERY slot of a fixed-size
   decode batch [B]: append each sequence's K/V at its own position
@@ -39,7 +40,10 @@ cohere2_moe, LMSpec block='parallel_moe'): embedding, the q/k/v
 projections, what follows attention, the per-layer lower bound on the
 columns a row sees, and the logits. Everything else — placement, the
 in-place arena writes, the one ``lax.scan`` over [L, ...]-stacked
-weights with the arenas as carry, the attention gather — is shared
+weights with the arenas as carry, the attention in blocks of rows and
+columns bounded by what the rows hold (ops/pallas/paged_attention.py:
+many tables with one query each for the decode step and spec verify,
+one table with many queries for every prefill) — is shared
 through ``_extend_rows``. A layer's kind (window, rotary) is scanned
 data beside the weights, never a second program.
 
@@ -47,11 +51,12 @@ How the arenas travel: the stacked K/V arenas [L, NB, bs, Hkv*D] (and
 the [L, NB, bs, H] scale arenas of the quantized dtypes) are the
 scan's CARRY, beside ``h``; only the weights and the layer index are
 scanned. A layer writes its new rows with ``dynamic_update_slice`` at
-(layer, page, slot, 0) and attends through a gather at (layer, table),
-so no program slices a layer out, stacks one back or hands an arena to
-a scatter: the op's KCacheOut/VCacheOut are the final carry, which the
-executor's donation aliases to the inputs, and the only instruction
-that touches arena-sized data is the attention gather
+(layer, page, slot, 0) and attends through gathers at (layer, a
+block of the tables), so no program slices a layer out, stacks one
+back or hands an arena to a scatter: the op's KCacheOut/VCacheOut are
+the final carry, which the executor's donation aliases to the inputs,
+the only instructions that touch arena-sized data are the attention's
+gathers, and what they produce is a block's pages
 (``serving/decode/hlo_check.py`` counts the others in a compiled
 program; chip_smoke.py fails above zero). The shape is what makes that
 possible on a TPU: a token's row is H*D contiguous lane-dense elements,
@@ -60,9 +65,10 @@ of tiles (with D minor-most it would lay the page axis minor and
 re-lay the arena at every program's entry and exit).
 
 Every per-row computation is independent of the
-other rows — and all three ops attend through the same
-``paged_attention`` gather over the same [P*bs] extent — so a
-sequence's token stream is bit-identical whether it decodes alone,
+other rows — all three ops attend through the same inner form, over
+column blocks that sit at absolute multiples of their width, and a
+block a row sees nothing of leaves its state bit for bit as it was —
+so a sequence's token stream is bit-identical whether it decodes alone,
 packed into a full batch, resumed from a cached prefix, or advanced
 k-at-a-time under speculation: the invariant
 tests/test_decode_serving.py's e2es assert.
@@ -325,8 +331,10 @@ def _write_in_place(arenas, rows, layer, place):
     at ``layer`` where ``place`` says, run by run with
     ``dynamic_update_slice`` (which clamps and never drops, so a slot
     that must not be written gets the value that is there). Runs go in
-    order, each reading the arena the one before it left. Nothing here
-    is of arena size: a scatter would have the TPU re-lay its whole
+    order, each reading the arena the one before it left, up to the
+    last run that writes anything: the rows past a decode batch and
+    the pages past a prompt's length cost no update. Nothing here is
+    of arena size: a scatter would have the TPU re-lay its whole
     operand."""
     blocks = [place.blocks(r) for r in rows]
 
@@ -341,7 +349,10 @@ def _write_in_place(arenas, rows, layer, place):
             out.append(jax.lax.dynamic_update_slice(
                 arena, jnp.where(keep, mine.reshape(run), there), at))
         return tuple(out)
-    return jax.lax.fori_loop(0, place.phys.shape[0], one, tuple(arenas))
+    n_runs = place.phys.shape[0]
+    writes = jnp.any(place.ok, axis=1)
+    upper = jnp.max(jnp.where(writes, jnp.arange(1, n_runs + 1), 0))
+    return jax.lax.fori_loop(0, upper, one, tuple(arenas))
 
 
 def _arena_kv_dtype(kc):
@@ -407,9 +418,10 @@ def _extend_rows(block, kcs, vcs, tokens, pos, tables, place,
     """Shared core of all three ops: write N new tokens' K/V at
     absolute positions ``pos`` where ``place`` (a _Placement over the
     same rows) says, attend each row at its own ragged length
-    (``pos + 1``) through per-row block ``tables`` [N, P] (or, for
-    consecutive rows of one sequence, its one table [P], gathered
-    once), and return the last hidden rows [N, D] plus the updated
+    (``pos + 1``; 0 where ``valid`` says a row is not live, so that it
+    costs no block) through per-row block ``tables`` [N, P] (or, for
+    consecutive rows of one sequence, its one table [P]), and return
+    the last hidden rows [N, D] plus the updated
     arenas and the block's per-layer statistics (None where it keeps
     none; ``valid`` [N] says which rows count). The arenas are carried
     through the layer loop and written in place (module docstring);
@@ -433,7 +445,10 @@ def _extend_rows(block, kcs, vcs, tokens, pos, tables, place,
     quantized = kv_q is not None
 
     x = block.embed(tokens, pos)
-    att_lens = pos + 1
+    # a row that is not live attends at length 0: it costs no block
+    att_lens = pos + 1 if valid is None else jnp.where(valid, pos + 1, 0)
+    attend = paged_attention_one_table if tables.ndim == 1 \
+        else paged_attention
 
     def body(carry, sl):
         h, arenas = carry
@@ -449,18 +464,11 @@ def _extend_rows(block, kcs, vcs, tokens, pos, tables, place,
             rows = (k_new.astype(kcs.dtype), v_new.astype(vcs.dtype))
         arenas = _write_in_place(arenas, rows, layer, place)
         q = block.q(nrm, p, pos)
-        lo = block.lower_bound(p, pos)
-        if tables.ndim == 1:
-            attn = paged_attention_one_table(
-                q, arenas[0], arenas[1], tables,
-                jnp.zeros_like(pos) if lo is None else lo, att_lens,
-                layer=layer)
-        else:
-            attn = paged_attention(
-                q, arenas[0], arenas[1], tables, att_lens,
-                k_scales=arenas[2] if quantized else None,
-                v_scales=arenas[3] if quantized else None,
-                layer=layer, lo=lo)
+        attn = attend(
+            q, arenas[0], arenas[1], tables, att_lens,
+            k_scales=arenas[2] if quantized else None,
+            v_scales=arenas[3] if quantized else None, layer=layer,
+            lo=block.lower_bound(p, pos))
         h, stats = block.finish(h, nrm, attn, p, valid)
         return (h, arenas), stats
 
@@ -494,20 +502,14 @@ def _paged_prefill(ctx):
     place = _page_runs(table, cached, length, s, kcs.shape[1],
                        kcs.shape[2])
     last = jnp.maximum(length - 1, 0)
-    if ctx.attr('one_table', False):
-        # the sequence's pages gathered once for the whole chunk, and
-        # the one row that is sampled projected onto the vocabulary
-        h, kcs, vcs, kss, vss, _ = _extend_rows(
-            block, kcs, vcs, ids, pos, table, place, kss, vss,
-            valid=jnp.arange(s) < length)
-        logits_last = block.logits(jax.lax.dynamic_slice_in_dim(
-            h, last, 1))[0]                                     # [V]
-    else:
-        tables = jnp.broadcast_to(table, (s, table.shape[0]))
-        h, kcs, vcs, kss, vss, _ = _extend_rows(
-            block, kcs, vcs, ids, pos, tables, place, kss, vss)
-        logits_last = jax.lax.dynamic_index_in_dim(
-            block.logits(h), last, keepdims=False)              # [V]
+    # the sequence's pages gathered block by block for the whole chunk
+    # (rows past ``length`` see nothing), and the one row that is
+    # sampled projected onto the vocabulary
+    h, kcs, vcs, kss, vss, _ = _extend_rows(
+        block, kcs, vcs, ids, pos, table, place, kss, vss,
+        valid=jnp.arange(s) < length)
+    logits_last = block.logits(jax.lax.dynamic_slice_in_dim(
+        h, last, 1))[0]                                         # [V]
     nxt = _sample_token(logits_last, seed, cached + length, temp)
     ctx.set_output('NextToken',
                    nxt.reshape(1).astype(ctx.out_dtype('NextToken',
@@ -537,7 +539,7 @@ def _paged_spec_verify(ctx):
     place = _single_rows(tables_rep, pos, kcs.shape[1], kcs.shape[2])
     h, kcs, vcs, kss, vss, _ = _extend_rows(
         block, kcs, vcs, tokens.reshape(-1), pos, tables_rep, place,
-        kss, vss)
+        kss, vss, valid=place.ok[:, 0])
 
     nxt = jax.vmap(_sample_token)(
         block.logits(h), jnp.repeat(seeds, k1), pos + 1,

@@ -12,14 +12,27 @@ not Tmax slots.
 
 Two backends, selected like ops/pallas/flash_attention.py:
 
-- **XLA gather path** (default, and the CPU/tier-1 path): gather the
-  per-sequence pages through the block table at (layer, table) into
-  [B, P, bs, H*D], read it as [B, P*bs, H, D] (no transpose), mask
-  columns >= seq_len, fp32 softmax. The gather is the only
-  instruction that reads the arena. What follows it is sized by the
-  batch's tables, not by the pool — and on the TPU the split of H*D
-  into [H, D] is still a re-tiling of the gathered pages when D is
-  under a lane tile (PERF.md section 5; ROADMAP S3b/S3c).
+- **XLA gather path** (default, and the CPU/tier-1 path): the
+  attention reads the pages its rows hold and no others. Two forms over
+  one inner form (``_attend_blocks``: whole pages of about BLOCK_COLS
+  columns gathered through the table at (layer, table) and consumed
+  there under a running softmax; float32 scores, normaliser and
+  accumulator):
+  ``paged_attention_blocked`` — many tables, one query each (decode
+  step, spec verify): rows ordered by attended length, blocks of
+  BLOCK_ROWS rows, each running the column blocks from the one that
+  holds its smallest lower bound to the one that holds its largest
+  length; rows that are not live cost no block;
+  ``paged_attention_one_table`` — one table, many queries (every
+  prefill): the chunk's rows as one group over the blocks the chunk can
+  see.
+  The loop bounds come from the step's own inputs (``block_bounds``), in
+  one compiled program per signature; nothing of the extent [B, P] or
+  [S, P] is gathered, re-tiled or multiplied (PERF.md section 6, PR 29;
+  before it the gather covered every page of every table whatever the
+  rows held). On the TPU the split of H*D into [H, D] is still a
+  re-tiling of a block's gathered pages when D is under a lane tile
+  (ROADMAP S3c).
 - **Pallas kernel** (PADDLE_TPU_USE_PALLAS=1): the block table rides
   scalar prefetch (pltpu.PrefetchScalarGridSpec) so each grid step's
   page index map reads table[b, page] — the kernel DMAs exactly the
@@ -27,9 +40,11 @@ Two backends, selected like ops/pallas/flash_attention.py:
   (ragged: short sequences cost proportionally less), and the online-
   softmax recurrence matches the flash kernel's.
 
-Parity across mixed sequence lengths vs a dense masked reference is
-asserted in tests/test_decode_serving.py (XLA path) and
-tests/test_pallas_kernels.py (kernel, interpret mode).
+Parity of both XLA forms with a dense masked oracle
+(``paged_attention_reference``, which no serving program calls) across
+mixed lengths, head layouts and arena dtypes is asserted in
+tests/test_paged_attention_blocked.py and tests/test_decode_serving.py,
+the kernel's in tests/test_pallas_kernels.py (interpret mode).
 
 Layouts:
     q            [B, H, D]      one query token per sequence
@@ -61,163 +76,289 @@ from . import pallas_enabled
 _NEG_INF = -1e9
 
 
-def _gather_pages(arena, layer, tables, n_head):
-    """arena [L, NB, bs, H*W] read at (layer, table) -> [B, P*bs, H, W]
-    (W = 1 for the scale arenas' [L, NB, bs, H]). One gather whose
-    slices are whole pages; the reshape splits and merges adjacent
-    axes only, so nothing is transposed."""
-    b, p = tables.shape
-    bs = arena.shape[2]
-    pages = arena[layer, tables]                   # [B, P, bs, H*W]
-    return pages.reshape(b, p * bs, n_head, -1)
+# The XLA path's two sizes, neither swept against a trace: a row block is
+# the hardware's sublane tile, a column block about the width the
+# one-table form has run at on the chip since PR 28.
+BLOCK_ROWS = 8
+BLOCK_COLS = 512
+
+
+def pages_per_block(n_pages, bs, block_cols=BLOCK_COLS):
+    """Whole pages a column block: the largest divisor of the table's
+    ``n_pages`` that holds at most ``block_cols`` columns, so every
+    block is full and block j covers the absolute columns
+    [j * pages * bs, (j + 1) * pages * bs)."""
+    return max(p for p in range(1, n_pages + 1)
+               if n_pages % p == 0 and p * bs <= max(block_cols, bs))
+
+
+def block_bounds(lo, hi, rows, block, n_blocks, xp=jnp):
+    """The column blocks each group of ``rows`` consecutive rows runs,
+    from what the rows hold: ``lo``/``hi`` [G * rows] ints (a row sees
+    columns lo <= j < hi; hi <= lo sees nothing) -> (first [G], last
+    [G]): from the block that holds the group's smallest ``lo`` to the
+    one that holds its largest ``hi``; last = first - 1 where no row of
+    the group sees anything. A pure function of the lengths over ``xp``
+    (jnp inside the program, numpy where the engine counts
+    ``decode.attn_pages_read``), so the count is of the loops that run."""
+    live = hi > lo
+    lo_g = xp.where(live, lo, n_blocks * block).reshape(-1, rows).min(axis=1)
+    hi_g = xp.where(live, hi, 0).reshape(-1, rows).max(axis=1)
+    first = xp.clip(lo_g // block, 0, n_blocks - 1)
+    last = xp.clip((hi_g - 1) // block, 0, n_blocks - 1)
+    return first, xp.where(hi_g > 0, last, first - 1)
+
+
+def row_blocks(lo, hi, block, n_blocks, xp=jnp):
+    """Many tables' rows in blocks of BLOCK_ROWS: (order [B], first [G],
+    last [G]). ``order`` puts the longest attended length first and the
+    rows that see nothing last, so a block holds rows of like length
+    and the blocks that run are a prefix; ``first``/``last`` are
+    ``block_bounds`` of the ordered rows, the last block filled up with
+    rows that see nothing."""
+    order = xp.argsort(-xp.where(hi > lo, hi, 0), stable=True)
+    fill = xp.zeros((-len(order) % BLOCK_ROWS,), hi.dtype)
+    first, last = block_bounds(xp.concatenate([lo[order], fill]),
+                               xp.concatenate([hi[order], fill]),
+                               BLOCK_ROWS, block, n_blocks, xp)
+    return order, first, last
+
+
+def pages_covered(lo, hi, n_pages, bs, xp=jnp):
+    """Pages one layer of ``paged_attention``'s XLA path gathers for
+    rows that see columns lo <= j < hi of tables of ``n_pages``: every
+    (row block, column block) pair that runs reads BLOCK_ROWS rows of
+    ``pages_per_block`` pages."""
+    per = pages_per_block(n_pages, bs)
+    _, first, last = row_blocks(lo, hi, per * bs, n_pages // per, xp)
+    return (last - first + 1).sum() * BLOCK_ROWS * per
+
+
+def _attend_blocks(q, arenas, layer, tables, lo, hi, first, last, per):
+    """The one inner form of the XLA path: R tables with S queries each
+    (decode: R = BLOCK_ROWS, S = 1; a prefill chunk: R = 1, S = bucket).
+    q [R, S, H, D] (scaled), ``arenas`` (K, V[, K scales, V scales]),
+    tables [R, P] (clipped), lo/hi [R, S]. Column blocks ``first`` ..
+    ``last`` of ``per`` whole pages go one after another under a running
+    softmax (maximum, normaliser, weighted sum; float32): each
+    iteration gathers [R, per pages] through the tables and is consumed
+    there. A block no column of which a row sees leaves that row's
+    state bit for bit as it was, and a block sits at an absolute
+    multiple of its width, so a row's result depends on its own
+    columns only. Operands of the two products at the arena's dtype
+    (float32 once dequantized), scores, normaliser and accumulator
+    float32. Returns [R, S, H, D] float32; rows that saw nothing 0."""
+    k_pages, v_pages = arenas[0], arenas[1]
+    r, s, h, d = q.shape
+    bs = k_pages.shape[2]
+    n_kv = k_pages.shape[-1] // d
+    bk = per * bs
+    quantized = len(arenas) == 4
+    group = h // n_kv
+    qg = jnp.transpose(
+        q.astype(jnp.float32 if quantized else k_pages.dtype).reshape(
+            r, s, n_kv, group, d), (0, 2, 3, 1, 4))     # [R, Hkv, G, S, D]
+    # float32 operands multiply as float32 (the forms replaced ran them
+    # on the vector unit at full precision; left to the default the
+    # compiler rounds the whole arena to bfloat16 ahead of the gather)
+    exact = jax.lax.Precision.HIGHEST if qg.dtype == jnp.float32 else None
+    # Splitting gathered rows into [Hkv, D] has the compiler re-lay
+    # the block head-major first (v5e compile, PR 28 and PR 29). Where
+    # a head is whole lane tiles it can be sliced out of a row as it
+    # lies instead, at the price of joining the heads' scores: taken
+    # where the scores are the smaller of the two (one query a table)
+    by_head = d % 128 == 0 and \
+        h * s * 4 < n_kv * d * jnp.dtype(k_pages.dtype).itemsize
+
+    def pages(arena, at):
+        # [R, per, bs, W]: whole pages; adjacent axes merged only
+        return arena[layer, at].reshape(r, bk, -1)
+
+    def heads(x, width):
+        """[R, bk, Hkv * width] -> one [R, bk, n, width] per product."""
+        if by_head:
+            return [x[:, :, n * width:(n + 1) * width][:, :, None]
+                    for n in range(n_kv)]
+        return [x.reshape(r, bk, n_kv, width)]
+
+    def block(j, state):
+        top, norm, acc = state
+        at = jax.lax.dynamic_slice_in_dim(tables, j * per, per, 1)
+        kb, vb = heads(pages(k_pages, at), d), heads(pages(v_pages, at), d)
+        if quantized:
+            kb = [x.astype(jnp.float32) * sc for x, sc in
+                  zip(kb, heads(pages(arenas[2], at), 1))]
+            vb = [x.astype(jnp.float32) * sc for x, sc in
+                  zip(vb, heads(pages(arenas[3], at), 1))]
+        col = j * bk + jnp.arange(bk)
+        seen = ((col >= lo[..., None]) &
+                (col < hi[..., None]))[:, None, None]      # [R, 1, 1, S, bk]
+        each = qg.shape[1] // len(kb)
+        scores = jnp.concatenate([
+            jnp.einsum('rngsd,rknd->rngsk',
+                       qg[:, i * each:(i + 1) * each], x, precision=exact,
+                       preferred_element_type=jnp.float32)
+            for i, x in enumerate(kb)], axis=1)
+        scores = jnp.where(seen, scores, _NEG_INF)
+        new_top = jnp.maximum(top, jnp.max(scores, axis=-1))
+        w = jnp.where(seen, jnp.exp(scores - new_top[..., None]), 0.0)
+        keep = jnp.exp(top - new_top)
+        norm = keep * norm + jnp.sum(w, axis=-1)
+        w = w.astype(vb[0].dtype)
+        acc = keep[..., None] * acc + jnp.concatenate([
+            jnp.einsum('rngsk,rknd->rngsd',
+                       w[:, i * each:(i + 1) * each], x, precision=exact,
+                       preferred_element_type=jnp.float32)
+            for i, x in enumerate(vb)], axis=1)
+        return new_top, norm, acc
+
+    shape = (r, n_kv, group, s)
+    init = (jnp.full(shape, _NEG_INF, jnp.float32),
+            jnp.zeros(shape, jnp.float32),
+            jnp.zeros(shape + (d,), jnp.float32))
+    _, norm, acc = jax.lax.fori_loop(first, last + 1, block, init)
+    out = acc / jnp.where(norm == 0.0, 1.0, norm)[..., None]
+    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(r, s, h, d)
+
+
+def _seen_from_to(lo, seq_lens):
+    """(lo, hi) [N] int32: a row sees columns lo <= j < hi (lo None: 0)."""
+    hi = jnp.asarray(seq_lens).reshape(-1).astype(jnp.int32)
+    return (jnp.zeros_like(hi) if lo is None
+            else jnp.asarray(lo).reshape(-1).astype(jnp.int32)), hi
+
+
+def _arenas(k_pages, v_pages, k_scales, v_scales):
+    given = (k_pages, v_pages) if k_scales is None else \
+        (k_pages, v_pages, k_scales, v_scales)
+    return tuple(jnp.asarray(a) for a in given)
+
+
+def paged_attention_blocked(q, k_pages, v_pages, block_tables, seq_lens,
+                            sm_scale=None, k_scales=None, v_scales=None,
+                            layer=0, lo=None, block_cols=BLOCK_COLS):
+    """The XLA path of ``paged_attention``: many tables, one query each.
+    Nothing of the extent [B, P] is gathered: the rows are ordered by
+    attended length (an argsort of [B] ints, undone on the result), go
+    in blocks of BLOCK_ROWS, and a row block runs the column blocks
+    from the one that holds its smallest ``lo`` to the one that holds
+    its largest length and no further (``block_bounds``); row blocks
+    past the last row that sees anything run nothing. A row with
+    seq_lens <= lo (an empty batch slot: callers pass length 0) costs
+    no block and yields 0.
+
+    Bit-stable contract with the Pallas kernel's masking: columns
+    outside [lo, seq_lens) contribute exactly 0, so the result is
+    independent of the garbage content of unowned/partial pages, and a
+    row's result does not depend on what else the batch holds
+    (``_attend_blocks``).
+
+    Quantized arenas: ``k_scales``/``v_scales`` [L, NB, bs, H] carry
+    one fp32 scale per stored (page, slot, head) K/V row; a block's
+    pages are dequantized to fp32 through the same table indices
+    before the attention math (int8/fp8 only ever live in HBM).
+    Grouped heads: where the arena's row holds fewer heads than ``q``
+    has (row width = Hkv * D), query head h reads KV head
+    h // (H / Hkv). ``lo`` [B] int32 is a lower bound on the columns a
+    row sees (a sliding window); None sees every column below
+    ``seq_lens``."""
+    nb, bs = k_pages.shape[1], k_pages.shape[2]
+    b, p = block_tables.shape
+    h, d = q.shape[1], q.shape[2]
+    scale = sm_scale if sm_scale is not None else d ** -0.5
+    per = pages_per_block(p, bs, block_cols)
+    lo, hi = _seen_from_to(lo, seq_lens)
+    order, first, last = row_blocks(lo, hi, per * bs, p // per)
+    short = -b % BLOCK_ROWS
+
+    def ordered(x):
+        return jnp.concatenate(
+            [x[order], jnp.zeros((short,) + x.shape[1:], x.dtype)])
+
+    q_s, lo_s, hi_s = ordered(q * scale), ordered(lo), ordered(hi)
+    tables = ordered(jnp.clip(block_tables.astype(jnp.int32), 0, nb - 1))
+    arenas = _arenas(k_pages, v_pages, k_scales, v_scales)
+
+    def rows(i, out):
+        def cut(x):
+            return jax.lax.dynamic_slice_in_dim(x, i * BLOCK_ROWS,
+                                                BLOCK_ROWS, 0)
+        got = _attend_blocks(cut(q_s)[:, None], arenas, layer, cut(tables),
+                             cut(lo_s)[:, None], cut(hi_s)[:, None],
+                             first[i], last[i], per)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, got[:, 0], i * BLOCK_ROWS, 0)
+
+    live_blocks = jnp.sum(last >= first)      # a prefix: rows are ordered
+    out = jax.lax.fori_loop(
+        0, live_blocks, rows, jnp.zeros((b + short, h, d), jnp.float32))
+    return out[jnp.argsort(order)]
+
+
+def paged_attention_one_table(q, k_pages, v_pages, table, seq_lens,
+                              sm_scale=None, k_scales=None, v_scales=None,
+                              layer=0, lo=None, block_cols=BLOCK_COLS):
+    """One table, many queries: consecutive rows of ONE sequence (a
+    prefill chunk) against that sequence's pages: q [S, H, D],
+    ``table`` [P], row s sees columns lo[s] <= j < seq_lens[s]
+    (seq_lens <= lo: a padded row, sees nothing and yields 0). The same
+    blocks under the same running softmax as
+    ``paged_attention_blocked``, with the S rows as one group: from the block that holds the smallest ``lo`` to
+    the one that holds the largest ``hi`` and no further, each block's
+    pages gathered once for all S rows. A chunk at the start of a
+    prompt multiplies one block and not the table's whole extent, a
+    chunk deep in a sliding layer its window's blocks, and the scores
+    alive at a time are [H, S, block]. Which blocks run depends on the
+    chunk's place in its own sequence only. Grouped heads and quantized
+    arenas as in ``paged_attention_blocked``."""
+    nb, bs = k_pages.shape[1], k_pages.shape[2]
+    s, h, d = q.shape
+    scale = sm_scale if sm_scale is not None else d ** -0.5
+    per = pages_per_block(table.shape[0], bs, block_cols)
+    lo, hi = _seen_from_to(lo, seq_lens)
+    first, last = block_bounds(lo, hi, s, per * bs, table.shape[0] // per)
+    out = _attend_blocks(
+        (q * scale)[None], _arenas(k_pages, v_pages, k_scales, v_scales),
+        layer, jnp.clip(table.astype(jnp.int32), 0, nb - 1)[None],
+        lo[None], hi[None], first[0], last[0], per)
+    return out[0]
 
 
 def paged_attention_reference(q, k_pages, v_pages, block_tables, seq_lens,
                               sm_scale=None, k_scales=None,
                               v_scales=None, layer=0, lo=None):
-    """XLA gather path. Bit-stable contract with the Pallas kernel's
-    masking: columns >= seq_lens[b] contribute exactly 0 (exp of a
-    large-negative underflows), so the result is independent of the
-    garbage content of unowned/partial pages.
-
-    Quantized arenas: ``k_scales``/``v_scales`` [L, NB, bs, H] carry
-    one fp32 scale per stored (page, slot, head) K/V row; the gather
-    dequantizes to fp32 through the same table indices before the
-    attention math (fp32 accumulation — int8/fp8 only ever live in
-    HBM).
-
-    Grouped heads: where the arena's row holds fewer heads than ``q``
-    has (row width = Hkv * D), query head h reads KV head
-    h // (H / Hkv). ``lo`` [B] int32 is a lower bound on the columns a
-    row sees (a sliding window: columns < lo[b] contribute exactly 0);
-    None sees every column below ``seq_lens``."""
-    if k_pages.shape[-1] != q.shape[1] * q.shape[2]:
-        return _grouped_reference(q, k_pages, v_pages, block_tables,
-                                  seq_lens, sm_scale, layer, lo)
+    """The dense masked oracle of the tests and of the Pallas kernel's
+    parity checks: every page of every table gathered ([B, P * bs, Hkv,
+    D]), one float32 softmax over the whole extent. No serving program
+    calls it."""
     nb, bs = k_pages.shape[1], k_pages.shape[2]
     b, p = block_tables.shape
     h, d = q.shape[1], q.shape[2]
+    n_kv = k_pages.shape[-1] // d
     scale = sm_scale if sm_scale is not None else d ** -0.5
     tables = jnp.clip(block_tables.astype(jnp.int32), 0, nb - 1)
-    k = _gather_pages(k_pages, layer, tables, h)   # [B, P*bs, H, D]
-    v = _gather_pages(v_pages, layer, tables, h)
-    if k_scales is not None:
-        k = k.astype(jnp.float32) * _gather_pages(k_scales, layer,
-                                                  tables, h)
-        v = v.astype(jnp.float32) * _gather_pages(v_scales, layer,
-                                                  tables, h)
-    logits = jnp.einsum('bhd,bkhd->bhk', (q * scale), k)
-    mask = _seen(p * bs, lo, seq_lens)
-    logits = jnp.where(mask[:, None, :], logits, _NEG_INF)
-    w = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    return jnp.einsum('bhk,bkhd->bhd', w.astype(v.dtype), v)
 
+    def gathered(arena, scales):
+        x = arena[layer, tables].reshape(b, p * bs, n_kv, -1)
+        if scales is None:
+            return x.astype(jnp.float32)
+        return x.astype(jnp.float32) * \
+            scales[layer, tables].reshape(b, p * bs, n_kv, 1)
 
-def _seen(n_cols, lo, hi):
-    """[B, n_cols] bool: columns lo[b] <= j < hi[b] (lo None: 0)."""
-    cols = jnp.arange(n_cols)[None, :]
-    mask = cols < hi.reshape(-1, 1)
+    k, v = gathered(k_pages, k_scales), gathered(v_pages, v_scales)
+    qg = (q * scale).astype(jnp.float32).reshape(b, n_kv, h // n_kv, d)
+    cols = jnp.arange(p * bs)[None, :]
+    seen = cols < seq_lens.reshape(-1, 1)
     if lo is not None:
-        mask &= cols >= lo.reshape(-1, 1)
-    return mask
-
-
-def _grouped_reference(q, k_pages, v_pages, block_tables, seq_lens,
-                       sm_scale, layer, lo):
-    """The gather path for grouped KV heads (unquantized arenas): q
-    [B, H, D] against rows of Hkv * D, scores and softmax in float32."""
-    nb, bs = k_pages.shape[1], k_pages.shape[2]
-    b, p = block_tables.shape
-    h, d = q.shape[1], q.shape[2]
-    n_kv = k_pages.shape[-1] // d
-    scale = sm_scale if sm_scale is not None else d ** -0.5
-    tables = jnp.clip(block_tables.astype(jnp.int32), 0, nb - 1)
-    k = k_pages[layer, tables].reshape(b, p * bs, n_kv * d)
-    v = v_pages[layer, tables].reshape(b, p * bs, n_kv * d)
-    qg = (q * scale).astype(k.dtype).reshape(b, n_kv, h // n_kv, d)
-    mask = _seen(p * bs, lo, seq_lens)[:, None, :]
-    out = []
-    # KV head by KV head on slices of the gathered rows' minor axis: a
-    # head is a whole number of lane tiles there (D = 128), so a slice
-    # feeds the product as it lies; splitting the axis into [Hkv, D]
-    # and batching over Hkv had the compiler re-lay the gathered pages
-    # head-major first (0.4 GB each for K and V a layer at 32 x 6,656
-    # tokens; v5e compile, PR 28)
-    for n in range(n_kv):
-        kn = k[:, :, n * d:(n + 1) * d]
-        vn = v[:, :, n * d:(n + 1) * d]
-        logits = jnp.einsum('bgd,bkd->bgk', qg[:, n], kn,
-                            preferred_element_type=jnp.float32)
-        w = jax.nn.softmax(jnp.where(mask, logits, _NEG_INF), axis=-1)
-        out.append(jnp.einsum('bgk,bkd->bgd', w.astype(vn.dtype), vn,
-                              preferred_element_type=jnp.float32))
-    return jnp.stack(out, axis=1).reshape(b, h, d)
-
-
-def paged_attention_one_table(q, k_pages, v_pages, table, lo, hi,
-                              sm_scale=None, layer=0, block_cols=512):
-    """Consecutive rows of ONE sequence (a prefill chunk) against that
-    sequence's pages, gathered once: q [S, H, D], ``table`` [P], row s
-    sees columns lo[s] <= j < hi[s]. The per-row form above gathers
-    the table once per row ([S, P, bs, W]), which is what kept prompts
-    at 512 (benchmark/configs/tbig_lm.json); here the gather is [P, bs,
-    W] whatever S is.
-
-    The columns go in blocks of whole pages (about ``block_cols``), from
-    the block that holds the smallest ``lo`` to the one that holds the
-    largest ``hi`` and no further, under a running softmax (maximum,
-    normaliser and weighted sum carried from block to block, float32):
-    a chunk at the start of a prompt multiplies one block and not the
-    table's whole extent, a chunk deep in a sliding layer its window's
-    blocks, and the scores alive at a time are [H, S, block], not
-    [H, S, P * bs]. Which blocks run depends on the chunk's place in
-    its own sequence only, so a row's result does not depend on what
-    else the engine holds. Grouped heads as in
-    ``paged_attention_reference``; unquantized arenas only."""
-    nb, bs = k_pages.shape[1], k_pages.shape[2]
-    s, h, d = q.shape
-    n_kv = k_pages.shape[-1] // d
-    group = h // n_kv
-    scale = sm_scale if sm_scale is not None else d ** -0.5
-    table = jnp.clip(table.astype(jnp.int32), 0, nb - 1)
-    n_pages = table.shape[0]
-    cols = n_pages * bs
-    # whole pages a block, a divisor of the table so every block is full
-    per_block = max(p for p in range(1, n_pages + 1)
-                    if n_pages % p == 0 and p * bs <= max(block_cols, bs))
-    bk = per_block * bs
-    k = k_pages[layer, table].reshape(cols, n_kv, d)
-    v = v_pages[layer, table].reshape(cols, n_kv, d)
-    qg = jnp.transpose((q * scale).astype(k.dtype).reshape(
-        s, n_kv, group, d), (1, 2, 0, 3))                  # [Hkv, G, S, D]
-    first = jnp.clip(jnp.min(lo) // bk, 0, cols // bk - 1)
-    last = jnp.clip((jnp.max(hi) - 1) // bk, 0, cols // bk - 1)
-
-    def block(j, state):
-        top, norm, acc = state
-        kb = jax.lax.dynamic_slice_in_dim(k, j * bk, bk, 0)  # [bk, Hkv, D]
-        vb = jax.lax.dynamic_slice_in_dim(v, j * bk, bk, 0)
-        at = j * bk + jnp.arange(bk)
-        seen = (at[None, :] >= lo[:, None]) & (at[None, :] < hi[:, None])
-        scores = jnp.einsum('ngsd,knd->ngsk', qg, kb,
-                            preferred_element_type=jnp.float32)
-        scores = jnp.where(seen[None, None], scores, _NEG_INF)
-        new_top = jnp.maximum(top, jnp.max(scores, axis=-1))
-        w = jnp.where(seen[None, None],
-                      jnp.exp(scores - new_top[..., None]), 0.0)
-        keep = jnp.exp(top - new_top)
-        norm = keep * norm + jnp.sum(w, axis=-1)
-        acc = keep[..., None] * acc + jnp.einsum(
-            'ngsk,knd->ngsd', w.astype(vb.dtype), vb,
-            preferred_element_type=jnp.float32)
-        return new_top, norm, acc
-
-    init = (jnp.full((n_kv, group, s), _NEG_INF, jnp.float32),
-            jnp.zeros((n_kv, group, s), jnp.float32),
-            jnp.zeros((n_kv, group, s, d), jnp.float32))
-    _, norm, acc = jax.lax.fori_loop(first, last + 1, block, init)
-    out = acc / jnp.where(norm == 0.0, 1.0, norm)[..., None]
-    return jnp.transpose(out, (2, 0, 1, 3)).reshape(s, h, d)
+        seen &= cols >= lo.reshape(-1, 1)
+    logits = jnp.einsum('bngd,bknd->bngk', qg, k,
+                        precision=jax.lax.Precision.HIGHEST)
+    w = jax.nn.softmax(jnp.where(seen[:, None, None], logits, _NEG_INF),
+                       axis=-1)
+    out = jnp.einsum('bngk,bknd->bngd', w, v,
+                     precision=jax.lax.Precision.HIGHEST)
+    # a row that sees nothing yields 0, as in the blocked forms
+    return out.reshape(b, h, d) * jnp.any(seen, axis=1)[:, None, None]
 
 
 def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -374,7 +515,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
             and _use_pallas(q, k_pages, v_pages, block_tables):
         return _paged_pallas(q, k_pages, v_pages, block_tables, seq_lens,
                              scale, layer=layer)
-    return paged_attention_reference(q, k_pages, v_pages, block_tables,
-                                     seq_lens, sm_scale=scale,
-                                     k_scales=k_scales,
-                                     v_scales=v_scales, layer=layer, lo=lo)
+    return paged_attention_blocked(q, k_pages, v_pages, block_tables,
+                                   seq_lens, sm_scale=scale,
+                                   k_scales=k_scales, v_scales=v_scales,
+                                   layer=layer, lo=lo)

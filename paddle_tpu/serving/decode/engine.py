@@ -50,6 +50,7 @@ continuous batching, prefix caching, and speculation are pure
 throughput wins, never a correctness trade.
 """
 
+import collections
 import contextlib
 import itertools
 import threading
@@ -880,10 +881,12 @@ class DecodeEngine(object):
                                       priority=seq.priority)
             seq.published_pages = full
 
-    def _step_feeds(self, batch):
+    def _step_feeds(self, batch, tokens_per_row=1):
         """``(lens, tables, temps, seeds)`` of one step over ``batch`` at
         the fixed [max_batch] signature; the rows past the batch point
-        every page beyond the pool, so their writes drop."""
+        every page beyond the pool, so their writes drop.
+        ``tokens_per_row`` is what the step scores per row (k + 1 under
+        speculation), for the counters only."""
         mb, pps, nb = self.max_batch, self.pages_per_seq, self.num_blocks
         lens = np.zeros((mb,), 'int32')
         tables = np.full((mb, pps), nb, 'int32')
@@ -898,6 +901,7 @@ class DecodeEngine(object):
             # the KV positions this step attends over
             _obs.record('decode.step_live_tokens', int(lens.sum()))
             _obs.inc('decode.step_rows', len(batch))
+            self._count_attn_pages(lens, len(batch), tokens_per_row)
             window = self.spec.sliding_window
             if window:
                 # rows whose sliding layers no longer see their first
@@ -907,6 +911,26 @@ class DecodeEngine(object):
                 _obs.record('decode.step_window_tokens',
                             int(np.minimum(lens, window).sum()))
         return lens, tables, temps, seeds
+
+    def _count_attn_pages(self, lens, rows, k1):
+        """How far the attention's bounds engage: the pages this step's
+        row and column blocks cover, summed over the layers (from the
+        function of the lengths that gives the program its loop bounds,
+        ops/pallas/paged_attention.py), beside the pages its tables
+        can address."""
+        from ...ops.pallas.paged_attention import pages_covered
+        pos = (lens[:, None] + np.arange(k1, dtype='int32')).reshape(-1)
+        live = (np.arange(len(pos)) < rows * k1) & (pos < self.capacity)
+        hi = np.where(live, pos + 1, 0)
+        read = 0
+        for window, layers in collections.Counter(
+                self.spec.windows()).items():
+            lo = np.maximum(hi - window, 0) if window else np.zeros_like(hi)
+            read += layers * int(pages_covered(
+                lo, hi, self.pages_per_seq, self.block_size, np))
+        _obs.inc('decode.attn_pages_read', read)
+        _obs.inc('decode.attn_pages_reachable',
+                 self.spec.n_layer * len(pos) * self.pages_per_seq)
 
     def _timed_step(self, dispatch, tokens, feeds, rows):
         """Enqueue one step, then block on its fetch: ``(tokens on the
@@ -1010,7 +1034,7 @@ class DecodeEngine(object):
                 drafts.append(d)
                 tokens[i, 0] = seq.pending_token
                 tokens[i, 1:] = d
-            feeds = self._step_feeds([seq for seq, _ in pairs])
+            feeds = self._step_feeds([seq for seq, _ in pairs], k + 1)
         nxt, now = self._timed_step(self._dispatch_verify, tokens, feeds,
                                     len(pairs))
         nxt = nxt.reshape(tokens.shape)

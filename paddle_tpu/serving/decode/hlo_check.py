@@ -1,24 +1,33 @@
-"""Does a compiled paged program move an arena? Read from its HLO.
+"""Does a compiled paged program move an arena, or gather a whole
+table? Read from its HLO.
 
 The paged ops (ops/paged_decode_ops.py) write the KV arenas in place:
 in the program the compiler hands back, the only instruction that may
-touch arena-sized data is the attention gather. Whether that holds is
-a property of the *optimised* HLO (a layout the TPU picks, a scatter
-it re-lays its operand for, a loop output it double-buffers all show
-up there as ``copy`` instructions and nowhere in the jaxpr), so this
-module reads ``compiled.as_text()`` — on the CPU for a described chip,
-or on the chip itself (chip_smoke.py) — and needs no trace.
+touch arena-sized data is an attention gather, and since the attention
+goes in blocks of rows and columns (ops/pallas/paged_attention.py) a
+gather's own result is a block's pages, never the extent of the
+batch's tables. Whether that holds is a property of the *optimised*
+HLO (a layout the TPU picks, a scatter it re-lays its operand for, a
+loop output it double-buffers all show up there as ``copy``
+instructions and nowhere in the jaxpr), so this module reads
+``compiled.as_text()`` — on the CPU for a described chip, or on the
+chip itself (chip_smoke.py) — and needs no trace.
 
 ``arena_sized_instructions(text, min_elements)`` lists the
-instructions that materialise ``min_elements`` or more (callers pass a
-layer's arena elements, NB * bs * H * D):
+instructions that materialise ``min_elements`` or more:
 
 - every ``copy`` (and ``copy-done``) of that size, wherever it is;
 - every other instruction of that size, except those that move
   nothing (parameters, tuples and their elements, bitcasts, loops), an
-  in-place ``dynamic-update-slice`` (alone or as a fusion), the gather
-  itself, and what only consumes the gather's result: the attention's
-  own read, whose extent is the batch's page tables and not the pool.
+  in-place ``dynamic-update-slice`` (alone or as a fusion) and, unless
+  ``gathers=True``, a gather (alone or as a fusion): it reads the
+  arena and writes a block.
+
+Callers pass a layer's arena elements, NB * bs * H * D (what consumes
+a gather's result is not exempt: a block's pages are far under an
+arena wherever the pool is larger than BLOCK_ROWS tables' column
+block), or, with ``gathers=True``, the whole-table extent
+N * P * bs * H * D that no instruction of a serving program may reach.
 
 Instructions inside a fusion never reach memory and are skipped; a
 fusion counts by what it calls.
@@ -42,7 +51,6 @@ _COMPUTATION = re.compile(
 _ASSIGN = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$')
 _ARRAY = re.compile(r'\b[a-z]+[0-9]*[a-z0-9]*\[([0-9,]*)\]')
 _OPCODE = re.compile(r'^\s*([\w\-]+)\(')
-_OPERAND = re.compile(r'%?([A-Za-z_][\w.\-]*)')
 _CALLS = re.compile(r'\bcalls=%?([\w.\-]+)')
 
 
@@ -72,8 +80,8 @@ def _elements(shape):
 
 
 def _parse(text):
-    """{computation: [(name, opcode, shape, operands, callee)]} in
-    program order."""
+    """{computation: [(name, opcode, shape, callee)]} in program
+    order."""
     comps = collections.OrderedDict()
     cur = None
     for line in text.splitlines():
@@ -96,24 +104,21 @@ def _parse(text):
         op = _OPCODE.match(rest)
         if not op:
             continue
-        args_at = rest.index('(')
-        args_end = _balanced(rest, args_at)
-        operands = _OPERAND.findall(re.sub(
-            r'/\*.*?\*/', '', rest[args_at + 1:args_end - 1]))
-        callee = _CALLS.search(rest[args_end:])
-        cur.append((name, op.group(1), shape, operands,
+        callee = _CALLS.search(rest[_balanced(rest, rest.index('(')):])
+        cur.append((name, op.group(1), shape,
                     callee.group(1) if callee else None))
     return comps
 
 
-def arena_sized_instructions(hlo_text, min_elements):
-    """The instructions of an optimised HLO module that copy or
-    re-lay ``min_elements`` or more outside the attention gather (see
-    the module docstring); empty when the arenas are written in
-    place. Returns ``Instruction`` tuples in program order."""
+def arena_sized_instructions(hlo_text, min_elements, gathers=False):
+    """The instructions of an optimised HLO module that materialise
+    ``min_elements`` or more (see the module docstring), gathers
+    included only with ``gathers=True``; empty when the arenas are
+    written in place and no table is gathered whole. Returns
+    ``Instruction`` tuples in program order."""
     comps = _parse(hlo_text)
     fused = {callee for body in comps.values()
-             for _, opcode, _, _, callee in body
+             for _, opcode, _, callee in body
              if opcode == 'fusion' and callee}
 
     def kind(opcode, callee):
@@ -121,11 +126,11 @@ def arena_sized_instructions(hlo_text, min_elements):
         if opcode != 'fusion':
             return opcode
         inner = comps.get(callee, ())
-        if any(op == 'gather' for _, op, _, _, _ in inner):
+        if any(op == 'gather' for _, op, _, _ in inner):
             return 'gather'
         if any(op == 'dynamic-update-slice'
                and _elements(shape) >= min_elements
-               for _, op, shape, _, _ in inner):
+               for _, op, shape, _ in inner):
             return 'dynamic-update-slice'
         return 'fusion'
 
@@ -133,24 +138,13 @@ def arena_sized_instructions(hlo_text, min_elements):
     for cname, body in comps.items():
         if cname in fused:
             continue
-        size = {}
-        from_gather = set()
-        for name, opcode, shape, operands, callee in body:
-            n = size[name] = _elements(shape)
+        for name, opcode, shape, callee in body:
+            n = _elements(shape)
             if n < min_elements:
                 continue
             what = kind(opcode, callee)
-            if what == 'gather':
-                from_gather.add(name)
-                continue
-            if what in ('copy', 'copy-done'):
-                found.append(Instruction(name, opcode, shape, n, cname))
-                continue
-            if what in _MOVES_NOTHING or what == 'dynamic-update-slice':
-                continue
-            big = [o for o in operands if size.get(o, 0) >= min_elements]
-            if big and all(o in from_gather for o in big):
-                from_gather.add(name)
+            if what in _MOVES_NOTHING or what == 'dynamic-update-slice' \
+                    or (what == 'gather' and not gathers):
                 continue
             found.append(Instruction(name, opcode, shape, n, cname))
     return found

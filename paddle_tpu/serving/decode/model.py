@@ -365,11 +365,8 @@ def build_lm_programs(spec, max_batch, block_size, num_blocks,
                        'Seed': [seed]})
         outputs = dict(_arena_outputs(kc, vc, ks, vs),
                        NextToken=[nxt])
-        # one_table: this block's prefill gathers the sequence's pages
-        # once for the chunk (ops/pallas/paged_attention.py)
         helper.append_op(type='paged_prefill', inputs=inputs,
-                         outputs=outputs,
-                         attrs=dict(attrs, one_table=moe))
+                         outputs=outputs, attrs=attrs)
         prefill_fetch = nxt.name
 
     with program_guard(decode_prog, startup):

@@ -271,7 +271,7 @@ def decide_paged_attention(b, p, h, bs, d, dv, dtype):
 
     def xla_thunk():
         args = mk_inputs()
-        return jax.jit(_pa.paged_attention_reference)(*args)
+        return jax.jit(_pa.paged_attention_blocked)(*args)
 
     candidates = [({'impl': 'xla'}, xla_thunk)]
     if bs % 8 == 0 and d % 8 == 0:   # kernel wants lane-aligned tiles

@@ -257,6 +257,38 @@ def test_programs_and_ops_carry_names_a_trace_can_be_searched_by():
     assert not exe.last_cache_miss
 
 
+@pytest.mark.parametrize('lengths,per_row,read,off_counts', [
+    # 4 slots x 4 pages of 4: one row block, one column block (the
+    # table's 16 columns fit one): 8 rows x 4 pages a layer, 2 layers
+    ((5, 9, 2), 1, 2 * 8 * 4, False),
+    ((15,), 1, 2 * 8 * 4, False),
+    ((), 1, 0, False),                    # nothing live: no block runs
+    # speculation, k + 1 = 3 rows a slot: 12 rows, 9 live -> two row
+    # blocks, each over the one column block
+    ((5, 9, 2), 3, 2 * 2 * 8 * 4, False),
+    ((5, 9, 2), 1, 0, True),              # observe off: nothing counted
+])
+def test_attn_pages_of_a_hand_built_batch(lengths, per_row, read,
+                                          off_counts):
+    """``decode.attn_pages_read`` is what the step's row and column
+    blocks cover, summed over the layers, beside the pages its tables
+    can address."""
+    if not off_counts:
+        observe.enable()
+    eng = _engine()
+    batch = []
+    for i, length in enumerate(lengths):
+        seq = Sequence(i + 1, [7] * length, 4, 0.0, i, None)
+        seq.cache_len = length
+        batch.append(seq)
+    eng._step_feeds(batch, per_row)
+    counters = observe.snapshot()['counters']
+    assert counters.get('decode.attn_pages_read', 0) == read
+    assert counters.get('decode.attn_pages_reachable', 0) == \
+        (0 if off_counts else 2 * 4 * per_row * 4)
+    eng.shutdown(drain=False)
+
+
 def test_live_tokens_of_a_hand_built_batch():
     observe.enable()
     eng = _engine()

@@ -12,8 +12,8 @@ arena, at a toy geometry on the CPU:
   the three drop cases (row not live, position past the table's
   capacity, table entry >= NB) included.
 
-Plus the HLO reader the chip's smoke run uses
-(serving/decode/hlo_check.py) on two canned modules.
+Plus the HLO reader the chip's smoke run and tests/test_v5e_compile.py
+use (serving/decode/hlo_check.py) on two canned modules.
 """
 
 import re
@@ -336,13 +336,21 @@ ENTRY %main (a: f32[2,8,2,4,8]) -> f32[2,8,2,4,8] {
 '''
 
 
-@pytest.mark.parametrize('text,want', [
-    (_CLEAN, []),
-    (_COPIES, ['fusion.26', 'copy.116', 'copy.117', 'fusion.153',
-               'copy.121', 'copy.164']),
-], ids=['in_place', 'parent_shape'])
-def test_hlo_reader_counts_arena_sized_instructions(text, want):
-    layer = 8 * 4 * 16          # one layer's arena elements in both
-    found = arena_sized_instructions(text, layer)
+@pytest.mark.parametrize('text,layer,gathers,want', [
+    (_CLEAN, 8 * 4 * 16, False, []),
+    (_COPIES, 8 * 4 * 16, False,
+     ['fusion.26', 'copy.116', 'copy.117', 'fusion.153', 'copy.121',
+      'copy.164']),
+    # at the extent of what the gather yields (3 tables x 2 pages): what
+    # consumes a gather's result is not exempt, and with gathers=True
+    # neither is the gather; the in-place update and the loop still are
+    (_CLEAN, 6 * 4 * 16, False, ['reshape.9']),
+    (_CLEAN, 6 * 4 * 16, True, ['fusion.3', 'reshape.9']),
+], ids=['in_place', 'parent_shape', 'gather_consumer', 'whole_table'])
+def test_hlo_reader_counts_arena_sized_instructions(text, layer, gathers,
+                                                    want):
+    # ``layer``: one layer's arena elements in both modules, or the
+    # elements of the tables' whole extent
+    found = arena_sized_instructions(text, layer, gathers=gathers)
     assert [i.name for i in found] == want
     assert all(i.elements >= layer for i in found)

@@ -216,8 +216,8 @@ def test_one_table_attention_in_blocks_is_the_dense_softmax(start, window,
     hi = pos + 1
     lo = np.maximum(hi - window, 0) if window else np.zeros_like(pos)
     got = paged_attention_one_table(
-        q, k_arena, v_arena, table, jnp.asarray(lo, jnp.int32),
-        jnp.asarray(hi, jnp.int32), layer=1, block_cols=block_cols)
+        q, k_arena, v_arena, table, jnp.asarray(hi, jnp.int32), layer=1,
+        lo=jnp.asarray(lo, jnp.int32), block_cols=block_cols)
     k = np.asarray(k_arena)[1][np.asarray(table)].reshape(-1, kv_heads, d)
     v = np.asarray(v_arena)[1][np.asarray(table)].reshape(-1, kv_heads, d)
     want = np.zeros((rows, heads, d), 'float32')

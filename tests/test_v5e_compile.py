@@ -1,9 +1,11 @@
-"""What the v5e's compiler makes of the routed block's two products at
-the published widths, compiled here for a described chip (nothing runs,
-no time is measured): the expert product over layer-stacked weights and
-the grouped-head gather attention each once cost a copy of their largest
-operand (PERF.md, PR 28), and these tests keep that from coming back.
-One file, the topology in a fixture (on-chip-measurement guide, 2)."""
+"""What the v5e's compiler makes of the serving programs' two costly
+parts at the published widths, compiled here for a described chip
+(nothing runs, no time is measured): the expert product over
+layer-stacked weights once cost a copy of its largest operand and the
+attention once gathered every page of every table and re-laid what it
+had gathered (PERF.md, PR 26, PR 28, PR 29), and these tests keep that
+from coming back. One file, the topology in a fixture
+(on-chip-measurement guide, 2)."""
 
 import re
 
@@ -76,21 +78,64 @@ def test_expert_product_reads_the_stacked_weights_where_they_lie(one_chip):
     assert _materialized(hlo, E * D * F * 2 // 4) == []
 
 
-def test_grouped_gather_attention_does_not_relay_the_pages(one_chip):
-    """32 rows x 208 pages x 32 slots of 8 KV heads x 128 under 128 query
-    heads: the two gathers (0.44 GB each) are the only instructions of
-    that size; nothing re-lays what they gathered."""
-    from paddle_tpu.ops.pallas.paged_attention import paged_attention
-    B, P, BS, NB, H, HKV, D = 32, 208, 32, 4096, 128, 8, 128
+# name -> (LMSpec arguments, engine arguments): both serving
+# configurations of the benchmark at their own attention geometry
+# (heads, head width, arena dtype, slots, pages a table, page, top
+# prefill bucket), cut in depth, pool, vocabulary and FFN width, which
+# no attention instruction is sized by. The pool is smaller than the
+# tables' extent, so that nothing but a whole-table gather or what
+# consumes one reaches max_batch x pages_per_seq pages.
+SERVING = {
+    'tbig_lm': (
+        dict(vocab_size=256, n_layer=2, n_head=16, d_key=64, d_value=64,
+             d_model=1024, d_inner=256),
+        dict(max_batch=64, block_size=32, pages_per_seq=24, num_blocks=512,
+             max_prompt_len=512, kv_dtype='float32')),
+    'command_a_plus': (
+        dict(vocab_size=256, n_layer=2, n_head=128, n_kv_head=8, d_key=128,
+             d_value=128, d_model=256, d_inner=64, block='parallel_moe',
+             layer_types=('sliding_attention', 'full_attention'),
+             sliding_window=4096, rope_theta=50000.0, n_experts=8,
+             experts_held=2, experts_per_token=2, n_shared_experts=1,
+             dtype='bfloat16'),
+        dict(max_batch=32, block_size=32, pages_per_seq=208,
+             num_blocks=1024, max_prompt_len=6144, prefill_chunk=512,
+             min_prompt_bucket=512, kv_dtype='bfloat16')),
+}
 
-    def attend(q, k, v, tables, lens, lo):
-        return paged_attention(q, k, v, tables, lens, layer=1, lo=lo)
 
-    arena = _shaped(one_chip, (4, NB, BS, HKV * D), jnp.bfloat16)
-    ints = _shaped(one_chip, (B,), jnp.int32)
-    hlo = jax.jit(attend).lower(
-        _shaped(one_chip, (B, H, D), jnp.float32), arena, arena,
-        _shaped(one_chip, (B, P), jnp.int32), ints, ints).compile().as_text()
-    gathered = B * P * BS * HKV * D * 2
-    big = _materialized(hlo, gathered // 2)
-    assert len(big) == 2 and all(n == gathered for _, _, n in big), big
+@pytest.fixture(scope='module', params=sorted(SERVING))
+def engine(request):
+    from paddle_tpu.serving.decode import DecodeEngine, LMSpec
+    spec, sizes = SERVING[request.param]
+    eng = DecodeEngine(LMSpec(**spec), **sizes)
+    yield eng
+    eng.shutdown(drain=False)
+
+
+@pytest.mark.parametrize('which', ['decode', 'prefill'])
+def test_no_serving_program_gathers_a_whole_table(one_chip, engine, which):
+    """The decode step and the largest prefill bucket of both blocks,
+    as the engine jits them: no instruction, gathers included,
+    materialises max_batch x pages_per_seq pages (the extent the parent
+    gathered, re-tiled and multiplied a layer whatever the rows held),
+    and the gathers that are there are a block's: at most 8 tables x
+    one column block of pages."""
+    from jax.extend.core import jaxpr_as_fun
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    from paddle_tpu.serving.decode.hlo_check import arena_sized_instructions
+    closed = engine.trace_program(
+        'decode' if which == 'decode' else engine.prompt_buckets[-1]).jaxpr
+    hlo = jax.jit(jaxpr_as_fun(closed)).lower(
+        *[_shaped(one_chip, a.shape, a.dtype)
+          for a in closed.in_avals]).compile().as_text()
+    page = engine.block_size * engine.spec.n_kv_head * engine.spec.d_key
+    assert arena_sized_instructions(
+        hlo, engine.max_batch * engine.pages_per_seq * page,
+        gathers=True) == []
+    per = pa.pages_per_block(engine.pages_per_seq, engine.block_size)
+    others = arena_sized_instructions(hlo, page)
+    gathers = [i for i in arena_sized_instructions(hlo, page, gathers=True)
+               if i not in others]
+    assert gathers, 'no attention gather found'
+    assert max(i.elements for i in gathers) <= pa.BLOCK_ROWS * per * page
