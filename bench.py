@@ -1283,7 +1283,7 @@ def bench_autoscale(in_dim=8, max_batch=8, max_queue_depth=12,
     test asserts):
 
     1. **flash crowd** — offered load jumps ~15x; the controller must
-       scale out (AOT-warm spawns) before the error budget burns
+       scale out before the error budget burns
        through: burn spikes >1x then recovers <1x within the run,
        with zero accepted-request loss.
     2. **crash loop** — one replica slot is killed repeatedly
@@ -1316,13 +1316,15 @@ def bench_autoscale(in_dim=8, max_batch=8, max_queue_depth=12,
     from paddle_tpu.inference import create_predictor
 
     delay_s = float(compute_delay_ms) / 1000.0
-    aot_dir = os.path.join(os.path.dirname(model_dir), 'aot_cache')
+    # one loaded program and one executor under every replica of this
+    # process: the first warmup compiles the ladder, and a spawn's
+    # warmup (the scale-up path) is served by the executor's in-memory
+    # cache instead of compiling the same program again
+    pred = _ChaosPredictor(create_predictor(model_dir), delay_s)
 
     def make_engine(name):
-        """The ReplicaFactory: a fresh predictor over the shared AOT
-        executable cache, so every spawn after the first warm-starts
-        from serialized executables instead of compiling."""
-        pred = _ChaosPredictor(create_predictor(model_dir), delay_s)
+        """The ReplicaFactory: an engine of its own (queue, batcher,
+        thread) over the one loaded model."""
         return ServingEngine(pred, max_batch_size=max_batch,
                              batch_timeout_ms=1.0,
                              max_queue_depth=max_queue_depth,
@@ -1467,14 +1469,8 @@ def bench_autoscale(in_dim=8, max_batch=8, max_queue_depth=12,
             'failovers': delta('router.failover_total'),
         }, **chaos_result)
 
-    prev = {k: os.environ.get(k) for k in
-            ('PADDLE_TPU_TRACE_SAMPLE', 'PADDLE_TPU_AOT_CACHE',
-             'PADDLE_TPU_AOT_CACHE_DIR')}
+    prev_sample = os.environ.get('PADDLE_TPU_TRACE_SAMPLE')
     os.environ['PADDLE_TPU_TRACE_SAMPLE'] = str(trace_sample)
-    # spawns ride the AOT executable cache: the first warmup populates
-    # it, every later spawn (the scale-up path) deserializes
-    os.environ['PADDLE_TPU_AOT_CACHE'] = '1'
-    os.environ['PADDLE_TPU_AOT_CACHE_DIR'] = aot_dir
     try:
         # 1 — flash crowd: must scale out before the budget burns away
         flash = run_scenario(
@@ -1521,11 +1517,10 @@ def bench_autoscale(in_dim=8, max_batch=8, max_queue_depth=12,
                         scale_out_cooldown_s=1e9, queue_high=1e9,
                         burn_high=1e9))
     finally:
-        for k, v in prev.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
+        if prev_sample is None:
+            os.environ.pop('PADDLE_TPU_TRACE_SAMPLE', None)
+        else:
+            os.environ['PADDLE_TPU_TRACE_SAMPLE'] = prev_sample
 
     # the hedging contract across all three scenarios: retry traffic
     # (every dispatch past each request's primary) never exceeded the
@@ -1604,7 +1599,6 @@ def bench_crosshost(in_dim=8, max_batch=4, max_queue_depth=16,
                                             percentiles)
 
     model_dir = _save_chaos_model(in_dim)
-    aot_dir = os.path.join(os.path.dirname(model_dir), 'aot_cache')
     delay_s = float(compute_delay_ms) / 1000.0
 
     # the typed vocabulary: every error a chaos run is ALLOWED to
@@ -1882,15 +1876,8 @@ def bench_crosshost(in_dim=8, max_batch=4, max_queue_depth=16,
                 'mismatches': mismatches,
                 'bit_identical': mismatches == 0}
 
-    prev = {k: os.environ.get(k) for k in
-            ('PADDLE_TPU_TRACE_SAMPLE', 'PADDLE_TPU_AOT_CACHE',
-             'PADDLE_TPU_AOT_CACHE_DIR')}
+    prev_sample = os.environ.get('PADDLE_TPU_TRACE_SAMPLE')
     os.environ['PADDLE_TPU_TRACE_SAMPLE'] = str(trace_sample)
-    # the AOT executable cache dir is INHERITED by every worker spawn:
-    # the first worker's warmup populates it, every later spawn (the
-    # heal path under chaos) warm-starts from serialized executables
-    os.environ['PADDLE_TPU_AOT_CACHE'] = '1'
-    os.environ['PADDLE_TPU_AOT_CACHE_DIR'] = aot_dir
     try:
         kill = run_scenario(
             'kill', kill_qps, kill_duration, n_start=2,
@@ -1919,11 +1906,10 @@ def bench_crosshost(in_dim=8, max_batch=4, max_queue_depth=16,
             chaos=crash_chaos)
         identity = identity_leg()
     finally:
-        for k, v in prev.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
+        if prev_sample is None:
+            os.environ.pop('PADDLE_TPU_TRACE_SAMPLE', None)
+        else:
+            os.environ['PADDLE_TPU_TRACE_SAMPLE'] = prev_sample
 
     result = {
         'workload': 'crosshost',
@@ -2507,28 +2493,11 @@ def bench_disagg(duration=5.0, clients=10, n_prefill=1, n_decode=2,
                 - counter_sum(snap0, 'decode.preemptions_total'),
         }
 
-    # every engine in both legs builds the same three programs — ride
-    # the AOT executable cache so engine #2..N deserialize their
-    # prefill ladder instead of re-compiling it (the same trick the
-    # autoscale bench uses for ~0.1s spawns)
-    import tempfile
-    prev = {k: os.environ.get(k) for k in
-            ('PADDLE_TPU_AOT_CACHE', 'PADDLE_TPU_AOT_CACHE_DIR')}
-    os.environ['PADDLE_TPU_AOT_CACHE'] = '1'
-    os.environ['PADDLE_TPU_AOT_CACHE_DIR'] = \
-        tempfile.mkdtemp(prefix='paddle_tpu_disagg_aot_')
-    try:
-        observe.flush(kind='snapshot')
-        coloc = run_leg('coloc', disagg=False)
-        observe.flush(kind='snapshot')
-        split = run_leg('disagg', disagg=True)
-        observe.flush(kind='snapshot')
-    finally:
-        for k, v in prev.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
+    observe.flush(kind='snapshot')
+    coloc = run_leg('coloc', disagg=False)
+    observe.flush(kind='snapshot')
+    split = run_leg('disagg', disagg=True)
+    observe.flush(kind='snapshot')
 
     p99_coloc = coloc['inter_token_ms'].get('p99')
     p99_disagg = split['inter_token_ms'].get('p99')
@@ -2761,12 +2730,7 @@ def bench_autotune(seqs=(1024, 4096), batch_tokens=4096, d=64, heads=8,
     (PADDLE_TPU_AUTOTUNE=on), then the tuner's pick is timed against
     the env-gated default (XLA, since PADDLE_TPU_USE_PALLAS is unset).
     `winners_differ` records whether the winner flips between the
-    shapes; the table lands at `table_path` for tools/tuning_inspect.py.
-
-    The cold/warm AOT start-up pair is not a leg here: this process
-    holds the chip, so children it spawned could not get the device.
-    `--workload autotune_child` is one such start-up measurement;
-    tests/test_tuning.py runs the pair on the CPU."""
+    shapes; the table lands at `table_path` for tools/tuning_inspect.py."""
     import tempfile
     import jax
     import jax.numpy as jnp
@@ -2821,40 +2785,6 @@ def bench_autotune(seqs=(1024, 4096), batch_tokens=4096, d=64, heads=8,
     out['table_entries'] = tuning.current_table().size()
     out['table_path'] = table_path
     return out
-
-
-def _autotune_startup_child():
-    """One cold-or-warm startup measurement: build a trainer-shaped MLP
-    program, run two steps, report wall from entry to the first fetch
-    plus the executor's AOT ledger and the compile flight-event count
-    (zero on a warm run — the acceptance check)."""
-    from paddle_tpu import observe
-    observe.arm_flight()    # count 'compile' events even with metrics off
-    t0 = time.perf_counter()
-    fluid = _fresh()
-    x = fluid.layers.data(name='x', shape=[256], dtype='float32')
-    y = fluid.layers.data(name='y', shape=[1], dtype='float32')
-    h = x
-    for _ in range(4):
-        h = fluid.layers.fc(input=h, size=256, act='relu')
-    pred = fluid.layers.fc(input=h, size=1)
-    cost = fluid.layers.mean(fluid.layers.square_error_cost(
-        input=pred, label=y))
-    fluid.optimizer.SGD(learning_rate=0.01).minimize(cost)
-    exe = fluid.Executor(fluid.TPUPlace(0))
-    exe.run(fluid.default_startup_program())
-    feed = {'x': np.ones((8, 256), 'float32'),
-            'y': np.ones((8, 1), 'float32')}
-    first = exe.run(feed=feed, fetch_list=[cost])
-    startup = time.perf_counter() - t0
-    np.asarray(exe.run(feed=feed, fetch_list=[cost])[0])
-    compiles = sum(1 for e in observe.flight_recorder().events()
-                   if e.get('kind') == 'compile')
-    return {'startup_seconds': round(startup, 4),
-            'first_loss': float(np.asarray(first[0]).reshape(())),
-            'aot_hits': exe.aot_stats['hits'],
-            'aot_saves': exe.aot_stats['saves'],
-            'compile_flight_events': compiles}
 
 
 def bench_verify(batch=8, seq=64, vocab=32000, iters=10):
@@ -3170,7 +3100,6 @@ WORKLOADS = {
     'disagg': bench_disagg,
     'linalg': bench_linalg,
     'autotune': bench_autotune,
-    'autotune_child': _autotune_startup_child,
     'verify': bench_verify,
     'crosshost': bench_crosshost,
     'multitenant': bench_multitenant,

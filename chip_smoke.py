@@ -13,7 +13,8 @@ at Transformer-base width on one TPU:
              copies (none allowed), start(), concurrent submit()s, then
              the same prompts one at a time
   kernels    every Pallas kernel in the tree, compiled for the chip
-             (interpret=False) and compared with its jnp reference
+             (interpret=False) and compared with its jnp reference; the
+             blocked paged attention against its dense oracle
   four_chip  the train leg over make_mesh(dp=2, tp=2) with ZeRO-1, when
              jax sees four devices; otherwise skipped and said so
 
@@ -396,7 +397,7 @@ def check_paged(model, eng):
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops.pallas.paged_attention import (
-        _paged_pallas, paged_attention_reference)
+        paged_attention_blocked, paged_attention_reference)
     b, p = eng['max_batch'], eng['pages_per_seq']
     nb, bs = eng['num_blocks'], eng['block_size']
     h, d, dv = model['n_head'], model['d_key'], model['d_value']
@@ -408,11 +409,11 @@ def check_paged(model, eng):
     tables = jnp.asarray(rng.permutation(nb)[:b * p].reshape(b, p),
                          jnp.int32)
     lens = jnp.asarray(np.linspace(1, p * bs, b).astype('int32'))
-    got, secs = timed(jax.jit(lambda *a: _paged_pallas(*a, d ** -0.5)),
+    got, secs = timed(jax.jit(paged_attention_blocked),
                       q, kp, vp, tables, lens)
     want = paged_attention_reference(q, kp, vp, tables, lens)
-    # on the chip both sides multiply f32 operands in bf16 passes (XLA's
-    # default precision) and sum in different orders
+    # the running softmax over blocks and the dense one sum in different
+    # orders
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-2, rtol=1e-2)
     return secs

@@ -147,8 +147,7 @@ class StepHandle(object):
 
 class _Compiled(object):
     __slots__ = ('fn', 'raw_fn', 'scope_in_names', 'scope_out_names',
-                 'feed_names', 'fetch_names', 'flops', 'aot_fp',
-                 'aot_state')
+                 'feed_names', 'fetch_names', 'flops')
 
     def __init__(self, fn, raw_fn, scope_in_names, scope_out_names,
                  feed_names, fetch_names):
@@ -159,9 +158,6 @@ class _Compiled(object):
         self.feed_names = feed_names
         self.fetch_names = fetch_names
         self.flops = None  # per-step XLA cost-analysis FLOPs (observe)
-        self.aot_fp = None      # aot_cache fingerprint, when cacheable
-        self.aot_state = None   # None | 'save' (serialize at dispatch)
-                                # | 'warm' (fn deserialized from disk)
 
 
 _SUB_BLOCK_ATTRS = ('sub_block', 'true_block', 'false_block')
@@ -263,12 +259,6 @@ class Executor(object):
         self._dispatch_lock = threading.Lock()
         self._tls = threading.local()
         self._step = 0
-        # AOT serialized-executable cache ledger (core/aot_cache.py):
-        # warm-start hits/misses and load seconds, read by warmup()
-        # wiring in serving/decode engines and the trainer. Mutated
-        # under self._lock.
-        self.aot_stats = {'hits': 0, 'misses': 0, 'saves': 0,
-                          'load_failures': 0, 'load_seconds': 0.0}
         from .platform_boot import arm_compile_cache
         arm_compile_cache()
 
@@ -282,17 +272,6 @@ class Executor(object):
     @last_cache_miss.setter
     def last_cache_miss(self, value):
         self._tls.last_cache_miss = value
-
-    @property
-    def last_warm_from_disk(self):
-        """Whether THIS thread's most recent run()/run_steps() call
-        installed its executable from the AOT disk cache instead of
-        tracing+compiling (thread-local, like last_cache_miss)."""
-        return getattr(self._tls, 'last_warm_from_disk', False)
-
-    @last_warm_from_disk.setter
-    def last_warm_from_disk(self, value):
-        self._tls.last_warm_from_disk = value
 
     def _next_steps(self, n):
         """Atomically claim n global step indices (dropout keys fold
@@ -319,21 +298,13 @@ class Executor(object):
                    fetch_names=fetch_names, mode=mode, label=kind)
         self._verified.add(key)
 
-    def _lookup_or_compile(self, kind, key, use_cache, compile_fn,
-                           program=None, aot_parts=None):
+    def _lookup_or_compile(self, kind, key, use_cache, compile_fn):
         """Compile-cache access, safe under concurrent serving threads:
         a hit is one locked dict read; a miss takes a per-key lock so
         two threads racing on the same (program, shapes) signature
         compile ONCE — the loser blocks, then reads the winner's entry
         as a hit. Distinct keys still compile concurrently. Returns
-        (compiled, missed).
-
-        On a miss, the AOT serialized-executable cache is consulted
-        first (core/aot_cache.py): a disk hit installs the deserialized
-        executable — zero trace, zero XLA compile, none of the
-        cache_miss/trace/compile events — and a disk miss marks the
-        entry for serialization at its first dispatch (when the
-        concrete input avals exist)."""
+        (compiled, missed)."""
         if not use_cache:
             return self._observed_compile(kind, key, compile_fn), True
         with self._lock:
@@ -347,122 +318,10 @@ class Executor(object):
                 compiled = self._cache.get(key)
             if compiled is not None:
                 return compiled, False
-            compiled, fp = None, None
-            if program is not None and aot_parts is not None and \
-                    program.mesh is None:
-                from . import aot_cache as _aot
-                if _aot.enabled():
-                    fp = _aot.fingerprint(program, aot_parts)
-                    compiled = self._try_warm_start(kind, key, fp,
-                                                    compile_fn)
-            if compiled is None:
-                compiled = self._observed_compile(kind, key, compile_fn)
-                if fp is not None:
-                    compiled.aot_fp = fp
-                    compiled.aot_state = 'save'
+            compiled = self._observed_compile(kind, key, compile_fn)
             with self._lock:
                 self._cache[key] = compiled
         return compiled, True
-
-    @staticmethod
-    def _donation_safe(loaded):
-        """Wrap a DESERIALIZED executable so its donation cannot
-        corrupt live state. jax-level donated-buffer bookkeeping does
-        not fully survive serialize/deserialize: the executable's
-        baked-in input/output aliasing still writes outputs (and
-        scratch) into the donated input buffers, but the caller-side
-        deleted-array marking that normally fences those buffers off
-        is not re-established — so a buffer the scope (or another
-        in-flight key) still references gets silently overwritten.
-        Observed as replica-weight corruption under concurrent serving
-        with PADDLE_TPU_AOT_CACHE=1; the fleet router's hedge
-        bit-identity check (router.hedge_mismatch_total) is what
-        caught it. Handing the executable a private copy of the
-        donated scope argument makes its in-place writes land in
-        memory nothing else references; the aliased outputs the
-        executor writes back to the scope then own those buffers
-        outright. Costs one params-sized device copy per dispatch on
-        warm keys only — correctness over the last ounce of warm-path
-        throughput."""
-        import jax.numpy as jnp
-
-        def call(scope_vals, *rest):
-            scope_vals = {k: jnp.array(v, copy=True)
-                          for k, v in scope_vals.items()}
-            return loaded(scope_vals, *rest)
-        return call
-
-    def _try_warm_start(self, kind, key, fp, compile_fn):
-        """Install a disk-cached executable for this key, or None. The
-        Python lowering walk (compile_fn) still runs — it supplies the
-        scope/feed name metadata — but jax never traces and XLA never
-        compiles, and none of the miss/trace/compile telemetry fires;
-        the warm path emits aot_hit/aot_load_seconds instead."""
-        from . import aot_cache as _aot
-        t0 = time.perf_counter()
-        loaded, status = _aot.load(fp)
-        if loaded is None:
-            with self._lock:
-                self.aot_stats['misses'] += 1
-                if status != 'absent':
-                    self.aot_stats['load_failures'] += 1
-            return None
-        compiled = compile_fn()
-        compiled.fn = self._donation_safe(loaded)
-        compiled.aot_fp = fp
-        compiled.aot_state = 'warm'
-        # the cost probe would compile — the one thing a warm start
-        # exists to avoid; MFU for this key is forfeited, not bought
-        compiled.flops = 0.0
-        dt = time.perf_counter() - t0
-        with self._lock:
-            self.aot_stats['hits'] += 1
-            self.aot_stats['load_seconds'] += dt
-        self.last_warm_from_disk = True
-        kid = _obs.key_id(key)
-        if _obs.enabled():
-            _obs.inc('executor.aot_hit_total', kind=kind, key=kid)
-            _obs.record('executor.aot_load_seconds', dt, kind=kind,
-                        key=kid)
-        _obs.flight_event('aot_load', kind=kind, key=kid,
-                          fingerprint=fp[:12],
-                          load_seconds=round(dt, 6))
-        return compiled
-
-    def _aot_save(self, kind, key, compiled, scope_vals, feed_vals):
-        """First dispatch of a disk-missed key: AOT-compile the step at
-        the live avals, serialize it for the next process, and install
-        the compiled executable as this entry's fn (so the jit wrapper
-        never compiles a second copy). Failures leave the jit path
-        intact — the cache is an optimization, never a dependency."""
-        from . import aot_cache as _aot
-        compiled.aot_state = None
-        kid = _obs.key_id(key)
-        try:
-            t0 = time.perf_counter()
-            with _obs.span('executor.xla_compile', key=kid):
-                exe = compiled.fn.lower(scope_vals, feed_vals,
-                                        np.int32(0)).compile()
-            dt = time.perf_counter() - t0
-            if _obs.enabled():
-                _obs.record('executor.compile_seconds', dt, key=kid)
-                _obs.overhead('compile', dt)
-                if compiled.flops is None:
-                    compiled.flops = _obs.cost_analysis_flops(exe) or 0.0
-                    if compiled.flops:
-                        _obs.set_gauge('executor.step_flops',
-                                       compiled.flops)
-        except Exception as e:
-            _obs.flight_event('aot_save_failed', kind=kind, key=kid,
-                              error='%s: %s' % (type(e).__name__, e))
-            return
-        if _aot.save(compiled.aot_fp, exe) is not None:
-            with self._lock:
-                self.aot_stats['saves'] += 1
-            _obs.flight_event('aot_save', kind=kind, key=kid,
-                              fingerprint=compiled.aot_fp[:12],
-                              compile_seconds=round(dt, 6))
-        compiled.fn = exe
 
     # ------------------------------------------------------------------ run
     def run(self, program=None, feed=None, fetch_list=None, scope=None,
@@ -470,72 +329,10 @@ class Executor(object):
             return_handle=False):
         with _obs.span('executor.run', record='executor.run_seconds',
                        kind='single') as run_span:
-            return self._run(run_span, program, feed, fetch_list, scope,
-                             return_numpy, use_program_cache,
-                             return_handle)
+            return self._dispatch(run_span, None, False, use_program_cache,
+                                  program, feed, fetch_list, scope,
+                                  return_numpy, return_handle)
 
-    def _run(self, run_span, program, feed, fetch_list, scope,
-             return_numpy, use_program_cache, return_handle):
-        _ensure_ops_imported()
-        program = program if program is not None else default_main_program()
-        feed = feed or {}
-        fetch_list = fetch_list or []
-        scope = scope if scope is not None else global_scope()
-        block = program.global_block()
-
-        with _obs.span('executor.lookup', record='executor.lookup_seconds'):
-            fetch_names = [f.name if isinstance(f, Variable) else f
-                           for f in fetch_list]
-
-            feed_vals = self._normalize_feed(block, feed)
-            feed_sig = tuple(sorted((n, v.shape, str(v.dtype))
-                                    for n, v in feed_vals.items()))
-            # read per call and folded into the cache key: flipping the
-            # PADDLE_TPU_QUANT_ALLREDUCE knob mid-process recompiles
-            # instead of silently reusing the other mode's executable
-            from ..parallel.collective import grad_bucket_policy
-            from ..quant.core import grad_allreduce_policy
-            qpolicy = grad_allreduce_policy(program)
-            bpolicy = grad_bucket_policy(program)
-            key = (id(program), program._version, program.amp,
-                   program.remat_policy, qpolicy, bpolicy, feed_sig,
-                   tuple(fetch_names))
-            self._maybe_verify('single', key, program, feed_vals,
-                               fetch_names)
-            self.last_warm_from_disk = False
-            compiled, missed = self._lookup_or_compile(
-                'single', key, use_program_cache,
-                lambda: self._compile(program, sorted(feed_vals),
-                                      fetch_names, quant_allreduce=qpolicy,
-                                      grad_bucket=bpolicy),
-                program=program,
-                aot_parts=('single', program.amp, program.remat_policy,
-                           qpolicy, bpolicy, feed_sig, tuple(fetch_names)))
-            self.last_cache_miss = missed
-            kid = self._account_lookup('single', key, missed, run_span)
-
-        with self._dispatch_lock:
-            with _obs.span('executor.prepare',
-                           record='executor.prepare_seconds'):
-                scope_vals, feed_vals = self._prepare_inputs(
-                    'Executor.run', program, compiled, scope, feed_vals)
-            if compiled.aot_state == 'save':
-                self._aot_save('single', key, compiled, scope_vals,
-                               feed_vals)
-            if _obs.enabled() and compiled.flops is None:
-                self._cost_account(compiled, key, scope_vals, feed_vals)
-
-            step_i = self._next_steps(1)
-            fetches, new_scope = self._enqueue(
-                'single', kid, missed, compiled, scope_vals, feed_vals,
-                step_i)
-
-            for name, value in new_scope.items():
-                scope.set(name, value)
-
-        return self._hand_back(fetches, 1, return_numpy, return_handle)
-
-    # ---------------------------------------------------------- multi-step
     def run_steps(self, steps, program=None, feed=None, fetch_list=None,
                   scope=None, return_numpy=True, stacked_feed=False,
                   return_handle=False):
@@ -560,22 +357,40 @@ class Executor(object):
         itself compiles into the program."""
         with _obs.span('executor.run', record='executor.run_seconds',
                        kind='multi') as run_span:
-            return self._run_steps(run_span, steps, program, feed,
-                                   fetch_list, scope, return_numpy,
-                                   stacked_feed, return_handle)
+            return self._dispatch(run_span, steps, stacked_feed, True,
+                                  program, feed, fetch_list, scope,
+                                  return_numpy, return_handle)
 
-    def _run_steps(self, run_span, steps, program, feed, fetch_list, scope,
-                   return_numpy, stacked_feed, return_handle):
+    def _resolve_call(self, program, feed, fetch_list, scope):
+        """What run / run_steps / compile_step each begin with: the
+        defaults, the fetch names, the feed at its declared dtypes and
+        the two gradient policies."""
         _ensure_ops_imported()
         program = program if program is not None else default_main_program()
-        fetch_list = fetch_list or []
         scope = scope if scope is not None else global_scope()
-        block = program.global_block()
-        with _obs.span('executor.lookup', record='executor.lookup_seconds'):
-            fetch_names = [f.name if isinstance(f, Variable) else f
-                           for f in fetch_list]
+        fetch_names = [f.name if isinstance(f, Variable) else f
+                       for f in (fetch_list or [])]
+        feed_vals = self._normalize_feed(program.global_block(), feed or {})
+        # read per call and folded into the cache key: flipping the
+        # PADDLE_TPU_QUANT_ALLREDUCE knob mid-process recompiles
+        # instead of silently reusing the other mode's executable
+        from ..parallel.collective import grad_bucket_policy
+        from ..quant.core import grad_allreduce_policy
+        return (program, scope, fetch_names, feed_vals,
+                grad_allreduce_policy(program), grad_bucket_policy(program))
 
-            feed_vals = self._normalize_feed(block, feed or {})
+    def _dispatch(self, run_span, steps, stacked_feed, use_program_cache,
+                  program, feed, fetch_list, scope, return_numpy,
+                  return_handle):
+        """The one body of run (``steps`` None) and run_steps: look the
+        executable up or compile it, gather its inputs, enqueue it and
+        write the scope back."""
+        multi = steps is not None
+        kind = 'multi' if multi else 'single'
+        n_steps = steps if multi else 1
+        with _obs.span('executor.lookup', record='executor.lookup_seconds'):
+            program, scope, fetch_names, feed_vals, qpolicy, bpolicy = \
+                self._resolve_call(program, feed, fetch_list, scope)
             if stacked_feed:
                 for name, arr in feed_vals.items():
                     if arr.shape[0] != steps:
@@ -583,53 +398,49 @@ class Executor(object):
                             'run_steps(stacked_feed=True): feed %r '
                             'leading dim %d != steps %d'
                             % (name, arr.shape[0], steps))
-
-            sig_shape = {n: (v.shape[1:] if stacked_feed else v.shape)
-                         for n, v in feed_vals.items()}
-            feed_sig = tuple(sorted((n, sig_shape[n], str(v.dtype))
-                                    for n, v in feed_vals.items()))
-            from ..parallel.collective import grad_bucket_policy
-            from ..quant.core import grad_allreduce_policy
-            qpolicy = grad_allreduce_policy(program)
-            bpolicy = grad_bucket_policy(program)
-            key = ('multi', id(program), program._version, program.amp,
+            feed_sig = tuple(sorted(
+                (n, v.shape[1:] if stacked_feed else v.shape, str(v.dtype))
+                for n, v in feed_vals.items()))
+            key = (id(program), program._version, program.amp,
                    program.remat_policy, qpolicy, bpolicy, feed_sig,
-                   tuple(fetch_names), steps, stacked_feed)
-            self._maybe_verify('multi', key, program, feed_vals,
-                               fetch_names)
-            self.last_warm_from_disk = False
+                   tuple(fetch_names))
+            if multi:
+                key = ('multi',) + key + (steps, stacked_feed)
+            feed_names = sorted(feed_vals)
+
+            def compile_fn():
+                if multi:
+                    return self._compile_multi(
+                        program, feed_names, fetch_names, qpolicy,
+                        bpolicy, steps, stacked_feed)
+                return self._compile(
+                    program, feed_names, fetch_names,
+                    quant_allreduce=qpolicy, grad_bucket=bpolicy)
+
+            self._maybe_verify(kind, key, program, feed_vals, fetch_names)
             compiled, missed = self._lookup_or_compile(
-                'multi', key, True,
-                lambda: self._compile_multi(
-                    program, sorted(feed_vals), fetch_names, qpolicy,
-                    bpolicy, steps, stacked_feed),
-                program=program,
-                aot_parts=('multi', program.amp, program.remat_policy,
-                           qpolicy, bpolicy, feed_sig, tuple(fetch_names),
-                           steps, stacked_feed))
+                kind, key, use_program_cache, compile_fn)
             self.last_cache_miss = missed
-            kid = self._account_lookup('multi', key, missed, run_span)
+            kid = self._account_lookup(kind, key, missed, run_span)
 
         with self._dispatch_lock:
             with _obs.span('executor.prepare',
                            record='executor.prepare_seconds'):
                 scope_vals, feed_vals = self._prepare_inputs(
-                    'Executor.run_steps', program, compiled, scope,
-                    feed_vals, feed_stack_axis=stacked_feed)
-            if compiled.aot_state == 'save':
-                self._aot_save('multi', key, compiled, scope_vals,
-                               feed_vals)
+                    'Executor.run_steps' if multi else 'Executor.run',
+                    program, compiled, scope, feed_vals,
+                    feed_stack_axis=stacked_feed)
             if _obs.enabled() and compiled.flops is None:
                 one_feed = {n: v[0] for n, v in feed_vals.items()} \
                     if stacked_feed else feed_vals
                 self._cost_account(compiled, key, scope_vals, one_feed)
-            step0 = self._next_steps(steps)
+            step0 = self._next_steps(n_steps)
             fetches, new_scope = self._enqueue(
-                'multi', kid, missed, compiled, scope_vals, feed_vals,
-                step0)
+                kind, kid, missed, compiled, scope_vals, feed_vals, step0)
             for name, value in new_scope.items():
                 scope.set(name, value)
-        return self._hand_back(fetches, steps, return_numpy, return_handle)
+        return self._hand_back(fetches, n_steps, return_numpy,
+                               return_handle)
 
     def _compile_multi(self, program, feed_names, fetch_names, qpolicy,
                        bpolicy, steps, stacked_feed):
@@ -1169,19 +980,11 @@ class Executor(object):
         feed_vals, step_i)`` is a pure jittable function returning
         ``(fetches, new_scope)``. Used by bench/__graft_entry__ and the
         inference predictor; ``Executor.run`` callers never need this."""
-        _ensure_ops_imported()
-        program = program if program is not None else default_main_program()
-        scope = scope if scope is not None else global_scope()
-        block = program.global_block()
-        fetch_names = [f.name if isinstance(f, Variable) else f
-                       for f in (fetch_list or [])]
-        feed_vals = self._normalize_feed(block, feed or {})
-        from ..parallel.collective import grad_bucket_policy
-        from ..quant.core import grad_allreduce_policy
+        program, scope, fetch_names, feed_vals, qpolicy, bpolicy = \
+            self._resolve_call(program, feed, fetch_list, scope)
         compiled = self._compile(
             program, sorted(feed_vals), fetch_names,
-            quant_allreduce=grad_allreduce_policy(program),
-            grad_bucket=grad_bucket_policy(program))
+            quant_allreduce=qpolicy, grad_bucket=bpolicy)
         scope_vals, feed_vals = self._prepare_inputs(
             'Executor.compile_step', program, compiled, scope, feed_vals)
         return compiled.raw_fn, scope_vals, feed_vals

@@ -32,8 +32,8 @@ def cache_root():
     """The one directory this package keeps compiled or tuned artefacts
     under: ``JAX_COMPILATION_CACHE_DIR`` where it is set, otherwise
     ``<checkout>/.jax_cache`` — the same string in every process,
-    because the path is part of jax's cache key. The AOT executable
-    cache (``aot/``) and the tuning table live beneath it."""
+    because the path is part of jax's cache key. The tuning table
+    lives beneath it."""
     env = os.environ.get('JAX_COMPILATION_CACHE_DIR')
     if env:
         return env
@@ -56,16 +56,12 @@ def arm_compile_cache():
     :func:`cache_root`. The ``compile_cache`` flag
     (PADDLE_TPU_COMPILE_CACHE) is 'auto' (TPU only), on, or off.
 
-    This is one of three layers — keep them apart when reading a cold
+    This is one of two layers — keep them apart when reading a cold
     start:
 
     1. **jax's compilation cache** (this function): skips the XLA
        compile; the process still traces every program.
-    2. **AOT executable cache** (``core/aot_cache.py``, only with
-       ``PADDLE_TPU_AOT_CACHE=1``): the Executor serializes the compiled
-       step keyed by program content — not by lowering code — so it is
-       off unless asked for.
-    3. **Kernel tuning table** (``paddle_tpu/tuning``): which kernel
+    2. **Kernel tuning table** (``paddle_tpu/tuning``): which kernel
        variant each (op, shape, dtype) dispatches; changes what gets
        compiled, not whether.
     """

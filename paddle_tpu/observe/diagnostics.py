@@ -10,9 +10,8 @@ started via ``observe.serve(port=...)`` or ``PADDLE_TPU_STATUSZ_PORT``
     /varz      the observe.snapshot() dict as JSON (exact values,
                host/pid tagged — the JSONL line shape, live)
     /statusz   run headline JSON: uptime, process_index, executor
-               compile-cache per-key hit/miss/compile-seconds plus
-               warm_from_disk + aot_load_seconds (AOT executable-cache
-               hits), the autotuner panel (tuning-table size, decision
+               compile-cache per-key hit/miss/compile-seconds, the
+               autotuner panel (tuning-table size, decision
                counts), trainer in-flight pipeline depth, MFU/goodput,
                the decode-engine panel (running/waiting sequences,
                KV-page occupancy, preemption/token counters), the
@@ -165,9 +164,9 @@ def _executor_cache_table(snap):
 
     def ent(key):
         return table.setdefault(key or '', {
-            'kind': None, 'hits': 0, 'misses': 0, 'warm_from_disk': 0,
+            'kind': None, 'hits': 0, 'misses': 0,
             'trace_seconds': None, 'compile_seconds': None,
-            'first_dispatch_seconds': None, 'aot_load_seconds': None})
+            'first_dispatch_seconds': None})
 
     for rendered, v in snap.get('counters', {}).items():
         name, labels = parse_rendered(rendered)
@@ -179,17 +178,10 @@ def _executor_cache_table(snap):
             e = ent(labels.get('key'))
             e['misses'] += v
             e['kind'] = labels.get('kind', e['kind'])
-        elif name == 'executor.aot_hit_total':
-            # the key was installed from the AOT serialized-executable
-            # cache: zero trace, zero XLA compile (core/aot_cache.py)
-            e = ent(labels.get('key'))
-            e['warm_from_disk'] += v
-            e['kind'] = labels.get('kind', e['kind'])
     for rendered, st in snap.get('histograms', {}).items():
         name, labels = parse_rendered(rendered)
         if name in ('executor.trace_seconds', 'executor.compile_seconds',
-                    'executor.first_dispatch_seconds',
-                    'executor.aot_load_seconds'):
+                    'executor.first_dispatch_seconds'):
             key = labels.get('key')
             if key in table:
                 table[key][name.split('.', 1)[1]] = st.get('sum')

@@ -26,8 +26,8 @@ cache lives in a paged pool (ops/pallas/paged_attention.py layouts):
   ``k+1`` tokens (the pending token + k draft proposals) for every
   slot of the [B] batch in ONE ragged paged-attention pass over
   ``B*(k+1)`` mixed-length rows (row (b, j) attends at length
-  lens[b]+j+1 — exactly the ragged shape the paged kernel was built
-  for). ``k`` is a static attr, so the verify step is one more fixed
+  lens[b]+j+1 — exactly the ragged shape the paged attention was
+  built for). ``k`` is a static attr, so the verify step is one more fixed
   signature beside the decode step's. Writes K/V for all k+1
   positions; the engine's longest-accepted-prefix rule decides how
   many become real (rejected positions sit above the advanced
@@ -438,7 +438,7 @@ def _extend_rows(block, kcs, vcs, tokens, pos, tables, place,
     hits) stores identical bits for identical tokens — the
     concurrent == sequential invariant survives at int8/fp8."""
     from ..quant.core import quantize_rows
-    from .pallas.paged_attention import (paged_attention,
+    from .pallas.paged_attention import (paged_attention_blocked,
                                          paged_attention_one_table)
     n = tokens.shape[0]
     kv_q = _arena_kv_dtype(kcs)
@@ -448,7 +448,7 @@ def _extend_rows(block, kcs, vcs, tokens, pos, tables, place,
     # a row that is not live attends at length 0: it costs no block
     att_lens = pos + 1 if valid is None else jnp.where(valid, pos + 1, 0)
     attend = paged_attention_one_table if tables.ndim == 1 \
-        else paged_attention
+        else paged_attention_blocked
 
     def body(carry, sl):
         h, arenas = carry

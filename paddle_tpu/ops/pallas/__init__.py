@@ -16,7 +16,7 @@ def pallas_enabled():
 
     Default: OFF — opt in with PADDLE_TPU_USE_PALLAS=1. Every kernel
     here — flash forward and both FA2 backward kernels, layer norm,
-    batch norm, paged attention — compiles for the v5e and agrees with
+    batch norm — compiles for the v5e and agrees with
     its jnp reference (chip_smoke.py's kernels leg, PR 21). None has a
     timing against XLA's own fusion in the driver's records, so a hand
     kernel has yet to earn its dispatch at any shape (ROADMAP S6).

@@ -10,41 +10,32 @@ FLOPs and, worse, padding HBM. Pages decouple cache capacity from
 per-sequence reservation: a 17-token sequence holds ceil(17/bs) pages,
 not Tmax slots.
 
-Two backends, selected like ops/pallas/flash_attention.py:
+The attention reads the pages its rows hold and no others, in plain
+XLA on every backend. Two forms over one inner form
+(``_attend_blocks``: whole pages of about BLOCK_COLS columns gathered
+through the table at (layer, table) and consumed there under a running
+softmax; float32 scores, normaliser and accumulator):
+``paged_attention_blocked`` — many tables, one query each (decode
+step, spec verify): rows ordered by attended length, blocks of
+BLOCK_ROWS rows, each running the column blocks from the one that
+holds its smallest lower bound to the one that holds its largest
+length; rows that are not live cost no block;
+``paged_attention_one_table`` — one table, many queries (every
+prefill): the chunk's rows as one group over the blocks the chunk can
+see.
+The loop bounds come from the step's own inputs (``block_bounds``), in
+one compiled program per signature; nothing of the extent [B, P] or
+[S, P] is gathered, re-tiled or multiplied (PERF.md section 6, PR 29;
+before it the gather covered every page of every table whatever the
+rows held). On the TPU the split of H*D into [H, D] is still a
+re-tiling of a block's gathered pages when D is under a lane tile
+(ROADMAP S3c).
 
-- **XLA gather path** (default, and the CPU/tier-1 path): the
-  attention reads the pages its rows hold and no others. Two forms over
-  one inner form (``_attend_blocks``: whole pages of about BLOCK_COLS
-  columns gathered through the table at (layer, table) and consumed
-  there under a running softmax; float32 scores, normaliser and
-  accumulator):
-  ``paged_attention_blocked`` — many tables, one query each (decode
-  step, spec verify): rows ordered by attended length, blocks of
-  BLOCK_ROWS rows, each running the column blocks from the one that
-  holds its smallest lower bound to the one that holds its largest
-  length; rows that are not live cost no block;
-  ``paged_attention_one_table`` — one table, many queries (every
-  prefill): the chunk's rows as one group over the blocks the chunk can
-  see.
-  The loop bounds come from the step's own inputs (``block_bounds``), in
-  one compiled program per signature; nothing of the extent [B, P] or
-  [S, P] is gathered, re-tiled or multiplied (PERF.md section 6, PR 29;
-  before it the gather covered every page of every table whatever the
-  rows held). On the TPU the split of H*D into [H, D] is still a
-  re-tiling of a block's gathered pages when D is under a lane tile
-  (ROADMAP S3c).
-- **Pallas kernel** (PADDLE_TPU_USE_PALLAS=1): the block table rides
-  scalar prefetch (pltpu.PrefetchScalarGridSpec) so each grid step's
-  page index map reads table[b, page] — the kernel DMAs exactly the
-  pages a sequence owns, pages past seq_len are skipped entirely
-  (ragged: short sequences cost proportionally less), and the online-
-  softmax recurrence matches the flash kernel's.
-
-Parity of both XLA forms with a dense masked oracle
+Parity of both forms with a dense masked oracle
 (``paged_attention_reference``, which no serving program calls) across
 mixed lengths, head layouts and arena dtypes is asserted in
-tests/test_paged_attention_blocked.py and tests/test_decode_serving.py,
-the kernel's in tests/test_pallas_kernels.py (interpret mode).
+tests/test_paged_attention_blocked.py, tests/test_decode_serving.py and
+tests/test_pallas_kernels.py.
 
 Layouts:
     q            [B, H, D]      one query token per sequence
@@ -57,26 +48,15 @@ Layouts:
     k/v_scales   [L, NB, bs, H] per-row fp32 scales (quantized arenas)
     block_tables [B, P] int32   physical page ids; >= NB means "no page"
     seq_lens     [B]  int32     live tokens (this token included)
-
-The Pallas kernel wants one layer's pages head-major, [NB, H, bs, D]:
-``_paged_pallas`` cuts its layer out of the arena and re-lays it on its
-own path (it is gated off by default; the kernel written for the
-token-major arena is ROADMAP S3c).
 """
-
-import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
-from . import interpret_mode
-from . import pallas_enabled
-
 _NEG_INF = -1e9
 
 
-# The XLA path's two sizes, neither swept against a trace: a row block is
+# The two block sizes, neither swept against a trace: a row block is
 # the hardware's sublane tile, a column block about the width the
 # one-table form has run at on the chip since PR 28.
 BLOCK_ROWS = 8
@@ -125,7 +105,7 @@ def row_blocks(lo, hi, block, n_blocks, xp=jnp):
 
 
 def pages_covered(lo, hi, n_pages, bs, xp=jnp):
-    """Pages one layer of ``paged_attention``'s XLA path gathers for
+    """Pages one layer of ``paged_attention_blocked`` gathers for
     rows that see columns lo <= j < hi of tables of ``n_pages``: every
     (row block, column block) pair that runs reads BLOCK_ROWS rows of
     ``pages_per_block`` pages."""
@@ -135,7 +115,7 @@ def pages_covered(lo, hi, n_pages, bs, xp=jnp):
 
 
 def _attend_blocks(q, arenas, layer, tables, lo, hi, first, last, per):
-    """The one inner form of the XLA path: R tables with S queries each
+    """The one inner form: R tables with S queries each
     (decode: R = BLOCK_ROWS, S = 1; a prefill chunk: R = 1, S = bucket).
     q [R, S, H, D] (scaled), ``arenas`` (K, V[, K scales, V scales]),
     tables [R, P] (clipped), lo/hi [R, S]. Column blocks ``first`` ..
@@ -237,7 +217,8 @@ def _arenas(k_pages, v_pages, k_scales, v_scales):
 def paged_attention_blocked(q, k_pages, v_pages, block_tables, seq_lens,
                             sm_scale=None, k_scales=None, v_scales=None,
                             layer=0, lo=None, block_cols=BLOCK_COLS):
-    """The XLA path of ``paged_attention``: many tables, one query each.
+    """Many tables, one query each: q [B, H, D], ``block_tables`` [B, P]
+    (entries >= NB mean "no page" and are never read), ``seq_lens`` [B].
     Nothing of the extent [B, P] is gathered: the rows are ordered by
     attended length (an argsort of [B] ints, undone on the result), go
     in blocks of BLOCK_ROWS, and a row block runs the column blocks
@@ -247,10 +228,9 @@ def paged_attention_blocked(q, k_pages, v_pages, block_tables, seq_lens,
     seq_lens <= lo (an empty batch slot: callers pass length 0) costs
     no block and yields 0.
 
-    Bit-stable contract with the Pallas kernel's masking: columns
-    outside [lo, seq_lens) contribute exactly 0, so the result is
-    independent of the garbage content of unowned/partial pages, and a
-    row's result does not depend on what else the batch holds
+    Columns outside [lo, seq_lens) contribute exactly 0, so the result
+    is independent of the garbage content of unowned/partial pages, and
+    a row's result does not depend on what else the batch holds
     (``_attend_blocks``).
 
     Quantized arenas: ``k_scales``/``v_scales`` [L, NB, bs, H] carry
@@ -327,10 +307,9 @@ def paged_attention_one_table(q, k_pages, v_pages, table, seq_lens,
 def paged_attention_reference(q, k_pages, v_pages, block_tables, seq_lens,
                               sm_scale=None, k_scales=None,
                               v_scales=None, layer=0, lo=None):
-    """The dense masked oracle of the tests and of the Pallas kernel's
-    parity checks: every page of every table gathered ([B, P * bs, Hkv,
-    D]), one float32 softmax over the whole extent. No serving program
-    calls it."""
+    """The dense masked oracle of the tests: every page of every table
+    gathered ([B, P * bs, Hkv, D]), one float32 softmax over the whole
+    extent. No serving program calls it."""
     nb, bs = k_pages.shape[1], k_pages.shape[2]
     b, p = block_tables.shape
     h, d = q.shape[1], q.shape[2]
@@ -359,163 +338,3 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, seq_lens,
                      precision=jax.lax.Precision.HIGHEST)
     # a row that sees nothing yields 0, as in the blocked forms
     return out.reshape(b, h, d) * jnp.any(seen, axis=1)[:, None, None]
-
-
-def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, bs, num_pages, sm_scale):
-    from jax.experimental import pallas as pl
-
-    b = pl.program_id(0)
-    pi = pl.program_id(2)
-    seq_len = len_ref[b]
-
-    @pl.when(pi == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    @pl.when(pi * bs < seq_len)
-    def _body():
-        q = q_ref[0, 0]                                # [1, d]
-        k = k_ref[0, 0]                                # [bs, d]
-        v = v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale   # [1, bs]
-        cols = pi * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        s = jnp.where(cols < seq_len, s, _NEG_INF)
-
-        m_prev = m_scr[:]                              # [1, 128]
-        l_prev = l_scr[:]
-        m_cur = jnp.max(s, axis=1, keepdims=True)      # [1, 1]
-        m_next = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_next)
-        p = jnp.exp(s - m_next[:, :1])                 # [1, bs] f32
-        l_cur = jnp.sum(p, axis=1, keepdims=True)
-        m_scr[:] = m_next
-        l_scr[:] = alpha * l_prev + l_cur
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)        # [1, d]
-        acc_scr[:] = acc_scr[:] * alpha[:, :1] + pv
-
-    @pl.when(pi == num_pages - 1)
-    def _finish():
-        denom = l_scr[:][:, :1]
-        denom = jnp.where(denom == 0.0, 1.0, denom)
-        o_ref[0, 0] = (acc_scr[:] / denom).astype(o_ref.dtype)
-
-
-def _layer_pages_head_major(arena, layer, n_head):
-    """[L, NB, bs, H*D] at ``layer`` -> [NB, H, bs, D]: the kernel's
-    page tile is (bs, D), which the token-major arena cannot hand out
-    as a block (D alone is under a lane tile). A copy of one layer's
-    pages, private to the Pallas path."""
-    nb, bs = arena.shape[1], arena.shape[2]
-    pages = jax.lax.dynamic_index_in_dim(arena, layer, keepdims=False)
-    return jnp.transpose(pages.reshape(nb, bs, n_head, -1), (0, 2, 1, 3))
-
-
-def _paged_pallas(q, k_pages, v_pages, block_tables, seq_lens, sm_scale,
-                  layer=0):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, h, d = q.shape
-    k_pages = _layer_pages_head_major(k_pages, layer, h)
-    v_pages = _layer_pages_head_major(v_pages, layer, h)
-    nb, bs = k_pages.shape[0], k_pages.shape[2]
-    p = block_tables.shape[1]
-    dv = v_pages.shape[-1]
-    tables = jnp.clip(block_tables.astype(jnp.int32), 0, nb - 1)
-    lens = seq_lens.astype(jnp.int32)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,        # block tables, lengths
-        grid=(b, h, p),
-        in_specs=[
-            # q rides as [B, H, 1, D] so a (b, h) row is a (1, d) block
-            # equal to the array's last two dims — a (1, 1, d) block
-            # over [B, H, D] has a second-minor of 1 that is neither H
-            # nor a multiple of 8, which the TPU lowering refuses
-            pl.BlockSpec((1, 1, 1, d),
-                         lambda bi, hi, pi, bt, ln: (bi, hi, 0, 0)),
-            # pages: the physical page id comes from the prefetched
-            # block table — the ragged gather IS the index map
-            pl.BlockSpec((1, 1, bs, d),
-                         lambda bi, hi, pi, bt, ln: (bt[bi, pi], hi, 0, 0)),
-            pl.BlockSpec((1, 1, bs, dv),
-                         lambda bi, hi, pi, bt, ln: (bt[bi, pi], hi, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, dv),
-                               lambda bi, hi, pi, bt, ln: (bi, hi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, 128), jnp.float32),
-            pltpu.VMEM((1, 128), jnp.float32),
-            pltpu.VMEM((1, dv), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(_paged_kernel, bs=bs, num_pages=p,
-                               sm_scale=sm_scale)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, 1, dv), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('parallel', 'parallel', 'arbitrary')),
-        interpret=interpret_mode(),
-    )(tables, lens, q.reshape(b, h, 1, d), k_pages, v_pages)
-    return out.reshape(b, h, dv)
-
-
-def _use_pallas(q, k_pages, v_pages, block_tables):
-    """The kernel wants lane-aligned page tiles; anything else takes the
-    gather path (which handles every shape). Precedence: an EXPLICIT
-    PADDLE_TPU_PAGED_PALLAS overrides everything (in either direction),
-    then an explicit PADDLE_TPU_USE_PALLAS, then — with
-    PADDLE_TPU_AUTOTUNE=on — the per-shape tuning table (this is the
-    dispatch the decode engine's ops/paged_decode_ops.py hot loop rides
-    through), then the pallas_enabled() default (off)."""
-    bs = k_pages.shape[2]
-    h, d = q.shape[1], q.shape[2]
-    aligned = bs % 8 == 0 and d % 8 == 0
-    env = os.environ.get('PADDLE_TPU_PAGED_PALLAS')
-    if env is not None:
-        return env not in ('0', 'false', 'False') and aligned
-    from ... import tuning
-    if tuning.autotune_mode() != 'off' and \
-            not tuning.env_gate_set('PADDLE_TPU_USE_PALLAS'):
-        b, p = block_tables.shape
-        picked = tuning.decide_paged_attention(
-            b, p, h, bs, d, v_pages.shape[-1] // h, str(q.dtype))
-        if picked is not None:
-            return picked.get('impl') == 'pallas' and aligned
-    return pallas_enabled() and aligned
-
-
-def paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
-                    sm_scale=None, k_scales=None, v_scales=None, layer=0,
-                    lo=None):
-    """Ragged paged attention: one query per sequence against its paged
-    KV cache. q [B, H, D]; pages [L, NB, bs, H*D*] read at ``layer``
-    (a traced scalar inside the decode ops' layer loop); block_tables
-    [B, P] int32 (entries >= NB mean "no page" and are never read);
-    seq_lens [B] int32. Grouped KV heads (rows of Hkv * D) and a lower
-    column bound ``lo`` [B] (a sliding window) take the gather path.
-    Quantized arenas pass their per-row fp32 scale
-    arenas as ``k_scales``/``v_scales`` [L, NB, bs, H] and take the
-    gather path (which dequantizes inline; the Pallas kernel stays
-    fp32/bf16). Returns [B, H, Dv]."""
-    d = q.shape[2]
-    scale = sm_scale if sm_scale is not None else d ** -0.5
-    plain = lo is None and k_pages.shape[-1] == q.shape[1] * d
-    if plain and k_scales is None \
-            and str(k_pages.dtype) in ('float32', 'bfloat16') \
-            and _use_pallas(q, k_pages, v_pages, block_tables):
-        return _paged_pallas(q, k_pages, v_pages, block_tables, seq_lens,
-                             scale, layer=layer)
-    return paged_attention_blocked(q, k_pages, v_pages, block_tables,
-                                   seq_lens, sm_scale=scale,
-                                   k_scales=k_scales, v_scales=v_scales,
-                                   layer=layer, lo=lo)

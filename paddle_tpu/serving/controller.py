@@ -87,9 +87,7 @@ class ReplicaFactory(object):
     ``drain(timeout=)``, ``shutdown(drain=)``, and optionally
     ``warmup()``/``start()`` (called by the controller when the
     replica comes back not-ready — a factory may also hand over an
-    already-serving replica). Build factories on a shared
-    ``PADDLE_TPU_AOT_CACHE_DIR`` so every spawn warm-starts from the
-    serialized executables instead of compiling."""
+    already-serving replica)."""
 
     def __init__(self, fn):
         self._fn = fn

@@ -4,7 +4,7 @@ Continuous batching + paged KV cache + ragged paged attention over a
 decoder-only LM: `DecodeEngine` admits requests into a fixed-shape
 decode batch as others finish, KV pages come from a shared HBM pool
 (`KVPool`) addressed through per-sequence block tables, and the
-attention kernel (ops/pallas/paged_attention.py) reads exactly the
+attention (ops/pallas/paged_attention.py) reads exactly the
 pages each sequence owns at its true length. See docs/serving.md
 (decode engine section); load-test with tools/decode_bench.py.
 """

@@ -226,7 +226,6 @@ class DecodeEngine(object):
         self._step_stats = None
         self.warmup_signatures = 0
         self.warmup_total_seconds = 0.0
-        self.warmup_aot_load_seconds = 0.0
 
     # ----------------------------------------------------------- weights
     def load_weights(self, weights):
@@ -528,11 +527,6 @@ class DecodeEngine(object):
         point every block-table entry past the pool (all writes drop),
         so device state is untouched. Returns the signature count."""
         t_all = time.perf_counter()
-        # AOT warm start: every warmup dispatch consults the serialized-
-        # executable cache (core/aot_cache.py); a restarted replica
-        # deserializes its prefill buckets + decode key instead of
-        # compiling them
-        aot0 = dict(self._exe.aot_stats)
         for b in self.prompt_buckets:
             t0 = time.perf_counter()
             self._run_prefill(*self._warm_args(b))
@@ -572,12 +566,7 @@ class DecodeEngine(object):
                         time.perf_counter() - t0, kind='handoff',
                         bucket='')
         self._warmed = True
-        st = self._exe.aot_stats
         self.warmup_total_seconds = time.perf_counter() - t_all
-        self.warmup_aot_load_seconds = \
-            st['load_seconds'] - aot0['load_seconds']
-        _obs.set_gauge('decode.warmup_warm_from_disk',
-                       st['hits'] - aot0['hits'])
         return self.warmup_signatures
 
     def drain(self, timeout=None):

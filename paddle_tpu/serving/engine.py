@@ -275,13 +275,6 @@ class ServingEngine(object):
         specs = self._predictor.feed_specs()
         sigs = self._ladder.signatures()
         t_all = time.perf_counter()
-        # AOT warm start (core/aot_cache.py): each signature dispatch
-        # below consults the serialized-executable cache — on a warmed
-        # replica every one deserializes instead of compiling, which is
-        # what turns scale-up from minutes of XLA into seconds of reads
-        exe = getattr(self._predictor, 'exe', None)
-        aot0 = dict(exe.aot_stats) if exe is not None and \
-            hasattr(exe, 'aot_stats') else None
         for b, s in sigs:
             feed = {}
             for name, (shape, dtype) in specs.items():
@@ -305,12 +298,6 @@ class ServingEngine(object):
         _obs.set_gauge('serving.warmup_signatures', len(sigs))
         _obs.set_gauge('serving.warmup_total_seconds',
                        time.perf_counter() - t_all)
-        if aot0 is not None:
-            st = exe.aot_stats
-            _obs.set_gauge('serving.warmup_warm_from_disk',
-                           st['hits'] - aot0['hits'])
-            _obs.set_gauge('serving.warmup_aot_load_seconds',
-                           st['load_seconds'] - aot0['load_seconds'])
         return len(sigs)
 
     def _synthetic(self, name, shape, dtype, batch, seq, example):

@@ -32,8 +32,7 @@ engine protocol both sides already speak:
   controller's next census tick, same as a corpse.
 - **spawner** (:class:`ProcessReplicaFactory`) — a `ReplicaFactory`
   for `FleetController` that spawns real worker processes
-  (``tools/replica_worker.py``), shares the parent's AOT executable
-  cache dir for warm starts, waits for the /readyz flip, and — when a
+  (``tools/replica_worker.py``), waits for the /readyz flip, and — when a
   replica's shutdown path finds the process still alive — SIGKILLs
   and reaps the corpse, so the controller's lineage/backoff/quarantine
   machinery governs real PIDs.
@@ -978,8 +977,7 @@ class ProcessReplicaFactory(object):
     ``config`` is the worker's engine description (see
     tools/replica_worker.py): ``kind`` ('serving'|'decode') plus the
     engine kwargs/model paths. Every spawn inherits the parent
-    environment — the AOT executable cache dir included, which is what
-    makes heal/scale-out spawns warm-start. Worker JSONL metrics land
+    environment. Worker JSONL metrics land
     beside the parent's sink (``<parent-stem>-<name>.jsonl``) with the
     replica name as the record ``host``, so
     ``tools/metrics_report.py --fleet`` merges the run."""

@@ -351,12 +351,6 @@ class Trainer(object):
                     'trainer.pipeline_overlap_fraction',
                     max(0.0, 1.0 - ((hb - blocked0[0]) +
                                     (db - blocked0[1])) / wall))
-            # AOT warm-start ledger: how many of this run's keys came
-            # off disk instead of trace+compile (core/aot_cache.py)
-            st = self.exe.aot_stats
-            _obs.set_gauge('trainer.warm_from_disk_keys', st['hits'])
-            _obs.set_gauge('trainer.aot_load_seconds',
-                           st['load_seconds'])
             _obs.flush()   # end-of-train snapshot (no-op without a sink)
 
     # ------------------------------------------------------ feed stream
@@ -571,8 +565,7 @@ class Trainer(object):
         if self._t_train_entry is not None:
             # cold-vs-warm startup headline: wall from train() entry to
             # the first dispatch ENQUEUED — startup-program run, resume,
-            # and the first step's trace+compile (or its AOT warm load)
-            # all land in here
+            # and the first step's trace+compile all land in here
             _obs.set_gauge('trainer.time_to_first_dispatch_seconds',
                            t1 - self._t_train_entry)
             self._t_train_entry = None

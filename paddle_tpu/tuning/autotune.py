@@ -3,9 +3,8 @@
 On first sight of an (op, shape, dtype, device_kind) key, microbenchmark
 the candidate variants — XLA vs Pallas, and a small grid of Pallas block
 sizes — and record the winner in the persisted :class:`TuningTable`.
-Dispatch sites (``ops/attention_ops.py``, ``ops/pallas/paged_attention
-.py`` — which also covers ``ops/paged_decode_ops.py`` — and the
-layer/batch-norm wrappers) consult ``decide()`` instead of the global
+Dispatch sites (``ops/attention_ops.py`` and the layer/batch-norm
+wrappers) consult ``decide()`` instead of the global
 env gates when autotuning is on; the explicit env gates
 (``PADDLE_TPU_USE_PALLAS`` etc.) always override the table.
 
@@ -249,38 +248,6 @@ def decide_attention(b, h, tq, tk, d, dtype, causal, masked):
             ({'impl': 'pallas', 'block_q': bq, 'block_k': bk},
              pallas_thunk))
     return decide('flash_attention', key, candidates)
-
-
-def decide_paged_attention(b, p, h, bs, d, dv, dtype):
-    """XLA gather path vs the scalar-prefetch Pallas kernel for one
-    ragged paged-attention shape (the decode hot loop)."""
-    import jax
-    import jax.numpy as jnp
-    from ..ops.pallas import paged_attention as _pa
-
-    key = ('paged_attention|b%d p%d h%d bs%d d%d dv%d|%s'
-           % (b, p, h, bs, d, dv, dtype))
-
-    def mk_inputs():
-        q = jnp.ones((b, h, d), dtype)
-        kp = jnp.ones((1, b * p, bs, h * d), dtype)
-        vp = jnp.ones((1, b * p, bs, h * dv), dtype)
-        tables = jnp.arange(b * p, dtype=jnp.int32).reshape(b, p)
-        lens = jnp.full((b,), p * bs - 1, jnp.int32)
-        return q, kp, vp, tables, lens
-
-    def xla_thunk():
-        args = mk_inputs()
-        return jax.jit(_pa.paged_attention_blocked)(*args)
-
-    candidates = [({'impl': 'xla'}, xla_thunk)]
-    if bs % 8 == 0 and d % 8 == 0:   # kernel wants lane-aligned tiles
-        def pallas_thunk():
-            q, kp, vp, tables, lens = mk_inputs()
-            return jax.jit(lambda *a: _pa._paged_pallas(
-                *a, sm_scale=d ** -0.5))(q, kp, vp, tables, lens)
-        candidates.append(({'impl': 'pallas'}, pallas_thunk))
-    return decide('paged_attention', key, candidates)
 
 
 def decide_layer_norm(n, d, dtype):

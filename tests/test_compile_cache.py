@@ -2,8 +2,7 @@
 (core/platform_boot.cache_root). With JAX_COMPILATION_CACHE_DIR set
 everything lives there and the code sets no directory; unset, it is
 <checkout>/.jax_cache in every process. Nothing resolves under the
-system temp directory, and the AOT executable cache is off unless
-PADDLE_TPU_AOT_CACHE=1 asks for it."""
+system temp directory."""
 
 import os
 import subprocess
@@ -31,8 +30,7 @@ TRAIN = ("import numpy as np, jax, paddle_tpu as fluid; "
 
 def _fresh_process(code, tmp_path, **env_overrides):
     env = dict(os.environ)
-    for var in ('JAX_COMPILATION_CACHE_DIR', 'PADDLE_TPU_AOT_CACHE',
-                'PADDLE_TPU_AOT_CACHE_DIR', 'PADDLE_TPU_TUNING_TABLE'):
+    for var in ('JAX_COMPILATION_CACHE_DIR', 'PADDLE_TPU_TUNING_TABLE'):
         env.pop(var, None)
     scratch = tmp_path / 'tmpdir'
     scratch.mkdir(exist_ok=True)
@@ -64,7 +62,6 @@ def test_env_dir_is_where_the_cache_lives_and_nowhere_else(tmp_path):
     # not the system temp directory
     assert _listing(CHECKOUT_CACHE) == before
     assert not [n for n in os.listdir(scratch) if 'paddle_tpu' in n]
-    assert not list(tmp_path.glob('**/*.jaot'))
 
 
 def test_unset_every_process_names_the_checkout(tmp_path):
@@ -75,14 +72,12 @@ def test_unset_every_process_names_the_checkout(tmp_path):
 
 def test_nothing_resolves_under_the_temp_dir(monkeypatch, tmp_path):
     from paddle_tpu import tuning
-    from paddle_tpu.core import aot_cache, platform_boot
+    from paddle_tpu.core import platform_boot
 
     def kept():
-        return [platform_boot.cache_root(), aot_cache.cache_dir(),
-                tuning.table_path()]
+        return [platform_boot.cache_root(), tuning.table_path()]
 
-    for var in ('JAX_COMPILATION_CACHE_DIR', 'PADDLE_TPU_AOT_CACHE_DIR',
-                'PADDLE_TPU_TUNING_TABLE'):
+    for var in ('JAX_COMPILATION_CACHE_DIR', 'PADDLE_TPU_TUNING_TABLE'):
         monkeypatch.delenv(var, raising=False)
     tmp = os.path.realpath(tempfile.gettempdir())
     for path in kept():
@@ -91,16 +86,6 @@ def test_nothing_resolves_under_the_temp_dir(monkeypatch, tmp_path):
     monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', str(tmp_path / 'c'))
     for path in kept():
         assert path.startswith(str(tmp_path / 'c')), path
-
-
-def test_aot_cache_is_off_without_the_explicit_1(monkeypatch):
-    from paddle_tpu.core import aot_cache, platform_boot
-    # not even on a TPU: the key ignores lowering code (ROADMAP D4)
-    monkeypatch.setattr(platform_boot, 'is_tpu_backend', lambda: True)
-    assert not aot_cache.enabled({})
-    assert not aot_cache.enabled({'PADDLE_TPU_AOT_CACHE': 'auto'})
-    assert not aot_cache.enabled({'PADDLE_TPU_AOT_CACHE': '0'})
-    assert aot_cache.enabled({'PADDLE_TPU_AOT_CACHE': '1'})
 
 
 def test_compile_cache_flag_opt_out(monkeypatch):

@@ -137,7 +137,8 @@ def test_paged_attention_matches_dense_masked_reference():
     must reconstruct exactly the dense sequence."""
     import jax.numpy as jnp
     from paddle_tpu.ops.attention_ops import reference_attention
-    from paddle_tpu.ops.pallas.paged_attention import paged_attention
+    from paddle_tpu.ops.pallas.paged_attention import \
+        paged_attention_blocked
 
     rng = np.random.RandomState(7)
     b, h, nb, bs, p, d = 4, 2, 32, 4, 4, 8
@@ -157,10 +158,10 @@ def test_paged_attention_matches_dense_masked_reference():
                 pages[1, perm[i, j]] = dense[i, :, j * bs:(j + 1) * bs] \
                     .transpose(1, 0, 2).reshape(bs, h * d)
 
-    got = paged_attention(jnp.asarray(q), jnp.asarray(k_pages),
-                          jnp.asarray(v_pages),
-                          jnp.asarray(perm, jnp.int32),
-                          jnp.asarray(lens), layer=1)
+    got = paged_attention_blocked(jnp.asarray(q), jnp.asarray(k_pages),
+                                  jnp.asarray(v_pages),
+                                  jnp.asarray(perm, jnp.int32),
+                                  jnp.asarray(lens), layer=1)
     want = reference_attention(jnp.asarray(q)[:, :, None, :],
                                jnp.asarray(dense_k),
                                jnp.asarray(dense_v),

@@ -211,6 +211,32 @@ def test_serve_scrapes_during_training():
     observe.stop_serving()
 
 
+def test_statusz_executor_table_columns():
+    """A key that has missed and hit: its /statusz row counts both,
+    carries the trace / compile / first-dispatch seconds of the miss,
+    and has no column of a disk cache."""
+    import paddle_tpu as fluid
+    from paddle_tpu import observe
+    from paddle_tpu.observe.diagnostics import _executor_cache_table
+
+    observe.reset()
+    observe.enable()
+    x = fluid.layers.data(name='x', shape=[4], dtype='float32')
+    out = fluid.layers.fc(input=x, size=2)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    for _ in range(3):
+        exe.run(feed={'x': np.ones((2, 4), 'float32')}, fetch_list=[out])
+    table = _executor_cache_table(observe.snapshot())
+    row, = [e for e in table.values() if e['hits'] == 2]
+    assert set(row) == {'kind', 'hits', 'misses', 'trace_seconds',
+                        'compile_seconds', 'first_dispatch_seconds'}
+    assert row['kind'] == 'single' and row['misses'] == 1
+    assert row['trace_seconds'] > 0
+    assert row['compile_seconds'] > 0
+    assert row['first_dispatch_seconds'] > 0
+
+
 def test_healthz_degraded_while_anomaly_tripped():
     """NaN loss trips the streaming detector immediately; /healthz
     flips to 503 degraded until enough in-band samples clear it."""

@@ -40,6 +40,52 @@ def test_compile_cache_reused_across_steps():
     assert len(exe._cache) == n_compiled + 1  # new batch size: new entry
 
 
+def test_run_and_run_steps_of_one_are_one_dispatch():
+    """run and run_steps(1) of one program from one state: the same
+    fetches and scope, and each a miss of its own key and kind."""
+    from paddle_tpu import observe
+    x = fluid.layers.data(name='x', shape=[4], dtype='float32')
+    y = fluid.layers.data(name='y', shape=[1], dtype='float32')
+    pred = fluid.layers.fc(input=x, size=1)
+    cost = fluid.layers.mean(fluid.layers.square_error_cost(pred, y))
+    fluid.optimizer.SGD(learning_rate=0.1).minimize(cost)
+    feed = {'x': rand(8, 4), 'y': rand(8, 1)}
+
+    def from_fresh_state(call):
+        # an executor of its own: the initializers and the step fold
+        # the executor's step index
+        exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+        with fluid.scope_guard(scope):
+            exe.run(fluid.default_startup_program())
+            before = observe.snapshot()['counters']
+            loss, = call(exe)
+            after = observe.snapshot()['counters']
+        missed = {k: v - before.get(k, 0) for k, v in after.items()
+                  if k.startswith('executor.cache_miss_total')
+                  and v != before.get(k, 0)}
+        return np.asarray(loss).reshape(()), \
+            {n: np.asarray(scope.find(n)) for n in scope.keys()}, missed
+
+    observe.reset()
+    observe.enable()
+    try:
+        one, scope_one, miss_one = from_fresh_state(
+            lambda exe: exe.run(feed=feed, fetch_list=[cost]))
+        many, scope_many, miss_many = from_fresh_state(
+            lambda exe: exe.run_steps(1, feed=feed, fetch_list=[cost]))
+    finally:
+        observe.disable()
+        observe.reset()
+    assert one == many
+    assert sorted(scope_one) == sorted(scope_many)
+    for name, value in scope_one.items():
+        np.testing.assert_array_equal(value, scope_many[name], name)
+    (k_one, n_one), = miss_one.items()
+    (k_many, n_many), = miss_many.items()
+    assert n_one == n_many == 1
+    assert 'kind=single' in k_one and 'kind=multi' in k_many
+
+
 def test_prune_skips_unrelated_branches():
     """Fetching one branch must not require feeds of the other."""
     x = fluid.layers.data(name='x', shape=[4], dtype='float32')

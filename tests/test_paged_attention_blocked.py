@@ -27,6 +27,7 @@ CAP = P * BS
 # name -> (query heads, KV heads, head width, arena dtype)
 LAYOUTS = {
     'plain_f32_d64': (4, 4, 64, 'float32'),
+    'plain_bf16_d16': (4, 4, 16, 'bfloat16'),
     'grouped_bf16_d128': (8, 2, 128, 'bfloat16'),
     'int8_scales': (4, 4, 16, 'int8'),
     'fp8_scales': (4, 4, 16, 'float8_e4m3fn'),

@@ -264,33 +264,31 @@ def _paged_case(b=3, h=2, nb=16, bs=8, p=4, d=16, seed=5):
     return q, k_pages, v_pages, tables, lens
 
 
-def test_paged_attention_kernel_parity(monkeypatch):
-    """The scalar-prefetch Pallas kernel (block table drives the page
-    index map) must match the XLA gather reference across mixed
+def test_paged_attention_parity():
+    """The blocked form must match the dense reference across mixed
     lengths."""
-    monkeypatch.setenv('PADDLE_TPU_PAGED_PALLAS', '1')
     from paddle_tpu.ops.pallas.paged_attention import (
-        paged_attention, paged_attention_reference)
+        paged_attention_blocked, paged_attention_reference)
     q, kp, vp, tables, lens = _paged_case()
-    got = paged_attention(q, kp, vp, tables, lens)
+    got = paged_attention_blocked(q, kp, vp, tables, lens)
     want = paged_attention_reference(q, kp, vp, tables, lens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-6)
 
 
-def test_paged_attention_kernel_ignores_unowned_pages(monkeypatch):
+def test_paged_attention_ignores_unowned_pages():
     """Entries past a sequence's length (including the >= NB 'no page'
     sentinel) must not leak into the output."""
-    monkeypatch.setenv('PADDLE_TPU_PAGED_PALLAS', '1')
-    from paddle_tpu.ops.pallas.paged_attention import paged_attention
+    from paddle_tpu.ops.pallas.paged_attention import \
+        paged_attention_blocked
     q, kp, vp, tables, lens = _paged_case()
-    base = np.asarray(paged_attention(q, kp, vp, tables, lens))
+    base = np.asarray(paged_attention_blocked(q, kp, vp, tables, lens))
     # scribble over every table entry beyond the owned pages
     t2 = np.asarray(tables).copy()
     nb, bs = kp.shape[1], kp.shape[2]
     for i, n in enumerate(np.asarray(lens)):
         owned = (int(n) + bs - 1) // bs
         t2[i, owned:] = nb + 7
-    got = np.asarray(paged_attention(q, kp, vp,
-                                     jnp.asarray(t2, jnp.int32), lens))
+    got = np.asarray(paged_attention_blocked(
+        q, kp, vp, jnp.asarray(t2, jnp.int32), lens))
     np.testing.assert_array_equal(base, got)

@@ -470,7 +470,7 @@ def test_paged_attention_quantized_parity():
     """The dequantizing gather path vs fp32 on ragged mixed lengths —
     the parity bound the bench asserts, in unit form."""
     from paddle_tpu.ops.pallas.paged_attention import (
-        paged_attention, paged_attention_reference)
+        paged_attention_blocked, paged_attention_reference)
     rng = np.random.RandomState(7)
     nb, h, bs, d = 6, 2, 4, 8
     kf = rng.randn(1, nb, bs, h, d).astype('float32')   # per-head rows
@@ -488,7 +488,7 @@ def test_paged_attention_quantized_parity():
             (('float8_e4m3fn',) if qcore.kv_fp8_supported() else ()):
         kq, ks = qcore.quantize_rows(jnp.asarray(kf), dt)
         vq, vs = qcore.quantize_rows(jnp.asarray(vf), dt)
-        got = np.asarray(paged_attention(
+        got = np.asarray(paged_attention_blocked(
             q, arena(kq), arena(vq), tables, lens,
             k_scales=np.asarray(ks), v_scales=np.asarray(vs)))
         cos = float((ref * got).sum() /

@@ -21,6 +21,24 @@ def test_repo_tree_is_clean():
     assert not violations, '\n'.join(v.format() for v in violations)
 
 
+def test_knob_census():
+    """Every whole PADDLE_TPU_* name the package reads (prefixes that
+    end in ``_`` are families, not names). A PR that adds one raises
+    the number here, in its diff."""
+    import re
+    names = set()
+    for root, _, files in os.walk(os.path.join(REPO, 'paddle_tpu')):
+        for f in files:
+            if f.endswith('.py'):
+                with open(os.path.join(root, f)) as src:
+                    names.update(re.findall(r'PADDLE_TPU_[A-Z0-9_]+',
+                                            src.read()))
+    whole = {n for n in names if not n.endswith('_')}
+    assert len(whole) <= 61, sorted(whole)
+    assert not whole & {'PADDLE_TPU_AOT_CACHE', 'PADDLE_TPU_AOT_CACHE_DIR',
+                        'PADDLE_TPU_PAGED_PALLAS'}
+
+
 def test_cli_exit_codes_and_json(tmp_path):
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, 'tools', 'repo_lint.py'),

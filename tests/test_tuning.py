@@ -1,11 +1,8 @@
-"""Kernel autotuner + AOT warm start (ISSUE 8).
+"""Kernel autotuner (ISSUE 8).
 
 Covers the tuning-table lifecycle (round-trip, corruption fallback,
 deterministic winners under injected timings, env-gate precedence over
-table entries), the per-call block-size satellite, the executor's AOT
-serialized-executable cache (in-process warm start with zero
-trace/compile events, tampered-cache fallback), the stdlib CLI, and
-the subprocess cold-vs-warm e2e the acceptance criteria name.
+table entries), the per-call block-size satellite and the stdlib CLI.
 """
 
 import json
@@ -13,10 +10,8 @@ import os
 import subprocess
 import sys
 
-import numpy as np
 import pytest
 
-import paddle_tpu as fluid
 from paddle_tpu import observe, tuning
 
 
@@ -25,9 +20,8 @@ def _fresh_tuning(tmp_path, monkeypatch):
     """Every test gets its own table path, a clean tuner, and no
     autotune/gate env leakage."""
     for var in ('PADDLE_TPU_AUTOTUNE', 'PADDLE_TPU_USE_PALLAS',
-                'PADDLE_TPU_PAGED_PALLAS', 'PADDLE_TPU_BN_PALLAS',
-                'PADDLE_TPU_PALLAS_BLOCK_K', 'PADDLE_TPU_PALLAS_BLOCK_Q',
-                'PADDLE_TPU_AOT_CACHE', 'PADDLE_TPU_AOT_CACHE_DIR'):
+                'PADDLE_TPU_BN_PALLAS', 'PADDLE_TPU_PALLAS_BLOCK_K',
+                'PADDLE_TPU_PALLAS_BLOCK_Q'):
         monkeypatch.delenv(var, raising=False)
     monkeypatch.setenv('PADDLE_TPU_TUNING_TABLE',
                        str(tmp_path / 'tuning.json'))
@@ -214,96 +208,7 @@ def test_attention_block_variants_divide():
             assert tq % bq == 0 and tk % bk == 0
 
 
-# --------------------------------------------------------- AOT warm start
-def _build_mlp():
-    fluid.reset_default_programs()
-    fluid.global_scope().clear()
-    x = fluid.layers.data(name='x', shape=[16], dtype='float32')
-    h = fluid.layers.fc(input=x, size=16, act='relu',
-                        param_attr=fluid.ParamAttr(
-                            initializer=fluid.initializer.Constant(0.1)))
-    out = fluid.layers.fc(input=h, size=2,
-                          param_attr=fluid.ParamAttr(
-                              initializer=fluid.initializer.Constant(0.2)))
-    return out
-
-
-def test_executor_aot_warm_start_zero_trace_events(tmp_path, monkeypatch):
-    monkeypatch.setenv('PADDLE_TPU_AOT_CACHE', '1')
-    monkeypatch.setenv('PADDLE_TPU_AOT_CACHE_DIR', str(tmp_path / 'aot'))
-    feed = {'x': np.ones((3, 16), 'float32')}
-
-    out = _build_mlp()
-    exe1 = fluid.Executor(fluid.CPUPlace())
-    exe1.run(fluid.default_startup_program())
-    r1 = exe1.run(feed=feed, fetch_list=[out])
-    assert exe1.aot_stats['saves'] == 2           # startup + step
-    assert not exe1.last_warm_from_disk
-
-    observe.arm_flight()
-    before = len(observe.flight_recorder().events())
-    out2 = _build_mlp()                            # same content, new ids
-    exe2 = fluid.Executor(fluid.CPUPlace())
-    exe2.run(fluid.default_startup_program())
-    r2 = exe2.run(feed=feed, fetch_list=[out2])
-    assert exe2.aot_stats['hits'] == 2
-    assert exe2.aot_stats['load_failures'] == 0
-    assert exe2.last_warm_from_disk
-    events = observe.flight_recorder().events()[before:]
-    kinds = [e['kind'] for e in events]
-    # THE warm-start contract: executables came off disk, nothing
-    # traced, nothing compiled
-    assert kinds.count('aot_load') == 2
-    assert 'compile' not in kinds
-    np.testing.assert_allclose(r1[0], r2[0])
-    # warm executable stays dispatchable (donation honored across calls)
-    r3 = exe2.run(feed=feed, fetch_list=[out2])
-    np.testing.assert_allclose(r2[0], r3[0])
-
-
-def test_aot_tampered_cache_falls_back(tmp_path, monkeypatch):
-    monkeypatch.setenv('PADDLE_TPU_AOT_CACHE', '1')
-    cache = tmp_path / 'aot'
-    monkeypatch.setenv('PADDLE_TPU_AOT_CACHE_DIR', str(cache))
-    feed = {'x': np.ones((3, 16), 'float32')}
-
-    out = _build_mlp()
-    exe1 = fluid.Executor(fluid.CPUPlace())
-    exe1.run(fluid.default_startup_program())
-    r1 = exe1.run(feed=feed, fetch_list=[out])
-    for f in cache.iterdir():                      # corrupt every entry
-        f.write_bytes(b'not a serialized executable')
-
-    observe.arm_flight()
-    before = len(observe.flight_recorder().events())
-    out2 = _build_mlp()
-    exe2 = fluid.Executor(fluid.CPUPlace())
-    exe2.run(fluid.default_startup_program())
-    r2 = exe2.run(feed=feed, fetch_list=[out2])
-    assert exe2.aot_stats['hits'] == 0
-    assert exe2.aot_stats['load_failures'] == 2
-    events = observe.flight_recorder().events()[before:]
-    assert any(e['kind'] == 'aot_fallback' for e in events)
-    np.testing.assert_allclose(r1[0], r2[0])       # live compile worked
-
-
-def test_aot_fingerprint_content_not_identity(tmp_path, monkeypatch):
-    """Two Program OBJECTS with identical content share a fingerprint;
-    different content (one extra layer) does not."""
-    from paddle_tpu.core import aot_cache
-    _build_mlp()
-    p1 = fluid.default_main_program()
-    fp1 = aot_cache.fingerprint(p1, ('single',))
-    _build_mlp()
-    p2 = fluid.default_main_program()
-    assert p2 is not p1
-    assert aot_cache.fingerprint(p2, ('single',)) == fp1
-    fluid.layers.fc(input=p2.global_block().var('x'), size=3)
-    assert aot_cache.fingerprint(p2, ('single',)) != fp1
-    assert aot_cache.fingerprint(p1, ('multi',)) != fp1
-
-
-# ------------------------------------------------------------ CLI + e2e
+# ------------------------------------------------------------------- CLI
 def test_tuning_inspect_cli(tmp_path, monkeypatch):
     monkeypatch.setenv('PADDLE_TPU_AUTOTUNE', 'on')
     tuning.set_timer(_fake_timer({'tq1024': 'xla',
@@ -363,63 +268,3 @@ def test_tuning_inspect_cli(tmp_path, monkeypatch):
     assert r2.returncode == 0 and 'winner' in r2.stdout
     assert 'linalg panel/block winners' in r2.stdout
     assert 'matmul dtype winners' in r2.stdout
-
-
-def _jsonl_records(path):
-    with open(path) as f:
-        return [json.loads(line) for line in f if line.strip()]
-
-
-def test_cold_then_warm_subprocess_e2e(tmp_path):
-    """Acceptance: the same program twice in two processes sharing one
-    AOT cache dir — the second reports zero compile flight events on
-    its hot keys and strictly lower startup wall (metrics JSONL is the
-    evidence trail)."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    cmd = [sys.executable, os.path.join(repo, 'bench.py'),
-           '--workload', 'autotune_child']
-
-    def run(tag):
-        env = dict(os.environ)
-        env.update({
-            'PADDLE_TPU_AOT_CACHE': '1',
-            'PADDLE_TPU_AOT_CACHE_DIR': str(tmp_path / 'aot'),
-            'PADDLE_TPU_METRICS_JSONL': str(tmp_path / (tag + '.jsonl')),
-            'JAX_PLATFORMS': 'cpu',
-        })
-        env.pop('PADDLE_TPU_AUTOTUNE', None)
-        r = subprocess.run(cmd, capture_output=True, text=True,
-                           timeout=300, env=env, cwd=repo)
-        assert r.returncode == 0, r.stderr[-2000:]
-        for line in reversed(r.stdout.splitlines()):
-            if line.startswith('RESULT_JSON '):
-                return json.loads(line[len('RESULT_JSON '):])
-        raise AssertionError('no RESULT_JSON in child stdout:\n'
-                             + r.stdout)
-
-    cold = run('cold')
-    warm = run('warm')
-    assert cold['aot_hits'] == 0 and cold['aot_saves'] >= 2
-    assert cold['compile_flight_events'] >= 2
-    # the warm process: every hot key came off disk, ZERO compiles
-    assert warm['aot_hits'] >= 2
-    assert warm['compile_flight_events'] == 0
-    assert warm['first_loss'] == pytest.approx(cold['first_loss'])
-    # strictly-below startup wall (CPU CI tolerance: the cold run pays
-    # a real multi-layer XLA compile, the warm run a deserialize)
-    assert warm['startup_seconds'] < cold['startup_seconds']
-    # and the metrics JSONL shows it: warm run recorded aot hits and
-    # NO executor cache misses
-    warm_recs = _jsonl_records(tmp_path / 'warm.jsonl')
-    counters = {}
-    for rec in warm_recs:
-        counters.update(rec.get('counters', {}))
-    assert any(k.startswith('executor.aot_hit_total') for k in counters)
-    assert not any(k.startswith('executor.cache_miss_total')
-                   for k in counters)
-    cold_recs = _jsonl_records(tmp_path / 'cold.jsonl')
-    cold_counters = {}
-    for rec in cold_recs:
-        cold_counters.update(rec.get('counters', {}))
-    assert any(k.startswith('executor.cache_miss_total')
-               for k in cold_counters)
