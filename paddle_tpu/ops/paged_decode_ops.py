@@ -214,6 +214,12 @@ class _ParallelMoEBlock(object):
         self.first = int(ctx.attr('first_expert', 0))
         self.logit_scale = float(ctx.attr('logit_scale', 1.0))
         self.params = _stacked_weights(ctx, self.slots)
+        # the routed experts stay stacked: each row tile of their product
+        # slices its (layer, expert) out where it lies (moe_held_ops)
+        self.routed = tuple(self.params.pop(s) for s in
+                            ('exp_gate', 'exp_up', 'exp_down'))
+        self.params['layer'] = jnp.arange(self.routed[0].shape[0],
+                                          dtype=jnp.int32)
         # the layer kind, scanned beside the weights
         self.params['window'] = jnp.asarray(
             [int(w) for w in ctx.attr('windows')], jnp.int32)
@@ -254,10 +260,10 @@ class _ParallelMoEBlock(object):
         from . import moe_held_ops as moe
         a = _mm(attn.reshape(h.shape[0], -1), p['slf_o'])
         chosen, weight = moe.route_sigmoid_topk(n, p['router'], self.top_k)
-        gate, hit = moe.held_gates(chosen, weight, self.first,
-                                   p['exp_gate'].shape[0])
-        m = moe.gated_experts(n, gate, p['exp_gate'], p['exp_up'],
-                              p['exp_down'])
+        held = self.routed[0].shape[1]
+        gate, hit = moe.held_gates(chosen, weight, self.first, held)
+        m = moe.routed_experts(n, gate, hit, valid, min(self.top_k, held),
+                               *self.routed, layer=p['layer'])
         n_shared = p['shr_gate'].shape[0]
         m += moe.gated_experts(
             n, jnp.full((n.shape[0], n_shared), 1.0 / n_shared),
@@ -409,7 +415,7 @@ def _paged_decode_step(ctx):
     ctx.set_output('NextTokens',
                    nxt.astype(ctx.out_dtype('NextTokens', 'int64')))
     if stats is not None:
-        ctx.set_output('MoeStats', stats)               # [L, 3] int32
+        ctx.set_output('MoeStats', stats)               # [L, 4] int32
     _set_arena_outputs(ctx, kcs, vcs, kss, vss)
 
 
@@ -505,7 +511,7 @@ def _paged_prefill(ctx):
     # the sequence's pages gathered block by block for the whole chunk
     # (rows past ``length`` see nothing), and the one row that is
     # sampled projected onto the vocabulary
-    h, kcs, vcs, kss, vss, _ = _extend_rows(
+    h, kcs, vcs, kss, vss, stats = _extend_rows(
         block, kcs, vcs, ids, pos, table, place, kss, vss,
         valid=jnp.arange(s) < length)
     logits_last = block.logits(jax.lax.dynamic_slice_in_dim(
@@ -514,6 +520,8 @@ def _paged_prefill(ctx):
     ctx.set_output('NextToken',
                    nxt.reshape(1).astype(ctx.out_dtype('NextToken',
                                                        'int64')))
+    if stats is not None:
+        ctx.set_output('MoeStats', stats)               # [L, 4] int32
     _set_arena_outputs(ctx, kcs, vcs, kss, vss)
 
 

@@ -224,6 +224,7 @@ class DecodeEngine(object):
         self._health_name = None
         self._step_no = 0
         self._step_stats = None
+        self._prefill_stats = []    # (device MoeStats, program rows) a chunk
         self.warmup_signatures = 0
         self.warmup_total_seconds = 0.0
 
@@ -721,17 +722,24 @@ class DecodeEngine(object):
                      wait=True):
         """One prefill dispatch. ``wait=False`` (a chunk that is not the
         prefix's last) leaves the sampled token on the device unread, so
-        the next chunk is enqueued behind it without a round trip."""
+        the next chunk is enqueued behind it without a round trip. A
+        block that keeps router statistics hands them back beside the
+        token; they are left in ``_prefill_stats`` (on the device, for a
+        chunk that is not waited for) for the prefill's emit."""
         # one Program, one XLA module per bucket: the name is read when a
         # bucket's signature compiles
         self._progs.prefill.name = 'prefill_%d' % ids.shape[1]
+        fetch = [self._progs.prefill_fetch]
+        if self._progs.prefill_stats_fetch is not None:
+            fetch.append(self._progs.prefill_stats_fetch)
         with self._arena_mu, scope_guard(self._scope):
             out = self._exe.run(
                 program=self._progs.prefill,
                 feed=self._prefill_feed(ids, length, cached, table, temp,
                                         seed),
-                fetch_list=[self._progs.prefill_fetch],
-                return_numpy=wait)
+                fetch_list=fetch, return_numpy=wait)
+        if len(out) > 1:
+            self._prefill_stats.append((out[1], ids.shape[1]))
         return int(np.asarray(out[0]).reshape(-1)[0]) if wait else None
 
     def _dispatch_verify(self, tokens, lens, tables, temps, seeds):
@@ -820,6 +828,7 @@ class DecodeEngine(object):
             # the largest program this prefill runs (its first chunk's):
             # the label of its span, its time and its trace stage
             bucket = self._bucket(min(top, s - cached))
+        del self._prefill_stats[:]
         with _obs.span('decode.prefill.run', bucket=bucket,
                        chunks=len(starts)):
             t0 = time.perf_counter()
@@ -841,6 +850,10 @@ class DecodeEngine(object):
         _obs.inc('decode.prefills_total')
         _obs.inc('decode.prefill_chunks', len(starts))
         with _obs.span('decode.prefill.emit'):
+            if _obs.enabled():
+                # every chunk's program ended before the token was read
+                for stats, rows in self._prefill_stats:
+                    self._record_moe_tiles(np.asarray(stats), rows)
             if cached:
                 _obs.flight_event('decode_prefix_hit',
                                   request_id=seq.request_id,
@@ -972,22 +985,34 @@ class DecodeEngine(object):
                     self._finish(seq, reason)
 
     def _record_moe(self, stats, rows):
-        """One decode step's router statistics ([n_layer, 3]: choices
+        """One decode step's router statistics ([n_layer, 4]: choices
         that landed on an expert held here, rows on the busiest of
-        them, experts any row chose) into the counters the benchmark
-        reads: of rows x experts_per_token choices a layer, the local
-        ones; the busiest expert's load against the mean, per
-        layer-step."""
+        them, experts any row chose, row tiles the routed product ran)
+        into the counters the benchmark reads: of rows x
+        experts_per_token choices a layer, the local ones; the busiest
+        expert's load against the mean, per layer-step."""
         held = self.spec.experts_held
+        self._record_moe_tiles(stats, self.max_batch)
         _obs.inc('decode.moe_assignments',
                  rows * self.spec.experts_per_token * len(stats))
         _obs.inc('decode.moe_local_assignments', int(stats[:, 0].sum()))
         _obs.inc('decode.moe_experts_touched', int(stats[:, 2].sum()))
         _obs.inc('decode.moe_layer_steps', len(stats))
-        for local, busiest, _ in stats:
+        for local, busiest in stats[:, :2]:
             if local:
                 _obs.record('decode.moe_load_max_over_mean',
                             busiest * held / float(local))
+
+    def _record_moe_tiles(self, stats, program_rows):
+        """The row tiles the routed experts' product ran in one program
+        (a decode step or a prefill chunk of ``program_rows`` rows),
+        beside what a product over every expert held costs at the same
+        tile."""
+        from ...ops.moe_held_ops import row_tiles
+        _obs.inc('decode.moe_row_tiles_run', int(stats[:, 3].sum()))
+        _obs.inc('decode.moe_row_tiles_dense',
+                 len(stats) * self.spec.experts_held
+                 * row_tiles(program_rows))
 
     def _spec_step(self):
         """Draft-and-verify decode: the draft proposes up to k tokens

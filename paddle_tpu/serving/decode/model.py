@@ -147,7 +147,7 @@ DecodePrograms = collections.namedtuple(
     'DecodePrograms',
     ['startup', 'prefill', 'decode', 'verify', 'prefill_fetch',
      'decode_fetch', 'verify_fetch', 'param_names', 'arena_names',
-     'capacity', 'kv_dtype', 'stats_fetch'])
+     'capacity', 'kv_dtype', 'stats_fetch', 'prefill_stats_fetch'])
 
 
 def kv_bytes_per_token(spec, kv_dtype='float32'):
@@ -319,6 +319,19 @@ def _arena_outputs(kc, vc, ks=None, vs=None):
     return outputs
 
 
+def _moe_stats_output(helper, spec, outputs):
+    """Give a ``parallel_moe`` program its MoeStats output (per layer:
+    choices that landed on an expert held here, rows on the busiest of
+    them, experts any row chose, row tiles the routed product ran) and
+    return its name; None for a block that routes nothing."""
+    if spec.block != 'parallel_moe':
+        return None
+    stats = helper.create_variable_for_type_inference('int32')
+    stats.shape = (spec.n_layer, 4)
+    outputs['MoeStats'] = [stats]
+    return stats.name
+
+
 def build_lm_programs(spec, max_batch, block_size, num_blocks,
                       pages_per_seq, spec_k=0, kv_dtype='float32'):
     """Returns DecodePrograms. ``capacity`` (= pages_per_seq *
@@ -365,6 +378,7 @@ def build_lm_programs(spec, max_batch, block_size, num_blocks,
                        'Seed': [seed]})
         outputs = dict(_arena_outputs(kc, vc, ks, vs),
                        NextToken=[nxt])
+        prefill_stats_fetch = _moe_stats_output(helper, spec, outputs)
         helper.append_op(type='paged_prefill', inputs=inputs,
                          outputs=outputs, attrs=attrs)
         prefill_fetch = nxt.name
@@ -387,14 +401,7 @@ def build_lm_programs(spec, max_batch, block_size, num_blocks,
                        'Seeds': [seeds]})
         outputs = dict(_arena_outputs(kc, vc, ks, vs),
                        NextTokens=[nxt])
-        stats_fetch = None
-        if moe:
-            # per layer: choices that landed on an expert held here, rows
-            # on the busiest of them, experts any row chose
-            stats = helper.create_variable_for_type_inference('int32')
-            stats.shape = (spec.n_layer, 3)
-            outputs['MoeStats'] = [stats]
-            stats_fetch = stats.name
+        stats_fetch = _moe_stats_output(helper, spec, outputs)
         helper.append_op(type='paged_decode_step', inputs=inputs,
                          outputs=outputs, attrs=attrs)
         decode_fetch = nxt.name
@@ -440,7 +447,8 @@ def build_lm_programs(spec, max_batch, block_size, num_blocks,
         verify_fetch=verify_fetch,
         param_names=param_names,
         arena_names=arena_names,
-        capacity=capacity, kv_dtype=kv_dtype, stats_fetch=stats_fetch)
+        capacity=capacity, kv_dtype=kv_dtype, stats_fetch=stats_fetch,
+        prefill_stats_fetch=prefill_stats_fetch)
 
 
 def random_weights(spec, seed=0):

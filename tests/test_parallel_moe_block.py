@@ -133,9 +133,9 @@ def test_router_hand_worked_case():
         np.asarray(gate), [[want[0, 1], 0.0], [0.0, want[1, 1]]], rtol=1e-6)
     assert hit.tolist() == [[True, False], [False, True]]
     stats = moe.load_stats(hit, jnp.asarray([True, True]))
-    assert stats.tolist() == [2, 1, 2]
+    assert stats.tolist() == [2, 1, 2, 2]
     assert moe.load_stats(hit, jnp.asarray([True, False])).tolist() == \
-        [1, 1, 1]
+        [1, 1, 1, 1]
 
 
 def test_reference_router_is_the_same_rule():
@@ -192,6 +192,112 @@ def test_shares_add_up_to_the_uncut_layer():
     # and a share alone is not the layer
     assert np.abs(np.asarray(ref.experts(n, cut(0, 4), layer, arch,
                                          (0, 4))) - uncut).max() > 1e-2
+
+
+# ------------------------------------- the routed product, by its oracle
+E_HELD, FIRST, TOP_K, N_EXPERTS = 4, 2, 3, 8
+# two layers of four held experts, 16 wide with 24 inside
+_EXPERTS = tuple(
+    (np.random.RandomState(3 + i).randn(2, E_HELD, a, b) * a ** -0.5)
+    .astype('float32') for i, (a, b) in enumerate(((16, 24), (16, 24),
+                                                    (24, 16))))
+
+
+def _routing(case, n, rng):
+    """(chosen [n, TOP_K] distinct experts of the 8, weight, valid)."""
+    held = np.arange(FIRST, FIRST + E_HELD)
+    away = np.setdiff1d(np.arange(N_EXPERTS), held)
+    pool = {'worst': held, 'none': away}.get(case, np.arange(N_EXPERTS))
+    chosen = np.stack([rng.permutation(pool)[:TOP_K] for _ in range(n)])
+    weight = rng.rand(n, TOP_K).astype('float32') + 0.1
+    weight /= weight.sum(axis=1, keepdims=True)
+    valid = rng.rand(n) < 0.6 if case == 'dead' else np.ones(n, bool)
+    return (jnp.asarray(chosen, jnp.int32), jnp.asarray(weight),
+            jnp.asarray(valid))
+
+
+def _routed(x, chosen, weight, valid, layer=1):
+    gate, hit = moe.held_gates(chosen, weight, FIRST, E_HELD)
+    out = moe.routed_experts(x, gate, hit, valid, min(TOP_K, E_HELD),
+                             *(jnp.asarray(w) for w in _EXPERTS),
+                             layer=jnp.int32(layer))
+    return out, gate, hit
+
+
+@pytest.mark.parametrize('rows', [32, 512])
+@pytest.mark.parametrize('case', ['random', 'worst', 'none', 'dead'])
+def test_grouped_product_is_the_masked_product(case, rows):
+    """Rows grouped by the expert they chose (512: tiles of 128 of one
+    expert; 32: all rows under each touched expert's gate column) give
+    what the gate-masked product over every held expert gives, for
+    random routings, for every row choosing as many held experts as it
+    can, for none choosing any, and with rows that are not live, whose
+    choices add nothing and touch nothing."""
+    rng = np.random.RandomState(rows + len(case))
+    x = jnp.asarray(rng.randn(rows, 16), jnp.float32)
+    chosen, weight, valid = _routing(case, rows, rng)
+    out, gate, hit = _routed(x, chosen, weight, valid)
+    want = moe.gated_experts(
+        x, jnp.where(valid[:, None], gate, 0.0),
+        *(jnp.asarray(w[1]) for w in _EXPERTS))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=TOL, rtol=0)
+    live = np.asarray(hit) & np.asarray(valid)[:, None]
+    stats = moe.load_stats(hit, valid).tolist()
+    assert stats[0] == live.sum() and stats[2] == live.any(axis=0).sum()
+    if case == 'worst':
+        assert stats[0] == rows * min(TOP_K, E_HELD)
+    if case == 'none':
+        assert stats == [0, 0, 0, 0] and not np.asarray(out).any()
+    if case == 'dead':
+        dead = ~np.asarray(valid)
+        assert dead.any() and np.asarray(hit)[dead].any()
+        assert not np.asarray(out)[dead].any()
+
+
+@pytest.mark.parametrize('rows', [32, 512])
+def test_a_row_alone_is_the_row_among_others_bit_for_bit(rows):
+    """The decode shape and a chunk shape: a row with every other row
+    dead, and the same row at the same slot among 31 (511) live others
+    that land it in another place of another tile, bit for bit."""
+    rng = np.random.RandomState(rows)
+    x = jnp.asarray(rng.randn(rows, 16), jnp.float32)
+    chosen, weight, _ = _routing('random', rows, rng)
+    # the row chooses two held experts and one that is away
+    slot = rows // 2 + 3
+    chosen = chosen.at[slot].set(jnp.asarray([FIRST + 3, 0, FIRST + 1]))
+    only = jnp.arange(rows) == slot
+    alone, _, _ = _routed(x, chosen, weight, only)
+    among, _, hit = _routed(x, chosen, weight, jnp.ones((rows,), bool))
+    assert np.asarray(hit)[:slot, [1, 3]].any()    # rows ahead of it
+    assert np.asarray(alone)[slot].any()
+    assert np.array_equal(np.asarray(alone)[slot], np.asarray(among)[slot])
+    assert not np.asarray(alone)[~np.asarray(only)].any()
+
+
+def test_row_tiles_hand_worked_case():
+    """512 rows over the four held experts, 0, 1, 128 and 300 live rows
+    on them: 0 + 1 + 1 + 3 tiles of 128; a dead row's choice makes none;
+    the first 32 rows alone (one on expert 1, 22 on expert 2) are one
+    tile for each of the two."""
+    hit = np.zeros((512, E_HELD), bool)
+    hit[5, 1] = True
+    hit[10:138, 2] = True
+    hit[200:500, 3] = True
+    valid = np.ones(512, bool)
+    assert moe.load_stats(jnp.asarray(hit), jnp.asarray(valid)).tolist() \
+        == [429, 300, 3, 5]
+    valid[5] = False            # expert 1 untouched
+    valid[499] = False          # 299 rows are still three tiles
+    valid[137] = False          # 127 rows are still one
+    assert moe.load_stats(jnp.asarray(hit), jnp.asarray(valid)).tolist() \
+        == [426, 299, 2, 4]
+    hit[138, 2] = True
+    valid[137] = True           # 129 rows are two
+    assert moe.load_stats(jnp.asarray(hit), jnp.asarray(valid)).tolist() \
+        == [428, 299, 2, 5]
+    assert moe.load_stats(jnp.asarray(hit[:32]),
+                          jnp.ones((32,), bool)).tolist() == [23, 22, 2, 2]
 
 
 # ------------------------------------------------- attention, alone
@@ -305,7 +411,9 @@ def test_decode_batch_of_mixed_lengths_matches_reference():
         np.testing.assert_allclose(
             np.asarray(got)[i], _reference_logits(seq)[-1], atol=TOL)
     stats = np.asarray(stats)
-    assert stats.shape == (SPEC.n_layer, 3)
+    assert stats.shape == (SPEC.n_layer, 4)
+    # 5 rows are one tile an expert: the loop runs once for each touched
+    assert (stats[:, 3] == stats[:, 2]).all()
     # 4 live rows x 3 choices a layer bound the local ones; the busiest
     # expert holds at most every live row; at most 4 experts are held
     assert (stats[:, 0] <= 12).all() and (stats[:, 1] <= 4).all()
@@ -430,6 +538,53 @@ def test_engine_batched_equals_one_at_a_time_and_the_reference():
         gaps, _ = ref.token_gaps(WEIGHTS, _arch(SPEC), _held(SPEC),
                                  prompt, answer, 8)
         assert max(gaps) <= TOL
+
+
+def test_engine_counts_the_row_tiles_its_programs_ran():
+    """A prefill in chunks and the decode steps behind it feed both
+    counters: a tile for every (layer, expert some live row chose) in
+    each program, against one for every expert held (all programs here
+    are under 128 rows); the decode steps' share is what the touched
+    experts counter says."""
+    from paddle_tpu import observe
+    eng = _engine()
+    eng.warmup()
+    eng.start()
+    observe.enable()
+    try:
+        before = observe.snapshot()
+        eng.generate(list(range(1, 20)), max_new_tokens=1, timeout=120)
+        prefill = observe.snapshot()
+        eng.generate(list(range(3, 9)), max_new_tokens=5, timeout=120)
+        after = observe.snapshot()
+    finally:
+        eng.shutdown()
+        observe.disable()
+        observe.reset()
+
+    def grown(name, a, b):
+        return b['counters'].get(name, 0) - a['counters'].get(name, 0)
+    per_program = SPEC.n_layer * SPEC.experts_held
+    # 19 tokens in chunks of 8 are three programs, and no decode step:
+    # the two counters the benchmark's HBM shares take ``touched`` from
+    # are the decode steps' alone, and a prefill leaves them as they were
+    assert grown('decode.prefill_chunks', before, prefill) == 3
+    assert grown('decode.moe_layer_steps', before, prefill) == 0
+    assert grown('decode.moe_experts_touched', before, prefill) == 0
+    assert grown('decode.moe_row_tiles_dense', before, prefill) == \
+        3 * per_program
+    assert 0 < grown('decode.moe_row_tiles_run', before, prefill) <= \
+        3 * per_program
+    # one chunk and four decode steps
+    steps = grown('decode.steps_total', prefill, after)
+    assert steps == 4
+    assert grown('decode.moe_row_tiles_dense', prefill, after) == \
+        (1 + steps) * per_program
+    run = grown('decode.moe_row_tiles_run', prefill, after)
+    touched = grown('decode.moe_experts_touched', prefill, after)
+    assert 0 < touched <= run <= touched + per_program
+    # one live row a step chooses at most 3 experts a layer
+    assert touched <= steps * SPEC.n_layer * 3
 
 
 def test_engine_keeps_declared_dtypes_and_device_arrays():

@@ -32,17 +32,25 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _materialized(hlo, at_least):
-    """(op, type[dims], bytes) of every instruction outside a fused
-    computation whose result is ``at_least`` bytes or more."""
-    out, fused = [], False
+def _outside_fusions(hlo):
+    """The lines of ``hlo`` that are not inside a fused computation."""
+    fused = False
     for line in hlo.split('\n'):
         head = re.match(r'^(ENTRY )?(%[\w.\-]+) \(', line)
         if head:
             fused = 'fused_computation' in head.group(2)
+        if not fused:
+            yield line
+
+
+def _materialized(hlo, at_least):
+    """(op, type[dims], bytes) of every instruction outside a fused
+    computation whose result is ``at_least`` bytes or more."""
+    out = []
+    for line in _outside_fusions(hlo):
         m = re.match(r'^\s+(ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* '
                      r'([\w\-]+)\(', line)
-        if not m or fused or m.group(2) not in SIZE:
+        if not m or m.group(2) not in SIZE:
             continue
         n = SIZE[m.group(2)]
         for d in m.group(3).split(','):
@@ -52,30 +60,68 @@ def _materialized(hlo, at_least):
     return out
 
 
+def _fusions_reading(hlo, operand_type):
+    """The fusion instructions outside fused computations one of whose
+    operands has ``operand_type`` (``dtype[dims]``)."""
+    kind = dict(re.findall(r'(%[\w.\-]+) = (\w+\[[\d,]*\])', hlo))
+    out = []
+    for line in _outside_fusions(hlo):
+        m = re.match(r'^\s+(ROOT )?(%[\w.\-]+) = \S+ fusion\(([^)]*)\)',
+                     line)
+        if m and any(kind.get(name) == operand_type
+                     for name in re.findall(r'%[\w.\-]+', m.group(3))):
+            out.append(m.group(2))
+    return out
+
+
 def _shaped(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def test_expert_product_reads_the_stacked_weights_where_they_lie(one_chip):
-    """32 decode rows against 16 experts of 3 x 4096 x 4096 in a scan over
-    2 stacked layers: no instruction writes anything of an expert
-    tensor's size (0.5 GB a layer), so each is read once, inside the
-    product."""
-    from paddle_tpu.ops.moe_held_ops import gated_experts
-    L, E, D, F, N = 2, 16, 4096, 4096, 32
+@pytest.mark.parametrize('form,rows', [('shared', 32), ('routed', 32),
+                                       ('routed', 512)])
+def test_expert_product_reads_the_stacked_weights_where_they_lie(
+        one_chip, form, rows):
+    """A decode step's 32 rows and a chunk of 512 in a scan over 2 stacked
+    layers at the published widths: the 4 shared experts through the
+    dense gate-masked product on the layer's slice, the 16 routed ones
+    through the product grouped by expert, which slices (layer, expert)
+    out of the stacked weights in every row tile. No instruction writes
+    anything of one expert matrix's size (32 MB; a layer's are 0.5 GB),
+    so each is read once, inside the product. The one thing that large
+    is the chunk's own float32 results, one row an assignment it can
+    make (8 x 512 + a tile: the dense form's were 16 x 512)."""
+    from paddle_tpu.ops import moe_held_ops as moe
+    L, D, F, K = 2, 4096, 4096, 8
+    E = 4 if form == 'shared' else 16
 
-    def step(x, gate, wg, wu, wd):
+    def shared(x, chosen, weight, valid, wg, wu, wd):
         def body(h, w):
-            return h + gated_experts(h, gate, *w), None
+            return h + moe.gated_experts(h, weight[:, :E], *w), None
         return jax.lax.scan(body, x, (wg, wu, wd))[0]
 
-    hlo = jax.jit(step).lower(
-        _shaped(one_chip, (N, D), jnp.float32),
-        _shaped(one_chip, (N, E), jnp.float32),
+    def routed(x, chosen, weight, valid, wg, wu, wd):
+        def body(h, layer):
+            gate, hit = moe.held_gates(chosen, weight, 0, E)
+            return h + moe.routed_experts(h, gate, hit, valid, K, wg, wu,
+                                          wd, layer), None
+        return jax.lax.scan(body, x, jnp.arange(L, dtype=jnp.int32))[0]
+
+    hlo = jax.jit(shared if form == 'shared' else routed).lower(
+        _shaped(one_chip, (rows, D), jnp.float32),
+        _shaped(one_chip, (rows, K), jnp.int32),
+        _shaped(one_chip, (rows, K), jnp.float32),
+        _shaped(one_chip, (rows,), jnp.bool_),
         _shaped(one_chip, (L, E, D, F), jnp.bfloat16),
         _shaped(one_chip, (L, E, D, F), jnp.bfloat16),
         _shaped(one_chip, (L, E, F, D), jnp.bfloat16)).compile().as_text()
-    assert _materialized(hlo, E * D * F * 2 // 4) == []
+    results = 'f32[%d,%d]' % (rows * K + moe.TILE_ROWS, D)
+    assert [m for m in _materialized(hlo, D * F * 2)
+            if m[1] != results] == []
+    # and the three products take the stacked weights themselves as an
+    # operand: a device trace names an op by its line with the operands'
+    # types, and the benchmark's readers find the expert products by it
+    assert len(_fusions_reading(hlo, 'bf16[%d,%d,4096,4096]' % (L, E))) >= 3
 
 
 # name -> (LMSpec arguments, engine arguments): both serving
