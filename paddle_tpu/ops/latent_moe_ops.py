@@ -1,0 +1,365 @@
+"""The latent_moe block of the paged decode ops (LMSpec
+block='latent_moe': dots3_note): latent attention under a learned sparse
+selection or a window, by layer kind, over three kinds of cache.
+
+A layer is ``h = x + Attn_kind(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``.
+
+**Latent attention** (both kinds, each at its own sizes). A token's
+cache row is ``[c_kv ; k_rope]``: ``c_kv = s_kv RMSNorm(x W_kva[:, :r])``
+and ``k_rope`` the last ``d_rope`` columns of ``x W_kva`` rotated
+(interleaved pairs); one row a token a layer, which every head reads.
+Queries go through a rank-``q_rank`` bottleneck, ``c_q = s_q RMSNorm(x
+W_qa)``, ``[q_nope ; q_rope]_h = c_q W_qb``. Every program runs the
+*absorbed* form: the key up-projection is folded into the query,
+``q'_h = [q_nope_h W_UK,h ; RoPE(q_rope_h)]`` (as wide as the row), so a
+score is one product of ``q'_h`` with the cached row, the weighted sum
+is over the rows' first ``r`` columns, and the value up-projection is
+applied to that sum, ``out_h = (sum_s p c_kv(s)) W_UV,h``: K and V are
+never expanded, a decode step reads ``row width x itemsize`` bytes a
+position and nothing else of the cache
+(``ops/pallas/paged_attention.py``: the latent form of
+``_attend_blocks``). A sigmoid gate a head, from the layer's normed
+input, multiplies ``out_h`` before ``W_o``.
+
+**The selection** (full layers; the DeepSeek-V3.2 indexer). A token
+also caches an index key ``k^I = LayerNorm(x W^I_k)`` (its first
+``d_rope`` columns rotated, half-split pairs), written in place like a
+latent row. A query scores every cached position,
+``I(t, s) = sum_j w_j ReLU(q^I_j . k^I(s))`` over ``index_n_heads``
+heads (``q^I = c_q W^I_q``, ``w = x W^I_w / sqrt(heads x width)``), and
+sees the ``index_topk`` positions ``s <= t`` of largest ``I``: exactly
+those ``lax.top_k`` returns (ties to the lower position), found without
+a sort (``select_topk``: the k-th largest score by bisection over the
+scores' bit patterns, 32 counting passes). The choice reaches the
+attention as a mask over its column blocks: a position left out
+contributes exactly 0, and the blocks are still the pages the row
+holds, so the program reads every cached row of a full layer and
+multiplies it (what a gather of the chosen rows would save is PERF.md's
+to measure). Scores, their weights and the selection are float32; the
+products take bfloat16 operands where the weights are bfloat16.
+
+**The layer loop.** The kinds have different weight shapes, so each
+kind's weights are a stack of their own (serving/decode/model.py:
+``latent_param_shapes``), and so are the arenas: the full layers' latent
+rows and index keys, the sliding layers' latent rows, each ``[layers of
+the kind, NB, bs, width]`` under the one block table. ``segments`` gives
+``_extend_rows`` the published order: the leading dense layers one by
+one, then one ``lax.scan`` over the whole periods of layer kinds (a
+period's layers unrolled inside the body, each indexing its kind's
+stacks at ``layers before + period x layers a period + its place``),
+then the remainder. The arenas are the carry throughout, written in
+place.
+
+**FFN.** The leading ``dense_layers`` a gated SiLU FFN; the others
+sigmoid top-k routing with a selection-only bias over every published
+expert, the experts held here computed (ops/moe_held_ops.py) and the
+shared experts added at weight 1.
+"""
+
+import jax
+import jax.numpy as jnp
+
+from . import moe_held_ops as moe
+from .paged_decode_ops import (_attention_of, _mm, _rope_gptj,
+                               _write_in_place)
+
+FULL, SLIDING = 'full_attention', 'sliding_attention'
+_TAG = {FULL: 'Full', SLIDING: 'Swa'}
+# op input slots: the attention of a kind (prefixed Full / Swa), the
+# full layers' indexer, the two FFNs
+_ATTN = ('QA', 'QLn', 'QB', 'KvA', 'KvLn', 'KvBK', 'KvBV', 'O', 'Gate')
+_INDEX = ('IdxQ', 'IdxK', 'IdxKLnW', 'IdxKLnB', 'IdxW')
+_DENSE = ('DenseGate', 'DenseUp', 'DenseDown')
+_ROUTED = ('Router', 'RouterBias', 'ShrGate', 'ShrUp', 'ShrDown')
+_NEG = -jnp.inf
+
+
+def rms_norm(x, gain, eps):
+    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True)
+                             + eps) * gain.astype(jnp.float32)
+
+
+def rope_half(x, pos, theta):
+    """x [N, heads, D] float32 at positions ``pos`` [N]: half-split pairs
+    (i, i + D/2) turned by pos * theta^(-2i/D)."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = pos.astype(jnp.float32)[:, None] * inv[None, :]
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    a, b = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], -1)
+
+
+def select_topk(scores, k):
+    """``scores`` [N, C] float32 (-inf: not a candidate) -> bool [N, C]:
+    the ``k`` largest of each row, ties to the lower column, which is
+    the set ``lax.top_k(scores, k)`` indexes; every column where C <= k.
+    No sort: the k-th largest value is built bit by bit over an
+    order-preserving map of the floats onto uint32 (32 passes that
+    count), then the ties at it are taken from the left."""
+    n, c = scores.shape
+    if c <= k:
+        return jnp.ones((n, c), bool)
+    bits = jax.lax.bitcast_convert_type(scores, jnp.uint32)
+    key = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+    def grow(i, kth):
+        cand = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(key >= cand[:, None], axis=1) >= k
+        return jnp.where(enough, cand, kth)
+    kth = jax.lax.fori_loop(0, 32, grow, jnp.zeros((n,), jnp.uint32))
+    above = key > kth[:, None]
+    ties = key == kth[:, None]
+    room = k - jnp.sum(above, axis=1, dtype=jnp.int32)
+    return above | (ties & (jnp.cumsum(ties, axis=1, dtype=jnp.int32)
+                            <= room[:, None]))
+
+
+def index_scores(q, w, arena, layer, tables, lens, per):
+    """The indexer's scores of every cached position: ``q`` [N, Hi, Di]
+    and ``w`` [N, Hi] float32, the index keys' ``arena`` [layers, NB, bs,
+    Di], ``tables`` [N, P] (one query each) or [P] (N queries of one
+    sequence), ``lens`` [N] -> float32 [N, P * bs], -inf at and past a
+    row's length. Column blocks of ``per`` pages, from the first to the
+    one that holds the largest length; the rest stays -inf."""
+    nb, bs = arena.shape[1], arena.shape[2]
+    n = q.shape[0]
+    one_table = tables.ndim == 1
+    pages = tables.shape[-1]
+    bk = per * bs
+    tables = jnp.clip(tables, 0, nb - 1)
+    qi = q.astype(arena.dtype)
+    exact = jax.lax.Precision.HIGHEST if qi.dtype == jnp.float32 else None
+
+    def block(j, out):
+        at = jax.lax.dynamic_slice_in_dim(tables, j * per, per, -1)
+        keys = arena[layer, at]              # [(N,) per, bs, Di]
+        if one_table:
+            dots = jnp.einsum('nhd,kd->nhk', qi, keys.reshape(bk, -1),
+                              precision=exact,
+                              preferred_element_type=jnp.float32)
+        else:
+            dots = jnp.einsum('nhd,nkd->nhk', qi, keys.reshape(n, bk, -1),
+                              precision=exact,
+                              preferred_element_type=jnp.float32)
+        got = jnp.sum(jax.nn.relu(dots) * w[:, :, None], axis=1)
+        return jax.lax.dynamic_update_slice(out, got, (0, j * bk))
+    blocks = (jnp.max(lens) + bk - 1) // bk
+    out = jax.lax.fori_loop(0, blocks, block,
+                            jnp.full((n, pages * bs), _NEG, jnp.float32))
+    return jnp.where(jnp.arange(pages * bs)[None, :] < lens[:, None],
+                     out, _NEG)
+
+
+def _at(stack, i):
+    """Layer ``i`` (an int or a traced scalar) of a kind's stack, sliced
+    where it lies."""
+    return jax.lax.dynamic_index_in_dim(stack, i, axis=0, keepdims=False)
+
+
+class LatentMoEBlock(object):
+    """What ``_extend_rows`` asks of a block (embed, segments, logits)
+    for LMSpec block='latent_moe'; module docstring."""
+
+    all_arena_slots = ('LatentFull', 'IndexFull', 'LatentSliding')
+
+    def __init__(self, ctx):
+        self.emb = ctx.input('Emb')
+        self.head = ctx.input('Head')
+        self.final_ln = ctx.input('FinalLN')
+        self.ln1, self.ln2 = ctx.input('Ln1W'), ctx.input('Ln2W')
+        self.eps = float(ctx.attr('norm_eps', 1e-5))
+        self.top_k = int(ctx.attr('top_k', 1))
+        self.first = int(ctx.attr('first_expert', 0))
+        self.window = int(ctx.attr('window', 0))
+        self.index_heads = int(ctx.attr('index_n_heads', 0))
+        self.index_topk = int(ctx.attr('index_topk', 0))
+        self.rescale = bool(ctx.attr('lora_rescale', 1))
+        self.plan = (tuple(ctx.attr('lead')), tuple(ctx.attr('period')),
+                     int(ctx.attr('n_periods')), tuple(ctx.attr('tail')))
+        kinds = set(self.plan[0] + self.plan[1] + self.plan[3])
+        self.arena_slots = tuple(
+            s for s in self.all_arena_slots
+            if (FULL if 'Full' in s else SLIDING) in kinds)
+        self.shape, self.theta, self.w = {}, {}, {}
+        for kind in kinds:
+            tag = _TAG[kind].lower()
+            self.shape[kind] = tuple(ctx.attr(tag + '_shape'))
+            self.theta[kind] = float(ctx.attr(tag + '_theta'))
+            for slot in _ATTN:
+                self.w[_TAG[kind] + slot] = ctx.input(_TAG[kind] + slot)
+        lead, period, n_periods, tail = self.plan
+        slots = (_INDEX if FULL in kinds else ()) + \
+            (_DENSE if lead else ()) + \
+            (_ROUTED if period or tail else ())
+        for slot in slots:
+            self.w[slot] = ctx.input(slot)
+        # the routed experts stay stacked: each row tile of their product
+        # slices its (layer, expert) out where it lies (moe_held_ops)
+        self.routed = tuple(ctx.input(s) for s in
+                            ('ExpGate', 'ExpUp', 'ExpDown')) \
+            if period or tail else None
+
+    # ------------------------------------------------------ the two ends
+    def embed(self, tokens, pos):
+        return jnp.take(self.emb, tokens, axis=0).astype(jnp.float32)
+
+    def logits(self, h):
+        y = rms_norm(h, self.final_ln, self.eps).astype(self.head.dtype)
+        return jax.lax.dot_general(
+            y, self.head, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    # ---------------------------------------------------- the layer loop
+    def segments(self, step):
+        lead, period, n_periods, tail = self.plan
+
+        def run(kinds, first_layer, before):
+            """The layers of ``kinds`` in order from ``first_layer``,
+            ``before[kind]`` layers of a kind ahead of them; as a scan's
+            body, ``j`` whole runs of ``kinds`` further on."""
+            def fn(carry, j):
+                h, arenas = carry
+                seen, stats = dict(before), []
+                runs = 0 if j is None else j
+                for m, kind in enumerate(kinds):
+                    layer = first_layer + runs * len(kinds) + m
+                    of_kind = seen[kind] + runs * kinds.count(kind)
+                    h, arenas, got = self._layer(
+                        h, arenas, step, kind, layer, of_kind)
+                    seen[kind] += 1
+                    if got is not None:
+                        stats.append(got)
+                return (h, arenas), jnp.stack(stats) if stats else None
+            return fn
+
+        before = {FULL: 0, SLIDING: 0}
+        out = []
+        if lead:
+            out.append((run(lead, 0, before), None))
+            before = {k: v + lead.count(k) for k, v in before.items()}
+        if n_periods:
+            out.append((run(period, len(lead), before),
+                        jnp.arange(n_periods, dtype=jnp.int32)))
+        if tail:
+            # the remainder sits where period ``n_periods`` would
+            start = len(lead) + n_periods * len(period)
+            ahead = {k: v + n_periods * period.count(k)
+                     for k, v in before.items()}
+            out.append((run(tail, start, ahead), None))
+        return out
+
+    def _layer(self, h, arenas, step, kind, layer, of_kind):
+        """Layer ``layer`` (of all; ``of_kind`` among its kind's), with
+        static or traced indices: (h, arenas, router statistics or
+        None)."""
+        n1 = rms_norm(h, _at(self.ln1, layer), self.eps)
+        attn, arenas = self._attention(n1, arenas, step, kind, of_kind)
+        h = h + attn
+        n2 = rms_norm(h, _at(self.ln2, layer), self.eps)
+        n_lead = len(self.plan[0])
+        if isinstance(layer, int) and layer < n_lead:
+            return h + self._dense(n2, layer), arenas, None
+        m, stats = self._routed(n2, layer - n_lead, step.valid)
+        return h + m, arenas, stats
+
+    # --------------------------------------------------------- attention
+    def _attention(self, n, arenas, step, kind, i):
+        heads, d_nope, d_rope = self.shape[kind]
+        theta, pos = self.theta[kind], step.pos
+        w = {slot: _at(self.w[_TAG[kind] + slot], i) for slot in _ATTN}
+        rows = n.shape[0]
+        d_model, q_rank = w['QA'].shape
+        rank = w['KvLn'].shape[0]
+        s_q = (d_model / q_rank) ** 0.5 if self.rescale else 1.0
+        s_kv = (d_model / rank) ** 0.5 if self.rescale else 1.0
+
+        c_q = rms_norm(_mm(n, w['QA']), w['QLn'], self.eps) * s_q
+        q = _mm(c_q, w['QB']).reshape(rows, heads, d_nope + d_rope)
+        down = _mm(n, w['KvA'])
+        c_kv = rms_norm(down[:, :rank], w['KvLn'], self.eps) * s_kv
+        k_rope = _rope_gptj(down[:, None, rank:], pos, theta)[:, 0]
+        mine = [self.arena_slots.index(
+            'LatentFull' if kind == FULL else 'LatentSliding')]
+        # a row is stored in whole lane tiles (CacheKind.stored): the
+        # columns past [c_kv ; k_rope] are written as zeros and the
+        # query carries zeros there
+        spare = arenas[mine[0]].shape[-1] - rank - d_rope
+        # the absorbed query: as wide as the cached row
+        q_abs = jnp.einsum('nhd,hdr->nhr',
+                           q[..., :d_nope].astype(w['KvBK'].dtype),
+                           w['KvBK'], preferred_element_type=jnp.float32)
+        q_row = jnp.concatenate(
+            [q_abs, _rope_gptj(q[..., d_nope:], pos, theta),
+             jnp.zeros((rows, heads, spare), jnp.float32)], -1)
+        new = [jnp.concatenate(
+            [c_kv, k_rope, jnp.zeros((rows, spare), jnp.float32)], -1)]
+        chosen, lo = None, None
+        if kind == FULL:
+            mine.append(self.arena_slots.index('IndexFull'))
+            q_i, w_i, k_i = self._index_rows(n, c_q, i, pos, theta, d_rope)
+            new.append(k_i)
+        else:
+            # a query at position pos sees keys pos - window < j <= pos
+            lo = jnp.maximum(pos + 1 - self.window, 0)
+        held = tuple(arenas[a] for a in mine)
+        held = _write_in_place(
+            held, [r.astype(a.dtype) for r, a in zip(new, held)], i,
+            step.place)
+        arenas = list(arenas)
+        for a, arena in zip(mine, held):
+            arenas[a] = arena
+        if kind == FULL:
+            from .pallas.paged_attention import pages_per_block
+            per = pages_per_block(step.tables.shape[-1], held[1].shape[2])
+            chosen = select_topk(
+                index_scores(q_i, w_i, held[1], i, step.tables, step.lens,
+                             per), self.index_topk)
+        mixed = _attention_of(step.tables)(
+            q_row, held[0], None, step.tables, step.lens,
+            sm_scale=(d_nope + d_rope) ** -0.5, layer=i, lo=lo,
+            latent=rank, chosen=chosen)                     # [N, H, r]
+        out = jnp.einsum('nhr,hrv->nhv', mixed.astype(w['KvBV'].dtype),
+                         w['KvBV'], preferred_element_type=jnp.float32)
+        out = out * jax.nn.sigmoid(_mm(n, w['Gate']))[:, :, None]
+        return _mm(out.reshape(rows, -1), w['O']), tuple(arenas)
+
+    def _index_rows(self, n, c_q, i, pos, theta, d_rope):
+        """(index queries [N, Hi, Di], their heads' weights [N, Hi], the
+        rows' own index keys [N, Di]), float32."""
+        w = {slot: _at(self.w[slot], i) for slot in _INDEX}
+        rows, heads = n.shape[0], self.index_heads
+        q = _mm(c_q, w['IdxQ']).reshape(rows, heads, -1)
+        q = jnp.concatenate([rope_half(q[..., :d_rope], pos, theta),
+                             q[..., d_rope:]], -1)
+        k = _mm(n, w['IdxK'])
+        mean = jnp.mean(k, -1, keepdims=True)
+        k = (k - mean) * jax.lax.rsqrt(
+            jnp.mean(jnp.square(k - mean), -1, keepdims=True) + self.eps) \
+            * w['IdxKLnW'].astype(jnp.float32) + \
+            w['IdxKLnB'].astype(jnp.float32)
+        k = jnp.concatenate(
+            [rope_half(k[:, None, :d_rope], pos, theta)[:, 0],
+             k[:, d_rope:]], -1)
+        weight = _mm(n, w['IdxW']) * (heads * q.shape[-1]) ** -0.5
+        return q, weight, k
+
+    # --------------------------------------------------------------- FFN
+    def _dense(self, n, i):
+        gate, up, down = (_at(self.w[s], i) for s in _DENSE)
+        return _mm(jax.nn.silu(_mm(n, gate)) * _mm(n, up), down)
+
+    def _routed(self, n, i, valid):
+        w = {slot: _at(self.w[slot], i) for slot in _ROUTED}
+        if valid is None:
+            valid = jnp.ones((n.shape[0],), bool)
+        chosen, weight = moe.route_sigmoid_topk(
+            n, w['Router'], self.top_k, bias=w['RouterBias'])
+        held = self.routed[0].shape[1]
+        gate, hit = moe.held_gates(chosen, weight, self.first, held)
+        m = moe.routed_experts(n, gate, hit, valid, min(self.top_k, held),
+                               *self.routed, layer=i)
+        n_shared = w['ShrGate'].shape[0]
+        m += moe.gated_experts(n, jnp.ones((n.shape[0], n_shared)),
+                               w['ShrGate'], w['ShrUp'], w['ShrDown'])
+        return m, moe.load_stats(hit, valid)

@@ -58,15 +58,22 @@ __all__ = ['route_sigmoid_topk', 'held_gates', 'gated_experts',
 TILE_ROWS = 128
 
 
-def route_sigmoid_topk(x, router, top_k):
+def route_sigmoid_topk(x, router, top_k, bias=None):
     """``x`` [N, D] float32, ``router`` [D, n_experts] -> (chosen
     [N, k] int32, weights [N, k] float32). Scores and weights in
     float32 at the highest matmul precision whatever the weights'
-    dtype: a choice that flips moves a row's whole expert sum."""
+    dtype: a choice that flips moves a row's whole expert sum.
+    ``bias`` [n_experts] (``noaux_tc``) is added to the scores for the
+    choosing only: the weights are the chosen experts' own scores,
+    normalised."""
     scores = jax.nn.sigmoid(jnp.matmul(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    top, chosen = jax.lax.top_k(scores, top_k)
+    if bias is None:
+        top, chosen = jax.lax.top_k(scores, top_k)
+    else:
+        _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        top = jnp.take_along_axis(scores, chosen, axis=1)
     return chosen, top / jnp.sum(top, axis=-1, keepdims=True)
 
 

@@ -36,7 +36,10 @@ cache lives in a paged pool (ops/pallas/paged_attention.py layouts):
 What differs between model families is a *block* object
 (``_PostLNBlock``: the 2017 decoder block, per-row math as in
 transformer_ops.py's ``_incremental_layer_scan``; ``_ParallelMoEBlock``:
-cohere2_moe, LMSpec block='parallel_moe'): embedding, the q/k/v
+cohere2_moe, LMSpec block='parallel_moe'; ``LatentMoEBlock`` in
+ops/latent_moe_ops.py: dots3_note, block='latent_moe', whose layer
+kinds have unlike shapes and three arenas, so it brings its own layer
+functions and order, ``segments``): embedding, the q/k/v
 projections, what follows attention, the per-layer lower bound on the
 columns a row sees, and the logits. Everything else — placement, the
 in-place arena writes, the one ``lax.scan`` over [L, ...]-stacked
@@ -120,13 +123,70 @@ def _stacked_weights(ctx, slots):
     return {s: ctx.env[ctx.op.input(_slot_to_input(s))] for s in slots}
 
 
-class _PostLNBlock(object):
+# What one op's rows are, for a layer: their positions, the block
+# table(s), where their cache rows land, the length each attends at (0:
+# not live) and which rows count.
+_Step = collections.namedtuple(
+    '_Step', ['pos', 'tables', 'place', 'lens', 'valid'])
+
+
+def _attention_of(tables):
+    from .pallas.paged_attention import (paged_attention_blocked,
+                                         paged_attention_one_table)
+    return paged_attention_one_table if tables.ndim == 1 \
+        else paged_attention_blocked
+
+
+class _UniformBlock(object):
+    """A block whose layers are all of one shape, with K and V arenas of
+    ``[L, ...]``: one ``lax.scan`` over the [L, ...]-stacked weights,
+    the layer's kind (window, rotary) scanned data beside them. A
+    subclass gives ``pre``, ``kv``, ``q``, ``lower_bound``, ``finish``."""
+
+    def segments(self, step):
+        n_layer = next(iter(self.params.values())).shape[0]
+        return [(self._layer(step),
+                 (self.params, jnp.arange(n_layer, dtype=jnp.int32)))]
+
+    def _layer(self, step):
+        from ..quant.core import quantize_rows
+        attend = _attention_of(step.tables)
+        n = step.pos.shape[0]
+
+        def body(carry, sl):
+            h, arenas = carry
+            p, layer = sl
+            kv_q = _arena_kv_dtype(arenas[0])
+            nrm = self.pre(h, p)
+            k_new, v_new = self.kv(nrm, p, step.pos)
+            if kv_q is not None:
+                kq, ks_row = quantize_rows(
+                    _split_heads(k_new, self.n_head), kv_q)
+                vq, vs_row = quantize_rows(
+                    _split_heads(v_new, self.n_head), kv_q)
+                rows = (kq.reshape(n, -1), vq.reshape(n, -1), ks_row, vs_row)
+            else:
+                rows = (k_new.astype(arenas[0].dtype),
+                        v_new.astype(arenas[1].dtype))
+            arenas = _write_in_place(arenas, rows, layer, step.place)
+            attn = attend(
+                self.q(nrm, p, step.pos), arenas[0], arenas[1], step.tables,
+                step.lens,
+                k_scales=arenas[2] if kv_q is not None else None,
+                v_scales=arenas[3] if kv_q is not None else None,
+                layer=layer, lo=self.lower_bound(p, step.pos))
+            h, stats = self.finish(h, nrm, attn, p, step.valid)
+            return (h, arenas), None if stats is None else stats[None]
+        return body
+
+
+class _PostLNBlock(_UniformBlock):
     """The 2017 decoder block: embedding scaled by sqrt(d_model) plus a
     position table, serial residual with LayerNorm after each sublayer,
     ReLU FFN, an output table of its own. One KV head per query head."""
 
     slots = LM_SLOTS
-    stats = False
+    arena_slots = ('KCache', 'VCache', 'KScale', 'VScale')
 
     def __init__(self, ctx):
         self.emb = ctx.input('Emb')
@@ -187,7 +247,7 @@ def _rope_gptj(x, pos, theta):
                      axis=-1).reshape(x.shape)
 
 
-class _ParallelMoEBlock(object):
+class _ParallelMoEBlock(_UniformBlock):
     """The cohere2_moe block (LMSpec block='parallel_moe'): one
     bias-free LayerNorm feeding attention and the expert FFN side by
     side, y = x + attn + experts; grouped KV heads; per layer a window
@@ -202,7 +262,7 @@ class _ParallelMoEBlock(object):
     weights' dtype."""
 
     slots = MOE_SLOTS
-    stats = True
+    arena_slots = ('KCache', 'VCache')
 
     def __init__(self, ctx):
         self.emb = ctx.input('Emb')
@@ -278,8 +338,12 @@ class _ParallelMoEBlock(object):
 
 
 def _block_of(ctx):
-    if ctx.attr('block', 'post_ln') == 'parallel_moe':
+    kind = ctx.attr('block', 'post_ln')
+    if kind == 'parallel_moe':
         return _ParallelMoEBlock(ctx)
+    if kind == 'latent_moe':
+        from .latent_moe_ops import LatentMoEBlock
+        return LatentMoEBlock(ctx)
     return _PostLNBlock(ctx)
 
 
@@ -379,25 +443,20 @@ def _sample_token(logits, seed, pos, temp):
 
 
 def _lm_inputs(ctx):
-    """(the op's block, K arena, V arena, K scales, V scales)."""
-    kc = ctx.input('KCache')            # [L, NB, bs, Hkv*dk]
-    vc = ctx.input('VCache')
-    ks = ctx.input('KScale') if ctx.has_input('KScale') else None
-    vs = ctx.input('VScale') if ctx.has_input('VScale') else None
-    return _block_of(ctx), kc, vc, ks, vs
+    """(the op's block, its page arenas in the block's order)."""
+    block = _block_of(ctx)
+    return block, tuple(ctx.input(slot) for slot in block.arena_slots
+                        if ctx.has_input(slot))
 
 
-def _set_arena_outputs(ctx, kcs, vcs, kss, vss):
-    ctx.set_output('KCacheOut', kcs)
-    ctx.set_output('VCacheOut', vcs)
-    if kss is not None:
-        ctx.set_output('KScaleOut', kss)
-        ctx.set_output('VScaleOut', vss)
+def _set_arena_outputs(ctx, block, arenas):
+    for slot, arena in zip(block.arena_slots, arenas):
+        ctx.set_output(slot + 'Out', arena)
 
 
 @register('paged_decode_step')
 def _paged_decode_step(ctx):
-    block, kcs, vcs, kss, vss = _lm_inputs(ctx)
+    block, arenas = _lm_inputs(ctx)
 
     tokens = ctx.input('Tokens').reshape(-1).astype(jnp.int32)     # [B]
     lens = ctx.input('SeqLens').reshape(-1).astype(jnp.int32)      # [B]
@@ -407,35 +466,39 @@ def _paged_decode_step(ctx):
 
     # one new token per row at position lens (empty slots feed all->NB
     # tables, so phys lands out of bounds and every write drops)
-    place = _single_rows(tables, lens, kcs.shape[1], kcs.shape[2])
-    h, kcs, vcs, kss, vss, stats = _extend_rows(
-        block, kcs, vcs, tokens, lens, tables, place, kss, vss,
-        valid=place.ok[:, 0])
+    place = _single_rows(tables, lens, *arenas[0].shape[1:3])
+    h, arenas, stats = _extend_rows(block, arenas, tokens, lens, tables,
+                                    place, valid=place.ok[:, 0])
     nxt = jax.vmap(_sample_token)(block.logits(h), seeds, lens + 1, temps)
     ctx.set_output('NextTokens',
                    nxt.astype(ctx.out_dtype('NextTokens', 'int64')))
     if stats is not None:
-        ctx.set_output('MoeStats', stats)               # [L, 4] int32
-    _set_arena_outputs(ctx, kcs, vcs, kss, vss)
+        ctx.set_output('MoeStats', stats)        # [routed layers, 4] int32
+    _set_arena_outputs(ctx, block, arenas)
 
 
-def _extend_rows(block, kcs, vcs, tokens, pos, tables, place,
-                 kscales=None, vscales=None, valid=None):
-    """Shared core of all three ops: write N new tokens' K/V at
+def _extend_rows(block, arenas, tokens, pos, tables, place, valid=None):
+    """Shared core of all three ops: write N new tokens' cache rows at
     absolute positions ``pos`` where ``place`` (a _Placement over the
     same rows) says, attend each row at its own ragged length
     (``pos + 1``; 0 where ``valid`` says a row is not live, so that it
     costs no block) through per-row block ``tables`` [N, P] (or, for
     consecutive rows of one sequence, its one table [P]), and return
-    the last hidden rows [N, D] plus the updated
-    arenas and the block's per-layer statistics (None where it keeps
-    none; ``valid`` [N] says which rows count). The arenas are carried
-    through the layer loop and written in place (module docstring);
-    ``block`` is what differs between model families: embedding, the
-    projections, what follows attention, and the per-layer bound on the
-    columns a row sees.
+    the last hidden rows [N, D], the updated ``arenas`` (a tuple in the
+    block's order) and the block's per-layer statistics (None where it
+    keeps none; ``valid`` [N] says which rows count). The arenas are
+    carried through the layer loop and written in place (module
+    docstring); ``block`` is what differs between model families:
+    embedding, the projections, what follows attention, the per-layer
+    bound on the columns a row sees, and the order its layers run in:
+    ``block.segments(step)`` is a list of ``(layer function, xs)``, run
+    one after another with ``(h, arenas)`` as carry, a segment with
+    ``xs`` as one ``lax.scan`` over it (a block of one layer shape: the
+    whole stack; one of several: its whole periods of layer kinds) and
+    one without called once (a leading layer, a remainder). One
+    compiled program per signature whatever the list.
 
-    Quantized arenas (``kscales``/``vscales`` [L, NB, bs, H] given):
+    Quantized arenas (scale arenas [L, NB, bs, H] behind K and V):
     each new K/V row is quantized independently (one fp32 scale per
     (token, head) row, deterministic rounding — quant.core
     quantize_rows) before the write, and the attention gather
@@ -443,55 +506,26 @@ def _extend_rows(block, kcs, vcs, tokens, pos, tables, place,
     independently, every path (prefill, decode, spec-verify, cache
     hits) stores identical bits for identical tokens — the
     concurrent == sequential invariant survives at int8/fp8."""
-    from ..quant.core import quantize_rows
-    from .pallas.paged_attention import (paged_attention_blocked,
-                                         paged_attention_one_table)
-    n = tokens.shape[0]
-    kv_q = _arena_kv_dtype(kcs)
-    quantized = kv_q is not None
-
     x = block.embed(tokens, pos)
     # a row that is not live attends at length 0: it costs no block
-    att_lens = pos + 1 if valid is None else jnp.where(valid, pos + 1, 0)
-    attend = paged_attention_one_table if tables.ndim == 1 \
-        else paged_attention_blocked
-
-    def body(carry, sl):
-        h, arenas = carry
-        p, layer = sl
-        nrm = block.pre(h, p)
-        k_new, v_new = block.kv(nrm, p, pos)
-        if quantized:
-            n_head = block.n_head
-            kq, ks_row = quantize_rows(_split_heads(k_new, n_head), kv_q)
-            vq, vs_row = quantize_rows(_split_heads(v_new, n_head), kv_q)
-            rows = (kq.reshape(n, -1), vq.reshape(n, -1), ks_row, vs_row)
+    lens = pos + 1 if valid is None else jnp.where(valid, pos + 1, 0)
+    carry, stats = (x, tuple(arenas)), []
+    for layer, xs in block.segments(_Step(pos, tables, place, lens, valid)):
+        if xs is None:
+            carry, got = layer(carry, None)
         else:
-            rows = (k_new.astype(kcs.dtype), v_new.astype(vcs.dtype))
-        arenas = _write_in_place(arenas, rows, layer, place)
-        q = block.q(nrm, p, pos)
-        attn = attend(
-            q, arenas[0], arenas[1], tables, att_lens,
-            k_scales=arenas[2] if quantized else None,
-            v_scales=arenas[3] if quantized else None, layer=layer,
-            lo=block.lower_bound(p, pos))
-        h, stats = block.finish(h, nrm, attn, p, valid)
-        return (h, arenas), stats
-
-    arenas = (kcs, vcs, kscales, vscales) if quantized else (kcs, vcs)
-    layers = jnp.arange(kcs.shape[0], dtype=jnp.int32)
-    (h, arenas), stats = jax.lax.scan(body, (x, arenas),
-                                      (block.params, layers))
-    if quantized:
-        kcs, vcs, kscales, vscales = arenas
-    else:
-        kcs, vcs = arenas
-    return h, kcs, vcs, kscales, vscales, stats
+            carry, got = jax.lax.scan(layer, carry, xs)
+            if got is not None:             # [n, layers a call, 4]
+                got = got.reshape((-1,) + got.shape[2:])
+        if got is not None:
+            stats.append(got)
+    h, arenas = carry
+    return h, arenas, jnp.concatenate(stats) if stats else None
 
 
 @register('paged_prefill')
 def _paged_prefill(ctx):
-    block, kcs, vcs, kss, vss = _lm_inputs(ctx)
+    block, arenas = _lm_inputs(ctx)
 
     ids = ctx.input('Ids').reshape(-1).astype(jnp.int32)   # [S] (padded)
     length = ctx.input('Len').reshape(()).astype(jnp.int32)
@@ -505,15 +539,13 @@ def _paged_prefill(ctx):
     # query attends to everything at or below it — the cached pages
     # plus this step's own earlier writes — through the table gather
     pos = cached + jnp.arange(s, dtype=jnp.int32)
-    place = _page_runs(table, cached, length, s, kcs.shape[1],
-                       kcs.shape[2])
+    place = _page_runs(table, cached, length, s, *arenas[0].shape[1:3])
     last = jnp.maximum(length - 1, 0)
     # the sequence's pages gathered block by block for the whole chunk
     # (rows past ``length`` see nothing), and the one row that is
     # sampled projected onto the vocabulary
-    h, kcs, vcs, kss, vss, stats = _extend_rows(
-        block, kcs, vcs, ids, pos, table, place, kss, vss,
-        valid=jnp.arange(s) < length)
+    h, arenas, stats = _extend_rows(block, arenas, ids, pos, table, place,
+                                    valid=jnp.arange(s) < length)
     logits_last = block.logits(jax.lax.dynamic_slice_in_dim(
         h, last, 1))[0]                                         # [V]
     nxt = _sample_token(logits_last, seed, cached + length, temp)
@@ -521,13 +553,13 @@ def _paged_prefill(ctx):
                    nxt.reshape(1).astype(ctx.out_dtype('NextToken',
                                                        'int64')))
     if stats is not None:
-        ctx.set_output('MoeStats', stats)               # [L, 4] int32
-    _set_arena_outputs(ctx, kcs, vcs, kss, vss)
+        ctx.set_output('MoeStats', stats)        # [routed layers, 4] int32
+    _set_arena_outputs(ctx, block, arenas)
 
 
 @register('paged_spec_verify')
 def _paged_spec_verify(ctx):
-    block, kcs, vcs, kss, vss = _lm_inputs(ctx)
+    block, arenas = _lm_inputs(ctx)
 
     tokens = ctx.input('Tokens').astype(jnp.int32)         # [B, K1]
     lens = ctx.input('SeqLens').reshape(-1).astype(jnp.int32)   # [B]
@@ -544,10 +576,9 @@ def _paged_spec_verify(ctx):
     j = jnp.arange(k1, dtype=jnp.int32)
     pos = (lens[:, None] + j[None, :]).reshape(-1)         # [B*K1]
     tables_rep = jnp.repeat(tables, k1, axis=0)            # [B*K1, P]
-    place = _single_rows(tables_rep, pos, kcs.shape[1], kcs.shape[2])
-    h, kcs, vcs, kss, vss, _ = _extend_rows(
-        block, kcs, vcs, tokens.reshape(-1), pos, tables_rep, place,
-        kss, vss, valid=place.ok[:, 0])
+    place = _single_rows(tables_rep, pos, *arenas[0].shape[1:3])
+    h, arenas, _ = _extend_rows(block, arenas, tokens.reshape(-1), pos,
+                                tables_rep, place, valid=place.ok[:, 0])
 
     nxt = jax.vmap(_sample_token)(
         block.logits(h), jnp.repeat(seeds, k1), pos + 1,
@@ -555,4 +586,4 @@ def _paged_spec_verify(ctx):
     ctx.set_output('NextTokens',
                    nxt.reshape(b, k1).astype(
                        ctx.out_dtype('NextTokens', 'int64')))
-    _set_arena_outputs(ctx, kcs, vcs, kss, vss)
+    _set_arena_outputs(ctx, block, arenas)

@@ -37,6 +37,11 @@ mixed lengths, head layouts and arena dtypes is asserted in
 tests/test_paged_attention_blocked.py, tests/test_decode_serving.py and
 tests/test_pallas_kernels.py.
 
+The latent form (``latent=r``; ops/latent_moe_ops.py): one arena whose
+row ``[c_kv ; k_rope]`` every head reads, keys the whole row and values
+its first ``r`` columns, and an optional per-row choice of columns
+(``chosen``) within the bounds, through the same blocks.
+
 Layouts:
     q            [B, H, D]      one query token per sequence
     k/v_pages    [L, NB, bs, H*D]  the pooled page arena, all layers:
@@ -114,7 +119,8 @@ def pages_covered(lo, hi, n_pages, bs, xp=jnp):
     return (last - first + 1).sum() * BLOCK_ROWS * per
 
 
-def _attend_blocks(q, arenas, layer, tables, lo, hi, first, last, per):
+def _attend_blocks(q, arenas, layer, tables, lo, hi, first, last, per,
+                   latent=None, chosen=None):
     """The one inner form: R tables with S queries each
     (decode: R = BLOCK_ROWS, S = 1; a prefill chunk: R = 1, S = bucket).
     q [R, S, H, D] (scaled), ``arenas`` (K, V[, K scales, V scales]),
@@ -127,11 +133,23 @@ def _attend_blocks(q, arenas, layer, tables, lo, hi, first, last, per):
     multiple of its width, so a row's result depends on its own
     columns only. Operands of the two products at the arena's dtype
     (float32 once dequantized), scores, normaliser and accumulator
-    float32. Returns [R, S, H, D] float32; rows that saw nothing 0."""
-    k_pages, v_pages = arenas[0], arenas[1]
+    float32. Returns [R, S, H, D] float32; rows that saw nothing 0.
+
+    The latent form (``latent`` = r, ``arenas`` one arena of rows
+    ``[c_kv ; k_rope]``): every head reads the one row, the keys are the
+    whole row (D is its width) and the values its first r columns, so a
+    page is gathered once and nothing is expanded; returns
+    [R, S, H, r]. ``chosen`` (a function of a block's first column ->
+    bool [R, S, bk], or None) narrows what a row sees within
+    [lo, hi) to a subset of its own choosing (a learned selection): a
+    column it leaves out contributes exactly 0, as one outside the
+    bounds does."""
+    k_pages = arenas[0]
+    v_pages = k_pages if latent else arenas[1]
     r, s, h, d = q.shape
     bs = k_pages.shape[2]
     n_kv = k_pages.shape[-1] // d
+    d_v = latent or d
     bk = per * bs
     quantized = len(arenas) == 4
     group = h // n_kv
@@ -164,15 +182,19 @@ def _attend_blocks(q, arenas, layer, tables, lo, hi, first, last, per):
     def block(j, state):
         top, norm, acc = state
         at = jax.lax.dynamic_slice_in_dim(tables, j * per, per, 1)
-        kb, vb = heads(pages(k_pages, at), d), heads(pages(v_pages, at), d)
+        kb = heads(pages(k_pages, at), d)
+        vb = [x[..., :latent] for x in kb] if latent \
+            else heads(pages(v_pages, at), d)
         if quantized:
             kb = [x.astype(jnp.float32) * sc for x, sc in
                   zip(kb, heads(pages(arenas[2], at), 1))]
             vb = [x.astype(jnp.float32) * sc for x, sc in
                   zip(vb, heads(pages(arenas[3], at), 1))]
         col = j * bk + jnp.arange(bk)
-        seen = ((col >= lo[..., None]) &
-                (col < hi[..., None]))[:, None, None]      # [R, 1, 1, S, bk]
+        seen = (col >= lo[..., None]) & (col < hi[..., None])
+        if chosen is not None:
+            seen &= chosen(j * bk)
+        seen = seen[:, None, None]                         # [R, 1, 1, S, bk]
         each = qg.shape[1] // len(kb)
         scores = jnp.concatenate([
             jnp.einsum('rngsd,rknd->rngsk',
@@ -195,10 +217,10 @@ def _attend_blocks(q, arenas, layer, tables, lo, hi, first, last, per):
     shape = (r, n_kv, group, s)
     init = (jnp.full(shape, _NEG_INF, jnp.float32),
             jnp.zeros(shape, jnp.float32),
-            jnp.zeros(shape + (d,), jnp.float32))
+            jnp.zeros(shape + (d_v,), jnp.float32))
     _, norm, acc = jax.lax.fori_loop(first, last + 1, block, init)
     out = acc / jnp.where(norm == 0.0, 1.0, norm)[..., None]
-    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(r, s, h, d)
+    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(r, s, h, d_v)
 
 
 def _seen_from_to(lo, seq_lens):
@@ -209,14 +231,15 @@ def _seen_from_to(lo, seq_lens):
 
 
 def _arenas(k_pages, v_pages, k_scales, v_scales):
-    given = (k_pages, v_pages) if k_scales is None else \
-        (k_pages, v_pages, k_scales, v_scales)
+    given = (k_pages,) if v_pages is None else (k_pages, v_pages) \
+        if k_scales is None else (k_pages, v_pages, k_scales, v_scales)
     return tuple(jnp.asarray(a) for a in given)
 
 
 def paged_attention_blocked(q, k_pages, v_pages, block_tables, seq_lens,
                             sm_scale=None, k_scales=None, v_scales=None,
-                            layer=0, lo=None, block_cols=BLOCK_COLS):
+                            layer=0, lo=None, block_cols=BLOCK_COLS,
+                            latent=None, chosen=None):
     """Many tables, one query each: q [B, H, D], ``block_tables`` [B, P]
     (entries >= NB mean "no page" and are never read), ``seq_lens`` [B].
     Nothing of the extent [B, P] is gathered: the rows are ordered by
@@ -241,7 +264,9 @@ def paged_attention_blocked(q, k_pages, v_pages, block_tables, seq_lens,
     has (row width = Hkv * D), query head h reads KV head
     h // (H / Hkv). ``lo`` [B] int32 is a lower bound on the columns a
     row sees (a sliding window); None sees every column below
-    ``seq_lens``."""
+    ``seq_lens``. The latent form (``v_pages`` None, ``latent`` the
+    values' width; ``_attend_blocks``) returns [B, H, latent];
+    ``chosen`` bool [B, P * bs] narrows each row's columns further."""
     nb, bs = k_pages.shape[1], k_pages.shape[2]
     b, p = block_tables.shape
     h, d = q.shape[1], q.shape[2]
@@ -258,26 +283,37 @@ def paged_attention_blocked(q, k_pages, v_pages, block_tables, seq_lens,
     q_s, lo_s, hi_s = ordered(q * scale), ordered(lo), ordered(hi)
     tables = ordered(jnp.clip(block_tables.astype(jnp.int32), 0, nb - 1))
     arenas = _arenas(k_pages, v_pages, k_scales, v_scales)
+    chosen_s = None if chosen is None else ordered(chosen)
 
     def rows(i, out):
         def cut(x):
             return jax.lax.dynamic_slice_in_dim(x, i * BLOCK_ROWS,
                                                 BLOCK_ROWS, 0)
+        mine = None if chosen is None else _columns_of(cut(chosen_s)[:, None],
+                                                       per * bs)
         got = _attend_blocks(cut(q_s)[:, None], arenas, layer, cut(tables),
                              cut(lo_s)[:, None], cut(hi_s)[:, None],
-                             first[i], last[i], per)
+                             first[i], last[i], per, latent, mine)
         return jax.lax.dynamic_update_slice_in_dim(
             out, got[:, 0], i * BLOCK_ROWS, 0)
 
     live_blocks = jnp.sum(last >= first)      # a prefix: rows are ordered
     out = jax.lax.fori_loop(
-        0, live_blocks, rows, jnp.zeros((b + short, h, d), jnp.float32))
+        0, live_blocks, rows,
+        jnp.zeros((b + short, h, latent or d), jnp.float32))
     return out[jnp.argsort(order)]
+
+
+def _columns_of(chosen, bk):
+    """``chosen`` bool [R, S, P * bs] as ``_attend_blocks`` asks for it:
+    the block of ``bk`` columns from a given first column."""
+    return lambda at: jax.lax.dynamic_slice_in_dim(chosen, at, bk, 2)
 
 
 def paged_attention_one_table(q, k_pages, v_pages, table, seq_lens,
                               sm_scale=None, k_scales=None, v_scales=None,
-                              layer=0, lo=None, block_cols=BLOCK_COLS):
+                              layer=0, lo=None, block_cols=BLOCK_COLS,
+                              latent=None, chosen=None):
     """One table, many queries: consecutive rows of ONE sequence (a
     prefill chunk) against that sequence's pages: q [S, H, D],
     ``table`` [P], row s sees columns lo[s] <= j < seq_lens[s]
@@ -289,8 +325,9 @@ def paged_attention_one_table(q, k_pages, v_pages, table, seq_lens,
     prompt multiplies one block and not the table's whole extent, a
     chunk deep in a sliding layer its window's blocks, and the scores
     alive at a time are [H, S, block]. Which blocks run depends on the
-    chunk's place in its own sequence only. Grouped heads and quantized
-    arenas as in ``paged_attention_blocked``."""
+    chunk's place in its own sequence only. Grouped heads, quantized
+    arenas, the latent form and ``chosen`` [S, P * bs] as in
+    ``paged_attention_blocked``."""
     nb, bs = k_pages.shape[1], k_pages.shape[2]
     s, h, d = q.shape
     scale = sm_scale if sm_scale is not None else d ** -0.5
@@ -300,7 +337,8 @@ def paged_attention_one_table(q, k_pages, v_pages, table, seq_lens,
     out = _attend_blocks(
         (q * scale)[None], _arenas(k_pages, v_pages, k_scales, v_scales),
         layer, jnp.clip(table.astype(jnp.int32), 0, nb - 1)[None],
-        lo[None], hi[None], first[0], last[0], per)
+        lo[None], hi[None], first[0], last[0], per, latent,
+        None if chosen is None else _columns_of(chosen[None], per * bs))
     return out[0]
 
 

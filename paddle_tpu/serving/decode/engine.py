@@ -36,13 +36,16 @@ workload — over a decoder-only LM with a paged KV cache:
   scores every proposal; longest-accepted-prefix acceptance emits
   up to k+1 tokens per step, bit-identical to plain decode.
 
-- **block families** (model.LMSpec ``block``): the 2017 post-LN block
-  and the parallel routed-expert block (grouped KV heads, sliding and
+- **block families** (model.LMSpec ``block``): the 2017 post-LN block,
+  the parallel routed-expert block (grouped KV heads, sliding and
   full layers in one cache, the experts held here of those the router
-  scores) run through this same engine; for the latter the prefix
-  cache, speculation and quantized arenas raise rather than run
-  untested. A prefix longer than the top prompt bucket is prefilled in
-  chunks of it (``prefill_chunk``).
+  scores) and the latent block (latent attention under a learned
+  sparse selection or a window by layer kind, three kinds of cache
+  under the one block table: ``LMSpec.cache_kinds``) run through this
+  same engine; for the latter two the prefix cache, speculation and
+  quantized arenas raise rather than run untested, and for the latent
+  block the page handoff too. A prefix longer than the top prompt
+  bucket is prefilled in chunks of it (``prefill_chunk``).
 
 Per-row device math is batch-composition-independent, so each
 request's token stream is bit-identical to running it alone —
@@ -66,7 +69,7 @@ from ...core.scope import Scope, scope_guard
 from ..buckets import pow2_ladder
 from ..engine import EngineClosedError, QueueFullError
 from .kv_pool import KVPool
-from .model import LMSpec, build_lm_programs
+from .model import FULL, SLIDING, LMSpec, build_lm_programs
 from .prefix_cache import PrefixCache, prefix_cache_enabled
 from .scheduler import RUNNING, Scheduler, Sequence
 from .spec import NgramDraft, accept_drafts, spec_k_from_env
@@ -110,7 +113,8 @@ class DecodeEngine(object):
                  draft=None, kv_dtype=None, name=None, prefill_chunk=None,
                  min_prompt_bucket=1):
         from ...quant.core import resolve_kv_dtype
-        from .model import kv_bytes_per_token
+        from ...quant.core import kv_itemsize
+        from .model import kv_bytes_per_kind, kv_bytes_per_token
         self.spec = spec
         # fleet identity: the routers key membership, placement, and
         # per-replica metrics on it (same contract as ServingEngine)
@@ -130,14 +134,18 @@ class DecodeEngine(object):
         # unquantized engine; int8/fp8 halve-to-quarter bytes/token,
         # which is more resident sequences per chip at equal HBM).
         self.prefix_cache_on = prefix_cache_enabled(prefix_cache)
-        if self.prefix_cache_on and spec.block == 'parallel_moe':
-            # shared pages under a window have no test against this
-            # block's reference yet
+        if self.prefix_cache_on and spec.block != 'post_ln':
+            # shared pages under a window have no test against these
+            # blocks' references yet
             raise NotImplementedError(
-                "block='parallel_moe' runs without the prefix cache")
+                "block=%r runs without the prefix cache" % spec.block)
         self.spec_k = spec_k_from_env(spec_k)
         self.kv_dtype = resolve_kv_dtype(kv_dtype)
         self.kv_bytes_per_token = kv_bytes_per_token(spec, self.kv_dtype)
+        self._kind_bytes = kv_bytes_per_kind(spec, self.kv_dtype)
+        # a row's own bytes in one layer, by kind (what must be read)
+        self._row_bytes = {k.name: k.width * kv_itemsize(self.kv_dtype)
+                           for k in spec.cache_kinds()}
         self.draft = draft if draft is not None else \
             (NgramDraft() if self.spec_k > 0 else None)
         self._progs = build_lm_programs(spec, self.max_batch,
@@ -193,6 +201,10 @@ class DecodeEngine(object):
             _obs.set_gauge('decode.kv_bytes_per_token',
                            self.kv_bytes_per_token,
                            kv_dtype=self.kv_dtype)
+            if len(self._kind_bytes) > 2:       # more than K and V
+                for kind, n in self._kind_bytes.items():
+                    _obs.set_gauge('decode.kv_bytes_per_token', n,
+                                   kv_dtype=self.kv_dtype, kind=kind)
         self.prefix_cache = PrefixCache(self.pool) \
             if self.prefix_cache_on else None
         self._sched = Scheduler(self.pool, self.max_batch,
@@ -365,6 +377,7 @@ class DecodeEngine(object):
         or dtype differs — a cross-dtype mismatch raises instead of
         silently dequantizing."""
         s = self.spec
+        self._per_head_cache('kv_geometry')
         return {
             'n_layer': s.n_layer, 'n_head': s.n_head,
             'n_kv_head': s.n_kv_head,
@@ -372,6 +385,17 @@ class DecodeEngine(object):
             'block_size': self.block_size, 'kv_dtype': self.kv_dtype,
             'arena_names': tuple(self._progs.arena_names),
         }
+
+    def _per_head_cache(self, what):
+        """The page handoff ships K and V rows of ``n_kv_head`` heads;
+        a block that caches anything else has no packet format yet."""
+        if not self.spec.per_head_cache():
+            from ..handoff import CacheKindError
+            raise CacheKindError(
+                '%s: block=%r caches %s, not per-head K/V rows: the page '
+                'handoff has no format for it'
+                % (what, self.spec.block,
+                   ', '.join(k.name for k in self.spec.cache_kinds())))
 
     def arena_specs(self):
         """{arena name: logical PartitionSpec or None} of the live
@@ -412,6 +436,7 @@ class DecodeEngine(object):
         pages so they cannot be reallocated mid-read."""
         import jax
         import jax.numpy as jnp
+        self._per_head_cache('read_pages')
         n = len(page_ids)
         pps = self.pages_per_seq
         # oversized groups walk warmed rungs chunk by chunk instead of
@@ -465,6 +490,7 @@ class DecodeEngine(object):
         set small, warmable, and never larger than warmup traced.
         Pages must be caller-owned (freshly alloc'd)."""
         import jax.numpy as jnp
+        self._per_head_cache('write_pages')
         n = len(page_ids)
         if not n:
             return
@@ -847,6 +873,8 @@ class DecodeEngine(object):
                         seq.seed, wait=start == starts[-1])
             t1 = time.perf_counter()
         _obs.record('decode.prefill_seconds', t1 - t0, bucket=bucket)
+        # the chunks run back to back and only the last is waited for
+        _obs.record('decode.prefill_chunk_seconds', (t1 - t0) / len(starts))
         _obs.inc('decode.prefills_total')
         _obs.inc('decode.prefill_chunks', len(starts))
         with _obs.span('decode.prefill.emit'):
@@ -912,7 +940,35 @@ class DecodeEngine(object):
                          int((lens[:len(batch)] >= window).sum()))
                 _obs.record('decode.step_window_tokens',
                             int(np.minimum(lens, window).sum()))
+            if self.spec.index_topk:
+                self._count_selection(lens[:len(batch)] + 1)
         return lens, tables, temps, seeds
+
+    def _count_selection(self, seen):
+        """What one decode step's attention has to read of a latent
+        cache, from the rows' own lengths (``seen`` [rows]: the
+        positions each live row holds, its new token's included), summed
+        over the layers of a kind: a full layer's indexer scores every
+        position's key and its attention reads the ``index_topk`` it
+        keeps; a sliding layer reads its window."""
+        spec = self.spec
+        kept = np.minimum(seen, spec.index_topk)
+        n_full = len(spec.layers_of(FULL))
+        n_sliding = len(spec.layers_of(SLIDING))
+        _obs.inc('decode.sparse_positions_seen', n_full * int(seen.sum()))
+        _obs.inc('decode.sparse_positions_selected',
+                 n_full * int(kept.sum()))
+        _obs.inc('decode.sparse_rows', len(seen))
+        _obs.inc('decode.sparse_rows_live',
+                 int((seen > spec.index_topk).sum()))
+        for kind, positions in (
+                ('lm_latent_full', n_full * int(kept.sum())),
+                ('lm_index_full', n_full * int(seen.sum())),
+                ('lm_latent_sliding', n_sliding * int(np.minimum(
+                    seen, spec.sliding_window).sum()))):
+            if kind in self._row_bytes:
+                _obs.inc('decode.cache_bytes_read',
+                         positions * self._row_bytes[kind], kind=kind)
 
     def _count_attn_pages(self, lens, rows, k1):
         """How far the attention's bounds engage: the pages this step's
@@ -985,7 +1041,7 @@ class DecodeEngine(object):
                     self._finish(seq, reason)
 
     def _record_moe(self, stats, rows):
-        """One decode step's router statistics ([n_layer, 4]: choices
+        """One decode step's router statistics ([routed layers, 4]: choices
         that landed on an expert held here, rows on the busiest of
         them, experts any row chose, row tiles the routed product ran)
         into the counters the benchmark reads: of rows x

@@ -7,8 +7,10 @@ Scope, all sharing one parameter namespace (prefix ``lm_``):
   'post_ln' — models.transformer._stacked_layer_params layout,
   ENC_SLOTS, causal self-attention + FFN + 2 LNs per layer, token
   embedding, sinusoid position table, output projection; 'parallel_moe'
-  — ``moe_param_shapes``, at ``LMSpec.dtype``) and the two zeroed KV
-  page arenas ``[L, NB, bs, Hkv*d]``. Arenas are persistable scope state: every
+  and 'latent_moe' — ``block_param_shapes``, at ``LMSpec.dtype``) and
+  the zeroed page arenas of the block's cache kinds
+  (``LMSpec.cache_kinds``: K and V ``[L, NB, bs, Hkv*d]``, or a latent
+  row and an index key per kind of layer). Arenas are persistable scope state: every
   prefill/decode run reads them from scope and writes them back
   through executor donation — in-place HBM updates, the same
   whole-program-state contract the trainer uses for params.
@@ -49,8 +51,53 @@ __all__ = ['LMSpec', 'DecodePrograms', 'build_lm_programs']
 SLIDING, FULL = 'sliding_attention', 'full_attention'
 
 
+class CacheKind(collections.namedtuple(
+        'CacheKind', ['name', 'slot', 'layers', 'width'])):
+    """One arena of the paged cache: its name, the op's input slot, the
+    layers that keep it (in order) and the elements a token's row
+    holds."""
+
+    LANES = 128
+
+    @property
+    def stored(self):
+        """The elements a row takes in the arena: ``width``, or where a
+        row is wider than a lane tile and not whole tiles (a latent
+        row: 576, 1,088), the next whole number of them. The TPU's
+        row-major tiling pads such a row to that anyway; left to itself
+        the compiler instead lays the *page* axis minor and re-lays the
+        whole arena at every program's entry and exit (v5e compile at the
+        published widths, PR 34), so the padding is made explicit and
+        written as zeros."""
+        if self.width <= self.LANES:
+            return self.width
+        return -(-self.width // self.LANES) * self.LANES
+
+
+class LatentShape(object):
+    """One layer kind's latent attention: ``n_head`` heads over a cached
+    row ``[c_kv ; k_rope]`` of ``kv_rank + d_rope`` that all of them
+    share; queries through a rank-``q_rank`` bottleneck, ``d_nope +
+    d_rope`` a head; values ``d_v`` a head."""
+
+    def __init__(self, n_head, q_rank, kv_rank, d_nope, d_rope, d_v,
+                 rope_theta):
+        self.n_head, self.q_rank, self.kv_rank = \
+            int(n_head), int(q_rank), int(kv_rank)
+        self.d_nope, self.d_rope, self.d_v = \
+            int(d_nope), int(d_rope), int(d_v)
+        self.rope_theta = float(rope_theta)
+        if self.d_rope % 2 or min(self.n_head, self.q_rank, self.kv_rank,
+                                  self.d_nope, self.d_rope, self.d_v) < 1:
+            raise ValueError('LatentShape: %r' % (vars(self),))
+
+    @property
+    def row_width(self):
+        return self.kv_rank + self.d_rope
+
+
 class LMSpec(object):
-    """Decoder-only LM hyperparameters: a family of two blocks.
+    """Decoder-only LM hyperparameters: a family of three blocks.
 
     ``block='post_ln'`` (the default; every argument after ``d_inner``
     unused): the 2017 decoder block — embedding scaled by sqrt(d_model)
@@ -75,14 +122,34 @@ class LMSpec(object):
     vocabulary) behind a final LayerNorm, logits times ``logit_scale``.
     ``dtype`` is what the matrices are kept and multiplied at
     (float32 / bfloat16); the residual stream, the norms' statistics,
-    the router, the softmax and the logits are float32."""
+    the router, the softmax and the logits are float32.
+
+    ``block='latent_moe'`` (dots3_note): a serial pre-norm block,
+    ``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``. Attention
+    is latent (``latent``: {layer kind: LatentShape arguments}): a token
+    caches one row ``[c_kv ; k_rope]`` a layer that every head reads,
+    with a sigmoid gate a head on the output. ``full_attention`` layers
+    see the ``index_topk`` positions that a learned indexer
+    (``index_n_heads`` heads of ``index_head_dim``, whose key a token
+    also caches) scores highest, all of them below ``index_topk``;
+    ``sliding_attention`` layers see the last ``sliding_window``. The
+    first ``dense_layers`` have a gated SiLU FFN of ``d_inner_dense``,
+    the others the routed experts of ``parallel_moe`` with a
+    selection-only router bias and the shared experts at weight 1; an
+    output head of its own. ``lora_rescale`` multiplies the normed
+    latents by sqrt(d_model / rank). ``n_head``, ``n_kv_head``,
+    ``d_key``, ``d_value`` and ``rope_theta`` are unused: the shapes are
+    per kind."""
 
     def __init__(self, vocab_size, n_layer=2, n_head=2, d_key=16,
                  d_value=16, d_model=32, d_inner=64, block='post_ln',
                  n_kv_head=None, layer_types=None, sliding_window=0,
                  rope_theta=10000.0, n_experts=0, experts_held=None,
                  first_expert=0, experts_per_token=0, n_shared_experts=0,
-                 norm_eps=1e-5, logit_scale=1.0, dtype='float32'):
+                 norm_eps=1e-5, logit_scale=1.0, dtype='float32',
+                 latent=None, dense_layers=0, d_inner_dense=0,
+                 index_n_heads=0, index_head_dim=0, index_topk=0,
+                 lora_rescale=True):
         self.vocab_size = int(vocab_size)
         self.n_layer = int(n_layer)
         self.n_head = int(n_head)
@@ -105,15 +172,24 @@ class LMSpec(object):
         self.norm_eps = float(norm_eps)
         self.logit_scale = float(logit_scale)
         self.dtype = str(dtype)
+        self.latent = {kind: LatentShape(**dict(shape))
+                       for kind, shape in (latent or {}).items()}
+        self.dense_layers = int(dense_layers)
+        self.d_inner_dense = int(d_inner_dense)
+        self.index_n_heads = int(index_n_heads)
+        self.index_head_dim = int(index_head_dim)
+        self.index_topk = int(index_topk)
+        self.lora_rescale = bool(lora_rescale)
         if self.block == 'post_ln':
             if self.n_kv_head != self.n_head:
                 raise ValueError("LMSpec: block='post_ln' has one KV head "
                                  "per query head")
             return
-        if self.block != 'parallel_moe':
+        if self.block not in ('parallel_moe', 'latent_moe'):
             raise ValueError('LMSpec: unknown block %r (post_ln, '
-                             'parallel_moe)' % self.block)
-        if self.n_head % self.n_kv_head or self.d_key != self.d_value:
+                             'parallel_moe, latent_moe)' % self.block)
+        if self.block == 'parallel_moe' and (
+                self.n_head % self.n_kv_head or self.d_key != self.d_value):
             raise ValueError('LMSpec: %d query heads over %d KV heads of '
                              '%d/%d' % (self.n_head, self.n_kv_head,
                                         self.d_key, self.d_value))
@@ -132,6 +208,78 @@ class LMSpec(object):
                    self.first_expert + self.experts_held - 1,
                    self.n_experts, self.experts_per_token,
                    self.n_shared_experts))
+        if self.block == 'latent_moe':
+            self._check_latent()
+
+    def _check_latent(self):
+        kinds = set(self.layer_types)
+        if set(self.latent) != kinds:
+            raise ValueError('LMSpec: latent shapes for %s, layers of %s'
+                             % (sorted(self.latent), sorted(kinds)))
+        if not 0 <= self.dense_layers <= self.n_layer or (
+                self.dense_layers and self.d_inner_dense < 1):
+            raise ValueError('LMSpec: %d leading dense layers of width %d '
+                             'in %d' % (self.dense_layers,
+                                        self.d_inner_dense, self.n_layer))
+        if FULL in kinds and not (
+                0 < self.index_topk and 0 < self.index_n_heads and
+                self.latent[FULL].d_rope <= self.index_head_dim):
+            raise ValueError(
+                'LMSpec: full layers select by an indexer: index_topk %d, '
+                '%d heads of %d' % (self.index_topk, self.index_n_heads,
+                                    self.index_head_dim))
+
+    def layers_of(self, kind):
+        """The layers of one kind, in order."""
+        return tuple(i for i, t in enumerate(self.layer_types) if t == kind)
+
+    def layer_plan(self):
+        """(lead, period, n_periods, tail) of the latent_moe layer loop:
+        ``lead`` the leading dense layers' kinds, ``period`` the
+        shortest run of kinds that the routed layers repeat, how many
+        whole periods there are, and ``tail`` the kinds of the routed
+        layers left over (a prefix of a period). The published 46
+        layers are 1 + 11 x (full, sliding, sliding, sliding) + 1."""
+        lead = self.layer_types[:self.dense_layers]
+        rest = self.layer_types[self.dense_layers:]
+        size = next(p for p in range(1, len(rest) + 2)
+                    if all(rest[i] == rest[i % p]
+                           for i in range(len(rest)))) if rest else 1
+        whole = len(rest) // size
+        return lead, rest[:size] if whole else (), whole, \
+            rest[whole * size:]
+
+    def cache_kinds(self):
+        """What a token keeps in the paged cache: one ``CacheKind``
+        (arena name, the op's input slot, the layers it holds in order,
+        the row's width in elements; ``stored`` is what the row takes
+        in the arena) per arena. The one place where a
+        token's cache is written down: the arenas' shapes, the bytes a
+        token costs and the pages a budget buys are all read from it.
+        Every kind is indexed by the one block table: a page id is a
+        page of every arena, and every layer keeps every token."""
+        every = tuple(range(self.n_layer))
+        if self.block != 'latent_moe':
+            return (CacheKind('lm_kcache', 'KCache', every,
+                              self.n_kv_head * self.d_key),
+                    CacheKind('lm_vcache', 'VCache', every,
+                              self.n_kv_head * self.d_value))
+        out = []
+        if FULL in self.latent:
+            out += [CacheKind('lm_latent_full', 'LatentFull',
+                              self.layers_of(FULL),
+                              self.latent[FULL].row_width),
+                    CacheKind('lm_index_full', 'IndexFull',
+                              self.layers_of(FULL), self.index_head_dim)]
+        if SLIDING in self.latent:
+            out.append(CacheKind('lm_latent_sliding', 'LatentSliding',
+                                 self.layers_of(SLIDING),
+                                 self.latent[SLIDING].row_width))
+        return tuple(out)
+
+    def per_head_cache(self):
+        """Whether a cached row is ``n_kv_head`` heads of K (or V)."""
+        return self.block != 'latent_moe'
 
     def windows(self):
         """Per layer, the keys a query sees (0: all of them)."""
@@ -150,32 +298,41 @@ DecodePrograms = collections.namedtuple(
      'capacity', 'kv_dtype', 'stats_fetch', 'prefill_stats_fetch'])
 
 
-def kv_bytes_per_token(spec, kv_dtype='float32'):
-    """HBM bytes one cached token costs across all layers: the K/V
-    rows at the arena dtype plus (for quantized arenas) the per-token
-    per-head fp32 scale pair. This is the number the ISSUE's capacity
-    claim rides on: int8 at d_head=128 is ~3.9x less than fp32."""
-    from ...quant.core import kv_itemsize, kv_quantized
+def kv_bytes_per_kind(spec, kv_dtype='float32'):
+    """{arena name: HBM bytes one cached token costs in it}, over the
+    layers that keep it (``LMSpec.cache_kinds``), at the arena dtype."""
+    from ...quant.core import kv_itemsize
     item = kv_itemsize(kv_dtype)
-    b = spec.n_layer * spec.n_kv_head * (spec.d_key + spec.d_value) * item
+    return collections.OrderedDict(
+        (kind.name, len(kind.layers) * kind.stored * item)
+        for kind in spec.cache_kinds())
+
+
+def kv_bytes_per_token(spec, kv_dtype='float32'):
+    """HBM bytes one cached token costs across all layers and cache
+    kinds: the rows at the arena dtype plus (for quantized arenas) the
+    per-token per-head fp32 scale pair. This is the number the ISSUE's
+    capacity claim rides on: int8 at d_head=128 is ~3.9x less than
+    fp32."""
+    from ...quant.core import kv_quantized
+    b = sum(kv_bytes_per_kind(spec, kv_dtype).values())
     if kv_quantized(kv_dtype):
         b += spec.n_layer * spec.n_kv_head * 2 * 4   # k + v scale rows
     return b
 
 
 def arena_bytes(spec, num_blocks, block_size, kv_dtype='float32'):
-    """Total bytes of the K/V (+ scale) arenas."""
-    return kv_bytes_per_token(spec, kv_dtype) * int(num_blocks) * \
-        int(block_size)
+    """Total bytes of the cache (+ scale) arenas."""
+    return kv_page_bytes(spec, block_size, kv_dtype) * int(num_blocks)
 
 
 def kv_page_bytes(spec, block_size, kv_dtype='float32'):
-    """Wire bytes one FULL page costs in a KV handoff packet
-    (serving/handoff.py): the page's K/V rows at the arena dtype plus,
-    for quantized arenas, its per-row fp32 scales. The 3-4x shrink the
-    disaggregated fleet claims at ``kv_dtype='int8'`` is exactly this
-    number's ratio to the fp32 one — quantized pages ship their scale
-    sideband, never a dequantized copy."""
+    """Bytes one FULL page costs, in the arenas and on the wire of a KV
+    handoff packet (serving/handoff.py): the page's rows at the arena
+    dtype plus, for quantized arenas, its per-row fp32 scales. The 3-4x
+    shrink the disaggregated fleet claims at ``kv_dtype='int8'`` is
+    exactly this number's ratio to the fp32 one — quantized pages ship
+    their scale sideband, never a dequantized copy."""
     return kv_bytes_per_token(spec, kv_dtype) * int(block_size)
 
 
@@ -183,15 +340,15 @@ def num_blocks_for_budget(budget_bytes, spec, block_size,
                           kv_dtype='float32'):
     """Pages an arena byte budget buys at ``kv_dtype`` — how bench.py
     sizes the equal-bytes capacity ablation."""
-    page = kv_bytes_per_token(spec, kv_dtype) * int(block_size)
-    return max(1, int(budget_bytes) // page)
+    return max(1, int(budget_bytes)
+               // kv_page_bytes(spec, block_size, kv_dtype))
 
 
 def _lm_params(spec, capacity):
     """Declare the shared parameter set in the CURRENT program (and its
     init ops in the current startup, first declaration wins): the op's
     weight inputs by slot, stacked ones under their slot names."""
-    if spec.block == 'parallel_moe':
+    if spec.block != 'post_ln':
         return _moe_params(spec)
     stacked = _stacked_layer_params(
         'lm_stack', spec.n_layer, spec.n_head, spec.d_key, spec.d_value,
@@ -243,13 +400,103 @@ def moe_param_shapes(spec):
     ])
 
 
+def latent_param_shapes(spec):
+    """``moe_param_shapes`` of the latent_moe block. Stacks are per
+    kind, over the layers of that kind in order: ``lm_full_*`` the full
+    layers' attention and indexer, ``lm_swa_*`` the sliding layers'
+    attention, ``lm_dense_*`` the leading dense FFNs, ``lm_moe_*`` the
+    routed layers' router and experts; the two norms' gains over all
+    layers. The kv up-projection is kept as its two halves, head-major:
+    ``kv_bk`` [H, d_nope, r] (keys) and ``kv_bv`` [H, r, d_v] (values),
+    which is how the absorbed form multiplies them. A fan-in of 0 marks
+    a bias: a float32 vector that starts at zero. The matrices that
+    read a rescaled latent (``lora_rescale``: its RMS is sqrt(d_model /
+    rank), not 1) count ``d_model`` as their fan-in, which is what the
+    rescale is for: every matrix is drawn as if it read the hidden
+    width, and queries, keys and scores come out at unit variance
+    (drawn by the rank, scores have a deviation of 6 at the published
+    widths, every head attends to one key, and the 0.5% of the selected
+    set that bfloat16 flips at the selection's boundary moves the
+    logits by 0.4 rms: PERF.md section 6, PR 34)."""
+    L, d, f = spec.n_layer, spec.d_model, spec.d_inner
+    e, sh = spec.experts_held, spec.n_shared_experts
+    n_dense, n_moe = spec.dense_layers, spec.n_layer - spec.dense_layers
+    out = collections.OrderedDict([
+        ('lm_emb', ([spec.vocab_size, d], d, 'Emb')),
+        ('lm_head.w', ([spec.vocab_size, d], d, 'Head')),
+        ('lm_final_ln.w', ([d], None, 'FinalLN')),
+        ('lm_stack_ln1.w', ([L, d], None, 'Ln1W')),
+        ('lm_stack_ln2.w', ([L, d], None, 'Ln2W')),
+    ])
+    for kind, tag, slot in ((FULL, 'full', 'Full'), (SLIDING, 'swa', 'Swa')):
+        if kind not in spec.latent:
+            continue
+        a, n = spec.latent[kind], len(spec.layers_of(kind))
+        qk = a.d_nope + a.d_rope
+        from_q = d if spec.lora_rescale else a.q_rank
+        from_kv = d if spec.lora_rescale else a.kv_rank
+        out.update([
+            ('lm_%s_q_a.w' % tag, ([n, d, a.q_rank], d, slot + 'QA')),
+            ('lm_%s_q_ln.w' % tag, ([n, a.q_rank], None, slot + 'QLn')),
+            ('lm_%s_q_b.w' % tag, ([n, a.q_rank, a.n_head * qk],
+                                   from_q, slot + 'QB')),
+            ('lm_%s_kv_a.w' % tag, ([n, d, a.row_width], d, slot + 'KvA')),
+            ('lm_%s_kv_ln.w' % tag, ([n, a.kv_rank], None, slot + 'KvLn')),
+            ('lm_%s_kv_bk.w' % tag, ([n, a.n_head, a.d_nope, a.kv_rank],
+                                     from_kv, slot + 'KvBK')),
+            ('lm_%s_kv_bv.w' % tag, ([n, a.n_head, a.kv_rank, a.d_v],
+                                     from_kv, slot + 'KvBV')),
+            ('lm_%s_o.w' % tag, ([n, a.n_head * a.d_v, d],
+                                 a.n_head * a.d_v, slot + 'O')),
+            ('lm_%s_gate.w' % tag, ([n, d, a.n_head], d, slot + 'Gate')),
+        ])
+        if kind == FULL:
+            hi, di = spec.index_n_heads, spec.index_head_dim
+            out.update([
+                ('lm_full_idx_q.w', ([n, a.q_rank, hi * di], from_q,
+                                     'IdxQ')),
+                ('lm_full_idx_k.w', ([n, d, di], d, 'IdxK')),
+                ('lm_full_idx_k_ln.w', ([n, di], None, 'IdxKLnW')),
+                ('lm_full_idx_k_ln.b', ([n, di], 0, 'IdxKLnB')),
+                ('lm_full_idx_w.w', ([n, d, hi], d, 'IdxW')),
+            ])
+    if n_dense:
+        fd = spec.d_inner_dense
+        out.update([
+            ('lm_dense_gate.w', ([n_dense, d, fd], d, 'DenseGate')),
+            ('lm_dense_up.w', ([n_dense, d, fd], d, 'DenseUp')),
+            ('lm_dense_down.w', ([n_dense, fd, d], fd, 'DenseDown')),
+        ])
+    if n_moe:
+        out.update([
+            ('lm_moe_router.w', ([n_moe, d, spec.n_experts], d, 'Router')),
+            ('lm_moe_router.b', ([n_moe, spec.n_experts], 0, 'RouterBias')),
+            ('lm_moe_exp_gate.w', ([n_moe, e, d, f], d, 'ExpGate')),
+            ('lm_moe_exp_up.w', ([n_moe, e, d, f], d, 'ExpUp')),
+            ('lm_moe_exp_down.w', ([n_moe, e, f, d], f, 'ExpDown')),
+            ('lm_moe_shr_gate.w', ([n_moe, sh, d, f], d, 'ShrGate')),
+            ('lm_moe_shr_up.w', ([n_moe, sh, d, f], d, 'ShrUp')),
+            ('lm_moe_shr_down.w', ([n_moe, sh, f, d], f, 'ShrDown')),
+        ])
+    return out
+
+
+def block_param_shapes(spec):
+    """{name: (shape, fan-in, op input slot)} of a routed block's
+    weights: a fan-in of None is a norm's gain (float32 ones), of 0 a
+    bias (float32 zeros), anything else a matrix kept at ``spec.dtype``
+    and drawn N(0, 1 / fan-in)."""
+    return latent_param_shapes(spec) if spec.block == 'latent_moe' \
+        else moe_param_shapes(spec)
+
+
 def _moe_params(spec):
     inputs = {}
-    for name, (shape, fan_in, slot) in moe_param_shapes(spec).items():
+    for name, (shape, fan_in, slot) in block_param_shapes(spec).items():
         init = Constant(1.0) if fan_in is None else \
-            Normal(0., fan_in ** -0.5)
+            Constant(0.0) if fan_in == 0 else Normal(0., fan_in ** -0.5)
         inputs[slot] = [layers.create_parameter(
-            shape=shape, dtype='float32' if fan_in is None else spec.dtype,
+            shape=shape, dtype=spec.dtype if fan_in else 'float32',
             name=name, attr=ParamAttr(name=name, initializer=init))]
     return inputs
 
@@ -264,70 +511,77 @@ def _block_attrs(spec, block_size):
             'top_k': spec.experts_per_token,
             'first_expert': spec.first_expert,
             'logit_scale': spec.logit_scale})
+    if spec.block == 'latent_moe':
+        lead, period, n_periods, tail = spec.layer_plan()
+        attrs.update({
+            'block': spec.block, 'norm_eps': spec.norm_eps,
+            'top_k': spec.experts_per_token,
+            'first_expert': spec.first_expert,
+            'lead': list(lead), 'period': list(period),
+            'n_periods': n_periods, 'tail': list(tail),
+            'window': spec.sliding_window,
+            'index_n_heads': spec.index_n_heads,
+            'index_topk': spec.index_topk,
+            'lora_rescale': int(spec.lora_rescale)})
+        for kind, tag in ((FULL, 'full'), (SLIDING, 'swa')):
+            if kind in spec.latent:
+                a = spec.latent[kind]
+                attrs[tag + '_shape'] = [a.n_head, a.d_nope, a.d_rope]
+                attrs[tag + '_theta'] = a.rope_theta
     return attrs
 
 
 def _arenas(spec, num_blocks, block_size, kv_dtype='float32'):
-    """K/V page arenas ``[L, NB, bs, Hkv*d]`` at ``kv_dtype``: token-major
-    inside a page, heads and head width merged into one lane-dense
-    minor axis, which is what lets the paged ops write a row in place
-    (ops/paged_decode_ops.py). Axes 0 and 1 are layer and page for
+    """{op input slot: page arena} at ``kv_dtype``, one per cache kind
+    of the block (``LMSpec.cache_kinds``): ``[layers of the kind, NB,
+    bs, row width]``, token-major inside a page and the row one
+    lane-dense minor axis (K or V of all KV heads merged; a latent row;
+    an index key), which is what lets the paged ops write a row in
+    place (ops/paged_decode_ops.py). Axes 0 and 1 are layer and page for
     every arena — all that read_pages/write_pages and the handoff
-    index by. Quantized dtypes (int8 / fp8) additionally get
+    index by — and a page id is the same page of every arena. Quantized
+    dtypes (int8 / fp8) additionally get
     per-(page, slot, head) fp32 scale arenas ``[L, NB, bs, H]`` — one
     scale per written K/V row, so a page's stored bits are a pure
     function of the tokens written into it (the bit-consistency
     invariant) and prefix-cache sharing carries the scales for free
     (same physical page index)."""
     from ...quant.core import kv_quantized
-    shapes = {
-        'lm_kcache': [spec.n_layer, num_blocks, block_size,
-                      spec.n_kv_head * spec.d_key],
-        'lm_vcache': [spec.n_layer, num_blocks, block_size,
-                      spec.n_kv_head * spec.d_value],
-    }
-    out = {}
-    for name, shape in shapes.items():
-        out[name] = layers.create_parameter(
-            shape=shape, dtype=kv_dtype, name=name,
-            attr=ParamAttr(name=name, initializer=Constant(0.0),
+
+    def arena(name, shape, dtype, fill):
+        return layers.create_parameter(
+            shape=shape, dtype=dtype, name=name,
+            attr=ParamAttr(name=name, initializer=Constant(fill),
                            trainable=False))
-    ks = vs = None
+    out = collections.OrderedDict(
+        (kind.slot, arena(kind.name, [len(kind.layers), num_blocks,
+                                      block_size, kind.stored],
+                          kv_dtype, 0.0))
+        for kind in spec.cache_kinds())
     if kv_quantized(kv_dtype):
         sshape = [spec.n_layer, num_blocks, block_size, spec.n_head]
-        ks, vs = [layers.create_parameter(
-            shape=sshape, dtype='float32', name=name,
-            attr=ParamAttr(name=name, initializer=Constant(1.0),
-                           trainable=False))
-            for name in ('lm_kscale', 'lm_vscale')]
-    return out['lm_kcache'], out['lm_vcache'], ks, vs
+        for name, slot in (('lm_kscale', 'KScale'), ('lm_vscale', 'VScale')):
+            out[slot] = arena(name, sshape, 'float32', 1.0)
+    return out
 
 
-def _common_inputs(params, kc, vc, ks=None, vs=None):
-    inputs = dict(params, KCache=[kc], VCache=[vc])
-    if ks is not None:
-        inputs['KScale'] = [ks]
-        inputs['VScale'] = [vs]
-    return inputs
+def _common_inputs(params, arenas):
+    return dict(params, **{slot: [a] for slot, a in arenas.items()})
 
 
-def _arena_outputs(kc, vc, ks=None, vs=None):
-    outputs = {'KCacheOut': [kc], 'VCacheOut': [vc]}
-    if ks is not None:
-        outputs['KScaleOut'] = [ks]
-        outputs['VScaleOut'] = [vs]
-    return outputs
+def _arena_outputs(arenas):
+    return {slot + 'Out': [a] for slot, a in arenas.items()}
 
 
 def _moe_stats_output(helper, spec, outputs):
-    """Give a ``parallel_moe`` program its MoeStats output (per layer:
+    """Give a routed block's program its MoeStats output (per routed layer:
     choices that landed on an expert held here, rows on the busiest of
     them, experts any row chose, row tiles the routed product ran) and
     return its name; None for a block that routes nothing."""
-    if spec.block != 'parallel_moe':
+    if spec.block == 'post_ln':
         return None
     stats = helper.create_variable_for_type_inference('int32')
-    stats.shape = (spec.n_layer, 4)
+    stats.shape = (spec.n_layer - spec.dense_layers, 4)
     outputs['MoeStats'] = [stats]
     return stats.name
 
@@ -347,13 +601,12 @@ def build_lm_programs(spec, max_batch, block_size, num_blocks,
     kv_dtype = resolve_kv_dtype(kv_dtype)
     capacity = int(pages_per_seq) * int(block_size)
     spec_k = int(spec_k)
-    moe = spec.block == 'parallel_moe'
-    if moe and (spec_k > 0 or kv_quantized(kv_dtype)):
-        # neither has a test against this block's reference yet
+    if spec.block != 'post_ln' and (spec_k > 0 or kv_quantized(kv_dtype)):
+        # neither has a test against these blocks' references yet
         raise NotImplementedError(
-            "block='parallel_moe' runs without speculation and with an "
+            "block=%r runs without speculation and with an "
             "unquantized KV arena (got spec_k=%d, kv_dtype=%s)"
-            % (spec_k, kv_dtype))
+            % (spec.block, spec_k, kv_dtype))
     attrs = _block_attrs(spec, block_size)
     startup = Program()
     prefill_prog = Program()
@@ -361,7 +614,7 @@ def build_lm_programs(spec, max_batch, block_size, num_blocks,
 
     with program_guard(prefill_prog, startup):
         params = _lm_params(spec, capacity)
-        kc, vc, ks, vs = _arenas(spec, num_blocks, block_size, kv_dtype)
+        arenas = _arenas(spec, num_blocks, block_size, kv_dtype)
         ids = layers.data(name='pf_ids', shape=[-1], dtype='int64')
         length = layers.data(name='pf_len', shape=[], dtype='int32')
         cached = layers.data(name='pf_cached', shape=[], dtype='int32')
@@ -372,12 +625,11 @@ def build_lm_programs(spec, max_batch, block_size, num_blocks,
         helper = LayerHelper('paged_prefill', name='paged_prefill')
         nxt = helper.create_variable_for_type_inference('int64')
         nxt.shape = (1,)
-        inputs = _common_inputs(params, kc, vc, ks, vs)
+        inputs = _common_inputs(params, arenas)
         inputs.update({'Ids': [ids], 'Len': [length], 'Cached': [cached],
                        'BlockTable': [table], 'Temp': [temp],
                        'Seed': [seed]})
-        outputs = dict(_arena_outputs(kc, vc, ks, vs),
-                       NextToken=[nxt])
+        outputs = dict(_arena_outputs(arenas), NextToken=[nxt])
         prefill_stats_fetch = _moe_stats_output(helper, spec, outputs)
         helper.append_op(type='paged_prefill', inputs=inputs,
                          outputs=outputs, attrs=attrs)
@@ -385,7 +637,7 @@ def build_lm_programs(spec, max_batch, block_size, num_blocks,
 
     with program_guard(decode_prog, startup):
         params = _lm_params(spec, capacity)
-        kc, vc, ks, vs = _arenas(spec, num_blocks, block_size, kv_dtype)
+        arenas = _arenas(spec, num_blocks, block_size, kv_dtype)
         tokens = layers.data(name='dec_tokens', shape=[], dtype='int64')
         lens = layers.data(name='dec_lens', shape=[], dtype='int32')
         tables = layers.data(name='dec_tables', shape=[pages_per_seq],
@@ -395,12 +647,11 @@ def build_lm_programs(spec, max_batch, block_size, num_blocks,
         helper = LayerHelper('paged_decode_step', name='paged_decode_step')
         nxt = helper.create_variable_for_type_inference('int64')
         nxt.shape = (max_batch,)
-        inputs = _common_inputs(params, kc, vc, ks, vs)
+        inputs = _common_inputs(params, arenas)
         inputs.update({'Tokens': [tokens], 'SeqLens': [lens],
                        'BlockTables': [tables], 'Temps': [temps],
                        'Seeds': [seeds]})
-        outputs = dict(_arena_outputs(kc, vc, ks, vs),
-                       NextTokens=[nxt])
+        outputs = dict(_arena_outputs(arenas), NextTokens=[nxt])
         stats_fetch = _moe_stats_output(helper, spec, outputs)
         helper.append_op(type='paged_decode_step', inputs=inputs,
                          outputs=outputs, attrs=attrs)
@@ -411,8 +662,7 @@ def build_lm_programs(spec, max_batch, block_size, num_blocks,
         verify_prog = Program()
         with program_guard(verify_prog, startup):
             params = _lm_params(spec, capacity)
-            kc, vc, ks, vs = _arenas(spec, num_blocks, block_size,
-                                     kv_dtype)
+            arenas = _arenas(spec, num_blocks, block_size, kv_dtype)
             tokens = layers.data(name='sv_tokens', shape=[spec_k + 1],
                                  dtype='int64')
             lens = layers.data(name='sv_lens', shape=[], dtype='int32')
@@ -425,21 +675,18 @@ def build_lm_programs(spec, max_batch, block_size, num_blocks,
                                  name='paged_spec_verify')
             nxt = helper.create_variable_for_type_inference('int64')
             nxt.shape = (max_batch, spec_k + 1)
-            inputs = _common_inputs(params, kc, vc, ks, vs)
+            inputs = _common_inputs(params, arenas)
             inputs.update({'Tokens': [tokens], 'SeqLens': [lens],
                            'BlockTables': [tables], 'Temps': [temps],
                            'Seeds': [seeds]})
-            outputs = dict(_arena_outputs(kc, vc, ks, vs),
-                           NextTokens=[nxt])
+            outputs = dict(_arena_outputs(arenas), NextTokens=[nxt])
             helper.append_op(type='paged_spec_verify', inputs=inputs,
                              outputs=outputs,
                              attrs=dict(attrs, k=spec_k))
             verify_fetch = nxt.name
 
     param_names = sorted(v[0].name for v in params.values())
-    arena_names = ('lm_kcache', 'lm_vcache')
-    if ks is not None:
-        arena_names += ('lm_kscale', 'lm_vscale')
+    arena_names = tuple(a.name for a in arenas.values())
     return DecodePrograms(
         startup=startup, prefill=prefill_prog, decode=decode_prog,
         verify=verify_prog,
@@ -457,11 +704,13 @@ def random_weights(spec, seed=0):
     identical weights (float32; an engine keeps each at its declared
     dtype)."""
     rng = np.random.RandomState(seed)
-    if spec.block == 'parallel_moe':
+    if spec.block != 'post_ln':
+        # a bias is drawn small, not zero, so that it changes choices
         return {name: np.ones(shape, 'float32') if fan_in is None else
-                (rng.randn(*shape) * fan_in ** -0.5).astype('float32')
+                (rng.randn(*shape) * (fan_in ** -0.5 if fan_in else 0.05)
+                 ).astype('float32')
                 for name, (shape, fan_in, _) in
-                moe_param_shapes(spec).items()}
+                block_param_shapes(spec).items()}
     d, dk, dv = spec.d_model, spec.d_key, spec.d_value
     h, L = spec.n_head, spec.n_layer
 

@@ -94,6 +94,13 @@ class KVGeometryError(HandoffError):
     not match the destination arenas."""
 
 
+class CacheKindError(HandoffError):
+    """The engine's block caches something other than per-head K/V rows
+    (``LMSpec.cache_kinds``: a latent row, an index key), for which the
+    packet has no format: raised by name rather than shipping pages a
+    destination would misread."""
+
+
 def handoff_verify_enabled(transport='inproc'):
     """PADDLE_TPU_HANDOFF_VERIFY knob, read per call. Unset, the
     default depends on the transport: OFF for the in-process hop

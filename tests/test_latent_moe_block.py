@@ -1,0 +1,657 @@
+"""The latent_moe block (LMSpec block='latent_moe': dots3_note) against
+its plain reference, at a tiny size on the CPU in float32: a leading
+dense full layer, then (full, sliding, sliding, sliding); 4 heads over a
+rank-12 latent in the full layers and 2 over a rank-20 one in the
+sliding ones; an indexer of 3 heads that keeps 8 positions; window 5;
+8 experts of which 4 are held, 3 per token, one shared. Every sequence
+runs past the 8 selected positions and the window.
+
+The comparisons are of logits, not tokens. Tolerance: both sides are
+float32 on the CPU; they differ in the order of their sums (the block
+folds the key up-projection into the query and applies the value
+up-projection to the weighted sum of latents, the reference expands keys
+and values head by head), which at these widths gives differences of a
+few 1e-6 on logits of order 1. 5e-5 leaves a margin, and is two orders
+and more under what bfloat16 state, a dropped gate, a dropped rescale or
+an unapplied selection gives (checked below by breaking each)."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu as fluid
+from paddle_tpu.models.reference import dots3_note as ref
+from paddle_tpu.ops import latent_moe_ops as lmo
+from paddle_tpu.ops import moe_held_ops as moe
+from paddle_tpu.ops import paged_decode_ops as pdo
+from paddle_tpu.serving.decode import DecodeEngine, LMSpec, random_weights
+from paddle_tpu.serving.decode import model as lm
+
+TOL = 5e-5
+BS, PAGES, NB = 4, 12, 40            # 48 positions a sequence
+F, S = lm.FULL, lm.SLIDING
+
+
+def _spec(**over):
+    kw = dict(
+        vocab_size=64, n_layer=5, d_model=32, d_inner=24,
+        block='latent_moe', layer_types=[F, F, S, S, S], sliding_window=5,
+        latent={F: dict(n_head=4, q_rank=16, kv_rank=12, d_nope=8,
+                        d_rope=4, d_v=8, rope_theta=8e7),
+                S: dict(n_head=2, q_rank=16, kv_rank=20, d_nope=12,
+                        d_rope=4, d_v=8, rope_theta=5e4)},
+        dense_layers=1, d_inner_dense=40, index_n_heads=3,
+        index_head_dim=8, index_topk=8, n_experts=8, experts_held=4,
+        first_expert=2, experts_per_token=3, n_shared_experts=1)
+    kw.update(over)
+    return LMSpec(**kw)
+
+
+SPEC = _spec()
+WEIGHTS = random_weights(SPEC, seed=5)
+
+
+class _Op(object):
+    def __init__(self, slots):
+        self._slots = slots
+
+    def input(self, slot):
+        return self._slots[slot]
+
+
+class _Ctx(object):
+    """What a paged op's lowering reads of its context, for driving the
+    block's row function without a Program."""
+
+    def __init__(self, spec, weights):
+        self._attrs = lm._block_attrs(spec, BS)
+        self.env = {}
+        slots = {}
+        for name, (_, _, slot) in lm.block_param_shapes(spec).items():
+            self.env[name] = jnp.asarray(weights[name])
+            slots[slot] = name
+        self.op = _Op(slots)
+
+    def attr(self, name, default=None):
+        return self._attrs.get(name, default)
+
+    def input(self, slot):
+        return self.env[self.op.input(slot)]
+
+
+def _block(spec=SPEC, weights=WEIGHTS):
+    return lmo.LatentMoEBlock(_Ctx(spec, weights))
+
+
+def _arenas(spec=SPEC):
+    return tuple(jnp.zeros((len(k.layers), NB, BS, k.stored), jnp.float32)
+                 for k in spec.cache_kinds())
+
+
+_JITTED = {}
+
+
+def _jitted(block, fn):
+    """``fn(block, ...)`` compiled once per block and shape."""
+    key = (id(block), fn.__name__)
+    if key not in _JITTED:
+        _JITTED[key] = (block, jax.jit(lambda *a: fn(block, *a)))
+    return _JITTED[key][1]
+
+
+def _chunk_rows(block, arenas, table, tokens, start):
+    s = tokens.shape[0]
+    pos = start + jnp.arange(s, dtype=jnp.int32)
+    place = pdo._page_runs(table, start, jnp.int32(s), s, NB, BS)
+    h, arenas, stats = pdo._extend_rows(
+        block, arenas, tokens, pos, table, place, valid=jnp.ones((s,), bool))
+    return block.logits(h), arenas, stats
+
+
+def _prefill_chunk(block, arenas, table, tokens, start):
+    """One chunk of one sequence through the one-table path, as the
+    paged_prefill op runs it: logits of every row."""
+    return _jitted(block, _chunk_rows)(
+        arenas, table, jnp.asarray(tokens, jnp.int32), jnp.int32(start))
+
+
+def _step_rows(block, arenas, tables, tokens, lens):
+    place = pdo._single_rows(tables, lens, NB, BS)
+    h, arenas, stats = pdo._extend_rows(
+        block, arenas, tokens, lens, tables, place, valid=place.ok[:, 0])
+    return block.logits(h), arenas, stats
+
+
+def _decode(block, arenas, tables, tokens, lens):
+    return _jitted(block, _step_rows)(arenas, tables, tokens, lens)
+
+
+def _reference_logits(tokens, spec=SPEC, weights=WEIGHTS, **lowered):
+    return np.asarray(ref.logits(
+        weights, np.asarray(tokens, np.int32),
+        dict(ref.arch_of(spec), **lowered), ref.held_of(spec)))
+
+
+# ------------------------------------------------------ spec and caches
+def test_layer_plan_of_the_published_list():
+    """1 leading dense full layer, 11 periods of (full, sliding x 3) and
+    one full layer over: the loop is written for the 46, of which the
+    cut runs the first five."""
+    types = [F] + [F, S, S, S] * 11 + [F]
+    spec = _spec(n_layer=46, layer_types=types)
+    assert spec.layer_plan() == ((F,), (F, S, S, S), 11, (F,))
+    assert SPEC.layer_plan() == ((F,), (F, S, S, S), 1, ())
+    assert len(spec.layers_of(F)) == 13 and len(spec.layers_of(S)) == 33
+    kinds = {k.name: k for k in spec.cache_kinds()}
+    assert kinds['lm_latent_full'].layers == spec.layers_of(F)
+    assert kinds['lm_latent_sliding'].layers == spec.layers_of(S)
+
+
+def test_one_place_for_a_tokens_cache_bytes():
+    """Three arenas under one table: a token costs the full layers'
+    latent row and index key and the sliding layers' latent row, and
+    every function of a token's bytes reads the same list."""
+    kinds = SPEC.cache_kinds()
+    assert [(k.name, k.slot, k.layers, k.width) for k in kinds] == [
+        ('lm_latent_full', 'LatentFull', (0, 1), 16),
+        ('lm_index_full', 'IndexFull', (0, 1), 8),
+        ('lm_latent_sliding', 'LatentSliding', (2, 3, 4), 24)]
+    per_token = 2 * 16 + 2 * 8 + 3 * 24
+    assert lm.kv_bytes_per_token(SPEC) == per_token * 4
+    assert lm.kv_bytes_per_token(SPEC, 'bfloat16') == per_token * 2
+    assert lm.kv_bytes_per_kind(SPEC, 'bfloat16') == {
+        'lm_latent_full': 64, 'lm_index_full': 32, 'lm_latent_sliding': 144}
+    assert lm.kv_page_bytes(SPEC, BS) == per_token * 4 * BS
+    assert lm.arena_bytes(SPEC, NB, BS) == per_token * 4 * BS * NB
+    assert lm.num_blocks_for_budget(
+        lm.arena_bytes(SPEC, NB, BS), SPEC, BS) == NB
+    # the published widths: 9,344 B a token in bfloat16 over the 5 layers,
+    # stored in whole lane tiles (576 -> 640, 1,088 -> 1,152): 9,984 B
+    big = _spec(latent={
+        F: dict(n_head=128, q_rank=1024, kv_rank=512, d_nope=128, d_rope=64,
+                d_v=128, rope_theta=8e7),
+        S: dict(n_head=64, q_rank=1024, kv_rank=1024, d_nope=192, d_rope=64,
+                d_v=128, rope_theta=5e4)}, index_head_dim=128)
+    assert sum(len(k.layers) * k.width * 2 for k in big.cache_kinds()) \
+        == 9344
+    assert [k.stored for k in big.cache_kinds()] == [640, 128, 1152]
+    assert lm.kv_bytes_per_token(big, 'bfloat16') == 9984
+    # the blocks of K and V rows declare theirs through the same function
+    old = LMSpec(vocab_size=8, n_layer=3, n_head=4, d_key=8, d_value=8)
+    assert [(k.name, k.layers, k.width) for k in old.cache_kinds()] == [
+        ('lm_kcache', (0, 1, 2), 32), ('lm_vcache', (0, 1, 2), 32)]
+
+
+@pytest.mark.parametrize('bad', [
+    dict(latent={F: dict(n_head=4, q_rank=16, kv_rank=12, d_nope=8,
+                         d_rope=4, d_v=8, rope_theta=8e7)}),
+    dict(index_topk=0), dict(dense_layers=6), dict(d_inner_dense=0)])
+def test_spec_refuses_what_it_cannot_run(bad):
+    with pytest.raises(ValueError):
+        _spec(**bad)
+
+
+# -------------------------------------------------------- the selection
+@pytest.mark.parametrize('rows,cols,k', [(5, 40, 8), (3, 64, 1), (4, 8, 8),
+                                         (2, 33, 32)])
+def test_selection_is_the_dense_top_k(rows, cols, k):
+    """The k largest of each row, found by counting: the set lax.top_k
+    indexes, with -inf rows' tails, fewer than k candidates, and ties
+    (taken from the left, as top_k takes them)."""
+    rng = np.random.RandomState(rows * cols + k)
+    scores = rng.randn(rows, cols).astype('float32')
+    scores[0, cols // 2:] = -np.inf                # fewer candidates
+    scores[1, :] = np.round(scores[1, :])          # many ties
+    scores[-1, 3:9] = scores[-1, 3]                # ties at the boundary?
+    got = np.asarray(lmo.select_topk(jnp.asarray(scores), k))
+    if cols <= k:
+        assert got.all()
+        return
+    _, at = jax.lax.top_k(jnp.asarray(scores), k)
+    want = np.zeros((rows, cols), bool)
+    want[np.arange(rows)[:, None], np.asarray(at)] = True
+    assert np.array_equal(got, want)
+    assert (got.sum(axis=1) == k).all()
+
+
+def test_index_scores_of_both_table_forms_are_the_reference():
+    """The indexer's scores over cached keys, through many tables (one
+    query each) and through one table (many queries): the reference's
+    dense I(t, s) with -inf at and past each row's length."""
+    rng = np.random.RandomState(9)
+    heads, width, n = 3, 8, 6
+    arena = jnp.asarray(rng.randn(2, NB, BS, width), jnp.float32)
+    q = jnp.asarray(rng.randn(n, heads, width), jnp.float32)
+    w = jnp.asarray(rng.randn(n, heads), jnp.float32)
+    table = jnp.asarray(rng.permutation(NB)[:PAGES], jnp.int32)
+    keys = np.asarray(arena)[1][np.asarray(table)].reshape(-1, width)
+    first = 17
+    lens = first + 1 + jnp.arange(n, dtype=jnp.int32)
+    want = np.asarray(ref.index_scores(q, w, jnp.asarray(keys), first,
+                                       'float32'))
+    one = lmo.index_scores(q, w, arena, 1, table, lens, 2)
+    many = lmo.index_scores(q, w, arena, 1, jnp.tile(table, (n, 1)), lens, 3)
+    for got in (one, many):
+        got = np.asarray(got)
+        assert np.array_equal(np.isinf(got), np.isinf(want))
+        np.testing.assert_allclose(got[~np.isinf(got)],
+                                   want[~np.isinf(want)], atol=1e-5)
+
+
+# ------------------------------------------------- the router's bias
+def test_router_bias_chooses_and_does_not_weigh():
+    """``noaux_tc``: the bias is added for the choosing only; the
+    weights are the chosen experts' own sigmoids, normalised."""
+    x = jnp.asarray([[1.0, 0.0]])
+    router = jnp.asarray([[0.0, 1.0, -1.0, 2.0], [0.0, 0.0, 0.0, 0.0]])
+    bias = jnp.asarray([0.0, 0.0, 5.0, -5.0])
+    chosen, weight = moe.route_sigmoid_topk(x, router, 2, bias=bias)
+    assert chosen.tolist() == [[2, 1]]          # without it: [3, 1]
+    sig = lambda z: 1.0 / (1.0 + np.exp(-z))
+    want = np.asarray([sig(-1.0), sig(1.0)])
+    np.testing.assert_allclose(np.asarray(weight)[0], want / want.sum(),
+                               rtol=1e-6)
+    got = ref.route(x, router, bias, 2)
+    assert np.array_equal(np.asarray(got[0]), np.asarray(chosen))
+    np.testing.assert_allclose(np.asarray(got[1]), np.asarray(weight),
+                               rtol=1e-6)
+    # and the drawn bias changes choices at these widths
+    rng = np.random.RandomState(0)
+    n = jnp.asarray(rng.randn(64, SPEC.d_model), jnp.float32)
+    with_b, _ = moe.route_sigmoid_topk(
+        n, WEIGHTS['lm_moe_router.w'][0], 3, bias=WEIGHTS['lm_moe_router.b'][0])
+    without, _ = moe.route_sigmoid_topk(n, WEIGHTS['lm_moe_router.w'][0], 3)
+    assert not np.array_equal(np.sort(np.asarray(with_b), 1),
+                              np.sort(np.asarray(without), 1))
+
+
+# ----------------------------------------------------- shares add up
+def test_shares_add_up_to_the_uncut_layer():
+    """The eight shares of a routed layer (8 experts, one a share), the
+    shared expert counted once, are the uncut layer's FFN: in the
+    reference, and between the block's product and the reference.
+    Attention, indexer and router are replicated: a share's are the
+    uncut model's own arrays."""
+    whole = _spec(experts_held=8, first_expert=0)
+    w = random_weights(whole, seed=11)
+    rng = np.random.RandomState(1)
+    n = jnp.asarray(rng.randn(7, whole.d_model), jnp.float32)
+    arch = ref.arch_of(whole)
+    layer = 2
+    uncut = np.asarray(ref.experts(n, w, layer, arch, (0, 8)))
+
+    def cut(first):
+        out = dict(w)
+        for part in ('gate', 'up', 'down'):
+            name = 'lm_moe_exp_%s.w' % part
+            out[name] = w[name][:, first:first + 1]
+        return out
+
+    shared = np.asarray(ref.expert(
+        n, w['lm_moe_shr_gate.w'][layer, 0], w['lm_moe_shr_up.w'][layer, 0],
+        w['lm_moe_shr_down.w'][layer, 0]))
+    from_reference, from_block = shared.copy(), shared.copy()
+    for first in range(8):
+        share = cut(first)
+        from_reference += np.asarray(
+            ref.experts(n, share, layer, arch, (first, 1))) - shared
+        chosen, weight = moe.route_sigmoid_topk(
+            n, share['lm_moe_router.w'][layer], whole.experts_per_token,
+            bias=share['lm_moe_router.b'][layer])
+        gate, _ = moe.held_gates(chosen, weight, first, 1)
+        from_block += np.asarray(moe.gated_experts(
+            n, gate, *(jnp.asarray(share['lm_moe_exp_%s.w' % p][layer])
+                       for p in ('gate', 'up', 'down'))))
+    np.testing.assert_allclose(from_reference, uncut, atol=TOL)
+    np.testing.assert_allclose(from_block, uncut, atol=TOL)
+    assert np.abs(np.asarray(ref.experts(n, cut(0), layer, arch, (0, 1)))
+                  - uncut).max() > 1e-2
+    # everything else of a share is the uncut model's
+    held = lm.block_param_shapes(_spec(experts_held=1, first_expert=3))
+    full = lm.block_param_shapes(whole)
+    assert {k for k in full if full[k][0] != held[k][0]} == {
+        'lm_moe_exp_gate.w', 'lm_moe_exp_up.w', 'lm_moe_exp_down.w'}
+
+
+# ------------------------------------- prefill in chunks, then decode
+@pytest.mark.parametrize('prompt_len,chunk', [(13, 8), (21, 16), (6, 8),
+                                              (30, 30)])
+def test_chunked_prefill_then_decode_matches_full_forward(prompt_len,
+                                                          chunk):
+    """A sequence that passes index_topk (8) and the window (5): its
+    prompt prefilled in chunks (or in one) through the three arenas,
+    then decoded a token at a time, row by row against the reference's
+    one full forward."""
+    rng = np.random.RandomState(prompt_len)
+    total = prompt_len + 12
+    tokens = rng.randint(0, SPEC.vocab_size, total)
+    want = _reference_logits(tokens)
+    block = _block()
+    arenas = _arenas()
+    table = jnp.asarray(rng.permutation(NB)[:PAGES], jnp.int32)
+    for start in range(0, prompt_len, chunk):
+        piece = tokens[start:min(start + chunk, prompt_len)]
+        got, arenas, stats = _prefill_chunk(block, arenas, table, piece,
+                                            start)
+        np.testing.assert_allclose(
+            np.asarray(got), want[start:start + len(piece)], atol=TOL)
+        assert np.asarray(stats).shape == (4, 4)     # the routed layers
+    for t in range(prompt_len, total):
+        got, arenas, _ = _decode(
+            block, arenas, table[None, :],
+            jnp.asarray(tokens[t:t + 1], jnp.int32),
+            jnp.asarray([t], jnp.int32))
+        np.testing.assert_allclose(np.asarray(got)[0], want[t], atol=TOL)
+
+
+def test_the_published_order_with_periods_and_a_remainder():
+    """Ten layers: one leading dense, two whole periods in the scan and
+    a full layer over, each kind's stacks and arenas indexed at its own
+    count: logits against the reference."""
+    types = [F] + [F, S, S, S] * 2 + [F]
+    spec = _spec(n_layer=10, layer_types=types)
+    assert spec.layer_plan() == ((F,), (F, S, S, S), 2, (F,))
+    w = random_weights(spec, seed=2)
+    rng = np.random.RandomState(2)
+    tokens = rng.randint(0, spec.vocab_size, 22)
+    want = _reference_logits(tokens, spec, w)
+    block, table = _block(spec, w), jnp.arange(PAGES, dtype=jnp.int32)
+    got, arenas, stats = _prefill_chunk(block, _arenas(spec), table,
+                                        tokens[:16], 0)
+    np.testing.assert_allclose(np.asarray(got), want[:16], atol=TOL)
+    assert np.asarray(stats).shape == (9, 4)
+    got, arenas, _ = _prefill_chunk(block, arenas, table, tokens[16:], 16)
+    np.testing.assert_allclose(np.asarray(got), want[16:], atol=TOL)
+
+
+def test_decode_batch_of_mixed_lengths_matches_reference():
+    """Four sequences of lengths on both sides of index_topk and the
+    window in one decode batch, an empty slot among them: every row's
+    logits are the reference's for that sequence, and the statistics
+    count the live rows of the four routed layers only."""
+    rng = np.random.RandomState(7)
+    lengths = [3, 9, 17, 30]
+    seqs = [rng.randint(0, SPEC.vocab_size, n + 1) for n in lengths]
+    block = _block()
+    arenas = _arenas()
+    pages = rng.permutation(NB)
+    tables = np.full((5, PAGES), NB, np.int32)
+    used = 0
+    for i, seq in enumerate(seqs):
+        need = -(-len(seq) // BS)
+        tables[i, :need] = pages[used:used + need]
+        used += need
+        _, arenas, _ = _prefill_chunk(block, arenas, jnp.asarray(tables[i]),
+                                      seq[:-1], 0)
+    got, arenas, stats = _decode(
+        block, arenas, jnp.asarray(tables),
+        jnp.asarray([s[-1] for s in seqs] + [0], jnp.int32),
+        jnp.asarray(lengths + [0], jnp.int32))
+    for i, seq in enumerate(seqs):
+        np.testing.assert_allclose(
+            np.asarray(got)[i], _reference_logits(seq)[-1], atol=TOL)
+    stats = np.asarray(stats)
+    assert stats.shape == (4, 4)
+    assert (stats[:, 0] <= 12).all() and (stats[:, 1] <= 4).all()
+    assert (stats[:, 2] <= SPEC.experts_held).all()
+
+
+def test_a_row_alone_is_the_row_in_a_full_batch_bit_for_bit():
+    """One sequence's decode step with every other slot empty, and the
+    same sequence at another slot among three others: its logits bit
+    for bit, its cache rows too."""
+    rng = np.random.RandomState(11)
+    lengths = [26, 9, 14, 31]
+    seqs = [rng.randint(0, SPEC.vocab_size, n + 1) for n in lengths]
+    block = _block()
+    arenas = _arenas()
+    tables = np.full((4, PAGES), NB, np.int32)
+    pages = rng.permutation(NB)
+    used = 0
+    for i, seq in enumerate(seqs):
+        need = -(-len(seq) // BS)
+        tables[i, :need] = pages[used:used + need]
+        used += need
+        _, arenas, _ = _prefill_chunk(block, arenas, jnp.asarray(tables[i]),
+                                      seq[:-1], 0)
+    among, _, _ = _decode(block, arenas, jnp.asarray(tables),
+                          jnp.asarray([s[-1] for s in seqs], jnp.int32),
+                          jnp.asarray(lengths, jnp.int32))
+    for slot in (0, 3):
+        alone_tables = np.full((4, PAGES), NB, np.int32)
+        alone_tables[1] = tables[slot]
+        tokens = np.zeros(4, np.int32)
+        tokens[1] = seqs[slot][-1]
+        lens = np.zeros(4, np.int32)
+        lens[1] = lengths[slot]
+        alone, _, _ = _decode(block, arenas, jnp.asarray(alone_tables),
+                              jnp.asarray(tokens), jnp.asarray(lens))
+        assert np.array_equal(np.asarray(alone)[1], np.asarray(among)[slot])
+
+
+def test_padded_chunk_rows_write_nothing():
+    """A chunk padded to its bucket: the rows past ``length`` leave all
+    three arenas as they were, and the real rows' logits do not move."""
+    rng = np.random.RandomState(3)
+    tokens = rng.randint(0, SPEC.vocab_size, 5)
+    block, table = _block(), jnp.arange(PAGES, dtype=jnp.int32)
+    exact, want, _ = _prefill_chunk(block, _arenas(), table, tokens, 0)
+    padded = np.concatenate([tokens, np.zeros(3, tokens.dtype)])
+    pos = jnp.arange(8, dtype=jnp.int32)
+    place = pdo._page_runs(table, jnp.int32(0), jnp.int32(5), 8, NB, BS)
+    h, got, _ = pdo._extend_rows(
+        block, _arenas(), jnp.asarray(padded, jnp.int32), pos, table, place,
+        valid=pos < 5)
+    np.testing.assert_allclose(np.asarray(block.logits(h))[:5],
+                               np.asarray(exact), atol=TOL)
+    for a, b in zip(want, got):
+        # (one side compiled, the other not: the rows agree to rounding)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+        flat = np.asarray(b).reshape(b.shape[0], NB * BS, -1)
+        assert flat[:, :5].any() and not flat[:, 5:].any()
+
+
+# --------------------------------- the absorbed against the expanded form
+def test_absorbed_attention_is_the_expanded_attention():
+    """The latent form of the paged attention (keys the cached row,
+    values its first r columns, the key up-projection folded into the
+    query and the value up-projection applied to the sum) against keys
+    and values expanded from the same rows, head by head, under a dense
+    masked softmax with a chosen subset of columns."""
+    from paddle_tpu.ops.pallas.paged_attention import \
+        paged_attention_blocked
+    rng = np.random.RandomState(5)
+    heads, rank, d_nope, d_rope, d_v, rows = 4, 12, 8, 4, 8, 6
+    arena = jnp.asarray(rng.randn(2, NB, BS, rank + d_rope), jnp.float32)
+    w_bk = rng.randn(heads, d_nope, rank).astype('float32')
+    w_bv = rng.randn(heads, rank, d_v).astype('float32')
+    q_nope = rng.randn(rows, heads, d_nope).astype('float32')
+    q_rope = rng.randn(rows, heads, d_rope).astype('float32')
+    tables = np.stack([rng.permutation(NB)[:PAGES] for _ in range(rows)])
+    lens = np.asarray([1, 7, 19, 33, 48, 0], np.int32)
+    chosen = rng.rand(rows, PAGES * BS) < 0.6
+    chosen[np.arange(rows), np.maximum(lens - 1, 0)] = True
+    q_row = np.concatenate([np.einsum('nhd,hdr->nhr', q_nope, w_bk),
+                            q_rope], -1)
+    scale = (d_nope + d_rope) ** -0.5
+    mixed = paged_attention_blocked(
+        jnp.asarray(q_row), arena, None, jnp.asarray(tables, jnp.int32),
+        jnp.asarray(lens), sm_scale=scale, layer=1, latent=rank,
+        chosen=jnp.asarray(chosen), block_cols=8)
+    got = np.einsum('nhr,hrv->nhv', np.asarray(mixed), w_bv)
+    for r in range(rows):
+        cached = np.asarray(arena)[1][tables[r]].reshape(-1, rank + d_rope)
+        see = chosen[r] & (np.arange(PAGES * BS) < lens[r])
+        if not see.any():
+            assert not got[r].any()
+            continue
+        c_kv, k_rope = cached[see, :rank], cached[see, rank:]
+        for h in range(heads):
+            keys, values = c_kv @ w_bk[h].T, c_kv @ w_bv[h]
+            sc = (keys @ q_nope[r, h] + k_rope @ q_rope[r, h]) * scale
+            p = np.exp(sc - sc.max())
+            np.testing.assert_allclose(got[r, h], (p / p.sum()) @ values,
+                                       atol=2e-5)
+
+
+# ------------------------------------------------ what the tolerance is for
+@pytest.mark.parametrize('broken', ['state', 'gate', 'rescale', 'select'])
+def test_the_tolerance_catches_what_it_is_for(broken):
+    """bfloat16 for the residual stream, scores, softmax and logits; a
+    dropped headwise gate; a dropped rescale of the latents; a full
+    layer that attends to all it holds: each moves the logits by far
+    more than the tolerance the sound block is held to."""
+    lowered = {'state': dict(state_dtype='bfloat16'),
+               'gate': dict(gate=False), 'rescale': dict(rescale=False),
+               'select': dict(select=False)}[broken]
+    rng = np.random.RandomState(4)
+    tokens = rng.randint(0, SPEC.vocab_size, 30)
+    sound = _reference_logits(tokens)
+    moved = np.abs(_reference_logits(tokens, **lowered) - sound)
+    assert moved.max() > 100 * TOL
+    if broken == 'select':
+        # the rows below index_topk are untouched: the selection is all
+        assert moved[:SPEC.index_topk].max() < TOL
+        assert moved[SPEC.index_topk:].max() > 100 * TOL
+
+
+@pytest.mark.parametrize('broken', ['window', 'index_topk', 'first_expert',
+                                    'lora_rescale'])
+def test_the_tolerance_catches_a_wrong_block(broken):
+    """And the block itself, broken: a window one key short, a
+    selection one position short, the wrong experts held, no rescale."""
+    over = {'window': dict(sliding_window=4), 'index_topk': dict(index_topk=7),
+            'first_expert': dict(first_expert=3),
+            'lora_rescale': dict(lora_rescale=False)}[broken]
+    rng = np.random.RandomState(2)
+    tokens = rng.randint(0, SPEC.vocab_size, 20)
+    got, _, _ = _prefill_chunk(_block(_spec(**over)), _arenas(),
+                               jnp.arange(PAGES, dtype=jnp.int32), tokens, 0)
+    assert np.abs(np.asarray(got) - _reference_logits(tokens)).max() > 1e-3
+
+
+# ------------------------------------------------------------ the engine
+def _engine(spec=SPEC, **kw):
+    kw.setdefault('max_batch', 4)
+    kw.setdefault('block_size', BS)
+    kw.setdefault('num_blocks', 64)
+    kw.setdefault('pages_per_seq', PAGES)
+    kw.setdefault('prefill_chunk', 8)
+    kw.setdefault('min_prompt_bucket', 4)
+    kw.setdefault('weights', WEIGHTS)
+    kw.setdefault('place', fluid.CPUPlace())
+    return DecodeEngine(spec, **kw)
+
+
+def _requests(n=6, seed=0):
+    rng = np.random.RandomState(seed)
+    return [(rng.randint(0, SPEC.vocab_size,
+                         int(rng.randint(9, 34))).tolist(),
+             int(rng.randint(3, 12))) for _ in range(n)]
+
+
+@pytest.fixture(scope='module')
+def engine():
+    eng = _engine()
+    eng.warmup()
+    eng.start()
+    yield eng
+    eng.shutdown(drain=False)
+
+
+def test_engine_serves_the_reference_tokens_batched_and_alone(engine):
+    """Through DecodeEngine's normal path (scheduler, pool, chunked
+    prefill, the one decode signature): every request's greedy tokens
+    are the reference's own choices, and the same served concurrently
+    and one at a time."""
+    requests = _requests()
+    streams = [engine.submit(p, max_new_tokens=n) for p, n in requests]
+    together = [s.result(300) for s in streams]
+    arch, held = ref.arch_of(SPEC), ref.held_of(SPEC)
+    for (prompt, n), tokens in zip(requests, together):
+        assert len(tokens) == n
+        gaps, _ = ref.token_gaps(WEIGHTS, arch, held, prompt, tokens, 8)
+        assert max(gaps) <= TOL
+    alone = [engine.generate(p, max_new_tokens=n, timeout=300)
+             for p, n in requests[:3]]
+    assert alone == together[:3]
+
+
+def test_engine_counts_the_selection_and_the_cache_by_kind(engine):
+    """The counters the benchmark reads: positions the full layers'
+    rows hold and those the selection keeps, rows past index_topk, the
+    bytes a step's attention must read by kind, the bytes a token costs
+    by kind, and the router statistics of the four routed layers."""
+    from paddle_tpu import observe
+    prompt = list(range(20))
+    observe.enable()
+    try:
+        before = observe.snapshot()
+        engine.generate(prompt, max_new_tokens=4, timeout=300)
+        after = observe.snapshot()
+    finally:
+        observe.disable()
+        observe.reset()
+
+    def grown(name, kind=None):
+        key = name if kind is None else '%s{kind=%s}' % (name, kind)
+
+        def total(snap):
+            return sum(v for k, v in snap['counters'].items()
+                       if k == key or k.startswith(name + '{') and not kind)
+        return total(after) - total(before)
+
+    # three decode steps at lengths 20, 21, 22 (+1: the new token), two
+    # full layers; the selection keeps 8 of them
+    seen = sum(n + 1 for n in (20, 21, 22))
+    assert grown('decode.sparse_positions_seen') == 2 * seen
+    assert grown('decode.sparse_positions_selected') == 2 * 3 * 8
+    assert grown('decode.sparse_rows') == 3
+    assert grown('decode.sparse_rows_live') == 3
+    item = 4
+    assert grown('decode.cache_bytes_read', kind='lm_latent_full') == \
+        2 * 3 * 8 * 16 * item
+    assert grown('decode.cache_bytes_read', kind='lm_index_full') == \
+        2 * seen * 8 * item
+    assert grown('decode.cache_bytes_read', kind='lm_latent_sliding') == \
+        3 * 3 * 5 * 24 * item
+    assert grown('decode.moe_layer_steps') == 3 * 4
+    assert engine.kv_bytes_per_token == lm.kv_bytes_per_token(SPEC)
+
+
+@pytest.mark.parametrize('kw,error', [
+    (dict(prefix_cache=True), NotImplementedError),
+    (dict(spec_k=2), NotImplementedError),
+    (dict(kv_dtype='int8'), NotImplementedError)])
+def test_engine_refuses_what_has_no_test_for_this_block(kw, error):
+    with pytest.raises(error):
+        _engine(**kw)
+
+
+def test_handoff_is_refused_for_a_cache_that_is_not_per_head_rows(engine):
+    from paddle_tpu.serving import handoff
+    with pytest.raises(handoff.CacheKindError):
+        engine.read_pages([0])
+    with pytest.raises(handoff.CacheKindError):
+        engine.write_pages([0], {})
+    with pytest.raises(handoff.CacheKindError):
+        handoff._geometry_header(engine)
+
+
+def test_programs_write_every_arena_in_place():
+    """The decode step and a prefill chunk as the executor jits them,
+    over a pool far larger than a block of the attention's gathers: no
+    instruction of the compiled program materialises a layer of any of
+    the three arenas (serving/decode/hlo_check.py)."""
+    from paddle_tpu.serving.decode.hlo_check import arena_sized_instructions
+    pool = 2048
+    eng = _engine(num_blocks=pool)
+    try:
+        smallest = min(pool * BS * k.width for k in SPEC.cache_kinds())
+        for which in ('decode', 8):
+            hlo = eng.trace_program(which).lower().compile().as_text()
+            assert arena_sized_instructions(hlo, smallest) == []
+    finally:
+        eng.shutdown(drain=False)

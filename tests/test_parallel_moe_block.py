@@ -93,16 +93,16 @@ def _prefill_chunk(block, kc, vc, table, tokens, start):
     s = len(tokens)
     pos = start + jnp.arange(s, dtype=jnp.int32)
     place = pdo._page_runs(table, jnp.int32(start), jnp.int32(s), s, NB, BS)
-    h, kc, vc, _, _, _ = pdo._extend_rows(
-        block, kc, vc, jnp.asarray(tokens, jnp.int32), pos, table, place,
+    h, (kc, vc), _ = pdo._extend_rows(
+        block, (kc, vc), jnp.asarray(tokens, jnp.int32), pos, table, place,
         valid=jnp.ones((s,), bool))
     return block.logits(h), kc, vc
 
 
 def _decode(block, kc, vc, tables, tokens, lens):
     place = pdo._single_rows(tables, lens, NB, BS)
-    h, kc, vc, _, _, stats = pdo._extend_rows(
-        block, kc, vc, tokens, lens, tables, place, valid=place.ok[:, 0])
+    h, (kc, vc), stats = pdo._extend_rows(
+        block, (kc, vc), tokens, lens, tables, place, valid=place.ok[:, 0])
     return block.logits(h), kc, vc, stats
 
 
@@ -375,8 +375,8 @@ def test_padded_chunk_rows_write_nothing():
     padded = np.concatenate([tokens, np.zeros(3, tokens.dtype)])
     pos = jnp.arange(8, dtype=jnp.int32)
     place = pdo._page_runs(table, jnp.int32(0), jnp.int32(5), 8, NB, BS)
-    h, kc2, vc2, _, _, _ = pdo._extend_rows(
-        block, kc, vc, jnp.asarray(padded, jnp.int32), pos, table, place,
+    h, (kc2, vc2), _ = pdo._extend_rows(
+        block, (kc, vc), jnp.asarray(padded, jnp.int32), pos, table, place,
         valid=pos < 5)
     np.testing.assert_allclose(np.asarray(block.logits(h))[:5],
                                np.asarray(exact), atol=TOL)

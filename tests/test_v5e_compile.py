@@ -124,11 +124,11 @@ def test_expert_product_reads_the_stacked_weights_where_they_lie(
     assert len(_fusions_reading(hlo, 'bf16[%d,%d,4096,4096]' % (L, E))) >= 3
 
 
-# name -> (LMSpec arguments, engine arguments): both serving
+# name -> (LMSpec arguments, engine arguments): the serving
 # configurations of the benchmark at their own attention geometry
-# (heads, head width, arena dtype, slots, pages a table, page, top
-# prefill bucket), cut in depth, pool, vocabulary and FFN width, which
-# no attention instruction is sized by. The pool is smaller than the
+# (heads, head width or latent ranks, arena dtype, slots, pages a table,
+# page, top prefill bucket), cut in depth, pool, vocabulary, hidden and
+# FFN width, which no attention instruction is sized by. The pool is smaller than the
 # tables' extent, so that nothing but a whole-table gather or what
 # consumes one reaches max_batch x pages_per_seq pages.
 SERVING = {
@@ -147,6 +147,26 @@ SERVING = {
         dict(max_batch=32, block_size=32, pages_per_seq=208,
              num_blocks=1024, max_prompt_len=6144, prefill_chunk=512,
              min_prompt_bucket=512, kv_dtype='bfloat16')),
+    # the latent block at the published ranks, heads and head widths of
+    # both layer kinds, the indexer's 64 x 128 and its 2,048 positions,
+    # the 513 window; one full and one sliding layer, both routed
+    'dots3_note': (
+        dict(vocab_size=256, n_layer=2, d_model=256, d_inner=64,
+             block='latent_moe',
+             layer_types=('full_attention', 'sliding_attention'),
+             sliding_window=513,
+             latent={'full_attention': dict(
+                 n_head=128, q_rank=1024, kv_rank=512, d_nope=128,
+                 d_rope=64, d_v=128, rope_theta=8e7),
+                 'sliding_attention': dict(
+                 n_head=64, q_rank=1024, kv_rank=1024, d_nope=192,
+                 d_rope=64, d_v=128, rope_theta=5e4)},
+             index_n_heads=64, index_head_dim=128, index_topk=2048,
+             n_experts=8, experts_held=2, experts_per_token=2,
+             n_shared_experts=1, dtype='bfloat16'),
+        dict(max_batch=32, block_size=32, pages_per_seq=528,
+             num_blocks=2048, max_prompt_len=16384, prefill_chunk=512,
+             min_prompt_bucket=512, kv_dtype='bfloat16')),
 }
 
 
@@ -161,12 +181,15 @@ def engine(request):
 
 @pytest.mark.parametrize('which', ['decode', 'prefill'])
 def test_no_serving_program_gathers_a_whole_table(one_chip, engine, which):
-    """The decode step and the largest prefill bucket of both blocks,
+    """The decode step and the largest prefill bucket of every block,
     as the engine jits them: no instruction, gathers included,
     materialises max_batch x pages_per_seq pages (the extent the parent
     gathered, re-tiled and multiplied a layer whatever the rows held),
     and the gathers that are there are a block's: at most 8 tables x
-    one column block of pages."""
+    one column block of pages (a page: of the widest cache kind, as it
+    is stored). Nor is any arena re-laid: the latent rows are stored in
+    whole lane tiles (CacheKind.stored), which is what keeps the
+    compiler from laying the page axis minor."""
     from jax.extend.core import jaxpr_as_fun
     from paddle_tpu.ops.pallas import paged_attention as pa
     from paddle_tpu.serving.decode.hlo_check import arena_sized_instructions
@@ -175,7 +198,18 @@ def test_no_serving_program_gathers_a_whole_table(one_chip, engine, which):
     hlo = jax.jit(jaxpr_as_fun(closed)).lower(
         *[_shaped(one_chip, a.shape, a.dtype)
           for a in closed.in_avals]).compile().as_text()
-    page = engine.block_size * engine.spec.n_kv_head * engine.spec.d_key
+    kinds = engine.spec.cache_kinds()
+    page = engine.block_size * max(k.stored for k in kinds)
+    # every arena stays row-major, pages major and the row minor (this
+    # program is compiled without the executor's donation, so copies of
+    # an arena are not counted here; tests/test_latent_moe_block.py and
+    # chip_smoke.py count them on the program as it is run)
+    short = {'float32': 'f32', 'bfloat16': 'bf16'}[engine.kv_dtype]
+    for k in kinds:
+        arena = '%s[%d,%d,%d,%d]' % (short, len(k.layers), engine.num_blocks,
+                                     engine.block_size, k.stored)
+        assert set(re.findall(re.escape(arena) + r'\{([\d,]+)', hlo)) \
+            == {'3,2,1,0'}, arena
     assert arena_sized_instructions(
         hlo, engine.max_batch * engine.pages_per_seq * page,
         gathers=True) == []
