@@ -44,12 +44,12 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split('\n')[0])
     ap.add_argument('--workload', required=True)
     ap.add_argument('--seed', type=int, default=1)
-    ap.add_argument('--seconds', type=float, default=1.0)
-    ap.add_argument('--trace', type=int, default=0)
     ap.add_argument('--lengths', default='3000,9000,15000')
     ap.add_argument('--rows', type=int, default=256)
     ap.add_argument('--faults', default='select,gate,rescale,state,weights')
     ap.add_argument('--rehearsal', action='store_true')
+    # bench.Context reads both; nothing is served or traced here
+    ap.set_defaults(seconds=0.0, trace=0)
     args = ap.parse_args(argv)
 
     root = os.path.dirname(HERE)

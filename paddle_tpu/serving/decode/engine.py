@@ -69,7 +69,7 @@ from ...core.scope import Scope, scope_guard
 from ..buckets import pow2_ladder
 from ..engine import EngineClosedError, QueueFullError
 from .kv_pool import KVPool
-from .model import FULL, SLIDING, LMSpec, build_lm_programs
+from .model import FULL, LMSpec, build_lm_programs
 from .prefix_cache import PrefixCache, prefix_cache_enabled
 from .scheduler import RUNNING, Scheduler, Sequence
 from .spec import NgramDraft, accept_drafts, spec_k_from_env
@@ -143,9 +143,13 @@ class DecodeEngine(object):
         self.kv_dtype = resolve_kv_dtype(kv_dtype)
         self.kv_bytes_per_token = kv_bytes_per_token(spec, self.kv_dtype)
         self._kind_bytes = kv_bytes_per_kind(spec, self.kv_dtype)
-        # a row's own bytes in one layer, by kind (what must be read)
-        self._row_bytes = {k.name: k.width * kv_itemsize(self.kv_dtype)
-                           for k in spec.cache_kinds()}
+        # by kind: a row's own bytes in one layer (what must be read)
+        # and, for each cap on the positions a step reads of a sequence
+        # (0: all), the layers that have it
+        self._kind_reads = [
+            (k.name, k.width * kv_itemsize(self.kv_dtype),
+             sorted(collections.Counter(k.reads).items()))
+            for k in spec.cache_kinds()]
         self.draft = draft if draft is not None else \
             (NgramDraft() if self.spec_k > 0 else None)
         self._progs = build_lm_programs(spec, self.max_batch,
@@ -201,10 +205,9 @@ class DecodeEngine(object):
             _obs.set_gauge('decode.kv_bytes_per_token',
                            self.kv_bytes_per_token,
                            kv_dtype=self.kv_dtype)
-            if len(self._kind_bytes) > 2:       # more than K and V
-                for kind, n in self._kind_bytes.items():
-                    _obs.set_gauge('decode.kv_bytes_per_token', n,
-                                   kv_dtype=self.kv_dtype, kind=kind)
+            for kind, n in self._kind_bytes.items():
+                _obs.set_gauge('decode.kv_bytes_per_token', n,
+                               kv_dtype=self.kv_dtype, kind=kind)
         self.prefix_cache = PrefixCache(self.pool) \
             if self.prefix_cache_on else None
         self._sched = Scheduler(self.pool, self.max_batch,
@@ -940,35 +943,32 @@ class DecodeEngine(object):
                          int((lens[:len(batch)] >= window).sum()))
                 _obs.record('decode.step_window_tokens',
                             int(np.minimum(lens, window).sum()))
-            if self.spec.index_topk:
-                self._count_selection(lens[:len(batch)] + 1)
+            self._count_cache_reads(lens[:len(batch)] + 1)
         return lens, tables, temps, seeds
 
-    def _count_selection(self, seen):
-        """What one decode step's attention has to read of a latent
-        cache, from the rows' own lengths (``seen`` [rows]: the
-        positions each live row holds, its new token's included), summed
-        over the layers of a kind: a full layer's indexer scores every
-        position's key and its attention reads the ``index_topk`` it
-        keeps; a sliding layer reads its window."""
-        spec = self.spec
-        kept = np.minimum(seen, spec.index_topk)
-        n_full = len(spec.layers_of(FULL))
-        n_sliding = len(spec.layers_of(SLIDING))
-        _obs.inc('decode.sparse_positions_seen', n_full * int(seen.sum()))
-        _obs.inc('decode.sparse_positions_selected',
-                 n_full * int(kept.sum()))
-        _obs.inc('decode.sparse_rows', len(seen))
-        _obs.inc('decode.sparse_rows_live',
-                 int((seen > spec.index_topk).sum()))
-        for kind, positions in (
-                ('lm_latent_full', n_full * int(kept.sum())),
-                ('lm_index_full', n_full * int(seen.sum())),
-                ('lm_latent_sliding', n_sliding * int(np.minimum(
-                    seen, spec.sliding_window).sum()))):
-            if kind in self._row_bytes:
-                _obs.inc('decode.cache_bytes_read',
-                         positions * self._row_bytes[kind], kind=kind)
+    def _count_cache_reads(self, seen):
+        """What one decode step's attention has to read of the paged
+        cache, by kind, from the rows' own lengths (``seen`` [rows]: the
+        positions each live row holds, its new token's included) and
+        what each kind says a layer reads of them (``CacheKind.reads``:
+        the positions a selection keeps, a window, or all), at the row's
+        own width; and, where full layers select, how far the selection
+        is live."""
+        total = int(seen.sum())
+        for kind, row_bytes, caps in self._kind_reads:
+            positions = sum(
+                n * (int(np.minimum(seen, cap).sum()) if cap else total)
+                for cap, n in caps)
+            _obs.inc('decode.cache_bytes_read', positions * row_bytes,
+                     kind=kind)
+        topk = self.spec.index_topk
+        if topk:
+            n_full = len(self.spec.layers_of(FULL))
+            _obs.inc('decode.sparse_positions_seen', n_full * total)
+            _obs.inc('decode.sparse_positions_selected',
+                     n_full * int(np.minimum(seen, topk).sum()))
+            _obs.inc('decode.sparse_rows', len(seen))
+            _obs.inc('decode.sparse_rows_live', int((seen > topk).sum()))
 
     def _count_attn_pages(self, lens, rows, k1):
         """How far the attention's bounds engage: the pages this step's

@@ -52,24 +52,30 @@ SLIDING, FULL = 'sliding_attention', 'full_attention'
 
 
 class CacheKind(collections.namedtuple(
-        'CacheKind', ['name', 'slot', 'layers', 'width'])):
+        'CacheKind', ['name', 'slot', 'layers', 'width', 'reads',
+                      'shared'])):
     """One arena of the paged cache: its name, the op's input slot, the
-    layers that keep it (in order) and the elements a token's row
-    holds."""
+    layers that keep it (in order), the elements a token's row holds,
+    per layer of ``layers`` the most positions of a sequence one decode
+    step's attention reads there (``reads``; 0: every position held),
+    and whether the one row serves every head (``shared``: a latent
+    row, an index key) and not ``n_kv_head`` heads' rows side by side."""
 
     LANES = 128
 
     @property
     def stored(self):
         """The elements a row takes in the arena: ``width``, or where a
-        row is wider than a lane tile and not whole tiles (a latent
-        row: 576, 1,088), the next whole number of them. The TPU's
-        row-major tiling pads such a row to that anyway; left to itself
-        the compiler instead lays the *page* axis minor and re-lays the
-        whole arena at every program's entry and exit (v5e compile at the
-        published widths, PR 34), so the padding is made explicit and
-        written as zeros."""
-        if self.width <= self.LANES:
+        row all heads share is wider than a lane tile and not whole
+        tiles (a latent row: 576, 1,088), the next whole number of
+        them. The TPU's row-major tiling pads such a row to that anyway;
+        left to itself the compiler instead lays the *page* axis minor
+        and re-lays the whole arena at every program's entry and exit
+        (v5e compile at the published widths, PR 34), so the padding is
+        made explicit and written as zeros. Per-head rows are never
+        padded: the attention reads their head count off the arena's
+        width (ops/pallas/paged_attention.py)."""
+        if not self.shared or self.width <= self.LANES:
             return self.width
         return -(-self.width // self.LANES) * self.LANES
 
@@ -260,26 +266,32 @@ class LMSpec(object):
         page of every arena, and every layer keeps every token."""
         every = tuple(range(self.n_layer))
         if self.block != 'latent_moe':
+            reads = tuple(self.windows())
             return (CacheKind('lm_kcache', 'KCache', every,
-                              self.n_kv_head * self.d_key),
+                              self.n_kv_head * self.d_key, reads, False),
                     CacheKind('lm_vcache', 'VCache', every,
-                              self.n_kv_head * self.d_value))
+                              self.n_kv_head * self.d_value, reads, False))
         out = []
         if FULL in self.latent:
-            out += [CacheKind('lm_latent_full', 'LatentFull',
-                              self.layers_of(FULL),
-                              self.latent[FULL].row_width),
-                    CacheKind('lm_index_full', 'IndexFull',
-                              self.layers_of(FULL), self.index_head_dim)]
+            full = self.layers_of(FULL)
+            out += [CacheKind('lm_latent_full', 'LatentFull', full,
+                              self.latent[FULL].row_width,
+                              (self.index_topk,) * len(full), True),
+                    # the indexer scores every position to choose
+                    CacheKind('lm_index_full', 'IndexFull', full,
+                              self.index_head_dim, (0,) * len(full),
+                              True)]
         if SLIDING in self.latent:
+            sliding = self.layers_of(SLIDING)
             out.append(CacheKind('lm_latent_sliding', 'LatentSliding',
-                                 self.layers_of(SLIDING),
-                                 self.latent[SLIDING].row_width))
+                                 sliding, self.latent[SLIDING].row_width,
+                                 (self.sliding_window,) * len(sliding),
+                                 True))
         return tuple(out)
 
     def per_head_cache(self):
         """Whether a cached row is ``n_kv_head`` heads of K (or V)."""
-        return self.block != 'latent_moe'
+        return not any(kind.shared for kind in self.cache_kinds())
 
     def windows(self):
         """Per layer, the keys a query sees (0: all of them)."""

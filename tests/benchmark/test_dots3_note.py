@@ -57,7 +57,18 @@ NEW_METRICS = {
     'serve.sparse_live_row_share', 'serve.latent_prefill_chunks_per_prompt',
     'serve.latent_moe_local_assignment_pct', 'serve.latent_attn_busy_share',
     'serve.indexer_busy_share', 'serve.latent_moe_ffn_busy_share',
-    'serve.latent_attn_roofline_share', 'serve.indexer_roofline_share'}
+    'serve.latent_attn_roofline_share', 'serve.indexer_roofline_share',
+    # the shared readers of the worker, the batch, the tails, the load
+    # balance, the page bounds and the idle device, as entries of this
+    # cell's own (the older entries' lists are frozen)
+    'serve.latent_worker_prefill_share', 'serve.latent_worker_step_share',
+    'serve.latent_worker_idle_share', 'serve.latent_batch_occupancy',
+    'serve.latent_ttft_p90_ms', 'serve.latent_itl_p95_ms',
+    'serve.latent_tokens_per_s', 'serve.latent_moe_load_max_over_mean',
+    'serve.latent_idle_attributed_pct', 'serve.latent_idle_under_host_pct',
+    'serve.latent_idle_under_fetch_pct',
+    'serve.latent_idle_under_dispatch_pct',
+    'serve.latent_attn_pages_read_share'}
 
 
 def _module(kind, name):
@@ -340,6 +351,53 @@ def test_the_op_patterns_find_their_ops_and_not_each_others(resolved):
             assert hit == (line in wanted), (name, line)
         # the layer loop carries the arenas and lasts the whole program
         assert not any(re.search(p, loop) for p in patterns)
+
+
+def test_the_op_patterns_carry_the_configurations_geometry(resolved):
+    """The patterns name ops by their shapes, so the numbers in them are
+    the configuration's: the pool's pages, a sequence's capacity (whole
+    and in lane tiles), a stored latent row, a chunk, a column block, a
+    row block, the two head counts. A change of ``engine`` or of a width
+    that the patterns do not follow would read nothing; it fails here
+    first."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    config = resolved['config']
+    engine = config['engine']
+    spec = _module('runners', 'serve_latent').spec_of(config)
+    stored = {k.name: k.stored for k in spec.cache_kinds()}
+    capacity = engine['pages_per_seq'] * engine['block_size']
+    cols = engine['block_size'] * pa.pages_per_block(
+        engine['pages_per_seq'], engine['block_size'])
+    heads = '(%d|%d)' % (spec.latent[FULL].n_head,
+                         spec.latent[SLIDING].n_head)
+    rows = '(%d|%d)' % (engine['max_batch'], engine['prefill_chunk'])
+    arena = r'bf16\[%d,%d,%d,%d\]' % (
+        len(spec.layers_of(FULL)), engine['num_blocks'],
+        engine['block_size'], stored['lm_index_full'])
+    skip = r'^(?!%?(while|conditional|call)[.\d]*( |=)).*'
+    attn = [skip + r'bf16\[[\d,]*,(%d|%d)\]' % (
+                stored['lm_latent_full'], stored['lm_latent_sliding']),
+            skip + r'f32\[%d,1,%s,1,%d' % (pa.BLOCK_ROWS, heads, cols),
+            skip + r'f32\[%s,%d,%d\]' % (heads, engine['prefill_chunk'],
+                                         cols)]
+    index = [skip + arena,
+             skip + r'\[%s,(%d|%d,128)\]' % (rows, capacity,
+                                             capacity // 128),
+             skip + r'\[%d,1,%d\]' % (pa.BLOCK_ROWS, capacity),
+             skip + r'= f32\[%s,%d\]\S* fusion\(.*bf16\[%s,%d,%d\]' % (
+                 rows, cols, rows, spec.index_n_heads,
+                 spec.index_head_dim)]
+    experts = [skip + r'bf16\[%d,(%d|%d),(%d,%d|%d,%d)\]' % (
+        spec.n_layer - spec.dense_layers, spec.experts_held,
+        spec.n_shared_experts, spec.d_model, spec.d_inner, spec.d_inner,
+        spec.d_model)]
+    specs = {m['entry']['name']: m['spec'] for m in resolved['per_layer']}
+    for name, want in (('serve.latent_attn_busy_share', attn),
+                       ('serve.latent_attn_roofline_share', attn),
+                       ('serve.indexer_busy_share', index),
+                       ('serve.indexer_roofline_share', index),
+                       ('serve.latent_moe_ffn_busy_share', experts)):
+        assert specs[name]['args']['match'] == want, name
 
 
 # ---------------------------------------------------- the reference

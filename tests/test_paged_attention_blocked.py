@@ -29,6 +29,8 @@ LAYOUTS = {
     'plain_f32_d64': (4, 4, 64, 'float32'),
     'plain_bf16_d16': (4, 4, 16, 'bfloat16'),
     'grouped_bf16_d128': (8, 2, 128, 'bfloat16'),
+    # a K/V row of 192: wider than a lane tile and not whole tiles
+    'grouped_f32_3x64': (12, 3, 64, 'float32'),
     'int8_scales': (4, 4, 16, 'int8'),
     'fp8_scales': (4, 4, 16, 'float8_e4m3fn'),
 }

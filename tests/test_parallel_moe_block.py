@@ -83,8 +83,9 @@ def _block(spec=SPEC, weights=WEIGHTS):
 
 
 def _arenas(spec=SPEC):
-    shape = (spec.n_layer, NB, BS, spec.n_kv_head * spec.d_key)
-    return jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32)
+    # as wide as the engine makes them (model._arenas: CacheKind.stored)
+    return tuple(jnp.zeros((len(kind.layers), NB, BS, kind.stored),
+                           jnp.float32) for kind in spec.cache_kinds())
 
 
 def _prefill_chunk(block, kc, vc, table, tokens, start):
@@ -363,6 +364,32 @@ def test_chunked_prefill_then_decode_matches_full_forward(prompt_len,
         np.testing.assert_allclose(np.asarray(got)[0], want[t], atol=TOL)
 
 
+def test_kv_rows_wider_than_a_lane_tile_keep_their_width():
+    """12 query heads over 3 KV heads of 64: a K (or V) row is 192
+    wide, more than a lane tile and not whole tiles. The arena is as
+    wide as the row (only a row all heads share is stored in whole
+    tiles: the attention reads the KV heads off the width, and 256
+    would read as 4 heads), and the logits are the reference's."""
+    spec = _spec(n_layer=2, n_head=12, n_kv_head=3, d_key=64, d_value=64,
+                 layer_types=['sliding_attention', 'full_attention'])
+    assert [(k.width, k.stored, k.shared) for k in spec.cache_kinds()] \
+        == [(192, 192, False)] * 2
+    assert kv_bytes_per_token(spec, 'bfloat16') == 2 * 2 * 192 * 2
+    weights = random_weights(spec, seed=7)
+    rng = np.random.RandomState(7)
+    tokens = rng.randint(0, spec.vocab_size, 17)
+    want = _reference_logits(tokens, spec, weights)
+    block = _block(spec, weights)
+    kc, vc = _arenas(spec)
+    table = jnp.asarray(rng.permutation(NB)[:PAGES], jnp.int32)
+    got, kc, vc = _prefill_chunk(block, kc, vc, table, tokens[:16], 0)
+    np.testing.assert_allclose(np.asarray(got), want[:16], atol=TOL)
+    got, kc, vc, _ = _decode(block, kc, vc, table[None, :],
+                             jnp.asarray(tokens[16:], jnp.int32),
+                             jnp.asarray([16], jnp.int32))
+    np.testing.assert_allclose(np.asarray(got)[0], want[16], atol=TOL)
+
+
 def test_padded_chunk_rows_write_nothing():
     """A chunk padded to its bucket: the rows past ``length`` leave the
     arenas as they were, and the real rows' logits do not move."""
@@ -585,6 +612,14 @@ def test_engine_counts_the_row_tiles_its_programs_ran():
     assert 0 < touched <= run <= touched + per_program
     # one live row a step chooses at most 3 experts a layer
     assert touched <= steps * SPEC.n_layer * 3
+    # what those steps' attention had to read of K (and of V), from
+    # what the kinds say a layer reads (CacheKind.reads): the row holds
+    # 7, 8, 9, 10 positions with its new token's, three layers see the
+    # last 8 of them and the fourth all, 2 KV heads x 8 wide, float32
+    positions = 3 * (7 + 8 + 8 + 8) + (7 + 8 + 9 + 10)
+    for kind in ('lm_kcache', 'lm_vcache'):
+        assert grown('decode.cache_bytes_read{kind=%s}' % kind,
+                     prefill, after) == positions * 2 * 8 * 4
 
 
 def test_engine_keeps_declared_dtypes_and_device_arrays():
