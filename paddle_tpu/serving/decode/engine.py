@@ -51,6 +51,46 @@ Per-row device math is batch-composition-independent, so each
 request's token stream is bit-identical to running it alone —
 continuous batching, prefix caching, and speculation are pure
 throughput wins, never a correctness trade.
+
+**The order of a decode step: one step ahead.** The worker enqueues
+step n+1 before it fetches step n's tokens: build n+1 → enqueue n+1
+(the device→host copy of its tokens started with it) → fetch n → emit
+n, all under one ``decode.step`` span a program, so the device goes
+from one decode program to the next while the host builds, dispatches
+and wakes up. Everything step n+1 is fed but its tokens is known
+without step n's result (``Sequence.ahead``: each row's position is
+``cache_len + ahead``; a finish by ``max_new_tokens`` is known by
+count and leaves the row out); the tokens are step n's fetch, still on
+the device, its rows moved to their places in the new batch and a row
+that joined from a prefill given its first token from the host by one
+tiny jitted program (``_merge_tokens``; none at all when the batch did
+not change). The device runs programs in the order they were enqueued
+and the arenas chain through the donated scope, so the order of the
+writes is the synchronous one.
+
+- *A finish only the token shows (EOS)* is seen when step n is emitted,
+  with step n+1 already holding the row: that row's token of step n+1
+  is dropped, never emitted or counted. Its write landed in a page the
+  row still owned when the step was enqueued; the pages are released
+  at the finish, after that enqueue, so their next owner's programs
+  come later in the device's order.
+- *The pipeline is empty (depth 0: enqueue, fetch, emit, as before)
+  before anything but the next decode step is enqueued*, decided from
+  what the engine observes where a step ends (``_stays_in_flight``), no
+  flag: (1) a waiting request the scheduler would admit (a prefill);
+  (2) a row whose next page the pool cannot give without a victim (a
+  preemption: the step in flight is fetched before ``ensure_growth``
+  picks one); (3) ``spec_k > 0`` (acceptance decides the next feeds);
+  (4) ``read_pages`` / ``write_pages`` (under the arena lock they wait
+  for the step in flight to end on the device before they enqueue
+  theirs); (5) the end of the work: no row goes on, ``drain()``,
+  ``shutdown()``, a worker error.
+- ``decode.step_seconds`` is recorded once a decode program, where its
+  tokens arrive: arrival(n) − max(arrival(n−1), the instant step n's
+  dispatch began). Consecutive records tile the time the device spends
+  on decode steps and never overlap. ``decode.steps_ahead_total``
+  counts the steps enqueued while the one before was unfetched, beside
+  ``decode.steps_total``.
 """
 
 import collections
@@ -77,6 +117,19 @@ from .spec import NgramDraft, accept_drafts, spec_k_from_env
 __all__ = ['DecodeEngine', 'LMSpec']
 
 _ENGINE_IDS = itertools.count(1)
+
+# a step enqueued and not yet fetched: its rows in order, its fetches
+# where the device leaves them, the instant its dispatch began
+_Step = collections.namedtuple('_Step', 'batch tokens stats t0')
+
+
+def _merge_tokens(prev, src, host):
+    """The next step's tokens from the last step's, on the device: row i
+    is ``prev[src[i]]``, or ``host[i]`` where ``src[i]`` < 0 (a row that
+    joined from a prefill; a slot past the batch)."""
+    import jax.numpy as jnp
+    return jnp.where(src >= 0, prev[jnp.maximum(src, 0)],
+                     host.astype(prev.dtype))
 
 # the worker thread's four states: their spans feed one histogram whose
 # label sums partition the thread's wall time between start() and
@@ -239,6 +292,12 @@ class DecodeEngine(object):
         self._health_name = None
         self._step_no = 0
         self._step_stats = None
+        # the newest decode step enqueued whose tokens are unfetched
+        # (None at depth 0) and the instant the last step's arrived
+        self._ahead = None
+        self._t_arrival = 0.0
+        import jax
+        self._merge = jax.jit(_merge_tokens)
         self._prefill_stats = []    # (device MoeStats, program rows) a chunk
         self.warmup_signatures = 0
         self.warmup_total_seconds = 0.0
@@ -448,6 +507,7 @@ class DecodeEngine(object):
                   for i in range(0, n, pps)] or [[]]
         out = {}
         with self._arena_mu:
+            self._settle()
             for name in self._progs.arena_names:
                 arr = self._scope.get(name)
                 dest = None
@@ -499,6 +559,7 @@ class DecodeEngine(object):
             return
         pps = self.pages_per_seq
         with self._arena_mu:
+            self._settle()
             for name in self._progs.arena_names:
                 if name not in arrays:
                     raise KeyError('write_pages: missing arena %r'
@@ -519,6 +580,14 @@ class DecodeEngine(object):
                     arr = arr.at[:, jnp.asarray(ids_np)].set(
                         payload, mode='drop')
                     self._scope.set(name, arr)
+
+    def _settle(self):
+        """Wait until the decode step in flight, if any, has ended on
+        the device. The caller holds the arena lock, so the worker
+        enqueues nothing behind it meanwhile."""
+        step = self._ahead
+        if step is not None:
+            step.tokens.block_until_ready()
 
     # ---------------------------------------------------------- lifecycle
     def ready(self):
@@ -552,8 +621,9 @@ class DecodeEngine(object):
 
     def warmup(self):
         """AOT-compile every signature live traffic can produce: one
-        prefill per prompt bucket, the single decode-step key, and —
-        with speculation on — the single spec-verify key. Warmup feeds
+        prefill per prompt bucket, the single decode-step key with the
+        token merge of a step run ahead, and — with speculation on —
+        the single spec-verify key. Warmup feeds
         point every block-table entry past the pool (all writes drop),
         so device state is untouched. Returns the signature count."""
         t_all = time.perf_counter()
@@ -563,7 +633,11 @@ class DecodeEngine(object):
             _obs.record('decode.warmup_seconds',
                         time.perf_counter() - t0, kind='prefill', bucket=b)
         t0 = time.perf_counter()
-        np.asarray(self._dispatch_decode(*self._warm_args('decode')))
+        args = self._warm_args('decode')
+        out = self._dispatch_decode(*args)
+        # what a step run ahead of a changed batch takes its tokens by
+        np.asarray(self._merge(out, np.zeros((self.max_batch,), 'int32'),
+                               args[0]))
         _obs.record('decode.warmup_seconds', time.perf_counter() - t0,
                     kind='decode', bucket='')
         self.warmup_signatures = len(self.prompt_buckets) + 1
@@ -603,7 +677,8 @@ class DecodeEngine(object):
         deadline = None if timeout is None else \
             time.perf_counter() + timeout
         with self._done_cv:
-            while self._unfinished > 0:
+            # a row that ended on EOS leaves its last step in flight
+            while self._unfinished > 0 or self._ahead is not None:
                 wait = None if deadline is None else \
                     deadline - time.perf_counter()
                 if wait is not None and wait <= 0:
@@ -667,6 +742,11 @@ class DecodeEngine(object):
             self._mu.wait(timeout)
 
     def _worker(self):
+        """Admit and prefill what fits, then one decode program a
+        ``decode.step`` span. With a step in flight (the module
+        docstring has the order and the cases that empty the pipeline)
+        nothing is admitted: where that step was left in flight no
+        request was admittable, and the next step's end looks again."""
         try:
             while True:
                 with self._mu:
@@ -677,8 +757,13 @@ class DecodeEngine(object):
                     if self._closed and (
                             not self._draining or
                             (waiting == 0 and running == 0)):
+                        # shutdown(drain=False): its rows are failed, the
+                        # device is let finish what it was given
+                        self._settle()
+                        self._ahead = None
                         return
-                self._admit()
+                if self._ahead is None:
+                    self._admit()
                 if self._sched.running:
                     self._step_no += 1
                     with _obs.span('decode.step', record=_WORKER_SECONDS,
@@ -692,6 +777,7 @@ class DecodeEngine(object):
                         if not self._closed:
                             self._idle_wait(0.05)
         except BaseException as e:  # fail fast, loudly, and visibly
+            self._ahead = None
             self._broken = e
             _obs.inc('decode.worker_errors_total')
             _obs.flight_event('decode_worker_died', error=repr(e))
@@ -926,7 +1012,7 @@ class DecodeEngine(object):
         temps = np.zeros((mb,), 'float32')
         seeds = np.zeros((mb,), 'int32')
         for i, seq in enumerate(batch):
-            lens[i] = seq.cache_len
+            lens[i] = seq.cache_len + seq.ahead
             tables[i] = self._table_row(seq)
             temps[i] = seq.temperature
             seeds[i] = seq.seed
@@ -990,49 +1076,131 @@ class DecodeEngine(object):
         _obs.inc('decode.attn_pages_reachable',
                  self.spec.n_layer * len(pos) * self.pages_per_seq)
 
-    def _timed_step(self, dispatch, tokens, feeds, rows):
-        """Enqueue one step, then block on its fetch: ``(tokens on the
-        host, the instant they arrived)``. ``decode.step_seconds`` is
-        dispatch + fetch; the two spans split it where the enqueue
-        returns, so the fetch span ends on the profiler's clock at the
-        worker's wake-up after the device's last op."""
+    def _enqueue(self, dispatch, batch, tokens, feeds):
+        """Enqueue one step over ``batch`` and start the copy of its
+        fetches to the host, so a fetch of a step that has ended does
+        not sleep. Its rows are one step further ahead."""
         t0 = time.perf_counter()
+        self._step_stats = None
         with _obs.span('decode.step.dispatch',
-                       record='decode.step_dispatch_seconds', batch=rows):
+                       record='decode.step_dispatch_seconds',
+                       batch=len(batch)):
             out = dispatch(tokens, *feeds)
+            step = _Step(batch, out, self._step_stats, t0)
+            for fetched in (step.tokens, step.stats):
+                if fetched is not None:
+                    fetched.copy_to_host_async()
+        for seq in batch:
+            seq.ahead += 1
+        return step
+
+    def _fetch(self, step):
+        """Block on ``step``'s tokens: ``(tokens on the host, the
+        instant they arrived)``. ``decode.step_seconds`` runs from the
+        later of the last step's arrival and this step's dispatch to this
+        arrival, so the records of consecutive steps tile the worker's
+        time in steps and none spans two; the fetch span ends on the
+        profiler's clock at the worker's wake-up."""
         with _obs.span('decode.step.fetch',
                        record='decode.step_fetch_seconds'):
-            out = np.asarray(out)
+            out = np.asarray(step.tokens)
         now = time.perf_counter()
-        _obs.record('decode.step_seconds', now - t0)
-        _obs.record('decode.batch_occupancy', rows / float(self.max_batch))
+        _obs.record('decode.step_seconds',
+                    now - max(self._t_arrival, step.t0))
+        self._t_arrival = now
+        _obs.record('decode.batch_occupancy',
+                    len(step.batch) / float(self.max_batch))
         _obs.inc('decode.steps_total')
+        for seq in step.batch:
+            seq.ahead -= 1
+        if self._ahead is step:
+            self._ahead = None
+            if self._unfinished <= 0:
+                with self._done_cv:
+                    self._done_cv.notify_all()
         return out, now
 
+    def _next_rows(self):
+        """The running sequences the next decode step holds: those that
+        need a token beyond the steps already enqueued for them."""
+        return [seq for seq in self._sched.running
+                if seq.state is RUNNING and seq.continues()]
+
+    def _next_tokens(self, batch, prev):
+        """The next step's ``dec_tokens``: from the host where no row of
+        ``batch`` is in flight; else ``prev``'s fetch where it lies on
+        the device, as it is if the batch is ``prev``'s, its rows moved
+        and the joined rows' tokens merged in if not."""
+        host = np.zeros((self.max_batch,), 'int64')
+        src = np.full((self.max_batch,), -1, 'int32')
+        row = {seq: i for i, seq in enumerate(prev.batch)} if prev else {}
+        for i, seq in enumerate(batch):
+            if seq.ahead:
+                src[i] = row[seq]
+            else:
+                host[i] = seq.pending_token
+        if not (src >= 0).any():
+            return host
+        if len(batch) == len(prev.batch) and \
+                (src[:len(batch)] == np.arange(len(batch))).all():
+            return prev.tokens
+        return self._merge(prev.tokens, src, host)
+
+    def _stays_in_flight(self):
+        """Whether the step just enqueued may stay unfetched while the
+        worker builds the next one: some row goes on, nothing else
+        wants the device (a request the scheduler would admit), the
+        next feeds do not hang on this step's result (speculation) and
+        the engine is not being torn down."""
+        if self.spec_k > 0 or (self._closed and not self._draining):
+            return False
+        return bool(self._next_rows()) and not self._sched.admittable()
+
     def _decode_step(self):
+        """One decode program: build and enqueue step n+1, fetch and
+        emit step n if it is in flight, then fetch and emit n+1 too
+        unless it may stay in flight."""
         if self.spec_k > 0 and self._spec_step():
             return
+        prev = self._ahead
+        if prev is not None and not all(
+                self.pool.grow(seq.table, seq.cache_len + seq.ahead + 1)
+                for seq in self._next_rows()):
+            # a preemption comes: the pipeline empties first
+            self._emit_step(prev)
+            prev = None
         with _obs.span('decode.step.build',
                        record='decode.step_build_seconds'):
-            for seq in list(self._sched.running):
+            for seq in self._next_rows():
                 if seq.state is not RUNNING:
                     continue   # preempted as a victim earlier in this pass
                 self._sched.ensure_growth(seq)
-            batch = list(self._sched.running)
+            batch = self._next_rows()
             if not batch:
                 return
-            tokens = np.zeros((self.max_batch,), 'int64')
-            for i, seq in enumerate(batch):
-                tokens[i] = seq.pending_token
+            tokens = self._next_tokens(batch, prev)
             feeds = self._step_feeds(batch)
-        nxt, now = self._timed_step(self._dispatch_decode, tokens, feeds,
-                                    len(batch))
+        step = self._ahead = self._enqueue(self._dispatch_decode, batch,
+                                           tokens, feeds)
+        if prev is not None:
+            _obs.inc('decode.steps_ahead_total')
+            self._emit_step(prev)
+        if not self._stays_in_flight():
+            self._emit_step(step)
+
+    def _emit_step(self, step):
+        """Fetch one decode step and hand its tokens out. A row that
+        finished on the token of the step before (EOS) is held by this
+        step too: its token is dropped."""
+        nxt, now = self._fetch(step)
         nxt = nxt.reshape(-1)
         with _obs.span('decode.step.emit',
                        record='decode.step_emit_seconds'):
-            if self._step_stats is not None and _obs.enabled():
-                self._record_moe(np.asarray(self._step_stats), len(batch))
-            for i, seq in enumerate(batch):
+            if step.stats is not None and _obs.enabled():
+                self._record_moe(np.asarray(step.stats), len(step.batch))
+            for i, seq in enumerate(step.batch):
+                if seq.state is not RUNNING:
+                    continue
                 seq.cache_len += 1
                 self._maybe_publish(seq)
                 self._emit(seq, int(nxt[i]), now)
@@ -1104,9 +1272,10 @@ class DecodeEngine(object):
                 drafts.append(d)
                 tokens[i, 0] = seq.pending_token
                 tokens[i, 1:] = d
-            feeds = self._step_feeds([seq for seq, _ in pairs], k + 1)
-        nxt, now = self._timed_step(self._dispatch_verify, tokens, feeds,
-                                    len(pairs))
+            batch = [seq for seq, _ in pairs]
+            feeds = self._step_feeds(batch, k + 1)
+        nxt, now = self._fetch(
+            self._enqueue(self._dispatch_verify, batch, tokens, feeds))
         nxt = nxt.reshape(tokens.shape)
         _obs.inc('decode.spec_steps_total')
         with _obs.span('decode.step.emit',

@@ -30,7 +30,11 @@ Decode-position bookkeeping: ``cache_len`` counts KV entries
 materialized on device. After prefilling a prefix of length p the
 cache holds p entries and the sampled next token is *pending* (its KV
 is written by the decode step that consumes it), so while running
-``cache_len == len(prefix) + len(generated) - 1``.
+``cache_len == len(prefix) + len(generated) - 1``. ``ahead`` counts the
+decode steps that hold the sequence and whose token the worker has not
+fetched yet (engine.py runs one step ahead): the next step's position
+is ``cache_len + ahead``, and ``cache_len`` itself moves only when a
+token is emitted.
 """
 
 import collections
@@ -106,7 +110,7 @@ class Sequence(object):
                  'state', 'stream', 'cache_len', 'pending_token',
                  't_submit', 't_admit', 't_first_token', 't_last_token',
                  'preemptions', 'cached_len', 'published_pages', 'ctx',
-                 'tenant', 'priority', 'prio_rank')
+                 'tenant', 'priority', 'prio_rank', 'ahead')
 
     def __init__(self, request_id, prompt, max_new_tokens, temperature,
                  seed, eos_id, ctx=None, tenant=None, priority=None):
@@ -122,6 +126,7 @@ class Sequence(object):
         self.state = WAITING
         self.stream = GenerationStream(request_id, len(self.prompt))
         self.cache_len = 0
+        self.ahead = 0             # steps enqueued, token not fetched
         self.pending_token = None
         self.t_submit = time.perf_counter()
         self.t_admit = None
@@ -142,6 +147,11 @@ class Sequence(object):
         """Tokens whose KV must exist before the next decode step —
         after a preemption this is what re-prefills."""
         return self.prompt + self.generated
+
+    def continues(self):
+        """Whether the sequence needs a token beyond those of the steps
+        already enqueued for it: known by count, without their result."""
+        return len(self.generated) + self.ahead < self.max_new_tokens
 
     def finished(self):
         if len(self.generated) >= self.max_new_tokens:
@@ -194,6 +204,38 @@ class Scheduler(object):
             _obs.set_gauge('decode.running_seqs', r)
 
     # --------------------------------------------------------- admission
+    def _head(self):
+        """Index of the waiting sequence admission takes next: highest
+        class first, FIFO within the class — so the batch class only
+        backfills slots no latency-class request is waiting for
+        (all-equal priorities reduce to plain FIFO, including preempted
+        sequences requeued at the front). Caller holds the lock."""
+        idx, best = 0, self.waiting[0].prio_rank
+        if best > 0:
+            for i, s in enumerate(self.waiting):
+                if s.prio_rank < best:
+                    idx, best = i, s.prio_rank
+                    if best == 0:
+                        break
+        return idx
+
+    def admittable(self):
+        """Whether ``pop_admittable`` would admit a request now, with
+        nothing changed: a slot is free and the pool, with every page
+        the prefix cache holds (mapped on a hit or given back on a
+        miss), covers the head request's prefix plus one write. The
+        engine asks before it leaves a decode step in flight."""
+        with self._mu:
+            if len(self.running) >= self.max_batch or not self.waiting:
+                return False
+            seq = self.waiting[self._head()]
+        need = self.pool.blocks_for(
+            len(seq.prompt) + len(seq.generated) + 1) - len(seq.table)
+        have = self.pool.free_blocks()
+        if self.cache is not None:
+            have += self.cache.cached_pages()
+        return need <= have
+
     def pop_admittable(self):
         """Admit the next waiting sequence if a batch slot is free and
         the pool covers its prefill prefix plus one decode write. A
@@ -203,18 +245,7 @@ class Scheduler(object):
         with self._mu:
             if len(self.running) >= self.max_batch or not self.waiting:
                 return None
-            # priority admission: highest class first, FIFO within the
-            # class — so the batch class only backfills slots no
-            # latency-class request is waiting for (all-equal
-            # priorities reduce to plain FIFO, including preempted
-            # sequences requeued at the front)
-            idx, best = 0, self.waiting[0].prio_rank
-            if best > 0:
-                for i, s in enumerate(self.waiting):
-                    if s.prio_rank < best:
-                        idx, best = i, s.prio_rank
-                        if best == 0:
-                            break
+            idx = self._head()
             seq = self.waiting[idx]
             prefix = seq.prefix()
             if self.cache is not None and not seq.table.block_ids:
@@ -245,14 +276,15 @@ class Scheduler(object):
     # ----------------------------------------------------------- growth
     def ensure_growth(self, seq, need_tokens=None):
         """Make sure ``seq`` owns the pages its next decode write lands
-        in (``need_tokens`` positions — default one write; speculative
-        steps need cache_len + k + 1), preempting victims on
+        in (``need_tokens`` positions — default one write past the steps
+        already enqueued; speculative steps need cache_len + k + 1),
+        preempting victims on
         exhaustion. Cache-reclaimable pages are consulted first: grow
         only fails once the prefix cache's LRU evictor (the pool's
         reclaimer) has nothing left to give. False when ``seq`` itself
         was preempted (caller must drop it from this step)."""
         if need_tokens is None:
-            need_tokens = seq.cache_len + 1
+            need_tokens = seq.cache_len + seq.ahead + 1
         while not self.pool.grow(seq.table, need_tokens):
             _obs.inc('decode.pool_exhausted_total')
             _obs.flight_event('decode_pool_exhausted',
