@@ -58,8 +58,8 @@ step n+1 before it fetches step n's tokens: build n+1 → enqueue n+1
 n, all under one ``decode.step`` span a program, so the device goes
 from one decode program to the next while the host builds, dispatches
 and wakes up. Everything step n+1 is fed but its tokens is known
-without step n's result (``Sequence.ahead``: each row's position is
-``cache_len + ahead``; a finish by ``max_new_tokens`` is known by
+without step n's result (``Sequence.position()``: ``cache_len`` plus
+the steps ``ahead``; a finish by ``max_new_tokens`` is known by
 count and leaves the row out); the tokens are step n's fetch, still on
 the device, its rows moved to their places in the new batch and a row
 that joined from a prefill given its first token from the host by one
@@ -165,6 +165,7 @@ class DecodeEngine(object):
                  place=None, weights=None, prefix_cache=None, spec_k=None,
                  draft=None, kv_dtype=None, name=None, prefill_chunk=None,
                  min_prompt_bucket=1):
+        import jax
         from ...quant.core import resolve_kv_dtype
         from ...quant.core import kv_itemsize
         from .model import kv_bytes_per_kind, kv_bytes_per_token
@@ -296,7 +297,6 @@ class DecodeEngine(object):
         # (None at depth 0) and the instant the last step's arrived
         self._ahead = None
         self._t_arrival = 0.0
-        import jax
         self._merge = jax.jit(_merge_tokens)
         self._prefill_stats = []    # (device MoeStats, program rows) a chunk
         self.warmup_signatures = 0
@@ -1012,7 +1012,7 @@ class DecodeEngine(object):
         temps = np.zeros((mb,), 'float32')
         seeds = np.zeros((mb,), 'int32')
         for i, seq in enumerate(batch):
-            lens[i] = seq.cache_len + seq.ahead
+            lens[i] = seq.position()
             tables[i] = self._table_row(seq)
             temps[i] = seq.temperature
             seeds[i] = seq.seed
@@ -1087,9 +1087,9 @@ class DecodeEngine(object):
                        batch=len(batch)):
             out = dispatch(tokens, *feeds)
             step = _Step(batch, out, self._step_stats, t0)
-            for fetched in (step.tokens, step.stats):
-                if fetched is not None:
-                    fetched.copy_to_host_async()
+            out.copy_to_host_async()
+            if step.stats is not None and _obs.enabled():
+                step.stats.copy_to_host_async()   # read where observe is on
         for seq in batch:
             seq.ahead += 1
         return step
@@ -1164,7 +1164,7 @@ class DecodeEngine(object):
             return
         prev = self._ahead
         if prev is not None and not all(
-                self.pool.grow(seq.table, seq.cache_len + seq.ahead + 1)
+                self.pool.grow(seq.table, seq.position() + 1)
                 for seq in self._next_rows()):
             # a preemption comes: the pipeline empties first
             self._emit_step(prev)

@@ -148,6 +148,11 @@ class Sequence(object):
         after a preemption this is what re-prefills."""
         return self.prompt + self.generated
 
+    def position(self):
+        """Where the next step enqueued for the sequence writes: past the
+        cache and the steps already enqueued."""
+        return self.cache_len + self.ahead
+
     def continues(self):
         """Whether the sequence needs a token beyond those of the steps
         already enqueued for it: known by count, without their result."""
@@ -284,7 +289,7 @@ class Scheduler(object):
         reclaimer) has nothing left to give. False when ``seq`` itself
         was preempted (caller must drop it from this step)."""
         if need_tokens is None:
-            need_tokens = seq.cache_len + seq.ahead + 1
+            need_tokens = seq.position() + 1
         while not self.pool.grow(seq.table, need_tokens):
             _obs.inc('decode.pool_exhausted_total')
             _obs.flight_event('decode_pool_exhausted',

@@ -6,7 +6,7 @@ then enqueued, fetched and emitted before anything else, the order
 before this mechanism."""
 
 import contextlib
-
+import sys
 import threading
 import time
 
@@ -250,10 +250,13 @@ def test_a_page_read_waits_for_the_step_in_flight():
             pages = eng.read_pages([0, 1])
             assert set(pages) == set(eng._progs.arena_names)
     thread = threading.Thread(target=reader)
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)     # the reader cuts into every step
     thread.start()
     try:
         assert len(stream.result(120)) == 36
     finally:
+        sys.setswitchinterval(switch)
         done.set()
         thread.join(60)
     assert not thread.is_alive()
