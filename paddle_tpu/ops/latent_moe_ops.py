@@ -1,6 +1,7 @@
 """The latent_moe block of the paged decode ops (LMSpec
-block='latent_moe': dots3_note): latent attention under a learned sparse
-selection or a window, by layer kind, over three kinds of cache.
+block='latent_moe': dots3_note, kimi_k2_6): latent attention over every
+cached position, under a learned sparse selection or under a window, by
+layer kind and configuration, over up to three kinds of cache.
 
 A layer is ``h = x + Attn_kind(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``.
 
@@ -18,8 +19,23 @@ applied to that sum, ``out_h = (sum_s p c_kv(s)) W_UV,h``: K and V are
 never expanded, a decode step reads ``row width x itemsize`` bytes a
 position and nothing else of the cache
 (``ops/pallas/paged_attention.py``: the latent form of
-``_attend_blocks``). A sigmoid gate a head, from the layer's normed
-input, multiplies ``out_h`` before ``W_o``.
+``_attend_blocks``). Where the configuration has it (``attn_gate``) a
+sigmoid gate a head, from the layer's normed input, multiplies
+``out_h`` before ``W_o``.
+
+**Dense latent attention** (full layers with ``index_topk`` 0:
+kimi_k2_6) is that loop with nothing left out: a row sees every
+position at or below its own, through the one block table, column block
+by column block under the running softmax, in the decode step (many
+tables, one query each) and in a prefill chunk that starts at any
+offset (one table, the chunk's rows as one group: after a cached span
+of shared pages, after earlier chunks). There is no indexer, no index
+key and no index arena. **Rope scaling** (YaRN, per kind): the angle a
+position advances pair ``i`` by comes from the kind's frequency table
+(``LatentShape.rope_frequencies``: the fast pairs as they are, the slow
+ones stretched) and the softmax scale carries ``m^2``
+(``softmax_multiplier``); a kind without scaling computes the plain
+powers of theta as before, in the same program as before.
 
 **The selection** (full layers; the DeepSeek-V3.2 indexer). A token
 also caches an index key ``k^I = LayerNorm(x W^I_k)`` (its first
@@ -61,13 +77,13 @@ import jax.numpy as jnp
 
 from . import moe_held_ops as moe
 from .paged_decode_ops import (_attention_of, _mm, _rope_gptj,
-                               _write_in_place)
+                               _rope_gptj_at, _write_in_place)
 
 FULL, SLIDING = 'full_attention', 'sliding_attention'
 _TAG = {FULL: 'Full', SLIDING: 'Swa'}
 # op input slots: the attention of a kind (prefixed Full / Swa), the
 # full layers' indexer, the two FFNs
-_ATTN = ('QA', 'QLn', 'QB', 'KvA', 'KvLn', 'KvBK', 'KvBV', 'O', 'Gate')
+_ATTN = ('QA', 'QLn', 'QB', 'KvA', 'KvLn', 'KvBK', 'KvBV', 'O')
 _INDEX = ('IdxQ', 'IdxK', 'IdxKLnW', 'IdxKLnB', 'IdxW')
 _DENSE = ('DenseGate', 'DenseUp', 'DenseDown')
 _ROUTED = ('Router', 'RouterBias', 'ShrGate', 'ShrUp', 'ShrDown')
@@ -175,21 +191,29 @@ class LatentMoEBlock(object):
         self.index_heads = int(ctx.attr('index_n_heads', 0))
         self.index_topk = int(ctx.attr('index_topk', 0))
         self.rescale = bool(ctx.attr('lora_rescale', 1))
+        self.gated = bool(ctx.attr('attn_gate', 1))
+        self.routed_scale = float(ctx.attr('routed_scale', 1.0))
         self.plan = (tuple(ctx.attr('lead')), tuple(ctx.attr('period')),
                      int(ctx.attr('n_periods')), tuple(ctx.attr('tail')))
         kinds = set(self.plan[0] + self.plan[1] + self.plan[3])
         self.arena_slots = tuple(
             s for s in self.all_arena_slots
-            if (FULL if 'Full' in s else SLIDING) in kinds)
+            if (FULL if 'Full' in s else SLIDING) in kinds
+            and (s != 'IndexFull' or self.index_topk))
         self.shape, self.theta, self.w = {}, {}, {}
+        self.freq, self.softmax_mult = {}, {}
         for kind in kinds:
             tag = _TAG[kind].lower()
             self.shape[kind] = tuple(ctx.attr(tag + '_shape'))
             self.theta[kind] = float(ctx.attr(tag + '_theta'))
-            for slot in _ATTN:
+            # rope scaling: the pairs' frequencies as a table, m^2
+            self.freq[kind] = ctx.attr(tag + '_rope_freq', None)
+            self.softmax_mult[kind] = float(
+                ctx.attr(tag + '_softmax_mult', 1.0))
+            for slot in _ATTN + (('Gate',) if self.gated else ()):
                 self.w[_TAG[kind] + slot] = ctx.input(_TAG[kind] + slot)
         lead, period, n_periods, tail = self.plan
-        slots = (_INDEX if FULL in kinds else ()) + \
+        slots = (_INDEX if FULL in kinds and self.index_topk else ()) + \
             (_DENSE if lead else ()) + \
             (_ROUTED if period or tail else ())
         for slot in slots:
@@ -267,7 +291,16 @@ class LatentMoEBlock(object):
     def _attention(self, n, arenas, step, kind, i):
         heads, d_nope, d_rope = self.shape[kind]
         theta, pos = self.theta[kind], step.pos
-        w = {slot: _at(self.w[_TAG[kind] + slot], i) for slot in _ATTN}
+        w = {slot: _at(self.w[_TAG[kind] + slot], i)
+             for slot in _ATTN + (('Gate',) if self.gated else ())}
+        if self.freq[kind] is None:
+            def turned(x):
+                return _rope_gptj(x, pos, theta)
+        else:
+            freq = jnp.asarray(self.freq[kind], jnp.float32)
+
+            def turned(x):
+                return _rope_gptj_at(x, pos, freq)
         rows = n.shape[0]
         d_model, q_rank = w['QA'].shape
         rank = w['KvLn'].shape[0]
@@ -278,7 +311,7 @@ class LatentMoEBlock(object):
         q = _mm(c_q, w['QB']).reshape(rows, heads, d_nope + d_rope)
         down = _mm(n, w['KvA'])
         c_kv = rms_norm(down[:, :rank], w['KvLn'], self.eps) * s_kv
-        k_rope = _rope_gptj(down[:, None, rank:], pos, theta)[:, 0]
+        k_rope = turned(down[:, None, rank:])[:, 0]
         mine = [self.arena_slots.index(
             'LatentFull' if kind == FULL else 'LatentSliding')]
         # a row is stored in whole lane tiles (CacheKind.stored): the
@@ -290,16 +323,17 @@ class LatentMoEBlock(object):
                            q[..., :d_nope].astype(w['KvBK'].dtype),
                            w['KvBK'], preferred_element_type=jnp.float32)
         q_row = jnp.concatenate(
-            [q_abs, _rope_gptj(q[..., d_nope:], pos, theta),
+            [q_abs, turned(q[..., d_nope:]),
              jnp.zeros((rows, heads, spare), jnp.float32)], -1)
         new = [jnp.concatenate(
             [c_kv, k_rope, jnp.zeros((rows, spare), jnp.float32)], -1)]
         chosen, lo = None, None
-        if kind == FULL:
+        selects = kind == FULL and self.index_topk > 0
+        if selects:
             mine.append(self.arena_slots.index('IndexFull'))
             q_i, w_i, k_i = self._index_rows(n, c_q, i, pos, theta, d_rope)
             new.append(k_i)
-        else:
+        elif kind == SLIDING:
             # a query at position pos sees keys pos - window < j <= pos
             lo = jnp.maximum(pos + 1 - self.window, 0)
         held = tuple(arenas[a] for a in mine)
@@ -309,7 +343,7 @@ class LatentMoEBlock(object):
         arenas = list(arenas)
         for a, arena in zip(mine, held):
             arenas[a] = arena
-        if kind == FULL:
+        if selects:
             from .pallas.paged_attention import pages_per_block
             per = pages_per_block(step.tables.shape[-1], held[1].shape[2])
             chosen = select_topk(
@@ -317,11 +351,13 @@ class LatentMoEBlock(object):
                              per), self.index_topk)
         mixed = _attention_of(step.tables)(
             q_row, held[0], None, step.tables, step.lens,
-            sm_scale=(d_nope + d_rope) ** -0.5, layer=i, lo=lo,
+            sm_scale=(d_nope + d_rope) ** -0.5 * self.softmax_mult[kind],
+            layer=i, lo=lo,
             latent=rank, chosen=chosen)                     # [N, H, r]
         out = jnp.einsum('nhr,hrv->nhv', mixed.astype(w['KvBV'].dtype),
                          w['KvBV'], preferred_element_type=jnp.float32)
-        out = out * jax.nn.sigmoid(_mm(n, w['Gate']))[:, :, None]
+        if self.gated:
+            out = out * jax.nn.sigmoid(_mm(n, w['Gate']))[:, :, None]
         return _mm(out.reshape(rows, -1), w['O']), tuple(arenas)
 
     def _index_rows(self, n, c_q, i, pos, theta, d_rope):
@@ -354,7 +390,8 @@ class LatentMoEBlock(object):
         if valid is None:
             valid = jnp.ones((n.shape[0],), bool)
         chosen, weight = moe.route_sigmoid_topk(
-            n, w['Router'], self.top_k, bias=w['RouterBias'])
+            n, w['Router'], self.top_k, bias=w['RouterBias'],
+            scale=self.routed_scale)
         held = self.routed[0].shape[1]
         gate, hit = moe.held_gates(chosen, weight, self.first, held)
         m = moe.routed_experts(n, gate, hit, valid, min(self.top_k, held),

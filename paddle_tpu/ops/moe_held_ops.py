@@ -58,14 +58,15 @@ __all__ = ['route_sigmoid_topk', 'held_gates', 'gated_experts',
 TILE_ROWS = 128
 
 
-def route_sigmoid_topk(x, router, top_k, bias=None):
+def route_sigmoid_topk(x, router, top_k, bias=None, scale=1.0):
     """``x`` [N, D] float32, ``router`` [D, n_experts] -> (chosen
     [N, k] int32, weights [N, k] float32). Scores and weights in
     float32 at the highest matmul precision whatever the weights'
     dtype: a choice that flips moves a row's whole expert sum.
     ``bias`` [n_experts] (``noaux_tc``) is added to the scores for the
     choosing only: the weights are the chosen experts' own scores,
-    normalised."""
+    normalised, times ``scale`` (``routed_scaling_factor``: the routed
+    sum is scaled, the shared experts are not)."""
     scores = jax.nn.sigmoid(jnp.matmul(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
@@ -74,7 +75,8 @@ def route_sigmoid_topk(x, router, top_k, bias=None):
     else:
         _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
         top = jnp.take_along_axis(scores, chosen, axis=1)
-    return chosen, top / jnp.sum(top, axis=-1, keepdims=True)
+    weights = top / jnp.sum(top, axis=-1, keepdims=True)
+    return chosen, weights if scale == 1.0 else weights * scale
 
 
 def held_gates(chosen, weights, first, n_held):

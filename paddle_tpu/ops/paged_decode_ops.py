@@ -238,7 +238,14 @@ def _rope_gptj(x, pos, theta):
     """x [N, heads, D] float32 at positions ``pos`` [N]: interleaved
     pairs (2i, 2i+1) turned by pos * theta^(-2i/D)."""
     d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    return _rope_gptj_at(
+        x, pos, theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+
+
+def _rope_gptj_at(x, pos, inv):
+    """``_rope_gptj`` with pair i turned by pos * inv[i] (``inv``
+    [D / 2] float32: a frequency table, as rope scaling gives)."""
+    d = x.shape[-1]
     angle = pos.astype(jnp.float32)[:, None] * inv[None, :]
     cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
     pairs = x.reshape(x.shape[:-1] + (d // 2, 2))

@@ -39,13 +39,18 @@ workload — over a decoder-only LM with a paged KV cache:
 - **block families** (model.LMSpec ``block``): the 2017 post-LN block,
   the parallel routed-expert block (grouped KV heads, sliding and
   full layers in one cache, the experts held here of those the router
-  scores) and the latent block (latent attention under a learned
-  sparse selection or a window by layer kind, three kinds of cache
-  under the one block table: ``LMSpec.cache_kinds``) run through this
-  same engine; for the latter two the prefix cache, speculation and
-  quantized arenas raise rather than run untested, and for the latent
-  block the page handoff too. A prefix longer than the top prompt
-  bucket is prefilled in chunks of it (``prefill_chunk``).
+  scores) and the latent block (latent attention over every position,
+  under a learned sparse selection or under a window by layer kind, up
+  to three kinds of cache under the one block table:
+  ``LMSpec.cache_kinds``) run through this same engine. For the latter
+  two speculation and quantized arenas raise rather than run untested,
+  and for the latent block the page handoff too. The prefix cache runs
+  for a spec whose frozen pages another sequence may map
+  (``LMSpec.shares_frozen_pages``: 'post_ln', and a latent block all
+  of whose layers read every cached position); the others raise: their
+  logits with shared pages are held to no reference yet. A prefix
+  longer than the top prompt bucket is prefilled in chunks of it
+  (``prefill_chunk``), from wherever its cached span ends.
 
 Per-row device math is batch-composition-independent, so each
 request's token stream is bit-identical to running it alone —
@@ -188,9 +193,10 @@ class DecodeEngine(object):
         # unquantized engine; int8/fp8 halve-to-quarter bytes/token,
         # which is more resident sequences per chip at equal HBM).
         self.prefix_cache_on = prefix_cache_enabled(prefix_cache)
-        if self.prefix_cache_on and spec.block != 'post_ln':
-            # shared pages under a window have no test against these
-            # blocks' references yet
+        if self.prefix_cache_on and not spec.shares_frozen_pages():
+            # a spec with a windowed or a selected cache kind, and the
+            # parallel block: their logits with shared pages are held
+            # to no reference yet (LMSpec.shares_frozen_pages)
             raise NotImplementedError(
                 "block=%r runs without the prefix cache" % spec.block)
         self.spec_k = spec_k_from_env(spec_k)
@@ -648,7 +654,7 @@ class DecodeEngine(object):
                         time.perf_counter() - t0, kind='spec_verify',
                         bucket='')
             self.warmup_signatures += 1
-        if self.prefix_cache is not None:
+        if self.prefix_cache is not None and self.spec.per_head_cache():
             # pre-trace the KV-handoff page gather/scatter rungs so a
             # live handoff never compiles behind the arena lock (the
             # jax-level twin of the executor-signature warmup above);
@@ -943,9 +949,12 @@ class DecodeEngine(object):
             # the largest program this prefill runs (its first chunk's):
             # the label of its span, its time and its trace stage
             bucket = self._bucket(min(top, s - cached))
+            pairs = self._attn_pairs(cached, s) if _obs.enabled() else 0
+            seq.stream.cached_tokens = cached
         del self._prefill_stats[:]
         with _obs.span('decode.prefill.run', bucket=bucket,
-                       chunks=len(starts)):
+                       chunks=len(starts), cached_tokens=cached,
+                       attn_pairs=pairs):
             t0 = time.perf_counter()
             for start in starts:
                 piece = prefix[start:start + top]
@@ -966,6 +975,8 @@ class DecodeEngine(object):
         _obs.record('decode.prefill_chunk_seconds', (t1 - t0) / len(starts))
         _obs.inc('decode.prefills_total')
         _obs.inc('decode.prefill_chunks', len(starts))
+        _obs.inc('decode.prompt_tokens_total', s)
+        _obs.inc('decode.prefill_attn_pairs', pairs)
         with _obs.span('decode.prefill.emit'):
             if _obs.enabled():
                 # every chunk's program ended before the token was read
@@ -985,6 +996,23 @@ class DecodeEngine(object):
             reason = seq.finished()
             if reason:
                 self._finish(seq, reason)
+
+    def _attn_pairs(self, start, end):
+        """The (query, key at or below it) pairs the attention of a
+        prefill of positions ``start .. end - 1`` has to weigh, summed
+        over the layers: every key up to its own for a layer that
+        reads all, the last ``cap`` for one under a window or a
+        selection (``CacheKind.reads`` of the kind attended over: the
+        first a layer is in). What a FLOP count of the chunks'
+        attention starts from."""
+        seen = np.arange(start + 1, end + 1, dtype=np.int64)
+        caps = {}
+        for kind in self.spec.cache_kinds():
+            for layer, cap in zip(kind.layers, kind.reads):
+                caps.setdefault(layer, cap)
+        return int(sum(
+            n * int((np.minimum(seen, cap) if cap else seen).sum())
+            for cap, n in collections.Counter(caps.values()).items()))
 
     def _maybe_publish(self, seq):
         """Offer every newly frozen (full) page to the prefix cache.

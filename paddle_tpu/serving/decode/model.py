@@ -87,19 +87,77 @@ class LatentShape(object):
     d_rope`` a head; values ``d_v`` a head."""
 
     def __init__(self, n_head, q_rank, kv_rank, d_nope, d_rope, d_v,
-                 rope_theta):
+                 rope_theta, rope_scaling=None):
         self.n_head, self.q_rank, self.kv_rank = \
             int(n_head), int(q_rank), int(kv_rank)
         self.d_nope, self.d_rope, self.d_v = \
             int(d_nope), int(d_rope), int(d_v)
         self.rope_theta = float(rope_theta)
+        # a published ``rope_scaling`` group (type 'yarn'), or None
+        self.rope_scaling = dict(rope_scaling) if rope_scaling else None
         if self.d_rope % 2 or min(self.n_head, self.q_rank, self.kv_rank,
                                   self.d_nope, self.d_rope, self.d_v) < 1:
             raise ValueError('LatentShape: %r' % (vars(self),))
+        if self.rope_scaling and self.rope_scaling.get('type') != 'yarn':
+            raise ValueError('LatentShape: rope_scaling %r (yarn)'
+                             % (self.rope_scaling,))
 
     @property
     def row_width(self):
         return self.kv_rank + self.d_rope
+
+    def rope_frequencies(self):
+        """Per rotated pair ``i`` the angle a position advances it by:
+        ``theta^(-2i/d_rope)``, and under YaRN (``rope_scaling``, as
+        DeepSeek-V3's reference code computes it) that frequency kept
+        for the fast pairs, divided by ``factor`` for the slow ones and
+        blended linearly between pair ``low`` and pair ``high``, the
+        pairs that turn ``beta_fast`` and ``beta_slow`` times over the
+        ``original_max_position_embeddings``. None without scaling: the
+        programs then compute the plain powers themselves."""
+        if not self.rope_scaling:
+            return None
+        low, high = self.yarn_range()
+        half = self.d_rope // 2
+        plain = self.rope_theta ** (
+            -np.arange(half, dtype=np.float64) * 2 / self.d_rope)
+        ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                       / ((high - low) or 1e-3), 0.0, 1.0)
+        factor = float(self.rope_scaling['factor'])
+        return plain * (1 - ramp) + plain / factor * ramp
+
+    def yarn_range(self):
+        """(low, high): the first pair that is stretched at all and the
+        first that is stretched by the whole ``factor``."""
+        rs = self.rope_scaling
+        original = float(rs['original_max_position_embeddings'])
+
+        def pair_of(turns):
+            return self.d_rope * np.log(original / (2 * np.pi * turns)) \
+                / (2 * np.log(self.rope_theta))
+        return (max(int(np.floor(pair_of(float(rs.get('beta_fast', 32))))),
+                    0),
+                min(int(np.ceil(pair_of(float(rs.get('beta_slow', 1))))),
+                    self.d_rope - 1))
+
+    def softmax_multiplier(self):
+        """What the softmax scale ``(d_nope + d_rope)^-1/2`` is
+        multiplied by: ``m^2`` with ``m = 0.1 mscale_all_dim ln(factor)
+        + 1`` under YaRN with ``mscale_all_dim`` (the scores of
+        stretched positions are sharpened), else 1. The cos and sin
+        themselves carry ``mscale(factor, mscale) / mscale(factor,
+        mscale_all_dim)``, which this form requires to be 1."""
+        rs = self.rope_scaling
+        if not rs or not rs.get('mscale_all_dim') or \
+                float(rs['factor']) <= 1:
+            return 1.0
+        if float(rs.get('mscale', 1)) != float(rs['mscale_all_dim']):
+            raise ValueError('LatentShape: mscale %r and mscale_all_dim '
+                             '%r differ: cos and sin would be scaled'
+                             % (rs.get('mscale'), rs['mscale_all_dim']))
+        m = 0.1 * float(rs['mscale_all_dim']) * \
+            np.log(float(rs['factor'])) + 1.0
+        return float(m * m)
 
 
 class LMSpec(object):
@@ -130,22 +188,26 @@ class LMSpec(object):
     (float32 / bfloat16); the residual stream, the norms' statistics,
     the router, the softmax and the logits are float32.
 
-    ``block='latent_moe'`` (dots3_note): a serial pre-norm block,
-    ``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``. Attention
-    is latent (``latent``: {layer kind: LatentShape arguments}): a token
-    caches one row ``[c_kv ; k_rope]`` a layer that every head reads,
-    with a sigmoid gate a head on the output. ``full_attention`` layers
-    see the ``index_topk`` positions that a learned indexer
-    (``index_n_heads`` heads of ``index_head_dim``, whose key a token
-    also caches) scores highest, all of them below ``index_topk``;
-    ``sliding_attention`` layers see the last ``sliding_window``. The
-    first ``dense_layers`` have a gated SiLU FFN of ``d_inner_dense``,
-    the others the routed experts of ``parallel_moe`` with a
-    selection-only router bias and the shared experts at weight 1; an
-    output head of its own. ``lora_rescale`` multiplies the normed
-    latents by sqrt(d_model / rank). ``n_head``, ``n_kv_head``,
-    ``d_key``, ``d_value`` and ``rope_theta`` are unused: the shapes are
-    per kind."""
+    ``block='latent_moe'`` (dots3_note, kimi_k2_6): a serial pre-norm
+    block, ``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``.
+    Attention is latent (``latent``: {layer kind: LatentShape
+    arguments}, with the kind's rope scaling if it has one): a token
+    caches one row ``[c_kv ; k_rope]`` a layer that every head reads.
+    ``full_attention`` layers see every position at or below their own
+    where ``index_topk`` is 0 (dense latent attention: no indexer, no
+    index cache); with ``index_topk`` > 0 they see the ``index_topk``
+    positions that a learned indexer (``index_n_heads`` heads of
+    ``index_head_dim``, whose key a token also caches) scores highest,
+    all of them below ``index_topk``. ``sliding_attention`` layers see
+    the last ``sliding_window``. The first ``dense_layers`` have a
+    gated SiLU FFN of ``d_inner_dense``, the others the routed experts
+    of ``parallel_moe`` with a selection-only router bias, their sum
+    times ``routed_scale``, and the shared experts at weight 1; an
+    output head of its own. Two options a configuration has on or off:
+    ``attn_gate`` (a sigmoid gate a head on the attention's output) and
+    ``lora_rescale`` (the normed latents times sqrt(d_model / rank)).
+    ``n_head``, ``n_kv_head``, ``d_key``, ``d_value`` and ``rope_theta``
+    are unused: the shapes are per kind."""
 
     def __init__(self, vocab_size, n_layer=2, n_head=2, d_key=16,
                  d_value=16, d_model=32, d_inner=64, block='post_ln',
@@ -155,7 +217,7 @@ class LMSpec(object):
                  norm_eps=1e-5, logit_scale=1.0, dtype='float32',
                  latent=None, dense_layers=0, d_inner_dense=0,
                  index_n_heads=0, index_head_dim=0, index_topk=0,
-                 lora_rescale=True):
+                 lora_rescale=True, attn_gate=True, routed_scale=1.0):
         self.vocab_size = int(vocab_size)
         self.n_layer = int(n_layer)
         self.n_head = int(n_head)
@@ -186,6 +248,8 @@ class LMSpec(object):
         self.index_head_dim = int(index_head_dim)
         self.index_topk = int(index_topk)
         self.lora_rescale = bool(lora_rescale)
+        self.attn_gate = bool(attn_gate)
+        self.routed_scale = float(routed_scale)
         if self.block == 'post_ln':
             if self.n_kv_head != self.n_head:
                 raise ValueError("LMSpec: block='post_ln' has one KV head "
@@ -227,7 +291,7 @@ class LMSpec(object):
             raise ValueError('LMSpec: %d leading dense layers of width %d '
                              'in %d' % (self.dense_layers,
                                         self.d_inner_dense, self.n_layer))
-        if FULL in kinds and not (
+        if FULL in kinds and self.index_topk and not (
                 0 < self.index_topk and 0 < self.index_n_heads and
                 self.latent[FULL].d_rope <= self.index_head_dim):
             raise ValueError(
@@ -263,7 +327,10 @@ class LMSpec(object):
         token's cache is written down: the arenas' shapes, the bytes a
         token costs and the pages a budget buys are all read from it.
         Every kind is indexed by the one block table: a page id is a
-        page of every arena, and every layer keeps every token."""
+        page of every arena, and every layer keeps every token. A full
+        layer of the latent block keeps its latent rows, of which a
+        step reads ``index_topk`` (0, no selection: all of them), and
+        with a selection the indexer's keys beside them."""
         every = tuple(range(self.n_layer))
         if self.block != 'latent_moe':
             reads = tuple(self.windows())
@@ -274,13 +341,14 @@ class LMSpec(object):
         out = []
         if FULL in self.latent:
             full = self.layers_of(FULL)
-            out += [CacheKind('lm_latent_full', 'LatentFull', full,
-                              self.latent[FULL].row_width,
-                              (self.index_topk,) * len(full), True),
-                    # the indexer scores every position to choose
-                    CacheKind('lm_index_full', 'IndexFull', full,
-                              self.index_head_dim, (0,) * len(full),
-                              True)]
+            out.append(CacheKind('lm_latent_full', 'LatentFull', full,
+                                 self.latent[FULL].row_width,
+                                 (self.index_topk,) * len(full), True))
+            if self.index_topk:
+                # the indexer scores every position to choose
+                out.append(CacheKind('lm_index_full', 'IndexFull', full,
+                                     self.index_head_dim,
+                                     (0,) * len(full), True))
         if SLIDING in self.latent:
             sliding = self.layers_of(SLIDING)
             out.append(CacheKind('lm_latent_sliding', 'LatentSliding',
@@ -288,6 +356,23 @@ class LMSpec(object):
                                  (self.sliding_window,) * len(sliding),
                                  True))
         return tuple(out)
+
+    def shares_frozen_pages(self):
+        """Whether the prefix cache may map one sequence's frozen pages
+        into another's table. The property tested: in every cache kind
+        every layer's attention reads every position a row holds
+        (``CacheKind.reads`` all 0: no window, no selection), so a
+        page's rows are a pure function of the token chain from the
+        root and are read the same way by whoever maps them; and the
+        block's logits with shared pages are held to its reference
+        (tests/test_decode_serving.py for 'post_ln',
+        tests/test_kimi_k2_6_block.py for the dense 'latent_moe'). The
+        windowed and the selected kinds, and 'parallel_moe', have no
+        such test and stay refused (ROADMAP A8)."""
+        if self.block == 'post_ln':
+            return True
+        return self.block == 'latent_moe' and not any(
+            cap for kind in self.cache_kinds() for cap in kind.reads)
 
     def per_head_cache(self):
         """Whether a cached row is ``n_kv_head`` heads of K (or V)."""
@@ -460,9 +545,10 @@ def latent_param_shapes(spec):
                                      from_kv, slot + 'KvBV')),
             ('lm_%s_o.w' % tag, ([n, a.n_head * a.d_v, d],
                                  a.n_head * a.d_v, slot + 'O')),
-            ('lm_%s_gate.w' % tag, ([n, d, a.n_head], d, slot + 'Gate')),
         ])
-        if kind == FULL:
+        if spec.attn_gate:
+            out['lm_%s_gate.w' % tag] = ([n, d, a.n_head], d, slot + 'Gate')
+        if kind == FULL and spec.index_topk:
             hi, di = spec.index_n_heads, spec.index_head_dim
             out.update([
                 ('lm_full_idx_q.w', ([n, a.q_rank, hi * di], from_q,
@@ -534,12 +620,18 @@ def _block_attrs(spec, block_size):
             'window': spec.sliding_window,
             'index_n_heads': spec.index_n_heads,
             'index_topk': spec.index_topk,
-            'lora_rescale': int(spec.lora_rescale)})
+            'lora_rescale': int(spec.lora_rescale),
+            'attn_gate': int(spec.attn_gate),
+            'routed_scale': spec.routed_scale})
         for kind, tag in ((FULL, 'full'), (SLIDING, 'swa')):
             if kind in spec.latent:
                 a = spec.latent[kind]
                 attrs[tag + '_shape'] = [a.n_head, a.d_nope, a.d_rope]
                 attrs[tag + '_theta'] = a.rope_theta
+                if a.rope_scaling:
+                    attrs[tag + '_rope_freq'] = [
+                        float(f) for f in a.rope_frequencies()]
+                    attrs[tag + '_softmax_mult'] = a.softmax_multiplier()
     return attrs
 
 

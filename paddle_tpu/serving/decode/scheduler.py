@@ -62,12 +62,15 @@ class GenerationStream(object):
     Iterate for tokens as they are generated (``for tok in stream:``),
     or block for the whole thing with ``result(timeout)`` (the list of
     generated token ids, prompt excluded). ``finish_reason`` is
-    'eos' | 'max_tokens' | 'error' once done."""
+    'eos' | 'max_tokens' | 'error' once done. ``cached_tokens`` is how
+    many of the prompt's tokens the last prefill took from the prefix
+    cache's shared pages (None until the request is admitted)."""
 
     def __init__(self, request_id, prompt_len):
         self.request_id = request_id
         self.prompt_len = prompt_len
         self.finish_reason = None
+        self.cached_tokens = None
         self._q = _queue.Queue()
         self._future = Future()
         self._future.set_running_or_notify_cancel()

@@ -1,0 +1,488 @@
+"""The ``kimi_k2_6`` configuration and its cell, off the chip: the file
+holds the published config with the cut beside it, its parameters add up
+to the stated cut, the runner builds the block it describes, the
+sessions' schedule is a pure function that shares the heads it says, the
+shape function and the reader this PR brings do their arithmetic, the
+benchmark's copy of the plain reference is the repository's, and the
+cell rehearses end to end on the CPU. No test here describes a TPU
+topology."""
+
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from benchmark import manifest, sessions    # noqa: E402
+from benchmark import run as bench          # noqa: E402
+
+MANIFEST = manifest.load(REPO)
+CELL = 'kimi_k2_6.doc_qa_sessions'
+BENCH = os.path.join(REPO, 'benchmark')
+FULL = 'full_attention'
+
+# config.json of moonshotai/Kimi-K2.6, every number of it, as published
+# (the catalog row beside the model-configs guide)
+PUBLISHED = {
+    'hidden_size': 7168, 'intermediate_size': 18432,
+    'moe_intermediate_size': 2048, 'num_attention_heads': 64,
+    'num_key_value_heads': 64, 'q_lora_rank': 1536, 'kv_lora_rank': 512,
+    'qk_nope_head_dim': 128, 'qk_rope_head_dim': 64, 'v_head_dim': 128,
+    'rope_theta': 50000, 'num_experts_per_tok': 8, 'n_shared_experts': 1,
+    'first_k_dense_replace': 1, 'moe_layer_freq': 1, 'n_group': 1,
+    'topk_group': 1, 'routed_scaling_factor': 2.827, 'rms_norm_eps': 1e-05,
+    'max_position_embeddings': 262144, 'ep_size': 1,
+    'num_nextn_predict_layers': 0}
+STATED = {
+    'attention_bias': False, 'hidden_act': 'silu', 'model_type': 'kimi_k2',
+    'norm_topk_prob': True, 'scoring_func': 'sigmoid',
+    'tie_word_embeddings': False, 'topk_method': 'noaux_tc',
+    'rope_scaling': {'beta_fast': 32, 'beta_slow': 1, 'factor': 64,
+                     'mscale': 1, 'mscale_all_dim': 1,
+                     'original_max_position_embeddings': 4096,
+                     'type': 'yarn'}}
+CUT = {'num_hidden_layers': (6, 61), 'n_routed_experts': (12, 384),
+       'vocab_size': (20480, 163840)}
+NEW_METRICS = {
+    'serve.mla_attn_busy_share', 'serve.mla_decode_attn_roofline_share',
+    'serve.mla_prefill_attn_mxu_share', 'serve.mla_moe_ffn_busy_share',
+    'serve.mla_moe_local_assignment_pct', 'serve.mla_moe_load_max_over_mean',
+    'serve.prefix_tokens_reused_share', 'serve.prefix_hit_ttft_ms',
+    'serve.prefix_miss_ttft_ms', 'serve.prefix_evicted_pages',
+    'serve.mla_prefill_chunk_ms', 'serve.mla_decode_step_ms',
+    'serve.mla_queue_wait_ms', 'serve.mla_worker_prefill_share',
+    'serve.mla_kv_pool_used_pct', 'serve.mla_recompiles'}
+
+
+def _module(kind, name):
+    return manifest.load_module(os.path.join(BENCH, kind, name + '.py'))
+
+
+@pytest.fixture(scope='module')
+def resolved():
+    return manifest.resolve(MANIFEST, CELL)
+
+
+# ------------------------------------------------------- the files
+def test_the_cell_resolves_to_files_by_name(resolved):
+    assert manifest.problems(MANIFEST) == []
+    r = resolved
+    assert os.path.isfile(r['runner']) and os.path.isfile(r['reference'])
+    assert r['config']['runner'] == 'serve_sessions'
+    assert r['cell']['chips'] == 1 and r['cell']['traffic'] == \
+        'doc_qa_sessions'
+    assert r['config']['reference'] and r['config']['assumed']
+    assert 'rehearsal' in r['config'] and 'rehearsal' in r['traffic']
+    assert {e['name'] for e in r['end_to_end']} == {
+        'setup_s', 'ttft_mean_ms', 'itl_mean_ms'}
+    for metric in r['per_layer']:
+        assert os.path.isfile(metric['reader']) and metric['spec']['doc']
+    (entry,) = [c for c in MANIFEST['configs'] if c['name'] == 'kimi_k2_6']
+    assert entry['reduced'] == r['config']['reduced'] == list(CUT)
+    assert len(entry['source']) <= 200 and len(r['cell']['why']) <= 200
+    assert entry['source'].startswith(r['config']['source'])
+
+
+def test_the_cell_has_sixteen_metrics_of_its_own_and_joined_no_list(
+        resolved):
+    """Every per-layer metric of the cell is an entry of its own (the
+    frozen tests of PRs 25, 28 and 34 assert the older lists letter for
+    letter), sixteen of them, and the cell reports the two end-to-end
+    metrics under the bounds they have."""
+    mine = {m['entry']['name'] for m in resolved['per_layer']}
+    assert mine == NEW_METRICS and len(mine) == 16
+    for metric in MANIFEST['per_layer']:
+        listed = CELL in metric.get('workloads', [])
+        assert listed == (metric['name'] in NEW_METRICS)
+        if listed:
+            assert metric['workloads'] == [CELL]
+    for name in ('ttft_mean_ms', 'itl_mean_ms'):
+        (e,) = [e for e in MANIFEST['end_to_end'] if e['name'] == name]
+        assert CELL in e['workloads'] and e['bound'] == 0.1
+    e2e = {e['name'] for e in resolved['end_to_end']}
+    for metric in resolved['per_layer']:
+        assert metric['entry']['moves'] in e2e
+    # no share of a roofline or of a peak can be joined: none is open
+    for metric in MANIFEST['per_layer']:
+        if 'roofline' in metric['name'] or 'mfu' in metric['name']:
+            assert 'workloads' in metric
+
+
+@pytest.mark.parametrize('key', sorted(PUBLISHED))
+def test_config_holds_the_published_value(resolved, key):
+    assert resolved['config'][key] == PUBLISHED[key]
+
+
+@pytest.mark.parametrize('key', sorted(STATED))
+def test_config_holds_the_published_setting(resolved, key):
+    assert resolved['config'][key] == STATED[key]
+
+
+@pytest.mark.parametrize('key', sorted(CUT))
+def test_config_states_each_cut_beside_the_published_value(resolved, key):
+    config = resolved['config']
+    held, published = CUT[key]
+    assert config[key] == held and config['published'][key] == published
+    assert key in config['reduced']
+
+
+def test_config_is_the_catalog_row_but_for_the_cut(resolved):
+    """Where the catalog is installed: every key of its ``config`` is in
+    the file under the same name with the same value, but the three that
+    are cut."""
+    catalog = '/opt/skills/guides/model-configs/architectures.jsonl'
+    if not os.path.isfile(catalog):
+        pytest.skip('no catalog here')
+    with open(catalog) as f:
+        rows = [json.loads(line) for line in f if line.strip()]
+    (row,) = [r for r in rows if r['name'] == 'Kimi-K2.6']
+    config = resolved['config']
+    assert config['source'] == row['source_url']
+    differs = {k for k, v in row['config'].items() if config.get(k) != v}
+    assert differs == set(CUT)
+
+
+def test_config_states_the_deployment_and_what_it_assumes(resolved):
+    config = resolved['config']
+    assert '32' in config['deployment'] and config['first_expert'] == 0
+    # the guide's floors for a cut: the dense layer and four expert
+    # layers, 8 experts, 1/8 of the vocabulary
+    assert config['num_hidden_layers'] >= \
+        config['first_k_dense_replace'] + 4
+    assert config['n_routed_experts'] >= 8
+    assert config['vocab_size'] * 8 >= config['published']['vocab_size']
+    for word in ('scope', 'block', 'attention', 'rotary', 'router',
+                 'weights', 'precision', 'geometry', 'sampling'):
+        assert len(config['assumed'][word]) > 40 or word == 'sampling'
+    geometry = config['engine']
+    assert geometry['pages_per_seq'] * geometry['block_size'] >= \
+        geometry['max_prompt_len'] + 256
+    assert geometry['prefix_cache'] is True and geometry['spec_k'] == 0
+    limits = config['reference']
+    assert limits['long_requests'] >= 1 and limits['shared_requests'] >= 2
+    assert limits['long_tokens'] == 16384 and limits['requests'] == 5
+
+
+def test_parameters_add_up_to_the_stated_cut(resolved):
+    """ISSUE 36's arithmetic, recounted from the program's own parameter
+    table: attention 101.1 M a layer, a routed expert 44.04 M and 12 of
+    them 528.5 M, an expert layer 676.4 M, the dense layer 497.5 M,
+    embedding + head 293.6 M: 4.173 B, 8.35 GB in bfloat16."""
+    from paddle_tpu.serving.decode.model import block_param_shapes
+    spec = _module('runners', 'serve_sessions').spec_of(resolved['config'])
+    shapes = block_param_shapes(spec)
+
+    def millions(*prefixes):
+        return sum(int(np.prod(shape)) for name, (shape, _, _) in
+                   shapes.items() if name.startswith(prefixes)) / 1e6
+    n_layer, n_moe = 6, 5
+    attention = millions('lm_full_') / n_layer
+    assert round(attention, 1) == 101.1
+    np.testing.assert_allclose(
+        [7168 * 1536 / 1e6, 1536 * 64 * 192 / 1e6, 7168 * 576 / 1e6,
+         2 * 64 * 128 * 512 / 1e6, 64 * 128 * 7168 / 1e6],
+        [11.01, 18.87, 4.13, 8.39, 58.72], atol=0.005)
+    assert round(millions('lm_moe_shr_') / n_moe, 1) == 44.0
+    assert round(millions('lm_moe_router') / n_moe, 2) == 2.75
+    routed = millions('lm_moe_exp_') / n_moe
+    assert round(routed / 12, 2) == 44.04 and round(routed, 1) == 528.5
+    assert round(attention + millions('lm_moe_') / n_moe, 1) == 676.4
+    assert round(attention + millions('lm_dense_'), 1) == 497.5
+    assert round(millions('lm_emb', 'lm_head'), 1) == 293.6
+    total = sum(int(np.prod(shape)) for shape, _, _ in shapes.values())
+    assert round(total / 1e9, 3) == 4.173
+    assert round(total * 2 / 1e9, 2) == 8.35
+    assert 'lm_full_gate.w' not in shapes and \
+        not [n for n in shapes if 'idx' in n]
+    # the cache: one kind, 576 values a layer stored 640, 7,680 B a token
+    from paddle_tpu.serving.decode.model import (arena_bytes,
+                                                 kv_bytes_per_token)
+    assert kv_bytes_per_token(spec, 'bfloat16') == 7680
+    geometry = resolved['config']['engine']
+    assert round(arena_bytes(spec, geometry['num_blocks'],
+                             geometry['block_size'], 'bfloat16') / 1e9,
+                 2) == round(geometry['num_blocks'] * 32 * 7680 / 1e9, 2)
+
+
+# ------------------------------------------------------ the runner
+def test_runner_builds_the_block_the_config_describes(resolved):
+    runner = _module('runners', 'serve_sessions')
+    spec = runner.spec_of(resolved['config'])
+    assert (spec.block, spec.n_layer, spec.d_model, spec.d_inner,
+            spec.d_inner_dense, spec.dense_layers) == \
+        ('latent_moe', 6, 7168, 2048, 18432, 1)
+    assert spec.layer_types == (FULL,) * 6
+    assert spec.layer_plan() == ((FULL,), (FULL,), 5, ())
+    shape = spec.latent[FULL]
+    assert (shape.n_head, shape.q_rank, shape.kv_rank, shape.d_nope,
+            shape.d_rope, shape.d_v, shape.rope_theta) == \
+        (64, 1536, 512, 128, 64, 128, 50000.0)
+    assert shape.yarn_range() == (8, 20)
+    assert round(shape.softmax_multiplier(), 4) == 2.0047
+    assert (spec.index_topk, spec.lora_rescale, spec.attn_gate,
+            spec.routed_scale) == (0, False, False, 2.827)
+    assert (spec.n_experts, spec.experts_held, spec.first_expert,
+            spec.experts_per_token, spec.n_shared_experts) == \
+        (384, 12, 0, 8, 1)
+    assert spec.vocab_size == 20480 and spec.dtype == 'bfloat16'
+    assert [(k.name, k.layers, k.width, k.stored, k.reads)
+            for k in spec.cache_kinds()] == [
+        ('lm_latent_full', (0, 1, 2, 3, 4, 5), 576, 640, (0,) * 6)]
+    assert spec.shares_frozen_pages()
+    reference = _module('references', 'kimi_k2_6')
+    arch = reference.arch_of(spec)
+    assert arch['top_k'] == 8 and arch['routed_scale'] == 2.827
+    assert arch['yarn'] and arch['softmax_mscale'] and arch['scale_routed']
+    assert arch['latent']['rope_scaling']['factor'] == 64
+    assert reference.held_of(spec) == (0, 12)
+    for wrong in (dict(model_type='deepseek_v3'), dict(topk_method='greedy'),
+                  dict(tie_word_embeddings=True), dict(n_group=8),
+                  dict(rope_scaling=dict(STATED['rope_scaling'],
+                                         type='linear'))):
+        with pytest.raises(ValueError, match='not the block'):
+            runner.spec_of(dict(resolved['config'], **wrong))
+
+
+# ------------------------------------------------- the sessions' schedule
+def test_sessions_schedule_is_a_pure_function_of_its_arguments(resolved):
+    traffic = {k: v for k, v in resolved['traffic'].items()
+               if k != 'rehearsal'}
+    a, asks_a = sessions.schedule(traffic, 5, 51.0)
+    b, asks_b = sessions.schedule(traffic, 5, 51.0)
+    c, asks_c = sessions.schedule(traffic, 3600000036, 51.0)
+    assert a == b and asks_a == asks_b
+    # another seed: the same requests at the same instants, other tokens
+    assert [(r.due, r.prompt_len, r.answer_len) for r in a] == \
+        [(r.due, r.prompt_len, r.answer_len) for r in c]
+    assert [(k.document, k.ask, k.doc_len) for k in asks_a.values()] == \
+        [(k.document, k.ask, k.doc_len) for k in asks_c.values()]
+    assert [r.token_seed for r in a] != [r.token_seed for r in c]
+    assert [r.due for r in a] == sorted(r.due for r in a)
+    assert [r.index for r in a] == list(range(len(a)))
+    # the offered rate, exactly
+    preroll = traffic['preroll_s']
+    in_window = [r for r in a if r.due >= preroll]
+    assert len(in_window) == int(round(traffic['rate_rps'] * 51.0))
+    assert all(r.due < preroll + 51.0 for r in a)
+    # the ISSUE's floor and what the held sample needs of the trace
+    assert len(in_window) >= 40
+    assert any(asks_a[r.index].ask == 0 and
+               r.prompt_len + r.answer_len > 16384 for r in in_window)
+
+
+def test_sessions_share_the_heads_they_say(resolved):
+    traffic = {k: v for k, v in resolved['traffic'].items()
+               if k != 'rehearsal'}
+    requests, asks = sessions.schedule(traffic, 7, 51.0)
+    by_doc = {}
+    for r in requests:
+        by_doc.setdefault(asks[r.index].document, []).append(r)
+    vocab = 20480
+    lo, hi = traffic['asks']
+    for doc, rs in list(by_doc.items())[:4]:
+        ks = [asks[r.index] for r in rs]
+        assert [k.ask for k in ks] == list(range(len(ks))) and \
+            len(ks) <= hi
+        n = ks[0].doc_len
+        assert traffic['doc_len'][0] <= n <= traffic['doc_len'][1]
+        prompts = [sessions.prompt_tokens(r, k, vocab)
+                   for r, k in zip(rs, ks)]
+        for r, k, p in zip(rs, ks, prompts):
+            assert len(p) == r.prompt_len == n + k.question_len
+            assert p[:n] == prompts[0][:n] and max(p) < vocab
+            assert traffic['question_len'][0] <= k.question_len <= \
+                traffic['question_len'][1]
+        if len(prompts) > 1:     # questions are their own
+            assert prompts[0][n:n + 8] != prompts[1][n:n + 8]
+        # a later ask is due at least the floor after the one before
+        for before, after in zip(rs, rs[1:]):
+            assert after.due - before.due >= traffic['ask_gap_floor_s']
+    # two documents do not share a head
+    first = [sessions.prompt_tokens(rs[0], asks[rs[0].index], vocab)[:64]
+             for rs in list(by_doc.values())[:3]]
+    assert first[0] != first[1] != first[2]
+    share = sessions.shared_share(requests, asks, 32, traffic['preroll_s'])
+    assert 0.6 < share < 0.85
+
+
+# ------------------------------------------- the shape function and reader
+def test_prefill_attention_flops_are_the_expanded_forms(resolved):
+    fn = _module('shape_fns', 'mla_prefill_attn_flops')
+    config = resolved['config']
+    # a chunk of 512 queries at depth 16,384 in one layer
+    pairs = sum(range(16384 - 512 + 1, 16384 + 1))
+    got = fn.least_flops(pairs, config)
+    assert got == pairs * 64 * 320 * 2
+    # ISSUE 36: the absorbed chunk costs 1.17 TFLOP a layer, 3.4 x this
+    absorbed = 512 * 16384 * 64 * (576 + 512) * 2
+    assert round(absorbed / 1e12, 2) == 1.17
+    assert round((576 + 512) / 320.0, 1) == 3.4
+
+
+def test_prefill_reader_sets_flops_against_the_ops_under_the_same_spans(
+        resolved):
+    reader = _module('readers', 'prefill_ops_mxu')
+    attn = 'fusion.1 = f32[64,512,512]{2,1,0} fusion(...)'
+    other = 'fusion.2 = bf16[512,7168]{1,0} fusion(...)'
+    host = [('decode.prefill.run', 100, 800), ('decode.step', 950, 40),
+            ('decode.prefill.run', 1000, 500),
+            ('decode.prefill.run', 1800, 400)]     # straddles the end
+    device = [(attn, 150, 100), (other, 300, 50), (attn, 500, 200),
+              (attn, 960, 20),                      # a step's, not counted
+              (attn, 1100, 300), (attn, 1900, 50)]
+    sources = dict(
+        trace={'window': (0, 2000), 'first': device, 'host': host},
+        peaks={'flops_bf16': 1e9}, config=resolved['config'],
+        bench_dir=BENCH, prefill_attn_pairs_in_tail=[1000, 500])
+    spec = resolved_metric(resolved, 'serve.mla_prefill_attn_mxu_share')
+    got = reader.read(dict(spec['args'], match=[r'f32\[64,512,512\]']),
+                      sources)
+    want = 100.0 * (1500 * 64 * 320 * 2 / 1e9) / (600 / 1e9)
+    np.testing.assert_allclose(got, want)
+    # the two lists have to be of the same prefills, else nothing is read
+    assert reader.read(spec['args'], dict(
+        sources, prefill_attn_pairs_in_tail=[1000])) is None
+    # a program whose spans carry no count (the parent): nothing to read
+    assert reader.read(spec['args'], dict(
+        sources, prefill_attn_pairs_in_tail=None)) is None
+    assert reader.read(spec['args'], dict(sources, trace=None)) is None
+
+
+def resolved_metric(resolved, name):
+    (metric,) = [m for m in resolved['per_layer']
+                 if m['entry']['name'] == name]
+    return metric['spec']
+
+
+def test_trace_patterns_are_the_configs_numbers(resolved):
+    """The patterns of the device-trace readers name this cell's
+    geometry: the stored latent row, the heads, the column block, the
+    chunk, the expert stacks."""
+    config = resolved['config']
+    attn = resolved_metric(resolved, 'serve.mla_attn_busy_share')
+    text = ' '.join(attn['args']['match'])
+    spec = _module('runners', 'serve_sessions').spec_of(config)
+    (kind,) = spec.cache_kinds()
+    assert ',%d\\]' % kind.stored in text
+    heads, chunk = config['num_attention_heads'], \
+        config['engine']['prefill_chunk']
+    assert 'f32\\[%d,%d,512\\]' % (heads, chunk) in text
+    assert 'f32\\[8,1,%d,1,512' % heads in text
+    for name in ('serve.mla_decode_attn_roofline_share',
+                 'serve.mla_prefill_attn_mxu_share'):
+        assert resolved_metric(resolved, name)['args']['match'] == \
+            attn['args']['match']
+    ffn = resolved_metric(resolved, 'serve.mla_moe_ffn_busy_share')
+    assert 'bf16\\[%d,(%d|1),(%d,%d|%d,%d)\\]' % (
+        config['num_hidden_layers'] - 1, config['n_routed_experts'],
+        config['hidden_size'], config['moe_intermediate_size'],
+        config['moe_intermediate_size'], config['hidden_size']) \
+        in ffn['args']['match'][0]
+
+
+def test_the_benchmarks_reference_is_the_repositorys():
+    mine = os.path.join(REPO, 'paddle_tpu', 'models', 'reference',
+                        'kimi_k2_6.py')
+    with open(mine) as a, open(os.path.join(
+            BENCH, 'references', 'kimi_k2_6.py')) as b:
+        assert a.read() == b.read()
+    with open(mine) as f:
+        assert 'paddle_tpu' not in f.read().split('"""')[2]   # the code
+
+
+# ---------------------------------------------------- the rehearsal
+@pytest.fixture
+def own_environment(monkeypatch):
+    """benchmark/run.py turns the executor's cost probe off for its
+    process; in a test process that has to end with the test."""
+    monkeypatch.setenv('PADDLE_TPU_OBSERVE_COST', '0')
+
+
+def test_the_cell_rehearses_in_process(capsys, own_environment):
+    assert bench.main(['--workload', CELL, '--seed', '3600000036',
+                       '--seconds', '3', '--trace', '0',
+                       '--rehearsal']) == 0
+    lines = capsys.readouterr().out.splitlines()
+    last = json.loads(lines[-1])
+    window = json.loads([ln for ln in lines
+                         if ln.startswith('WINDOW ')][-1][7:])
+    assert last['rehearsal'] is True and last['correct'] is True
+    assert last['attempted'] > 10 and last['failed'] == 0
+    assert set(last['metrics']) == {'ttft_mean_ms', 'itl_mean_ms',
+                                    'setup_s'}
+    assert all(m['value'] is None for m in last['metrics'].values())
+    assert window['same_one_at_a_time'] is True and window['rechecked'] == 2
+    assert window['reference_gap_max'] <= 1e-4
+    # the sample holds a long first ask and two asks from shared pages
+    assert window['held_long_first_asks'] == 1
+    assert window['held_shared_later_asks'] == 2
+    assert sum(1 for c in window['held_cached_tokens'] if c) >= 2
+    assert window['reference_longest_tokens'] > 32
+    assert window['refused'] == 0 and window['compiles_in_window'] == 0
+    # what the engine served from shared pages is what the schedule says
+    assert abs(window['cached_share_of_prompt_tokens']
+               - window['schedule_shared_share']) < 0.02
+    assert 'pacer_late_ms_max' in window
+
+
+def test_the_traced_rehearsal_reads_the_counters_this_pr_adds(
+        capsys, own_environment):
+    """Under --trace 1 the program's counters reach the line: the share
+    of prompt tokens from shared pages, no eviction, no recompile, the
+    local share of 3 held of 8."""
+    assert bench.main(['--workload', CELL, '--seed', '2147483683',
+                       '--seconds', '3', '--trace', '1',
+                       '--rehearsal']) == 0
+    lines = capsys.readouterr().out.splitlines()
+    last = json.loads(lines[-1])
+    window = json.loads([ln for ln in lines
+                         if ln.startswith('WINDOW ')][-1][7:])
+    got = {k: v['value'] for k, v in last['metrics'].items()}
+    assert last['correct'] is True
+    assert abs(got['serve.prefix_tokens_reused_share']
+               - 100 * window['schedule_shared_share']) < 10
+    assert got['serve.prefix_evicted_pages'] == 0
+    assert got['serve.mla_recompiles'] == 0
+    assert 0 < got['serve.mla_moe_local_assignment_pct'] <= 100
+    assert 0 < got['serve.mla_kv_pool_used_pct'] <= 100
+    assert 'serve.mla_decode_attn_roofline_share' not in got   # no device
+    assert 'serve.mla_prefill_attn_mxu_share' not in got
+
+
+def test_the_fault_probe_rehearses(capsys):
+    """benchmark/probe_session_faults.py at the toy size: plain rope, a
+    softmax scale without m^2, an unscaled routed sum and a suffix at
+    positions counted from 0 each fail the cell's limits; the bfloat16
+    state shows least (the CPU logits tests hold it)."""
+    from benchmark import probe_session_faults as probe
+    assert probe.main(['--workload', CELL, '--rehearsal', '--seed', '5',
+                       '--lengths', '72', '--rows', '24', '--suffix', '12',
+                       '--faults', 'yarn,softmax_mscale,scale_routed,'
+                       'offset,state']) == 0
+    lines = [json.loads(ln[8:]) for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith('PLANTED ')]
+    assert [ln['fault'] for ln in lines] == [
+        'yarn', 'softmax_mscale', 'scale_routed', 'offset', 'state']
+    for line in lines[:4]:
+        assert line['within_limits'] is False and line['not_first'] > 0
+    assert lines[4]['logits_rms_diff'] < min(
+        ln['logits_rms_diff'] for ln in lines[:4])
+
+
+def test_the_sessions_sweep_rehearses(capsys):
+    from benchmark import sweep_sessions
+    assert sweep_sessions.main(['--workload', CELL, '--rehearsal',
+                                '--rates', '6,8', '--seconds', '3']) == 0
+    lines = [json.loads(ln[6:]) for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith('SWEEP ')]
+    assert [ln['rate_rps'] for ln in lines] == [6.0, 8.0]
+    for line in lines:
+        assert line['unfinished'] == 0 and line['refused'] == 0
+        assert line['requests'] == int(round(line['rate_rps'] * 3))
+        assert line['evictions'] == 0

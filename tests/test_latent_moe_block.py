@@ -187,7 +187,7 @@ def test_one_place_for_a_tokens_cache_bytes():
 @pytest.mark.parametrize('bad', [
     dict(latent={F: dict(n_head=4, q_rank=16, kv_rank=12, d_nope=8,
                          d_rope=4, d_v=8, rope_theta=8e7)}),
-    dict(index_topk=0), dict(dense_layers=6), dict(d_inner_dense=0)])
+    dict(index_n_heads=0), dict(dense_layers=6), dict(d_inner_dense=0)])
 def test_spec_refuses_what_it_cannot_run(bad):
     with pytest.raises(ValueError):
         _spec(**bad)

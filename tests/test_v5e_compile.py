@@ -167,6 +167,25 @@ SERVING = {
         dict(max_batch=32, block_size=32, pages_per_seq=528,
              num_blocks=2048, max_prompt_len=16384, prefill_chunk=512,
              min_prompt_bucket=512, kv_dtype='bfloat16')),
+    # the same block as kimi_k2_6 runs it: dense latent attention (no
+    # indexer, one arena) at the published rank, heads and head widths,
+    # YaRN's table and m^2, tables of 1,040 pages
+    'kimi_k2_6': (
+        dict(vocab_size=256, n_layer=2, d_model=256, d_inner=64,
+             block='latent_moe', layer_types=('full_attention',) * 2,
+             latent={'full_attention': dict(
+                 n_head=64, q_rank=1536, kv_rank=512, d_nope=128,
+                 d_rope=64, d_v=128, rope_theta=5e4, rope_scaling=dict(
+                     type='yarn', factor=64, beta_fast=32, beta_slow=1,
+                     mscale=1, mscale_all_dim=1,
+                     original_max_position_embeddings=4096))},
+             dense_layers=1, d_inner_dense=128, index_topk=0,
+             lora_rescale=False, attn_gate=False, routed_scale=2.827,
+             n_experts=8, experts_held=2, experts_per_token=2,
+             n_shared_experts=1, dtype='bfloat16'),
+        dict(max_batch=32, block_size=32, pages_per_seq=1040,
+             num_blocks=4096, max_prompt_len=33024, prefill_chunk=512,
+             min_prompt_bucket=512, kv_dtype='bfloat16')),
 }
 
 
