@@ -16,10 +16,23 @@ prefill and the suffix after a hit are both held to the reference on
 the chip. ``serve_block.serve`` draws its requests from
 ``loadgen.schedule`` inside itself, so the loop is written out here a
 third time (PERF.md section 7 asks a benchmark issue for hooks); the
-window, the pre-roll, the one-at-a-time check, the limits and what
-``correct`` means are its own (``within_limits``,
-``against_reference``), as are the reader of a stream (``poll``) and
-the drawing of the weights (``serve_latent.draw_weights``).
+window, the pre-roll, the limits and what ``correct`` means are its own
+(``within_limits``, ``against_reference``), as are the reader of a
+stream (``poll``) and the drawing of the weights
+(``serve_latent.draw_weights``).
+
+The one-at-a-time check is of the same computation, as it is in the
+other cells: a request served again alone has to take the programs it
+took in the window, and with the prefix cache on a request served again
+finds its own pages published and prefills a shorter suffix through a
+smaller bucket's program, whose bfloat16 products round otherwise (the
+first chip run of PR 36 read different tokens so, within the limits of
+the reference both times). So the check takes requests that found
+nothing cached (first asks), empties the cache before each and serves
+them alone: the same chunks from position 0, in another batch. What a
+hit serves is held to the reference (the sample's later asks); one hit
+served again through a deeper hit is reported beside it
+(``hit_again_same``), not judged.
 """
 
 import os
@@ -37,6 +50,25 @@ against_reference = _latent.against_reference
 draw_weights = _latent.draw_weights
 
 FULL = 'full_attention'
+# the served tokens' gaps are reported over this ladder, for the limits
+LADDER = (0.05, 0.1, 0.2, 0.3, 0.5, 0.75, 1.0)
+
+
+class _Recorder(object):
+    """The cell's reference with the gaps it returned kept, so that the
+    WINDOW line can say how they are spread; ``against_reference`` sees
+    the reference's own functions."""
+
+    def __init__(self, reference):
+        self._reference, self.gaps = reference, []
+
+    def __getattr__(self, name):
+        return getattr(self._reference, name)
+
+    def token_gaps(self, *args):
+        gaps, deviation = self._reference.token_gaps(*args)
+        self.gaps.extend(gaps)
+        return gaps, deviation
 
 
 def spec_of(config):
@@ -213,17 +245,32 @@ def serve(ctx, engine, traffic, config, signatures):
     miss = [r.ttft for r in good if not cached.get(r.request.index)]
     offered = sum(r.request.prompt_len for r in sample)
 
-    # the engine's invariant, on the chip: the same prompts one at a time
+    # the engine's invariant, on the chip: the same prompts one at a
+    # time through the same programs (module docstring)
     rng = np.random.RandomState(ctx.seed % (1 << 32))
     short = [r for r in good
              if r.request.answer_len <= traffic['recheck_max_answer']]
-    again = [short[i] for i in rng.permutation(len(short))[
-        :traffic['recheck_requests']]]
-    same = all(engine.generate(prompts[r.request.index],
+
+    def alone(r):
+        return engine.generate(prompts[r.request.index],
                                max_new_tokens=r.request.answer_len,
-                               timeout=600) == r.tokens for r in again)
+                               timeout=600) == r.tokens
+    hits = [r for r in short if cached.get(r.request.index)]
+    hit_again = alone(hits[rng.randint(len(hits))]) if hits else None
+    missed = [r for r in short if not cached.get(r.request.index)]
+    again = [missed[i] for i in rng.permutation(len(missed))[
+        :traffic['recheck_requests']]]
+    same = True
+    for r in again:
+        engine.prefix_cache.clear()
+        same = alone(r) and same
     held, n_long, n_shared = held_sample(good, asks, cached, limits, rng)
-    reference = against_reference(ctx, engine, config, prompts, held)
+    recorder = _Recorder(ctx.reference)
+    ctx.reference, kept = recorder, ctx.reference
+    try:
+        reference = against_reference(ctx, engine, config, prompts, held)
+    finally:
+        ctx.reference = kept
     agrees = reference['reference_agrees']
     # the window has to be held to the reference where it is hard: a long
     # first ask prefilled in chunks, and suffixes after shared pages
@@ -253,7 +300,10 @@ def serve(ctx, engine, traffic, config, signatures):
             'signatures': signatures, 'requests_sent': len(records),
             'refused': refused, 'errored': errored,
             'unfinished': unfinished, 'rechecked': len(again),
-            'same_one_at_a_time': same,
+            'same_one_at_a_time': same, 'hit_again_same': hit_again,
+            'reference_share_over': {
+                str(t): sum(1 for g in recorder.gaps if g > t)
+                / float(len(recorder.gaps) or 1) for t in LADDER},
             'held_long_first_asks': n_long,
             'held_shared_later_asks': n_shared,
             'held_cached_tokens': [cached.get(r.request.index, 0)

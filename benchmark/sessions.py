@@ -26,8 +26,8 @@ Arrival times and every length come from the traffic file's
 ``pool_seed`` alone, so every run and every ``--seed`` sends the same
 requests at the same instants; the seed fills documents and questions
 with other tokens. The gaps between openings are scaled by one factor,
-the one nearest the rate's own mean gap for which the requests due
-inside the window number exactly ``round(rate_rps x window_s)``: the
+the one nearest 1 (between a quarter and four) at which the requests
+due inside the window number exactly ``round(rate_rps x window_s)``: the
 offered rate, exactly, as ``loadgen._segment`` scales its gaps.
 """
 
@@ -42,7 +42,6 @@ Ask = collections.namedtuple(
 
 # documents drawn from the pool: more than any rate of a sweep opens
 POOL_DOCS = 512
-_SCALES = np.exp(np.linspace(np.log(0.25), np.log(4.0), 4001))
 
 
 def _drawn(traffic):
@@ -67,37 +66,54 @@ def _drawn(traffic):
             (POOL_DOCS, most))}
 
 
-def _due(drawn, scale, end_s):
-    """[(due, document, ask)] of every ask due before ``end_s`` with the
-    openings' gaps times ``scale``, in order of due time."""
-    opened = scale * (np.cumsum(drawn['gaps']) - drawn['gaps'][0])
-    out = []
-    for d in range(int(np.searchsorted(opened, end_s))):
-        due = opened[d]
-        for a in range(int(drawn['asks'][d])):
-            if a:
-                due = due + drawn['ask_gaps'][d, a]
-            if due < end_s:
-                out.append((float(due), d, a))
-    return sorted(out)
+def _openings(drawn, traffic, window_s):
+    """The openings' gaps in seconds: the rate's own mean gap times the
+    scale nearest 1 at which the asks due inside the window number
+    exactly ``round(rate_rps x window_s)``. An ask of document d is due
+    at ``scale x opening_d + its own gaps``, so it is inside the window
+    over one interval of scales; the count changes only where an
+    interval begins or ends, and every stretch between two such scales
+    is tried, the nearest to 1 first, at the point of it nearest 1."""
+    preroll = traffic['preroll_s']
+    end = preroll + window_s
+    target = int(round(traffic['rate_rps'] * window_s))
+    mean_gap = 1.0 / (traffic['rate_rps'] * traffic['docs_per_request'])
+    opened = mean_gap * (np.cumsum(drawn['gaps']) - drawn['gaps'][0])
+    later = np.cumsum(np.concatenate(
+        [np.zeros((POOL_DOCS, 1)), drawn['ask_gaps'][:, 1:]], axis=1), axis=1)
+    sent = np.arange(later.shape[1])[None, :] < drawn['asks'][:, None]
+    # document 0 opens at 0 whatever the scale
+    always = int(np.sum(sent[0] & (later[0] >= preroll) & (later[0] < end)))
+    first = ((preroll - later[1:]) / opened[1:, None])[sent[1:]]
+    last = ((end - later[1:]) / opened[1:, None])[sent[1:]]
+    edges = np.unique(np.concatenate([first, last, [0.25, 4.0]]))
+    edges = edges[(edges >= 0.25) & (edges <= 4.0)]
+    lo, hi = edges[:-1], edges[1:]
+    middle = (lo + hi) / 2
+    count = always + np.array([np.sum((first <= m) & (m < last))
+                               for m in middle])
+    at = np.clip(1.0, lo + (hi - lo) / 4, hi - (hi - lo) / 4)
+    fits = np.flatnonzero(count == target)
+    if not len(fits):
+        raise ValueError('sessions: no scale of the openings gives %d '
+                         'requests in the window' % target)
+    return opened * at[fits[np.argmin(np.abs(np.log(at[fits])))]]
 
 
 def schedule(traffic, seed, window_s):
     """(requests sorted by due time, {request index: Ask})."""
     drawn = _drawn(traffic)
-    preroll = traffic['preroll_s']
-    end = preroll + window_s
-    target = int(round(traffic['rate_rps'] * window_s))
-    mean_gap = 1.0 / (traffic['rate_rps'] * traffic['docs_per_request'])
-    chosen = None
-    for scale in sorted(_SCALES, key=lambda s: abs(np.log(s))):
-        due = _due(drawn, mean_gap * scale, end)
-        if sum(1 for t, _, _ in due if t >= preroll) == target:
-            chosen = due
-            break
-    if chosen is None:
-        raise ValueError('sessions: no scale of the openings gives %d '
-                         'requests in the window' % target)
+    end = traffic['preroll_s'] + window_s
+    opened = _openings(drawn, traffic, window_s)
+    chosen = []
+    for d in range(int(np.searchsorted(opened, end))):
+        due = opened[d]
+        for a in range(int(drawn['asks'][d])):
+            if a:
+                due = due + drawn['ask_gaps'][d, a]
+            if due < end:
+                chosen.append((float(due), d, a))
+    chosen.sort()
     tokens = np.random.RandomState(seed % (1 << 32))
     doc_seeds = tokens.randint(0, 1 << 31, POOL_DOCS)
     ask_seeds = tokens.randint(0, 1 << 31, drawn['asks'].shape[0] *

@@ -210,6 +210,14 @@ class DecodeEngine(object):
             (k.name, k.width * kv_itemsize(self.kv_dtype),
              sorted(collections.Counter(k.reads).items()))
             for k in spec.cache_kinds()]
+        # per cap on the positions a layer's attention weighs for one
+        # query (0: all), the layers that have it: a layer's is that of
+        # the kind it attends over, the first it is in
+        caps = {}
+        for k in spec.cache_kinds():
+            for layer, cap in zip(k.layers, k.reads):
+                caps.setdefault(layer, cap)
+        self._attn_caps = sorted(collections.Counter(caps.values()).items())
         self.draft = draft if draft is not None else \
             (NgramDraft() if self.spec_k > 0 else None)
         self._progs = build_lm_programs(spec, self.max_batch,
@@ -1002,17 +1010,12 @@ class DecodeEngine(object):
         prefill of positions ``start .. end - 1`` has to weigh, summed
         over the layers: every key up to its own for a layer that
         reads all, the last ``cap`` for one under a window or a
-        selection (``CacheKind.reads`` of the kind attended over: the
-        first a layer is in). What a FLOP count of the chunks'
-        attention starts from."""
+        selection (``CacheKind.reads``). What a FLOP count of the
+        chunks' attention starts from."""
         seen = np.arange(start + 1, end + 1, dtype=np.int64)
-        caps = {}
-        for kind in self.spec.cache_kinds():
-            for layer, cap in zip(kind.layers, kind.reads):
-                caps.setdefault(layer, cap)
         return int(sum(
             n * int((np.minimum(seen, cap) if cap else seen).sum())
-            for cap, n in collections.Counter(caps.values()).items()))
+            for cap, n in self._attn_caps))
 
     def _maybe_publish(self, seq):
         """Offer every newly frozen (full) page to the prefix cache.
