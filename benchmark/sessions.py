@@ -90,8 +90,9 @@ def _openings(drawn, traffic, window_s):
     edges = edges[(edges >= 0.25) & (edges <= 4.0)]
     lo, hi = edges[:-1], edges[1:]
     middle = (lo + hi) / 2
-    count = always + np.array([np.sum((first <= m) & (m < last))
-                               for m in middle])
+    # the intervals that have begun at a scale less those that have ended
+    count = always + np.searchsorted(np.sort(first), middle, 'right') \
+        - np.searchsorted(np.sort(last), middle, 'right')
     at = np.clip(1.0, lo + (hi - lo) / 4, hi - (hi - lo) / 4)
     fits = np.flatnonzero(count == target)
     if not len(fits):

@@ -192,6 +192,7 @@ class LatentMoEBlock(object):
         self.index_topk = int(ctx.attr('index_topk', 0))
         self.rescale = bool(ctx.attr('lora_rescale', 1))
         self.gated = bool(ctx.attr('attn_gate', 1))
+        self.attn_slots = _ATTN + (('Gate',) if self.gated else ())
         self.routed_scale = float(ctx.attr('routed_scale', 1.0))
         self.plan = (tuple(ctx.attr('lead')), tuple(ctx.attr('period')),
                      int(ctx.attr('n_periods')), tuple(ctx.attr('tail')))
@@ -210,7 +211,7 @@ class LatentMoEBlock(object):
             self.freq[kind] = ctx.attr(tag + '_rope_freq', None)
             self.softmax_mult[kind] = float(
                 ctx.attr(tag + '_softmax_mult', 1.0))
-            for slot in _ATTN + (('Gate',) if self.gated else ()):
+            for slot in self.attn_slots:
                 self.w[_TAG[kind] + slot] = ctx.input(_TAG[kind] + slot)
         lead, period, n_periods, tail = self.plan
         slots = (_INDEX if FULL in kinds and self.index_topk else ()) + \
@@ -292,15 +293,13 @@ class LatentMoEBlock(object):
         heads, d_nope, d_rope = self.shape[kind]
         theta, pos = self.theta[kind], step.pos
         w = {slot: _at(self.w[_TAG[kind] + slot], i)
-             for slot in _ATTN + (('Gate',) if self.gated else ())}
-        if self.freq[kind] is None:
-            def turned(x):
-                return _rope_gptj(x, pos, theta)
-        else:
-            freq = jnp.asarray(self.freq[kind], jnp.float32)
+             for slot in self.attn_slots}
 
-            def turned(x):
-                return _rope_gptj_at(x, pos, freq)
+        def turned(x):
+            if self.freq[kind] is None:
+                return _rope_gptj(x, pos, theta)
+            return _rope_gptj_at(
+                x, pos, jnp.asarray(self.freq[kind], jnp.float32))
         rows = n.shape[0]
         d_model, q_rank = w['QA'].shape
         rank = w['KvLn'].shape[0]
