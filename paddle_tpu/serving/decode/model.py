@@ -86,6 +86,8 @@ class LatentShape(object):
     share; queries through a rank-``q_rank`` bottleneck, ``d_nope +
     d_rope`` a head; values ``d_v`` a head."""
 
+    rope_scaling = None
+
     def __init__(self, n_head, q_rank, kv_rank, d_nope, d_rope, d_v,
                  rope_theta, rope_scaling=None):
         self.n_head, self.q_rank, self.kv_rank = \
@@ -93,8 +95,10 @@ class LatentShape(object):
         self.d_nope, self.d_rope, self.d_v = \
             int(d_nope), int(d_rope), int(d_v)
         self.rope_theta = float(rope_theta)
-        # a published ``rope_scaling`` group (type 'yarn'), or None
-        self.rope_scaling = dict(rope_scaling) if rope_scaling else None
+        if rope_scaling:
+            # a published ``rope_scaling`` group (type 'yarn'); a shape
+            # without one keeps the attributes it always had
+            self.rope_scaling = dict(rope_scaling)
         if self.d_rope % 2 or min(self.n_head, self.q_rank, self.kv_rank,
                                   self.d_nope, self.d_rope, self.d_v) < 1:
             raise ValueError('LatentShape: %r' % (vars(self),))
