@@ -220,8 +220,11 @@ def serve(ctx, engine, traffic, config, signatures):
     client = loadgen.drive(submit, poll, requests, t0, housekeeping)
     lo, hi = ctx.t_window, ctx.t_window + ctx.seconds
     loadgen.wait_until(hi, client.step)
+    # before end_window: it stops the profiler, which takes seconds in
+    # which prefills go on ending outside the traced window
+    t_end = time.perf_counter()
     ctx.end_window()
-    tail = prefills_in_tail(ctx, time.perf_counter())
+    tail = prefills_in_tail(ctx, t_end)
     if tail is not None:
         ctx.sources['prefill_attn_pairs_in_tail'] = tail
     unfinished = client.finish(hi + traffic['drain_s'])
