@@ -370,9 +370,13 @@ def test_trace_patterns_are_the_configs_numbers(resolved):
     spec = _module('runners', 'serve_sessions').spec_of(config)
     (kind,) = spec.cache_kinds()
     assert ',%d\\]' % kind.stored in text
-    heads, chunk = config['num_attention_heads'], \
-        config['engine']['prefill_chunk']
-    assert 'f32\\[%d,%d,512\\]' % (heads, chunk) in text
+    heads, geometry = config['num_attention_heads'], config['engine']
+    buckets, b = [], geometry['min_prompt_bucket']
+    while b <= geometry['prefill_chunk']:      # the prefill programs
+        buckets.append(str(b))
+        b *= 2
+    assert 'f32\\[(1,1,)?%d,(%s),512\\]' % (heads, '|'.join(buckets)) \
+        in text
     assert 'f32\\[8,1,%d,1,512' % heads in text
     for name in ('serve.mla_decode_attn_roofline_share',
                  'serve.mla_prefill_attn_mxu_share'):
