@@ -1,62 +1,165 @@
 """Share, in percent, of the chip's matrix peak that a family of ops
-reaches inside prefills: the least operations those prefills need,
+reaches inside prefill chunks: the least operations those chunks need,
 which a named shape function counts, over a peak, against the device
-time the ops took, both sides over the same prefills of the traced
-tail.
+time the ops took, both sides over the same chunks of the traced tail.
 
-The prefills: the worker's ``decode.prefill.run`` spans that lie whole
-inside the traced window. A prefill's chunks are enqueued back to back
-and only the last is waited for, inside that span, and the worker
-empties its decode pipeline before it admits, so every device op of a
-prefill starts under its span and nothing else does
-(``readers/step_ops_roofline.py`` is this for ``decode.step`` spans
-and bytes). The operations: the runner hands over, for the same spans
-in order, the (query, key) pairs each prefill's attention weighs
-(``sources['prefill_attn_pairs_in_tail']``, from the spans' own
-``attn_pairs``, which the trace's events do not keep); where the two
-lists differ in length (a span that straddles an edge by the two
-clocks' difference) or the program's spans carry no count, there is
-nothing to read. A counter over the window would not do: a 32k prefill
-takes longer than the traced tail, and whichever edge it straddles its
-count and its time would be of different chunks.
+Counted by chunk, not by prefill: a first ask's prefill of 65 chunks
+takes longer than the traced tail, so whichever edge it straddles, the
+chunks that ran inside the tail are read and the others are not. A
+chunk is one run of a prefill program on the device (an event of the
+trace's ``XLA Modules`` line, which ``tracelib.read_xplane`` does not
+keep: the runner reads it with ``program_runs`` before the trace is
+reduced and hands it over as ``sources['prefill_program_runs']``). The
+host enqueues a prefill's chunks back to back and waits for the last
+only, so a chunk's host span says nothing of when it ran; what holds is
+the order: the device runs the prefill programs in the order the worker
+dispatched them. The runner hands over every chunk the worker
+dispatched, in order (``sources['prefill_chunks']``: the prefill it is
+of, that prefill's ``decode.prefill.run`` span by the recorder's clock
+relative to the profiler's start, the chunk's bucket and its
+``attn_pairs``). One prefill whose span lies whole inside the tail
+anchors the two orders (its span in the trace, matched to the
+recorder's by start and duration; its first program run is its first
+chunk), and every other run is its neighbour's neighbour. Each matched
+run's program has to be its chunk's bucket's, and the runs under every
+whole span have to be that prefill's chunks, else nothing is read.
+
+What is not read is said: a line ``PREFILL_CHUNKS`` with the runs read
+and the runs of the tail that were dropped (cut by an edge of the
+window or by the profiler's stop, or with no chunk to match), and why
+where nothing was read (no prefill whole inside the tail: one prefill
+longer than it; a program whose spans carry no count: the parent).
 
 Nothing is clipped: a share above 100 means the operations are counted
 too high or the ops too few.
 args: {"function": shape function with least_flops(pairs, config),
-"match": [regex, ...], "peak": key of peaks.json}."""
+"match": [regex, ...], "program": regex whose group 1 is the bucket in
+a program run's name, "peak": key of peaks.json}."""
 
+import bisect
+import json
 import os
+import re
 
 from benchmark import manifest, tracelib
 
 SPAN = 'decode.prefill.run'
+MODULES_LINE = 'XLA Modules'
+# the recorder's clock against the trace's: the profiler's window span
+# opens a registry snapshot after the instant the runner took
+START_SLACK_S = 0.25
+DUR_SLACK_S = 0.001
 
 
-def span_op_ns(device, host, patterns, lo, hi):
-    """(nanoseconds of matching device ops that started under a prefill
-    span inside [lo, hi], the number of those spans)."""
-    spans = sorted((s, s + d) for name, s, d in host
+def program_runs(path):
+    """[(name, start_ns, dur_ns)] of the whole programs the first chip
+    ran, from the ``.xplane.pb`` at ``path``; [] where there is none."""
+    from jax.profiler import ProfileData
+    planes = {}
+    for plane in ProfileData.from_file(path).planes:
+        dev = tracelib.DEVICE_PLANE.match(plane.name)
+        if dev:
+            planes[int(dev.group(1))] = plane
+    if not planes:
+        return []
+    return sorted(
+        ((ev.name, int(ev.start_ns), int(ev.duration_ns))
+         for line in planes[min(planes)].lines if line.name == MODULES_LINE
+         for ev in line.events), key=lambda ev: ev[1])
+
+
+def anchor(chunks, whole, lo):
+    """(index in ``chunks`` of a prefill's first chunk, that prefill's
+    span in the trace) for the first span of ``whole`` that exactly one
+    dispatched prefill matches by start and duration, else None."""
+    firsts = {}
+    for i, c in enumerate(chunks):
+        firsts.setdefault(c['run'], i)
+    for s, e in whole:
+        near = [i for i in firsts.values()
+                if abs(chunks[i]['dur'] - (e - s) / 1e9) < DUR_SLACK_S
+                and abs(chunks[i]['t'] - (s - lo) / 1e9) < START_SLACK_S]
+        if len(near) == 1:
+            return near[0], (s, e)
+    return None
+
+
+def chunks_read(chunks, runs, host, window, program, last_op_start):
+    """({'read', 'dropped', 'why'}, [(pairs, start_ns, end_ns)] of the
+    program runs whole inside ``window`` with the chunk each is). The
+    profiler cuts the run it stops in to where it stopped, inside the
+    window's last millisecond: a run is whole only if the chip started
+    an op after it (``last_op_start``)."""
+    lo, hi = window
+    hi = min(hi, last_op_start)
+    rx = re.compile(program)
+    runs = [(rx.search(name), s, s + d) for name, s, d in runs]
+    runs = [(int(m.group(1)), s, e) for m, s, e in runs if m]
+    inside = [r for r in runs if r[2] > lo and r[1] < hi]
+    said = {'read': 0, 'dropped': len(inside), 'why': None}
+    if not chunks or any(c['pairs'] is None for c in chunks):
+        said['why'] = 'the program gave no count'
+        return said, []
+    whole = sorted((s, s + d) for name, s, d in host
                    if name == SPAN and s >= lo and s + d <= hi)
-    ns, i = 0, 0
-    for _, s, d in sorted(tracelib.matching(device, patterns),
-                          key=lambda ev: ev[1]):
-        while i < len(spans) and spans[i][1] <= s:
-            i += 1
-        if i < len(spans) and spans[i][0] <= s:
+    found = anchor(chunks, whole, lo)
+    if found is None:
+        said['why'] = 'no prefill whole inside the tail'
+        return said, []
+    first_chunk, (s, e) = found
+    starts = [r[1] for r in runs]
+    shift = first_chunk - bisect.bisect_left(starts, s)
+    out = []
+    for i, (bucket, s, e) in enumerate(runs):
+        if e <= lo or s >= hi or not 0 <= i + shift < len(chunks):
+            continue
+        chunk = chunks[i + shift]
+        if chunk['bucket'] != bucket:
+            said['why'] = 'run %d is prefill_%d, its chunk %d' % (
+                i, bucket, chunk['bucket'])
+            return said, []
+        if s >= lo and e <= hi:
+            out.append((chunk['pairs'], s, e, chunk['run']))
+    # the runs under a whole span are one prefill's chunks, all of them
+    for s, e in whole:
+        under = [c for c in out if s <= c[1] < e]
+        if len({c[3] for c in under}) != 1 or len(under) != sum(
+                1 for c in chunks if c['run'] == under[0][3]):
+            said['why'] = 'the runs under a span are not one prefill'
+            return said, []
+    said.update(read=len(out), dropped=len(inside) - len(out))
+    return said, [c[:3] for c in out]
+
+
+def op_ns(device, patterns, intervals):
+    """Nanoseconds of matching device ops that started inside one of the
+    disjoint ``intervals``."""
+    intervals = sorted(intervals)
+    starts = [s for s, _ in intervals]
+    ns = 0
+    for _, s, d in tracelib.matching(device, patterns):
+        i = bisect.bisect_right(starts, s) - 1
+        if i >= 0 and s < intervals[i][1]:
             ns += d
-    return ns, len(spans)
+    return ns
 
 
 def read(args, sources):
     trace, peaks = sources['trace'], sources['peaks']
-    pairs = sources.get('prefill_attn_pairs_in_tail')
-    if not trace or 'window' not in trace or peaks is None or not pairs:
+    runs = sources.get('prefill_program_runs')
+    if not trace or 'window' not in trace or peaks is None or not runs:
         return None
-    ns, spans = span_op_ns(trace['first'], trace['host'], args['match'],
-                           *trace['window'])
-    if not ns or spans != len(pairs):
+    said, chunks = chunks_read(
+        sources.get('prefill_chunks'), runs, trace['host'],
+        trace['window'], args['program'],
+        max([s for _, s, _ in trace['first']] or [0]))
+    print('PREFILL_CHUNKS %s' % json.dumps(said, sort_keys=True),
+          flush=True)
+    ns = op_ns(trace['first'], args['match'],
+               [(s, e) for _, s, e in chunks])
+    if not ns:
         return None
     least = manifest.load_module(os.path.join(
         sources['bench_dir'], 'shape_fns', args['function'] + '.py')
-    ).least_flops(sum(pairs), sources['config'])
+    ).least_flops(sum(pairs for pairs, _, _ in chunks), sources['config'])
     return 100.0 * (least / peaks[args['peak']]) / (ns / 1e9)

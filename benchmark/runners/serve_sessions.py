@@ -159,28 +159,50 @@ def held_sample(good, asks, cached, reference, rng):
         len(long_ones), len(shared)
 
 
-def prefills_in_tail(ctx, hi):
-    """[attention pairs] of the engine's ``decode.prefill.run`` spans
-    that lie whole inside the traced tail, in order: what
-    ``readers/prefill_ops_mxu.py`` sets against the device time under
-    the same spans of the trace. None in an untraced run or on a program
-    whose spans do not carry the count."""
-    if ctx.t_trace is None:
-        return None
+def chunks_dispatched(t_trace):
+    """Every prefill chunk the worker dispatched, in order, as
+    ``readers/prefill_ops_mxu.py`` takes them: the prefill it is of
+    (``run``), that prefill's ``decode.prefill.run`` span in seconds
+    from ``t_trace`` by the recorder's clock (``t``, ``dur``), the
+    chunk's ``bucket`` and its ``pairs`` (None on a program whose spans
+    carry no count). A prefill of one chunk has no chunk span: the
+    prefill's span is the chunk's."""
     from paddle_tpu import observe
     recorder = observe.spans()
-    zero = recorder._epoch0        # the recorder's ts are epoch + perf
-    out = []
-    for ev in recorder.events():
-        if ev.get('name') != 'decode.prefill.run':
-            continue
-        t0 = ev['ts'] / 1e6 - zero
-        if t0 >= ctx.t_trace and t0 + ev['dur'] / 1e6 <= hi:
-            pairs = (ev.get('args') or {}).get('attn_pairs')
-            if pairs is None:
-                return None
-            out.append(pairs)
+    events = sorted((ev for ev in recorder.events() if ev.get('name') in (
+        'decode.prefill.run', 'decode.prefill.chunk')),
+        key=lambda ev: ev['ts'])
+    out, run = [], -1
+    for ev in events:
+        args = ev.get('args') or {}
+        if ev['name'] == 'decode.prefill.run':
+            run += 1
+            span = {'run': run, 'dur': ev['dur'] / 1e6,
+                    't': recorder.perf_time(ev) - t_trace}
+            if args.get('chunks', 1) > 1:
+                continue
+        elif run < 0:
+            continue        # the ring lost this chunk's prefill
+        out.append(dict(span, bucket=args.get('bucket'),
+                        pairs=args.get('attn_pairs')))
     return out
+
+
+def hand_over_prefill_chunks(ctx):
+    """What ``readers/prefill_ops_mxu.py`` sets against each other, in a
+    traced run: the chunks as dispatched and the programs as the chip
+    ran them. The trace's directory is the harness's own; it reduces and
+    removes it after the runner returns."""
+    if ctx.t_trace is None:
+        return
+    from benchmark import tracelib
+    reader = manifest.load_module(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        'readers', 'prefill_ops_mxu.py'))
+    path = tracelib.find_xplane(getattr(ctx, '_trace_dir', ''))
+    if path:
+        ctx.sources['prefill_chunks'] = chunks_dispatched(ctx.t_trace)
+        ctx.sources['prefill_program_runs'] = reader.program_runs(path)
 
 
 def serve(ctx, engine, traffic, config, signatures):
@@ -220,18 +242,15 @@ def serve(ctx, engine, traffic, config, signatures):
     client = loadgen.drive(submit, poll, requests, t0, housekeeping)
     lo, hi = ctx.t_window, ctx.t_window + ctx.seconds
     loadgen.wait_until(hi, client.step)
-    # before end_window: it stops the profiler, which takes seconds in
-    # which prefills go on ending outside the traced window
-    t_end = time.perf_counter()
     ctx.end_window()
-    tail = prefills_in_tail(ctx, t_end)
-    if tail is not None:
-        ctx.sources['prefill_attn_pairs_in_tail'] = tail
     unfinished = client.finish(hi + traffic['drain_s'])
     records = client.records
     # what did not finish keeps the engine busy: the checks below need it
     # idle (a dispatch donates the arrays the reference reads)
     idle = engine.drain(timeout=traffic['drain_s'])
+    # once idle: every prefill that ran in the traced tail has closed
+    # its span, and reading the trace delays no token's time stamp
+    hand_over_prefill_chunks(ctx)
 
     sample = [r for r in records if r.request.due >= preroll]
     good = [r for r in sample if r.complete]

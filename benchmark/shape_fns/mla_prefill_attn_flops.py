@@ -7,8 +7,8 @@ over ``qk_nope_head_dim + qk_rope_head_dim`` and one weighted value of
 
 with ``pairs`` already summed over the layers (the engine counts them
 where it builds a prefill: the ``attn_pairs`` of its
-``decode.prefill.run`` span and the counter
-``decode.prefill_attn_pairs``; for kimi_k2_6 every layer reads every
+``decode.prefill.run`` span, of each chunk's ``decode.prefill.chunk``
+span, and the counter ``decode.prefill_attn_pairs``; for kimi_k2_6 every layer reads every
 position, so a prefill of positions a .. b - 1 has layers x sum_{t=a}^{b-1}
 (t + 1) of them). This is the expanded form's count (keys and values a
 head, 192 and 128 wide). No form does less: the absorbed form, which

@@ -226,6 +226,11 @@ class SpanRecorder(object):
         with self._lock:
             return list(self._events)
 
+    def perf_time(self, ev):
+        """``time.perf_counter()`` as it read when the recorded event
+        ``ev`` began (its ``ts`` is that on the recorder's epoch)."""
+        return ev['ts'] / 1e6 - self._epoch0
+
     def clear(self):
         with self._lock:
             self._events.clear()

@@ -34,8 +34,8 @@ key and no index arena. **Rope scaling** (YaRN, per kind): the angle a
 position advances pair ``i`` by comes from the kind's frequency table
 (``LatentShape.rope_frequencies``: the fast pairs as they are, the slow
 ones stretched) and the softmax scale carries ``m^2``
-(``softmax_multiplier``); a kind without scaling computes the plain
-powers of theta as before, in the same program as before.
+(``softmax_multiplier``); a kind without scaling has the plain powers
+of theta in its table and a multiplier of 1.
 
 **The selection** (full layers; the DeepSeek-V3.2 indexer). A token
 also caches an index key ``k^I = LayerNorm(x W^I_k)`` (its first
@@ -76,8 +76,8 @@ import jax
 import jax.numpy as jnp
 
 from . import moe_held_ops as moe
-from .paged_decode_ops import (_attention_of, _mm, _rope_gptj,
-                               _rope_gptj_at, _write_in_place)
+from .paged_decode_ops import (_attention_of, _mm, _rope_gptj_at,
+                               _write_in_place)
 
 FULL, SLIDING = 'full_attention', 'sliding_attention'
 _TAG = {FULL: 'Full', SLIDING: 'Swa'}
@@ -207,10 +207,11 @@ class LatentMoEBlock(object):
             tag = _TAG[kind].lower()
             self.shape[kind] = tuple(ctx.attr(tag + '_shape'))
             self.theta[kind] = float(ctx.attr(tag + '_theta'))
-            # rope scaling: the pairs' frequencies as a table, m^2
-            self.freq[kind] = ctx.attr(tag + '_rope_freq', None)
+            # the pairs' frequencies as a table (rope scaling or the
+            # plain powers), and m^2
+            self.freq[kind] = ctx.attr(tag + '_rope_freq')
             self.softmax_mult[kind] = float(
-                ctx.attr(tag + '_softmax_mult', 1.0))
+                ctx.attr(tag + '_softmax_mult'))
             for slot in self.attn_slots:
                 self.w[_TAG[kind] + slot] = ctx.input(_TAG[kind] + slot)
         lead, period, n_periods, tail = self.plan
@@ -296,8 +297,6 @@ class LatentMoEBlock(object):
              for slot in self.attn_slots}
 
         def turned(x):
-            if self.freq[kind] is None:
-                return _rope_gptj(x, pos, theta)
             return _rope_gptj_at(
                 x, pos, jnp.asarray(self.freq[kind], jnp.float32))
         rows = n.shape[0]

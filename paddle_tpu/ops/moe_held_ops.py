@@ -76,7 +76,7 @@ def route_sigmoid_topk(x, router, top_k, bias=None, scale=1.0):
         _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
         top = jnp.take_along_axis(scores, chosen, axis=1)
     weights = top / jnp.sum(top, axis=-1, keepdims=True)
-    return chosen, weights if scale == 1.0 else weights * scale
+    return chosen, weights * scale
 
 
 def held_gates(chosen, weights, first, n_held):

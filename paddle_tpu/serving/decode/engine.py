@@ -957,21 +957,26 @@ class DecodeEngine(object):
             # the largest program this prefill runs (its first chunk's):
             # the label of its span, its time and its trace stage
             bucket = self._bucket(min(top, s - cached))
-            pairs = self._attn_pairs(cached, s) if _obs.enabled() else 0
+            # each chunk's own count: a trace sets a chunk's attention
+            # against the program run that is that chunk
+            chunk_pairs = [self._attn_pairs(a, min(a + top, s))
+                           if _obs.enabled() else 0 for a in starts]
+            pairs = sum(chunk_pairs)
             seq.stream.cached_tokens = cached
         del self._prefill_stats[:]
         with _obs.span('decode.prefill.run', bucket=bucket,
                        chunks=len(starts), cached_tokens=cached,
                        attn_pairs=pairs):
             t0 = time.perf_counter()
-            for start in starts:
+            for start, its_pairs in zip(starts, chunk_pairs):
                 piece = prefix[start:start + top]
                 rung = self._bucket(len(piece))
                 ids = np.zeros((1, rung), 'int64')
                 ids[0, :len(piece)] = piece
                 # a prefix of one chunk is the span above and no more
                 chunk_span = _obs.span(
-                    'decode.prefill.chunk', bucket=rung, start=start) \
+                    'decode.prefill.chunk', bucket=rung, start=start,
+                    attn_pairs=its_pairs) \
                     if len(starts) > 1 else contextlib.nullcontext()
                 with chunk_span:
                     tok = self._run_prefill(

@@ -97,7 +97,9 @@ class LatentShape(object):
         self.rope_theta = float(rope_theta)
         if rope_scaling:
             # a published ``rope_scaling`` group (type 'yarn'); a shape
-            # without one keeps the attributes it always had
+            # without one keeps the attributes it always had (dots3_note's
+            # benchmark test holds ``vars()`` of its shapes letter for
+            # letter)
             self.rope_scaling = dict(rope_scaling)
         if self.d_rope % 2 or min(self.n_head, self.q_rank, self.kv_rank,
                                   self.d_nope, self.d_rope, self.d_v) < 1:
@@ -117,14 +119,14 @@ class LatentShape(object):
         for the fast pairs, divided by ``factor`` for the slow ones and
         blended linearly between pair ``low`` and pair ``high``, the
         pairs that turn ``beta_fast`` and ``beta_slow`` times over the
-        ``original_max_position_embeddings``. None without scaling: the
-        programs then compute the plain powers themselves."""
-        if not self.rope_scaling:
-            return None
-        low, high = self.yarn_range()
+        ``original_max_position_embeddings``. The programs take the
+        table either way."""
         half = self.d_rope // 2
         plain = self.rope_theta ** (
             -np.arange(half, dtype=np.float64) * 2 / self.d_rope)
+        if not self.rope_scaling:
+            return plain
+        low, high = self.yarn_range()
         ramp = np.clip((np.arange(half, dtype=np.float64) - low)
                        / ((high - low) or 1e-3), 0.0, 1.0)
         factor = float(self.rope_scaling['factor'])
@@ -632,10 +634,9 @@ def _block_attrs(spec, block_size):
                 a = spec.latent[kind]
                 attrs[tag + '_shape'] = [a.n_head, a.d_nope, a.d_rope]
                 attrs[tag + '_theta'] = a.rope_theta
-                if a.rope_scaling:
-                    attrs[tag + '_rope_freq'] = [
-                        float(f) for f in a.rope_frequencies()]
-                    attrs[tag + '_softmax_mult'] = a.softmax_multiplier()
+                attrs[tag + '_rope_freq'] = [
+                    float(f) for f in a.rope_frequencies()]
+                attrs[tag + '_softmax_mult'] = a.softmax_multiplier()
     return attrs
 
 

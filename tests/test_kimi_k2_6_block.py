@@ -191,9 +191,11 @@ def test_yarn_table_and_m_are_the_closed_form_at_the_published_numbers():
     attrs = lm._block_attrs(_spec(latent={F: dict(vars(shape))}), BS)
     np.testing.assert_allclose(attrs['full_rope_freq'], freq, rtol=1e-12)
     assert attrs['full_softmax_mult'] == shape.softmax_multiplier()
-    # a kind without scaling gives the programs no table
-    assert 'full_rope_freq' not in lm._block_attrs(_spec(latent={F: dict(
+    # a kind without scaling gives the programs the plain powers
+    unscaled = lm._block_attrs(_spec(latent={F: dict(
         vars(shape), rope_scaling=None)}), BS)
+    np.testing.assert_allclose(unscaled['full_rope_freq'], plain, rtol=1e-12)
+    assert unscaled['full_softmax_mult'] == 1.0
 
 
 def test_mscale_that_would_scale_cos_and_sin_is_refused():
@@ -466,6 +468,35 @@ def test_engine_counts_every_cached_position_and_the_chunks_pairs(engine):
     assert grown('decode.prefix_tokens_reused_total', middle, after) == 20
     assert grown('decode.prefill_attn_pairs', middle, after) == \
         4 * (21 + 22 + 23)
+
+
+def test_each_chunks_span_carries_its_own_pairs(engine):
+    """A prefill of several chunks (``prefill_chunk`` 16): every
+    ``decode.prefill.chunk`` span has the pairs of its own positions,
+    which add up to the ``decode.prefill.run`` span's; a prefill of one
+    chunk has the run's span alone."""
+    from paddle_tpu import observe
+    observe.enable()
+    try:
+        engine.prefix_cache.clear()
+        engine.generate(list(range(30, 70)), max_new_tokens=1, timeout=300)
+        engine.generate(list(range(80, 90)), max_new_tokens=1, timeout=300)
+        events = [ev for ev in observe.spans().events()
+                  if ev['name'].startswith('decode.prefill.')]
+    finally:
+        observe.disable()
+        observe.reset()
+    runs = [ev['args'] for ev in events if ev['name'] == 'decode.prefill.run']
+    chunks = [ev['args'] for ev in events
+              if ev['name'] == 'decode.prefill.chunk']
+    assert [r['chunks'] for r in runs] == [3, 1]
+    assert [c['start'] for c in chunks] == [0, 16, 32]
+    assert [c['attn_pairs'] for c in chunks] == [
+        4 * sum(range(a + 1, b + 1)) for a, b in ((0, 16), (16, 32),
+                                                  (32, 40))]
+    assert runs[0]['attn_pairs'] == sum(c['attn_pairs'] for c in chunks) \
+        == 4 * 40 * 41 // 2
+    assert runs[1]['attn_pairs'] == 4 * 10 * 11 // 2
 
 
 @pytest.mark.parametrize('spec,kw', [
