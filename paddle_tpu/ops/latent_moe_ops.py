@@ -10,15 +10,44 @@ cache row is ``[c_kv ; k_rope]``: ``c_kv = s_kv RMSNorm(x W_kva[:, :r])``
 and ``k_rope`` the last ``d_rope`` columns of ``x W_kva`` rotated
 (interleaved pairs); one row a token a layer, which every head reads.
 Queries go through a rank-``q_rank`` bottleneck, ``c_q = s_q RMSNorm(x
-W_qa)``, ``[q_nope ; q_rope]_h = c_q W_qb``. Every program runs the
-*absorbed* form: the key up-projection is folded into the query,
-``q'_h = [q_nope_h W_UK,h ; RoPE(q_rope_h)]`` (as wide as the row), so a
-score is one product of ``q'_h`` with the cached row, the weighted sum
-is over the rows' first ``r`` columns, and the value up-projection is
-applied to that sum, ``out_h = (sum_s p c_kv(s)) W_UV,h``: K and V are
-never expanded, a decode step reads ``row width x itemsize`` bytes a
-position and nothing else of the cache
-(``ops/pallas/paged_attention.py``: the latent form of
+W_qa)``, ``[q_nope ; q_rope]_h = c_q W_qb``.
+
+The attention takes one of two forms, by how many query rows share a
+block table (``serving/decode/model.py``: ``latent_expands``, a function
+of the kind's rank and head widths and the program's static row count;
+the engine counts ``decode.prefill_chunks_expanded`` by the same
+function):
+
+- *absorbed*, for one query a table (the decode step, spec verify) and
+  for a short chunk: the key up-projection is folded into the query,
+  ``q'_h = [q_nope_h W_UK,h ; RoPE(q_rope_h)]`` (as wide as the row), so
+  a score is one product of ``q'_h`` with the cached row, the weighted
+  sum is over the rows' first ``r`` columns, and the value up-projection
+  is applied to that sum, ``out_h = (sum_s p c_kv(s)) W_UV,h``: K and V
+  are never expanded, and a decode step reads ``row width x itemsize``
+  bytes a position and nothing else of the cache. A (query, key, head)
+  costs ``2 (2 r + d_rope)`` operations and the running sum is
+  ``[H, rows, r]``, as large as a score block;
+- *expanded*, for a chunk of many rows (the model's own order of
+  operations in a prefill): each column block of latent rows is
+  gathered once, as above, and expanded there to
+  ``K_h = [c_kv W_UK,h ; k_rope]`` and ``V_h = c_kv W_UV,h``, which the
+  block's queries ``[q_nope_h ; RoPE(q_rope_h)]`` then attend head by
+  head. Expanding a key costs ``2 r (d_nope + d_v)`` a head whatever the
+  rows; a (query, key, head) then costs ``2 (d_nope + d_rope + d_v)``
+  and the running sum is ``[H, rows, d_v]``. It pays where
+  ``rows > r (d_nope + d_v) / (2 r - d_nope - d_v)``: 171 rows at rank
+  512 over 128 + 128 (kimi_k2_6, dots3_note's full layers), 190 at rank
+  1,024 over 192 + 128 (dots3_note's sliding layers), so the buckets
+  256 and 512 expand and 64 and 128 stay absorbed (measured on the
+  chip at kimi_k2_6's widths, PERF.md section 6, PR 37: 512 rows at
+  depth 20k 14.2 -> 6.3 ms a layer, 256 rows 5.2 -> 4.0, 128 rows 2.6
+  against 3.0 expanded). Nothing of the table's extent is expanded: a
+  block's keys and values (512 x H x 320 values) live until its
+  products are done.
+
+Both forms run the same column blocks under the same bounds, mask and
+running softmax (``ops/pallas/paged_attention.py``: the latent forms of
 ``_attend_blocks``). Where the configuration has it (``attn_gate``) a
 sigmoid gate a head, from the layer's normed input, multiplies
 ``out_h`` before ``W_o``.
@@ -75,9 +104,12 @@ shared experts added at weight 1.
 import jax
 import jax.numpy as jnp
 
+from ..serving.decode.model import latent_expands
 from . import moe_held_ops as moe
 from .paged_decode_ops import (_attention_of, _mm, _rope_gptj_at,
                                _write_in_place)
+from .pallas.paged_attention import (paged_attention_one_table,
+                                     pages_per_block)
 
 FULL, SLIDING = 'full_attention', 'sliding_attention'
 _TAG = {FULL: 'Full', SLIDING: 'Swa'}
@@ -313,16 +345,8 @@ class LatentMoEBlock(object):
         mine = [self.arena_slots.index(
             'LatentFull' if kind == FULL else 'LatentSliding')]
         # a row is stored in whole lane tiles (CacheKind.stored): the
-        # columns past [c_kv ; k_rope] are written as zeros and the
-        # query carries zeros there
+        # columns past [c_kv ; k_rope] are written as zeros
         spare = arenas[mine[0]].shape[-1] - rank - d_rope
-        # the absorbed query: as wide as the cached row
-        q_abs = jnp.einsum('nhd,hdr->nhr',
-                           q[..., :d_nope].astype(w['KvBK'].dtype),
-                           w['KvBK'], preferred_element_type=jnp.float32)
-        q_row = jnp.concatenate(
-            [q_abs, turned(q[..., d_nope:]),
-             jnp.zeros((rows, heads, spare), jnp.float32)], -1)
         new = [jnp.concatenate(
             [c_kv, k_rope, jnp.zeros((rows, spare), jnp.float32)], -1)]
         chosen, lo = None, None
@@ -342,18 +366,34 @@ class LatentMoEBlock(object):
         for a, arena in zip(mine, held):
             arenas[a] = arena
         if selects:
-            from .pallas.paged_attention import pages_per_block
             per = pages_per_block(step.tables.shape[-1], held[1].shape[2])
             chosen = select_topk(
                 index_scores(q_i, w_i, held[1], i, step.tables, step.lens,
                              per), self.index_topk)
-        mixed = _attention_of(step.tables)(
-            q_row, held[0], None, step.tables, step.lens,
+        q_rope = turned(q[..., d_nope:])
+        attend = dict(
             sm_scale=(d_nope + d_rope) ** -0.5 * self.softmax_mult[kind],
-            layer=i, lo=lo,
-            latent=rank, chosen=chosen)                     # [N, H, r]
-        out = jnp.einsum('nhr,hrv->nhv', mixed.astype(w['KvBV'].dtype),
-                         w['KvBV'], preferred_element_type=jnp.float32)
+            layer=i, lo=lo, latent=rank, chosen=chosen)
+        # the form follows the rows that share a table (module docstring)
+        if step.tables.ndim == 1 and latent_expands(
+                rank, d_nope, w['KvBV'].shape[-1], rows):
+            out = paged_attention_one_table(
+                jnp.concatenate([q[..., :d_nope], q_rope], -1), held[0],
+                None, step.tables, step.lens,
+                expand=(w['KvBK'], w['KvBV']), **attend)    # [N, H, d_v]
+        else:
+            # the absorbed query: as wide as the cached row, zeros over
+            # its spare columns
+            q_abs = jnp.einsum('nhd,hdr->nhr',
+                               q[..., :d_nope].astype(w['KvBK'].dtype),
+                               w['KvBK'], preferred_element_type=jnp.float32)
+            mixed = _attention_of(step.tables)(
+                jnp.concatenate(
+                    [q_abs, q_rope,
+                     jnp.zeros((rows, heads, spare), jnp.float32)], -1),
+                held[0], None, step.tables, step.lens, **attend)  # [N, H, r]
+            out = jnp.einsum('nhr,hrv->nhv', mixed.astype(w['KvBV'].dtype),
+                             w['KvBV'], preferred_element_type=jnp.float32)
         if self.gated:
             out = out * jax.nn.sigmoid(_mm(n, w['Gate']))[:, :, None]
         return _mm(out.reshape(rows, -1), w['O']), tuple(arenas)

@@ -37,19 +37,35 @@ mixed lengths, head layouts and arena dtypes is asserted in
 tests/test_paged_attention_blocked.py, tests/test_decode_serving.py and
 tests/test_pallas_kernels.py.
 
-The latent form (``latent=r``; ops/latent_moe_ops.py): one arena whose
-row ``[c_kv ; k_rope]`` every head reads, keys the whole row and values
-its first ``r`` columns, and an optional per-row choice of columns
-(``chosen``) within the bounds, through the same blocks.
+The latent forms (``latent=r``; ops/latent_moe_ops.py): one arena whose
+row ``[c_kv ; k_rope]`` every head reads, and an optional per-row choice
+of columns (``chosen``) within the bounds, through the same blocks.
+*Absorbed* (both forms; the decode step, spec verify, a short chunk):
+keys the whole row and values its first ``r`` columns, the two
+up-projections applied by the caller to the query and to the result.
+*Expanded* (``expand=(W_UK, W_UV)``, the one-table form only: a chunk of
+many rows): a block's gathered rows are expanded where they are
+consumed to per-head keys ``[c_kv W_UK,h ; k_rope]`` and values
+``c_kv W_UV,h``, head-major out of the products, and the block is the
+per-head form from there. Which of the two a program runs is a function
+of its static row count and the kind's widths
+(``serving/decode/model.py``: ``latent_expands``); ``_attend_blocks``
+has what each costs.
 
 Layouts:
-    q            [B, H, D]      one query token per sequence
+    q            [B, H, D]      one query token per sequence (latent,
+                 absorbed: D the stored row's width; expanded:
+                 d_nope + d_rope)
     k/v_pages    [L, NB, bs, H*D]  the pooled page arena, all layers:
                  token-major inside a page, heads and head width merged
                  into one lane-dense minor axis (a token's K row is
                  H*D contiguous elements), so the TPU keeps the arena
                  row-major and a row can be written in place
                  (ops/paged_decode_ops.py). ``layer`` picks the layer.
+                 Latent: one arena, [L, NB, bs, W] with W the row
+                 [c_kv ; k_rope] stored in whole lane tiles.
+    expand       (W_UK [H, d_nope, r], W_UV [H, r, d_v]) one layer's
+                 up-projections, at the weights' dtype
     k/v_scales   [L, NB, bs, H] per-row fp32 scales (quantized arenas)
     block_tables [B, P] int32   physical page ids; >= NB means "no page"
     seq_lens     [B]  int32     live tokens (this token included)
@@ -120,7 +136,7 @@ def pages_covered(lo, hi, n_pages, bs, xp=jnp):
 
 
 def _attend_blocks(q, arenas, layer, tables, lo, hi, first, last, per,
-                   latent=None, chosen=None):
+                   latent=None, chosen=None, expand=None):
     """The one inner form: R tables with S queries each
     (decode: R = BLOCK_ROWS, S = 1; a prefill chunk: R = 1, S = bucket).
     q [R, S, H, D] (scaled), ``arenas`` (K, V[, K scales, V scales]),
@@ -136,10 +152,32 @@ def _attend_blocks(q, arenas, layer, tables, lo, hi, first, last, per,
     float32. Returns [R, S, H, D] float32; rows that saw nothing 0.
 
     The latent form (``latent`` = r, ``arenas`` one arena of rows
-    ``[c_kv ; k_rope]``): every head reads the one row, the keys are the
-    whole row (D is its width) and the values its first r columns, so a
-    page is gathered once and nothing is expanded; returns
-    [R, S, H, r]. ``chosen`` (a function of a block's first column ->
+    ``[c_kv ; k_rope]``), *absorbed* (``expand`` None): every head reads
+    the one row, the keys are the whole row (D is its width) and the
+    values its first r columns, so a page is gathered once and nothing
+    is expanded; returns [R, S, H, r]. Its accumulator is as large as
+    a score block ([H, S, r] beside [H, S, bk]): the form for few
+    queries a table.
+
+    The latent form *expanded* (``expand`` = (W_UK [H, d_nope, r],
+    W_UV [H, r, d_v]); q [R, S, H, d_nope + d_rope] as the query
+    projection gives it): a block's rows are gathered once, as above,
+    and expanded there to a key and a value a head,
+    ``K_h = [c_kv W_UK,h ; k_rope]`` and ``V_h = c_kv W_UV,h``, each the
+    result of one product that has the head as its leading axis (no
+    split of gathered rows: ``by_head`` below says what that costs);
+    from there the block is the per-head form, one KV head a query
+    head, with the values' width apart from the keys' and an
+    accumulator of [H, S, d_v]. Expanding costs the same whatever S
+    is and each (query, key) is then cheaper: the form for many
+    queries a table (``serving/decode/model.py``: ``latent_expands``
+    has both costs and the rule; the caller applies it). Only a
+    block's keys and values are alive at a time, bk x H x
+    (d_nope + d_rope + d_v) values. Returns [R, S, H, d_v]. The state
+    keeps the absorbed form's axes ([R, 1, H, S]: a score block is
+    [R, 1, H, S, bk] either way).
+
+    ``chosen`` (a function of a block's first column ->
     bool [R, S, bk], or None) narrows what a row sees within
     [lo, hi) to a subset of its own choosing (a learned selection): a
     column it leaves out contributes exactly 0, as one outside the
@@ -148,8 +186,8 @@ def _attend_blocks(q, arenas, layer, tables, lo, hi, first, last, per,
     v_pages = k_pages if latent else arenas[1]
     r, s, h, d = q.shape
     bs = k_pages.shape[2]
-    n_kv = k_pages.shape[-1] // d
-    d_v = latent or d
+    n_kv = 1 if expand else k_pages.shape[-1] // d
+    d_v = expand[1].shape[-1] if expand else latent or d
     bk = per * bs
     quantized = len(arenas) == 4
     group = h // n_kv
@@ -165,8 +203,12 @@ def _attend_blocks(q, arenas, layer, tables, lo, hi, first, last, per,
     # a head is whole lane tiles it can be sliced out of a row as it
     # lies instead, at the price of joining the heads' scores: taken
     # where the scores are the smaller of the two (one query a table)
-    by_head = d % 128 == 0 and \
+    by_head = not expand and d % 128 == 0 and \
         h * s * 4 < n_kv * d * jnp.dtype(k_pages.dtype).itemsize
+    # a block's keys and values: [R, bk, n, width] one KV head a group,
+    # or [R, H, bk, width] a head of its own (expanded)
+    score, mix = ('rngsd,rgkd->rngsk', 'rngsk,rgkd->rngsd') if expand \
+        else ('rngsd,rknd->rngsk', 'rngsk,rknd->rngsd')
 
     def pages(arena, at):
         # [R, per, bs, W]: whole pages; adjacent axes merged only
@@ -179,12 +221,32 @@ def _attend_blocks(q, arenas, layer, tables, lo, hi, first, last, per,
                     for n in range(n_kv)]
         return [x.reshape(r, bk, n_kv, width)]
 
+    def expanded(rows):
+        """[R, bk, W] latent rows -> ([[R, H, bk, d_nope + d_rope]],
+        [[R, H, bk, d_v]]) at the rows' dtype, head-major out of the
+        products; the rotated columns are every head's alike."""
+        w_uk, w_uv = expand
+        d_rope = d - w_uk.shape[1]
+        c_kv = rows[..., :latent].astype(w_uk.dtype)
+        rope = rows[:, None, :, latent:latent + d_rope]
+        k_nope = jnp.einsum('hdc,rkc->rhkd', w_uk, c_kv, precision=exact,
+                            preferred_element_type=jnp.float32)
+        v = jnp.einsum('hcv,rkc->rhkv', w_uv, c_kv, precision=exact,
+                       preferred_element_type=jnp.float32)
+        return [jnp.concatenate(
+            [k_nope.astype(rows.dtype),
+             jnp.broadcast_to(rope, k_nope.shape[:3] + (d_rope,))],
+            -1)], [v.astype(rows.dtype)]
+
     def block(j, state):
         top, norm, acc = state
         at = jax.lax.dynamic_slice_in_dim(tables, j * per, per, 1)
-        kb = heads(pages(k_pages, at), d)
-        vb = [x[..., :latent] for x in kb] if latent \
-            else heads(pages(v_pages, at), d)
+        if expand:
+            kb, vb = expanded(pages(k_pages, at))
+        else:
+            kb = heads(pages(k_pages, at), d)
+            vb = [x[..., :latent] for x in kb] if latent \
+                else heads(pages(v_pages, at), d)
         if quantized:
             kb = [x.astype(jnp.float32) * sc for x, sc in
                   zip(kb, heads(pages(arenas[2], at), 1))]
@@ -197,7 +259,7 @@ def _attend_blocks(q, arenas, layer, tables, lo, hi, first, last, per,
         seen = seen[:, None, None]                         # [R, 1, 1, S, bk]
         each = qg.shape[1] // len(kb)
         scores = jnp.concatenate([
-            jnp.einsum('rngsd,rknd->rngsk',
+            jnp.einsum(score,
                        qg[:, i * each:(i + 1) * each], x, precision=exact,
                        preferred_element_type=jnp.float32)
             for i, x in enumerate(kb)], axis=1)
@@ -208,7 +270,7 @@ def _attend_blocks(q, arenas, layer, tables, lo, hi, first, last, per,
         norm = keep * norm + jnp.sum(w, axis=-1)
         w = w.astype(vb[0].dtype)
         acc = keep[..., None] * acc + jnp.concatenate([
-            jnp.einsum('rngsk,rknd->rngsd',
+            jnp.einsum(mix,
                        w[:, i * each:(i + 1) * each], x, precision=exact,
                        preferred_element_type=jnp.float32)
             for i, x in enumerate(vb)], axis=1)
@@ -313,21 +375,27 @@ def _columns_of(chosen, bk):
 def paged_attention_one_table(q, k_pages, v_pages, table, seq_lens,
                               sm_scale=None, k_scales=None, v_scales=None,
                               layer=0, lo=None, block_cols=BLOCK_COLS,
-                              latent=None, chosen=None):
+                              latent=None, chosen=None, expand=None):
     """One table, many queries: consecutive rows of ONE sequence (a
     prefill chunk) against that sequence's pages: q [S, H, D],
     ``table`` [P], row s sees columns lo[s] <= j < seq_lens[s]
     (seq_lens <= lo: a padded row, sees nothing and yields 0). The same
     blocks under the same running softmax as
-    ``paged_attention_blocked``, with the S rows as one group: from the block that holds the smallest ``lo`` to
-    the one that holds the largest ``hi`` and no further, each block's
+    ``paged_attention_blocked``, with the S rows as one group: from the
+    block that holds the smallest ``lo`` to the one that holds the
+    largest ``hi`` and no further, each block's
     pages gathered once for all S rows. A chunk at the start of a
     prompt multiplies one block and not the table's whole extent, a
     chunk deep in a sliding layer its window's blocks, and the scores
     alive at a time are [H, S, block]. Which blocks run depends on the
     chunk's place in its own sequence only. Grouped heads, quantized
     arenas, the latent form and ``chosen`` [S, P * bs] as in
-    ``paged_attention_blocked``."""
+    ``paged_attention_blocked``. ``expand`` (the latent form's two
+    up-projections; ``_attend_blocks``) has each block's rows expanded
+    to per-head keys and values where they are gathered: q is then
+    [S, H, d_nope + d_rope] and the result [S, H, d_v]. Only this form
+    takes it: with one query a table the absorbed form is the cheaper
+    (``LatentShape.expands``)."""
     nb, bs = k_pages.shape[1], k_pages.shape[2]
     s, h, d = q.shape
     scale = sm_scale if sm_scale is not None else d ** -0.5
@@ -338,7 +406,8 @@ def paged_attention_one_table(q, k_pages, v_pages, table, seq_lens,
         (q * scale)[None], _arenas(k_pages, v_pages, k_scales, v_scales),
         layer, jnp.clip(table.astype(jnp.int32), 0, nb - 1)[None],
         lo[None], hi[None], first[0], last[0], per, latent,
-        None if chosen is None else _columns_of(chosen[None], per * bs))
+        None if chosen is None else _columns_of(chosen[None], per * bs),
+        expand)
     return out[0]
 
 

@@ -963,6 +963,7 @@ class DecodeEngine(object):
                            if _obs.enabled() else 0 for a in starts]
             pairs = sum(chunk_pairs)
             seq.stream.cached_tokens = cached
+        expanded = 0
         del self._prefill_stats[:]
         with _obs.span('decode.prefill.run', bucket=bucket,
                        chunks=len(starts), cached_tokens=cached,
@@ -971,6 +972,10 @@ class DecodeEngine(object):
             for start, its_pairs in zip(starts, chunk_pairs):
                 piece = prefix[start:start + top]
                 rung = self._bucket(len(piece))
+                # the chunks whose latent attention runs expanded: the
+                # rule the lowering takes its form by
+                expanded += any(shape.expands(rung)
+                                for shape in self.spec.latent.values())
                 ids = np.zeros((1, rung), 'int64')
                 ids[0, :len(piece)] = piece
                 # a prefix of one chunk is the span above and no more
@@ -988,6 +993,7 @@ class DecodeEngine(object):
         _obs.record('decode.prefill_chunk_seconds', (t1 - t0) / len(starts))
         _obs.inc('decode.prefills_total')
         _obs.inc('decode.prefill_chunks', len(starts))
+        _obs.inc('decode.prefill_chunks_expanded', expanded)
         _obs.inc('decode.prompt_tokens_total', s)
         _obs.inc('decode.prefill_attn_pairs', pairs)
         with _obs.span('decode.prefill.emit'):

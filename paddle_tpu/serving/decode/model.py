@@ -80,6 +80,22 @@ class CacheKind(collections.namedtuple(
         return -(-self.width // self.LANES) * self.LANES
 
 
+def latent_expands(kv_rank, d_nope, d_v, rows):
+    """The form of a latent attention with ``rows`` queries a table, as
+    a function of its shapes alone. Absorbed (the key up-projection
+    folded into the query, the value up-projection applied to the
+    weighted sum) a (query, key, head) costs ``2 (2 kv_rank + d_rope)``
+    operations; expanded (a key and a value a head made from each
+    latent row read) it costs ``2 (d_nope + d_rope + d_v)``, after
+    ``2 kv_rank (d_nope + d_v)`` a (key, head) for the expansion
+    whatever ``rows`` is. So expanding pays where
+    ``rows > kv_rank (d_nope + d_v) / (2 kv_rank - d_nope - d_v)``: 171
+    rows at rank 512 over 128 + 128, 190 at rank 1,024 over 192 + 128;
+    never where the latent is no wider than what it expands to. A
+    decode step and a short chunk stay absorbed."""
+    return rows * (2 * kv_rank - d_nope - d_v) > kv_rank * (d_nope + d_v)
+
+
 class LatentShape(object):
     """One layer kind's latent attention: ``n_head`` heads over a cached
     row ``[c_kv ; k_rope]`` of ``kv_rank + d_rope`` that all of them
@@ -111,6 +127,15 @@ class LatentShape(object):
     @property
     def row_width(self):
         return self.kv_rank + self.d_rope
+
+    def expands(self, rows):
+        """Whether the attention of ``rows`` queries that share one
+        block table (a prefill chunk's bucket) expands the latent rows
+        it reads to per-head keys and values, or runs absorbed
+        (``latent_expands``). Static: the lowering takes the form by it
+        and the engine counts ``decode.prefill_chunks_expanded`` by
+        it."""
+        return latent_expands(self.kv_rank, self.d_nope, self.d_v, rows)
 
     def rope_frequencies(self):
         """Per rotated pair ``i`` the angle a position advances it by:
@@ -511,7 +536,9 @@ def latent_param_shapes(spec):
     routed layers' router and experts; the two norms' gains over all
     layers. The kv up-projection is kept as its two halves, head-major:
     ``kv_bk`` [H, d_nope, r] (keys) and ``kv_bv`` [H, r, d_v] (values),
-    which is how the absorbed form multiplies them. A fan-in of 0 marks
+    which is how both forms of the attention multiply them (absorbed:
+    into the query and onto the sum; expanded: onto each block of
+    latent rows read). A fan-in of 0 marks
     a bias: a float32 vector that starts at zero. The matrices that
     read a rescaled latent (``lora_rescale``: its RMS is sqrt(d_model /
     rank), not 1) count ``d_model`` as their fan-in, which is what the

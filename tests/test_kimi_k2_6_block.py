@@ -7,14 +7,16 @@ shared, ``routed_scale`` 2.827; YaRN by 4 over 16 original positions, so
 every sequence here runs past the original length.
 
 The comparisons are of logits, not tokens. Tolerance: both sides are
-float32 on the CPU; they differ in the order of their sums (the block
-folds the key up-projection into the query and applies the value
-up-projection to the weighted sum of latents; the reference expands keys
-and values head by head), which at these widths gives differences of a
-few 1e-6 on logits of order 1. 5e-5 leaves a margin and is two orders
-and more under what plain rope, a softmax scale without m^2, an unscaled
-routed sum or a suffix at positions counted from 0 gives (checked below
-by breaking each)."""
+float32 on the CPU; they differ in the order of their sums (in a decode
+step and a short chunk the block folds the key up-projection into the
+query and applies the value up-projection to the weighted sum of
+latents, in a chunk of more rows it expands keys and values a column
+block at a time under a running softmax; the reference expands them
+head by head over the whole sequence), which at these widths gives
+differences of a few 1e-6 on logits of order 1. 5e-5 leaves a margin
+and is two orders and more under what plain rope, a softmax scale
+without m^2, an unscaled routed sum or a suffix at positions counted
+from 0 gives (checked below by breaking each)."""
 
 import numpy as np
 import pytest

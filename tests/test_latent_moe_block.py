@@ -7,13 +7,16 @@ sliding ones; an indexer of 3 heads that keeps 8 positions; window 5;
 runs past the 8 selected positions and the window.
 
 The comparisons are of logits, not tokens. Tolerance: both sides are
-float32 on the CPU; they differ in the order of their sums (the block
-folds the key up-projection into the query and applies the value
-up-projection to the weighted sum of latents, the reference expands keys
-and values head by head), which at these widths gives differences of a
-few 1e-6 on logits of order 1. 5e-5 leaves a margin, and is two orders
-and more under what bfloat16 state, a dropped gate, a dropped rescale or
-an unapplied selection gives (checked below by breaking each)."""
+float32 on the CPU; they differ in the order of their sums (in a decode
+step and a short chunk the block folds the key up-projection into the
+query and applies the value up-projection to the weighted sum of
+latents, in a chunk of more rows it expands keys and values a column
+block at a time under a running softmax; the reference expands them
+head by head over the whole sequence), which at these widths gives
+differences of a few 1e-6 on logits of order 1. 5e-5 leaves a margin,
+and is two orders and more under what bfloat16 state, a dropped gate, a
+dropped rescale or an unapplied selection gives (checked below by
+breaking each)."""
 
 import numpy as np
 import pytest

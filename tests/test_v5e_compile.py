@@ -238,3 +238,66 @@ def test_no_serving_program_gathers_a_whole_table(one_chip, engine, which):
                if i not in others]
     assert gathers, 'no attention gather found'
     assert max(i.elements for i in gathers) <= pa.BLOCK_ROWS * per * page
+
+
+def _while_bodies(hlo):
+    """{computation name: its lines} of the computations some ``while``
+    runs as its body."""
+    bodies = set(re.findall(r'body=(%[\w.\-]+)', hlo))
+    out, name = {}, None
+    for line in hlo.split('\n'):
+        head = re.match(r'^(ENTRY )?(%[\w.\-]+) \(', line)
+        if head:
+            name = head.group(2) if head.group(2) in bodies else None
+            if name:
+                out[name] = []
+        elif name:
+            out[name].append(line)
+    return out
+
+
+# name -> (heads, rank, d_nope, d_rope, d_v, stored row, pages a table):
+# the latent kinds of the two configurations at their published widths
+EXPANDED = {
+    'kimi_k2_6': (64, 512, 128, 64, 128, 640, 1040),
+    'dots3_note_full': (128, 512, 128, 64, 128, 640, 528),
+    'dots3_note_sliding': (64, 1024, 192, 64, 128, 1152, 528),
+}
+
+
+@pytest.mark.parametrize('kind', sorted(EXPANDED))
+def test_an_expanded_chunk_makes_keys_and_values_head_major(one_chip, kind):
+    """A chunk of 512 rows in the expanded latent form: inside the column
+    block loop the gathered rows go into the two up-projections and come
+    out of them as the per-head products read them. No ``copy`` or
+    ``transpose`` there is as large as a block's values (512 x H x d_v
+    bfloat16), which is what a re-lay of the expanded block would be;
+    the accumulator is [1, 1, H, 512, d_v]; and a score block keeps the
+    axes the benchmark's trace patterns name it by ([1, 1, H, 512, 512]:
+    benchmark/layer_metrics/serve.mla_attn_busy_share.json)."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    from paddle_tpu.serving.decode.model import latent_expands
+    h, rank, d_nope, d_rope, d_v, stored, pages = EXPANDED[kind]
+    s, bs = 512, 32
+    assert latent_expands(rank, d_nope, d_v, s)
+
+    def chunk(q, arena, table, lens, lo, w_uk, w_uv):
+        return pa.paged_attention_one_table(
+            q, arena, None, table, lens, layer=1, lo=lo, latent=rank,
+            expand=(w_uk, w_uv))
+    hlo = jax.jit(chunk).lower(
+        _shaped(one_chip, (s, h, d_nope + d_rope), jnp.float32),
+        _shaped(one_chip, (2, 2048, bs, stored), jnp.bfloat16),
+        _shaped(one_chip, (pages,), jnp.int32),
+        _shaped(one_chip, (s,), jnp.int32),
+        _shaped(one_chip, (s,), jnp.int32),
+        _shaped(one_chip, (h, d_nope, rank), jnp.bfloat16),
+        _shaped(one_chip, (h, rank, d_v), jnp.bfloat16)).compile().as_text()
+    (body,) = [lines for lines in _while_bodies(hlo).values()
+               if any(' gather(' in line or 'kind=kCustom' in line
+                      for line in lines)]
+    relaid = [m for m in _materialized('\n'.join(body), 512 * h * d_v * 2)
+              if m[0] in ('copy', 'transpose', 'copy-start')]
+    assert relaid == []
+    assert 'f32[1,1,%d,%d,512]' % (h, s) in hlo
+    assert 'f32[1,1,%d,%d,%d]' % (h, s, d_v) in hlo
