@@ -523,10 +523,17 @@ class Executor(object):
             return StepHandle(list(fetches), steps=steps,
                               cache_miss=self.last_cache_miss)
         if return_numpy:
-            with _obs.span('executor.fetch',
-                           record='executor.fetch_seconds'):
-                return [np.asarray(v) for v in fetches]
+            return self.fetch(fetches)
         return list(fetches)
+
+    @staticmethod
+    def fetch(values):
+        """Block on fetches that ``run(return_numpy=False)`` handed back
+        on the device and return them as numpy: what ``run`` does itself
+        with ``return_numpy=True``, for a caller that has something to do
+        between the enqueue and the wait."""
+        with _obs.span('executor.fetch', record='executor.fetch_seconds'):
+            return [np.asarray(v) for v in values]
 
     def _observed_compile(self, kind, key, compile_fn):
         """Trace/prune/compile with telemetry: cache-miss counter, a

@@ -62,12 +62,13 @@ from .flight import FlightRecorder
 from .mfu import (GoodputTracker, cost_analysis_flops,  # noqa: F401
                   device_peak_flops, overlap_fraction)
 from .registry import Registry
-from .spans import SpanRecorder
+from .spans import SpanRecorder, StateClock
 
 __all__ = ['enabled', 'enable', 'enable_from_env', 'disable', 'reset',
            'registry', 'spans', 'counter', 'gauge', 'histogram', 'inc',
            'set_gauge', 'add_gauge', 'record', 'get_gauge', 'get_counter',
-           'span', 'key_id', 'flush', 'maybe_flush', 'jsonl_path',
+           'span', 'StateClock', 'key_id', 'flush', 'maybe_flush',
+           'jsonl_path',
            'export_trace',
            'run_begin', 'step_done', 'overhead', 'goodput',
            'step_telemetry', 'summary_table', 'snapshot',
@@ -263,37 +264,47 @@ _NULL = _NullCtx()
 
 
 class _SpanCtx(object):
-    __slots__ = ('name', 'attrs', 'hist', 'labels', '_sp')
+    __slots__ = ('name', 'attrs', 'hist', 'labels', 'clock', '_sp')
 
-    def __init__(self, name, attrs, hist, labels):
+    def __init__(self, name, attrs, hist, labels, clock):
         self.name = name
         self.attrs = attrs
         self.hist = hist
         self.labels = labels
+        self.clock = clock
 
     def __enter__(self):
-        self._sp = _SPANS.begin(self.name, self.attrs or None)
-        return self._sp
+        sp = self._sp = _SPANS.begin(self.name, self.attrs or None)
+        if self.clock is not None:
+            self.clock.enter(self.labels['state'], sp.t0)
+        return sp
 
     def __exit__(self, *exc):
         seconds = _SPANS.end(self._sp)
-        if self.hist is not None and seconds is not None:
-            _REG.histogram(self.hist).observe(seconds,
-                                              **(self.labels or {}))
+        if seconds is not None:
+            if self.hist is not None:
+                _REG.histogram(self.hist).observe(seconds,
+                                                  **(self.labels or {}))
+            if self.clock is not None:
+                self.clock.exit(self.labels['state'],
+                                self._sp.t0 + seconds)
         return False
 
 
-def span(name, record=None, labels=None, **attrs):
+def span(name, record=None, labels=None, clock=None, **attrs):
     """Context manager recording one nested host span (and, when jax is
     loaded, a jax.profiler.TraceAnnotation of the same name). ``record``
     names a histogram that takes the span's duration on exit, under
     ``labels``: one pair of clock readings gives both the span (the
     profiler's clock, a traced window) and the histogram (the whole
-    run). ``attrs`` carry identifiers (step, bucket, request id); they
+    run). ``clock`` is a ``StateClock`` of which the span is the state
+    ``labels['state']``: the same pair of readings moves it, so its
+    totals are the histogram's sums (and the loop's time between two
+    spans). ``attrs`` carry identifiers (step, bucket, request id); they
     never go into the name. No-op singleton when disabled."""
     if not _enabled:
         return _NULL
-    return _SpanCtx(name, attrs, record, labels)
+    return _SpanCtx(name, attrs, record, labels, clock)
 
 
 def key_id(key):

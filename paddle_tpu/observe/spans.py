@@ -24,8 +24,19 @@ completed interval with explicit perf_counter timestamps (no stack), so
 a stage measured on thread A but *observed* finishing on thread B still
 lands on the observing thread's track with exact bounds, and
 ``add_instant`` records zero-duration marks (per-token events).
+
+Two clocks beside the ring. ``StateClock`` keeps the seconds one thread
+has spent in each of a few states, fed by the enter and exit of the
+spans that are those states, so that an interval that begins in one
+span or on one thread and ends in another can be split by state as the
+difference of two readings. ``SpanRecorder.offset_to`` measures where
+the ring's clock stands against another record of the same spans (the
+profiler's trace holds a copy of every span that began and ended while
+it ran), so that the ring's own events, also those the trace lost at an
+edge, can be laid over that trace.
 """
 
+import bisect
 import collections
 import json
 import os
@@ -33,12 +44,16 @@ import sys
 import threading
 import time
 
-__all__ = ['SpanRecorder', 'FlowHandle', 'MAX_EVENTS']
+__all__ = ['SpanRecorder', 'FlowHandle', 'StateClock', 'MAX_EVENTS']
 
 # bound memory in unbounded runs: a ring of the newest MAX_EVENTS events
 # (a long-lived server exports its last minutes, not its start-up); the
 # count of those pushed out is recorded in the export metadata
 MAX_EVENTS = 200000
+# offset_to: how far apart two recorders' lengths of one span may lie,
+# and how far from the voted offset a pair of its copies
+VOTE_US = 25.0
+PAIR_US = 500.0
 
 
 class _Span(object):
@@ -63,6 +78,60 @@ class FlowHandle(object):
     def __init__(self, flow_id, name):
         self.flow_id = flow_id
         self.name = name
+
+
+class StateClock(object):
+    """Cumulative seconds one thread has spent in each of ``states``,
+    readable at any instant, mid-span, from any thread.
+
+    The thread's spans feed it: ``observe.span(..., clock=)`` hands the
+    span's own two clock readings to ``enter`` and ``exit``. The time
+    between one state's exit and the next one's enter (a loop's own
+    microseconds) stays with the state that closed, so from the first
+    enter on the totals tile the thread's wall time without a hole, and
+    the parts of any interval sum to its length. What is known lives in
+    one tuple that ``enter`` and ``exit`` replace whole, so a reader on
+    another thread takes no lock and sees no half-made state."""
+
+    def __init__(self, states):
+        self.states = tuple(states)
+        self._index = {s: i for i, s in enumerate(self.states)}
+        # (seconds by state up to ``since``, the state running from then
+        # on or None between two spans, since, the state that ran up to
+        # ``since``)
+        self._now = ((0.0,) * len(self.states), None, 0.0, None)
+
+    @staticmethod
+    def _plus(totals, i, seconds):
+        return totals[:i] + (totals[i] + seconds,) + totals[i + 1:]
+
+    def enter(self, state, t):
+        totals, _, since, last = self._now
+        if last is not None:
+            totals = self._plus(totals, last, t - since)
+        self._now = (totals, self._index[state], t, last)
+
+    def exit(self, state, t):
+        totals, _, since, _ = self._now
+        i = self._index[state]
+        self._now = (self._plus(totals, i, t - since), None, t, i)
+
+    def at(self, t):
+        """Seconds by state, in the order of ``states``, as they stood
+        at ``time.perf_counter()`` = ``t``: now, or an instant of the
+        state still running. Between two spans time runs on under the
+        state that closed (a reader that holds the lock the thread is
+        waiting for sees such a stretch last milliseconds), and an
+        instant just before the last transition (a reader on another
+        thread that lost a race with it) is taken off the state that ran
+        until then: the states always sum to ``t`` less the first
+        enter. After the thread's last span, read it at that span's
+        end."""
+        totals, running, since, last = self._now
+        if t < since or running is None:
+            running = last
+        return totals if running is None else \
+            self._plus(totals, running, t - since)
 
 
 class SpanRecorder(object):
@@ -230,6 +299,72 @@ class SpanRecorder(object):
         """``time.perf_counter()`` as it read when the recorded event
         ``ev`` began (its ``ts`` is that on the recorder's epoch)."""
         return ev['ts'] / 1e6 - self._epoch0
+
+    def offset_to(self, copies, min_matched=20):
+        """Where another clock stands against this ring's, by
+        measurement: ``copies`` are ``(name, start_ns, dur_ns)`` of
+        spans as another recorder timed them (the profiler's trace has
+        one of every span of this ring that began and ended while it
+        ran). Returns ``{'matched': n, 'copies': m, 'offset_ns': o,
+        'residual_us_p95': r}`` such that a ring event's ``ts * 1000 +
+        o`` is its start on the other clock, ``n`` of the ``m`` copies
+        whose names the ring knows having found their span; None under
+        ``min_matched`` pairs.
+
+        A few of the longest copies vote first, each with every ring
+        span of its name and its length (to ``VOTE_US``: the two
+        recorders read their clocks microseconds apart) and with one
+        vote to share among them, so a span of a length of its own (an
+        idle wait, a prefill) outweighs one of many alike; the offset
+        with most votes is the guess. Every copy is then paired with
+        the ring span of its name that starts nearest the guess (within
+        ``PAIR_US``); the offset is the pairs' median and the residual
+        how far 95% of them lie from it at most."""
+        ring = {}
+        for ev in self.events():
+            if ev.get('ph') == 'X':
+                ring.setdefault(ev['name'], []).append(
+                    (ev['ts'] * 1e3, ev['dur'] * 1e3))
+        copies = [c for c in copies if c[0] in ring and c[2] > 0]
+        if len(copies) < min_matched:
+            return None
+        for spans in ring.values():
+            spans.sort()
+        near = VOTE_US * 1e3
+        votes = []
+        for name, s, d in sorted(copies, key=lambda c: -c[2])[:16]:
+            alike = [s - t0 for t0, dur in ring[name] if abs(dur - d) <= near]
+            votes.extend((off, 1.0 / len(alike)) for off in alike)
+        if not votes:
+            return None
+        votes.sort()
+        # the heaviest window of the votes
+        best, guess, weight, lo = 0.0, None, 0.0, 0
+        for hi, (off, w) in enumerate(votes):
+            weight += w
+            while off - votes[lo][0] > near:
+                weight -= votes[lo][1]
+                lo += 1
+            if weight > best + 1e-9:
+                best, guess = weight, votes[(lo + hi) // 2][0]
+        slack = PAIR_US * 1e3
+        apart = []
+        for name, s, _ in copies:
+            starts = ring[name]
+            i = bisect.bisect_left(starts, (s - guess,))
+            d = min((s - starts[j][0] for j in (i - 1, i)
+                     if 0 <= j < len(starts)),
+                    key=lambda d: abs(d - guess))
+            if abs(d - guess) <= slack:
+                apart.append(d)
+        if len(apart) < min_matched:
+            return None
+        apart.sort()
+        offset = apart[len(apart) // 2]
+        off = sorted(abs(d - offset) for d in apart)
+        return {'matched': len(apart), 'copies': len(copies),
+                'offset_ns': offset,
+                'residual_us_p95': off[int(0.95 * (len(off) - 1))] / 1e3}
 
     def clear(self):
         with self._lock:

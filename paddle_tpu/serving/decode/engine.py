@@ -96,6 +96,41 @@ writes is the synchronous one.
   on decode steps and never overlap. ``decode.steps_ahead_total``
   counts the steps enqueued while the one before was unfetched, beside
   ``decode.steps_total``.
+
+**The worker's clock** (with observe on; one boolean read a call site
+with it off). The four spans that partition the worker thread's wall
+time (``decode.idle``, ``.admit``, ``.prefill``, ``.step``: the states
+of ``decode.worker_seconds``) also move a ``StateClock``: the seconds
+spent in each state so far, readable at any instant from any thread.
+Three intervals that begin in one span or on one thread and end in
+another are split by it, each as the difference of two readings, so
+that the parts of each sum to what the older histogram records:
+
+- ``decode.queue_wait_seconds{state}``: a request's wait from
+  ``t_submit`` to ``t_admit`` (``decode.queue_seconds``): behind other
+  requests' prefills, behind the step in flight, in the worker's
+  wake-up from idle;
+- ``decode.token_gap_seconds{state}`` over ``decode.token_gaps_total``:
+  the gap between two tokens of one sequence
+  (``decode.inter_token_seconds``): ``prefill`` is what other requests'
+  prompts put between them;
+- ``decode.device_empty_seconds{state}`` and the ring's span
+  ``decode.device_empty``: the stretches in which the engine has **no
+  program outstanding on the device**. A program is outstanding from
+  the return of its enqueue (``Executor.run(return_numpy=False)``) to
+  the arrival of its tokens on the host; a prefill's chunks are
+  enqueued back to back and only the last is fetched, so a chunked
+  prefill is outstanding from its first chunk's enqueue to its last
+  chunk's fetch. A stretch begins where an arrival leaves nothing
+  behind it (``_fetch`` of a step with no newer one in flight, the
+  fetch of a prefill's last chunk, the worker's start) and ends where
+  the next enqueue returns (a prefill's first chunk, a decode or
+  verify step) or with the worker. ``idle`` in it is an engine with
+  nothing to run; the other states are the host standing between two
+  programs though work exists. What is idle on the device *while* a
+  program is outstanding (launch latency, gaps inside a program) is in
+  no such stretch. The page handoff's gathers and scatters and the
+  token merge are not the worker's programs and are not counted.
 """
 
 import collections
@@ -124,8 +159,9 @@ __all__ = ['DecodeEngine', 'LMSpec']
 _ENGINE_IDS = itertools.count(1)
 
 # a step enqueued and not yet fetched: its rows in order, its fetches
-# where the device leaves them, the instant its dispatch began
-_Step = collections.namedtuple('_Step', 'batch tokens stats t0')
+# where the device leaves them, the instant its dispatch began, the
+# ``decode.step`` span it was enqueued under
+_Step = collections.namedtuple('_Step', 'batch tokens stats t0 no')
 
 
 def _merge_tokens(prev, src, host):
@@ -138,8 +174,11 @@ def _merge_tokens(prev, src, host):
 
 # the worker thread's four states: their spans feed one histogram whose
 # label sums partition the thread's wall time between start() and
-# shutdown()
+# shutdown(), and the engine's ``StateClock``, by which an interval
+# that crosses spans or threads is split
 _WORKER_SECONDS = 'decode.worker_seconds'
+_STATES = ('idle', 'admit', 'prefill', 'step')
+_NO_TIME = (0.0,) * len(_STATES)
 _IDLE = {'state': 'idle'}
 _ADMIT = {'state': 'admit'}
 _PREFILL = {'state': 'prefill'}
@@ -311,6 +350,13 @@ class DecodeEngine(object):
         # (None at depth 0) and the instant the last step's arrived
         self._ahead = None
         self._t_arrival = 0.0
+        # with observe on: the worker's seconds by state; (instant, the
+        # clock then) from which no program has been outstanding on the
+        # device, None while one is; the token gaps of one emit pass,
+        # [count, seconds by state]
+        self._clock = _obs.StateClock(_STATES)
+        self._empty_since = None
+        self._gaps = [0, *_NO_TIME]
         self._merge = jax.jit(_merge_tokens)
         self._prefill_stats = []    # (device MoeStats, program rows) a chunk
         self.warmup_signatures = 0
@@ -400,6 +446,9 @@ class DecodeEngine(object):
             seq = Sequence(next(self._ids), prompt, max_new, temperature,
                            seed, eos_id, ctx=ctx, tenant=tenant,
                            priority=priority)
+            if _obs.enabled():
+                # before the worker can see the request
+                seq.clk_submit = self._clock.at(seq.t_submit)
             with self._done_cv:
                 self._unfinished += 1
             self._sched.add(seq)
@@ -752,7 +801,7 @@ class DecodeEngine(object):
         """One wait on the engine's condition (held by the caller):
         nothing to run, or head-of-line blocked on pages."""
         with _obs.span('decode.idle', record=_WORKER_SECONDS,
-                       labels=_IDLE):
+                       labels=_IDLE, clock=self._clock):
             self._mu.wait(timeout)
 
     def _worker(self):
@@ -761,6 +810,9 @@ class DecodeEngine(object):
         docstring has the order and the cases that empty the pipeline)
         nothing is admitted: where that step was left in flight no
         request was admittable, and the next step's end looks again."""
+        self._empty_since = None
+        if _obs.enabled():
+            self._device_emptied(time.perf_counter())
         try:
             while True:
                 with self._mu:
@@ -781,7 +833,8 @@ class DecodeEngine(object):
                 if self._sched.running:
                     self._step_no += 1
                     with _obs.span('decode.step', record=_WORKER_SECONDS,
-                                   labels=_STEP, step=self._step_no):
+                                   labels=_STEP, clock=self._clock,
+                                   step=self._step_no):
                         self._decode_step()
                 elif self._sched.waiting:
                     # head-of-line blocked on pages with nothing running
@@ -796,11 +849,15 @@ class DecodeEngine(object):
             _obs.inc('decode.worker_errors_total')
             _obs.flight_event('decode_worker_died', error=repr(e))
             self._fail_remaining(e)
+        finally:
+            # the worker's last stretch with nothing on the device ends
+            # with the worker
+            self._device_taken()
 
     def _admit(self):
         while True:
             with _obs.span('decode.admit', record=_WORKER_SECONDS,
-                           labels=_ADMIT):
+                           labels=_ADMIT, clock=self._clock):
                 seq = self._sched.pop_admittable()
                 if seq is None:
                     return
@@ -808,13 +865,64 @@ class DecodeEngine(object):
                             seq.t_admit - seq.t_submit,
                             exemplar=seq.ctx.exemplar() if seq.ctx
                             else None)
+                if _obs.enabled() and seq.clk_submit is not None:
+                    # what the worker did while the request waited
+                    self._by_state(
+                        _obs.inc, 'decode.queue_wait_seconds',
+                        self._clock.at(seq.t_admit), seq.clk_submit)
                 if seq.ctx is not None and seq.ctx.sampled:
                     # began on the submit thread, ends here on the worker
                     seq.ctx.stage('queue_wait', seq.t_submit, seq.t_admit)
                     seq.ctx.flow_step()
             with _obs.span('decode.prefill', record=_WORKER_SECONDS,
-                           labels=_PREFILL, request_id=seq.request_id):
+                           labels=_PREFILL, clock=self._clock,
+                           request_id=seq.request_id):
                 self._prefill(seq)
+
+    # ------------------------------------------------- the worker's clock
+    @staticmethod
+    def _by_state(feed, name, clk, clk0):
+        """An interval between two readings of the worker's clock into
+        the series ``name{state}`` (``feed``: ``observe.inc`` or
+        ``observe.record``), the seconds of each state in it."""
+        for state, t, t0 in zip(_STATES, clk, clk0):
+            if t != t0:
+                feed(name, t - t0, state=state)
+
+    def _clock_at(self, now):
+        """The worker's clock at ``now`` for an emit pass, None with
+        observe off."""
+        return self._clock.at(now) if _obs.enabled() else None
+
+    def _count_token_gaps(self):
+        """The token gaps ``_emit`` summed over one emit pass, into
+        their counters."""
+        gaps = self._gaps
+        if gaps[0]:
+            _obs.inc('decode.token_gaps_total', gaps[0])
+            self._by_state(_obs.inc, 'decode.token_gap_seconds', gaps[1:],
+                           _NO_TIME)
+            gaps[:] = (0,) + _NO_TIME
+
+    def _device_emptied(self, now):
+        """``now`` is the arrival that left no program outstanding on
+        the device (observe is on)."""
+        self._empty_since = (now, self._clock.at(now))
+
+    def _device_taken(self):
+        """A program's enqueue has just returned: the stretch with
+        none outstanding, if one was open, ends here, split by what the
+        worker did in it and kept as a span of the ring."""
+        since = self._empty_since
+        if since is None:
+            return
+        self._empty_since = None
+        if not _obs.enabled():
+            return
+        now = time.perf_counter()
+        self._by_state(_obs.record, 'decode.device_empty_seconds',
+                       self._clock.at(now), since[1])
+        _obs.spans().add_span('decode.device_empty', since[0], now)
 
     # ----------------------------------------------------------- dispatch
     @staticmethod
@@ -851,7 +959,9 @@ class DecodeEngine(object):
                      wait=True):
         """One prefill dispatch. ``wait=False`` (a chunk that is not the
         prefix's last) leaves the sampled token on the device unread, so
-        the next chunk is enqueued behind it without a round trip. A
+        the next chunk is enqueued behind it without a round trip; the
+        last one's is read after the enqueue has returned
+        (``Executor.fetch``), as a step's is. A
         block that keeps router statistics hands them back beside the
         token; they are left in ``_prefill_stats`` (on the device, for a
         chunk that is not waited for) for the prefill's emit."""
@@ -866,10 +976,17 @@ class DecodeEngine(object):
                 program=self._progs.prefill,
                 feed=self._prefill_feed(ids, length, cached, table, temp,
                                         seed),
-                fetch_list=fetch, return_numpy=wait)
+                fetch_list=fetch, return_numpy=False)
+        if self._empty_since is not None:
+            self._device_taken()
         if len(out) > 1:
             self._prefill_stats.append((out[1], ids.shape[1]))
-        return int(np.asarray(out[0]).reshape(-1)[0]) if wait else None
+        if not wait:
+            return None
+        token = int(self._exe.fetch(out[:1])[0].reshape(-1)[0])
+        if _obs.enabled():
+            self._device_emptied(time.perf_counter())
+        return token
 
     def _dispatch_verify(self, tokens, lens, tables, temps, seeds):
         """Enqueue one spec-verify step; its fetch stays on the device."""
@@ -967,7 +1084,7 @@ class DecodeEngine(object):
         del self._prefill_stats[:]
         with _obs.span('decode.prefill.run', bucket=bucket,
                        chunks=len(starts), cached_tokens=cached,
-                       attn_pairs=pairs):
+                       attn_pairs=pairs, request_id=seq.request_id):
             t0 = time.perf_counter()
             for start, its_pairs in zip(starts, chunk_pairs):
                 piece = prefix[start:start + top]
@@ -981,7 +1098,7 @@ class DecodeEngine(object):
                 # a prefix of one chunk is the span above and no more
                 chunk_span = _obs.span(
                     'decode.prefill.chunk', bucket=rung, start=start,
-                    attn_pairs=its_pairs) \
+                    attn_pairs=its_pairs, request_id=seq.request_id) \
                     if len(starts) > 1 else contextlib.nullcontext()
                 with chunk_span:
                     tok = self._run_prefill(
@@ -1011,7 +1128,11 @@ class DecodeEngine(object):
                               chunks=len(starts))
             seq.cache_len = s
             self._maybe_publish(seq)
-            self._emit(seq, tok, time.perf_counter())
+            now = time.perf_counter()
+            clk = self._clock_at(now)
+            self._emit(seq, tok, now, clk)
+            if clk is not None:
+                self._count_token_gaps()
             reason = seq.finished()
             if reason:
                 self._finish(seq, reason)
@@ -1126,9 +1247,11 @@ class DecodeEngine(object):
         self._step_stats = None
         with _obs.span('decode.step.dispatch',
                        record='decode.step_dispatch_seconds',
-                       batch=len(batch)):
+                       batch=len(batch), step=self._step_no):
             out = dispatch(tokens, *feeds)
-            step = _Step(batch, out, self._step_stats, t0)
+            if self._empty_since is not None:
+                self._device_taken()
+            step = _Step(batch, out, self._step_stats, t0, self._step_no)
             out.copy_to_host_async()
             if step.stats is not None and _obs.enabled():
                 step.stats.copy_to_host_async()   # read where observe is on
@@ -1144,9 +1267,11 @@ class DecodeEngine(object):
         time in steps and none spans two; the fetch span ends on the
         profiler's clock at the worker's wake-up."""
         with _obs.span('decode.step.fetch',
-                       record='decode.step_fetch_seconds'):
+                       record='decode.step_fetch_seconds', step=step.no):
             out = np.asarray(step.tokens)
         now = time.perf_counter()
+        if _obs.enabled() and (self._ahead is step or self._ahead is None):
+            self._device_emptied(now)     # no step behind this one
         _obs.record('decode.step_seconds',
                     now - max(self._t_arrival, step.t0))
         self._t_arrival = now
@@ -1240,15 +1365,18 @@ class DecodeEngine(object):
                        record='decode.step_emit_seconds'):
             if step.stats is not None and _obs.enabled():
                 self._record_moe(np.asarray(step.stats), len(step.batch))
+            clk = self._clock_at(now)
             for i, seq in enumerate(step.batch):
                 if seq.state is not RUNNING:
                     continue
                 seq.cache_len += 1
                 self._maybe_publish(seq)
-                self._emit(seq, int(nxt[i]), now)
+                self._emit(seq, int(nxt[i]), now, clk)
                 reason = seq.finished()
                 if reason:
                     self._finish(seq, reason)
+            if clk is not None:
+                self._count_token_gaps()
 
     def _record_moe(self, stats, rows):
         """One decode step's router statistics ([routed layers, 4]: choices
@@ -1322,6 +1450,7 @@ class DecodeEngine(object):
         _obs.inc('decode.spec_steps_total')
         with _obs.span('decode.step.emit',
                        record='decode.step_emit_seconds'):
+            clk = self._clock_at(now)
             for i, (seq, _) in enumerate(pairs):
                 emit = accept_drafts(drafts[i], nxt[i])
                 _obs.record('decode.spec_accepted_len', len(emit) - 1)
@@ -1330,14 +1459,18 @@ class DecodeEngine(object):
                 for tok in emit:
                     seq.cache_len += 1
                     self._maybe_publish(seq)
-                    self._emit(seq, int(tok), now)
+                    self._emit(seq, int(tok), now, clk)
                     reason = seq.finished()
                     if reason:
                         self._finish(seq, reason)
                         break
+            if clk is not None:
+                self._count_token_gaps()
         return True
 
-    def _emit(self, seq, token, now):
+    def _emit(self, seq, token, now, clk):
+        """Hand one token of ``seq`` out; it arrived at ``now``, where
+        the worker's clock read ``clk`` (None with observe off)."""
         seq.generated.append(token)
         seq.pending_token = token
         if self.draft is not None and hasattr(self.draft, 'observe'):
@@ -1353,7 +1486,15 @@ class DecodeEngine(object):
         if seq.t_last_token is not None:
             _obs.record('decode.inter_token_seconds',
                         now - seq.t_last_token)
+            if clk is not None and seq.clk_last_token is not None:
+                # what the worker did between the two tokens
+                gaps = self._gaps
+                gaps[0] += 1
+                for i, t0 in enumerate(seq.clk_last_token):
+                    gaps[i + 1] += clk[i] - t0
         seq.t_last_token = now
+        if clk is not None:
+            seq.clk_last_token = clk
         seq.stream._put(token)
         seq.streamed += 1
         if seq.ctx is not None and seq.ctx.sampled:

@@ -113,7 +113,8 @@ class Sequence(object):
                  'state', 'stream', 'cache_len', 'pending_token',
                  't_submit', 't_admit', 't_first_token', 't_last_token',
                  'preemptions', 'cached_len', 'published_pages', 'ctx',
-                 'tenant', 'priority', 'prio_rank', 'ahead')
+                 'tenant', 'priority', 'prio_rank', 'ahead',
+                 'clk_submit', 'clk_last_token')
 
     def __init__(self, request_id, prompt, max_new_tokens, temperature,
                  seed, eos_id, ctx=None, tenant=None, priority=None):
@@ -135,6 +136,10 @@ class Sequence(object):
         self.t_admit = None
         self.t_first_token = None
         self.t_last_token = None
+        # the engine's worker clock (seconds by state) as it read at
+        # t_submit and at t_last_token; None where observe was off
+        self.clk_submit = None
+        self.clk_last_token = None
         self.preemptions = 0
         self.cached_len = 0        # prefix-cache hit span (this prefill)
         self.published_pages = 0   # full pages already offered to cache

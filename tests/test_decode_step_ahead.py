@@ -44,6 +44,9 @@ _WEIGHTS = {}
 
 @pytest.fixture(autouse=True)
 def _observe_clean():
+    # also what an earlier file of this process left in the registry
+    observe.disable()
+    observe.reset()
     yield
     observe.disable()
     observe.reset()
@@ -302,6 +305,29 @@ def test_one_record_and_one_span_a_program_and_the_records_tile():
     ahead = observe.get_counter('decode.steps_ahead_total')
     assert 0 < ahead < programs
     assert ahead == sum(1 for step in dispatched if step is not None)
+    # the spans say which program they are of: a dispatch its
+    # ``decode.step`` span's, a fetch that one's or, with it in flight,
+    # the one's before; every program is fetched once
+    spans = {name: [(e['ts'], e['ts'] + e['dur'], e['args']['step'])
+                    for e in observe.spans().events() if e['name'] == name]
+             for name in ('decode.step', 'decode.step.dispatch',
+                          'decode.step.fetch')}
+
+    def under(span):
+        (step,) = [no for t0, t1, no in spans['decode.step']
+                   if t0 <= span[0] and span[1] <= t1]
+        return step
+    assert sorted(no for _, _, no in spans['decode.step']) == \
+        list(range(1, programs + 1))
+    assert all(d[2] == under(d) for d in spans['decode.step.dispatch'])
+    late = [under(f) - f[2] for f in spans['decode.step.fetch']]
+    assert set(late) == {0, 1} and late.count(1) == ahead
+    assert sorted(f[2] for f in spans['decode.step.fetch']) == \
+        list(range(1, programs + 1))
+    prefills = [e['args'] for e in observe.spans().events()
+                if e['name'] in ('decode.prefill.run',
+                                 'decode.prefill.chunk')]
+    assert prefills and all(a['request_id'] > 0 for a in prefills)
     # consecutive records never overlap: together they fit in the run
     assert 0 < steps.aggregate()[1] <= wall
     assert observe.get_counter('executor.cache_miss_total') == compiled

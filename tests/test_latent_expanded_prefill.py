@@ -444,7 +444,9 @@ def test_the_share_of_expanded_chunks_resolves_and_reads():
     bench = manifest.load(ROOT)
     assert manifest.problems(bench) == []
     (entry,) = [m for m in bench['per_layer'] if m['name'] == name]
-    assert entry == bench['per_layer'][-1]
+    # appended after every entry that was there at PR 37 (the 84 older
+    # ones); later PRs append behind it
+    assert bench['per_layer'].index(entry) == 84
     assert entry == dict(
         name=name, unit='%', better='higher', source='program_counter',
         layer='op lowerings', moves='ttft_mean_ms', workloads=[cell])
