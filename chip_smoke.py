@@ -58,8 +58,8 @@ REHEARSAL = {
     'model': dict(n_layer=1, n_head=2, d_key=8, d_value=8, d_model=16,
                   d_inner=32),
     'vocab': 64, 'batch': 8, 'seq': 8, 'steps': 5, 'window': 3,
-    # a pool larger than a row block's column block (8 tables x 4
-    # pages), so that a block's pages are not of a layer's arena size
+    # a pool larger than an iteration's pages (8 pairs x 4 pages), so
+    # that what it gathers is not of a layer's arena size
     'engine': dict(max_batch=4, block_size=8, num_blocks=64,
                    pages_per_seq=4, max_prompt_len=8),
     'requests': 8, 'max_new': 6,

@@ -43,8 +43,8 @@ functions and order, ``segments``): embedding, the q/k/v
 projections, what follows attention, the per-layer lower bound on the
 columns a row sees, and the logits. Everything else — placement, the
 in-place arena writes, the one ``lax.scan`` over [L, ...]-stacked
-weights with the arenas as carry, the attention in blocks of rows and
-columns bounded by what the rows hold (ops/pallas/paged_attention.py:
+weights with the arenas as carry, the attention in column blocks
+bounded by what each row holds (ops/pallas/paged_attention.py:
 many tables with one query each for the decode step and spec verify,
 one table with many queries for every prefill) — is shared
 through ``_extend_rows``. A layer's kind (window, rotary) is scanned

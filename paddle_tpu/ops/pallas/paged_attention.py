@@ -11,25 +11,30 @@ per-sequence reservation: a 17-token sequence holds ceil(17/bs) pages,
 not Tmax slots.
 
 The attention reads the pages its rows hold and no others, in plain
-XLA on every backend. Two forms over one inner form
-(``_attend_blocks``: whole pages of about BLOCK_COLS columns gathered
-through the table at (layer, table) and consumed there under a running
-softmax; float32 scores, normaliser and accumulator):
+XLA on every backend, in whole pages of about BLOCK_COLS columns
+gathered through the table at (layer, table) and consumed where they
+are gathered (``_block_products``: float32 scores, normaliser and
+accumulator). Two forms:
 ``paged_attention_blocked`` — many tables, one query each (decode
-step, spec verify): rows ordered by attended length, blocks of
-BLOCK_ROWS rows, each running the column blocks from the one that
-holds its smallest lower bound to the one that holds its largest
-length; rows that are not live cost no block;
+step, spec verify). Its unit of work is a *pair* (row, column block):
+a live row holds one pair for each column block between its bounds,
+a row that is not live none (``row_pairs``). One loop takes
+BLOCK_ROWS consecutive pairs an iteration, each through its own
+table at its own block, as so many independent problems, and a row's
+pairs advance its softmax state one after another in column order,
+within an iteration as across two, so a short row beside a long one
+costs its own blocks and a row's result is its own columns' alone;
 ``paged_attention_one_table`` — one table, many queries (every
 prefill): the chunk's rows as one group over the blocks the chunk can
-see.
+see, under one running softmax (``_attend_blocks``).
 The loop bounds come from the step's own inputs (``block_bounds``), in
 one compiled program per signature; nothing of the extent [B, P] or
 [S, P] is gathered, re-tiled or multiplied (PERF.md section 6, PR 29;
 before it the gather covered every page of every table whatever the
-rows held). On the TPU the split of H*D into [H, D] is still a
-re-tiling of a block's gathered pages when D is under a lane tile
-(ROADMAP S3c).
+rows held; until PR 41 a block of BLOCK_ROWS rows ran every column
+block to its longest row). On the TPU the split of H*D into [H, D] is
+still a re-tiling of a block's gathered pages when D is under a lane
+tile (ROADMAP S3c).
 
 Parity of both forms with a dense masked oracle
 (``paged_attention_reference``, which no serving program calls) across
@@ -77,9 +82,11 @@ import jax.numpy as jnp
 _NEG_INF = -1e9
 
 
-# The two block sizes, neither swept against a trace: a row block is
-# the hardware's sublane tile, a column block about the width the
-# one-table form has run at on the chip since PR 28.
+# The two block sizes, neither swept against a trace: BLOCK_ROWS is the
+# pairs of (row, column block) an iteration of the many-tables form
+# takes (the hardware's sublane tile; the name is from when they were 8
+# rows), a column block about the width the one-table form has run at
+# on the chip since PR 28.
 BLOCK_ROWS = 8
 BLOCK_COLS = 512
 
@@ -110,78 +117,59 @@ def block_bounds(lo, hi, rows, block, n_blocks, xp=jnp):
     return first, xp.where(hi_g > 0, last, first - 1)
 
 
-def row_blocks(lo, hi, block, n_blocks, xp=jnp):
-    """Many tables' rows in blocks of BLOCK_ROWS: (order [B], first [G],
-    last [G]). ``order`` puts the longest attended length first and the
-    rows that see nothing last, so a block holds rows of like length
-    and the blocks that run are a prefix; ``first``/``last`` are
-    ``block_bounds`` of the ordered rows, the last block filled up with
-    rows that see nothing."""
-    order = xp.argsort(-xp.where(hi > lo, hi, 0), stable=True)
-    fill = xp.zeros((-len(order) % BLOCK_ROWS,), hi.dtype)
-    first, last = block_bounds(xp.concatenate([lo[order], fill]),
-                               xp.concatenate([hi[order], fill]),
-                               BLOCK_ROWS, block, n_blocks, xp)
-    return order, first, last
+def row_pairs(lo, hi, block, n_blocks, xp=jnp):
+    """Many tables' work as a list of (row, column block) pairs: a live
+    row holds one pair for each column block from the one that holds
+    its ``lo`` to the one that holds its ``hi`` (``block_bounds`` with
+    one row a group), a row that sees nothing holds none. The list goes
+    row after row, a row's blocks in ascending column order. Returns
+    (first [B], last [B], ends [B]): each row's own bounds and the
+    pairs held up to and including it, so the list has ``ends[-1]``
+    pairs and row r holds pairs ends[r] - (last[r] - first[r] + 1) <=
+    t < ends[r]."""
+    first, last = block_bounds(lo, hi, 1, block, n_blocks, xp)
+    return first, last, xp.cumsum(last - first + 1)
+
+
+def pairs_at(t, last, ends, xp=jnp):
+    """Pairs ``t`` [N] of ``row_pairs``' list -> (row [N], block [N]).
+    ``row`` is the first row whose ``ends`` pass t (rows that hold no
+    pair are stepped over); t past the list gives row = len(ends), the
+    fill of a last iteration, and a block of no meaning."""
+    row = (ends[None, :] <= t[:, None]).sum(axis=1)
+    at = xp.minimum(row, len(ends) - 1)
+    return row, last[at] - (ends[at] - 1 - t)
+
+
+def pages_held(lo, hi, n_pages, bs, xp=jnp):
+    """Pages the column blocks of the rows' own bounds hold, one
+    layer: pairs x ``pages_per_block``. The least any form that goes in
+    whole column blocks gathers."""
+    per = pages_per_block(n_pages, bs)
+    return row_pairs(lo, hi, per * bs, n_pages // per, xp)[2][-1] * per
 
 
 def pages_covered(lo, hi, n_pages, bs, xp=jnp):
     """Pages one layer of ``paged_attention_blocked`` gathers for
     rows that see columns lo <= j < hi of tables of ``n_pages``: every
-    (row block, column block) pair that runs reads BLOCK_ROWS rows of
-    ``pages_per_block`` pages."""
-    per = pages_per_block(n_pages, bs)
-    _, first, last = row_blocks(lo, hi, per * bs, n_pages // per, xp)
-    return (last - first + 1).sum() * BLOCK_ROWS * per
+    iteration of its loop takes BLOCK_ROWS pairs of ``pages_per_block``
+    pages, the fill of the last included."""
+    taken = BLOCK_ROWS * pages_per_block(n_pages, bs)
+    return -(-pages_held(lo, hi, n_pages, bs, xp) // taken) * taken
 
 
-def _attend_blocks(q, arenas, layer, tables, lo, hi, first, last, per,
-                   latent=None, chosen=None, expand=None):
-    """The one inner form: R tables with S queries each
-    (decode: R = BLOCK_ROWS, S = 1; a prefill chunk: R = 1, S = bucket).
-    q [R, S, H, D] (scaled), ``arenas`` (K, V[, K scales, V scales]),
-    tables [R, P] (clipped), lo/hi [R, S]. Column blocks ``first`` ..
-    ``last`` of ``per`` whole pages go one after another under a running
-    softmax (maximum, normaliser, weighted sum; float32): each
-    iteration gathers [R, per pages] through the tables and is consumed
-    there. A block no column of which a row sees leaves that row's
-    state bit for bit as it was, and a block sits at an absolute
-    multiple of its width, so a row's result depends on its own
-    columns only. Operands of the two products at the arena's dtype
-    (float32 once dequantized), scores, normaliser and accumulator
-    float32. Returns [R, S, H, D] float32; rows that saw nothing 0.
-
-    The latent form (``latent`` = r, ``arenas`` one arena of rows
-    ``[c_kv ; k_rope]``), *absorbed* (``expand`` None): every head reads
-    the one row, the keys are the whole row (D is its width) and the
-    values its first r columns, so a page is gathered once and nothing
-    is expanded; returns [R, S, H, r]. Its accumulator is as large as
-    a score block ([H, S, r] beside [H, S, bk]): the form for few
-    queries a table.
-
-    The latent form *expanded* (``expand`` = (W_UK [H, d_nope, r],
-    W_UV [H, r, d_v]); q [R, S, H, d_nope + d_rope] as the query
-    projection gives it): a block's rows are gathered once, as above,
-    and expanded there to a key and a value a head,
-    ``K_h = [c_kv W_UK,h ; k_rope]`` and ``V_h = c_kv W_UV,h``, each the
-    result of one product that has the head as its leading axis (no
-    split of gathered rows: ``by_head`` below says what that costs);
-    from there the block is the per-head form, one KV head a query
-    head, with the values' width apart from the keys' and an
-    accumulator of [H, S, d_v]. Expanding costs the same whatever S
-    is and each (query, key) is then cheaper: the form for many
-    queries a table (``serving/decode/model.py``: ``latent_expands``
-    has both costs and the rule; the caller applies it). Only a
-    block's keys and values are alive at a time, bk x H x
-    (d_nope + d_rope + d_v) values. Returns [R, S, H, d_v]. The state
-    keeps the absorbed form's axes ([R, 1, H, S]: a score block is
-    [R, 1, H, S, bk] either way).
-
-    ``chosen`` (a function of a block's first column ->
-    bool [R, S, bk], or None) narrows what a row sees within
-    [lo, hi) to a subset of its own choosing (a learned selection): a
-    column it leaves out contributes exactly 0, as one outside the
-    bounds does."""
+def _block_products(q, arenas, layer, per, latent=None, expand=None):
+    """What both forms do to one column block of R tables, S queries
+    each (``_attend_blocks`` has the layouts and what ``latent`` and
+    ``expand`` mean): q [R, S, H, D] (scaled) -> (gathered, scored,
+    mixed, shape, d_v). ``gathered(at)``: the block's keys and values
+    through page ids ``at`` [R, per], dequantized where the arenas are
+    quantized; ``scored(keys, seen)``: float32 scores [R, Hkv, G, S, bk]
+    with every column ``seen`` (broadcastable to them) leaves out at
+    _NEG_INF; ``mixed(w, values)``: the product of weights
+    [R, Hkv, G, S, bk] at the values' dtype with the values, float32
+    [R, Hkv, G, S, d_v]; ``shape`` (R, Hkv, G, S), the axes of a
+    softmax state."""
     k_pages = arenas[0]
     v_pages = k_pages if latent else arenas[1]
     r, s, h, d = q.shape
@@ -238,9 +226,7 @@ def _attend_blocks(q, arenas, layer, tables, lo, hi, first, last, per,
              jnp.broadcast_to(rope, k_nope.shape[:3] + (d_rope,))],
             -1)], [v.astype(rows.dtype)]
 
-    def block(j, state):
-        top, norm, acc = state
-        at = jax.lax.dynamic_slice_in_dim(tables, j * per, per, 1)
+    def gathered(at):
         if expand:
             kb, vb = expanded(pages(k_pages, at))
         else:
@@ -252,37 +238,104 @@ def _attend_blocks(q, arenas, layer, tables, lo, hi, first, last, per,
                   zip(kb, heads(pages(arenas[2], at), 1))]
             vb = [x.astype(jnp.float32) * sc for x, sc in
                   zip(vb, heads(pages(arenas[3], at), 1))]
-        col = j * bk + jnp.arange(bk)
-        seen = (col >= lo[..., None]) & (col < hi[..., None])
-        if chosen is not None:
-            seen &= chosen(j * bk)
-        seen = seen[:, None, None]                         # [R, 1, 1, S, bk]
+        return kb, vb
+
+    def scored(kb, seen):
         each = qg.shape[1] // len(kb)
         scores = jnp.concatenate([
             jnp.einsum(score,
                        qg[:, i * each:(i + 1) * each], x, precision=exact,
                        preferred_element_type=jnp.float32)
             for i, x in enumerate(kb)], axis=1)
-        scores = jnp.where(seen, scores, _NEG_INF)
+        return jnp.where(seen, scores, _NEG_INF)
+
+    def mixed(w, vb):
+        each = qg.shape[1] // len(vb)
+        return jnp.concatenate([
+            jnp.einsum(mix,
+                       w[:, i * each:(i + 1) * each], x, precision=exact,
+                       preferred_element_type=jnp.float32)
+            for i, x in enumerate(vb)], axis=1)
+
+    return gathered, scored, mixed, (r, n_kv, group, s), d_v
+
+
+def _attend_blocks(q, arenas, layer, tables, lo, hi, first, last, per,
+                   latent=None, chosen=None, expand=None):
+    """The one-table form's loop: R tables with S queries each (a
+    prefill chunk: R = 1, S = bucket).
+    q [R, S, H, D] (scaled), ``arenas`` (K, V[, K scales, V scales]),
+    tables [R, P] (clipped), lo/hi [R, S]. Column blocks ``first`` ..
+    ``last`` of ``per`` whole pages go one after another under a running
+    softmax (maximum, normaliser, weighted sum; float32): each
+    iteration gathers [R, per pages] through the tables and is consumed
+    there. A block no column of which a row sees leaves that row's
+    state bit for bit as it was, and a block sits at an absolute
+    multiple of its width, so a row's result depends on its own
+    columns only. Operands of the two products at the arena's dtype
+    (float32 once dequantized), scores, normaliser and accumulator
+    float32. Returns [R, S, H, D] float32; rows that saw nothing 0.
+
+    The latent form (``latent`` = r, ``arenas`` one arena of rows
+    ``[c_kv ; k_rope]``), *absorbed* (``expand`` None): every head reads
+    the one row, the keys are the whole row (D is its width) and the
+    values its first r columns, so a page is gathered once and nothing
+    is expanded; returns [R, S, H, r]. Its accumulator is as large as
+    a score block ([H, S, r] beside [H, S, bk]): the form for few
+    queries a table.
+
+    The latent form *expanded* (``expand`` = (W_UK [H, d_nope, r],
+    W_UV [H, r, d_v]); q [R, S, H, d_nope + d_rope] as the query
+    projection gives it): a block's rows are gathered once, as above,
+    and expanded there to a key and a value a head,
+    ``K_h = [c_kv W_UK,h ; k_rope]`` and ``V_h = c_kv W_UV,h``, each the
+    result of one product that has the head as its leading axis (no
+    split of gathered rows: ``by_head`` below says what that costs);
+    from there the block is the per-head form, one KV head a query
+    head, with the values' width apart from the keys' and an
+    accumulator of [H, S, d_v]. Expanding costs the same whatever S
+    is and each (query, key) is then cheaper: the form for many
+    queries a table (``serving/decode/model.py``: ``latent_expands``
+    has both costs and the rule; the caller applies it). Only a
+    block's keys and values are alive at a time, bk x H x
+    (d_nope + d_rope + d_v) values. Returns [R, S, H, d_v]. The state
+    keeps the absorbed form's axes ([R, 1, H, S]: a score block is
+    [R, 1, H, S, bk] either way).
+
+    ``chosen`` (a function of a block's first column ->
+    bool [R, S, bk], or None) narrows what a row sees within
+    [lo, hi) to a subset of its own choosing (a learned selection): a
+    column it leaves out contributes exactly 0, as one outside the
+    bounds does."""
+    r, s = q.shape[:2]
+    bk = per * arenas[0].shape[2]
+    gathered, scored, mixed, shape, d_v = _block_products(
+        q, arenas, layer, per, latent, expand)
+
+    def block(j, state):
+        top, norm, acc = state
+        kb, vb = gathered(
+            jax.lax.dynamic_slice_in_dim(tables, j * per, per, 1))
+        col = j * bk + jnp.arange(bk)
+        seen = (col >= lo[..., None]) & (col < hi[..., None])
+        if chosen is not None:
+            seen &= chosen(j * bk)
+        seen = seen[:, None, None]                         # [R, 1, 1, S, bk]
+        scores = scored(kb, seen)
         new_top = jnp.maximum(top, jnp.max(scores, axis=-1))
         w = jnp.where(seen, jnp.exp(scores - new_top[..., None]), 0.0)
         keep = jnp.exp(top - new_top)
         norm = keep * norm + jnp.sum(w, axis=-1)
         w = w.astype(vb[0].dtype)
-        acc = keep[..., None] * acc + jnp.concatenate([
-            jnp.einsum(mix,
-                       w[:, i * each:(i + 1) * each], x, precision=exact,
-                       preferred_element_type=jnp.float32)
-            for i, x in enumerate(vb)], axis=1)
+        acc = keep[..., None] * acc + mixed(w, vb)
         return new_top, norm, acc
 
-    shape = (r, n_kv, group, s)
     init = (jnp.full(shape, _NEG_INF, jnp.float32),
             jnp.zeros(shape, jnp.float32),
             jnp.zeros(shape + (d_v,), jnp.float32))
     _, norm, acc = jax.lax.fori_loop(first, last + 1, block, init)
     out = acc / jnp.where(norm == 0.0, 1.0, norm)[..., None]
-    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(r, s, h, d_v)
+    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(r, s, -1, d_v)
 
 
 def _seen_from_to(lo, seq_lens):
@@ -304,19 +357,28 @@ def paged_attention_blocked(q, k_pages, v_pages, block_tables, seq_lens,
                             latent=None, chosen=None):
     """Many tables, one query each: q [B, H, D], ``block_tables`` [B, P]
     (entries >= NB mean "no page" and are never read), ``seq_lens`` [B].
-    Nothing of the extent [B, P] is gathered: the rows are ordered by
-    attended length (an argsort of [B] ints, undone on the result), go
-    in blocks of BLOCK_ROWS, and a row block runs the column blocks
-    from the one that holds its smallest ``lo`` to the one that holds
-    its largest length and no further (``block_bounds``); row blocks
-    past the last row that sees anything run nothing. A row with
-    seq_lens <= lo (an empty batch slot: callers pass length 0) costs
-    no block and yields 0.
+    Nothing of the extent [B, P] is gathered: the work is the list of
+    (row, column block) pairs the live rows hold (``row_pairs``: row
+    after row, a row's blocks from the one that holds its ``lo`` to the
+    one that holds its length, in ascending order), and one loop of
+    ceil(pairs / BLOCK_ROWS) iterations takes BLOCK_ROWS consecutive
+    pairs at a time: [BLOCK_ROWS, pages of a block] gathered through
+    each pair's own table at its own block, scores
+    [BLOCK_ROWS, Hkv, G, 1, columns of a block] and a softmax partial
+    (maximum, normaliser, weighted sum; float32) a pair. A row's
+    partials are merged into its state one after another in column
+    order, the same way whether two of them fall in one iteration or
+    in two (the open row's state rides in the loop's carry), and a
+    row's last pair writes its result. The fill of the last iteration
+    sees nothing. A row with seq_lens <= lo (an empty batch slot:
+    callers pass length 0) holds no pair, costs nothing and yields 0.
 
     Columns outside [lo, seq_lens) contribute exactly 0, so the result
     is independent of the garbage content of unowned/partial pages, and
-    a row's result does not depend on what else the batch holds
-    (``_attend_blocks``).
+    a row's result does not depend on what else the batch holds: a
+    pair's partial is its own block's, a block sits at an absolute
+    multiple of its width, and the merges are the row's own in its own
+    order.
 
     Quantized arenas: ``k_scales``/``v_scales`` [L, NB, bs, H] carry
     one fp32 scale per stored (page, slot, head) K/V row; a block's
@@ -327,43 +389,93 @@ def paged_attention_blocked(q, k_pages, v_pages, block_tables, seq_lens,
     h // (H / Hkv). ``lo`` [B] int32 is a lower bound on the columns a
     row sees (a sliding window); None sees every column below
     ``seq_lens``. The latent form (``v_pages`` None, ``latent`` the
-    values' width; ``_attend_blocks``) returns [B, H, latent];
-    ``chosen`` bool [B, P * bs] narrows each row's columns further."""
+    values' width; ``_attend_blocks`` has the layouts) returns
+    [B, H, latent]; ``chosen`` bool [B, P * bs] narrows each row's
+    columns further."""
     nb, bs = k_pages.shape[1], k_pages.shape[2]
     b, p = block_tables.shape
     h, d = q.shape[1], q.shape[2]
     scale = sm_scale if sm_scale is not None else d ** -0.5
     per = pages_per_block(p, bs, block_cols)
+    bk, n_blocks = per * bs, p // per
     lo, hi = _seen_from_to(lo, seq_lens)
-    order, first, last = row_blocks(lo, hi, per * bs, p // per)
-    short = -b % BLOCK_ROWS
-
-    def ordered(x):
-        return jnp.concatenate(
-            [x[order], jnp.zeros((short,) + x.shape[1:], x.dtype)])
-
-    q_s, lo_s, hi_s = ordered(q * scale), ordered(lo), ordered(hi)
-    tables = ordered(jnp.clip(block_tables.astype(jnp.int32), 0, nb - 1))
+    first, last, ends = row_pairs(lo, hi, bk, n_blocks)
     arenas = _arenas(k_pages, v_pages, k_scales, v_scales)
-    chosen_s = None if chosen is None else ordered(chosen)
+    q = (jnp.asarray(q) * scale).astype(
+        jnp.float32 if len(arenas) == 4 else k_pages.dtype)
+    # The whole list, once a call and nothing of it inside the loop:
+    # each pair's row, block, bounds, whether it opens or closes its
+    # row and where its result goes (past the rows unless it closes
+    # one), and its pages through its row's table
+    t = jnp.arange(-(-b * n_blocks // BLOCK_ROWS) * BLOCK_ROWS)
+    row, block = pairs_at(t, last, ends)
+    at, live = jnp.minimum(row, b - 1), row < b
+    block = jnp.where(live, block, 0)
+    listed = jnp.stack([
+        at, block, lo[at], jnp.where(live, hi[at], 0),
+        live & (block == first[at]),
+        jnp.where(live & (block == last[at]), row, b + t % BLOCK_ROWS)], 1)
+    tables = jnp.clip(block_tables.astype(jnp.int32), 0, nb - 1).reshape(
+        b, n_blocks, per)[at, block]
+    if chosen is not None:
+        chosen = jnp.asarray(chosen).reshape(b, n_blocks, bk)
 
-    def rows(i, out):
-        def cut(x):
+    def pairs(i, state):
+        def mine(x):
             return jax.lax.dynamic_slice_in_dim(x, i * BLOCK_ROWS,
                                                 BLOCK_ROWS, 0)
-        mine = None if chosen is None else _columns_of(cut(chosen_s)[:, None],
-                                                       per * bs)
-        got = _attend_blocks(cut(q_s)[:, None], arenas, layer, cut(tables),
-                             cut(lo_s)[:, None], cut(hi_s)[:, None],
-                             first[i], last[i], per, latent, mine)
-        return jax.lax.dynamic_update_slice_in_dim(
-            out, got[:, 0], i * BLOCK_ROWS, 0)
+        at, block, lo_p, hi_p, opens, goes = mine(listed).T
+        gathered, scored, mixed, _, _ = _block_products(
+            q[at][:, None], arenas, layer, per, latent)
+        kb, vb = gathered(mine(tables))
+        col = block[:, None] * bk + jnp.arange(bk)
+        seen = (col >= lo_p[:, None]) & (col < hi_p[:, None])
+        if chosen is not None:
+            seen &= chosen[at, block]
+        seen = seen[:, None, None, None]                   # [8, 1, 1, 1, bk]
+        scores = scored(kb, seen)
+        top = jnp.max(scores, axis=-1)
+        w = jnp.where(seen, jnp.exp(scores - top[..., None]), 0.0)
+        norm = jnp.sum(w, axis=-1)
+        acc = mixed(w.astype(vb[0].dtype), vb)
+        # A row's pairs advance its state one after another in column
+        # order, here as from one iteration to the next: the open
+        # row's state rides in the carry
+        (row_top, row_norm, row_acc), out = state
+        done = []
+        for k in range(BLOCK_ROWS):
+            fresh = opens[k] > 0
+            row_top = jnp.where(fresh, _NEG_INF, row_top)
+            row_norm = jnp.where(fresh, 0.0, row_norm)
+            row_acc = jnp.where(fresh, 0.0, row_acc)
+            # the side that holds the larger maximum is taken as it
+            # is (its factor would be exactly 1), so each sum has one
+            # product and rounds alike wherever in an iteration it
+            # falls, whether or not a backend fuses the two
+            older = row_top >= top[k]
+            after = jnp.maximum(row_top, top[k])
+            keep, scale_k = jnp.exp(row_top - after), jnp.exp(top[k] - after)
+            row_norm = jnp.where(older, row_norm + scale_k * norm[k],
+                                 keep * row_norm + norm[k])
+            row_acc = jnp.where(
+                older[..., None], row_acc + scale_k[..., None] * acc[k],
+                keep[..., None] * row_acc + acc[k])
+            row_top = after
+            done.append(row_acc / jnp.where(row_norm == 0.0, 1.0,
+                                            row_norm)[..., None])
+        return (row_top, row_norm, row_acc), out.at[goes].set(
+            jnp.stack(done).reshape(BLOCK_ROWS, h, -1))
 
-    live_blocks = jnp.sum(last >= first)      # a prefix: rows are ordered
-    out = jax.lax.fori_loop(
-        0, live_blocks, rows,
-        jnp.zeros((b + short, h, latent or d), jnp.float32))
-    return out[jnp.argsort(order)]
+    n_kv = k_pages.shape[-1] // d
+    d_v = latent or d
+    shape = (n_kv, h // n_kv, 1)
+    row_state = (jnp.full(shape, _NEG_INF, jnp.float32),
+                 jnp.zeros(shape, jnp.float32),
+                 jnp.zeros(shape + (d_v,), jnp.float32))
+    _, out = jax.lax.fori_loop(
+        0, -(-ends[-1] // BLOCK_ROWS), pairs,
+        (row_state, jnp.zeros((b + BLOCK_ROWS, h, d_v), jnp.float32)))
+    return out[:b]
 
 
 def _columns_of(chosen, bk):

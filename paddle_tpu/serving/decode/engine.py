@@ -1220,22 +1220,27 @@ class DecodeEngine(object):
             _obs.inc('decode.sparse_rows_live', int((seen > topk).sum()))
 
     def _count_attn_pages(self, lens, rows, k1):
-        """How far the attention's bounds engage: the pages this step's
-        row and column blocks cover, summed over the layers (from the
-        function of the lengths that gives the program its loop bounds,
-        ops/pallas/paged_attention.py), beside the pages its tables
-        can address."""
-        from ...ops.pallas.paged_attention import pages_covered
+        """How far the attention's bounds engage, summed over the
+        layers: the pages this step's loops gather (``read``: its
+        (row, column block) pairs, eight an iteration, the fill of a
+        last iteration included), the pages those pairs hold (``held``:
+        the live rows' own column blocks, the least a blocked form
+        gathers), both from the functions of the lengths that give the
+        program its loop bounds (ops/pallas/paged_attention.py), beside
+        the pages its tables can address."""
+        from ...ops.pallas.paged_attention import pages_covered, pages_held
         pos = (lens[:, None] + np.arange(k1, dtype='int32')).reshape(-1)
         live = (np.arange(len(pos)) < rows * k1) & (pos < self.capacity)
         hi = np.where(live, pos + 1, 0)
-        read = 0
+        read = held = 0
         for window, layers in collections.Counter(
                 self.spec.windows()).items():
             lo = np.maximum(hi - window, 0) if window else np.zeros_like(hi)
-            read += layers * int(pages_covered(
-                lo, hi, self.pages_per_seq, self.block_size, np))
+            bounds = (lo, hi, self.pages_per_seq, self.block_size, np)
+            read += layers * int(pages_covered(*bounds))
+            held += layers * int(pages_held(*bounds))
         _obs.inc('decode.attn_pages_read', read)
+        _obs.inc('decode.attn_pages_held', held)
         _obs.inc('decode.attn_pages_reachable',
                  self.spec.n_layer * len(pos) * self.pages_per_seq)
 

@@ -4,9 +4,9 @@ table? Read from its HLO.
 The paged ops (ops/paged_decode_ops.py) write the KV arenas in place:
 in the program the compiler hands back, the only instruction that may
 touch arena-sized data is an attention gather, and since the attention
-goes in blocks of rows and columns (ops/pallas/paged_attention.py) a
-gather's own result is a block's pages, never the extent of the
-batch's tables. Whether that holds is a property of the *optimised*
+goes in column blocks, eight (row, column block) pairs an iteration
+(ops/pallas/paged_attention.py), a gather's own result is those
+pairs' pages, never the extent of the batch's tables. Whether that holds is a property of the *optimised*
 HLO (a layout the TPU picks, a scatter it re-lays its operand for, a
 loop output it double-buffers all show up there as ``copy``
 instructions and nowhere in the jaxpr), so this module reads
@@ -24,9 +24,8 @@ instructions that materialise ``min_elements`` or more:
   arena and writes a block.
 
 Callers pass a layer's arena elements, NB * bs * H * D (what consumes
-a gather's result is not exempt: a block's pages are far under an
-arena wherever the pool is larger than BLOCK_ROWS tables' column
-block), or, with ``gathers=True``, the whole-table extent
+a gather's result is not exempt: an iteration's pages are far under
+an arena wherever the pool is larger than BLOCK_ROWS column blocks), or, with ``gathers=True``, the whole-table extent
 N * P * bs * H * D that no instruction of a serving program may reach.
 
 Instructions inside a fusion never reach memory and are skipped; a

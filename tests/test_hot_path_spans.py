@@ -257,22 +257,24 @@ def test_programs_and_ops_carry_names_a_trace_can_be_searched_by():
     assert not exe.last_cache_miss
 
 
-@pytest.mark.parametrize('lengths,per_row,read,off_counts', [
-    # 4 slots x 4 pages of 4: one row block, one column block (the
-    # table's 16 columns fit one): 8 rows x 4 pages a layer, 2 layers
-    ((5, 9, 2), 1, 2 * 8 * 4, False),
-    ((15,), 1, 2 * 8 * 4, False),
-    ((), 1, 0, False),                    # nothing live: no block runs
-    # speculation, k + 1 = 3 rows a slot: 12 rows, 9 live -> two row
-    # blocks, each over the one column block
-    ((5, 9, 2), 3, 2 * 2 * 8 * 4, False),
-    ((5, 9, 2), 1, 0, True),              # observe off: nothing counted
+@pytest.mark.parametrize('lengths,per_row,read,held,off_counts', [
+    # 4 slots x 4 pages of 4: the table's 16 columns fit one column
+    # block, so a live row holds one pair; 3 pairs fill one iteration
+    # of 8: 8 x 4 pages a layer gathered, 3 x 4 held, 2 layers
+    ((5, 9, 2), 1, 2 * 8 * 4, 2 * 3 * 4, False),
+    ((15,), 1, 2 * 8 * 4, 2 * 1 * 4, False),
+    ((), 1, 0, 0, False),                 # nothing live: no pair runs
+    # speculation, k + 1 = 3 rows a slot: 12 rows, 9 live -> 9 pairs,
+    # two iterations
+    ((5, 9, 2), 3, 2 * 2 * 8 * 4, 2 * 9 * 4, False),
+    ((5, 9, 2), 1, 0, 0, True),           # observe off: nothing counted
 ])
-def test_attn_pages_of_a_hand_built_batch(lengths, per_row, read,
+def test_attn_pages_of_a_hand_built_batch(lengths, per_row, read, held,
                                           off_counts):
-    """``decode.attn_pages_read`` is what the step's row and column
-    blocks cover, summed over the layers, beside the pages its tables
-    can address."""
+    """``decode.attn_pages_read`` is what the step's pairs of (row,
+    column block) gather, eight an iteration, and ``_held`` what they
+    hold, summed over the layers, beside the pages its tables can
+    address."""
     if not off_counts:
         observe.enable()
     eng = _engine()
@@ -284,6 +286,7 @@ def test_attn_pages_of_a_hand_built_batch(lengths, per_row, read,
     eng._step_feeds(batch, per_row)
     counters = observe.snapshot()['counters']
     assert counters.get('decode.attn_pages_read', 0) == read
+    assert counters.get('decode.attn_pages_held', 0) == held
     assert counters.get('decode.attn_pages_reachable', 0) == \
         (0 if off_counts else 2 * 4 * per_row * 4)
     eng.shutdown(drain=False)

@@ -457,7 +457,14 @@ def test_padded_chunk_rows_write_nothing():
 
 
 # --------------------------------- the absorbed against the expanded form
-def test_absorbed_attention_is_the_expanded_attention():
+@pytest.mark.parametrize('pad,lens', [
+    (0, [1, 7, 19, 33, 48, 0]),
+    # the row stored wider than it is (whole lane tiles on the chip),
+    # what lies past it never read into a score; 17 pairs of 8 columns
+    # in rows of 1 to 6, so rows straddle the iterations of 8
+    (8, [48, 0, 3, 30, 17, 24]),
+])
+def test_absorbed_attention_is_the_expanded_attention(pad, lens):
     """The latent form of the paged attention (keys the cached row,
     values its first r columns, the key up-projection folded into the
     query and the value up-projection applied to the sum) against keys
@@ -467,17 +474,18 @@ def test_absorbed_attention_is_the_expanded_attention():
         paged_attention_blocked
     rng = np.random.RandomState(5)
     heads, rank, d_nope, d_rope, d_v, rows = 4, 12, 8, 4, 8, 6
-    arena = jnp.asarray(rng.randn(2, NB, BS, rank + d_rope), jnp.float32)
+    arena = jnp.asarray(rng.randn(2, NB, BS, rank + d_rope + pad),
+                        jnp.float32)
     w_bk = rng.randn(heads, d_nope, rank).astype('float32')
     w_bv = rng.randn(heads, rank, d_v).astype('float32')
     q_nope = rng.randn(rows, heads, d_nope).astype('float32')
     q_rope = rng.randn(rows, heads, d_rope).astype('float32')
     tables = np.stack([rng.permutation(NB)[:PAGES] for _ in range(rows)])
-    lens = np.asarray([1, 7, 19, 33, 48, 0], np.int32)
+    lens = np.asarray(lens, np.int32)
     chosen = rng.rand(rows, PAGES * BS) < 0.6
     chosen[np.arange(rows), np.maximum(lens - 1, 0)] = True
     q_row = np.concatenate([np.einsum('nhd,hdr->nhr', q_nope, w_bk),
-                            q_rope], -1)
+                            q_rope, np.zeros((rows, heads, pad), 'f')], -1)
     scale = (d_nope + d_rope) ** -0.5
     mixed = paged_attention_blocked(
         jnp.asarray(q_row), arena, None, jnp.asarray(tables, jnp.int32),
@@ -485,12 +493,13 @@ def test_absorbed_attention_is_the_expanded_attention():
         chosen=jnp.asarray(chosen), block_cols=8)
     got = np.einsum('nhr,hrv->nhv', np.asarray(mixed), w_bv)
     for r in range(rows):
-        cached = np.asarray(arena)[1][tables[r]].reshape(-1, rank + d_rope)
+        cached = np.asarray(arena)[1][tables[r]].reshape(
+            -1, rank + d_rope + pad)
         see = chosen[r] & (np.arange(PAGES * BS) < lens[r])
         if not see.any():
             assert not got[r].any()
             continue
-        c_kv, k_rope = cached[see, :rank], cached[see, rank:]
+        c_kv, k_rope = cached[see, :rank], cached[see, rank:rank + d_rope]
         for h in range(heads):
             keys, values = c_kv @ w_bk[h].T, c_kv @ w_bv[h]
             sc = (keys @ q_nope[r, h] + k_rope @ q_rope[r, h]) * scale

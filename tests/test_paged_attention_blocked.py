@@ -1,4 +1,4 @@
-"""The XLA path of paged attention goes in blocks of rows and columns
+"""The XLA path of paged attention goes in column blocks
 (ops/pallas/paged_attention.py): ``paged_attention_blocked`` (many
 tables, one query each: decode step, spec verify) and
 ``paged_attention_one_table`` (one table, many queries: prefill) over
@@ -8,7 +8,10 @@ enough for every edge to be crossed:
 - both forms against the dense masked oracle, over the head layouts and
   arena dtypes the serving programs run;
 - a row alone and the same row among others give the same bits;
-- the loop bounds and the engine's count of the pages they cover.
+- the list of (row, column block) pairs the many-tables form runs,
+  eight an iteration, and the engine's counts of the pages they hold
+  and the pages the loop gathers;
+- ``chosen`` columns against a dense softmax over them.
 """
 
 import numpy as np
@@ -21,7 +24,7 @@ from paddle_tpu.quant import core as qcore
 
 NB, BS, P = 64, 8, 12            # pool, page, table: 96 columns a table
 COLS = 32                        # a column block: 4 pages, 3 blocks
-N = 20                           # 3 row blocks of 8, the last part-filled
+N = 20                           # rows (tables) a call
 CAP = P * BS
 
 # name -> (query heads, KV heads, head width, arena dtype)
@@ -36,19 +39,25 @@ LAYOUTS = {
 }
 
 # name -> attended lengths [N] (0: a row that is not live). Each crosses
-# row-block and column-block edges in its own way.
+# iteration and column-block edges in its own way.
 LENGTHS = {
     # every length class in one batch, dead rows in the middle, one row
     # at the table's full capacity, lengths on both sides of the column
     # edges 32 and 64
     'mixed_with_dead_rows': [5, 33, 0, CAP, 31, 32, 0, 0, 64, 65, 1, 17,
                              0, 90, 8, 40, 0, 63, 2, 70],
-    # fewer live rows than a row block; the other blocks run nothing
+    # three live rows of 1, 2 and 3 blocks: one iteration, part-filled
     'three_live_rows': [0] * 7 + [50] + [0] * 6 + [3] + [0] * 4 + [CAP],
-    # every row full: all 3 x 3 blocks run
+    # every row full: 20 x 3 pairs
     'all_at_capacity': [CAP] * N,
     # nothing is live
     'all_dead': [0] * N,
+    # one row at capacity and nothing else: its own blocks, no more
+    'one_of_32_at_capacity': [0] * 11 + [CAP] + [0] * 8,
+    # a row of one block beside a row of all three
+    'short_beside_full': [0] * 4 + [7] + [0] * 9 + [CAP] + [0] * 5,
+    # one block each for two iterations' worth of rows
+    'sixteen_of_one_block': [0, 0] + list(range(1, 17)) + [0, 0],
 }
 
 # name -> window (0: none). lo = max(len - window, 0): inside the first
@@ -155,31 +164,130 @@ def test_one_table_rows_equal_the_dense_oracle(layout, start, rows, window):
     assert not np.asarray(got)[rows:].any()
 
 
-@pytest.mark.parametrize('lengths,window,blocks', [
-    # 16 live rows -> 2 row blocks, the longer block to column 96 (3
-    # blocks), the shorter (<= 32) one block
-    ('mixed_with_dead_rows', 0, 3 + 1),
-    # sorted: 96, 90, 70, 65, 64, 63, 40, 33 -> lo 72..9: blocks 0..2;
-    # 32, 31, 17, 8, 5, 2, 1 and a dead row -> lo 8..0: block 0
-    ('mixed_with_dead_rows', 24, 3 + 1),
-    ('three_live_rows', 0, 3),
-    ('three_live_rows', 24, 3),
-    ('all_at_capacity', 0, 9),
-    ('all_at_capacity', 24, 3),       # lo = 72: the last block only
-    ('all_dead', 0, 0),
-])
-def test_pages_covered_counts_the_blocks_that_run(lengths, window, blocks):
-    """The engine's ``decode.attn_pages_read`` comes from the function
-    that bounds the program's loops, on numpy as on jnp."""
+def _bounds(lengths, window):
     hi = np.asarray(LENGTHS[lengths], 'int32')
     lo = np.maximum(hi - window, 0) if window else np.zeros_like(hi)
+    return lo.astype('int32'), hi
+
+
+@pytest.mark.parametrize('lengths,window,blocks', [
+    # 15 live rows: 96, 90, 70 and 65 hold 3 blocks each, 64, 63, 40
+    # and 33 two, the seven of 32 or less one: 27 pairs, 4 iterations
+    ('mixed_with_dead_rows', 0, (27, 4)),
+    # lo = hi - 24: 96 and 90 see block 2 only, 64 and 63 block 1, 70
+    # and 65 blocks 1..2, 40 and 33 blocks 0..1, the seven short rows
+    # block 0: 19 pairs, 3 iterations
+    ('mixed_with_dead_rows', 24, (19, 3)),
+    ('three_live_rows', 0, (2 + 1 + 3, 1)),
+    ('three_live_rows', 24, (2 + 1 + 1, 1)),
+    ('all_at_capacity', 0, (60, 8)),
+    ('all_at_capacity', 24, (20, 3)),     # lo = 72: the last block only
+    ('all_dead', 0, (0, 0)),
+    # one live row of 32 at capacity: its blocks alone
+    ('one_of_32_at_capacity', 0, (3, 1)),
+    # a 1-block row beside a full-length row costs one pair
+    ('short_beside_full', 0, (3 + 1, 1)),
+    # 16 rows of one block each: the pairs fill their iterations
+    ('sixteen_of_one_block', 0, (16, 2)),
+])
+def test_pages_covered_counts_the_blocks_that_run(lengths, window, blocks):
+    """The engine's ``decode.attn_pages_read`` and ``_held`` come from
+    the function that bounds the program's loop, on numpy as on jnp:
+    ``blocks`` = (the pairs the live rows hold, the iterations they
+    fill)."""
+    lo, hi = _bounds(lengths, window)
     per = pa.pages_per_block(P, BS, COLS)
     assert per == 4
-    order, first, last = pa.row_blocks(lo, hi, per * BS, P // per, np)
-    assert sorted(order.tolist()) == list(range(N))
-    assert int((last - first + 1).sum()) == blocks
-    # the default width holds the whole toy table: one block a live group
-    live_groups = -(-int((hi > 0).sum()) // pa.BLOCK_ROWS)
+    pairs, iterations = blocks
+    first, last, ends = pa.row_pairs(lo, hi, per * BS, P // per, np)
+    assert int(ends[-1]) == pairs == int((last - first + 1).sum())
+    assert -(-pairs // pa.BLOCK_ROWS) == iterations
+    # the default width holds the whole toy table: one pair a live row
+    live = int((hi > 0).sum())
     for xp in (np, jnp):
-        assert int(pa.pages_covered(xp.asarray(lo), xp.asarray(hi), P, BS,
-                                    xp)) == live_groups * pa.BLOCK_ROWS * P
+        args = (xp.asarray(lo), xp.asarray(hi), P, BS, xp)
+        held, read = int(pa.pages_held(*args)), int(pa.pages_covered(*args))
+        assert held == live * P
+        assert read == -(-live // pa.BLOCK_ROWS) * pa.BLOCK_ROWS * P
+        assert held <= read and (held == read) == (live % pa.BLOCK_ROWS == 0)
+
+
+def test_a_long_row_alone_runs_its_own_blocks_and_no_row_blocks():
+    """One live row of 32 at the capacity of a table of 64 column
+    blocks: ceil(64 / 8) iterations, whatever the other 31 slots are;
+    beside it a row of one block adds one pair, not a row block's run
+    to the longest row."""
+    n_pages, bs = 1024, 32                       # 64 blocks of 512
+    per = pa.pages_per_block(n_pages, bs)
+    hi = np.zeros((32,), 'int32')
+    hi[11] = n_pages * bs
+    lo = np.zeros_like(hi)
+    assert int(pa.pages_held(lo, hi, n_pages, bs, np)) == 64 * per
+    assert int(pa.pages_covered(lo, hi, n_pages, bs, np)) == 8 * 8 * per
+    hi[3] = 17
+    assert int(pa.pages_held(lo, hi, n_pages, bs, np)) == 65 * per
+    assert int(pa.pages_covered(lo, hi, n_pages, bs, np)) == 9 * 8 * per
+
+
+@pytest.mark.parametrize('window', sorted(WINDOWS))
+@pytest.mark.parametrize('lengths', sorted(LENGTHS))
+def test_the_pair_list_is_the_same_on_numpy_and_in_the_program(lengths,
+                                                               window):
+    """Pair t -> (row, column block), from ``lo``/``hi`` alone: row after
+    row, a row's blocks in ascending column order, the rows that see
+    nothing stepped over, the fill past the list at row B; on numpy
+    (the engine's count) as on jnp (the program's loop)."""
+    lo, hi = _bounds(lengths, WINDOWS[window])
+    block, n_blocks = COLS, CAP // COLS
+    want = [(r, j) for r in range(N) if hi[r] > lo[r]
+            for j in range(lo[r] // block, (hi[r] - 1) // block + 1)]
+    t = np.arange(N * n_blocks + pa.BLOCK_ROWS)
+    got = {}
+    for xp in (np, jnp):
+        first, last, ends = pa.row_pairs(xp.asarray(lo), xp.asarray(hi),
+                                         block, n_blocks, xp)
+        row, col = pa.pairs_at(xp.asarray(t), last, ends, xp)
+        got[xp] = np.asarray(row), np.asarray(col), np.asarray(first)
+        assert int(ends[-1]) == len(want)
+    for a, b in zip(got[np], got[jnp]):
+        assert np.array_equal(a, b)
+    row, col, first = got[np]
+    assert list(zip(row[:len(want)], col[:len(want)])) == want
+    assert (row[len(want):] == N).all()
+    # a pair opens its row at the row's first block
+    opens = [j == first[r] for r, j in want]
+    assert opens == [i == 0 or want[i - 1][0] != r
+                     for i, (r, _) in enumerate(want)]
+
+
+@pytest.mark.parametrize('window', sorted(WINDOWS))
+@pytest.mark.parametrize('lengths', ['mixed_with_dead_rows',
+                                     'short_beside_full'])
+def test_chosen_columns_equal_a_dense_softmax_over_them(lengths, window):
+    """``chosen`` narrows each row's columns within its bounds (a learned
+    selection): per-head K/V, float32, against a dense softmax over the
+    chosen and seen columns; a row whose choice is empty yields 0."""
+    q, k, v, tables, hi, lo, _ = _case('plain_f32_d64', lengths,
+                                       WINDOWS[window])
+    rng = np.random.RandomState(7)
+    chosen = rng.rand(N, CAP) < 0.4
+    chosen[5] = False                            # a live row, nothing chosen
+    got = np.asarray(pa.paged_attention_blocked(
+        q, k, v, tables, hi, layer=1, lo=lo, block_cols=COLS,
+        chosen=jnp.asarray(chosen)))
+    h, _, d, _ = LAYOUTS['plain_f32_d64']
+    cols = np.arange(CAP)
+    lo = np.zeros((N,), 'int32') if lo is None else np.asarray(lo)
+    clipped = np.clip(np.asarray(tables), 0, NB - 1)
+    for r in range(N):
+        see = chosen[r] & (cols >= lo[r]) & (cols < int(hi[r]))
+        if not see.any():
+            assert not got[r].any()
+            continue
+        keys = np.asarray(k)[1][clipped[r]].reshape(CAP, h, d)[see]
+        vals = np.asarray(v)[1][clipped[r]].reshape(CAP, h, d)[see]
+        sc = np.einsum('hd,khd->hk', np.asarray(q)[r], keys) * d ** -0.5
+        w = np.exp(sc - sc.max(axis=1, keepdims=True))
+        want = np.einsum('hk,khd->hd', w / w.sum(axis=1, keepdims=True),
+                         vals)
+        np.testing.assert_allclose(got[r], want, atol=2e-5, rtol=2e-5)
