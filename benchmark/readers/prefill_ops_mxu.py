@@ -22,10 +22,15 @@ anchors the two orders (its span in the trace, matched to the
 recorder's by start and duration; its first program run is its first
 chunk), and every other run is its neighbour's neighbour. Each matched
 run's program has to be its chunk's bucket's, and the runs under every
-whole span have to be that prefill's chunks, else nothing is read.
+whole span have to be that prefill's chunks, else nothing is read. The
+anchor's first run is the first that starts at or after its span; the
+profiler's alignment of the device's clock with the host's can put it
+just before the span instead, so the two neighbouring shifts are tried
+too and the one kept under which both conditions hold (``shift`` on the
+line below: 0, or 1 where the run before was the first chunk's).
 
-What is not read is said: a line ``PREFILL_CHUNKS`` with the runs read
-and the runs of the tail that were dropped (cut by an edge of the
+What is not read is said: a line ``PREFILL_CHUNKS`` with the runs read,
+the shift, and the runs of the tail that were dropped (cut by an edge of the
 window or by the profiler's stop, or with no chunk to match), and why
 where nothing was read (no prefill whole inside the tail: one prefill
 longer than it; a program whose spans carry no count: the parent).
@@ -49,6 +54,10 @@ MODULES_LINE = 'XLA Modules'
 # opens a registry snapshot after the instant the runner took
 START_SLACK_S = 0.25
 DUR_SLACK_S = 0.001
+# the device's clock against the host's inside one trace: a prefill's
+# first program run was seen up to the 0.5-1.8 ms the host needs from
+# the span's start to the dispatch *before* its span (PERF.md, PR 37)
+EDGE_SLACK_NS = 2000000
 
 
 def program_runs(path):
@@ -85,8 +94,9 @@ def anchor(chunks, whole, lo):
 
 
 def chunks_read(chunks, runs, host, window, program, last_op_start):
-    """({'read', 'dropped', 'why'}, [(pairs, start_ns, end_ns)] of the
-    program runs whole inside ``window`` with the chunk each is). The
+    """({'read', 'dropped', 'why'; 'shift' where runs were read},
+    [(pairs, start_ns, end_ns)] of the program runs whole inside
+    ``window`` with the chunk each is). The
     profiler cuts the run it stops in to where it stopped, inside the
     window's last millisecond: a run is whole only if the chip started
     an op after it (``last_op_start``)."""
@@ -107,28 +117,47 @@ def chunks_read(chunks, runs, host, window, program, last_op_start):
         said['why'] = 'no prefill whole inside the tail'
         return said, []
     first_chunk, (s, e) = found
-    starts = [r[1] for r in runs]
-    shift = first_chunk - bisect.bisect_left(starts, s)
+    # the anchor's first chunk is the first run that starts at or after
+    # its span; where the profiler set the device's clock a little early
+    # against the host's it is the run before that one, so the
+    # neighbouring shifts are tried too and the one kept under which
+    # every run is its chunk's
+    guess = first_chunk - bisect.bisect_left([r[1] for r in runs], s)
+    why = None
+    for shift in (guess, guess + 1, guess - 1):
+        out, fault = _placed(chunks, runs, whole, lo, hi, shift)
+        if out is not None:
+            said.update(read=len(out), dropped=len(inside) - len(out),
+                        shift=shift - guess)
+            return said, [c[:3] for c in out]
+        why = why or fault
+    said['why'] = why
+    return said, []
+
+
+def _placed(chunks, runs, whole, lo, hi, shift):
+    """([(pairs, start_ns, end_ns, prefill)] of the runs whole inside
+    [lo, hi], None) with run ``i`` taken as chunk ``i + shift``, or
+    (None, why) where that cannot be: a run whose program is not its
+    chunk's bucket's, or a whole span under which the runs are not one
+    prefill's chunks, all of them (a run belongs to the span it starts
+    under, give or take ``EDGE_SLACK_NS`` at the span's start)."""
     out = []
     for i, (bucket, s, e) in enumerate(runs):
         if e <= lo or s >= hi or not 0 <= i + shift < len(chunks):
             continue
         chunk = chunks[i + shift]
         if chunk['bucket'] != bucket:
-            said['why'] = 'run %d is prefill_%d, its chunk %d' % (
+            return None, 'run %d is prefill_%d, its chunk %d' % (
                 i, bucket, chunk['bucket'])
-            return said, []
         if s >= lo and e <= hi:
             out.append((chunk['pairs'], s, e, chunk['run']))
-    # the runs under a whole span are one prefill's chunks, all of them
     for s, e in whole:
-        under = [c for c in out if s <= c[1] < e]
+        under = [c for c in out if s - EDGE_SLACK_NS <= c[1] < e]
         if len({c[3] for c in under}) != 1 or len(under) != sum(
                 1 for c in chunks if c['run'] == under[0][3]):
-            said['why'] = 'the runs under a span are not one prefill'
-            return said, []
-    said.update(read=len(out), dropped=len(inside) - len(out))
-    return said, [c[:3] for c in out]
+            return None, 'the runs under a span are not one prefill'
+    return out, None
 
 
 def op_ns(device, patterns, intervals):

@@ -1,20 +1,20 @@
 """Of the first chip's idle nanoseconds in the traced part of the
 window, the share, in percent, that lies under spans of the program's
 own ring (``paddle_tpu.observe.spans()``) whose names match ``spans``,
-each clipped to the window: what ``trace_gap_cover`` reads from the
-trace's host events, read from the ring instead, which also holds the
-spans the trace lost (an annotation entered before the profiler started
-or still open as it stopped is never in the trace: an idle wait that
-straddles an edge of the tail vanishes whole) and the spans the program
-recorded with explicit bounds (``decode.device_empty``). Where spans
-nest, the innermost owns the time under it; ``among`` names every span
-that takes part in that contest (default: ``spans``), as there
-(``trace_gap_cover.owned``).
+each clipped to the window. The trace's own host events hold copies of
+those spans; the ring also holds the spans the trace lost (an annotation
+entered before the profiler started or still open as it stopped is
+never in the trace: an idle wait that straddles an edge of the tail
+vanishes whole) and the spans the program recorded with explicit bounds
+(``decode.device_empty``). Where spans nest, the innermost owns the
+time under it; ``among`` names every span that takes part in that
+contest (default: ``spans``), so time under a child that is not counted
+is not given to its counted parent (``tracelib.owned``).
 
 The ring is on the program's clock, the trace on the profiler's. The
 recorder measures where one stands against the other from the spans
 both hold (``SpanRecorder.offset_to``: hundreds of them a tail), once a
-run (kept in ``sources``), and the line ``SPAN_CLOCK {"matched": n,
+run (``tracelib.ring_on_trace``, kept in ``sources``), and the line ``SPAN_CLOCK {"matched": n,
 "copies": m, "offset_ns": ..., "residual_us_p95": ...}`` says how well.
 Under 20 matched spans, with a residual above 200 us, with more than
 one in twenty of the trace's copies finding no span of the ring at that
@@ -33,29 +33,16 @@ beside ``decode.worker_seconds{state}``.
 args: {"spans": [regex, ...], "among": [regex, ...]}."""
 
 import json
-import os
 import re
 
-from benchmark import manifest, stats, tracelib
+from benchmark import stats, tracelib
 
-MIN_MATCHED = 20
-MAX_RESIDUAL_US = 200.0
-MIN_SHARE = 0.95       # of the trace's copies of ring spans, those placed
 STATES = ('idle', 'admit', 'prefill', 'step')
-_KEPT, _SAID = 'ring_on_trace', 'worker_clock_said'
+_SAID = 'worker_clock_said'
 
 
 def say(tag, **fields):
     print('%s %s' % (tag, json.dumps(fields, sort_keys=True)), flush=True)
-
-
-def ring_on_trace(sources):
-    """The ring's completed spans on the trace's clock, ``[(name,
-    start_ns, end_ns)]``, or None where the two clocks cannot be set
-    against each other; measured once a run."""
-    if _KEPT not in sources:
-        sources[_KEPT] = _place(sources['trace']['host'])
-    return sources[_KEPT]
 
 
 def say_splits(before, after):
@@ -79,29 +66,6 @@ def say_splits(before, after):
         worker_seconds=by_state('histograms', 'decode.worker_seconds'))
 
 
-def _place(copies):
-    from paddle_tpu import observe
-    recorder = observe.spans()
-    measure = getattr(recorder, 'offset_to', None)
-    if measure is None:
-        say('SPAN_CLOCK', matched=0, why='the recorder has no offset_to')
-        return None
-    found = measure(copies, MIN_MATCHED)
-    if found is None:
-        say('SPAN_CLOCK', matched=0,
-            why='under %d spans in both records' % MIN_MATCHED)
-        return None
-    say('SPAN_CLOCK', **found)
-    if found['residual_us_p95'] > MAX_RESIDUAL_US or \
-            found['matched'] < MIN_SHARE * found['copies']:
-        return None
-    offset = found['offset_ns']
-    return [(ev['name'], int(ev['ts'] * 1e3 + offset),
-             int((ev['ts'] + ev['dur']) * 1e3 + offset))
-            for ev in recorder.events()
-            if ev.get('ph') == 'X' and ev['dur'] > 0]
-
-
 def read(args, sources):
     if _SAID not in sources:
         sources[_SAID] = True
@@ -109,7 +73,7 @@ def read(args, sources):
     trace = sources['trace']
     if not trace or 'window' not in trace:
         return None
-    ring = ring_on_trace(sources)
+    ring = tracelib.ring_on_trace(sources)
     if ring is None:
         return None
     lo, hi = trace['window']
@@ -128,9 +92,7 @@ def read(args, sources):
             found = found or mine
     if not idle or not found:
         return None
-    owned = manifest.load_module(os.path.join(os.path.dirname(
-        os.path.abspath(__file__)), 'trace_gap_cover.py')).owned
-    uncovered = tracelib.subtract(idle, owned(family))
+    uncovered = tracelib.subtract(idle, tracelib.owned(family))
     share = 1.0 - tracelib.total(uncovered) / float(tracelib.total(idle))
     gap = max(idle, key=lambda g: g[1] - g[0])
     say('IDLE_COVER', spans=args['spans'],

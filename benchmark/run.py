@@ -37,7 +37,13 @@ from benchmark import manifest, tracelib            # noqa: E402
 
 TRACE_SECONDS = 3.0        # the traced tail of the window
 WINDOW_SPAN = 'bench.window'
-GAP_LABELS = ('bench.dispatch', 'bench.wait_oldest')
+# the host spans an idle gap of the device is named after (regexes; where
+# they nest the innermost owns the time under it): the train runner's
+# two, the decode worker's and the executor's. Not the ring's
+# ``decode.device_empty``: it is the engine's account of the device, cut
+# where programs arrive and not where the worker's spans nest
+GAP_LABELS = (r'^bench\.(dispatch|wait_oldest)$',
+              r'^decode\.(?!device_empty$)', r'^executor\.')
 COMPILE_EVENTS = ('/jax/compilation_cache/cache_hits',
                   '/jax/compilation_cache/cache_misses')
 COMPILE_DURATION = '/jax/core/compile/backend_compile_duration'
@@ -232,13 +238,24 @@ def reduce_trace(ctx, chips):
     fields = {'busy_s': sum(busy) / len(busy) / 1e9,
               'window_s': (hi - lo) / 1e9}
     first = trace['devices'][used[0]]
-    breakdown = {
-        'device_ops': tracelib.top_ops(first, lo, hi, 10),
-        'idle_gaps': tracelib.idle_gaps(first, trace['host'], lo, hi,
-                                        GAP_LABELS, 5)}
+    breakdown = {'device_ops': tracelib.top_ops(first, lo, hi, 10)}
     trace['window'] = (lo, hi)
     trace['first'] = first
     return fields, breakdown, trace
+
+
+def named_idle_gaps(sources):
+    """``breakdown.idle_gaps``: the longest idle gaps of the traced tail,
+    each named after the host span that owns most of it. The spans are
+    the trace's own and, where the program's ring can be laid over the
+    trace (``tracelib.ring_on_trace``: the offset is measured once a run
+    and kept in ``sources`` for the readers), the ring's as well: an
+    idle wait that an edge of the tail cut is in the ring alone."""
+    trace = sources['trace']
+    ring = tracelib.ring_on_trace(sources) or []
+    host = trace['host'] + [(name, s, e - s) for name, s, e in ring]
+    return tracelib.idle_gaps(trace['first'], host, *trace['window'],
+                              GAP_LABELS, 5)
 
 
 def main(argv=None, root=None):
@@ -311,16 +328,18 @@ def main(argv=None, root=None):
            'failed': int(result['failed']), 'metrics': {},
            'device': device}
     if args.trace:
+        t_reduce = time.perf_counter()
         fields, breakdown, trace = reduce_trace(ctx, chips)
         device.update(fields)
-        if breakdown:
-            out['breakdown'] = breakdown
         sources = dict(
             ctx.sources, spans=ctx.spans, samples=ctx.samples,
             registry_before=ctx.registry[0], registry_after=ctx.registry[1],
             registry_tail=ctx.registry_tail, trace=trace, measured=measured,
             config=ctx.config, traffic=ctx.traffic, cell=ctx.cell,
             peaks=peaks, bench_dir=m['_dir'])
+        if breakdown:
+            breakdown['idle_gaps'] = named_idle_gaps(sources)
+            out['breakdown'] = breakdown
         for metric in resolved['per_layer']:
             value = manifest.load_module(metric['reader']).read(
                 metric['spec'].get('args', {}), sources)
@@ -328,6 +347,10 @@ def main(argv=None, root=None):
                 out['metrics'][metric['entry']['name']] = {
                     'value': value, 'unit': metric['entry']['unit'],
                     'source': metric['entry']['source']}
+        # what a traced run pays after its window for the per-layer line
+        say('READERS', listed=len(resolved['per_layer']),
+            read=len(out['metrics']),
+            seconds=time.perf_counter() - t_reduce)
     else:
         for entry in resolved['end_to_end']:
             out['metrics'][entry['name']] = {

@@ -12,12 +12,17 @@ events appear there under their own names.
 """
 
 import glob
+import json
 import os
 import re
 
 DEVICE_PLANE = re.compile(r'^/device:TPU:(\d+)$')
 OPS_LINE = 'XLA Ops'
 HOST_PLANE = '/host:CPU'
+# laying the program's ring over the trace (``ring_on_trace``)
+MIN_MATCHED = 20
+MAX_RESIDUAL_US = 200.0
+MIN_SHARE = 0.95       # of the trace's copies of ring spans, those placed
 
 
 def merged(intervals):
@@ -118,21 +123,53 @@ LAYOUT = re.compile(r'\{[^{}]*\}')
 CONTROL_FLOW = re.compile(r'^%?(while|conditional|call)([.\d]*)( |=|$)')
 
 
+def innermost(spans):
+    """``[(t0, t1, span)]``: each stretch between two edges of ``spans``
+    (tuples that begin ``(start, end, ...)``) in which one of them is
+    open, with the innermost open one: the latest to start and, of two
+    that start together, the one that ends first."""
+    spans = sorted(spans, key=lambda sp: sp[:2])
+    cuts = sorted({t for sp in spans for t in sp[:2]})
+    out, active, i = [], [], 0
+    for t0, t1 in zip(cuts, cuts[1:]):
+        while i < len(spans) and spans[i][0] <= t0:
+            active.append(spans[i])
+            i += 1
+        active = [sp for sp in active if sp[1] > t0]
+        if active:
+            out.append((t0, t1, max(active,
+                                    key=lambda sp: (sp[0], -sp[1]))))
+    return out
+
+
+def owned(spans):
+    """The disjoint sorted intervals in which the innermost of
+    ``spans``, ``(start, end, counted)`` triples, is a counted one: time
+    under a child that is not counted is not given to its counted
+    parent."""
+    return merged([(t0, t1) for t0, t1, sp in innermost(spans) if sp[2]])
+
+
 def idle_gaps(events, host_events, lo, hi, labels, n=5):
     """The n longest gaps of [lo, hi) in which no event ran, each named
-    after the host span of ``labels`` that covers most of it, else
-    ``unattributed``: ``[[label, seconds], ...]``."""
+    after the host span that owns most of it, else ``unattributed``:
+    ``[[label, seconds], ...]``. The spans that take part are those
+    whose names match one of the regexes ``labels``; where they nest,
+    the innermost owns the time under it (``innermost``)."""
     busy = merged(clipped(spans_of(events), lo, hi))
     gaps = subtract([(lo, hi)], busy)
     gaps.sort(key=lambda g: g[0] - g[1])
-    host = [ev for ev in host_events if ev[0] in labels]
+    host = [(s, s + d, name) for name, s, d in matching(host_events, labels)
+            if d > 0]
     out = []
     for s, e in gaps[:n]:
-        best, cover = 'unattributed', 0
-        for name, hs, hd in host:
-            c = min(e, hs + hd) - max(s, hs)
-            if c > cover:
-                best, cover = name, c
+        by_name = {}
+        for t0, t1, span in innermost(
+                [sp for sp in host if sp[0] < e and sp[1] > s]):
+            under = min(t1, e) - max(t0, s)
+            if under > 0:
+                by_name[span[2]] = by_name.get(span[2], 0) + under
+        best = max(by_name, key=by_name.get) if by_name else 'unattributed'
         out.append([best, (e - s) / 1e9])
     return out
 
@@ -180,3 +217,42 @@ def window_of(trace, label):
         return None
     return (min(s for _, s, _ in every),
             max(s + d for _, s, d in every))
+
+
+def ring_on_trace(sources):
+    """The completed spans of the program's own ring
+    (``paddle_tpu.observe.spans()``) on the trace's clock, ``[(name,
+    start_ns, end_ns)]``, or None where the two clocks cannot be set
+    against each other. Measured once a run and kept in ``sources`` for
+    whoever asks next: the harness's ``breakdown.idle_gaps`` and
+    ``readers/ring_gap_cover.py``, whose docstring says how the offset
+    is found and when it is refused."""
+    if 'ring_on_trace' not in sources:
+        sources['ring_on_trace'] = _place_ring(sources['trace']['host'])
+    return sources['ring_on_trace']
+
+
+def _place_ring(copies):
+    from paddle_tpu import observe
+
+    def say(**fields):
+        print('SPAN_CLOCK %s' % json.dumps(fields, sort_keys=True),
+              flush=True)
+    recorder = observe.spans()
+    measure = getattr(recorder, 'offset_to', None)
+    if measure is None:
+        say(matched=0, why='the recorder has no offset_to')
+        return None
+    found = measure(copies, MIN_MATCHED)
+    if found is None:
+        say(matched=0, why='under %d spans in both records' % MIN_MATCHED)
+        return None
+    say(**found)
+    if found['residual_us_p95'] > MAX_RESIDUAL_US or \
+            found['matched'] < MIN_SHARE * found['copies']:
+        return None
+    offset = found['offset_ns']
+    return [(ev['name'], int(ev['ts'] * 1e3 + offset),
+             int((ev['ts'] + ev['dur']) * 1e3 + offset))
+            for ev in recorder.events()
+            if ev.get('ph') == 'X' and ev['dur'] > 0]

@@ -36,17 +36,24 @@ PUBLISHED = {
     'prefix_dense_sliding_window_pattern': 1}
 CUT = {'num_hidden_layers': (4, 32), 'num_experts': (16, 128),
        'vocab_size': (32768, 262144)}
-# PR 25's serving metrics: the engine feeds their spans and counters for
-# this block too, but tests/benchmark/test_hot_path_readers.py asserts
-# ``workloads == ['tbig_lm.chat_steady']`` of each, so the new cell does
-# not join them in this PR
-PR25_ONLY_TBIG_LM = {
+# what ``tbig_lm.chat_steady`` reported when this cell came (PR 28) and
+# reports still, and PR 25's serving metrics, which this cell's engine
+# feeds too and which list it since PR 42 (less the five it retired)
+OLDER_CELL = 'tbig_lm.chat_steady'
+SHARED_SINCE_PR28 = {
+    'serve.recompiles', 'serve.queue_wait_ms', 'serve.prefill_ms',
+    'serve.decode_step_ms', 'serve.batch_occupancy',
+    'serve.kv_pool_used_pct', 'serve.copy_busy_share', 'serve.ttft_p90_ms',
+    'serve.itl_p95_ms', 'serve.tokens_per_s'}
+PR25_SERVING = {
     'serve.worker_prefill_share', 'serve.worker_step_share',
     'serve.worker_idle_share', 'serve.step_build_ms',
     'serve.step_dispatch_ms', 'serve.step_fetch_ms', 'serve.step_emit_ms',
-    'serve.fetch_wake_ms', 'serve.idle_attributed_pct',
-    'serve.idle_under_host_pct', 'serve.idle_under_fetch_pct',
-    'serve.live_tokens_per_step', 'serve.idle_under_dispatch_pct'}
+    'serve.live_tokens_per_step'}
+MINE = {'serve.moe_step_hbm_share', 'serve.moe_ffn_busy_share',
+        'serve.moe_ffn_roofline_share', 'serve.moe_local_assignment_pct',
+        'serve.moe_load_max_over_mean', 'serve.window_bound_row_share',
+        'serve.prefill_chunks_per_prompt'}
 
 
 def _module(kind, name):
@@ -59,15 +66,11 @@ def resolved():
 
 
 # ------------------------------------------------------- the files
-def test_the_cell_resolves_to_files_by_name(resolved):
+def shape_the_cell_resolves_to_files_by_name(m):
     """What test_benchmark.py asserts of every cell, for a cell whose
-    configuration is a cut with a runner of its own. Its own case of
-    that cell, ``test_cell_resolves_to_files_by_name[command_a_plus.
-    mixed_len_steady]``, fails by ``runner in ('train', 'serve')`` and
-    ``reduced == []``, which a ``benchmark`` PR loosens in that file
-    (PERF.md section 7); nothing mutes it."""
-    assert manifest.problems(MANIFEST) == []
-    r = resolved
+    configuration is a cut with a runner of its own."""
+    assert manifest.problems(m) == []
+    r = manifest.resolve(m, CELL)
     assert os.path.isfile(r['runner']) and os.path.isfile(r['reference'])
     assert r['config']['runner'] == 'serve_block'
     assert r['cell']['chips'] == 1
@@ -77,37 +80,40 @@ def test_the_cell_resolves_to_files_by_name(resolved):
         'setup_s', 'ttft_mean_ms', 'itl_mean_ms'}
     for metric in r['per_layer']:
         assert os.path.isfile(metric['reader']) and metric['spec']['doc']
-    names = {m['entry']['name'] for m in r['per_layer']}
-    assert {'serve.moe_step_hbm_share', 'serve.moe_ffn_busy_share',
-            'serve.moe_ffn_roofline_share', 'serve.moe_local_assignment_pct',
-            'serve.moe_load_max_over_mean', 'serve.window_bound_row_share',
-            'serve.prefill_chunks_per_prompt'} <= names
-    # tbig_lm's shape function reads tbig_lm's model keys; PR 25's
-    # thirteen worker metrics are asserted to list tbig_lm's cell alone
-    # (test_hot_path_readers.py) and stay so until that is loosened
+    names = {p['entry']['name'] for p in r['per_layer']}
+    assert MINE | SHARED_SINCE_PR28 | PR25_SERVING <= names
+    # tbig_lm's shape function reads tbig_lm's model keys: an entry whose
+    # ``args`` carry one configuration's shapes is that configuration's
     assert 'serve.decode_step_hbm_share' not in names
-    assert not PR25_ONLY_TBIG_LM & names
-    entry = [c for c in MANIFEST['configs'] if c['name'] == 'command_a_plus']
-    assert entry[0]['reduced'] == r['config']['reduced'] == sorted(
+    (entry,) = [c for c in m['configs'] if c['name'] == 'command_a_plus']
+    assert entry['reduced'] == r['config']['reduced'] == sorted(
         CUT, key=list(CUT).index)
-    assert len(entry[0]['source']) <= 200
+    assert len(entry['source']) <= 200
 
 
-def test_every_cell_keeps_the_metrics_it_had(resolved):
-    """The new cell joined lists; it took nothing from the old ones."""
-    old = manifest.resolve(MANIFEST, 'tbig_lm.chat_steady')
-    mine = {m['entry']['name'] for m in resolved['per_layer']}
-    theirs = {m['entry']['name'] for m in old['per_layer']}
-    assert theirs - mine == {'serve.decode_step_hbm_share'} | \
-        PR25_ONLY_TBIG_LM
-    assert all(n.startswith('serve.moe_') or n in (
-        'serve.window_bound_row_share', 'serve.prefill_chunks_per_prompt')
-        for n in mine - theirs)
-    for m in old['per_layer']:
-        name = m['entry']['name']
-        assert m['entry']['workloads'] == ['tbig_lm.chat_steady'] + (
-            [CELL] if name in mine else [])
-        assert 'bound' not in m['entry'] and m['spec']['doc']
+def shape_every_cell_keeps_the_metrics_it_had(m):
+    """The new cell joined lists; it took nothing from the old ones. By
+    name and by membership: a later cell joins the same lists."""
+    mine = {p['entry']['name']: p for p in manifest.resolve(
+        m, CELL)['per_layer']}
+    theirs = {p['entry']['name']: p for p in manifest.resolve(
+        m, OLDER_CELL)['per_layer']}
+    kept = SHARED_SINCE_PR28 | PR25_SERVING | {'serve.decode_step_hbm_share'}
+    assert kept <= set(theirs)
+    assert kept - set(mine) == {'serve.decode_step_hbm_share'}
+    for name in kept:
+        entry = theirs[name]['entry']
+        assert OLDER_CELL in entry['workloads']
+        assert (CELL in entry['workloads']) == (name in mine)
+        assert 'bound' not in entry and theirs[name]['spec']['doc']
+
+
+def test_the_cell_resolves_to_files_by_name():
+    shape_the_cell_resolves_to_files_by_name(MANIFEST)
+
+
+def test_every_cell_keeps_the_metrics_it_had():
+    shape_every_cell_keeps_the_metrics_it_had(MANIFEST)
 
 
 @pytest.mark.parametrize('key', sorted(PUBLISHED))

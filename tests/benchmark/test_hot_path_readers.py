@@ -1,6 +1,7 @@
-"""The three readers that turn the program's hot-path spans into
-per-layer metrics, on synthetic ``(name, start, dur)`` lists, and the
-manifest with their entries."""
+"""What turns the program's hot-path spans into per-layer metrics and
+into the names of the device's idle gaps, on synthetic ``(name, start,
+dur)`` lists (the innermost-span rule, ``registry_sum_share``), and the
+manifest with PR 25's entries."""
 
 import os
 import sys
@@ -12,7 +13,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-from benchmark import manifest  # noqa: E402
+from benchmark import manifest, tracelib  # noqa: E402
 
 MANIFEST = manifest.load(REPO)
 FAMILY = ['^decode\\.', '^executor\\.']
@@ -42,65 +43,97 @@ HOST = [('decode.step', 0, 190),
         ('decode.prefill.emit', 395, 45)]
 
 
-@pytest.mark.parametrize('args, want', [
+def _idle(device, lo=0, hi=1000):
+    return tracelib.subtract([(lo, hi)], tracelib.merged(tracelib.clipped(
+        tracelib.spans_of(device), lo, hi)))
+
+
+@pytest.mark.parametrize('spans, among, want', [
     # every decode.* / executor.* span: all idle but 190-200 and 290
-    ({'spans': FAMILY}, 100.0 * (90 + 100) / 200),
+    (FAMILY, None, 90 + 100),
     # the worker's Python, innermost winning: emit owns 120-190; the
     # prefill's emit 395-400; decode.prefill itself is not counted
-    ({'spans': ['^decode\\.step\\.emit$', '^decode\\.prefill\\.emit$'],
-      'among': FAMILY}, 100.0 * (70 + 5) / 200),
+    (['^decode\\.step\\.emit$', '^decode\\.prefill\\.emit$'], FAMILY, 70 + 5),
     # under a fetch: 100-120 of the step's, 300-320 of the prefill's
-    ({'spans': ['^decode\\.step\\.fetch$', '^executor\\.fetch$'],
-      'among': FAMILY}, 100.0 * (20 + 20) / 200),
+    (['^decode\\.step\\.fetch$', '^executor\\.fetch$'], FAMILY, 20 + 20),
     # under a dispatch, the prefill's fetch taken out: 320-390
-    ({'spans': ['^decode\\.step\\.dispatch$', '^decode\\.prefill\\.run$',
-                '^executor\\.(run|lookup|prepare|enqueue)$'],
-      'among': FAMILY}, 100.0 * 70 / 200),
+    (['^decode\\.step\\.dispatch$', '^decode\\.prefill\\.run$',
+      '^executor\\.(run|lookup|prepare|enqueue)$'], FAMILY, 70),
     # without ``among`` a counted parent keeps its children's time
-    ({'spans': ['^decode\\.prefill$']}, 100.0 * 100 / 200),
+    (['^decode\\.prefill$'], None, 100),
     # ... and keeps only 390-395, between two children, when they compete
-    ({'spans': ['^decode\\.prefill$'], 'among': FAMILY},
-     100.0 * 5 / 200),
-    # no span of that name in the trace (the parent commit): nothing
-    ({'spans': ['^scheduler\\.']}, None),
+    (['^decode\\.prefill$'], FAMILY, 5),
+    # no span of that name: nothing is owned
+    (['^scheduler\\.'], None, 0),
 ])
-def test_trace_gap_cover_gives_idle_time_to_the_innermost_span(args, want):
-    got = _reader('trace_gap_cover').read(args, _trace(DEVICE, HOST))
-    assert got == (None if want is None else pytest.approx(want))
+def test_owned_gives_idle_time_to_the_innermost_span(spans, among, want):
+    """``tracelib.owned``, the rule ``readers/ring_gap_cover.py`` and
+    ``breakdown.idle_gaps`` share (until PR 42 ``trace_gap_cover``'s):
+    of the device's idle 100-200 and 300-400, the nanoseconds under a
+    counted span, a child that is not counted taking its time from a
+    counted parent."""
+    counted = set(tracelib.matching(HOST, spans))
+    family = set(tracelib.matching(HOST, among or spans)) | counted
+    mine = tracelib.owned([(s, s + d, (name, s, d) in counted)
+                           for name, s, d in family])
+    idle = _idle(DEVICE)
+    assert tracelib.total(idle) == 200
+    assert tracelib.total(idle) - tracelib.total(
+        tracelib.subtract(idle, mine)) == want
 
 
-@pytest.mark.parametrize('sources', [
-    {'trace': None},
-    {'trace': {'first': [], 'host': HOST}},                  # no window
-    _trace([('%fusion.1', 0, 1000)], HOST),                  # never idle
+@pytest.mark.parametrize('spans, want', [
+    # of two that start together the one that ends first is innermost
+    ([(0, 10, 'a'), (0, 4, 'b')], [(0, 4, 'b'), (4, 10, 'a')]),
+    # the latest to start owns until it ends, then its parent again
+    ([(0, 10, 'a'), (2, 5, 'b'), (3, 4, 'c')],
+     [(0, 2, 'a'), (2, 3, 'b'), (3, 4, 'c'), (4, 5, 'b'), (5, 10, 'a')]),
+    # a stretch under no span is in no triple
+    ([(0, 2, 'a'), (5, 6, 'b')], [(0, 2, 'a'), (5, 6, 'b')]),
+    ([], []),
 ])
-def test_trace_gap_cover_has_nothing_to_read(sources):
-    assert _reader('trace_gap_cover').read({'spans': FAMILY},
-                                           sources) is None
+def test_innermost_is_the_latest_to_start(spans, want):
+    assert [(t0, t1, sp[2]) for t0, t1, sp in tracelib.innermost(spans)] \
+        == want
 
 
-@pytest.mark.parametrize('host, want_ms', [
-    # 120 - 100 = 20 ns; the while's end is not inside the span
-    ([('decode.step.fetch', 10, 110)], 20e-6),
-    # two spans, the second ends 5 after copy.2: mean of 20 and 5
-    ([('decode.step.fetch', 10, 110), ('decode.step.fetch', 210, 95)],
-     12.5e-6),
-    # a span with no device op ending inside it is left out
-    ([('decode.step.fetch', 10, 110), ('decode.step.fetch', 310, 40)],
-     20e-6),
-    # nested ops: the last end inside wins (fusion.4 at 450)
-    ([('decode.step.fetch', 390, 70)], 10e-6),
-    # a span that starts before the traced window is left out
-    ([('decode.step.fetch', -5, 125)], None),
-    ([('decode.step.emit', 10, 110)], None),
-    ([], None),
+@pytest.mark.parametrize('labels, want', [
+    # the train runner's two spans, as until PR 42: the wait covers both
+    (['^bench\\.(dispatch|wait_oldest)$'],
+     ['bench.wait_oldest', 'bench.wait_oldest']),
+    # with the worker's and the executor's spans the innermost names a
+    # gap: 100-200 is the step's fetch to 120 and its emit from there;
+    # 300-400 the prefill's run but for its fetch's 20 and the emit's 5
+    (['^bench\\.(dispatch|wait_oldest)$'] + FAMILY,
+     ['decode.step.emit', 'decode.prefill.run']),
+    (['^decode\\.(idle|admit|prefill|step)$'],
+     ['decode.step', 'decode.prefill']),
+    (['^scheduler\\.'], ['unattributed', 'unattributed']),
 ])
-def test_trace_wake_is_span_end_minus_last_device_op_end(host, want_ms):
-    got = _reader('trace_wake').read({'span': 'decode.step.fetch'},
-                                     _trace(DEVICE, host))
-    assert got == (None if want_ms is None else pytest.approx(want_ms))
-    assert _reader('trace_wake').read({'span': 'decode.step.fetch'},
-                                      {'trace': None}) is None
+def test_idle_gaps_are_named_after_the_innermost_span(labels, want):
+    """``run.py``'s ``breakdown.idle_gaps`` under its ``GAP_LABELS``:
+    the serving cells' gaps read ``unattributed`` until PR 42."""
+    gaps = tracelib.idle_gaps(DEVICE, HOST, 0, 1000, labels, 5)
+    assert gaps == [[name, 100e-9] for name in want]
+
+
+def test_run_names_the_gaps_by_the_ring_laid_over_the_trace(monkeypatch):
+    """``run.named_idle_gaps``: a wait that an edge of the tail cut is
+    in the ring alone (the trace holds no event of a span it did not see
+    open and close), and the gap under it is named after it."""
+    from benchmark import run as bench
+    # the engine's own account of an empty device is no worker span: it
+    # is cut where programs arrive, not where spans nest, and names none
+    ring = [('decode.idle', -50, 195), ('decode.step', 280, 420),
+            ('decode.device_empty', 150, 310)]
+    monkeypatch.setattr(tracelib, '_place_ring', lambda copies: ring)
+    sources = _trace(DEVICE, [('bench.window', 0, 1000)])
+    assert bench.named_idle_gaps(sources) == [
+        ['decode.idle', 100e-9], ['decode.step', 100e-9]]
+    # a ring that cannot be placed: the trace's own spans alone
+    monkeypatch.setattr(tracelib, '_place_ring', lambda copies: None)
+    assert [g[0] for g in bench.named_idle_gaps(_trace(DEVICE, HOST))] == [
+        'decode.step.emit', 'decode.prefill.run']
 
 
 def _registry(**sums):
@@ -134,28 +167,39 @@ def test_registry_sum_share_is_one_label_over_the_partition(
     assert got == (None if want is None else pytest.approx(want))
 
 
+# PR 25's entries, less the five PR 42 retired (``fetch_wake`` and the
+# four ``idle_*`` shares read the next step's ops since PR 35 put a step
+# in flight; ``serve.idle_under_states_pct``, ``idle_in_device_empty_pct``
+# and the ``device_empty_*`` shares took their place)
 NEW_METRICS = {
     'tbig_lm.chat_steady': {
         'serve.worker_prefill_share', 'serve.worker_step_share',
         'serve.worker_idle_share', 'serve.step_build_ms',
         'serve.step_dispatch_ms', 'serve.step_fetch_ms',
-        'serve.step_emit_ms', 'serve.fetch_wake_ms',
-        'serve.idle_attributed_pct', 'serve.idle_under_host_pct',
-        'serve.idle_under_fetch_pct', 'serve.live_tokens_per_step',
-        'serve.idle_under_dispatch_pct'},
+        'serve.step_emit_ms', 'serve.live_tokens_per_step'},
     'tbig_nmt.train_seq128': {
         'train.exe_lookup_ms', 'train.exe_prepare_ms',
         'train.exe_enqueue_ms'},
 }
 
 
-@pytest.mark.parametrize('cell', sorted(NEW_METRICS))
-def test_manifest_is_sound_with_the_hot_path_metrics(cell):
-    assert manifest.problems(MANIFEST) == []
-    resolved = {m['entry']['name']: m
-                for m in manifest.resolve(MANIFEST, cell)['per_layer']}
+def cell_reports_the_hot_path_metrics(m, cell):
+    resolved = {r['entry']['name']: r
+                for r in manifest.resolve(m, cell)['per_layer']}
     assert NEW_METRICS[cell] <= set(resolved)
     for name in NEW_METRICS[cell]:
         entry = resolved[name]['entry']
-        assert entry['workloads'] == [cell] and 'bound' not in entry
+        assert cell in entry['workloads'] and 'bound' not in entry
         assert resolved[name]['spec']['doc']
+
+
+def shape_the_hot_path_metrics_are_reported(m):
+    assert manifest.problems(m) == []
+    for cell in NEW_METRICS:
+        cell_reports_the_hot_path_metrics(m, cell)
+
+
+@pytest.mark.parametrize('cell', sorted(NEW_METRICS))
+def test_manifest_is_sound_with_the_hot_path_metrics(cell):
+    assert manifest.problems(MANIFEST) == []
+    cell_reports_the_hot_path_metrics(MANIFEST, cell)

@@ -9,6 +9,7 @@ topology."""
 
 import json
 import os
+import re
 import sys
 
 import numpy as np
@@ -49,15 +50,37 @@ STATED = {
                      'type': 'yarn'}}
 CUT = {'num_hidden_layers': (6, 61), 'n_routed_experts': (12, 384),
        'vocab_size': (20480, 163840)}
-NEW_METRICS = {
+# the entries that carry this configuration's shapes or its prefix cache
+OWN_METRICS = {
     'serve.mla_attn_busy_share', 'serve.mla_decode_attn_roofline_share',
     'serve.mla_prefill_attn_mxu_share', 'serve.mla_moe_ffn_busy_share',
-    'serve.mla_moe_local_assignment_pct', 'serve.mla_moe_load_max_over_mean',
     'serve.prefix_tokens_reused_share', 'serve.prefix_hit_ttft_ms',
-    'serve.prefix_miss_ttft_ms', 'serve.prefix_evicted_pages',
-    'serve.mla_prefill_chunk_ms', 'serve.mla_decode_step_ms',
-    'serve.mla_queue_wait_ms', 'serve.mla_worker_prefill_share',
-    'serve.mla_kv_pool_used_pct', 'serve.mla_recompiles'}
+    'serve.prefix_miss_ttft_ms', 'serve.prefix_evicted_pages'}
+# the shared readers: entries of this cell's own (``serve.mla_*``) until
+# PR 42, since then the one entry a reader over the serving cells; and
+# what its engine fed and ISSUE 36's cap of sixteen entries left unread
+SHARED_METRICS = {
+    'serve.moe_local_assignment_pct', 'serve.moe_load_max_over_mean',
+    'serve.prefill_chunk_ms', 'serve.decode_step_ms', 'serve.queue_wait_ms',
+    'serve.worker_prefill_share', 'serve.kv_pool_used_pct',
+    'serve.recompiles', 'serve.mla_prefill_expanded_chunk_share',
+    'serve.worker_step_share', 'serve.worker_idle_share',
+    'serve.batch_occupancy', 'serve.step_build_ms',
+    'serve.step_dispatch_ms', 'serve.step_fetch_ms',
+    'serve.steps_ahead_share', 'serve.prefill_chunks_per_prompt',
+    'serve.attn_pages_read_share', 'serve.attn_pages_held_share',
+    'serve.ttft_p90_ms', 'serve.itl_p95_ms', 'serve.tokens_per_s',
+    'serve.gap_under_prefill_ms', 'serve.idle_in_device_empty_pct'}
+
+
+def name_in(names, shared):
+    """The name under which the cell reports a shared quantity: the
+    shared entry's or, while the pin outside the benchmark's paths
+    stands, its kept copy's (``serve.mla_*``: ``KEPT_COPIES`` in
+    ``test_benchmark.py``). One of the two, never both."""
+    copy = shared.replace('serve.', 'serve.mla_', 1)
+    (name,) = [n for n in (shared, copy) if n in names]
+    return name
 
 
 def _module(kind, name):
@@ -70,9 +93,9 @@ def resolved():
 
 
 # ------------------------------------------------------- the files
-def test_the_cell_resolves_to_files_by_name(resolved):
-    assert manifest.problems(MANIFEST) == []
-    r = resolved
+def shape_the_cell_resolves_to_files_by_name(m):
+    assert manifest.problems(m) == []
+    r = manifest.resolve(m, CELL)
     assert os.path.isfile(r['runner']) and os.path.isfile(r['reference'])
     assert r['config']['runner'] == 'serve_sessions'
     assert r['cell']['chips'] == 1 and r['cell']['traffic'] == \
@@ -83,32 +106,44 @@ def test_the_cell_resolves_to_files_by_name(resolved):
         'setup_s', 'ttft_mean_ms', 'itl_mean_ms'}
     for metric in r['per_layer']:
         assert os.path.isfile(metric['reader']) and metric['spec']['doc']
-    (entry,) = [c for c in MANIFEST['configs'] if c['name'] == 'kimi_k2_6']
+    (entry,) = [c for c in m['configs'] if c['name'] == 'kimi_k2_6']
     assert entry['reduced'] == r['config']['reduced'] == list(CUT)
     assert len(entry['source']) <= 200 and len(r['cell']['why']) <= 200
     assert entry['source'].startswith(r['config']['source'])
 
 
-def test_the_cell_reports_its_metrics_and_the_two_end_to_end(resolved):
-    """The cell reports each per-layer metric PR 36 brought for it and
-    the two end-to-end metrics under the bounds they have. Membership
-    only: a later cell may join these lists, and a later benchmark PR
-    may merge them with the older cells' (PERF.md 7)."""
-    mine = {m['entry']['name'] for m in resolved['per_layer']}
-    assert mine >= NEW_METRICS
-    for metric in MANIFEST['per_layer']:
-        if metric['name'] in NEW_METRICS:
-            assert CELL in metric['workloads']
+def shape_the_cell_reports_its_metrics_and_the_two_end_to_end(m):
+    """The cell reports each per-layer metric named here and the two
+    end-to-end metrics under the bounds they have. Membership only: a
+    later cell may join these lists."""
+    resolved = manifest.resolve(m, CELL)
+    mine = {p['entry']['name'] for p in resolved['per_layer']}
+    assert mine >= OWN_METRICS
+    for shared in SHARED_METRICS:
+        name_in(mine, shared)           # under one name, and only one
+    for metric in m['per_layer']:
+        if metric['name'].startswith('serve.mla_') and \
+                metric['name'] in OWN_METRICS:
+            assert all(cell.startswith('kimi_k2_6.')
+                       for cell in metric['workloads'])
     for name in ('ttft_mean_ms', 'itl_mean_ms'):
-        (e,) = [e for e in MANIFEST['end_to_end'] if e['name'] == name]
+        (e,) = [e for e in m['end_to_end'] if e['name'] == name]
         assert CELL in e['workloads'] and e['bound'] == 0.1
     e2e = {e['name'] for e in resolved['end_to_end']}
     for metric in resolved['per_layer']:
         assert metric['entry']['moves'] in e2e
     # no share of a roofline or of a peak can be joined: none is open
-    for metric in MANIFEST['per_layer']:
+    for metric in m['per_layer']:
         if 'roofline' in metric['name'] or 'mfu' in metric['name']:
             assert 'workloads' in metric
+
+
+def test_the_cell_resolves_to_files_by_name():
+    shape_the_cell_resolves_to_files_by_name(MANIFEST)
+
+
+def test_the_cell_reports_its_metrics_and_the_two_end_to_end():
+    shape_the_cell_reports_its_metrics_and_the_two_end_to_end(MANIFEST)
 
 
 @pytest.mark.parametrize('key', sorted(PUBLISHED))
@@ -376,7 +411,41 @@ def test_prefill_reader_sets_each_chunks_flops_against_its_own_program_run(
     np.testing.assert_allclose(
         got, 100.0 * (pairs * 64 * 320 * 2 / 1e9) / (ns / 1e3))
     said = json.loads(capsys.readouterr().out.split('PREFILL_CHUNKS ')[1])
-    assert said == {'read': 5, 'dropped': 2, 'why': None}
+    assert said == {'read': 5, 'dropped': 2, 'why': None, 'shift': 0}
+
+
+@pytest.mark.parametrize('early_ms, shift', [(0.9, 1), (1.9, 1), (0.0, 0)])
+def test_prefill_reader_reads_a_tail_whose_device_clock_runs_early(
+        resolved, capsys, early_ms, shift):
+    """The profiler sets the device's clock against the host's to within
+    the 0.5-1.8 ms the host takes from a prefill span's start to its
+    first dispatch: where every program run appears that much earlier
+    than it ran, the anchor's first chunk starts just before its span,
+    the first run at or after the span is its second, and every chunk
+    would be set one run off (one of PR 37's five traced runs read
+    nothing so). The neighbouring shift is tried and read; the answer is
+    the aligned trace's."""
+    reader = _module('readers', 'prefill_ops_mxu')
+    sources = _prefill_sources(resolved)
+    # the anchor (the hit at 4,000-4,900 ms, its run at 4,100) with its
+    # dispatch 0.5 ms after the span's start, as on the chip
+    early = int(early_ms * 1e6)
+    tight = 99500000 if early else 0
+    sources['prefill_program_runs'] = [
+        (name, s - tight - early, d) for name, s, d in
+        sources['prefill_program_runs']]
+    sources['trace']['first'] = [
+        (name, s - tight - early, d) for name, s, d in
+        sources['trace']['first']]
+    spec = resolved_metric(resolved, 'serve.mla_prefill_attn_mxu_share')
+    args = dict(spec['args'], match=[r'f32\[64,512,512\]'])
+    got = reader.read(args, sources)
+    pairs = 300 + 50 + 7 + 1000 + 2000
+    ns = 300 + 100 + 40 + 500 + 900
+    np.testing.assert_allclose(
+        got, 100.0 * (pairs * 64 * 320 * 2 / 1e9) / (ns / 1e3))
+    said = json.loads(capsys.readouterr().out.split('PREFILL_CHUNKS ')[1])
+    assert said == {'read': 5, 'dropped': 2, 'why': None, 'shift': shift}
 
 
 @pytest.mark.parametrize('change,why', [
@@ -427,6 +496,7 @@ def test_runner_hands_over_every_chunk_in_dispatch_order():
     clock of the profiler's start."""
     from paddle_tpu import observe
     runner = _module('runners', 'serve_sessions')
+    observe.reset()        # what an earlier test of this process left
     observe.enable()
     try:
         rec = observe.spans()
@@ -472,17 +542,104 @@ def test_trace_patterns_are_the_configs_numbers(resolved):
         b *= 2
     assert 'f32\\[(1,1,)?%d,(%s),512\\]' % (heads, '|'.join(buckets)) \
         in text
-    assert 'f32\\[8,1,%d,1,512' % heads in text
-    for name in ('serve.mla_decode_attn_roofline_share',
-                 'serve.mla_prefill_attn_mxu_share'):
-        assert resolved_metric(resolved, name)['args']['match'] == \
-            attn['args']['match']
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    rank = spec.latent[FULL].kv_rank
+    assert 'f32\\[%d,1,%d,1,%d' % (pa.BLOCK_ROWS, heads, rank) in text
+    # the decode loop's scatter of closed rows and a pair's normaliser
+    assert 'f32\\[%d,%d,%d\\]' % (geometry['max_batch'] + pa.BLOCK_ROWS,
+                                    heads, rank) in text
+    assert 'f32\\[%d,%d\\]' % (pa.BLOCK_ROWS, heads) in text
+    assert resolved_metric(
+        resolved, 'serve.mla_decode_attn_roofline_share')['args'][
+            'match'] == attn['args']['match']
+    # a prefill program has no pair loop: its reader keeps the patterns
+    # of the arena, the score blocks and the chunks, and not those two
+    assert resolved_metric(
+        resolved, 'serve.mla_prefill_attn_mxu_share')['args']['match'] == \
+        attn['args']['match'][:3]
+    # 8 rows by heads is a pair loop's shape only while a step's batch is
+    # not 8 rows itself
+    assert geometry['max_batch'] != pa.BLOCK_ROWS
     ffn = resolved_metric(resolved, 'serve.mla_moe_ffn_busy_share')
     assert 'bf16\\[%d,(%d|1),(%d,%d|%d,%d)\\]' % (
         config['num_hidden_layers'] - 1, config['n_routed_experts'],
         config['hidden_size'], config['moe_intermediate_size'],
         config['moe_intermediate_size'], config['hidden_size']) \
         in ffn['args']['match'][0]
+
+
+# Op lines of the decode program as the v5e's compiler writes them at the
+# configuration's heads, ranks and max_batch (compiled here for a described
+# chip, PR 42; layouts shortened): the pair loop's, which the patterns PR 42
+# added are for, then ops near them in shape that are not the attention's.
+PAIR_LOOP_OPS = {
+    'scatter': '%fusion.472 = f32[40,64,512]{2,1,0:T(8,128)S(1)} fusion('
+               'f32[40,64,512]{2,1,0:T(8,128)S(1)} %out, s32[8]{0:T(128)S(1)} '
+               '%goes, f32[8,64,512]{2,1,0:T(8,128)S(1)} %done)',
+    'zeros': '%broadcast_in_dim.392 = f32[40,64,512]{2,1,0:T(8,128)S(1)} '
+             'broadcast(f32[]{:T(128)} %constant.352)',
+    'result': '%copy.125 = bf16[40,64,512]{2,0,1:T(8,128)(2,1)S(1)} copy('
+              'f32[40,64,512]{2,1,0:T(8,128)S(1)} %while.56)',
+    'normaliser': '%fusion.457 = f32[8,64]{1,0:T(8,128)S(1)} fusion('
+                  'f32[8,64,512]{2,1,0:T(8,128)S(1)} %weights, f32[8,64]{1,0:'
+                  'T(8,128)S(1)} %top, pred[8,512]{1,0:T(8,128)(4,1)S(1)} '
+                  '%seen)',
+    'open_row': '%compare_bitcast_fusion.20 = pred[64]{0:T(512)(128)(4,1)S(1)'
+                '} fusion(f32[1,64,1]{1,2,0:T(1,128)S(1)} %state, f32[8,64]'
+                '{1,0:T(8,128)S(1)} %top)',
+    'scale': '%fusion.461 = (f32[64]{0:T(128)S(1)}, f32[64]{0:T(128)S(1)}) '
+             'fusion(f32[1,64,1]{1,2,0:T(1,128)S(1)} %state, f32[8,64]{1,0:'
+             'T(8,128)S(1)} %top)',
+    'merge': '%bitcast_dynamic-update-slice_fusion.33 = f32[8,1,64,1,512]'
+             '{4,2,3,1,0:T(8,128)S(1)} fusion(f32[8,1,64,1,512]{4,2,3,1,0:'
+             'T(8,128)S(1)} %acc, f32[64]{0:T(128)S(1)} %keep, f32[64]'
+             '{0:T(128)S(1)} %scale, f32[8,1,64,1,512]{4,2,3,1,0:T(8,128)'
+             'S(1)} %partial)'}
+OTHER_OPS = {
+    # the new key's rotary part: batch rows by the rope width, 64 as the
+    # heads are
+    'rope_slice': '%gather.61 = f32[32,64]{1,0:T(8,128)S(1)} slice('
+                  'f32[32,576]{1,0:T(8,128)S(1)} %fusion.181)',
+    # the query's up-projection and its no-rope part (the doc's "query's
+    # scaling": the step's, not the loop's)
+    'query_up': '%fusion.186 = f32[32,64,192]{2,0,1:T(8,128)S(1)} fusion('
+                'bf16[64,192,1536]{2,1,0:T(8,128)(2,1)} %w_uq, f32[32,1536]'
+                '{1,0:T(8,128)S(1)} %q)',
+    'query_nope': '%slice.623 = f32[32,64,64]{2,0,1:T(8,128)S(1)} slice('
+                  'f32[32,64,192]{2,0,1:T(8,128)S(1)} %fusion.186)',
+    # the router: batch rows by the 8 experts a token takes
+    'gate': '%broadcast_add_fusion = (f32[32,8]{0,1:T(8,128)S(1)}, f32[32,8]'
+            '{0,1:T(8,128)S(1)}) fusion(f32[8]{0:T(128)S(1)} %bias, bf16[1,'
+            '7168,12]{1,2,0:T(8,128)(2,1)S(1)} %w, f32[32,7168]{1,0} %x)',
+    'top_k': '%sort.1 = (f32[32,8]{0,1:T(8,128)}, s32[32,8]{0,1:T(8,128)S(1)'
+             '}) sort(f32[32,8]{0,1:T(8,128)S(1)} %scores, s32[32,8]{0,1:'
+             'T(8,128)S(1)} %iota.11)',
+    # the pair list's own: the mask and a pair's pages
+    'mask': '%fusion.455 = pred[8,512]{1,0:T(8,128)(4,1)S(1)} fusion(s32[8]'
+            '{0:T(128)S(1)} %lo, s32[8]{0:T(128)S(1)} %hi, s32[8]{0:T(128)'
+            'S(1)} %at)',
+    'pages': '%fusion.453 = s32[8,16]{1,0:T(8,128)S(1)} fusion(s32[2080,16]'
+             '{1,0:T(8,128)} %tables, s32[]{:T(128)S(6)} %first)',
+    # the layer loop carries the attention's state and lasts the program
+    'loop': '%while.56 = (s32[], f32[40,64,512]{2,1,0}, f32[8,64]{1,0}, '
+            'bf16[6,16384,32,640]{3,2,1,0}) while(%tuple.9)'}
+
+
+@pytest.mark.parametrize('op', sorted(PAIR_LOOP_OPS) + sorted(OTHER_OPS))
+def test_the_attention_patterns_find_the_pair_loop_and_nothing_near_it(
+        resolved, op):
+    for name in ('serve.mla_attn_busy_share',
+                 'serve.mla_decode_attn_roofline_share'):
+        patterns = resolved_metric(resolved, name)['args']['match']
+        line = PAIR_LOOP_OPS.get(op) or OTHER_OPS[op]
+        assert any(re.search(p, line) for p in patterns) == \
+            (op in PAIR_LOOP_OPS), (name, op)
+    # ... and a prefill chunk's reader, which keeps the patterns it had,
+    # knows none of the loop's ops but the merge it always knew
+    prefill = resolved_metric(
+        resolved, 'serve.mla_prefill_attn_mxu_share')['args']['match']
+    assert any(re.search(p, (PAIR_LOOP_OPS.get(op) or OTHER_OPS[op]))
+               for p in prefill) == (op == 'merge')
 
 
 def test_the_benchmarks_reference_is_the_repositorys():
@@ -499,8 +656,17 @@ def test_the_benchmarks_reference_is_the_repositorys():
 @pytest.fixture
 def own_environment(monkeypatch):
     """benchmark/run.py turns the executor's cost probe off for its
-    process; in a test process that has to end with the test."""
+    process and, traced, ``observe`` on: in a test process both have to
+    end with the test, and the registry the run counted into is emptied,
+    so that no later test of this worker hangs on whether this file ran
+    before it (xdist gives a worker whole files in any order)."""
+    from paddle_tpu import observe
     monkeypatch.setenv('PADDLE_TPU_OBSERVE_COST', '0')
+    observe.disable()
+    observe.reset()
+    yield
+    observe.disable()
+    observe.reset()
 
 
 def test_the_cell_rehearses_in_process(capsys, own_environment):
@@ -547,9 +713,10 @@ def test_the_traced_rehearsal_reads_the_counters_this_pr_adds(
     assert abs(got['serve.prefix_tokens_reused_share']
                - 100 * window['schedule_shared_share']) < 10
     assert got['serve.prefix_evicted_pages'] == 0
-    assert got['serve.mla_recompiles'] == 0
-    assert 0 < got['serve.mla_moe_local_assignment_pct'] <= 100
-    assert 0 < got['serve.mla_kv_pool_used_pct'] <= 100
+    assert got[name_in(got, 'serve.recompiles')] == 0
+    assert 0 < got[name_in(got, 'serve.moe_local_assignment_pct')] <= 100
+    assert 0 < got[name_in(got, 'serve.kv_pool_used_pct')] <= 100
+    assert 0 < got['serve.attn_pages_held_share'] <= 100
     assert 'serve.mla_decode_attn_roofline_share' not in got   # no device
     assert 'serve.mla_prefill_attn_mxu_share' not in got
 

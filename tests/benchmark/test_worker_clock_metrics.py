@@ -1,7 +1,8 @@
-"""The eighteen per-layer metrics of the decode worker's clock (PR 39):
-their entries and data files, ``readers/ring_gap_cover.py`` on a
-synthetic trace and ring, and a traced rehearsal in which the splits
-reach the line and sum to the older histograms."""
+"""The nine per-layer metrics of the decode worker's clock (PR 39; one
+entry a reader over the serving cells since PR 42): their entries and
+data files, ``readers/ring_gap_cover.py`` on a synthetic trace and ring,
+and a traced rehearsal in which the splits reach the line and sum to
+the older histograms."""
 
 import json
 import os
@@ -19,8 +20,9 @@ from benchmark import run as bench  # noqa: E402
 from paddle_tpu import observe  # noqa: E402
 
 MANIFEST = manifest.load(REPO)
-CHAT = ['tbig_lm.chat_steady', 'command_a_plus.mixed_len_steady']
-MLA = ['kimi_k2_6.doc_qa_sessions']
+# the cells whose engine feeds the worker's clock: each is on every list
+SERVING = ['tbig_lm.chat_steady', 'command_a_plus.mixed_len_steady',
+           'kimi_k2_6.doc_qa_sessions', 'dots3_note.long_ctx_steady']
 # <x>: (reader, unit, the end-to-end metric it moves)
 X = {
     'queue_under_prefill_ms': ('registry_ratio', 'ms', 'ttft_mean_ms'),
@@ -33,8 +35,7 @@ X = {
     'idle_under_states_pct': ('ring_gap_cover', '%', 'itl_mean_ms'),
     'idle_in_device_empty_pct': ('ring_gap_cover', '%', 'itl_mean_ms'),
 }
-ENTRIES = [(prefix + x, cells) for prefix, cells in
-           (('serve.', CHAT), ('serve.mla_', MLA)) for x in sorted(X)]
+PAIRS = [('serve.' + x, cell) for x in sorted(X) for cell in SERVING]
 
 
 @pytest.fixture(autouse=True)
@@ -48,50 +49,60 @@ def _observe_clean():
 
 
 # ------------------------------------------------------- the manifest
-def test_the_manifest_is_sound_and_ends_with_the_eighteen():
-    assert manifest.problems(MANIFEST) == []
-    assert sorted(p['name'] for p in MANIFEST['per_layer'][-18:]) == \
-        sorted(name for name, _ in ENTRIES)
-
-
-@pytest.mark.parametrize('name, cells', ENTRIES)
-def test_an_entry_lists_its_cells_and_moves_what_they_report(name, cells):
-    (entry,) = [p for p in MANIFEST['per_layer'] if p['name'] == name]
-    reader, unit, moves = X[name.replace('serve.mla_', '').replace(
-        'serve.', '')]
-    assert entry['workloads'] == cells
+def entry_lists_the_cell(m, name, cell):
+    """One (metric, cell) pair: the entry is found by its name, the cell
+    is on its list, and the cell reports what the entry moves."""
+    (entry,) = [p for p in m['per_layer'] if p['name'] == name]
+    reader, unit, moves = X[name[len('serve.'):]]
+    assert cell in entry['workloads']
     assert entry['unit'] == unit and entry['moves'] == moves
     assert entry['layer'] == 'decode engine' and 'bound' not in entry
     assert entry['source'] == ('device_trace' if reader == 'ring_gap_cover'
                                else 'program_span')
-    for cell in cells:
-        resolved = manifest.resolve(MANIFEST, cell)
-        assert moves in {e['name'] for e in resolved['end_to_end']}
-        (metric,) = [m for m in resolved['per_layer']
-                     if m['entry']['name'] == name]
-        assert metric['spec']['reader'] == reader and metric['spec']['doc']
-        assert os.path.basename(metric['reader']) == reader + '.py'
-    # the cell that cannot list them: its frozen test pins its metrics
-    assert 'dots3_note.long_ctx_steady' not in entry['workloads']
+    resolved = manifest.resolve(m, cell)
+    assert moves in {e['name'] for e in resolved['end_to_end']}
+    (metric,) = [r for r in resolved['per_layer']
+                 if r['entry']['name'] == name]
+    assert metric['spec']['reader'] == reader and metric['spec']['doc']
+    assert os.path.basename(metric['reader']) == reader + '.py'
+
+
+def shape_the_clocks_nine_are_entries_over_the_serving_cells(m):
+    """What this file holds of the manifest, for any manifest that has
+    grown from the committed one (test_benchmark.py calls every
+    ``shape_*`` of the test files on such a copy)."""
+    assert manifest.problems(m) == []
+    for name, cell in PAIRS:
+        entry_lists_the_cell(m, name, cell)
+
+
+def test_the_manifest_is_sound_and_holds_the_nine_by_name():
+    assert manifest.problems(MANIFEST) == []
+    assert {'serve.' + x for x in X} <= {
+        p['name'] for p in MANIFEST['per_layer']}
+
+
+@pytest.mark.parametrize('name, cell', PAIRS)
+def test_an_entry_lists_its_cells_and_moves_what_they_report(name, cell):
+    entry_lists_the_cell(MANIFEST, name, cell)
 
 
 def test_the_states_of_a_split_are_the_engines():
     """The data files name the series the engine feeds, state by
     state."""
     from paddle_tpu.serving.decode import engine
-    for prefix in ('serve.', 'serve.mla_'):
-        for x, state in (('queue_under_prefill_ms', 'prefill'),
-                         ('gap_under_step_ms', 'step'),
-                         ('device_empty_idle_share', 'idle')):
-            spec = manifest.read_json(os.path.join(
-                REPO, 'benchmark', 'layer_metrics', prefix + x + '.json'))
-            series = spec['args'].get('counter') or spec['args']['part']
-            assert series.endswith('{state=%s}' % state)
-            assert state in engine._STATES
-            if 'whole' in spec['args']:
-                assert spec['args']['whole'] == [
-                    'decode.worker_seconds{state=%s}' % s
-                    for s in engine._STATES]
+    for x, state in (('queue_under_prefill_ms', 'prefill'),
+                     ('gap_under_step_ms', 'step'),
+                     ('device_empty_idle_share', 'idle')):
+        spec = manifest.read_json(os.path.join(
+            REPO, 'benchmark', 'layer_metrics', 'serve.' + x + '.json'))
+        series = spec['args'].get('counter') or spec['args']['part']
+        assert series.endswith('{state=%s}' % state)
+        assert state in engine._STATES
+        if 'whole' in spec['args']:
+            assert spec['args']['whole'] == [
+                'decode.worker_seconds{state=%s}' % s
+                for s in engine._STATES]
 
 
 # ------------------------------- ring_gap_cover on a synthetic trace
