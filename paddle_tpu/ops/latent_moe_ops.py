@@ -88,11 +88,11 @@ kind's weights are a stack of their own (serving/decode/model.py:
 ``latent_param_shapes``), and so are the arenas: the full layers' latent
 rows and index keys, the sliding layers' latent rows, each ``[layers of
 the kind, NB, bs, width]`` under the one block table. ``segments`` gives
-``_extend_rows`` the published order: the leading dense layers one by
-one, then one ``lax.scan`` over the whole periods of layer kinds (a
-period's layers unrolled inside the body, each indexing its kind's
-stacks at ``layers before + period x layers a period + its place``),
-then the remainder. The arenas are the carry throughout, written in
+``_extend_rows`` the published order (``period_segments``): the leading
+dense layers one by one, then one ``lax.scan`` over the whole periods of
+layer kinds (a period's layers unrolled inside the body, each indexing
+its kind's stacks at ``layers before + period x layers a period + its
+place``), then the remainder. The arenas are the carry throughout, written in
 place.
 
 **FFN.** The leading ``dense_layers`` a gated SiLU FFN; the others
@@ -107,7 +107,7 @@ import jax.numpy as jnp
 from ..serving.decode.model import latent_expands
 from . import moe_held_ops as moe
 from .paged_decode_ops import (_attention_of, _mm, _rope_gptj_at,
-                               _write_in_place)
+                               _write_in_place, period_segments)
 from .pallas.paged_attention import (paged_attention_one_table,
                                      pages_per_block)
 
@@ -210,6 +210,7 @@ class LatentMoEBlock(object):
     for LMSpec block='latent_moe'; module docstring."""
 
     all_arena_slots = ('LatentFull', 'IndexFull', 'LatentSliding')
+    pools = (('', 0),)          # every arena under the one block table
 
     def __init__(self, ctx):
         self.emb = ctx.input('Emb')
@@ -270,42 +271,9 @@ class LatentMoEBlock(object):
 
     # ---------------------------------------------------- the layer loop
     def segments(self, step):
-        lead, period, n_periods, tail = self.plan
-
-        def run(kinds, first_layer, before):
-            """The layers of ``kinds`` in order from ``first_layer``,
-            ``before[kind]`` layers of a kind ahead of them; as a scan's
-            body, ``j`` whole runs of ``kinds`` further on."""
-            def fn(carry, j):
-                h, arenas = carry
-                seen, stats = dict(before), []
-                runs = 0 if j is None else j
-                for m, kind in enumerate(kinds):
-                    layer = first_layer + runs * len(kinds) + m
-                    of_kind = seen[kind] + runs * kinds.count(kind)
-                    h, arenas, got = self._layer(
-                        h, arenas, step, kind, layer, of_kind)
-                    seen[kind] += 1
-                    if got is not None:
-                        stats.append(got)
-                return (h, arenas), jnp.stack(stats) if stats else None
-            return fn
-
-        before = {FULL: 0, SLIDING: 0}
-        out = []
-        if lead:
-            out.append((run(lead, 0, before), None))
-            before = {k: v + lead.count(k) for k, v in before.items()}
-        if n_periods:
-            out.append((run(period, len(lead), before),
-                        jnp.arange(n_periods, dtype=jnp.int32)))
-        if tail:
-            # the remainder sits where period ``n_periods`` would
-            start = len(lead) + n_periods * len(period)
-            ahead = {k: v + n_periods * period.count(k)
-                     for k, v in before.items()}
-            out.append((run(tail, start, ahead), None))
-        return out
+        return period_segments(
+            self.plan, lambda h, arenas, kind, layer, of_kind:
+            self._layer(h, arenas, step, kind, layer, of_kind))
 
     def _layer(self, h, arenas, step, kind, layer, of_kind):
         """Layer ``layer`` (of all; ``of_kind`` among its kind's), with

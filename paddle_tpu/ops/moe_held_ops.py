@@ -2,9 +2,10 @@
 
 One chip of an expert-parallel deployment holds ``E`` consecutive
 experts of the ``n_experts`` the router scores (``first .. first + E -
-1``). Every row is routed over all of them (sigmoid scores, the
-``top_k`` largest, weights normalised over all that were chosen,
-wherever they live), and this chip adds what its own experts give: the
+1``). Every row is routed over all of them (sigmoid scores or a
+softmax over them, by the block; the ``top_k`` largest, weights
+normalised over all that were chosen, wherever they live), and this
+chip adds what its own experts give: the
 partial result that the exchange between chips would sum. There is no
 capacity and nothing is dropped, so ``ops/moe_ops.py``'s ``[S, E, C]``
 dispatch (Switch/GShard, for the training programs) has no part here.
@@ -50,12 +51,29 @@ ones.
 import jax
 import jax.numpy as jnp
 
-__all__ = ['route_sigmoid_topk', 'held_gates', 'gated_experts',
-           'routed_experts', 'row_tiles', 'load_stats', 'TILE_ROWS']
+__all__ = ['route_sigmoid_topk', 'route_softmax_topk', 'held_gates',
+           'gated_experts', 'routed_experts', 'row_tiles', 'load_stats',
+           'TILE_ROWS']
 
 # rows of one expert that one iteration of the routed product takes: the
 # matrix unit's 128, on every TPU generation this runs on
 TILE_ROWS = 128
+
+
+def _router_logits(x, router):
+    return jnp.matmul(x.astype(jnp.float32), router.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def route_softmax_topk(x, router, top_k):
+    """``route_sigmoid_topk`` for a softmax router (mellum): the scores
+    are the softmax of the logits over every expert, the ``top_k``
+    largest are chosen and the weights are those scores normalised over
+    the chosen (``norm_topk_prob``), which is the softmax over the
+    chosen experts' logits alone. float32 at the highest precision."""
+    scores = jax.nn.softmax(_router_logits(x, router), axis=-1)
+    top, chosen = jax.lax.top_k(scores, top_k)
+    return chosen, top / jnp.sum(top, axis=-1, keepdims=True)
 
 
 def route_sigmoid_topk(x, router, top_k, bias=None, scale=1.0):
@@ -67,9 +85,7 @@ def route_sigmoid_topk(x, router, top_k, bias=None, scale=1.0):
     choosing only: the weights are the chosen experts' own scores,
     normalised, times ``scale`` (``routed_scaling_factor``: the routed
     sum is scaled, the shared experts are not)."""
-    scores = jax.nn.sigmoid(jnp.matmul(
-        x.astype(jnp.float32), router.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
+    scores = jax.nn.sigmoid(_router_logits(x, router))
     if bias is None:
         top, chosen = jax.lax.top_k(scores, top_k)
     else:

@@ -39,7 +39,11 @@ transformer_ops.py's ``_incremental_layer_scan``; ``_ParallelMoEBlock``:
 cohere2_moe, LMSpec block='parallel_moe'; ``LatentMoEBlock`` in
 ops/latent_moe_ops.py: dots3_note, block='latent_moe', whose layer
 kinds have unlike shapes and three arenas, so it brings its own layer
-functions and order, ``segments``): embedding, the q/k/v
+functions and order, ``segments``; ``GqaMoEBlock`` in
+ops/gqa_moe_ops.py: mellum, block='gqa_moe', whose layer kinds keep
+their K and V in page pools of their own, each under its own block
+table: an op then has a table input a pool of its block, ``pools``):
+embedding, the q/k/v
 projections, what follows attention, the per-layer lower bound on the
 columns a row sees, and the logits. Everything else — placement, the
 in-place arena writes, the one ``lax.scan`` over [L, ...]-stacked
@@ -125,9 +129,12 @@ def _stacked_weights(ctx, slots):
 
 # What one op's rows are, for a layer: their positions, the block
 # table(s), where their cache rows land, the length each attends at (0:
-# not live) and which rows count.
+# not live) and which rows count. ``tables`` and ``place`` are the first
+# page pool's; ``pools`` has every pool's (tables, place) in the
+# block's order, for a block whose layer kinds lie in pools of their
+# own.
 _Step = collections.namedtuple(
-    '_Step', ['pos', 'tables', 'place', 'lens', 'valid'])
+    '_Step', ['pos', 'tables', 'place', 'lens', 'valid', 'pools'])
 
 
 def _attention_of(tables):
@@ -137,11 +144,63 @@ def _attention_of(tables):
         else paged_attention_blocked
 
 
+def period_segments(plan, layer_of):
+    """``_extend_rows``' segments for a block whose layers run in the
+    published order of their kinds: ``plan`` is ``LMSpec.layer_plan()``
+    (lead, period, n_periods, tail) and ``layer_of(h, arenas, kind,
+    layer, of_kind)`` runs layer ``layer`` (of all; ``of_kind`` among
+    its kind's; ints or traced scalars) and returns (h, arenas, router
+    statistics or None). The leading layers one by one, then one
+    ``lax.scan`` over the whole periods of layer kinds (a period's
+    layers unrolled inside the body, each at ``layers before + period x
+    layers a period + its place``), then the remainder."""
+    lead, period, n_periods, tail = plan
+    kinds = set(lead + period + tail)
+
+    def run(run_kinds, first_layer, before):
+        """The layers of ``run_kinds`` in order from ``first_layer``,
+        ``before[kind]`` layers of a kind ahead of them; as a scan's
+        body, ``j`` whole runs of ``run_kinds`` further on."""
+        def fn(carry, j):
+            h, arenas = carry
+            seen, stats = dict(before), []
+            runs = 0 if j is None else j
+            for m, kind in enumerate(run_kinds):
+                layer = first_layer + runs * len(run_kinds) + m
+                of_kind = seen[kind] + runs * run_kinds.count(kind)
+                h, arenas, got = layer_of(h, arenas, kind, layer, of_kind)
+                seen[kind] += 1
+                if got is not None:
+                    stats.append(got)
+            return (h, arenas), jnp.stack(stats) if stats else None
+        return fn
+
+    before = {kind: 0 for kind in kinds}
+    out = []
+    if lead:
+        out.append((run(lead, 0, before), None))
+        before = {k: v + lead.count(k) for k, v in before.items()}
+    if n_periods:
+        out.append((run(period, len(lead), before),
+                    jnp.arange(n_periods, dtype=jnp.int32)))
+    if tail:
+        # the remainder sits where period ``n_periods`` would
+        start = len(lead) + n_periods * len(period)
+        ahead = {k: v + n_periods * period.count(k)
+                 for k, v in before.items()}
+        out.append((run(tail, start, ahead), None))
+    return out
+
+
 class _UniformBlock(object):
     """A block whose layers are all of one shape, with K and V arenas of
     ``[L, ...]``: one ``lax.scan`` over the [L, ...]-stacked weights,
     the layer's kind (window, rotary) scanned data beside them. A
     subclass gives ``pre``, ``kv``, ``q``, ``lower_bound``, ``finish``."""
+
+    # (op input suffix of a page pool's block table, an arena of it
+    # among ``arena_slots``): the one pool every arena lies in
+    pools = (('', 0),)
 
     def segments(self, step):
         n_layer = next(iter(self.params.values())).shape[0]
@@ -351,6 +410,9 @@ def _block_of(ctx):
     if kind == 'latent_moe':
         from .latent_moe_ops import LatentMoEBlock
         return LatentMoEBlock(ctx)
+    if kind == 'gqa_moe':
+        from .gqa_moe_ops import GqaMoEBlock
+        return GqaMoEBlock(ctx)
     return _PostLNBlock(ctx)
 
 
@@ -456,6 +518,17 @@ def _lm_inputs(ctx):
                         if ctx.has_input(slot))
 
 
+def _pool_tables(ctx, block, slot):
+    """The op's block tables, one a page pool of the block: int32."""
+    return [ctx.input(slot + suffix).astype(jnp.int32)
+            for suffix, _ in block.pools]
+
+
+def _pool_shapes(block, arenas):
+    """(pages, slots a page) of each pool's arenas."""
+    return [arenas[a].shape[1:3] for _, a in block.pools]
+
+
 def _set_arena_outputs(ctx, block, arenas):
     for slot, arena in zip(block.arena_slots, arenas):
         ctx.set_output(slot + 'Out', arena)
@@ -467,15 +540,17 @@ def _paged_decode_step(ctx):
 
     tokens = ctx.input('Tokens').reshape(-1).astype(jnp.int32)     # [B]
     lens = ctx.input('SeqLens').reshape(-1).astype(jnp.int32)      # [B]
-    tables = ctx.input('BlockTables').astype(jnp.int32)            # [B, P]
+    tables = _pool_tables(ctx, block, 'BlockTables')          # [B, P] each
     temps = ctx.input('Temps').reshape(-1).astype(jnp.float32)
     seeds = ctx.input('Seeds').reshape(-1).astype(jnp.int32)
 
     # one new token per row at position lens (empty slots feed all->NB
     # tables, so phys lands out of bounds and every write drops)
-    place = _single_rows(tables, lens, *arenas[0].shape[1:3])
-    h, arenas, stats = _extend_rows(block, arenas, tokens, lens, tables,
-                                    place, valid=place.ok[:, 0])
+    place = [_single_rows(t, lens, *shape)
+             for t, shape in zip(tables, _pool_shapes(block, arenas))]
+    h, arenas, stats = _extend_rows(
+        block, arenas, tokens, lens, tables[0], place[0],
+        valid=place[0].ok[:, 0], more=zip(tables[1:], place[1:]))
     nxt = jax.vmap(_sample_token)(block.logits(h), seeds, lens + 1, temps)
     ctx.set_output('NextTokens',
                    nxt.astype(ctx.out_dtype('NextTokens', 'int64')))
@@ -484,13 +559,15 @@ def _paged_decode_step(ctx):
     _set_arena_outputs(ctx, block, arenas)
 
 
-def _extend_rows(block, arenas, tokens, pos, tables, place, valid=None):
+def _extend_rows(block, arenas, tokens, pos, tables, place, valid=None,
+                 more=()):
     """Shared core of all three ops: write N new tokens' cache rows at
     absolute positions ``pos`` where ``place`` (a _Placement over the
     same rows) says, attend each row at its own ragged length
     (``pos + 1``; 0 where ``valid`` says a row is not live, so that it
     costs no block) through per-row block ``tables`` [N, P] (or, for
-    consecutive rows of one sequence, its one table [P]), and return
+    consecutive rows of one sequence, its one table [P]; ``more``: the
+    (tables, place) of the block's page pools past the first), and return
     the last hidden rows [N, D], the updated ``arenas`` (a tuple in the
     block's order) and the block's per-layer statistics (None where it
     keeps none; ``valid`` [N] says which rows count). The arenas are
@@ -517,7 +594,9 @@ def _extend_rows(block, arenas, tokens, pos, tables, place, valid=None):
     # a row that is not live attends at length 0: it costs no block
     lens = pos + 1 if valid is None else jnp.where(valid, pos + 1, 0)
     carry, stats = (x, tuple(arenas)), []
-    for layer, xs in block.segments(_Step(pos, tables, place, lens, valid)):
+    step = _Step(pos, tables, place, lens, valid,
+                 ((tables, place),) + tuple(more))
+    for layer, xs in block.segments(step):
         if xs is None:
             carry, got = layer(carry, None)
         else:
@@ -537,7 +616,8 @@ def _paged_prefill(ctx):
     ids = ctx.input('Ids').reshape(-1).astype(jnp.int32)   # [S] (padded)
     length = ctx.input('Len').reshape(()).astype(jnp.int32)
     cached = ctx.input('Cached').reshape(()).astype(jnp.int32)
-    table = ctx.input('BlockTable').astype(jnp.int32).reshape(-1)  # [P]
+    table = [t.reshape(-1) for t in
+             _pool_tables(ctx, block, 'BlockTable')]               # [P] each
     temp = ctx.input('Temp').reshape(()).astype(jnp.float32)
     seed = ctx.input('Seed').reshape(()).astype(jnp.int32)
     s = ids.shape[0]
@@ -546,13 +626,15 @@ def _paged_prefill(ctx):
     # query attends to everything at or below it — the cached pages
     # plus this step's own earlier writes — through the table gather
     pos = cached + jnp.arange(s, dtype=jnp.int32)
-    place = _page_runs(table, cached, length, s, *arenas[0].shape[1:3])
+    place = [_page_runs(t, cached, length, s, *shape)
+             for t, shape in zip(table, _pool_shapes(block, arenas))]
     last = jnp.maximum(length - 1, 0)
     # the sequence's pages gathered block by block for the whole chunk
     # (rows past ``length`` see nothing), and the one row that is
     # sampled projected onto the vocabulary
-    h, arenas, stats = _extend_rows(block, arenas, ids, pos, table, place,
-                                    valid=jnp.arange(s) < length)
+    h, arenas, stats = _extend_rows(
+        block, arenas, ids, pos, table[0], place[0],
+        valid=jnp.arange(s) < length, more=zip(table[1:], place[1:]))
     logits_last = block.logits(jax.lax.dynamic_slice_in_dim(
         h, last, 1))[0]                                         # [V]
     nxt = _sample_token(logits_last, seed, cached + length, temp)
@@ -570,7 +652,7 @@ def _paged_spec_verify(ctx):
 
     tokens = ctx.input('Tokens').astype(jnp.int32)         # [B, K1]
     lens = ctx.input('SeqLens').reshape(-1).astype(jnp.int32)   # [B]
-    tables = ctx.input('BlockTables').astype(jnp.int32)    # [B, P]
+    tables = _pool_tables(ctx, block, 'BlockTables')       # [B, P] each
     temps = ctx.input('Temps').reshape(-1).astype(jnp.float32)
     seeds = ctx.input('Seeds').reshape(-1).astype(jnp.int32)
     b, k1 = tokens.shape
@@ -582,10 +664,12 @@ def _paged_spec_verify(ctx):
     # the decode step: all-NB tables drop every write)
     j = jnp.arange(k1, dtype=jnp.int32)
     pos = (lens[:, None] + j[None, :]).reshape(-1)         # [B*K1]
-    tables_rep = jnp.repeat(tables, k1, axis=0)            # [B*K1, P]
-    place = _single_rows(tables_rep, pos, *arenas[0].shape[1:3])
-    h, arenas, _ = _extend_rows(block, arenas, tokens.reshape(-1), pos,
-                                tables_rep, place, valid=place.ok[:, 0])
+    tables_rep = [jnp.repeat(t, k1, axis=0) for t in tables]   # [B*K1, P]
+    place = [_single_rows(t, pos, *shape) for t, shape in
+             zip(tables_rep, _pool_shapes(block, arenas))]
+    h, arenas, _ = _extend_rows(
+        block, arenas, tokens.reshape(-1), pos, tables_rep[0], place[0],
+        valid=place[0].ok[:, 0], more=zip(tables_rep[1:], place[1:]))
 
     nxt = jax.vmap(_sample_token)(
         block.logits(h), jnp.repeat(seeds, k1), pos + 1,

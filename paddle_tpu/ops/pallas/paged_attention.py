@@ -57,6 +57,18 @@ of its static row count and the kind's widths
 (``serving/decode/model.py``: ``latent_expands``); ``_attend_blocks``
 has what each costs.
 
+A block table may have holes below a row's lower bound. Where a layer
+kind's arenas lie in a page pool of their own whose pages go back to
+the pool behind the window (``serving/decode/kv_pool.py``:
+``KVPool.trim``; ops/gqa_moe_ops.py), each call takes the table of its
+layer's kind, and the entries of pages given back point past the pool
+like unowned ones. Every column block wholly below ``lo`` is outside
+the loops (``block_bounds``, ``row_pairs``) and is never gathered; in
+the one block that holds ``lo`` such an entry is gathered clipped to a
+real page (whoever owns it now: finite values, an arena starts as zeros
+and only ever takes finite rows) and its columns contribute exactly 0,
+as the columns past a row's length do.
+
 Layouts:
     q            [B, H, D]      one query token per sequence (latent,
                  absorbed: D the stored row's width; expanded:

@@ -42,9 +42,13 @@ workload — over a decoder-only LM with a paged KV cache:
   scores) and the latent block (latent attention over every position,
   under a learned sparse selection or under a window by layer kind, up
   to three kinds of cache under the one block table:
-  ``LMSpec.cache_kinds``) run through this same engine. For the latter
-  two speculation and quantized arenas raise rather than run untested,
-  and for the latent block the page handoff too. The prefix cache runs
+  ``LMSpec.cache_kinds``) and the grouped block with a page pool a
+  layer kind (sliding layers whose pool keeps a window's pages of a
+  sequence beside full layers whose pool keeps every page:
+  ``LMSpec.page_pools``; a ``KVPool``, a page count and a block table a
+  pool, ``self.pools``) run through this same engine. For the latter
+  three speculation and quantized arenas raise rather than run untested,
+  and for the latent and the grouped block the page handoff too. The prefix cache runs
   for a spec whose frozen pages another sequence may map
   (``LMSpec.shares_frozen_pages``: 'post_ln', and a latent block all
   of whose layers read every cached position); the others raise: their
@@ -208,7 +212,7 @@ class DecodeEngine(object):
                  pages_per_seq=8, max_queue_depth=64, max_prompt_len=None,
                  place=None, weights=None, prefix_cache=None, spec_k=None,
                  draft=None, kv_dtype=None, name=None, prefill_chunk=None,
-                 min_prompt_bucket=1):
+                 min_prompt_bucket=1, pool_blocks=None):
         import jax
         from ...quant.core import resolve_kv_dtype
         from ...quant.core import kv_itemsize
@@ -220,6 +224,17 @@ class DecodeEngine(object):
         self.max_batch = int(max_batch)
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
+        # pages by page pool of the spec (LMSpec.page_pools): every pool
+        # has ``num_blocks`` but those ``pool_blocks`` names
+        page_pools = spec.page_pools()
+        # the feeds of the pools past the first: ``pf_table<suffix>``
+        self._more_tables = [pool.feed for pool in page_pools[1:]]
+        pages = {pool.name: self.num_blocks for pool in page_pools}
+        unknown = sorted(set(pool_blocks or ()) - set(pages) - {''})
+        if unknown:
+            raise ValueError('pool_blocks for %s: the spec\'s pools are %s'
+                             % (unknown, sorted(pages)))
+        pages.update({name: int(n) for name, n in (pool_blocks or {}).items()})
         self.pages_per_seq = int(pages_per_seq)
         self.max_queue_depth = int(max_queue_depth)
         # feature knobs: explicit constructor args win, else the env
@@ -260,7 +275,7 @@ class DecodeEngine(object):
         self.draft = draft if draft is not None else \
             (NgramDraft() if self.spec_k > 0 else None)
         self._progs = build_lm_programs(spec, self.max_batch,
-                                        self.block_size, self.num_blocks,
+                                        self.block_size, pages,
                                         self.pages_per_seq,
                                         spec_k=self.spec_k,
                                         kv_dtype=self.kv_dtype)
@@ -307,7 +322,16 @@ class DecodeEngine(object):
         if weights:
             self.load_weights(weights)
 
-        self.pool = KVPool(self.num_blocks, self.block_size)
+        # a pool a page space; the first is ``self.pool``. A pool with a
+        # lifetime keeps a window behind a program's first row, and a
+        # program writes a prefill chunk at most
+        self.pools = [
+            KVPool(pages[pool.name], self.block_size,
+                   kind=(pool.name or 'full') if len(page_pools) > 1
+                   else None, keep=pool.keeps, ahead=self.prefill_chunk)
+            for pool in page_pools]
+        self.pool = self.pools[0]
+        self._trims = any(pool.keep for pool in self.pools)
         if _obs.enabled():
             _obs.set_gauge('decode.kv_bytes_per_token',
                            self.kv_bytes_per_token,
@@ -317,7 +341,7 @@ class DecodeEngine(object):
                                kv_dtype=self.kv_dtype, kind=kind)
         self.prefix_cache = PrefixCache(self.pool) \
             if self.prefix_cache_on else None
-        self._sched = Scheduler(self.pool, self.max_batch,
+        self._sched = Scheduler(self.pools, self.max_batch,
                                 cache=self.prefix_cache)
         # serializes arena access between the worker's executor
         # dispatches (which donate the arena buffers) and out-of-band
@@ -422,10 +446,11 @@ class DecodeEngine(object):
                 '%d (pages_per_seq=%d x block_size=%d)'
                 % (total, self.capacity, self.pages_per_seq,
                    self.block_size))
-        if self.pool.blocks_for(total) > self.num_blocks:
-            raise ValueError(
-                'request needs %d KV pages but the pool only has %d'
-                % (self.pool.blocks_for(total), self.num_blocks))
+        for pool in self.pools:
+            if pool.span_pages(total) > pool.num_blocks:
+                raise ValueError(
+                    'request needs %d KV pages but the pool only has %d'
+                    % (pool.span_pages(total), pool.num_blocks))
         with self._mu:
             if self._closed:
                 raise EngineClosedError('DecodeEngine is shut down')
@@ -514,11 +539,11 @@ class DecodeEngine(object):
     def _per_head_cache(self, what):
         """The page handoff ships K and V rows of ``n_kv_head`` heads;
         a block that caches anything else has no packet format yet."""
-        if not self.spec.per_head_cache():
+        if not self.spec.per_head_cache() or len(self.pools) > 1:
             from ..handoff import CacheKindError
             raise CacheKindError(
-                '%s: block=%r caches %s, not per-head K/V rows: the page '
-                'handoff has no format for it'
+                '%s: block=%r caches %s, not per-head K/V rows under one '
+                'block table: the page handoff has no format for it'
                 % (what, self.spec.block,
                    ', '.join(k.name for k in self.spec.cache_kinds())))
 
@@ -925,37 +950,47 @@ class DecodeEngine(object):
         _obs.spans().add_span('decode.device_empty', since[0], now)
 
     # ----------------------------------------------------------- dispatch
-    @staticmethod
-    def _prefill_feed(ids, length, cached, table, temp, seed):
-        return {'pf_ids': ids,
+    def _prefill_feed(self, ids, length, cached, table, temp, seed, *more):
+        """A prefill's feeds; ``more`` the tables of the page pools past
+        the first, in order."""
+        feed = {'pf_ids': ids,
                 'pf_len': np.asarray([length], 'int32'),
                 'pf_cached': np.asarray([cached], 'int32'),
                 'pf_table': table,
                 'pf_temp': np.asarray([temp], 'float32'),
                 'pf_seed': np.asarray([seed], 'int32')}
+        for suffix, its in zip(self._more_tables, more):
+            feed['pf_table' + suffix] = its
+        return feed
 
-    @staticmethod
-    def _step_feed(prefix, tokens, lens, tables, temps, seeds):
+    def _step_feed(self, prefix, tokens, lens, tables, temps, seeds, *more):
         """The decode step's ('dec') or the spec-verify step's ('sv')
-        five feeds."""
-        return {prefix + '_tokens': tokens, prefix + '_lens': lens,
+        five feeds, and ``more``: the tables of the page pools past the
+        first, in order."""
+        feed = {prefix + '_tokens': tokens, prefix + '_lens': lens,
                 prefix + '_tables': tables, prefix + '_temps': temps,
                 prefix + '_seeds': seeds}
+        for suffix, its in zip(self._more_tables, more):
+            feed[prefix + '_tables' + suffix] = its
+        return feed
 
     def _warm_args(self, which):
         """What ``warmup()`` dispatches ``which`` ('decode', 'verify' or
         a prefill bucket's size) with: the shapes live traffic uses,
         every block-table entry past the pool so nothing is written."""
-        nb, mb, pps = self.num_blocks, self.max_batch, self.pages_per_seq
+        mb, pps = self.max_batch, self.pages_per_seq
+        rows = 1 if which not in ('decode', 'verify') else mb
+        first, *more = [np.full((rows, pps), pool.num_blocks, 'int32')
+                        for pool in self.pools]
         if which in ('decode', 'verify'):
             toks = (mb,) if which == 'decode' else (mb, self.spec_k + 1)
             return (np.zeros(toks, 'int64'), np.zeros((mb,), 'int32'),
-                    np.full((mb, pps), nb, 'int32'),
-                    np.zeros((mb,), 'float32'), np.zeros((mb,), 'int32'))
-        return (np.zeros((1, int(which)), 'int64'), 1, 0,
-                np.full((1, pps), nb, 'int32'), 0.0, 0)
+                    first, np.zeros((mb,), 'float32'),
+                    np.zeros((mb,), 'int32'), *more)
+        return (np.zeros((1, int(which)), 'int64'), 1, 0, first, 0.0, 0,
+                *more)
 
-    def _run_prefill(self, ids, length, cached, table, temp, seed,
+    def _run_prefill(self, ids, length, cached, table, temp, seed, *more,
                      wait=True):
         """One prefill dispatch. ``wait=False`` (a chunk that is not the
         prefix's last) leaves the sampled token on the device unread, so
@@ -975,7 +1010,7 @@ class DecodeEngine(object):
             out = self._exe.run(
                 program=self._progs.prefill,
                 feed=self._prefill_feed(ids, length, cached, table, temp,
-                                        seed),
+                                        seed, *more),
                 fetch_list=fetch, return_numpy=False)
         if self._empty_since is not None:
             self._device_taken()
@@ -988,18 +1023,18 @@ class DecodeEngine(object):
             self._device_emptied(time.perf_counter())
         return token
 
-    def _dispatch_verify(self, tokens, lens, tables, temps, seeds):
+    def _dispatch_verify(self, *feeds):
         """Enqueue one spec-verify step; its fetch stays on the device."""
         with self._arena_mu, scope_guard(self._scope):
             return self._exe.run(
                 program=self._progs.verify,
-                feed=self._step_feed('sv', tokens, lens, tables, temps,
-                                     seeds),
+                feed=self._step_feed('sv', *feeds),
                 fetch_list=[self._progs.verify_fetch],
                 return_numpy=False)[0]
 
-    def _dispatch_decode(self, tokens, lens, tables, temps, seeds):
-        """Enqueue one decode step; its fetch stays on the device. A
+    def _dispatch_decode(self, *feeds):
+        """Enqueue one decode step (``_step_feed``'s arguments); its
+        fetch stays on the device. A
         block that keeps router statistics hands them back beside the
         tokens; they are left in ``_step_stats`` for the step's emit."""
         fetch = [self._progs.decode_fetch]
@@ -1008,8 +1043,7 @@ class DecodeEngine(object):
         with self._arena_mu, scope_guard(self._scope):
             out = self._exe.run(
                 program=self._progs.decode,
-                feed=self._step_feed('dec', tokens, lens, tables, temps,
-                                     seeds),
+                feed=self._step_feed('dec', *feeds),
                 fetch_list=fetch, return_numpy=False)
         self._step_stats = out[1] if len(out) > 1 else None
         return out[0]
@@ -1049,11 +1083,17 @@ class DecodeEngine(object):
         raise ValueError('prefix of %d tokens exceeds the top prompt '
                          'bucket %d' % (n, self.prompt_buckets[-1]))
 
-    def _table_row(self, seq):
-        row = np.full((self.pages_per_seq,), self.num_blocks, 'int32')
-        ids = seq.table.block_ids
-        row[:len(ids)] = ids
-        return row
+    def _table_rows(self, seq):
+        """``seq``'s block tables as the programs take them, one a page
+        pool: an entry it does not own (past its pages, or given back
+        behind a window) points past the pool."""
+        rows = []
+        for pool, table in zip(self.pools, seq.tables):
+            row = np.full((self.pages_per_seq,), pool.num_blocks, 'int32')
+            ids = table.block_ids[table.freed:]
+            row[table.freed:table.freed + len(ids)] = ids
+            rows.append(row)
+        return rows
 
     def _prefill(self, seq):
         """Prefill the uncached suffix of ``seq.prefix()`` — the whole
@@ -1070,7 +1110,7 @@ class DecodeEngine(object):
             cached = seq.cached_len
             top = self.prefill_chunk
             starts = list(range(cached, s, top))
-            table = self._table_row(seq)[None, :]
+            table, *more = [row[None, :] for row in self._table_rows(seq)]
             # the largest program this prefill runs (its first chunk's):
             # the label of its span, its time and its trace stage
             bucket = self._bucket(min(top, s - cached))
@@ -1100,10 +1140,23 @@ class DecodeEngine(object):
                     'decode.prefill.chunk', bucket=rung, start=start,
                     attn_pairs=its_pairs, request_id=seq.request_id) \
                     if len(starts) > 1 else contextlib.nullcontext()
+                if self._trims:
+                    # a pool with a lifetime gives back what lies behind
+                    # the chunk's first row and takes the chunk's pages
+                    # (the last chunk's with the first decode write's);
+                    # the others have theirs since admission
+                    if not self._sched.grow(seq, min(start + top, s) + 1,
+                                            start):
+                        # admission took the most a sequence ever holds
+                        raise RuntimeError(
+                            'prefill of request %r: a page pool ran out '
+                            'between chunks' % (seq.request_id,))
+                    table, *more = [row[None, :]
+                                    for row in self._table_rows(seq)]
                 with chunk_span:
                     tok = self._run_prefill(
                         ids, len(piece), start, table, seq.temperature,
-                        seq.seed, wait=start == starts[-1])
+                        seq.seed, *more, wait=start == starts[-1])
             t1 = time.perf_counter()
         _obs.record('decode.prefill_seconds', t1 - t0, bucket=bucket)
         # the chunks run back to back and only the last is waited for
@@ -1165,18 +1218,21 @@ class DecodeEngine(object):
 
     def _step_feeds(self, batch, tokens_per_row=1):
         """``(lens, tables, temps, seeds)`` of one step over ``batch`` at
-        the fixed [max_batch] signature; the rows past the batch point
+        the fixed [max_batch] signature, and after them the tables of
+        the page pools past the first; the rows past the batch point
         every page beyond the pool, so their writes drop.
         ``tokens_per_row`` is what the step scores per row (k + 1 under
         speculation), for the counters only."""
-        mb, pps, nb = self.max_batch, self.pages_per_seq, self.num_blocks
+        mb, pps = self.max_batch, self.pages_per_seq
         lens = np.zeros((mb,), 'int32')
-        tables = np.full((mb, pps), nb, 'int32')
+        tables, *more = [np.full((mb, pps), pool.num_blocks, 'int32')
+                         for pool in self.pools]
         temps = np.zeros((mb,), 'float32')
         seeds = np.zeros((mb,), 'int32')
         for i, seq in enumerate(batch):
             lens[i] = seq.position()
-            tables[i] = self._table_row(seq)
+            for mine, row in zip([tables] + more, self._table_rows(seq)):
+                mine[i] = row
             temps[i] = seq.temperature
             seeds[i] = seq.seed
         if _obs.enabled():
@@ -1193,7 +1249,7 @@ class DecodeEngine(object):
                 _obs.record('decode.step_window_tokens',
                             int(np.minimum(lens, window).sum()))
             self._count_cache_reads(lens[:len(batch)] + 1)
-        return lens, tables, temps, seeds
+        return (lens, tables, temps, seeds, *more)
 
     def _count_cache_reads(self, seen):
         """What one decode step's attention has to read of the paged
@@ -1336,7 +1392,7 @@ class DecodeEngine(object):
             return
         prev = self._ahead
         if prev is not None and not all(
-                self.pool.grow(seq.table, seq.position() + 1)
+                self._sched.grow(seq, seq.position() + 1)
                 for seq in self._next_rows()):
             # a preemption comes: the pipeline empties first
             self._emit_step(prev)

@@ -18,6 +18,16 @@ Exhaustion is a normal state, not an error: ``alloc`` returns None and
 the continuous-batching scheduler reacts by preempting a victim
 sequence (freeing its pages, requeueing it) — see scheduler.py.
 
+Lifetimes: a pool built with ``keep`` > 0 indexes arenas whose layers
+all read at most the last ``keep`` positions (a sliding window;
+``model.LMSpec.page_pools``). ``trim`` hands back a table's leading
+pages once every position in them lies further back than that from
+every query still to come; the table keeps its logical indexing (entry i
+covers positions i * bs ...), the given-back entries are None (on the
+device: past the pool, like an unowned entry) and ``grow`` goes on at
+the end. A sequence then holds about ``keep`` positions of such a pool
+however long it is.
+
 Cache integration: a global prefix cache (prefix_cache.py) parks
 frozen pages at refcount 1 so future requests can map them instead of
 re-prefilling. Those pages are *reclaimable*, not free — ``alloc``
@@ -41,12 +51,15 @@ _FRAG_PUBLISH_EVERY = 64
 
 
 class BlockTable(object):
-    """One sequence's logical->physical page map."""
+    """One sequence's logical->physical page map. ``freed`` leading
+    entries were given back behind a window (``KVPool.trim``) and are
+    None."""
 
-    __slots__ = ('block_ids',)
+    __slots__ = ('block_ids', 'freed')
 
     def __init__(self):
         self.block_ids = []
+        self.freed = 0
 
     def __len__(self):
         return len(self.block_ids)
@@ -57,15 +70,25 @@ class BlockTable(object):
 
 class KVPool(object):
     """Free-list allocator over ``num_blocks`` physical pages of
-    ``block_size`` token slots each."""
+    ``block_size`` token slots each. ``kind`` names the pool where an
+    engine has several (its series then carry ``kind=``; the one pool
+    of an engine publishes them bare, as it always did). ``keep`` > 0
+    is the pool's lifetime (module docstring) and ``ahead`` the most
+    consecutive positions one program writes (a prefill chunk): a
+    sequence never holds more than ``span_pages()``, which is what
+    admission asks of such a pool, whatever the prompt's length."""
 
-    def __init__(self, num_blocks, block_size):
+    def __init__(self, num_blocks, block_size, kind=None, keep=0, ahead=1):
         if num_blocks < 1 or block_size < 1:
             raise ValueError('KVPool: need num_blocks >= 1 and '
                              'block_size >= 1, got %d / %d'
                              % (num_blocks, block_size))
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
+        self.kind = kind
+        self.keep = int(keep)
+        self.ahead = int(ahead)
+        self._labels = {'kind': kind} if kind else {}
         self._mu = threading.Lock()
         self._free = list(range(self.num_blocks - 1, -1, -1))
         self._refs = [0] * self.num_blocks
@@ -133,18 +156,25 @@ class KVPool(object):
     def _publish_frag_locked(self, run):
         if _obs.enabled():
             free = len(self._free)
-            _obs.set_gauge('decode.kv_largest_free_run', run)
+            _obs.set_gauge('decode.kv_largest_free_run', run,
+                           **self._labels)
             _obs.set_gauge('decode.kv_fragmentation',
-                           1.0 - run / float(free) if free else 0.0)
+                           1.0 - run / float(free) if free else 0.0,
+                           **self._labels)
 
     def _publish(self):
         if _obs.enabled():
             free = len(self._free)
-            _obs.set_gauge('decode.kv_blocks_free', free)
-            _obs.set_gauge('decode.kv_free_pages', free)
-            _obs.set_gauge('decode.kv_blocks_total', self.num_blocks)
+            labels = self._labels
+            _obs.set_gauge('decode.kv_blocks_free', free, **labels)
+            _obs.set_gauge('decode.kv_free_pages', free, **labels)
+            _obs.set_gauge('decode.kv_blocks_total', self.num_blocks,
+                           **labels)
             _obs.set_gauge('decode.kv_block_occupancy',
-                           1.0 - free / float(self.num_blocks))
+                           1.0 - free / float(self.num_blocks), **labels)
+            if labels:
+                _obs.set_gauge('decode.kv_pages_used',
+                               self.num_blocks - free, **labels)
             # largest-run is an O(free log free) sort — keep it OFF
             # the per-alloc/free hot path: refresh every Nth publish
             # (and on every direct largest_free_run/fragmentation
@@ -157,6 +187,16 @@ class KVPool(object):
         """Pages needed to hold n_tokens positions."""
         return max(0, (int(n_tokens) + self.block_size - 1)
                    // self.block_size)
+
+    def span_pages(self, n_tokens):
+        """Pages a sequence of ``n_tokens`` positions holds of this
+        pool at most at one time: all of them, or under a lifetime the
+        pages that ``keep`` positions behind the first row of a program
+        and its ``ahead`` rows touch."""
+        pages = self.blocks_for(n_tokens)
+        if not self.keep:
+            return pages
+        return min(pages, self.blocks_for(self.keep + self.ahead) + 2)
 
     def refcount(self, page_id):
         with self._mu:
@@ -179,6 +219,8 @@ class KVPool(object):
                         self._refs[i] = 1
                     self._publish()
                     self._record_stall(t0)
+                    _obs.inc('decode.kv_pages_allocated_total', n,
+                             **self._labels)
                     return ids
                 short = n - len(self._free)
             # the stall clock starts at the first shortfall: everything
@@ -229,9 +271,32 @@ class KVPool(object):
                     self._free.append(i)
             self._publish()
 
+    def trim(self, table, first_query):
+        """Give back the leading pages of ``table`` that no query at or
+        past position ``first_query`` reads: a query at ``p`` reads keys
+        ``p - keep < j <= p``, so the pages all of whose positions lie
+        below ``first_query + 1 - keep``. Nothing under a pool without
+        a lifetime. Returns the pages given back. Their next owner's
+        programs are enqueued after every program of this sequence that
+        could read them, and the device runs programs in order."""
+        if not self.keep:
+            return 0
+        upto = min((int(first_query) + 1 - self.keep) // self.block_size,
+                   len(table.block_ids))
+        if upto <= table.freed:
+            return 0
+        ids = table.block_ids[table.freed:upto]
+        table.block_ids[table.freed:upto] = [None] * len(ids)
+        table.freed = upto
+        self.free(ids)
+        _obs.inc('decode.kv_pages_freed_behind_window_total', len(ids),
+                 **self._labels)
+        return len(ids)
+
     def release(self, table):
         """Free a sequence's whole table."""
-        ids, table.block_ids = table.block_ids, []
+        ids = table.block_ids[table.freed:]
+        table.block_ids, table.freed = [], 0
         self.free(ids)
 
     def fork(self, table, frozen_tokens=None):
@@ -246,6 +311,8 @@ class KVPool(object):
         would land inside the child's view. With ``frozen_tokens=None``
         every page is shared and the CALLER promises the donor is
         frozen (finished, or forked exactly at a page boundary)."""
+        if table.freed:
+            raise ValueError('fork of a table trimmed behind a window')
         ids = table.block_ids
         if frozen_tokens is not None:
             ids = ids[:int(frozen_tokens) // self.block_size]

@@ -6,11 +6,12 @@ Scope, all sharing one parameter namespace (prefix ``lm_``):
 - **startup** — initializes the block's weights (``LMSpec.block``:
   'post_ln' — models.transformer._stacked_layer_params layout,
   ENC_SLOTS, causal self-attention + FFN + 2 LNs per layer, token
-  embedding, sinusoid position table, output projection; 'parallel_moe'
-  and 'latent_moe' — ``block_param_shapes``, at ``LMSpec.dtype``) and
-  the zeroed page arenas of the block's cache kinds
-  (``LMSpec.cache_kinds``: K and V ``[L, NB, bs, Hkv*d]``, or a latent
-  row and an index key per kind of layer). Arenas are persistable scope state: every
+  embedding, sinusoid position table, output projection; 'parallel_moe',
+  'latent_moe' and 'gqa_moe' — ``block_param_shapes``, at
+  ``LMSpec.dtype``) and the zeroed page arenas of the block's cache
+  kinds (``LMSpec.cache_kinds``: K and V ``[L, NB, bs, Hkv*d]``, a
+  latent row and an index key per kind of layer, or K and V per kind of
+  layer, each kind in a page pool of its own: ``LMSpec.page_pools``). Arenas are persistable scope state: every
   prefill/decode run reads them from scope and writes them back
   through executor donation — in-place HBM updates, the same
   whole-program-state contract the trainer uses for params.
@@ -53,15 +54,26 @@ SLIDING, FULL = 'sliding_attention', 'full_attention'
 
 class CacheKind(collections.namedtuple(
         'CacheKind', ['name', 'slot', 'layers', 'width', 'reads',
-                      'shared'])):
+                      'shared', 'pool', 'keeps'])):
     """One arena of the paged cache: its name, the op's input slot, the
     layers that keep it (in order), the elements a token's row holds,
     per layer of ``layers`` the most positions of a sequence one decode
     step's attention reads there (``reads``; 0: every position held),
-    and whether the one row serves every head (``shared``: a latent
-    row, an index key) and not ``n_kv_head`` heads' rows side by side."""
+    whether the one row serves every head (``shared``: a latent
+    row, an index key) and not ``n_kv_head`` heads' rows side by side,
+    the page pool whose ids and block table index it (``pool``:
+    ``LMSpec.page_pools``), and the kind's lifetime (``keeps``): ``w``
+    where every layer of it is under a window and reads at most the
+    last ``w`` positions, its query's own included; 0 where some layer
+    reads every position or chooses among them all (a selection's
+    ``reads`` is no lifetime), and so every position is kept."""
 
     LANES = 128
+
+    def __new__(cls, name, slot, layers, width, reads, shared, pool='',
+                keeps=0):
+        return super(CacheKind, cls).__new__(
+            cls, name, slot, layers, width, reads, shared, pool, keeps)
 
     @property
     def stored(self):
@@ -78,6 +90,63 @@ class CacheKind(collections.namedtuple(
         if not self.shared or self.width <= self.LANES:
             return self.width
         return -(-self.width // self.LANES) * self.LANES
+
+
+class PagePool(collections.namedtuple('PagePool',
+                                        ['name', 'kinds', 'keeps'])):
+    """One space of page ids: the cache kinds (arenas) that one block
+    table a sequence indexes, and the pool's lifetime, ``keeps``: 0
+    where some kind of it keeps every position, else the most
+    positions behind a query that any of its kinds reads, so that a
+    page all of whose positions lie further back than that from every
+    query still to come can go back to the pool (``KVPool.trim``). The
+    first pool of a spec has no name and feeds the programs under the
+    names one table always had; another is named after its layers' kind
+    and has feeds of its own (``pf_table_<name>``)."""
+
+    @property
+    def feed(self):
+        return '_' + self.name if self.name else ''
+
+    @property
+    def slot(self):
+        return self.name.capitalize()
+
+
+def yarn_frequencies(dim, theta, scaling=None):
+    """Per rotated pair ``i`` of a head (or a part of one) of ``dim``
+    the angle a position advances it by: ``theta^(-2i/dim)``, and under
+    YaRN (``scaling``: ``factor``, ``original_max_position_embeddings``,
+    ``beta_fast``, ``beta_slow``; as DeepSeek-V3's reference code and
+    the transformers library compute it, ``truncate`` on) that
+    frequency kept for the fast pairs, divided by ``factor`` for the
+    slow ones and blended linearly between pair ``low`` and pair
+    ``high``, the pairs that turn ``beta_fast`` and ``beta_slow`` times
+    over the original positions. float64 [dim / 2]. The one place the
+    table is computed: ``LatentShape.rope_frequencies`` (latent_moe)
+    and ``LMSpec.rope_tables`` (gqa_moe) both call it."""
+    half = dim // 2
+    plain = float(theta) ** (-np.arange(half, dtype=np.float64) * 2 / dim)
+    if not scaling:
+        return plain
+    low, high = yarn_range(dim, theta, scaling)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                   / ((high - low) or 1e-3), 0.0, 1.0)
+    return plain * (1 - ramp) + plain / float(scaling['factor']) * ramp
+
+
+def yarn_range(dim, theta, scaling):
+    """(low, high): the first pair that is stretched at all and the
+    first that is stretched by the whole ``factor``."""
+    original = float(scaling['original_max_position_embeddings'])
+
+    def pair_of(turns):
+        return dim * np.log(original / (2 * np.pi * turns)) \
+            / (2 * np.log(float(theta)))
+    return (max(int(np.floor(pair_of(float(scaling.get('beta_fast', 32))))),
+                0),
+            min(int(np.ceil(pair_of(float(scaling.get('beta_slow', 1))))),
+                dim - 1))
 
 
 def latent_expands(kv_rank, d_nope, d_v, rows):
@@ -138,38 +207,15 @@ class LatentShape(object):
         return latent_expands(self.kv_rank, self.d_nope, self.d_v, rows)
 
     def rope_frequencies(self):
-        """Per rotated pair ``i`` the angle a position advances it by:
-        ``theta^(-2i/d_rope)``, and under YaRN (``rope_scaling``, as
-        DeepSeek-V3's reference code computes it) that frequency kept
-        for the fast pairs, divided by ``factor`` for the slow ones and
-        blended linearly between pair ``low`` and pair ``high``, the
-        pairs that turn ``beta_fast`` and ``beta_slow`` times over the
-        ``original_max_position_embeddings``. The programs take the
-        table either way."""
-        half = self.d_rope // 2
-        plain = self.rope_theta ** (
-            -np.arange(half, dtype=np.float64) * 2 / self.d_rope)
-        if not self.rope_scaling:
-            return plain
-        low, high = self.yarn_range()
-        ramp = np.clip((np.arange(half, dtype=np.float64) - low)
-                       / ((high - low) or 1e-3), 0.0, 1.0)
-        factor = float(self.rope_scaling['factor'])
-        return plain * (1 - ramp) + plain / factor * ramp
+        """Per rotated pair the angle a position advances it by
+        (``yarn_frequencies`` over the ``d_rope`` rotated columns: the
+        plain powers of theta, or YaRN's table under ``rope_scaling``).
+        The programs take the table either way."""
+        return yarn_frequencies(self.d_rope, self.rope_theta,
+                                self.rope_scaling)
 
     def yarn_range(self):
-        """(low, high): the first pair that is stretched at all and the
-        first that is stretched by the whole ``factor``."""
-        rs = self.rope_scaling
-        original = float(rs['original_max_position_embeddings'])
-
-        def pair_of(turns):
-            return self.d_rope * np.log(original / (2 * np.pi * turns)) \
-                / (2 * np.log(self.rope_theta))
-        return (max(int(np.floor(pair_of(float(rs.get('beta_fast', 32))))),
-                    0),
-                min(int(np.ceil(pair_of(float(rs.get('beta_slow', 1))))),
-                    self.d_rope - 1))
+        return yarn_range(self.d_rope, self.rope_theta, self.rope_scaling)
 
     def softmax_multiplier(self):
         """What the softmax scale ``(d_nope + d_rope)^-1/2`` is
@@ -192,7 +238,7 @@ class LatentShape(object):
 
 
 class LMSpec(object):
-    """Decoder-only LM hyperparameters: a family of three blocks.
+    """Decoder-only LM hyperparameters: a family of four blocks.
 
     ``block='post_ln'`` (the default; every argument after ``d_inner``
     unused): the 2017 decoder block — embedding scaled by sqrt(d_model)
@@ -238,7 +284,25 @@ class LMSpec(object):
     ``attn_gate`` (a sigmoid gate a head on the attention's output) and
     ``lora_rescale`` (the normed latents times sqrt(d_model / rank)).
     ``n_head``, ``n_kv_head``, ``d_key``, ``d_value`` and ``rope_theta``
-    are unused: the shapes are per kind."""
+    are unused: the shapes are per kind.
+
+    ``block='gqa_moe'`` (mellum): the serial pre-norm block of
+    ``latent_moe`` over per-head keys and values: ``n_head`` query heads
+    over ``n_kv_head`` KV heads of ``d_key`` (= ``d_value``), q and k
+    rotated over the whole head in half-split pairs (i, i + d/2) by the
+    table of the layer's kind (``rope_parameters``: {layer kind: the
+    published section: ``rope_theta``, and for ``rope_type`` 'yarn' its
+    ``factor``, ``original_max_position_embeddings``, ``beta_fast``,
+    ``beta_slow`` and the ``attention_factor`` that scales cos and
+    sin}); ``sliding_attention`` layers see the last ``sliding_window``
+    keys, ``full_attention`` layers all; routed experts in every layer
+    under a softmax router (the ``experts_per_token`` largest of the
+    softmax over ``n_experts``, normalised over those), of which
+    ``experts_held`` from ``first_expert`` are computed here; no shared
+    expert; an output head of its own behind a final RMSNorm. Each
+    layer kind's K and V are arenas of their own under a page pool of
+    their own (``page_pools``): the sliding layers' pool keeps a
+    window's pages a sequence, the full layers' every page."""
 
     def __init__(self, vocab_size, n_layer=2, n_head=2, d_key=16,
                  d_value=16, d_model=32, d_inner=64, block='post_ln',
@@ -248,7 +312,8 @@ class LMSpec(object):
                  norm_eps=1e-5, logit_scale=1.0, dtype='float32',
                  latent=None, dense_layers=0, d_inner_dense=0,
                  index_n_heads=0, index_head_dim=0, index_topk=0,
-                 lora_rescale=True, attn_gate=True, routed_scale=1.0):
+                 lora_rescale=True, attn_gate=True, routed_scale=1.0,
+                 rope_parameters=None):
         self.vocab_size = int(vocab_size)
         self.n_layer = int(n_layer)
         self.n_head = int(n_head)
@@ -281,15 +346,18 @@ class LMSpec(object):
         self.lora_rescale = bool(lora_rescale)
         self.attn_gate = bool(attn_gate)
         self.routed_scale = float(routed_scale)
+        self.rope_parameters = {kind: dict(section) for kind, section
+                                in (rope_parameters or {}).items()}
         if self.block == 'post_ln':
             if self.n_kv_head != self.n_head:
                 raise ValueError("LMSpec: block='post_ln' has one KV head "
                                  "per query head")
             return
-        if self.block not in ('parallel_moe', 'latent_moe'):
+        if self.block not in ('parallel_moe', 'latent_moe', 'gqa_moe'):
             raise ValueError('LMSpec: unknown block %r (post_ln, '
-                             'parallel_moe, latent_moe)' % self.block)
-        if self.block == 'parallel_moe' and (
+                             'parallel_moe, latent_moe, gqa_moe)'
+                             % self.block)
+        if self.block in ('parallel_moe', 'gqa_moe') and (
                 self.n_head % self.n_kv_head or self.d_key != self.d_value):
             raise ValueError('LMSpec: %d query heads over %d KV heads of '
                              '%d/%d' % (self.n_head, self.n_kv_head,
@@ -300,8 +368,11 @@ class LMSpec(object):
                              % (self.layer_types, self.n_layer))
         if SLIDING in self.layer_types and self.sliding_window < 1:
             raise ValueError('LMSpec: sliding layers need a window')
+        # a block has shared experts or has none: the other is refused
+        shared_ok = self.n_shared_experts == 0 if self.block == 'gqa_moe' \
+            else self.n_shared_experts > 0
         if not (0 < self.experts_per_token <= self.n_experts and
-                0 < self.experts_held and self.n_shared_experts > 0 and
+                0 < self.experts_held and shared_ok and
                 self.first_expert + self.experts_held <= self.n_experts):
             raise ValueError(
                 'LMSpec: experts %d..%d of %d, %d per token, %d shared'
@@ -311,6 +382,20 @@ class LMSpec(object):
                    self.n_shared_experts))
         if self.block == 'latent_moe':
             self._check_latent()
+        if self.block == 'gqa_moe':
+            self._check_rope()
+
+    def _check_rope(self):
+        kinds = set(self.layer_types)
+        if set(self.rope_parameters) != kinds or self.d_key % 2:
+            raise ValueError('LMSpec: rope_parameters for %s, layers of %s,'
+                             ' heads of %d' % (sorted(self.rope_parameters),
+                                               sorted(kinds), self.d_key))
+        for kind, section in self.rope_parameters.items():
+            if section.get('rope_type', 'default') not in ('default',
+                                                           'yarn'):
+                raise ValueError('LMSpec: rope_type %r of %s (default, '
+                                 'yarn)' % (section['rope_type'], kind))
 
     def _check_latent(self):
         kinds = set(self.layer_types)
@@ -363,6 +448,26 @@ class LMSpec(object):
         step reads ``index_topk`` (0, no selection: all of them), and
         with a selection the indexer's keys beside them."""
         every = tuple(range(self.n_layer))
+        if self.block == 'gqa_moe':
+            # K and V by layer kind: the full layers' pool has no name,
+            # the sliding layers' is named and keeps a window's pages
+            out = []
+            for kind, tag in ((FULL, 'full'), (SLIDING, 'sliding')):
+                held = self.layers_of(kind)
+                reads = (self.sliding_window if kind == SLIDING else 0,
+                         ) * len(held)
+                pool = tag if kind == SLIDING and FULL in self.layer_types \
+                    else ''
+                if held:
+                    out += [CacheKind('lm_kcache_' + tag,
+                                      'KCache' + tag.capitalize(), held,
+                                      self.n_kv_head * self.d_key, reads,
+                                      False, pool, reads[0]),
+                            CacheKind('lm_vcache_' + tag,
+                                      'VCache' + tag.capitalize(), held,
+                                      self.n_kv_head * self.d_value, reads,
+                                      False, pool, reads[0])]
+            return tuple(out)
         if self.block != 'latent_moe':
             reads = tuple(self.windows())
             return (CacheKind('lm_kcache', 'KCache', every,
@@ -386,6 +491,29 @@ class LMSpec(object):
                                  sliding, self.latent[SLIDING].row_width,
                                  (self.sliding_window,) * len(sliding),
                                  True))
+        return tuple(out)
+
+    def page_pools(self):
+        """The spaces of page ids a token's cache lies in, one block
+        table a sequence each (``PagePool``): the cache kinds grouped
+        by ``CacheKind.pool`` in order of first appearance, each with
+        its lifetime: the largest of its kinds' where each has one. A
+        property of the spec, like ``shares_frozen_pages``: nothing
+        turns it on. Every block but 'gqa_moe' has the one pool that
+        keeps every page: there a windowed kind (dots3_note's
+        ``lm_latent_sliding``) shares its table with kinds that keep
+        all, or holds layers of both sorts (command_a_plus' K and V),
+        and a table of its own would be a new feed of its programs."""
+        names = []
+        for kind in self.cache_kinds():
+            if kind.pool not in names:
+                names.append(kind.pool)
+        out = []
+        for name in names:
+            kinds = tuple(k for k in self.cache_kinds() if k.pool == name)
+            keeps = [k.keeps for k in kinds]
+            out.append(PagePool(name, kinds,
+                                max(keeps) if all(keeps) else 0))
         return tuple(out)
 
     def shares_frozen_pages(self):
@@ -414,9 +542,27 @@ class LMSpec(object):
         return [self.sliding_window if t == SLIDING else 0
                 for t in self.layer_types]
 
+    def rope_tables(self):
+        """{layer kind: (frequencies float64 [d_key / 2], what cos and
+        sin are multiplied by) or None}: the position table of each
+        kind of layer of a per-head block, None for a kind that carries
+        no position. 'parallel_moe' turns its sliding layers by the
+        plain powers of ``rope_theta`` and leaves its full layers
+        unturned; 'gqa_moe' turns every layer by its kind's section of
+        ``rope_parameters`` (``yarn_frequencies``)."""
+        if self.block == 'gqa_moe':
+            return {kind: (yarn_frequencies(
+                self.d_key, section['rope_theta'],
+                section if section.get('rope_type') == 'yarn' else None),
+                float(section.get('attention_factor', 1.0)))
+                for kind, section in self.rope_parameters.items()}
+        return {SLIDING: (yarn_frequencies(self.d_key, self.rope_theta),
+                          1.0), FULL: None}
+
     def rotary(self):
         """Per layer, whether q and k are rotated."""
-        return [t == SLIDING for t in self.layer_types]
+        tables = self.rope_tables()
+        return [tables[t] is not None for t in self.layer_types]
 
 
 DecodePrograms = collections.namedtuple(
@@ -449,9 +595,28 @@ def kv_bytes_per_token(spec, kv_dtype='float32'):
     return b
 
 
+def pages_by_pool(spec, num_blocks):
+    """{pool name: pages} from ``num_blocks``: one count, which every
+    pool of the spec then has, or {pool name: pages} with the first
+    pool's under ``''``."""
+    pools = [pool.name for pool in spec.page_pools()]
+    if not isinstance(num_blocks, dict):
+        return {name: int(num_blocks) for name in pools}
+    if sorted(num_blocks) != sorted(pools):
+        raise ValueError('page counts for pools %s, the spec has %s'
+                         % (sorted(num_blocks), sorted(pools)))
+    return {name: int(num_blocks[name]) for name in pools}
+
+
 def arena_bytes(spec, num_blocks, block_size, kv_dtype='float32'):
-    """Total bytes of the cache (+ scale) arenas."""
-    return kv_page_bytes(spec, block_size, kv_dtype) * int(num_blocks)
+    """Total bytes of the cache (+ scale) arenas; ``num_blocks`` as
+    ``pages_by_pool`` takes it."""
+    pages = pages_by_pool(spec, num_blocks)
+    if len(pages) == 1:
+        return kv_page_bytes(spec, block_size, kv_dtype) * pages['']
+    return sum(n * int(block_size) * pages[kind.pool]
+               for kind, n in zip(spec.cache_kinds(), kv_bytes_per_kind(
+                   spec, kv_dtype).values()))
 
 
 def kv_page_bytes(spec, block_size, kv_dtype='float32'):
@@ -612,13 +777,44 @@ def latent_param_shapes(spec):
     return out
 
 
+def gqa_param_shapes(spec):
+    """``moe_param_shapes`` of the gqa_moe block: every layer has the
+    one shape whatever its kind, so each matrix is one stack over all
+    layers; two norms a layer, no shared expert, a head of its own. The
+    query projection is kept as its transpose, ``[L, heads x d,
+    d_model]`` (as the head is ``[V, d_model]``): kept ``[L, d_model,
+    heads x d]`` the v5e's compiler re-laid the whole stack, 151 MB at
+    the published widths, at the entry of every program (``copy
+    bf16[8,2304,4096]{1,2,0}``: 0.46 ms of a 5.2 ms decode step; my chip
+    run, PR 43)."""
+    L, d, f = spec.n_layer, spec.d_model, spec.d_inner
+    q, kv = spec.n_head * spec.d_key, spec.n_kv_head * spec.d_key
+    e = spec.experts_held
+    return collections.OrderedDict([
+        ('lm_emb', ([spec.vocab_size, d], d, 'Emb')),
+        ('lm_head.w', ([spec.vocab_size, d], d, 'Head')),
+        ('lm_final_ln.w', ([d], None, 'FinalLN')),
+        ('lm_stack_ln1.w', ([L, d], None, 'Ln1W')),
+        ('lm_stack_ln2.w', ([L, d], None, 'Ln2W')),
+        ('lm_stack_slf_q.w', ([L, q, d], d, 'SlfQ')),
+        ('lm_stack_slf_k.w', ([L, d, kv], d, 'SlfK')),
+        ('lm_stack_slf_v.w', ([L, d, kv], d, 'SlfV')),
+        ('lm_stack_slf_o.w', ([L, q, d], q, 'SlfO')),
+        ('lm_stack_router.w', ([L, d, spec.n_experts], d, 'Router')),
+        ('lm_stack_exp_gate.w', ([L, e, d, f], d, 'ExpGate')),
+        ('lm_stack_exp_up.w', ([L, e, d, f], d, 'ExpUp')),
+        ('lm_stack_exp_down.w', ([L, e, f, d], f, 'ExpDown')),
+    ])
+
+
 def block_param_shapes(spec):
     """{name: (shape, fan-in, op input slot)} of a routed block's
     weights: a fan-in of None is a norm's gain (float32 ones), of 0 a
     bias (float32 zeros), anything else a matrix kept at ``spec.dtype``
     and drawn N(0, 1 / fan-in)."""
-    return latent_param_shapes(spec) if spec.block == 'latent_moe' \
-        else moe_param_shapes(spec)
+    return {'latent_moe': latent_param_shapes,
+            'gqa_moe': gqa_param_shapes}.get(spec.block,
+                                             moe_param_shapes)(spec)
 
 
 def _moe_params(spec):
@@ -664,6 +860,22 @@ def _block_attrs(spec, block_size):
                 attrs[tag + '_rope_freq'] = [
                     float(f) for f in a.rope_frequencies()]
                 attrs[tag + '_softmax_mult'] = a.softmax_multiplier()
+    if spec.block == 'gqa_moe':
+        lead, period, n_periods, tail = spec.layer_plan()
+        attrs.update({
+            'block': spec.block, 'norm_eps': spec.norm_eps,
+            'top_k': spec.experts_per_token,
+            'first_expert': spec.first_expert,
+            'lead': list(lead), 'period': list(period),
+            'n_periods': n_periods, 'tail': list(tail),
+            'window': spec.sliding_window,
+            'pools': [pool.slot for pool in spec.page_pools()]})
+        for kind, (freq, factor) in spec.rope_tables().items():
+            tag = 'full' if kind == FULL else 'sliding'
+            attrs[tag + '_rope_freq'] = [float(f) for f in freq]
+            # cos and sin times the factor, on q and on k: the scores
+            # times its square
+            attrs[tag + '_softmax_mult'] = factor * factor
     return attrs
 
 
@@ -675,7 +887,8 @@ def _arenas(spec, num_blocks, block_size, kv_dtype='float32'):
     an index key), which is what lets the paged ops write a row in
     place (ops/paged_decode_ops.py). Axes 0 and 1 are layer and page for
     every arena — all that read_pages/write_pages and the handoff
-    index by — and a page id is the same page of every arena. Quantized
+    index by — and a page id is the same page of every arena of its
+    pool (``num_blocks``: {pool name: pages}). Quantized
     dtypes (int8 / fp8) additionally get
     per-(page, slot, head) fp32 scale arenas ``[L, NB, bs, H]`` — one
     scale per written K/V row, so a page's stored bits are a pure
@@ -690,12 +903,13 @@ def _arenas(spec, num_blocks, block_size, kv_dtype='float32'):
             attr=ParamAttr(name=name, initializer=Constant(fill),
                            trainable=False))
     out = collections.OrderedDict(
-        (kind.slot, arena(kind.name, [len(kind.layers), num_blocks,
+        (kind.slot, arena(kind.name, [len(kind.layers),
+                                      num_blocks[kind.pool],
                                       block_size, kind.stored],
                           kv_dtype, 0.0))
         for kind in spec.cache_kinds())
     if kv_quantized(kv_dtype):
-        sshape = [spec.n_layer, num_blocks, block_size, spec.n_head]
+        sshape = [spec.n_layer, num_blocks[''], block_size, spec.n_head]
         for name, slot in (('lm_kscale', 'KScale'), ('lm_vscale', 'VScale')):
             out[slot] = arena(name, sshape, 'float32', 1.0)
     return out
@@ -744,9 +958,20 @@ def build_lm_programs(spec, max_batch, block_size, num_blocks,
             "unquantized KV arena (got spec_k=%d, kv_dtype=%s)"
             % (spec.block, spec_k, kv_dtype))
     attrs = _block_attrs(spec, block_size)
+    num_blocks = pages_by_pool(spec, num_blocks)
+    pools = spec.page_pools()
+    if len(pools) > 1 and spec_k > 0:
+        raise NotImplementedError('speculation under one block table')
     startup = Program()
     prefill_prog = Program()
     decode_prog = Program()
+
+    def tables_of(prefix, slot, shape):
+        """{op input slot: [feed]} of the block tables, one a pool: the
+        first under the names one table always had."""
+        return {slot + pool.slot: [layers.data(
+            name=prefix + pool.feed, shape=shape, dtype='int32')]
+            for pool in pools}
 
     with program_guard(prefill_prog, startup):
         params = _lm_params(spec, capacity)
@@ -754,8 +979,7 @@ def build_lm_programs(spec, max_batch, block_size, num_blocks,
         ids = layers.data(name='pf_ids', shape=[-1], dtype='int64')
         length = layers.data(name='pf_len', shape=[], dtype='int32')
         cached = layers.data(name='pf_cached', shape=[], dtype='int32')
-        table = layers.data(name='pf_table', shape=[pages_per_seq],
-                            dtype='int32')
+        tables = tables_of('pf_table', 'BlockTable', [pages_per_seq])
         temp = layers.data(name='pf_temp', shape=[], dtype='float32')
         seed = layers.data(name='pf_seed', shape=[], dtype='int32')
         helper = LayerHelper('paged_prefill', name='paged_prefill')
@@ -763,8 +987,8 @@ def build_lm_programs(spec, max_batch, block_size, num_blocks,
         nxt.shape = (1,)
         inputs = _common_inputs(params, arenas)
         inputs.update({'Ids': [ids], 'Len': [length], 'Cached': [cached],
-                       'BlockTable': [table], 'Temp': [temp],
-                       'Seed': [seed]})
+                       'Temp': [temp], 'Seed': [seed]})
+        inputs.update(tables)
         outputs = dict(_arena_outputs(arenas), NextToken=[nxt])
         prefill_stats_fetch = _moe_stats_output(helper, spec, outputs)
         helper.append_op(type='paged_prefill', inputs=inputs,
@@ -776,8 +1000,7 @@ def build_lm_programs(spec, max_batch, block_size, num_blocks,
         arenas = _arenas(spec, num_blocks, block_size, kv_dtype)
         tokens = layers.data(name='dec_tokens', shape=[], dtype='int64')
         lens = layers.data(name='dec_lens', shape=[], dtype='int32')
-        tables = layers.data(name='dec_tables', shape=[pages_per_seq],
-                             dtype='int32')
+        tables = tables_of('dec_tables', 'BlockTables', [pages_per_seq])
         temps = layers.data(name='dec_temps', shape=[], dtype='float32')
         seeds = layers.data(name='dec_seeds', shape=[], dtype='int32')
         helper = LayerHelper('paged_decode_step', name='paged_decode_step')
@@ -785,8 +1008,8 @@ def build_lm_programs(spec, max_batch, block_size, num_blocks,
         nxt.shape = (max_batch,)
         inputs = _common_inputs(params, arenas)
         inputs.update({'Tokens': [tokens], 'SeqLens': [lens],
-                       'BlockTables': [tables], 'Temps': [temps],
-                       'Seeds': [seeds]})
+                       'Temps': [temps], 'Seeds': [seeds]})
+        inputs.update(tables)
         outputs = dict(_arena_outputs(arenas), NextTokens=[nxt])
         stats_fetch = _moe_stats_output(helper, spec, outputs)
         helper.append_op(type='paged_decode_step', inputs=inputs,

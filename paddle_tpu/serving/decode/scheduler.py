@@ -109,7 +109,7 @@ class Sequence(object):
     """One in-flight generation request."""
 
     __slots__ = ('request_id', 'prompt', 'max_new_tokens', 'temperature',
-                 'seed', 'eos_id', 'table', 'generated', 'streamed',
+                 'seed', 'eos_id', 'tables', 'generated', 'streamed',
                  'state', 'stream', 'cache_len', 'pending_token',
                  't_submit', 't_admit', 't_first_token', 't_last_token',
                  'preemptions', 'cached_len', 'published_pages', 'ctx',
@@ -124,7 +124,9 @@ class Sequence(object):
         self.temperature = float(temperature)
         self.seed = int(seed)
         self.eos_id = eos_id
-        self.table = BlockTable()
+        # one block table a page pool of the engine (the scheduler adds
+        # the others' as it takes the request in)
+        self.tables = [BlockTable()]
         self.generated = []
         self.streamed = 0
         self.state = WAITING
@@ -150,6 +152,11 @@ class Sequence(object):
         self.tenant = tenant
         self.priority = priority
         self.prio_rank = priority_rank(priority)
+
+    @property
+    def table(self):
+        """The first page pool's block table."""
+        return self.tables[0]
 
     def prefix(self):
         """Tokens whose KV must exist before the next decode step —
@@ -182,10 +189,16 @@ class Scheduler(object):
     admission first maps the prompt's cached pages into the block
     table — and because the cache is the pool's reclaimer, every grow
     below LRU-evicts reclaimable cached pages before this scheduler
-    ever preempts a running victim."""
+    ever preempts a running victim. ``pool`` is the engine's KVPool or,
+    where its cache kinds lie in several page pools, the list of them:
+    a sequence then has a table a pool, admission and growth need every
+    pool's pages, and a pool with a lifetime is trimmed behind its
+    window before it is grown (``grow``)."""
 
     def __init__(self, pool, max_batch, cache=None):
-        self.pool = pool
+        self.pools = list(pool) if isinstance(pool, (list, tuple)) \
+            else [pool]
+        self.pool = self.pools[0]
         self.max_batch = int(max_batch)
         self.cache = cache
         self.waiting = collections.deque()
@@ -195,6 +208,8 @@ class Scheduler(object):
 
     # ------------------------------------------------------------ intake
     def add(self, seq):
+        while len(seq.tables) < len(self.pools):
+            seq.tables.append(BlockTable())
         with self._mu:
             self.waiting.append(seq)
         self._publish()
@@ -242,12 +257,14 @@ class Scheduler(object):
             if len(self.running) >= self.max_batch or not self.waiting:
                 return False
             seq = self.waiting[self._head()]
-        need = self.pool.blocks_for(
-            len(seq.prompt) + len(seq.generated) + 1) - len(seq.table)
-        have = self.pool.free_blocks()
-        if self.cache is not None:
-            have += self.cache.cached_pages()
-        return need <= have
+        tokens = len(seq.prompt) + len(seq.generated) + 1
+        for pool, table in zip(self.pools, seq.tables):
+            have = pool.free_blocks()
+            if self.cache is not None:
+                have += self.cache.cached_pages()
+            if pool.span_pages(tokens) - len(table) > have:
+                return False
+        return True
 
     def pop_admittable(self):
         """Admit the next waiting sequence if a batch slot is free and
@@ -265,7 +282,7 @@ class Scheduler(object):
                 seq.cached_len = self.cache.match(prefix, seq.table)
                 seq.published_pages = seq.cached_len // \
                     self.pool.block_size
-            if not self.pool.grow(seq.table, len(prefix) + 1):
+            if not self._admit_pages(seq, len(prefix) + 1):
                 if seq.cached_len:
                     # roll the match back: pinned cache pages would
                     # block the very evictions admission is waiting on
@@ -286,7 +303,39 @@ class Scheduler(object):
         self._publish()
         return seq
 
+    def _admit_pages(self, seq, tokens):
+        """The pages ``seq`` is admitted with, of every pool or of none:
+        a prefix of ``tokens`` positions whole, or under a lifetime its
+        first ``span_pages`` pages, the most the sequence ever holds of
+        that pool (``grow`` trims before it grows and the worker is the
+        one thread that takes pages, so a prefill's later chunks find
+        theirs)."""
+        for i, (pool, table) in enumerate(zip(self.pools, seq.tables)):
+            if not pool.grow(table, min(
+                    tokens, pool.span_pages(tokens) * pool.block_size)):
+                # a later pool's shortfall (no prefix cache there, so
+                # the tables were empty): nothing is kept
+                for done, mine in zip(self.pools[:i], seq.tables[:i]):
+                    done.release(mine)
+                return False
+        return True
+
     # ----------------------------------------------------------- growth
+    def grow(self, seq, need_tokens, first_query=None):
+        """Make ``seq``'s tables cover ``need_tokens`` positions for
+        programs whose first query sits at ``first_query`` (default:
+        the last position, one row), in every pool: a pool with a
+        lifetime first gives back the pages that lie behind that
+        query's window. False where some pool cannot supply its pages
+        (what was given back or grown stays so)."""
+        if first_query is None:
+            first_query = need_tokens - 1
+        ok = True
+        for pool, table in zip(self.pools, seq.tables):
+            pool.trim(table, first_query)
+            ok = pool.grow(table, need_tokens) and ok
+        return ok
+
     def ensure_growth(self, seq, need_tokens=None):
         """Make sure ``seq`` owns the pages its next decode write lands
         in (``need_tokens`` positions — default one write past the steps
@@ -298,7 +347,7 @@ class Scheduler(object):
         was preempted (caller must drop it from this step)."""
         if need_tokens is None:
             need_tokens = seq.position() + 1
-        while not self.pool.grow(seq.table, need_tokens):
+        while not self.grow(seq, need_tokens, seq.position()):
             _obs.inc('decode.pool_exhausted_total')
             _obs.flight_event('decode_pool_exhausted',
                               request_id=seq.request_id,
@@ -330,7 +379,7 @@ class Scheduler(object):
         with self._mu:
             self.running.remove(seq)
             self.waiting.appendleft(seq)
-        self.pool.release(seq.table)
+        self._release(seq)
         seq.state = WAITING
         seq.cache_len = 0
         seq.pending_token = None
@@ -350,11 +399,16 @@ class Scheduler(object):
             seq.ctx.event('preempt', generated=len(seq.generated))
         self._publish()
 
+    def _release(self, seq):
+        for pool, table in zip(self.pools, seq.tables):
+            if table.block_ids:
+                pool.release(table)
+
     # ----------------------------------------------------------- finish
     def finish(self, seq, reason):
         with self._mu:
             self.running.remove(seq)
-        self.pool.release(seq.table)
+        self._release(seq)
         seq.state = FINISHED
         _obs.inc('decode.finished_total', reason=reason)
         seq.stream._finish(reason, seq.generated)
@@ -369,8 +423,7 @@ class Scheduler(object):
             self.running = []
             self.waiting.clear()
         for seq in seqs:
-            if seq.table.block_ids:
-                self.pool.release(seq.table)
+            self._release(seq)
             seq.state = FINISHED
             seq.stream._fail(exc)
         self._publish()

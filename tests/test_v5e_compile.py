@@ -186,6 +186,26 @@ SERVING = {
         dict(max_batch=32, block_size=32, pages_per_seq=1040,
              num_blocks=4096, max_prompt_len=33024, prefill_chunk=512,
              min_prompt_bucket=512, kv_dtype='bfloat16')),
+    # the gqa_moe block as mellum2_12b runs it: 32 query heads over 4 KV
+    # heads of 128, one period of (sliding, sliding, sliding, full), the
+    # 1,024 window, both position tables, each kind's arenas under a
+    # pool and a table of its own
+    'mellum2_12b': (
+        dict(vocab_size=256, n_layer=4, n_head=32, n_kv_head=4, d_key=128,
+             d_value=128, d_model=256, d_inner=64, block='gqa_moe',
+             layer_types=('sliding_attention',) * 3 + ('full_attention',),
+             sliding_window=1024, n_experts=8, experts_per_token=2,
+             norm_eps=1e-6, dtype='bfloat16', rope_parameters={
+                 'full_attention': dict(
+                     rope_type='yarn', rope_theta=500000, factor=16,
+                     original_max_position_embeddings=8192, beta_fast=32,
+                     beta_slow=1, attention_factor=1.2772588722239782),
+                 'sliding_attention': dict(rope_type='default',
+                                           rope_theta=500000)}),
+        dict(max_batch=32, block_size=32, pages_per_seq=1040,
+             num_blocks=4096, pool_blocks={'sliding': 1600},
+             max_prompt_len=32768, prefill_chunk=512,
+             min_prompt_bucket=512, kv_dtype='bfloat16')),
 }
 
 
@@ -224,8 +244,10 @@ def test_no_serving_program_gathers_a_whole_table(one_chip, engine, which):
     # an arena are not counted here; tests/test_latent_moe_block.py and
     # chip_smoke.py count them on the program as it is run)
     short = {'float32': 'f32', 'bfloat16': 'bf16'}[engine.kv_dtype]
+    pages = {pool.name: engine.pools[i].num_blocks
+             for i, pool in enumerate(engine.spec.page_pools())}
     for k in kinds:
-        arena = '%s[%d,%d,%d,%d]' % (short, len(k.layers), engine.num_blocks,
+        arena = '%s[%d,%d,%d,%d]' % (short, len(k.layers), pages[k.pool],
                                      engine.block_size, k.stored)
         assert set(re.findall(re.escape(arena) + r'\{([\d,]+)', hlo)) \
             == {'3,2,1,0'}, arena
@@ -301,3 +323,28 @@ def test_an_expanded_chunk_makes_keys_and_values_head_major(one_chip, kind):
     assert relaid == []
     assert 'f32[1,1,%d,%d,512]' % (h, s) in hlo
     assert 'f32[1,1,%d,%d,%d]' % (h, s, d_v) in hlo
+
+
+@pytest.mark.parametrize('side', ['program', 'reference'])
+@pytest.mark.parametrize('rows,heads', [(32, 32), (512, 4)])
+def test_the_half_split_rotation_compiles_as_a_program_of_its_own(
+        one_chip, side, rows, heads):
+    """mellum2_12b turns the two halves of a 128-wide head. Written as a
+    concatenate of two halves of 64 columns the rotation aborted the
+    v5e's compiler wherever it was a program of its own
+    (``fusion_emitter.cc: IsFusibleUnalignedDUS``: the plain reference's
+    first sequence on the chip, PR 43; inside the serving programs the
+    same lines happened to fuse otherwise). Both sides now swap the
+    halves by a roll; this compiles each at the published widths."""
+    if side == 'program':
+        from paddle_tpu.ops.gqa_moe_ops import rope_half_at as rotate
+        extra = ()
+    else:
+        from paddle_tpu.models.reference.mellum2_12b import \
+            rotate_halves as rotate
+        extra = (_shaped(one_chip, (), jnp.float32),)
+    hlo = jax.jit(rotate).lower(
+        _shaped(one_chip, (rows, heads, 128), jnp.float32),
+        _shaped(one_chip, (rows,), jnp.int32),
+        _shaped(one_chip, (64,), jnp.float32), *extra).compile().as_text()
+    assert 'f32[%d,%d,128]' % (rows, heads) in hlo
