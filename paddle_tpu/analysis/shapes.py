@@ -253,28 +253,40 @@ def _conv2d(ctx, op, i, sparse):
 
 
 def _fused_attention(ctx, op, i, sparse):
-    q = _in_shape(ctx, op, 'Q')
-    k = _in_shape(ctx, op, 'K')
-    v = _in_shape(ctx, op, 'V')
+    """Either form of the op (ops/attention_ops.py): Q, K, V as the
+    caller projected them, or X, Mem and the four weights, whose columns
+    are then what the three feature dims were."""
+    projected = op.input('Wq') is not None
+    slots = ('Wq', 'Wk', 'Wv') if projected else ('Q', 'K', 'V')
+    q, k, v = (_in_shape(ctx, op, slot) for slot in slots)
     n_head = op.attr('n_head', 1) or 1
-    for slot, s in (('Q', q), ('K', k), ('V', v)):
+
+    def mismatch(message, slot):
+        ctx.error('attention-mismatch', message, op=op, op_index=i,
+                  var=op.input(slot))
+    for slot, s in zip(slots, (q, k, v)):
         if s is not None and _known(s[-1]) and s[-1] % n_head:
-            ctx.error('attention-mismatch',
-                      '%s feature dim %d is not divisible by n_head=%d'
-                      % (slot, s[-1], n_head), op=op, op_index=i,
-                      var=op.input(slot))
+            mismatch('%s feature dim %d is not divisible by n_head=%d'
+                     % (slot, s[-1], n_head), slot)
     if q is not None and k is not None and \
             not _dims_eq(q[-1], k[-1]):
-        ctx.error('attention-mismatch',
-                  'Q%s and K%s disagree on the key feature dim'
-                  % (list(q), list(k)), op=op, op_index=i,
-                  var=op.input('K'))
-    if k is not None and v is not None and len(k) == len(v) and \
-            len(k) >= 2 and not _dims_eq(k[-2], v[-2]):
-        ctx.error('attention-mismatch',
-                  'K%s and V%s disagree on the source sequence dim'
-                  % (list(k), list(v)), op=op, op_index=i,
-                  var=op.input('V'))
+        mismatch('%s%s and %s%s disagree on the key feature dim'
+                 % (slots[0], list(q), slots[1], list(k)), slots[1])
+    if not projected:
+        if k is not None and v is not None and len(k) == len(v) and \
+                len(k) >= 2 and not _dims_eq(k[-2], v[-2]):
+            mismatch('K%s and V%s disagree on the source sequence dim'
+                     % (list(k), list(v)), 'V')
+        return
+    # what each weight contracts: the model dim of the side it projects,
+    # and for Wo the value features the heads hand it
+    for src, slot in (('X', 'Wq'), ('Mem', 'Wk'), ('Mem', 'Wv'),
+                      ('Wv', 'Wo')):
+        a, w = _in_shape(ctx, op, src), _in_shape(ctx, op, slot)
+        if a is not None and w is not None and len(w) == 2 and \
+                not _dims_eq(a[-1], w[0]):
+            mismatch('%s%s has %d rows but %s%s hands it %d features'
+                     % (slot, list(w), w[0], src, list(a), a[-1]), slot)
 
 
 def _layer_norm(ctx, op, i, sparse):

@@ -178,7 +178,7 @@ def switch_transformer_lm(vocab_size, seq_len, n_layer=2, n_head=4,
     for i in range(0 if scan_layers else n_layer):
         d_head = d_model // n_head
         proj = _multi_head_attention(
-            x, x, x, d_head, d_head, d_model, n_head, dropout_rate,
+            x, x, d_head, d_head, d_model, n_head, dropout_rate,
             causal=True, name='moe_%d_slf' % i)
         x = layers.layer_norm(
             layers.elementwise_add(x=x, y=proj),

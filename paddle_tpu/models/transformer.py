@@ -2,10 +2,14 @@
 config — 6-layer encoder/decoder, d_model 512, 8 heads, label smoothing).
 
 TPU-first differences from the reference build:
-- attention is the fused `fused_attention` IR op (Pallas flash kernel on
-  TPU) instead of a chain of reshape/matmul/softmax ops, and padding
-  masks derive in-graph from a per-example `length` vector — the
-  reference feeds precomputed [B, H, T, T] bias tensors from the host.
+- an attention sublayer is ONE `fused_attention` IR op, its four
+  projections included (ops/attention_ops.py: q, k and v leave their
+  matmul head-major; between them the XLA-fused jnp attention, ring
+  attention on an 'sp' mesh, the Pallas flash kernel only from 512
+  positions on and only when opted in), instead of a chain of
+  reshape/matmul/softmax ops, and padding masks derive in-graph from a
+  per-example `length` vector — the reference feeds precomputed
+  [B, H, T, T] bias tensors from the host.
 - positional encodings are a non-trainable device-resident table sliced
   per step, not host-fed.
 - the whole train step (fwd + bwd + Adam + label smoothing) compiles to
@@ -34,35 +38,35 @@ def position_encoding_table(max_length, d_model):
     return table
 
 
-def _multi_head_attention(queries, keys, values, d_key, d_value, d_model,
-                          n_head, dropout_rate, causal=False,
-                          key_length=None, name='attn'):
-    q = layers.fc(input=queries, size=d_key * n_head, num_flatten_dims=2,
-                  bias_attr=False,
-                  param_attr=ParamAttr(name=name + '_q.w'))
-    k = layers.fc(input=keys, size=d_key * n_head, num_flatten_dims=2,
-                  bias_attr=False,
-                  param_attr=ParamAttr(name=name + '_k.w'))
-    v = layers.fc(input=values, size=d_value * n_head, num_flatten_dims=2,
-                  bias_attr=False,
-                  param_attr=ParamAttr(name=name + '_v.w'))
-
+def _multi_head_attention(x, mem, d_key, d_value, d_model, n_head,
+                          dropout_rate, causal=False, key_length=None,
+                          name='attn'):
+    """One attention sublayer as ONE ``fused_attention`` op that carries
+    its four weights: queries from ``x``, keys and values from ``mem``
+    (``x`` again for self-attention). The weights keep the names and the
+    shapes four bias-free fc layers gave them ([d_model, H*D] and
+    [H*D, d_model]), so a checkpoint reads either way."""
     from ..layers.helper import LayerHelper
     helper = LayerHelper('fused_attention', name=name)
-    out = helper.create_variable_for_type_inference(q.dtype)
-    if q.shape is not None:
-        out.shape = (q.shape[0], q.shape[1], d_value * n_head)
-    inputs = {'Q': [q], 'K': [k], 'V': [v]}
+    dtype = x.dtype
+
+    def weight(suffix, shape):
+        return helper.create_parameter(
+            attr=ParamAttr(name=name + suffix), shape=shape, dtype=dtype)
+    inputs = {'X': [x], 'Mem': [mem],
+              'Wq': [weight('_q.w', [x.shape[-1], d_key * n_head])],
+              'Wk': [weight('_k.w', [mem.shape[-1], d_key * n_head])],
+              'Wv': [weight('_v.w', [mem.shape[-1], d_value * n_head])],
+              'Wo': [weight('_out.w', [d_value * n_head, d_model])]}
     if key_length is not None:
         inputs['KeyLength'] = [key_length]
+    out = helper.create_variable_for_type_inference(dtype)
+    out.shape = tuple(x.shape[:2]) + (d_model,)
     helper.append_op(type='fused_attention', inputs=inputs,
                      outputs={'Out': [out]},
                      attrs={'n_head': n_head, 'causal': causal,
                             'dropout_rate': dropout_rate})
-    proj = layers.fc(input=out, size=d_model, num_flatten_dims=2,
-                     bias_attr=False,
-                     param_attr=ParamAttr(name=name + '_out.w'))
-    return proj
+    return out
 
 
 def _ffn(x, d_inner, d_model, dropout_rate, name='ffn'):
@@ -118,7 +122,7 @@ def _prepare_input(word_ids, vocab_size, d_model, max_length, dropout_rate,
 
 def encoder_layer(x, n_head, d_key, d_value, d_model, d_inner, dropout_rate,
                   src_length=None, name='enc'):
-    attn = _multi_head_attention(x, x, x, d_key, d_value, d_model, n_head,
+    attn = _multi_head_attention(x, x, d_key, d_value, d_model, n_head,
                                  dropout_rate, key_length=src_length,
                                  name=name + '_slf')
     x = _post_process(x, attn, dropout_rate, name=name + '_pp1')
@@ -128,12 +132,12 @@ def encoder_layer(x, n_head, d_key, d_value, d_model, d_inner, dropout_rate,
 
 def decoder_layer(x, enc_out, n_head, d_key, d_value, d_model, d_inner,
                   dropout_rate, src_length=None, name='dec'):
-    slf = _multi_head_attention(x, x, x, d_key, d_value, d_model, n_head,
+    slf = _multi_head_attention(x, x, d_key, d_value, d_model, n_head,
                                 dropout_rate, causal=True,
                                 name=name + '_slf')
     x = _post_process(x, slf, dropout_rate, name=name + '_pp1')
-    cross = _multi_head_attention(x, enc_out, enc_out, d_key, d_value,
-                                  d_model, n_head, dropout_rate,
+    cross = _multi_head_attention(x, enc_out, d_key, d_value, d_model,
+                                  n_head, dropout_rate,
                                   key_length=src_length,
                                   name=name + '_cross')
     x = _post_process(x, cross, dropout_rate, name=name + '_pp2')

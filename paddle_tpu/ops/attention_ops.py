@@ -2,14 +2,24 @@
 
 Reference parity: the reference builds attention from primitive ops
 (fluid/nets.py scaled_dot_product_attention; the transformer model in its
-book/benchmark configs). TPU-native design: attention is ONE IR op so the
-executor can dispatch the whole q·kᵀ→mask→softmax→·v chain to a Pallas
-flash-attention kernel on TPU (ops/pallas/flash_attention.py), falling
-back to a jnp reference everywhere else. Inputs are the head-merged
-projections [B, T, H*D]; masking is computed in-kernel from attrs
-(causal) and an optional per-example KeyLength vector — no giant
+book/benchmark configs). TPU-native design: the attention sublayer is
+ONE IR op, the four projections included, so its lowering decides the
+layout of what lies between them: queries, keys and values leave their
+projection head-major ([B, H, T, D]) and the output projection contracts
+(h, d) out of the context, so no head split or merge is left for the
+compiler to materialise as a pass over HBM (``attention_sublayer``).
+A caller that built Q, K and V itself hands in the head-merged
+projections [B, T, H*D] (``fused_attention``). Masking is computed from
+attrs (causal) and an optional per-example KeyLength vector: no
 [B, H, T, T] bias tensors cross the feed boundary as they do in the
 reference transformer config.
+
+What runs between the projections: ring attention on a mesh with an
+active 'sp' axis; the Pallas flash kernel (ops/pallas/flash_attention.py)
+only for 512 positions or more and only when opted in
+(PADDLE_TPU_USE_PALLAS, or the tuning table under PADDLE_TPU_AUTOTUNE);
+everywhere else, which is every length the models train at by default,
+the jnp ``reference_attention`` that XLA fuses.
 """
 
 import jax
@@ -100,10 +110,10 @@ def _sp_size(mesh):
     return dict(mesh.shape).get('sp', 1)
 
 
-def fused_attention(q3, k3, v3, n_head, causal=False, key_length=None,
-                    query_length=None, dropout_rate=0.0, rng=None,
-                    is_test=False, mesh=None):
-    """q3/k3/v3: [B, T, H*D]. Returns [B, Tq, H*Dv].
+def _attend(q, k, v, causal, key_length, query_length, dropout_rate, rng,
+            is_test, mesh):
+    """q, k, v: [B, H, T, D], head-major. Returns the context
+    [B, H, Tq, Dv] with the output dropout applied.
 
     Dispatch order: ring attention when the program runs on a mesh with
     an active 'sp' axis (long-context sequence parallelism — K/V blocks
@@ -111,10 +121,6 @@ def fused_attention(q3, k3, v3, n_head, causal=False, key_length=None,
     when opted in and profitable; otherwise the XLA-fused jnp reference.
     """
     import os
-    q = _split_heads(q3, n_head)
-    k = _split_heads(k3, n_head)
-    v = _split_heads(v3, n_head)
-
     sp = _sp_size(mesh)
     use_ring = (sp > 1 and
                 q.shape[-2] % sp == 0 and k.shape[-2] % sp == 0 and
@@ -175,25 +181,71 @@ def fused_attention(q3, k3, v3, n_head, causal=False, key_length=None,
         keep = 1.0 - dropout_rate
         mask = jax.random.bernoulli(rng, keep, out.shape)
         out = jnp.where(mask, out / keep, 0.0)
+    return out
+
+
+def fused_attention(q3, k3, v3, n_head, causal=False, key_length=None,
+                    query_length=None, dropout_rate=0.0, rng=None,
+                    is_test=False, mesh=None):
+    """q3/k3/v3: [B, T, H*D], projected by the caller. Returns
+    [B, Tq, H*Dv]. The split and the merge are transposes the compiler
+    materialises at training widths: a sublayer that owns its weights
+    goes through ``attention_sublayer``."""
+    out = _attend(_split_heads(q3, n_head), _split_heads(k3, n_head),
+                  _split_heads(v3, n_head), causal, key_length,
+                  query_length, dropout_rate, rng, is_test, mesh)
     return _merge_heads(out)
+
+
+def attention_sublayer(x, mem, wq, wk, wv, wo, n_head, causal=False,
+                       key_length=None, dropout_rate=0.0, rng=None,
+                       is_test=False, mesh=None):
+    """The matmul part of an attention sublayer. x: [B, Tq, M] (the
+    queries' side), mem: [B, Tk, M'] (keys and values; x itself for
+    self-attention); wq, wk: [M, H*Dk], wv: [M', H*Dv], wo: [H*Dv, Mo],
+    as they are stored. Returns [B, Tq, Mo].
+
+    Each projection is one contraction over the model dimension against
+    the weight viewed [M, H, D] (a bitcast), so its result is born
+    [B, H, T, D]; the output projection contracts (h, d) against the
+    weight viewed [H, D, Mo]. Nothing is reshaped between a projection
+    and the attention, which is what lets the compiler lay each result
+    out as the attention's dots want it where the matmul stores it."""
+    def heads(w):
+        return w.reshape(w.shape[0], n_head, w.shape[1] // n_head)
+    q = jnp.einsum('btm,mhd->bhtd', x, heads(wq))
+    k = jnp.einsum('btm,mhd->bhtd', mem, heads(wk))
+    v = jnp.einsum('btm,mhd->bhtd', mem, heads(wv))
+    out = _attend(q, k, v, causal, key_length, None, dropout_rate, rng,
+                  is_test, mesh)
+    return jnp.einsum('bhtd,hdm->btm', out,
+                      wo.reshape(n_head, wo.shape[0] // n_head,
+                                 wo.shape[1]))
 
 
 @register('fused_attention')
 def _fused_attention(ctx):
-    q = ctx.input('Q')
-    k = ctx.input('K')
-    v = ctx.input('V')
+    """Two forms, told apart by the inputs the op carries: X, Mem and the
+    four weights (the sublayer, projections included), or Q, K, V that
+    the caller projected."""
     key_length = ctx.input('KeyLength') if ctx.has_input('KeyLength') \
         else None
-    query_length = ctx.input('QueryLength') \
-        if ctx.has_input('QueryLength') else None
-    n_head = ctx.attr('n_head', 1)
-    causal = ctx.attr('causal', False)
     dropout_rate = ctx.attr('dropout_rate', 0.0)
-    rng = ctx.rng_key() if dropout_rate else None
-    mesh = getattr(ctx.block.program, 'mesh', None)
-    out = fused_attention(q, k, v, n_head, causal=causal,
-                          key_length=key_length, query_length=query_length,
-                          dropout_rate=dropout_rate, rng=rng,
-                          is_test=ctx.is_test, mesh=mesh)
+    common = dict(causal=ctx.attr('causal', False), key_length=key_length,
+                  dropout_rate=dropout_rate,
+                  rng=ctx.rng_key() if dropout_rate else None,
+                  is_test=ctx.is_test,
+                  mesh=getattr(ctx.block.program, 'mesh', None))
+    n_head = ctx.attr('n_head', 1)
+    if ctx.has_input('Wq'):
+        out = attention_sublayer(
+            ctx.input('X'), ctx.input('Mem'), ctx.input('Wq'),
+            ctx.input('Wk'), ctx.input('Wv'), ctx.input('Wo'), n_head,
+            **common)
+    else:
+        query_length = ctx.input('QueryLength') \
+            if ctx.has_input('QueryLength') else None
+        out = fused_attention(ctx.input('Q'), ctx.input('K'),
+                              ctx.input('V'), n_head,
+                              query_length=query_length, **common)
     ctx.set_output('Out', out)

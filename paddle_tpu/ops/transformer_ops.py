@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import register
-from .attention_ops import fused_attention
+from .attention_ops import attention_sublayer
 
 
 def _dropout(x, rate, key, is_test):
@@ -44,13 +44,10 @@ def _post_process(prev, out, p, rate, key, is_test, ln_slot):
 
 def _attn(x, mem, p, pre, n_head, causal, key_length, rate, key, is_test,
           mesh):
-    q3 = x @ p[pre + '_q']
-    k3 = mem @ p[pre + '_k']
-    v3 = mem @ p[pre + '_v']
-    out = fused_attention(q3, k3, v3, n_head, causal=causal,
-                          key_length=key_length, dropout_rate=rate,
-                          rng=key, is_test=is_test, mesh=mesh)
-    return out @ p[pre + '_o']
+    return attention_sublayer(
+        x, mem, p[pre + '_q'], p[pre + '_k'], p[pre + '_v'], p[pre + '_o'],
+        n_head, causal=causal, key_length=key_length, dropout_rate=rate,
+        rng=key, is_test=is_test, mesh=mesh)
 
 
 def _ffn(x, p, rate, key, is_test):

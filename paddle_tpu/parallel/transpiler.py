@@ -201,6 +201,16 @@ def _auto_tp_specs(program):
                 else:
                     specs[yn] = P(*([None] * (ndim - 1) + ['tp']))
                     tp_last.update(op.output_names())
+        elif op.type == 'fused_attention':
+            # a sublayer that owns its projections splits its heads: q,
+            # k and v by columns, the output projection by rows (its
+            # psum restores replication, so the output stays unmarked)
+            for slot in ('Wq', 'Wk', 'Wv', 'Wo'):
+                for n in op.inputs.get(slot, ()):
+                    if n not in specs and isinstance(
+                            block._find_var_recursive(n), Parameter):
+                        specs[n] = P('tp', None) if slot == 'Wo' \
+                            else P(None, 'tp')
         elif op.type == 'elementwise_add' and \
                 op.inputs.get('X', [None])[0] in tp_last:
             yn = op.inputs.get('Y', [None])[0]

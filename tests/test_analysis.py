@@ -120,6 +120,67 @@ def test_shapes_matmul_mismatch():
     assert '4' in d.message and '5' in d.message
 
 
+def _attention_program(shapes, n_head=4):
+    """One fused_attention op over vars of the given shapes: the
+    projected form when 'Wq' is among them, else Q/K/V."""
+    prog = fluid.Program()
+    b = prog.global_block()
+    for name, shape in shapes.items():
+        if name.startswith('W'):
+            b.create_parameter(name, shape=shape, dtype='float32')
+        else:
+            b.create_var(name=name, shape=shape, dtype='float32',
+                         is_data=True)
+    b.create_var(name='o', shape=[-1, 6, 16], dtype='float32')
+    b.append_op('fused_attention',
+                inputs={n: [n] for n in shapes}, outputs={'Out': ['o']},
+                attrs={'n_head': n_head})
+    return prog
+
+
+_PROJECTED = {'X': [-1, 6, 16], 'Mem': [-1, 9, 16], 'Wq': [16, 32],
+              'Wk': [16, 32], 'Wv': [16, 24], 'Wo': [24, 16]}
+_QKV = {'Q': [-1, 6, 32], 'K': [-1, 9, 32], 'V': [-1, 9, 24]}
+
+
+@pytest.mark.parametrize('form', ['projected', 'qkv'])
+def test_shapes_attention_accepts_both_forms(form):
+    """Tq != Tk and Dk != Dv are legal in either form."""
+    prog = _attention_program(_PROJECTED if form == 'projected' else _QKV)
+    assert [d for d in analysis.run_passes(prog)
+            if d.code == 'attention-mismatch'] == []
+
+
+@pytest.mark.parametrize('base,change,var,words', [
+    (_PROJECTED, {'Wq': [16, 30], 'Wk': [16, 30]}, 'Wq',
+     ('30', 'n_head=4')),
+    (_PROJECTED, {'Wv': [16, 22], 'Wo': [22, 16]}, 'Wv',
+     ('22', 'n_head=4')),
+    (_PROJECTED, {'Wk': [16, 28]}, 'Wk', ('key feature dim',)),
+    (_PROJECTED, {'Wq': [12, 32]}, 'Wq', ('12 rows', 'X', '16')),
+    (_PROJECTED, {'Wk': [20, 32]}, 'Wk', ('20 rows', 'Mem', '16')),
+    (_PROJECTED, {'Wv': [20, 24]}, 'Wv', ('20 rows', 'Mem', '16')),
+    (_PROJECTED, {'Wo': [32, 16]}, 'Wo', ('32 rows', 'Wv', '24')),
+    (_QKV, {'Q': [-1, 6, 30], 'K': [-1, 9, 30]}, 'Q', ('30', 'n_head=4')),
+    (_QKV, {'K': [-1, 9, 28]}, 'K', ('key feature dim',)),
+    (_QKV, {'V': [-1, 7, 24]}, 'V', ('source sequence dim',)),
+], ids=['wq-heads', 'wv-heads', 'wq-wk', 'x-wq', 'mem-wk', 'mem-wv',
+        'wv-wo', 'q-heads', 'q-k', 'k-v-length'])
+def test_shapes_attention_mismatch(base, change, var, words):
+    """Each contract of fused_attention fires from the shapes of the
+    form the op carries: the weights' when it owns its projections,
+    Q/K/V's when the caller projected."""
+    prog = _attention_program(dict(base, **change))
+    got = [d for d in analysis.run_passes(prog)
+           if d.pass_name == 'shapes' and d.code == 'attention-mismatch']
+    assert got, 'no attention-mismatch'
+    d, = [d for d in got if d.var == var]
+    assert (d.severity, d.op_index, d.op_type) == \
+        ('error', 0, 'fused_attention')
+    for word in words:
+        assert word in d.message, d.message
+
+
 def test_shapes_elementwise_and_optimizer_contracts():
     prog = fluid.Program()
     b = prog.global_block()

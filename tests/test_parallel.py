@@ -584,7 +584,7 @@ def _train_attention_model(mesh=None, strategy=None, steps=3, causal=True):
     fluid.global_scope().clear()
     x = fluid.layers.data(name='x', shape=[16, 32], dtype='float32')
     y = fluid.layers.data(name='y', shape=[16, 32], dtype='float32')
-    attn = _multi_head_attention(x, x, x, d_key=8, d_value=8, n_head=4,
+    attn = _multi_head_attention(x, x, d_key=8, d_value=8, n_head=4,
                                  d_model=32, dropout_rate=0.0,
                                  causal=causal, name='spattn')
     loss = fluid.layers.mean(
@@ -621,6 +621,24 @@ def test_ring_attention_dispatch_matches_unsharded():
         assert abs(loss_1 - loss_sp) < 1e-4, (causal, loss_1, loss_sp)
         np.testing.assert_allclose(w_1, w_sp, rtol=1e-4, atol=1e-5,
                                    err_msg='causal=%s' % causal)
+
+
+def test_auto_tp_splits_an_attention_sublayer_by_heads():
+    """tensor_parallel with no tp_rules over the one-op attention
+    sublayer: q, k and v projections column-split (one head a shard at
+    n_head = tp = 4), the output projection row-split, and training
+    follows the unsharded run."""
+    loss_1, w_1 = _train_attention_model(mesh=None)
+    mesh = make_mesh(dp=2, tp=4)
+    loss_tp, w_tp = _train_attention_model(
+        mesh=mesh, strategy=ParallelStrategy(data_parallel=True,
+                                             tensor_parallel=True))
+    sh = fluid.default_main_program().var_shardings
+    for name in ('spattn_q.w', 'spattn_k.w', 'spattn_v.w'):
+        assert tuple(sh[name]) == (None, 'tp'), (name, sh[name])
+    assert tuple(sh['spattn_out.w']) == ('tp', None)
+    assert abs(loss_1 - loss_tp) < 1e-4
+    np.testing.assert_allclose(w_1, w_tp, rtol=1e-4, atol=1e-5)
 
 
 def test_parallel_executor_facade():

@@ -4,7 +4,9 @@ parts at the published widths, compiled here for a described chip
 layer-stacked weights once cost a copy of its largest operand and the
 attention once gathered every page of every table and re-laid what it
 had gathered (PERF.md, PR 26, PR 28, PR 29), and these tests keep that
-from coming back. One file, the topology in a fixture
+from coming back. So does the train step's attention, which once re-laid
+its queries, keys, values and their cotangents in passes of their own
+(PERF.md, PR 44). One file, the topology in a fixture
 (on-chip-measurement guide, 2)."""
 
 import re
@@ -348,3 +350,83 @@ def test_the_half_split_rotation_compiles_as_a_program_of_its_own(
         _shaped(one_chip, (rows,), jnp.int32),
         _shaped(one_chip, (64,), jnp.float32), *extra).compile().as_text()
     assert 'f32[%d,%d,128]' % (rows, heads) in hlo
+
+
+def _entry_copies(hlo, under):
+    """(elements, op_name) of every ``copy`` in the ENTRY computation
+    whose ``op_name`` has ``under`` among its scopes."""
+    out, inside = [], False
+    for line in hlo.split('\n'):
+        if line.startswith('ENTRY '):
+            inside = True
+        elif inside and line.startswith('}'):
+            break
+        m = inside and re.match(
+            r'^\s+(ROOT )?%[\w.\-]+ = \w+\[([\d,]*)\]\S* copy\(', line)
+        name = m and re.search(r'op_name="([^"]*)"', line)
+        if name and under in name.group(1):
+            n = 1
+            for d in filter(None, m.group(2).split(',')):
+                n *= int(d)
+            out.append((n, name.group(1)))
+    return out
+
+
+@pytest.mark.parametrize('kind', ['self', 'causal', 'cross'])
+def test_a_train_layer_relays_nothing_around_its_attention(
+        one_chip, monkeypatch, kind):
+    """One layer of `tbig_nmt.train_seq128`'s step as the executor jits
+    it (128 x 128 tokens, 16 heads of 64, d_model 1,024, FFN 4,096,
+    dropout 0.3, bf16 matmuls, forward, backward and Adam) with each of
+    the three attentions the model has: ``self`` is the encoder layer,
+    ``causal`` and ``cross`` the decoder layer's two, each with the
+    layer's FFN behind it. The ENTRY computation holds no ``copy`` of
+    B x T x H x D elements under ``fused_attention``: when the model
+    projected q, k and v itself and split the heads by a reshape and a
+    transpose, each attention cost four such passes (one forward, three
+    backward: 72 a step of the cell, 33.5 MB each). Whatever re-tiling
+    is left happens where a matmul stores its result."""
+    import numpy as np
+    import paddle_tpu as fluid
+    from paddle_tpu import layers
+    from paddle_tpu.models import transformer as T
+    b, t, h, d, m, ffn, rate = 128, 128, 16, 64, 1024, 4096, 0.3
+    monkeypatch.setenv('PADDLE_TPU_PRNG', 'rbg')   # as on the chip
+    with fluid.program_guard(fluid.Program(), fluid.Program()), \
+            fluid.scope_guard(fluid.Scope()):
+        x = layers.data(name='x', shape=[t, m], dtype='float32')
+        mem = layers.data(name='mem', shape=[t, m], dtype='float32')
+        length = layers.data(name='length', shape=[], dtype='int64')
+        target = layers.data(name='target', shape=[t, m], dtype='float32')
+        # the attention reads what a layer before it wrote, as in the
+        # model, not a feed whose layout the compiler cannot choose
+        x = layers.layer_norm(x, begin_norm_axis=2)
+        mem = layers.layer_norm(mem, begin_norm_axis=2)
+        attn = T._multi_head_attention(
+            x, mem if kind == 'cross' else x, d, d, m, h, rate,
+            causal=kind == 'causal',
+            key_length=None if kind == 'causal' else length, name='attn')
+        x = T._post_process(x, attn, rate, name='pp1')
+        out = T._post_process(x, T._ffn(x, ffn, m, rate), rate, name='pp2')
+        loss = layers.mean(layers.elementwise_mul(x=out, y=target))
+        fluid.optimizer.Adam(learning_rate=1e-4).minimize(loss)
+        prog = fluid.default_main_program()
+        prog.amp = 'bf16'
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(fluid.default_startup_program())
+        acts = np.zeros((b, t, m), 'float32')
+        step, scope_vals, feed_vals = exe.compile_step(
+            prog, feed={'x': acts, 'mem': acts, 'target': acts,
+                        'length': np.full((b,), t, 'int64')},
+            fetch_list=[loss])
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _shaped(one_chip, np.shape(a), a.dtype), tree)
+    hlo = jax.jit(step, donate_argnums=(0,)).lower(
+        shaped(scope_vals), shaped(feed_vals),
+        _shaped(one_chip, (), jnp.int32)).compile().as_text()
+    assert 'fused_attention' in hlo
+    relaid = [c for c in _entry_copies(hlo, 'fused_attention')
+              if c[0] >= b * t * h * d]
+    assert relaid == []
