@@ -42,7 +42,10 @@ kinds have unlike shapes and three arenas, so it brings its own layer
 functions and order, ``segments``; ``GqaMoEBlock`` in
 ops/gqa_moe_ops.py: mellum, block='gqa_moe', whose layer kinds keep
 their K and V in page pools of their own, each under its own block
-table: an op then has a table input a pool of its block, ``pools``):
+table: an op then has a table input a pool of its block, ``pools``;
+``SsmHybridBlock`` in ops/ssm_hybrid_ops.py: block='ssm_hybrid', whose
+Mamba-2 layers keep a state a sequence in arenas indexed by a slot and
+not by pages, read and written where it lies by ops/ssm_ops.py):
 embedding, the q/k/v
 projections, what follows attention, the per-layer lower bound on the
 columns a row sees, and the logits. Everything else — placement, the
@@ -413,6 +416,9 @@ def _block_of(ctx):
     if kind == 'gqa_moe':
         from .gqa_moe_ops import GqaMoEBlock
         return GqaMoEBlock(ctx)
+    if kind == 'ssm_hybrid':
+        from .ssm_hybrid_ops import SsmHybridBlock
+        return SsmHybridBlock(ctx)
     return _PostLNBlock(ctx)
 
 

@@ -46,8 +46,12 @@ workload — over a decoder-only LM with a paged KV cache:
   layer kind (sliding layers whose pool keeps a window's pages of a
   sequence beside full layers whose pool keeps every page:
   ``LMSpec.page_pools``; a ``KVPool``, a page count and a block table a
-  pool, ``self.pools``) run through this same engine. For the latter
-  three speculation and quantized arenas raise rather than run untested,
+  pool, ``self.pools``) and the state-space hybrid (Mamba-2 layers whose
+  state is one slot a sequence, a cache kind with a size a sequence in
+  a pool of slots, beside attention layers in pages: the programs are
+  fed a slot a row, ``_table_widths``) run through this same engine. For
+  the latter four speculation and quantized arenas raise rather than run
+  untested,
   and for the latent and the grouped block the page handoff too. The prefix cache runs
   for a spec whose frozen pages another sequence may map
   (``LMSpec.shares_frozen_pages``: 'post_ln', and a latent block all
@@ -229,13 +233,19 @@ class DecodeEngine(object):
         page_pools = spec.page_pools()
         # the feeds of the pools past the first: ``pf_table<suffix>``
         self._more_tables = [pool.feed for pool in page_pools[1:]]
-        pages = {pool.name: self.num_blocks for pool in page_pools}
+        # a pool of whole states has a slot a batch row: a sequence
+        # holds one from admission to release, and none while it waits
+        pages = {pool.name: self.max_batch if pool.per_sequence
+                 else self.num_blocks for pool in page_pools}
         unknown = sorted(set(pool_blocks or ()) - set(pages) - {''})
         if unknown:
             raise ValueError('pool_blocks for %s: the spec\'s pools are %s'
                              % (unknown, sorted(pages)))
         pages.update({name: int(n) for name, n in (pool_blocks or {}).items()})
         self.pages_per_seq = int(pages_per_seq)
+        # entries of a sequence's table, by pool
+        self._table_widths = [pool.table_width(self.pages_per_seq)
+                              for pool in page_pools]
         self.max_queue_depth = int(max_queue_depth)
         # feature knobs: explicit constructor args win, else the env
         # (PADDLE_TPU_PREFIX_CACHE / PADDLE_TPU_SPEC_K /
@@ -250,9 +260,11 @@ class DecodeEngine(object):
         if self.prefix_cache_on and not spec.shares_frozen_pages():
             # a spec with a windowed or a selected cache kind, and the
             # parallel block: their logits with shared pages are held
-            # to no reference yet (LMSpec.shares_frozen_pages)
+            # to no reference yet; one that keeps a state has no page of
+            # it to share (LMSpec.shares_frozen_pages, .refusal)
             raise NotImplementedError(
-                "block=%r runs without the prefix cache" % spec.block)
+                "block=%r runs without the prefix cache: %s"
+                % (spec.block, spec.refusal('prefix_cache')))
         self.spec_k = spec_k_from_env(spec_k)
         self.kv_dtype = resolve_kv_dtype(kv_dtype)
         self.kv_bytes_per_token = kv_bytes_per_token(spec, self.kv_dtype)
@@ -328,10 +340,15 @@ class DecodeEngine(object):
         self.pools = [
             KVPool(pages[pool.name], self.block_size,
                    kind=(pool.name or 'full') if len(page_pools) > 1
-                   else None, keep=pool.keeps, ahead=self.prefill_chunk)
+                   else None, keep=pool.keeps, ahead=self.prefill_chunk,
+                   whole=pool.per_sequence)
             for pool in page_pools]
         self.pool = self.pools[0]
         self._trims = any(pool.keep for pool in self.pools)
+        # the layers that keep a state a sequence (0: none): what a
+        # step's state update and a chunk's scan are counted by
+        self._state_layers = max(
+            [len(k.layers) for k in spec.cache_kinds() if k.per_seq] or [0])
         if _obs.enabled():
             _obs.set_gauge('decode.kv_bytes_per_token',
                            self.kv_bytes_per_token,
@@ -978,10 +995,11 @@ class DecodeEngine(object):
         """What ``warmup()`` dispatches ``which`` ('decode', 'verify' or
         a prefill bucket's size) with: the shapes live traffic uses,
         every block-table entry past the pool so nothing is written."""
-        mb, pps = self.max_batch, self.pages_per_seq
+        mb = self.max_batch
         rows = 1 if which not in ('decode', 'verify') else mb
-        first, *more = [np.full((rows, pps), pool.num_blocks, 'int32')
-                        for pool in self.pools]
+        first, *more = [np.full((rows, width), pool.num_blocks, 'int32')
+                        for pool, width in zip(self.pools,
+                                               self._table_widths)]
         if which in ('decode', 'verify'):
             toks = (mb,) if which == 'decode' else (mb, self.spec_k + 1)
             return (np.zeros(toks, 'int64'), np.zeros((mb,), 'int32'),
@@ -1086,10 +1104,12 @@ class DecodeEngine(object):
     def _table_rows(self, seq):
         """``seq``'s block tables as the programs take them, one a page
         pool: an entry it does not own (past its pages, or given back
-        behind a window) points past the pool."""
+        behind a window) points past the pool; of a pool of whole states
+        the one entry, its slot."""
         rows = []
-        for pool, table in zip(self.pools, seq.tables):
-            row = np.full((self.pages_per_seq,), pool.num_blocks, 'int32')
+        for pool, table, width in zip(self.pools, seq.tables,
+                                      self._table_widths):
+            row = np.full((width,), pool.num_blocks, 'int32')
             ids = table.block_ids[table.freed:]
             row[table.freed:table.freed + len(ids)] = ids
             rows.append(row)
@@ -1120,11 +1140,19 @@ class DecodeEngine(object):
                            if _obs.enabled() else 0 for a in starts]
             pairs = sum(chunk_pairs)
             seq.stream.cached_tokens = cached
-        expanded = 0
+            # with layers that keep a state: the (row, layer) steps of
+            # the recurrence a span's programs take (what a FLOP count
+            # of the chunked scan starts from)
+            layers = self._state_layers
+
+            def scanned(rows):
+                return {'scan_rows': rows * layers} if layers else {}
+        expanded = scan_chunks = 0
         del self._prefill_stats[:]
         with _obs.span('decode.prefill.run', bucket=bucket,
                        chunks=len(starts), cached_tokens=cached,
-                       attn_pairs=pairs, request_id=seq.request_id):
+                       attn_pairs=pairs, request_id=seq.request_id,
+                       **scanned(s - cached)):
             t0 = time.perf_counter()
             for start, its_pairs in zip(starts, chunk_pairs):
                 piece = prefix[start:start + top]
@@ -1138,8 +1166,10 @@ class DecodeEngine(object):
                 # a prefix of one chunk is the span above and no more
                 chunk_span = _obs.span(
                     'decode.prefill.chunk', bucket=rung, start=start,
-                    attn_pairs=its_pairs, request_id=seq.request_id) \
+                    attn_pairs=its_pairs, request_id=seq.request_id,
+                    **scanned(len(piece))) \
                     if len(starts) > 1 else contextlib.nullcontext()
+                scan_chunks += layers * -(-rung // self.spec.ssm_chunk)
                 if self._trims:
                     # a pool with a lifetime gives back what lies behind
                     # the chunk's first row and takes the chunk's pages
@@ -1166,6 +1196,13 @@ class DecodeEngine(object):
         _obs.inc('decode.prefill_chunks_expanded', expanded)
         _obs.inc('decode.prompt_tokens_total', s)
         _obs.inc('decode.prefill_attn_pairs', pairs)
+        if self._state_layers:
+            # a first chunk starts its slot from zeros; the scan runs
+            # in chunks of ``ssm_chunk`` rows of each program's bucket
+            _obs.inc('decode.state_resets_total')
+            _obs.inc('decode.prefill_scan_chunks_total', scan_chunks)
+            if seq.preemptions:
+                _obs.inc('decode.state_recomputed_tokens_total', s)
         with _obs.span('decode.prefill.emit'):
             if _obs.enabled():
                 # every chunk's program ended before the token was read
@@ -1223,10 +1260,11 @@ class DecodeEngine(object):
         every page beyond the pool, so their writes drop.
         ``tokens_per_row`` is what the step scores per row (k + 1 under
         speculation), for the counters only."""
-        mb, pps = self.max_batch, self.pages_per_seq
+        mb = self.max_batch
         lens = np.zeros((mb,), 'int32')
-        tables, *more = [np.full((mb, pps), pool.num_blocks, 'int32')
-                         for pool in self.pools]
+        tables, *more = [np.full((mb, width), pool.num_blocks, 'int32')
+                         for pool, width in zip(self.pools,
+                                                self._table_widths)]
         temps = np.zeros((mb,), 'float32')
         seeds = np.zeros((mb,), 'int32')
         for i, seq in enumerate(batch):
@@ -1239,6 +1277,10 @@ class DecodeEngine(object):
             # the KV positions this step attends over
             _obs.record('decode.step_live_tokens', int(lens.sum()))
             _obs.inc('decode.step_rows', len(batch))
+            if self._state_layers:
+                # the states this step reads, advances and writes back
+                _obs.inc('decode.step_state_rows_total',
+                         len(batch) * self._state_layers)
             self._count_attn_pages(lens, len(batch), tokens_per_row)
             window = self.spec.sliding_window
             if window:
@@ -1289,8 +1331,8 @@ class DecodeEngine(object):
         live = (np.arange(len(pos)) < rows * k1) & (pos < self.capacity)
         hi = np.where(live, pos + 1, 0)
         read = held = 0
-        for window, layers in collections.Counter(
-                self.spec.windows()).items():
+        windows = self.spec.attn_windows()
+        for window, layers in collections.Counter(windows).items():
             lo = np.maximum(hi - window, 0) if window else np.zeros_like(hi)
             bounds = (lo, hi, self.pages_per_seq, self.block_size, np)
             read += layers * int(pages_covered(*bounds))
@@ -1298,7 +1340,7 @@ class DecodeEngine(object):
         _obs.inc('decode.attn_pages_read', read)
         _obs.inc('decode.attn_pages_held', held)
         _obs.inc('decode.attn_pages_reachable',
-                 self.spec.n_layer * len(pos) * self.pages_per_seq)
+                 len(windows) * len(pos) * self.pages_per_seq)
 
     def _enqueue(self, dispatch, batch, tokens, feeds):
         """Enqueue one step over ``batch`` and start the copy of its

@@ -30,6 +30,19 @@ N * P * bs * H * D that no instruction of a serving program may reach.
 
 Instructions inside a fusion never reach memory and are skipped; a
 fusion counts by what it calls.
+
+The arenas of a cache kind with a size a sequence (a recurrent layer's
+state, ``[layers, slots + 1, ...]``: model.CacheKind.per_seq) are held to
+the same rule by the same function, with a layer of the arena as
+``min_elements``: the decode step slices a live row's slot, advances it
+and writes it back with ``dynamic-update-slice`` at (layer, slot), a
+prefill chunk the one slot of its sequence, and nothing else of that
+size may appear (tests/test_v5e_compile.py compiles both for a described
+v5e at the published state geometry and also holds every ``copy`` of an
+arena's shape to the entry computation). What to look for there: a slot's
+slice that fuses into two consumers makes the arena an operand of a
+computation beside its own in-place update, and the compiler then copies
+the arena whole (ops/ssm_ops.py::_slot_of keeps the slice one value).
 """
 
 import collections

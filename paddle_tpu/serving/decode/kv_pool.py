@@ -28,6 +28,13 @@ device: past the pool, like an unowned entry) and ``grow`` goes on at
 the end. A sequence then holds about ``keep`` positions of such a pool
 however long it is.
 
+Slots: a pool built with ``whole`` indexes arenas whose unit is one
+sequence's whole state (a recurrent layer's: ``model.CacheKind.per_seq``),
+not a page of token rows. It is the same free list of ids; what differs
+is how many a sequence needs: one, whatever its length (``blocks_for``),
+taken at admission like its first pages, kept while it runs, given back
+by ``release`` at its finish or its preemption.
+
 Cache integration: a global prefix cache (prefix_cache.py) parks
 frozen pages at refcount 1 so future requests can map them instead of
 re-prefilling. Those pages are *reclaimable*, not free — ``alloc``
@@ -76,9 +83,12 @@ class KVPool(object):
     is the pool's lifetime (module docstring) and ``ahead`` the most
     consecutive positions one program writes (a prefill chunk): a
     sequence never holds more than ``span_pages()``, which is what
-    admission asks of such a pool, whatever the prompt's length."""
+    admission asks of such a pool, whatever the prompt's length.
+    ``whole``: a unit is a sequence's whole state, and a sequence holds
+    one (module docstring)."""
 
-    def __init__(self, num_blocks, block_size, kind=None, keep=0, ahead=1):
+    def __init__(self, num_blocks, block_size, kind=None, keep=0, ahead=1,
+                 whole=False):
         if num_blocks < 1 or block_size < 1:
             raise ValueError('KVPool: need num_blocks >= 1 and '
                              'block_size >= 1, got %d / %d'
@@ -88,6 +98,7 @@ class KVPool(object):
         self.kind = kind
         self.keep = int(keep)
         self.ahead = int(ahead)
+        self.whole = bool(whole)
         self._labels = {'kind': kind} if kind else {}
         self._mu = threading.Lock()
         self._free = list(range(self.num_blocks - 1, -1, -1))
@@ -175,6 +186,10 @@ class KVPool(object):
             if labels:
                 _obs.set_gauge('decode.kv_pages_used',
                                self.num_blocks - free, **labels)
+            if self.whole:
+                _obs.set_gauge('decode.state_slots_used',
+                               self.num_blocks - free)
+                _obs.set_gauge('decode.state_slots_total', self.num_blocks)
             # largest-run is an O(free log free) sort — keep it OFF
             # the per-alloc/free hot path: refresh every Nth publish
             # (and on every direct largest_free_run/fragmentation
@@ -184,7 +199,10 @@ class KVPool(object):
                 self._publish_frag_locked(self._largest_run_locked())
 
     def blocks_for(self, n_tokens):
-        """Pages needed to hold n_tokens positions."""
+        """Pages needed to hold n_tokens positions; of a pool of whole
+        states, the one slot a sequence of any length holds."""
+        if self.whole:
+            return min(1, max(0, int(n_tokens)))
         return max(0, (int(n_tokens) + self.block_size - 1)
                    // self.block_size)
 

@@ -7,11 +7,15 @@ Scope, all sharing one parameter namespace (prefix ``lm_``):
   'post_ln' — models.transformer._stacked_layer_params layout,
   ENC_SLOTS, causal self-attention + FFN + 2 LNs per layer, token
   embedding, sinusoid position table, output projection; 'parallel_moe',
-  'latent_moe' and 'gqa_moe' — ``block_param_shapes``, at
-  ``LMSpec.dtype``) and the zeroed page arenas of the block's cache
+  'latent_moe', 'gqa_moe' and 'ssm_hybrid' — ``block_param_shapes``, at
+  ``LMSpec.dtype``) and the zeroed arenas of the block's cache
   kinds (``LMSpec.cache_kinds``: K and V ``[L, NB, bs, Hkv*d]``, a
   latent row and an index key per kind of layer, or K and V per kind of
-  layer, each kind in a page pool of its own: ``LMSpec.page_pools``). Arenas are persistable scope state: every
+  layer, each kind in a page pool of its own: ``LMSpec.page_pools``;
+  and, for a kind whose size is a sequence's and not a token's, the
+  recurrent state and the convolution's last inputs of the state-space
+  layers, ``[L, slots + 1, ...]``: one slot a sequence, the last one a
+  spare that rows past the batch read and write). Arenas are persistable scope state: every
   prefill/decode run reads them from scope and writes them back
   through executor donation — in-place HBM updates, the same
   whole-program-state contract the trainer uses for params.
@@ -50,13 +54,19 @@ __all__ = ['LMSpec', 'DecodePrograms', 'build_lm_programs']
 
 
 SLIDING, FULL = 'sliding_attention', 'full_attention'
+# the layer kinds of block='ssm_hybrid': a Mamba-2 mixer, or attention
+# that sees every position and carries none
+MAMBA, ATTENTION = 'mamba', 'attention'
 
 
 class CacheKind(collections.namedtuple(
         'CacheKind', ['name', 'slot', 'layers', 'width', 'reads',
-                      'shared', 'pool', 'keeps'])):
-    """One arena of the paged cache: its name, the op's input slot, the
-    layers that keep it (in order), the elements a token's row holds,
+                      'shared', 'pool', 'keeps', 'per_seq', 'dtype'])):
+    """One arena of the cache: its name, the op's input slot, the
+    layers that keep it (in order), the elements its unit holds in one
+    layer (``width``: a token's row; or, for a kind whose size is a
+    sequence's whatever its length, ``per_seq``: the shape of the one
+    slot a sequence holds, and ``width`` its elements),
     per layer of ``layers`` the most positions of a sequence one decode
     step's attention reads there (``reads``; 0: every position held),
     whether the one row serves every head (``shared``: a latent
@@ -66,14 +76,24 @@ class CacheKind(collections.namedtuple(
     where every layer of it is under a window and reads at most the
     last ``w`` positions, its query's own included; 0 where some layer
     reads every position or chooses among them all (a selection's
-    ``reads`` is no lifetime), and so every position is kept."""
+    ``reads`` is no lifetime), and so every position is kept. A kind
+    with ``per_seq`` reads no position (``reads`` empty) and has no
+    lifetime in positions; ``dtype`` is what its arena is kept at where
+    that is not the engine's ``kv_dtype`` (a recurrent state: float32,
+    whatever K and V are stored at)."""
 
     LANES = 128
 
     def __new__(cls, name, slot, layers, width, reads, shared, pool='',
-                keeps=0):
+                keeps=0, per_seq=(), dtype=None):
         return super(CacheKind, cls).__new__(
-            cls, name, slot, layers, width, reads, shared, pool, keeps)
+            cls, name, slot, layers, width, reads, shared, pool, keeps,
+            tuple(per_seq), dtype)
+
+    def unit_shape(self, block_size):
+        """The shape of one unit of the kind's pool in one layer: a
+        page of ``block_size`` rows, or a sequence's slot."""
+        return self.per_seq or (int(block_size), self.stored)
 
     @property
     def stored(self):
@@ -102,7 +122,19 @@ class PagePool(collections.namedtuple('PagePool',
     query still to come can go back to the pool (``KVPool.trim``). The
     first pool of a spec has no name and feeds the programs under the
     names one table always had; another is named after its layers' kind
-    and has feeds of its own (``pf_table_<name>``)."""
+    and has feeds of its own (``pf_table_<name>``). A pool of kinds with
+    a size a sequence (``per_sequence``) has slots for pages: a sequence
+    holds exactly one whatever its length, its table is that one entry,
+    and the arenas have one slot more than the pool, a spare for the
+    rows that hold none."""
+
+    @property
+    def per_sequence(self):
+        return all(kind.per_seq for kind in self.kinds)
+
+    def table_width(self, pages_per_seq):
+        """Entries of a sequence's table in this pool."""
+        return 1 if self.per_sequence else int(pages_per_seq)
 
     @property
     def feed(self):
@@ -238,7 +270,7 @@ class LatentShape(object):
 
 
 class LMSpec(object):
-    """Decoder-only LM hyperparameters: a family of four blocks.
+    """Decoder-only LM hyperparameters: a family of five blocks.
 
     ``block='post_ln'`` (the default; every argument after ``d_inner``
     unused): the 2017 decoder block — embedding scaled by sqrt(d_model)
@@ -302,7 +334,27 @@ class LMSpec(object):
     expert; an output head of its own behind a final RMSNorm. Each
     layer kind's K and V are arenas of their own under a page pool of
     their own (``page_pools``): the sliding layers' pool keeps a
-    window's pages a sequence, the full layers' every page."""
+    window's pages a sequence, the full layers' every page.
+
+    ``block='ssm_hybrid'`` (granitemoehybrid without experts): a serial
+    pre-norm block with a mixer by layer kind (``layer_types``: 'mamba'
+    / 'attention') and a dense gated SiLU MLP of ``d_inner`` in every
+    layer: ``h = x + r Mixer(RMSNorm(x))``, ``y = h + r MLP(RMSNorm(h))``
+    with ``r`` = ``residual_scale``; the embedding times
+    ``embed_scale``, tied, logits times ``logit_scale``. An attention
+    layer is ``n_head`` query heads over ``n_kv_head`` KV heads of
+    ``d_key`` with **no position** and the softmax scale ``attn_scale``;
+    its K and V are paged like every other block's. A Mamba-2 layer
+    (``ssm_heads`` heads of ``ssm_head_dim``, state ``ssm_state``, one
+    group, a causal depthwise convolution of ``ssm_conv`` taps, chunks
+    of ``ssm_chunk`` in prefill) keeps per sequence, whatever its
+    length, a state ``[ssm_state, ssm_heads x ssm_head_dim]`` in float32
+    and the last ``ssm_conv - 1`` inputs of its convolution end to end in
+    one row: cache kinds
+    with a size a sequence (``CacheKind.per_seq``) in a pool of their
+    own whose unit is one sequence's slot. Such a state cannot be
+    mapped from a page boundary nor rewound past a rejected draft: the
+    prefix cache and speculation are refused."""
 
     def __init__(self, vocab_size, n_layer=2, n_head=2, d_key=16,
                  d_value=16, d_model=32, d_inner=64, block='post_ln',
@@ -313,7 +365,9 @@ class LMSpec(object):
                  latent=None, dense_layers=0, d_inner_dense=0,
                  index_n_heads=0, index_head_dim=0, index_topk=0,
                  lora_rescale=True, attn_gate=True, routed_scale=1.0,
-                 rope_parameters=None):
+                 rope_parameters=None, ssm_heads=0, ssm_head_dim=0,
+                 ssm_state=0, ssm_conv=4, ssm_chunk=256, embed_scale=1.0,
+                 residual_scale=1.0, attn_scale=None):
         self.vocab_size = int(vocab_size)
         self.n_layer = int(n_layer)
         self.n_head = int(n_head)
@@ -348,6 +402,18 @@ class LMSpec(object):
         self.routed_scale = float(routed_scale)
         self.rope_parameters = {kind: dict(section) for kind, section
                                 in (rope_parameters or {}).items()}
+        self.ssm_heads = int(ssm_heads)
+        self.ssm_head_dim = int(ssm_head_dim)
+        self.ssm_state = int(ssm_state)
+        self.ssm_conv = int(ssm_conv)
+        self.ssm_chunk = int(ssm_chunk)
+        self.embed_scale = float(embed_scale)
+        self.residual_scale = float(residual_scale)
+        self.attn_scale = float(attn_scale) if attn_scale \
+            else self.d_key ** -0.5
+        if self.block == 'ssm_hybrid':
+            self._check_ssm()
+            return
         if self.block == 'post_ln':
             if self.n_kv_head != self.n_head:
                 raise ValueError("LMSpec: block='post_ln' has one KV head "
@@ -355,8 +421,8 @@ class LMSpec(object):
             return
         if self.block not in ('parallel_moe', 'latent_moe', 'gqa_moe'):
             raise ValueError('LMSpec: unknown block %r (post_ln, '
-                             'parallel_moe, latent_moe, gqa_moe)'
-                             % self.block)
+                             'parallel_moe, latent_moe, gqa_moe, '
+                             'ssm_hybrid)' % self.block)
         if self.block in ('parallel_moe', 'gqa_moe') and (
                 self.n_head % self.n_kv_head or self.d_key != self.d_value):
             raise ValueError('LMSpec: %d query heads over %d KV heads of '
@@ -384,6 +450,37 @@ class LMSpec(object):
             self._check_latent()
         if self.block == 'gqa_moe':
             self._check_rope()
+
+    def _check_ssm(self):
+        if len(self.layer_types) != self.n_layer or \
+                set(self.layer_types) - {MAMBA, ATTENTION}:
+            raise ValueError('LMSpec: layer_types %r for %d layers (mamba, '
+                             'attention)' % (self.layer_types, self.n_layer))
+        if self.n_head % self.n_kv_head or self.d_key != self.d_value:
+            raise ValueError('LMSpec: %d query heads over %d KV heads of '
+                             '%d/%d' % (self.n_head, self.n_kv_head,
+                                        self.d_key, self.d_value))
+        if MAMBA in self.layer_types and min(
+                self.ssm_heads, self.ssm_head_dim, self.ssm_state,
+                self.ssm_conv - 1, self.ssm_chunk) < 1:
+            raise ValueError(
+                'LMSpec: mamba layers of %d heads of %d, state %d, %d taps, '
+                'chunks of %d' % (self.ssm_heads, self.ssm_head_dim,
+                                  self.ssm_state, self.ssm_conv,
+                                  self.ssm_chunk))
+        if self.n_experts or self.dense_layers:
+            raise ValueError("LMSpec: block='ssm_hybrid' has a dense MLP in "
+                             "every layer and no experts")
+
+    @property
+    def ssm_inner(self):
+        """The width a Mamba-2 layer works at: heads x head width."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_width(self):
+        """What the convolution runs over: x, B and C of the one group."""
+        return self.ssm_inner + 2 * self.ssm_state
 
     def _check_rope(self):
         kinds = set(self.layer_types)
@@ -442,12 +539,41 @@ class LMSpec(object):
         in the arena) per arena. The one place where a
         token's cache is written down: the arenas' shapes, the bytes a
         token costs and the pages a budget buys are all read from it.
-        Every kind is indexed by the one block table: a page id is a
-        page of every arena, and every layer keeps every token. A full
+        Every kind of a pool is indexed by the pool's one block table: a
+        page id is a page of every arena of it. A full
         layer of the latent block keeps its latent rows, of which a
         step reads ``index_topk`` (0, no selection: all of them), and
         with a selection the indexer's keys beside them."""
         every = tuple(range(self.n_layer))
+        if self.block == 'ssm_hybrid':
+            # K and V of the attention layers in the pool that keeps
+            # every page; the Mamba layers' state and convolution rows
+            # in a pool whose unit is a sequence's slot
+            out = []
+            held = self.layers_of(ATTENTION)
+            if held:
+                reads = (0,) * len(held)
+                out += [CacheKind('lm_kcache', 'KCache', held,
+                                  self.n_kv_head * self.d_key, reads, False),
+                        CacheKind('lm_vcache', 'VCache', held,
+                                  self.n_kv_head * self.d_value, reads,
+                                  False)]
+            held = self.layers_of(MAMBA)
+            if held:
+                # the convolution's K - 1 rows lie end to end in one
+                # lane-dense row a slot: kept [K - 1, C] the v5e's
+                # compiler laid the slot axis inside the three rows and
+                # re-laid the arena around every program (compiled here
+                # for a described chip, PR 45)
+                state = (self.ssm_state, self.ssm_inner)
+                conv = ((self.ssm_conv - 1) * self.ssm_conv_width,)
+                out += [CacheKind('lm_ssm_state', 'SsmState', held,
+                                  state[0] * state[1], (), True, 'state',
+                                  per_seq=state, dtype='float32'),
+                        CacheKind('lm_ssm_conv', 'SsmConv', held, conv[0],
+                                  (), True, 'state', per_seq=conv,
+                                  dtype=self.dtype)]
+            return tuple(out)
         if self.block == 'gqa_moe':
             # K and V by layer kind: the full layers' pool has no name,
             # the sliding layers' is named and keeps a window's pages
@@ -527,11 +653,42 @@ class LMSpec(object):
         (tests/test_decode_serving.py for 'post_ln',
         tests/test_kimi_k2_6_block.py for the dense 'latent_moe'). The
         windowed and the selected kinds, and 'parallel_moe', have no
-        such test and stay refused (ROADMAP A8)."""
+        such test and stay refused (ROADMAP A8). A kind with a size a
+        sequence has no pages at all: a recurrent state is a function
+        of the whole chain and exists only at the position its owner
+        has reached, so nothing of it can be mapped from a page
+        boundary (``refusal``)."""
         if self.block == 'post_ln':
             return True
         return self.block == 'latent_moe' and not any(
             cap for kind in self.cache_kinds() for cap in kind.reads)
+
+    def keeps_state(self):
+        """Whether some cache kind is a sequence's state."""
+        return any(kind.per_seq for kind in self.cache_kinds())
+
+    def refusal(self, what):
+        """Why this spec runs without ``what`` ('prefix_cache' or
+        'speculation'), for the raise that refuses it."""
+        if self.keeps_state():
+            return {
+                'prefix_cache': (
+                    'a state-space layer keeps one state a sequence, not '
+                    'rows a token: there is no page of it to map from a '
+                    'page boundary, and no checkpoint of it is kept there'),
+                'speculation': (
+                    'a state-space layer\'s state is updated in place and '
+                    'cannot be rewound past a rejected draft'),
+            }[what]
+        return {'prefix_cache': 'its logits with shared pages are held to '
+                                'no reference yet',
+                'speculation': 'it has no test against the block\'s '
+                               'reference yet'}[what]
+
+    def attn_windows(self):
+        """``windows()`` of the layers that attend at all."""
+        return [w for w, t in zip(self.windows(), self.layer_types)
+                if t != MAMBA]
 
     def per_head_cache(self):
         """Whether a cached row is ``n_kv_head`` heads of K (or V)."""
@@ -574,12 +731,30 @@ DecodePrograms = collections.namedtuple(
 
 def kv_bytes_per_kind(spec, kv_dtype='float32'):
     """{arena name: HBM bytes one cached token costs in it}, over the
-    layers that keep it (``LMSpec.cache_kinds``), at the arena dtype."""
+    layers that keep it (``LMSpec.cache_kinds``), at the arena dtype: 0
+    for a kind whose size is a sequence's (``unit_bytes_per_kind`` has
+    what a slot of it costs)."""
     from ...quant.core import kv_itemsize
     item = kv_itemsize(kv_dtype)
     return collections.OrderedDict(
-        (kind.name, len(kind.layers) * kind.stored * item)
+        (kind.name, 0 if kind.per_seq
+         else len(kind.layers) * kind.stored * item)
         for kind in spec.cache_kinds())
+
+
+def unit_bytes_per_kind(spec, block_size, kv_dtype='float32'):
+    """{arena name: HBM bytes one unit of its pool costs in it}, over
+    the layers that keep it: a page of ``block_size`` tokens' rows at
+    the arena dtype, or one sequence's slot at the kind's own."""
+    from ...quant.core import kv_itemsize
+    out = collections.OrderedDict()
+    for kind in spec.cache_kinds():
+        elements = 1
+        for n in kind.unit_shape(block_size):
+            elements *= n
+        out[kind.name] = len(kind.layers) * elements * kv_itemsize(
+            kind.dtype or kv_dtype)
+    return out
 
 
 def kv_bytes_per_token(spec, kv_dtype='float32'):
@@ -614,9 +789,10 @@ def arena_bytes(spec, num_blocks, block_size, kv_dtype='float32'):
     pages = pages_by_pool(spec, num_blocks)
     if len(pages) == 1:
         return kv_page_bytes(spec, block_size, kv_dtype) * pages['']
-    return sum(n * int(block_size) * pages[kind.pool]
-               for kind, n in zip(spec.cache_kinds(), kv_bytes_per_kind(
-                   spec, kv_dtype).values()))
+    # a kind with a size a sequence has its spare slot beside the pool's
+    return sum(n * (pages[kind.pool] + bool(kind.per_seq))
+               for kind, n in zip(spec.cache_kinds(), unit_bytes_per_kind(
+                   spec, block_size, kv_dtype).values()))
 
 
 def kv_page_bytes(spec, block_size, kv_dtype='float32'):
@@ -807,14 +983,65 @@ def gqa_param_shapes(spec):
     ])
 
 
+def ssm_param_shapes(spec):
+    """``moe_param_shapes`` of the ssm_hybrid block. The two norms and
+    the gated MLP are stacks over all layers (the published input matrix
+    ``[d, 2 f]`` as its two halves, ``mlp_gate`` and ``mlp_up``: as one
+    stack of 40 layers it is 1.34 G elements, and the start-up program's
+    draw of it in float32 asked the chip for 5.0 GB of scratch beside
+    12.4 GB of arrays and did not load; my chip run, PR 45); ``lm_attn_*`` over the attention layers
+    in order, ``lm_mamba_*`` over the Mamba-2 layers: ``in`` projects to
+    ``[z (H P); x (H P); B (N); C (N); dt (H)]``, ``conv`` the depthwise
+    taps ``[K, H P + 2 N]`` (tap K - 1 reads the row's own input) and
+    their bias, ``dt.b`` and ``a_log`` a head each (biases: float32,
+    zero at start, so A = -1 until weights are loaded), ``d`` the skip
+    gain a head and ``norm`` the gated norm's (ones)."""
+    L, d, f = spec.n_layer, spec.d_model, spec.d_inner
+    q, kv = spec.n_head * spec.d_key, spec.n_kv_head * spec.d_key
+    out = collections.OrderedDict([
+        ('lm_emb', ([spec.vocab_size, d], d, 'Emb')),
+        ('lm_final_ln.w', ([d], None, 'FinalLN')),
+        ('lm_stack_ln1.w', ([L, d], None, 'Ln1W')),
+        ('lm_stack_ln2.w', ([L, d], None, 'Ln2W')),
+        ('lm_stack_mlp_gate.w', ([L, d, f], d, 'MlpGate')),
+        ('lm_stack_mlp_up.w', ([L, d, f], d, 'MlpUp')),
+        ('lm_stack_mlp_down.w', ([L, f, d], f, 'MlpDown')),
+    ])
+    n = len(spec.layers_of(ATTENTION))
+    if n:
+        out.update([
+            ('lm_attn_q.w', ([n, d, q], d, 'SlfQ')),
+            ('lm_attn_k.w', ([n, d, kv], d, 'SlfK')),
+            ('lm_attn_v.w', ([n, d, kv], d, 'SlfV')),
+            ('lm_attn_o.w', ([n, q, d], q, 'SlfO')),
+        ])
+    n = len(spec.layers_of(MAMBA))
+    if n:
+        heads, inner = spec.ssm_heads, spec.ssm_inner
+        conv = spec.ssm_conv_width
+        out.update([
+            ('lm_mamba_in.w', ([n, d, inner + conv + heads], d, 'SsmIn')),
+            ('lm_mamba_conv.w', ([n, spec.ssm_conv, conv], spec.ssm_conv,
+                                 'SsmConvW')),
+            ('lm_mamba_conv.b', ([n, conv], 0, 'SsmConvB')),
+            ('lm_mamba_dt.b', ([n, heads], 0, 'SsmDtB')),
+            ('lm_mamba_a_log', ([n, heads], 0, 'SsmALog')),
+            ('lm_mamba_d', ([n, heads], None, 'SsmD')),
+            ('lm_mamba_norm.w', ([n, inner], None, 'SsmNorm')),
+            ('lm_mamba_out.w', ([n, inner, d], inner, 'SsmOut')),
+        ])
+    return out
+
+
 def block_param_shapes(spec):
-    """{name: (shape, fan-in, op input slot)} of a routed block's
-    weights: a fan-in of None is a norm's gain (float32 ones), of 0 a
-    bias (float32 zeros), anything else a matrix kept at ``spec.dtype``
-    and drawn N(0, 1 / fan-in)."""
+    """{name: (shape, fan-in, op input slot)} of a block's weights
+    (every block but 'post_ln'): a fan-in of None is a norm's gain
+    (float32 ones), of 0 a bias (float32 zeros), anything else a matrix
+    kept at ``spec.dtype`` and drawn N(0, 1 / fan-in)."""
     return {'latent_moe': latent_param_shapes,
-            'gqa_moe': gqa_param_shapes}.get(spec.block,
-                                             moe_param_shapes)(spec)
+            'gqa_moe': gqa_param_shapes,
+            'ssm_hybrid': ssm_param_shapes}.get(spec.block,
+                                                moe_param_shapes)(spec)
 
 
 def _moe_params(spec):
@@ -876,13 +1103,25 @@ def _block_attrs(spec, block_size):
             # cos and sin times the factor, on q and on k: the scores
             # times its square
             attrs[tag + '_softmax_mult'] = factor * factor
+    if spec.block == 'ssm_hybrid':
+        lead, period, n_periods, tail = spec.layer_plan()
+        attrs.update({
+            'block': spec.block, 'norm_eps': spec.norm_eps,
+            'lead': list(lead), 'period': list(period),
+            'n_periods': n_periods, 'tail': list(tail),
+            'ssm_heads': spec.ssm_heads, 'ssm_state': spec.ssm_state,
+            'ssm_chunk': spec.ssm_chunk, 'embed_scale': spec.embed_scale,
+            'residual_scale': spec.residual_scale,
+            'attn_scale': spec.attn_scale,
+            'logit_scale': spec.logit_scale})
     return attrs
 
 
 def _arenas(spec, num_blocks, block_size, kv_dtype='float32'):
     """{op input slot: page arena} at ``kv_dtype``, one per cache kind
     of the block (``LMSpec.cache_kinds``): ``[layers of the kind, NB,
-    bs, row width]``, token-major inside a page and the row one
+    bs, row width]`` (a kind with a size a sequence: ``[layers of the
+    kind, slots + 1, slot shape]``), token-major inside a page and the row one
     lane-dense minor axis (K or V of all KV heads merged; a latent row;
     an index key), which is what lets the paged ops write a row in
     place (ops/paged_decode_ops.py). Axes 0 and 1 are layer and page for
@@ -902,11 +1141,14 @@ def _arenas(spec, num_blocks, block_size, kv_dtype='float32'):
             shape=shape, dtype=dtype, name=name,
             attr=ParamAttr(name=name, initializer=Constant(fill),
                            trainable=False))
+    # a kind with a size a sequence: ``[layers, slots + 1, ...]``, the
+    # last slot a spare for the rows that hold none, at its own dtype
     out = collections.OrderedDict(
-        (kind.slot, arena(kind.name, [len(kind.layers),
-                                      num_blocks[kind.pool],
-                                      block_size, kind.stored],
-                          kv_dtype, 0.0))
+        (kind.slot, arena(
+            kind.name, [len(kind.layers),
+                        num_blocks[kind.pool] + bool(kind.per_seq)]
+            + list(kind.unit_shape(block_size)),
+            kind.dtype or kv_dtype, 0.0))
         for kind in spec.cache_kinds())
     if kv_quantized(kv_dtype):
         sshape = [spec.n_layer, num_blocks[''], block_size, spec.n_head]
@@ -928,7 +1170,7 @@ def _moe_stats_output(helper, spec, outputs):
     choices that landed on an expert held here, rows on the busiest of
     them, experts any row chose, row tiles the routed product ran) and
     return its name; None for a block that routes nothing."""
-    if spec.block == 'post_ln':
+    if not spec.n_experts:
         return None
     stats = helper.create_variable_for_type_inference('int32')
     stats.shape = (spec.n_layer - spec.dense_layers, 4)
@@ -952,11 +1194,12 @@ def build_lm_programs(spec, max_batch, block_size, num_blocks,
     capacity = int(pages_per_seq) * int(block_size)
     spec_k = int(spec_k)
     if spec.block != 'post_ln' and (spec_k > 0 or kv_quantized(kv_dtype)):
-        # neither has a test against these blocks' references yet
+        # neither has a test against these blocks' references yet; a
+        # block that keeps a state has a reason of its own
         raise NotImplementedError(
-            "block=%r runs without speculation and with an "
+            "block=%r runs without speculation (%s) and with an "
             "unquantized KV arena (got spec_k=%d, kv_dtype=%s)"
-            % (spec.block, spec_k, kv_dtype))
+            % (spec.block, spec.refusal('speculation'), spec_k, kv_dtype))
     attrs = _block_attrs(spec, block_size)
     num_blocks = pages_by_pool(spec, num_blocks)
     pools = spec.page_pools()
@@ -966,11 +1209,13 @@ def build_lm_programs(spec, max_batch, block_size, num_blocks,
     prefill_prog = Program()
     decode_prog = Program()
 
-    def tables_of(prefix, slot, shape):
+    def tables_of(prefix, slot):
         """{op input slot: [feed]} of the block tables, one a pool: the
-        first under the names one table always had."""
+        first under the names one table always had; a pool of slots a
+        sequence feeds the one slot index a row."""
         return {slot + pool.slot: [layers.data(
-            name=prefix + pool.feed, shape=shape, dtype='int32')]
+            name=prefix + pool.feed,
+            shape=[pool.table_width(pages_per_seq)], dtype='int32')]
             for pool in pools}
 
     with program_guard(prefill_prog, startup):
@@ -979,7 +1224,7 @@ def build_lm_programs(spec, max_batch, block_size, num_blocks,
         ids = layers.data(name='pf_ids', shape=[-1], dtype='int64')
         length = layers.data(name='pf_len', shape=[], dtype='int32')
         cached = layers.data(name='pf_cached', shape=[], dtype='int32')
-        tables = tables_of('pf_table', 'BlockTable', [pages_per_seq])
+        tables = tables_of('pf_table', 'BlockTable')
         temp = layers.data(name='pf_temp', shape=[], dtype='float32')
         seed = layers.data(name='pf_seed', shape=[], dtype='int32')
         helper = LayerHelper('paged_prefill', name='paged_prefill')
@@ -1000,7 +1245,7 @@ def build_lm_programs(spec, max_batch, block_size, num_blocks,
         arenas = _arenas(spec, num_blocks, block_size, kv_dtype)
         tokens = layers.data(name='dec_tokens', shape=[], dtype='int64')
         lens = layers.data(name='dec_lens', shape=[], dtype='int32')
-        tables = tables_of('dec_tables', 'BlockTables', [pages_per_seq])
+        tables = tables_of('dec_tables', 'BlockTables')
         temps = layers.data(name='dec_temps', shape=[], dtype='float32')
         seeds = layers.data(name='dec_seeds', shape=[], dtype='int32')
         helper = LayerHelper('paged_decode_step', name='paged_decode_step')

@@ -1,0 +1,595 @@
+"""The ssm_hybrid block (granite_4_0_h_micro: Mamba-2 layers whose state
+is one slot a sequence beside position-free attention layers in the
+paged cache, a dense gated MLP in every layer) against its plain
+reference, at a tiny size on the CPU in float32: two periods of (mamba,
+mamba, attention, mamba), 4 heads of 16 over a state of 8, scan chunks of
+8, 4 query heads over 2 KV heads of 8.
+
+The comparisons are of logits, not tokens. Tolerance: both sides are
+float32 on the CPU; they differ in the order of their sums (the block
+scans in chunks and attends column block by column block under a running
+softmax; the reference steps the recurrence token by token and takes one
+softmax over the whole sequence), which at these widths gives differences
+of a few 1e-6 on logits of order 1. 5e-5 leaves a margin and is an order
+and more under what a dropped skip term, time-step bias or gate, or a
+state rounded to bfloat16, gives (checked below by breaking each)."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu import observe
+from paddle_tpu.models.reference import granite_4_0_h_micro as ref
+from paddle_tpu.ops import paged_decode_ops as pdo
+from paddle_tpu.ops import ssm_hybrid_ops as sho
+from paddle_tpu.ops import ssm_ops
+from paddle_tpu.serving.decode import DecodeEngine, LMSpec, random_weights
+from paddle_tpu.serving.decode import model as lm
+from paddle_tpu.serving.decode.kv_pool import KVPool
+from paddle_tpu.serving.decode.scheduler import Scheduler, Sequence
+
+TOL = 5e-5
+BS, PAGES, NB, SLOTS = 4, 24, 64, 4      # 96 positions a sequence
+CHUNK = 16                               # the engine's prefill chunk
+M, A = lm.MAMBA, lm.ATTENTION
+
+
+@pytest.fixture(autouse=True)
+def _clean_observe():
+    yield
+    observe.disable()
+    observe.reset()
+
+
+def _spec(**over):
+    kw = dict(
+        vocab_size=64, n_layer=8, n_head=4, n_kv_head=2, d_key=8, d_value=8,
+        d_model=32, d_inner=48, block='ssm_hybrid',
+        layer_types=[M, M, A, M] * 2, ssm_heads=4, ssm_head_dim=16,
+        ssm_state=8, ssm_conv=4, ssm_chunk=8, embed_scale=12.0,
+        residual_scale=0.22, attn_scale=0.125, logit_scale=0.125,
+        norm_eps=1e-5)
+    kw.update(over)
+    return LMSpec(**kw)
+
+
+SPEC = _spec()
+WEIGHTS = random_weights(SPEC, seed=11)
+
+
+class _Op(object):
+    def __init__(self, slots):
+        self._slots = slots
+
+    def input(self, slot):
+        return self._slots[slot]
+
+
+class _Ctx(object):
+    """What a paged op's lowering reads of its context, for driving the
+    block's row function without a Program; ``feeds``: the op inputs
+    the block reads itself (the slot a row; a prefill's cached span)."""
+
+    def __init__(self, spec, weights, feeds):
+        self._attrs = lm._block_attrs(spec, BS)
+        self._feeds = feeds
+        self.env = {}
+        slots = {}
+        for name, (_, _, slot) in lm.block_param_shapes(spec).items():
+            self.env[name] = jnp.asarray(weights[name])
+            slots[slot] = name
+        self.op = _Op(slots)
+
+    def attr(self, name, default=None):
+        return self._attrs.get(name, default)
+
+    def has_input(self, slot):
+        return slot in self._feeds
+
+    def input(self, slot):
+        if slot in self._feeds:
+            return self._feeds[slot]
+        return self.env[self.op.input(slot)]
+
+
+def _arenas(spec=SPEC, slots=SLOTS):
+    return tuple(
+        jnp.zeros((len(k.layers), (slots + 1) if k.per_seq else NB)
+                  + tuple(k.unit_shape(BS)), jnp.float32)
+        for k in spec.cache_kinds())
+
+
+@jax.jit
+def _chunk(arenas, table, slot, tokens, start, length):
+    """A prefill chunk as ``paged_prefill`` runs it: the logits of every
+    row and the arenas it leaves."""
+    block = sho.SsmHybridBlock(_Ctx(SPEC, WEIGHTS, {
+        'BlockTableState': slot, 'Cached': start}))
+    rows = tokens.shape[0]
+    pos = start + jnp.arange(rows, dtype=jnp.int32)
+    place = pdo._page_runs(table, start, length, rows, NB, BS)
+    h, arenas, _ = pdo._extend_rows(block, arenas, tokens, pos, table, place,
+                                    valid=jnp.arange(rows) < length)
+    return block.logits(h), arenas
+
+
+@jax.jit
+def _step(arenas, tables, slots, tokens, lens):
+    """A decode step as ``paged_decode_step`` runs it."""
+    block = sho.SsmHybridBlock(_Ctx(SPEC, WEIGHTS, {
+        'BlockTablesState': slots}))
+    place = pdo._single_rows(tables, lens, NB, BS)
+    h, arenas, _ = pdo._extend_rows(block, arenas, tokens, lens, tables,
+                                    place, valid=place.ok[:, 0])
+    return block.logits(h), arenas
+
+
+def _reference_logits(tokens, **lowered):
+    w = {k: jnp.asarray(v) for k, v in WEIGHTS.items()}
+    return np.asarray(ref.logits(w, np.asarray(tokens, np.int32),
+                                 dict(ref.arch_of(SPEC), **lowered)))
+
+
+def _tokens(n, seed=0):
+    return np.random.RandomState(seed).randint(0, 64, n).astype(np.int32)
+
+
+def _table(first, n_tokens):
+    """A block table whose pages start at page ``first``."""
+    row = np.full((PAGES,), NB, np.int32)
+    pages = -(-n_tokens // BS)
+    row[:pages] = first + np.arange(pages)
+    return jnp.asarray(row)
+
+
+def _prefill(arenas, table, slot, tokens, pieces):
+    """``tokens`` prefilled in chunks of the given lengths, each padded
+    to the next power of two of at least 4: [(logits of its valid rows)],
+    arenas."""
+    out, start = [], 0
+    for n in pieces:
+        bucket = max(4, 1 << (n - 1).bit_length())
+        ids = np.zeros((bucket,), np.int32)
+        ids[:n] = tokens[start:start + n]
+        lg, arenas = _chunk(arenas, table, jnp.asarray([slot], jnp.int32),
+                            jnp.asarray(ids), jnp.int32(start), jnp.int32(n))
+        out.append(np.asarray(lg)[:n])
+        start += n
+    return np.concatenate(out), arenas
+
+
+# ------------------------------------------------------- the cache's terms
+def test_the_state_is_a_cache_kind_with_a_size_a_sequence():
+    kinds = {k.name: k for k in SPEC.cache_kinds()}
+    assert sorted(kinds) == ['lm_kcache', 'lm_ssm_conv', 'lm_ssm_state',
+                             'lm_vcache']
+    assert kinds['lm_kcache'].layers == (2, 6) and not kinds[
+        'lm_kcache'].per_seq
+    state, conv = kinds['lm_ssm_state'], kinds['lm_ssm_conv']
+    assert state.layers == conv.layers == (0, 1, 3, 4, 5, 7)
+    assert state.per_seq == (8, 64) and state.dtype == 'float32'
+    assert conv.per_seq == (3 * (64 + 16),) and conv.dtype == 'float32'
+    assert state.reads == () and state.keeps == 0
+    pools = SPEC.page_pools()
+    assert [(p.name, p.per_sequence, p.keeps) for p in pools] == [
+        ('', False, 0), ('state', True, 0)]
+    assert [p.table_width(PAGES) for p in pools] == [PAGES, 1]
+    assert SPEC.layer_plan() == ((), (M, M, A, M), 2, ())
+    assert not SPEC.shares_frozen_pages() and not SPEC.per_head_cache()
+    assert SPEC.keeps_state() and not _other().keeps_state()
+
+
+def _other():
+    return LMSpec(vocab_size=64, n_layer=2)
+
+
+def test_the_bytes_count_the_state_without_a_case_for_the_block():
+    # K and V: 2 layers x 16 columns x 4 B a token; a slot: 6 layers x
+    # (8 x 64 + 3 x 80) x 4 B
+    assert lm.kv_bytes_per_kind(SPEC) == {
+        'lm_kcache': 128, 'lm_vcache': 128, 'lm_ssm_state': 0,
+        'lm_ssm_conv': 0}
+    assert lm.kv_bytes_per_token(SPEC) == 256
+    unit = lm.unit_bytes_per_kind(SPEC, BS)
+    assert unit == {'lm_kcache': 512, 'lm_vcache': 512,
+                    'lm_ssm_state': 6 * 8 * 64 * 4,
+                    'lm_ssm_conv': 6 * 3 * 80 * 4}
+    pages = {'': NB, 'state': SLOTS}
+    assert lm.pages_by_pool(SPEC, pages) == pages
+    # the arenas have the spare slot beside the pool's
+    assert lm.arena_bytes(SPEC, pages, BS) == 2 * 512 * NB + (
+        unit['lm_ssm_state'] + unit['lm_ssm_conv']) * (SLOTS + 1)
+    # the state is float32 whatever K and V are kept at
+    half = lm.unit_bytes_per_kind(_spec(dtype='bfloat16'), BS, 'bfloat16')
+    assert half['lm_ssm_state'] == unit['lm_ssm_state']
+    assert half['lm_ssm_conv'] * 2 == unit['lm_ssm_conv']
+    assert half['lm_kcache'] * 2 == unit['lm_kcache']
+
+
+def test_a_pool_of_whole_states_gives_a_sequence_one_slot():
+    observe.enable()
+    pool = KVPool(3, BS, kind='state', whole=True)
+    assert [pool.blocks_for(n) for n in (0, 1, 5, 9000)] == [0, 1, 1, 1]
+    assert pool.span_pages(9000) == 1
+    sched = Scheduler([KVPool(64, BS), pool], max_batch=4)
+    seqs = [Sequence(i, list(range(1, 10 + i)), 4, 0.0, 1, None)
+            for i in range(4)]
+    for seq in seqs:
+        sched.add(seq)
+    taken = [sched.pop_admittable() for _ in range(3)]
+    assert taken == seqs[:3]
+    assert [len(s.tables[1]) for s in seqs] == [1, 1, 1, 0]
+    assert sorted(s.tables[1].block_ids[0] for s in taken) == [0, 1, 2]
+    # a batch row is free and pages are, a slot is not: the fourth waits
+    assert not sched.admittable() and sched.pop_admittable() is None
+    assert observe.get_gauge('decode.state_slots_used') == 3
+    assert observe.get_gauge('decode.state_slots_total') == 3
+    # growth never asks the state pool for more
+    seqs[0].cache_len = 9
+    assert sched.ensure_growth(seqs[0], need_tokens=40)
+    assert len(seqs[0].tables[1]) == 1 and pool.used_blocks() == 3
+    # a preemption gives the slot back, a finish too
+    sched.preempt(seqs[2])
+    assert pool.used_blocks() == 2 and len(seqs[2].tables[1]) == 0
+    assert sched.pop_admittable() is seqs[2]
+    for seq in seqs[:3]:
+        sched.finish(seq, 'max_tokens')
+    assert pool.used_blocks() == 0
+    assert observe.get_gauge('decode.state_slots_used') == 0
+
+
+@pytest.mark.parametrize('over,what', [
+    (dict(layer_types=[M, 'full_attention'] * 4), 'layer_types'),
+    (dict(ssm_state=0), 'mamba layers'),
+    (dict(n_experts=4, experts_per_token=2), 'no experts'),
+    (dict(n_kv_head=3), 'query heads'),
+])
+def test_a_spec_the_block_cannot_build_is_refused(over, what):
+    with pytest.raises(ValueError, match=what):
+        _spec(**over)
+
+
+def test_the_prefix_cache_and_speculation_are_refused_with_their_reasons():
+    with pytest.raises(NotImplementedError,
+                       match='no page of it to map from a page boundary'):
+        DecodeEngine(SPEC, prefix_cache=True)
+    with pytest.raises(NotImplementedError,
+                       match='cannot be rewound past a rejected draft'):
+        DecodeEngine(SPEC, spec_k=2)
+    # the other blocks keep the reason they had
+    with pytest.raises(NotImplementedError, match='no test against'):
+        lm.build_lm_programs(
+            LMSpec(vocab_size=64, n_layer=2, block='parallel_moe',
+                   n_experts=4, experts_per_token=2, n_shared_experts=1),
+            2, BS, 8, 4, spec_k=2)
+    eng = DecodeEngine(SPEC, max_batch=2, block_size=BS, num_blocks=8,
+                       pages_per_seq=4)
+    from paddle_tpu.serving.handoff import CacheKindError
+    with pytest.raises(CacheKindError):
+        eng.kv_geometry()
+    eng.shutdown(drain=False)
+
+
+# ------------------------------------------------------------ the two ops
+def _scan_inputs(rows, seed=0):
+    rng = np.random.RandomState(seed)
+    x = rng.randn(rows, 4, 16).astype('f')
+    b, c = rng.randn(rows, 8).astype('f'), rng.randn(rows, 8).astype('f')
+    dt = np.log1p(np.exp(rng.randn(rows, 4))).astype('f')
+    a = -np.exp(rng.randn(4) * 0.5).astype('f')
+    return x, b, c, dt, a
+
+
+@pytest.mark.parametrize('rows,chunk', [(8, 8), (32, 8), (16, 256)])
+def test_the_chunked_scan_is_the_token_by_token_recurrence(rows, chunk):
+    x, b, c, dt, a = _scan_inputs(rows)
+    want = np.asarray(ref.recurrence(x, b, c, dt, a, 'float32'))
+    state = jnp.zeros((2, 3, 8, 64), jnp.float32)
+    got, state = jax.jit(ssm_ops.ssm_chunk_scan, static_argnums=(9, 10))(
+        state, 1, 2, x, b, c, dt, a, True, chunk, jnp.float32)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+    # the slot holds the state the recurrence ends in, state-major
+    s = np.zeros((4, 16, 8), 'f')
+    for t in range(rows):
+        s = np.exp(dt[t] * a)[:, None, None] * s + \
+            (dt[t][:, None] * x[t])[:, :, None] * b[t][None, None, :]
+    np.testing.assert_allclose(np.asarray(state[1, 2]),
+                               s.reshape(64, 8).T, atol=2e-5)
+    assert not np.asarray(state[0]).any() and not np.asarray(
+        state[1, :2]).any()
+
+
+def test_a_scan_seeded_from_its_slot_goes_on_where_the_last_one_ended():
+    x, b, c, dt, a = _scan_inputs(24, seed=3)
+    want = np.asarray(ref.recurrence(x, b, c, dt, a, 'float32'))
+    scan = jax.jit(ssm_ops.ssm_chunk_scan, static_argnums=(9, 10))
+    state = jnp.full((1, 2, 8, 64), 7.0)      # the last owner's
+    first, state = scan(state, 0, 1, x[:16], b[:16], c[:16], dt[:16], a,
+                        True, 8, jnp.float32)
+    # a padded tail (dt = 0) leaves the state alone
+    pad = np.zeros((8, 4), 'f')
+    rest, state = scan(state, 0, 1, np.concatenate([x[16:], x[:8]]),
+                       np.concatenate([b[16:], b[:8]]),
+                       np.concatenate([c[16:], c[:8]]),
+                       np.concatenate([dt[16:], pad]), a, False, 8,
+                       jnp.float32)
+    np.testing.assert_allclose(
+        np.concatenate([np.asarray(first), np.asarray(rest)[:8]]), want,
+        atol=2e-5)
+    again, _ = scan(state, 0, 1, x[:8] * 0, b[:8], c[:8] * 0 + 1, pad, a,
+                    False, 8, jnp.float32)
+    whole, _ = scan(jnp.zeros_like(state), 0, 1, x, b, c, dt, a, True, 8,
+                    jnp.float32)
+    assert np.isfinite(np.asarray(again)).all() and whole.shape == (24, 4,
+                                                                   16)
+    assert (np.asarray(state[0, 0]) == 7.0).all()
+
+
+def test_the_decode_update_steps_each_live_row_s_own_slot():
+    x, b, c, dt, a = _scan_inputs(4, seed=5)
+    rng = np.random.RandomState(1)
+    state = jnp.asarray(rng.randn(2, 5, 8, 64).astype('f'))
+    conv = jnp.asarray(rng.randn(2, 5, 240).astype('f'))
+    window = jnp.asarray(rng.randn(4, 4, 80).astype('f'))
+    slots = jnp.asarray([3, 0, 4, 4], jnp.int32)      # two live rows
+    live = jnp.asarray([True, True, False, False])
+    y, new, kept = jax.jit(ssm_ops.ssm_decode_update)(
+        state, conv, 1, slots, live, x, b, c, dt, a, window)
+    for i, slot in ((0, 3), (1, 0)):
+        s = np.asarray(state[1, slot]).T.reshape(4, 16, 8)
+        s = np.exp(dt[i] * a)[:, None, None] * s + \
+            (dt[i][:, None] * x[i])[:, :, None] * b[i][None, None, :]
+        np.testing.assert_allclose(np.asarray(new[1, slot]),
+                                   s.reshape(64, 8).T, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(y[i]),
+                                   (s * c[i][None, None, :]).sum(-1),
+                                   atol=1e-5)
+        np.testing.assert_array_equal(np.asarray(kept[1, slot]),
+                                      np.asarray(window[i, 1:]).reshape(-1))
+    # nothing else moved: the other layer, the other slots, the spare
+    untouched = np.asarray(new) == np.asarray(state)
+    assert untouched[0].all() and untouched[1, [1, 2, 4]].all()
+    assert (np.asarray(kept[1, [1, 2, 4]])
+            == np.asarray(conv[1, [1, 2, 4]])).all()
+
+
+# ------------------------------------------- the block against the reference
+def test_a_whole_prompt_prefill_matches_the_full_forward():
+    tokens = _tokens(16, 1)
+    got, _ = _prefill(_arenas(), _table(0, 16), 1, tokens, [16])
+    np.testing.assert_allclose(got, _reference_logits(tokens), atol=TOL)
+
+
+@pytest.mark.parametrize('pieces', [
+    [16, 16, 5],          # a last chunk shorter than its bucket (8)
+    [16, 2],              # one of fewer rows than the convolution keeps
+    [1, 1, 1, 16, 3],     # first chunks of under three rows
+    [13],                 # padded: 3 rows of a bucket of 16 are not live
+])
+def test_prefill_in_chunks_matches_the_full_forward(pieces):
+    tokens = _tokens(sum(pieces), 2)
+    got, _ = _prefill(_arenas(), _table(3, len(tokens)), 2, tokens, pieces)
+    np.testing.assert_allclose(got, _reference_logits(tokens), atol=TOL)
+
+
+def test_prefill_then_decode_through_the_cache_matches_the_full_forward():
+    """Three sequences of unlike depth, each prefilled in chunks into
+    its own slot and pages, then decoded together; between the steps the
+    rows change places (the engine compacts its batch every step: a row
+    index is no home for state, the slot is)."""
+    seqs = [_tokens(n, 10 + n) for n in (29, 42, 22)]
+    prompts = (17, 30, 9)
+    slots, firsts = (2, 0, 3), (0, 12, 30)
+    arenas = _arenas()
+    tables = [_table(f, len(s)) for f, s in zip(firsts, seqs)]
+    for seq, p, slot, table in zip(seqs, prompts, slots, tables):
+        _, arenas = _prefill(arenas, table, slot, seq[:p],
+                             [CHUNK] * (p // CHUNK) + [p % CHUNK])
+    want = [_reference_logits(s) for s in seqs]
+    order = [0, 1, 2]
+    for step in range(12):
+        if step % 3 == 2:
+            order = order[1:] + order[:1]       # rows move, slots stay
+        rows = [i for i in order if prompts[i] + step < len(seqs[i])]
+        pad = 4 - len(rows)
+        lens = [prompts[i] + step for i in rows]
+        lg, arenas = _step(
+            arenas,
+            jnp.stack([tables[i] for i in rows]
+                      + [jnp.full((PAGES,), NB, jnp.int32)] * pad),
+            jnp.asarray([slots[i] for i in rows] + [SLOTS] * pad,
+                        jnp.int32),
+            jnp.asarray([seqs[i][n] for i, n in zip(rows, lens)]
+                        + [0] * pad, jnp.int32),
+            jnp.asarray(lens + [0] * pad, jnp.int32))
+        for r, (i, n) in enumerate(zip(rows, lens)):
+            np.testing.assert_allclose(np.asarray(lg)[r], want[i][n],
+                                       atol=TOL)
+
+
+@pytest.mark.parametrize('lowered,what', [
+    (dict(d_skip=False), 'the skip term'),
+    (dict(dt_bias=False), 'the time-step bias'),
+    (dict(gate=False), 'the gate'),
+    (dict(state_dtype='bfloat16'), 'a state in bfloat16'),
+])
+def test_the_tolerance_catches_a_wrong_layer(lowered, what):
+    tokens = _tokens(40, 8)
+    sound = _reference_logits(tokens)
+    wrong = _reference_logits(tokens, **lowered)
+    assert np.abs(sound - wrong).max() > 10 * TOL, what
+
+
+# Whether a program copies an arena is a property of the chip's compiler
+# (the CPU's drops the barrier that keeps a slot's slice a value of its
+# own, ops/ssm_ops.py::_slot_of, and copies the arena twice a row):
+# tests/test_v5e_compile.py::test_the_state_arenas_are_written_where_they_lie
+# reads it off the programs compiled for a described v5e.
+
+
+# ------------------------------------------------------------ the engine
+def _engine(**over):
+    kw = dict(max_batch=SLOTS, block_size=BS, num_blocks=NB,
+              pages_per_seq=PAGES, prefill_chunk=CHUNK, min_prompt_bucket=4,
+              weights=WEIGHTS)
+    kw.update(over)
+    return DecodeEngine(SPEC, **kw)
+
+
+def _reference_tokens(prompt, answer):
+    lg = _reference_logits(list(prompt) + list(answer))
+    return lg[len(prompt) - 1:len(prompt) + len(answer) - 1].argmax(1) \
+        .tolist()
+
+
+PROMPTS = [_tokens(n, 20 + n).tolist() for n in (5, 23, 41, 60, 33, 2, 18)]
+ANSWERS = (18, 6, 25, 9, 30, 12, 3)
+
+
+@pytest.fixture(scope='module')
+def alone():
+    """Each request through an engine of its own batch row, one at a
+    time, sampled at temperature 1 (a stream depends on its seed and
+    positions alone): what every other way of serving them must give."""
+    eng = _engine(max_batch=1)
+    eng.start()
+    out = [eng.generate(p, max_new_tokens=n, temperature=1.0, seed=i,
+                        timeout=600)
+           for i, (p, n) in enumerate(zip(PROMPTS, ANSWERS))]
+    eng.shutdown()
+    return out
+
+
+@pytest.fixture(scope='module')
+def served():
+    """Seven requests through an engine of four rows, submitted
+    together: rows join as others leave, the batch is compacted every
+    step, slots are reused. (greedy answers, sampled answers, counters,
+    slots and pages used at the end, signatures)."""
+    observe.enable()
+    eng = _engine()
+    eng.warmup()
+    eng.start()
+    greedy = [s.result(timeout=600) for s in [
+        eng.submit(p, max_new_tokens=n) for p, n in zip(PROMPTS, ANSWERS)]]
+    sampled = [s.result(timeout=600) for s in [
+        eng.submit(p, max_new_tokens=n, temperature=1.0, seed=i)
+        for i, (p, n) in enumerate(zip(PROMPTS, ANSWERS))]]
+    counters = dict(observe.snapshot()['counters'])
+    used = [(p.used_blocks(), p.num_blocks) for p in eng.pools]
+    signatures = eng.warmup_signatures
+    eng.shutdown()
+    observe.disable()
+    observe.reset()
+    return greedy, sampled, counters, used, signatures
+
+
+@pytest.mark.parametrize('i', range(len(PROMPTS)))
+def test_the_engine_serves_the_references_tokens(served, i):
+    assert served[0][i] == _reference_tokens(PROMPTS[i], served[0][i])
+
+
+def test_concurrent_is_one_at_a_time(served, alone):
+    assert served[1] == alone
+    assert len({tuple(a) for a in alone}) == len(alone)
+
+
+def test_slots_and_pages_return_and_the_series_count_them(served):
+    _, _, counters, used, signatures = served
+    assert used == [(0, NB), (0, SLOTS)]
+    assert signatures == 4                  # buckets 4, 8, 16 and the step
+    # a first chunk a request; 6 state layers a live row a step
+    assert counters['decode.state_resets_total'] == 2 * len(PROMPTS)
+    assert counters['decode.step_state_rows_total'] == \
+        6 * counters['decode.step_rows']
+    assert counters['decode.prefill_scan_chunks_total'] >= \
+        6 * counters['decode.prefill_chunks']
+    assert 'decode.state_recomputed_tokens_total' not in counters
+    assert counters['decode.kv_pages_allocated_total{kind=state}'] == \
+        2 * len(PROMPTS)
+    # the attention's counters see the two attention layers alone
+    assert counters['decode.cache_bytes_read{kind=lm_kcache}'] > 0
+    assert counters.get('decode.cache_bytes_read{kind=lm_ssm_state}', 0) == 0
+    assert counters['decode.attn_pages_reachable'] % (2 * PAGES) == 0
+
+
+def test_a_reused_slot_gives_what_a_fresh_engine_gives(alone):
+    """One slot, so every request takes the one its predecessor left
+    full of state: a sequence's first chunk starts from zeros."""
+    eng = _engine(max_batch=1)
+    eng.start()
+    for i in (3, 1, 5):
+        assert eng.pools[1].used_blocks() == 0
+        assert eng.generate(PROMPTS[i], max_new_tokens=ANSWERS[i],
+                            temperature=1.0, seed=i, timeout=600) == alone[i]
+    # the slot was left full: the arena is not zeros
+    assert np.abs(np.asarray(eng._scope.get('lm_ssm_state'))[:, 0]).max() > 0
+    eng.shutdown()
+
+
+def test_a_preempted_sequence_resumes_to_identical_tokens(alone):
+    """Pages to admit all three and not to finish them: the youngest is
+    preempted,
+    its slot and pages released, its prefix prefilled again from a zero
+    state, and its stream goes on as if nothing had happened."""
+    observe.enable()
+    tight = _engine(num_blocks=38, max_batch=3)
+    tight.start()
+    picks = (2, 3, 4)
+    streams = [tight.submit(PROMPTS[i], max_new_tokens=ANSWERS[i],
+                            temperature=1.0, seed=i) for i in picks]
+    got = [s.result(timeout=600) for s in streams]
+    assert observe.get_counter('decode.preemptions_total') > 0
+    assert observe.get_counter('decode.state_recomputed_tokens_total') > 0
+    assert got == [alone[i] for i in picks]
+    assert [p.used_blocks() for p in tight.pools] == [0, 0]
+    tight.shutdown()
+
+
+def test_a_step_kept_in_flight_sees_the_state_the_step_before_wrote(alone):
+    """With nothing waiting the worker enqueues step n + 1 before it
+    fetches step n; the arenas chain through the donated scope, so the
+    tokens are the synchronous ones."""
+    observe.enable()
+    eng = _engine(max_batch=2)
+    eng.warmup()
+    eng.start()
+    picks = (0, 4)
+    streams = [eng.submit(PROMPTS[i], max_new_tokens=ANSWERS[i],
+                          temperature=1.0, seed=i) for i in picks]
+    assert [s.result(timeout=600) for s in streams] == \
+        [alone[i] for i in picks]
+    assert observe.get_counter('decode.steps_ahead_total') > 0
+    eng.shutdown()
+
+
+def test_the_programs_take_a_slot_a_row_and_keep_one_signature():
+    eng = _engine()
+    feeds = {v.name: tuple(v.shape) for v in
+             eng._progs.decode.global_block().vars.values()
+             if getattr(v, 'is_data', False)}
+    assert sorted(feeds) == ['dec_lens', 'dec_seeds', 'dec_tables',
+                             'dec_tables_state', 'dec_temps', 'dec_tokens']
+    assert feeds['dec_tables_state'][-1] == 1
+    assert eng._progs.arena_names == ('lm_kcache', 'lm_vcache',
+                                      'lm_ssm_state', 'lm_ssm_conv')
+    shapes = {n: tuple(eng._scope.get(n).shape)
+              for n in eng._progs.arena_names}
+    assert shapes == {'lm_kcache': (2, NB, BS, 16),
+                      'lm_vcache': (2, NB, BS, 16),
+                      'lm_ssm_state': (6, SLOTS + 1, 8, 64),
+                      'lm_ssm_conv': (6, SLOTS + 1, 240)}
+    assert str(eng._scope.get('lm_ssm_state').dtype) == 'float32'
+    # the rows past the batch and every warm-up feed point at the spare
+    args = eng._warm_args('decode')
+    assert args[-1].shape == (SLOTS, 1) and (args[-1] == SLOTS).all()
+    assert eng._warm_args(8)[-1].tolist() == [[SLOTS]]
+    # a state pool as wide as the batch unless told otherwise
+    assert eng.pools[1].num_blocks == SLOTS and eng.pools[1].whole
+    wide = _engine(pool_blocks={'state': 7})
+    assert wide.pools[1].num_blocks == 7
+    assert wide._scope.get('lm_ssm_state').shape[1] == 8
+    wide.shutdown(drain=False)
+    eng.shutdown(drain=False)

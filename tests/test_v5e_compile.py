@@ -430,3 +430,54 @@ def test_a_train_layer_relays_nothing_around_its_attention(
     relaid = [c for c in _entry_copies(hlo, 'fused_attention')
               if c[0] >= b * t * h * d]
     assert relaid == []
+
+
+def test_the_state_arenas_are_written_where_they_lie(one_chip):
+    """The ssm_hybrid block at the published state geometry (64 heads of
+    64 over a state of 128, a convolution over 4,352 columns; one period
+    of m m m m m a m m m m; hidden, MLP and vocabulary cut, which size
+    no instruction of the state's): the decode step and the 512 chunk as
+    the engine jits them. The compiler keeps both state arenas row-major
+    wherever they appear (the convolution's three rows lie end to end in
+    one row a slot: kept ``[3, 4352]`` it laid the slot axis inside the
+    rows and re-laid the arena around every program), and outside the
+    entry computation, which here copies every undonated argument, no
+    instruction materialises a layer of the state arena and no ``copy``
+    has an arena's shape: a row's state is sliced, advanced and written
+    back where it lies (ops/ssm_ops.py)."""
+    from jax.extend.core import jaxpr_as_fun
+    from paddle_tpu.serving.decode import DecodeEngine, LMSpec
+    from paddle_tpu.serving.decode.hlo_check import arena_sized_instructions
+    spec = LMSpec(
+        vocab_size=256, n_layer=10, n_head=32, n_kv_head=8, d_key=64,
+        d_value=64, d_model=256, d_inner=512, block='ssm_hybrid',
+        layer_types=['mamba'] * 5 + ['attention'] + ['mamba'] * 4,
+        ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_conv=4,
+        ssm_chunk=256, embed_scale=12.0, residual_scale=0.22,
+        attn_scale=0.015625, logit_scale=0.125, dtype='bfloat16')
+    eng = DecodeEngine(spec, max_batch=16, block_size=32, num_blocks=512,
+                       pages_per_seq=128, max_prompt_len=2048,
+                       prefill_chunk=512, min_prompt_bucket=512,
+                       kv_dtype='bfloat16')
+    try:
+        state, conv = 'f32[9,17,128,4096]', 'bf16[9,17,13056]'
+        for which in ('decode', 512):
+            closed = eng.trace_program(which).jaxpr
+            hlo = jax.jit(jaxpr_as_fun(closed)).lower(
+                *[_shaped(one_chip, a.shape, a.dtype)
+                  for a in closed.in_avals]).compile().as_text()
+            assert set(re.findall(re.escape(state) + r'\{([\d,]+)', hlo)) \
+                == {'3,2,1,0'}, which
+            assert set(re.findall(re.escape(conv) + r'\{([\d,]+)', hlo)) \
+                == {'2,1,0'}, which
+            inner = [i for i in arena_sized_instructions(
+                hlo, 17 * 128 * 4096) if not i.computation.startswith('main')]
+            assert inner == [], which
+            copies = [i for i in arena_sized_instructions(hlo, 17 * 13056)
+                      if i.opcode.startswith('copy')
+                      and not i.computation.startswith('main')
+                      and re.match(r'(f32\[\d+,17,128,4096\]|'
+                                   r'bf16\[\d+,17,13056\])', i.shape)]
+            assert copies == [], which
+    finally:
+        eng.shutdown(drain=False)
