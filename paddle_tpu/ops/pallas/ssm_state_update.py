@@ -1,0 +1,201 @@
+"""The decode step's state update of one Mamba-2 layer as one Pallas TPU
+kernel over the live rows' slots (``ops/ssm_ops.py::ssm_decode_update``
+is the caller and says what the update is; its row loop is the form on
+every other platform and what this kernel is held to,
+``tests/test_granite_block.py``).
+
+The grid is ``(live rows, tiles)``, its first extent the live count the
+step arrives with: rows past it cost no grid step. A slot ``[N, H P]``
+float32 (2 MB at the published widths) goes through VMEM in row tiles of
+``TILE_BYTES``, each one contiguous in the arena. Both arenas are passed
+whole and aliased in and out; a tile is addressed by ``(layer, slot,
+tile)`` through the prefetched scalars and rides Pallas's own block
+pipeline, so the next tile (or the next row's first) is on its way in
+and the last one on its way out while this one is computed. What is a
+row of the batch (``keep``, ``xdt``, ``kept``, ``y``) stays in VMEM for
+the whole call as the array the program has, rows of 8 to a tile: a
+block of one row would ask the program for another layout, and a copy
+of each array a layer to get there (five copies a layer, some 30 us
+beside a kernel of 220: my chip run, PR 46).
+
+The convolution's kept rows cannot leave by a DMA a row (a row of a
+2-byte arena is half of a packed word, and Mosaic slices a tiled
+dimension by whole tiles), so they go through the same pipeline by
+``GROUP`` slots: a group's rows come in, the row's own is replaced, the
+group goes out. Two rows whose slots share a group must then not be in
+flight at once, so the rows are walked **in the order of their slots**:
+such rows are neighbours, the pipeline keeps a block whose index does
+not change where it is, and the second row writes into the first one's
+result (``carried``).
+
+With no live row at all the one step hands its blocks back as they came.
+
+The arithmetic is the loop's, in float32 on the vector unit: ``S <- S
+keep + B[:, None] xdt``, ``y = sum_n S[n, :] C[n]`` summed tile by tile.
+A row's ``B`` and ``C`` lie along the lanes and the state's rows want
+them along the sublanes; they are turned by a masked sum along the lanes
+against the identity (one term is not zero, so it is exact). Handed over
+transposed they would be read as they lie, but the compiler then lays
+the whole convolution output they are sliced from batch-minor and
+copies four arrays a layer back (5% of the device's time: my chip run,
+PR 46). A row of ``kept`` is picked out of its ``PACK`` aligned rows
+the same way, along the sublanes.
+"""
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import interpret_mode
+
+# a tile of a slot in flight, two buffers each way: two a slot at the
+# published widths. From 512 KB to a whole slot the chip reads 75.2, 76.0
+# and 76.3% of the HBM peak at 41 rows (78% at 64, the same for all: the
+# DMAs bound it, not the steps; my chip run, PR 46)
+TILE_BYTES = 1024 * 1024
+# slots whose convolution rows share a tile of the arena: the least a
+# block of them can be
+GROUP = 8
+# rows of ``kept`` read together: a packed tile of a 2-byte type
+PACK = 16
+
+
+def _tile_rows(n_state, width):
+    """The state rows a tile holds: ``TILE_BYTES`` worth in whole
+    sublane groups of 8 where that divides the state, else the slot."""
+    rows = max(8, TILE_BYTES // (4 * width) // 8 * 8)
+    return rows if n_state % rows == 0 else n_state
+
+
+def _kernel(layer_ref, slots_ref, order_ref, n_ref, state_ref, keep_ref,
+            xdt_ref, b_ref, c_ref, kept_ref, held_ref, out_ref, y_ref,
+            conv_ref):
+    j, t = pl.program_id(0), pl.program_id(1)
+    n = n_ref[0]
+    rows = state_ref.shape[0]
+
+    @pl.when(n > 0)
+    def _():
+        i = order_ref[j]
+        row = pl.ds(i, 1)
+        # B and C of the tile's state rows as columns: the row of the
+        # batch laid along the sublanes by a masked sum along the lanes
+        n_state = b_ref.shape[1]
+        own = jax.lax.broadcasted_iota(jnp.int32, (rows, n_state), 0) \
+            + t * rows == jax.lax.broadcasted_iota(
+                jnp.int32, (rows, n_state), 1)
+        b = jnp.sum(jnp.where(own, b_ref[row, :], 0.0), axis=1,
+                    keepdims=True)
+        c = jnp.sum(jnp.where(own, c_ref[row, :], 0.0), axis=1,
+                    keepdims=True)
+        s = state_ref[...] * keep_ref[row, :] + b * xdt_ref[row, :]
+        out_ref[...] = s
+        part = jnp.sum(s * c, axis=0, keepdims=True)
+
+        @pl.when(t == 0)
+        def _():
+            y_ref[row, :] = part
+
+        @pl.when(t > 0)
+        def _():
+            y_ref[row, :] += part
+
+        @pl.when(t == 0)
+        def _():
+            # the convolution's kept rows: row ``i`` of ``kept``, picked
+            # out of its aligned rows, into its slot's row of the group
+            near = kept_ref[pl.ds(pl.multiple_of(i // PACK * PACK, PACK),
+                                  PACK), :].astype(jnp.float32)
+            kept = jnp.sum(jnp.where(jax.lax.broadcasted_iota(
+                jnp.int32, near.shape, 0) == i % PACK, near, 0.0), axis=0,
+                keepdims=True)
+            slot = slots_ref[i]
+            its = jax.lax.broadcasted_iota(
+                jnp.int32, conv_ref.shape, 0) == slot % GROUP
+            before = slots_ref[order_ref[jnp.maximum(j, 1) - 1]] // GROUP
+            carried = jnp.logical_and(j > 0, before == slot // GROUP)
+
+            def put(group):
+                conv_ref[...] = jnp.where(
+                    its, kept, group.astype(jnp.float32)
+                ).astype(conv_ref.dtype)
+
+            @pl.when(carried)
+            def _():
+                put(conv_ref[...])
+
+            @pl.when(jnp.logical_not(carried))
+            def _():
+                put(held_ref[...])
+
+    @pl.when(n == 0)
+    def _():
+        out_ref[...] = state_ref[...]
+        conv_ref[...] = held_ref[...]
+
+
+def state_update(state, conv, layer, slots, n, keep, xdt, b, c, kept):
+    """Rows ``0 .. n - 1`` of the batch, each in ``state[layer,
+    slots[i]]`` and ``conv[layer, slots[i]]``: ``keep`` and ``xdt`` [B,
+    H P] and ``b``, ``c`` [B, N] float32, ``kept`` [B, (K - 1) C] at
+    ``conv``'s dtype. Returns (y [B, H P] float32, zeros from row ``n``
+    on as the loop leaves them; state; conv)."""
+    batch, width = keep.shape
+    n_state = state.shape[2]
+    rows = _tile_rows(n_state, width)
+    tiles = n_state // rows
+    slots = slots.astype(jnp.int32)
+    n = jnp.reshape(n, (1,)).astype(jnp.int32)
+    # the rows in the order of their slots, those past ``n`` behind them
+    order = jnp.argsort(jnp.where(jnp.arange(batch) < n, slots,
+                                  state.shape[1])).astype(jnp.int32)
+    kept = jnp.pad(kept, ((0, -batch % PACK), (0, 0)))
+
+    def of_slot(j, t, layer_ref, slots_ref, order_ref, n_ref):
+        return (layer_ref[0], slots_ref[order_ref[j]], t, 0)
+
+    def of_group(j, t, layer_ref, slots_ref, order_ref, n_ref):
+        return (layer_ref[0], slots_ref[order_ref[j]] // GROUP, 0)
+
+    def whole(arr):
+        return pl.BlockSpec(arr.shape, lambda *_: (0,) * arr.ndim,
+                            memory_space=pltpu.VMEM)
+
+    tile = pl.BlockSpec((None, None, rows, width), of_slot,
+                        memory_space=pltpu.VMEM)
+    group = pl.BlockSpec((None, GROUP, conv.shape[2]), of_group,
+                         memory_space=pltpu.VMEM)
+    # what stays for the whole call (``y`` is another ``keep``) in the
+    # pipeline's two buffers, the four tiles in flight, and room for the
+    # groups and the compiler's own scratch
+    vmem = 2 * sum(a.size * a.dtype.itemsize
+                   for a in (keep, xdt, keep, b, c, kept)) \
+        + 4 * rows * width * 4 + (8 << 20)
+    state, y, conv = pl.pallas_call(
+        _kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            # a row a live row: with none, one step hands its blocks back
+            grid=(jnp.maximum(n[0], 1), tiles),
+            in_specs=[tile, whole(keep), whole(xdt), whole(b), whole(c),
+                      whole(kept), group],
+            out_specs=[tile, whole(keep), group]),
+        # the arenas stay in HBM: left to choose, the compiler moves the
+        # convolution's whole arena (61 MB) into VMEM before a period's
+        # first kernel and back behind its last, every step (my chip run,
+        # PR 46)
+        out_shape=[pltpu.HBM(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct(keep.shape, jnp.float32),
+                   pltpu.HBM(conv.shape, conv.dtype)],
+        # operands count the prefetched scalars: 4 is the state, 10 conv
+        input_output_aliases={4: 0, 10: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary', 'arbitrary'),
+            vmem_limit_bytes=vmem),
+        name='ssm_state_update',
+        interpret=interpret_mode(),
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), slots, order, n, state,
+      keep, xdt, b, c, kept, conv)
+    # the kernel writes no row of ``y`` from ``n`` on
+    return jnp.where(jnp.arange(batch)[:, None] < n, y, 0.0), state, conv

@@ -20,12 +20,11 @@ inputs ``[Q, H P]``; ``C`` against the carried state ``[N, H P]``), the
 decode step's read-out is a sum down the rows, and a row is ``H P``
 lane-dense elements, so the compiler keeps the arena row-major.
 
-Both ops touch the arenas by ``dynamic_slice`` / ``dynamic_update_slice``
-at ``(layer, slot)`` alone, as ``paged_decode_ops._write_in_place``
-writes K and V: nothing of arena size is gathered, scattered or copied
-(``serving/decode/hlo_check.py`` counts such instructions in a compiled
-program). The slot past the pool (``slots``) is a spare: rows that hold
-no slot read and write it.
+The ops touch the arenas at ``(layer, slot)`` alone, as
+``paged_decode_ops._write_in_place`` writes K and V: nothing of arena
+size is gathered, scattered or copied (``serving/decode/hlo_check.py``
+counts such instructions in a compiled program). The slot past the pool
+(``slots``) is a spare: rows that hold no slot read and write it.
 
 ``ssm_chunk_scan`` (a prefill chunk of one sequence, rows padded to a
 bucket): inside a scan chunk of ``Q`` rows the masked product
@@ -36,21 +35,47 @@ x)``; scan chunks one after another, the state carried in float32.
 Products take their operands at ``mm_dtype`` (the weights') and
 accumulate in float32; the decays, their exponentials and the state are
 float32. A padded row has ``dt = 0``: it decays nothing and adds
-nothing.
+nothing. The one slot is sliced out (``_slot_of``) and written back with
+``dynamic_update_slice``.
 
-``ssm_decode_update`` (one token a row of a decode batch): a loop over
-the rows up to the last live one; row ``i`` slices its slot's state
-(``N x H P``: 2 MB at the published widths), takes one step of the
+``ssm_decode_update`` (one token a row of a decode batch): row ``i``'s
+slot (``N x H P``: 2 MB at the published widths) takes one step of the
 recurrence in float32 on the vector unit (a product through the matrix
-unit would round the state to the operands' precision), writes it back,
-and reads ``y`` out as ``sum_n S[n, :] C[n]``. The least a step can
-move is what it moves: each live row's state read once and written
-once. A gather of the rows, a batched update and a scatter back would
-move it twice more and re-lay the arena.
+unit would round the state to the operands' precision), ``S <- S keep +
+B[:, None] xdt``, and ``y`` is read out as ``sum_n S[n, :] C[n]``; the
+decays ``keep``, the weighted inputs ``xdt`` and the convolution's kept
+rows are computed for the batch before it, rows past the last live one
+move nothing. The least a step can move is what it moves: each live
+row's state read once and written once. A gather of the rows, a batched
+update and a scatter back would move it twice more and re-lay the arena.
+It has two forms, chosen by the platform the program is lowered for
+(``jax.lax.platform_dependent``) and by nothing else:
+
+- **on a TPU, one Pallas kernel a layer** that walks the live rows'
+  slots (``ops/pallas/ssm_state_update.py``): both arenas passed whole
+  and aliased in and out, a slot in row tiles through VMEM, the next
+  tile on its way in and the last on its way out while this one is
+  computed, the convolution's rows through the same pipeline. One form
+  for every batch size; a live count of 1 walks one row;
+- **everywhere else, a loop over the rows** up to the last live one
+  (``_update_row_by_row``): it slices a row's slot, advances it and
+  writes it back, one row after another (on the v5e 11.4 us a row and
+  layer against 5.2 at the HBM peak, since an iteration carries the
+  arena and the next row's read cannot start under this row's
+  arithmetic: PERF.md, PRs 45 and 46). It is kept as the reference the
+  kernel is held to (``tests/test_granite_block.py``: the same state bit
+  for bit) and because an interpreted kernel would slow every CPU test
+  that decodes. The two share ``keep``, ``xdt`` and ``kept`` and nothing
+  else.
+
+The barrier in ``_slot_of`` still guards the two places that slice a
+slot in XLA: the loop above and the prefill chunk.
 """
 
 import jax
 import jax.numpy as jnp
+
+from .pallas.ssm_state_update import state_update
 
 
 def _at(arena, layer, slot):
@@ -161,7 +186,19 @@ def ssm_decode_update(state, conv, layer, slots, live, x, b, c, dt, a,
     keep = jnp.repeat(jnp.exp(dt * a[None, :]), width, axis=1)   # [B, H P]
     xdt = (x * dt[:, :, None]).reshape(rows, -1)                 # [B, H P]
     kept = window[:, 1:].astype(conv.dtype).reshape(rows, -1)
+    upper = jnp.max(jnp.where(live, jnp.arange(1, rows + 1), 0))
+    with jax.named_scope('ssm_state_update'):
+        ys, state, conv = jax.lax.platform_dependent(
+            state, conv, layer, slots, upper, keep, xdt, b, c, kept,
+            tpu=state_update, default=_update_row_by_row)
+    return ys.reshape(rows, heads, width), state, conv
 
+
+def _update_row_by_row(state, conv, layer, slots, upper, keep, xdt, b, c,
+                       kept):
+    """The update as a loop over rows ``0 .. upper - 1``, one row's slot
+    sliced, advanced and written back after another: the form of every
+    platform but the TPU, and what the kernel is held to."""
     def one(i, carry):
         state, conv, ys = carry
         s = _slot_of(state, layer, slots[i])
@@ -175,9 +212,6 @@ def ssm_decode_update(state, conv, layer, slots, live, x, b, c, dt, a,
                          jax.lax.dynamic_index_in_dim(kept, i))
         return state, conv, jax.lax.dynamic_update_slice(ys, y, (i, 0))
 
-    upper = jnp.max(jnp.where(live, jnp.arange(1, rows + 1), 0))
-    with jax.named_scope('ssm_state_update'):
-        state, conv, ys = jax.lax.fori_loop(
-            0, upper, one,
-            (state, conv, jnp.zeros((rows, heads * width), jnp.float32)))
-    return ys.reshape(rows, heads, width), state, conv
+    state, conv, ys = jax.lax.fori_loop(
+        0, upper, one, (state, conv, jnp.zeros_like(keep)))
+    return ys, state, conv

@@ -21,7 +21,11 @@ instructions that materialise ``min_elements`` or more:
   nothing (parameters, tuples and their elements, bitcasts, loops), an
   in-place ``dynamic-update-slice`` (alone or as a fusion) and, unless
   ``gathers=True``, a gather (alone or as a fusion): it reads the
-  arena and writes a block.
+  arena and writes a block;
+- of a ``custom-call`` (a kernel), the results it does not alias to an
+  operand (``output_to_operand_aliasing``): a result that is its
+  operand's buffer is written where it lies, and the kernel moves what
+  its own blocks move, never the arena.
 
 Callers pass a layer's arena elements, NB * bs * H * D (what consumes
 a gather's result is not exempt: an iteration's pages are far under
@@ -34,9 +38,12 @@ fusion counts by what it calls.
 The arenas of a cache kind with a size a sequence (a recurrent layer's
 state, ``[layers, slots + 1, ...]``: model.CacheKind.per_seq) are held to
 the same rule by the same function, with a layer of the arena as
-``min_elements``: the decode step slices a live row's slot, advances it
-and writes it back with ``dynamic-update-slice`` at (layer, slot), a
-prefill chunk the one slot of its sequence, and nothing else of that
+``min_elements``: the decode step compiled for the TPU walks the live
+rows' slots in one kernel a layer that takes both arenas whole and
+aliases them to its results (ops/pallas/ssm_state_update.py; on every
+other platform a loop slices a live row's slot, advances it and writes
+it back with ``dynamic-update-slice`` at (layer, slot)), a prefill chunk
+slices and writes the one slot of its sequence, and nothing else of that
 size may appear (tests/test_v5e_compile.py compiles both for a described
 v5e at the published state geometry and also holds every ``copy`` of an
 arena's shape to the entry computation). What to look for there: a slot's
@@ -64,6 +71,8 @@ _ASSIGN = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$')
 _ARRAY = re.compile(r'\b[a-z]+[0-9]*[a-z0-9]*\[([0-9,]*)\]')
 _OPCODE = re.compile(r'^\s*([\w\-]+)\(')
 _CALLS = re.compile(r'\bcalls=%?([\w.\-]+)')
+_ALIASING = 'output_to_operand_aliasing='
+_ALIASED = re.compile(r'\{(\d*)\}:')
 
 
 def _balanced(s, start):
@@ -77,6 +86,33 @@ def _balanced(s, start):
             if depth == 0:
                 return i + 1
     return len(s)
+
+
+def _results(shape):
+    """The result shapes of a (possibly tuple) shape string, in order."""
+    if not shape.startswith('('):
+        return [shape]
+    out, depth, last = [], 0, 1
+    for i, ch in enumerate(shape):
+        depth += ch in '([{'
+        depth -= ch in ')]}'
+        if (ch == ',' and depth == 1) or depth == 0:
+            out.append(shape[last:i])
+            last = i + 1
+    return out
+
+
+def _unaliased(shape, rest):
+    """``shape`` without the results that ``rest``, an instruction's text
+    behind its shape, aliases to operands (``{{0}: (4, {}), {2}: (10,
+    {})}``: results 0 and 2)."""
+    at = rest.find(_ALIASING)
+    if at < 0:
+        return shape
+    aliased = {int(i or 0) for i in
+               _ALIASED.findall(rest[at:rest.find(')}', at)])}
+    return ', '.join(r for i, r in enumerate(_results(shape))
+                     if i not in aliased)
 
 
 def _elements(shape):
@@ -93,7 +129,7 @@ def _elements(shape):
 
 def _parse(text):
     """{computation: [(name, opcode, shape, callee)]} in program
-    order."""
+    order; a custom call's ``shape`` is what it does not alias."""
     comps = collections.OrderedDict()
     cur = None
     for line in text.splitlines():
@@ -116,7 +152,10 @@ def _parse(text):
         op = _OPCODE.match(rest)
         if not op:
             continue
-        callee = _CALLS.search(rest[_balanced(rest, rest.index('(')):])
+        behind = rest[_balanced(rest, rest.index('(')):]
+        callee = _CALLS.search(behind)
+        if op.group(1) == 'custom-call':
+            shape = _unaliased(shape, behind)
         cur.append((name, op.group(1), shape,
                     callee.group(1) if callee else None))
     return comps

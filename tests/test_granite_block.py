@@ -115,8 +115,7 @@ def _chunk(arenas, table, slot, tokens, start, length):
     return block.logits(h), arenas
 
 
-@jax.jit
-def _step(arenas, tables, slots, tokens, lens):
+def _step_rows(arenas, tables, slots, tokens, lens):
     """A decode step as ``paged_decode_step`` runs it."""
     block = sho.SsmHybridBlock(_Ctx(SPEC, WEIGHTS, {
         'BlockTablesState': slots}))
@@ -124,6 +123,9 @@ def _step(arenas, tables, slots, tokens, lens):
     h, arenas, _ = pdo._extend_rows(block, arenas, tokens, lens, tables,
                                     place, valid=place.ok[:, 0])
     return block.logits(h), arenas
+
+
+_step = jax.jit(_step_rows)
 
 
 def _reference_logits(tokens, **lowered):
@@ -327,32 +329,112 @@ def test_a_scan_seeded_from_its_slot_goes_on_where_the_last_one_ended():
     assert (np.asarray(state[0, 0]) == 7.0).all()
 
 
-def test_the_decode_update_steps_each_live_row_s_own_slot():
-    x, b, c, dt, a = _scan_inputs(4, seed=5)
-    rng = np.random.RandomState(1)
-    state = jnp.asarray(rng.randn(2, 5, 8, 64).astype('f'))
-    conv = jnp.asarray(rng.randn(2, 5, 240).astype('f'))
-    window = jnp.asarray(rng.randn(4, 4, 80).astype('f'))
-    slots = jnp.asarray([3, 0, 4, 4], jnp.int32)      # two live rows
-    live = jnp.asarray([True, True, False, False])
-    y, new, kept = jax.jit(ssm_ops.ssm_decode_update)(
-        state, conv, 1, slots, live, x, b, c, dt, a, window)
-    for i, slot in ((0, 3), (1, 0)):
-        s = np.asarray(state[1, slot]).T.reshape(4, 16, 8)
+@pytest.fixture
+def in_a_kernel(monkeypatch):
+    """The decode update's TPU form on this platform: the kernel of
+    ``ops/pallas/ssm_state_update.py``, interpreted, in the place of the
+    row loop that every platform but the TPU lowers (the choice is by
+    the platform a program is lowered for, so a test on the CPU steers
+    it here). A slot goes in two row tiles at the small widths."""
+    from paddle_tpu.ops.pallas import ssm_state_update as kernel
+    monkeypatch.setenv('PADDLE_TPU_PALLAS_INTERPRET', '1')
+    monkeypatch.setattr(kernel, 'TILE_BYTES', 8 * 64 * 4)
+    # a function of its own for both branches: jax keeps a branch's trace
+    # by the function, and an interpreted kernel must not be found again
+    # under the name a program for the chip is traced by
+    interpreted = lambda *args: kernel.state_update(*args)
+    monkeypatch.setattr(ssm_ops, 'state_update', interpreted)
+    monkeypatch.setattr(ssm_ops, '_update_row_by_row', interpreted)
+
+
+SMALL = dict(heads=4, width=16, n_state=16, cols=80, slots=9)
+# 64 heads of 64 over a state of 128 and a convolution over 4,352
+# columns: one layer at the published widths, a slot in four tiles
+PUBLISHED = dict(heads=64, width=64, n_state=128, cols=4352, slots=9)
+UPDATES = {
+    # live rows, the slot a row (the spare is ``slots``), the widths
+    'one live row': ([1, 0, 0, 0, 0, 0], [3, 0, 8, 4, 1, 5], SMALL),
+    'a live prefix and rows past it': (
+        [1, 1, 1, 0, 0, 0], [3, 8, 0, 4, 1, 5], SMALL),
+    'every row live': ([1] * 6, [3, 0, 8, 4, 1, 5], SMALL),
+    'a row that is not valid inside the prefix': (
+        [1, 0, 1, 1, 0, 0], [3, 9, 8, 4, 9, 9], SMALL),
+    'no live row': ([0] * 6, [3, 0, 8, 4, 1, 5], SMALL),
+    'one layer at the published widths': (
+        [1, 1, 1, 0], [8, 2, 5, 9], PUBLISHED),
+}
+
+
+def _update_inputs(live, heads, width, n_state, cols, slots):
+    rows = len(live)
+    rng = np.random.RandomState(5)
+    x = rng.randn(rows, heads, width).astype('f')
+    b = rng.randn(rows, n_state).astype('f')
+    c = rng.randn(rows, n_state).astype('f')
+    # a row that is not live takes a step of dt = 0
+    dt = np.log1p(np.exp(rng.randn(rows, heads))).astype('f') * \
+        np.asarray(live, 'f')[:, None]
+    a = -np.exp(rng.randn(heads) * 0.5).astype('f')
+    state = rng.randn(2, slots + 1, n_state, heads * width).astype('f')
+    conv = rng.randn(2, slots + 1, 3 * cols).astype('f')
+    window = rng.randn(rows, 4, cols).astype('f')
+    return state, conv, x, b, c, dt, a, window
+
+
+def _updated(live, slots, sizes):
+    state, conv, x, b, c, dt, a, window = _update_inputs(live, **sizes)
+    # a jit of its own: the form is chosen as the program is traced
+    y, new, kept = jax.jit(lambda *args: ssm_ops.ssm_decode_update(*args))(
+        jnp.asarray(state), jnp.asarray(conv), 1,
+        jnp.asarray(slots, jnp.int32), jnp.asarray(live, bool), x, b, c, dt,
+        a, jnp.asarray(window))
+    return (np.asarray(y), np.asarray(new), np.asarray(kept)), \
+        (state, conv, x, b, c, dt, a, window)
+
+
+@pytest.mark.parametrize('case', sorted(UPDATES))
+@pytest.mark.parametrize('form', ['the row loop', 'the kernel'])
+def test_the_decode_update_steps_each_live_row_s_own_slot(form, case,
+                                                          request):
+    """Both forms of the decode update against the recurrence in
+    float32, and the kernel against the row loop: the state, the kept
+    rows and ``y`` of every row up to the last live one; every other
+    slot, the spare and the other layer bit for bit as they were."""
+    live, slots, sizes = UPDATES[case]
+    by_loop, (state, conv, x, b, c, dt, a, window) = _updated(
+        live, slots, sizes)
+    y, new, kept = by_loop
+    if form == 'the kernel':
+        request.getfixturevalue('in_a_kernel')
+        (y, new, kept), _ = _updated(live, slots, sizes)
+    heads, width, n_state = sizes['heads'], sizes['width'], sizes['n_state']
+    upper = max([i + 1 for i, on in enumerate(live) if on] or [0])
+    spare = sizes['slots']
+    for i in range(upper):
+        if slots[i] == spare:
+            continue            # whatever the rows without a slot left
+        s = state[1, slots[i]].T.reshape(heads, width, n_state)
         s = np.exp(dt[i] * a)[:, None, None] * s + \
             (dt[i][:, None] * x[i])[:, :, None] * b[i][None, None, :]
-        np.testing.assert_allclose(np.asarray(new[1, slot]),
-                                   s.reshape(64, 8).T, atol=1e-5)
-        np.testing.assert_allclose(np.asarray(y[i]),
-                                   (s * c[i][None, None, :]).sum(-1),
-                                   atol=1e-5)
-        np.testing.assert_array_equal(np.asarray(kept[1, slot]),
-                                      np.asarray(window[i, 1:]).reshape(-1))
-    # nothing else moved: the other layer, the other slots, the spare
-    untouched = np.asarray(new) == np.asarray(state)
-    assert untouched[0].all() and untouched[1, [1, 2, 4]].all()
-    assert (np.asarray(kept[1, [1, 2, 4]])
-            == np.asarray(conv[1, [1, 2, 4]])).all()
+        np.testing.assert_allclose(
+            new[1, slots[i]], s.reshape(heads * width, n_state).T, atol=1e-5)
+        np.testing.assert_allclose(y[i], (s * c[i][None, None, :]).sum(-1),
+                                   atol=1e-5 * n_state ** 0.5)
+        np.testing.assert_array_equal(kept[1, slots[i]],
+                                      window[i, 1:].reshape(-1))
+    assert not y[upper:].any()
+    # nothing else moved: the other layer, the slots of no row up to the
+    # last live one, and the spare unless such a row points at it
+    still = [j for j in range(spare + 1) if j not in slots[:upper]]
+    np.testing.assert_array_equal(new[0], state[0])
+    np.testing.assert_array_equal(new[1, still], state[1, still])
+    np.testing.assert_array_equal(kept[0], conv[0])
+    np.testing.assert_array_equal(kept[1, still], conv[1, still])
+    # the kernel's arithmetic is the loop's: the state's expression has
+    # one order, the read-out's sum is free to take another
+    np.testing.assert_array_equal(new, by_loop[1])
+    np.testing.assert_array_equal(kept, by_loop[2])
+    np.testing.assert_allclose(y, by_loop[0], atol=1e-5 * n_state ** 0.5)
 
 
 # ------------------------------------------- the block against the reference
@@ -374,11 +456,18 @@ def test_prefill_in_chunks_matches_the_full_forward(pieces):
     np.testing.assert_allclose(got, _reference_logits(tokens), atol=TOL)
 
 
-def test_prefill_then_decode_through_the_cache_matches_the_full_forward():
+@pytest.mark.parametrize('form', ['the row loop', 'the kernel'])
+def test_prefill_then_decode_through_the_cache_matches_the_full_forward(
+        form, request):
     """Three sequences of unlike depth, each prefilled in chunks into
     its own slot and pages, then decoded together; between the steps the
     rows change places (the engine compacts its batch every step: a row
-    index is no home for state, the slot is)."""
+    index is no home for state, the slot is). With the state update in
+    either form."""
+    _step = globals()['_step']
+    if form == 'the kernel':
+        request.getfixturevalue('in_a_kernel')
+        _step = jax.jit(_step_rows)     # traced again, with the kernel
     seqs = [_tokens(n, 10 + n) for n in (29, 42, 22)]
     prompts = (17, 30, 9)
     slots, firsts = (2, 0, 3), (0, 12, 30)

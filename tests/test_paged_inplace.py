@@ -336,6 +336,22 @@ ENTRY %main (a: f32[2,8,2,4,8]) -> f32[2,8,2,4,8] {
 '''
 
 
+# a kernel that takes the arena whole: its first result is its fourth
+# operand's buffer, its second a buffer of its own
+_KERNEL = '''HloModule jit_decode_step, is_scheduled=true
+
+ENTRY %main (a: f32[2,8,4,16], n: s32[1]) -> f32[2,8,4,16] {
+  %a = f32[2,8,4,16]{3,2,1,0:T(8,128)} parameter(0)
+  %n = s32[1]{0} parameter(1)
+  %rows = f32[3,1,64]{2,1,0:T(1,128)} broadcast(%n), dimensions={}
+  %update.1 = (f32[2,8,4,16]{3,2,1,0:T(8,128)}, f32[3,1,64]{2,1,0:T(1,128)S(1)}) custom-call(%n, %n, %rows, %a), custom_call_target="tpu_custom_call", operand_layout_constraints={s32[1]{0}, s32[1]{0}, f32[3,1,64]{2,1,0}, f32[2,8,4,16]{3,2,1,0}}, output_to_operand_aliasing={{0}: (3, {})}, backend_config={"custom_call_config": {"body": "TUzvUg", "needs_layout_passes": true}}
+  %relaid.1 = (f32[2,8,4,16]{3,2,1,0:T(8,128)}, f32[8,64]{1,0}) custom-call(%n, %a), custom_call_target="tpu_custom_call", output_to_operand_aliasing={{1}: (0, {})}
+  %whole.1 = f32[2,8,4,16]{3,2,1,0:T(8,128)} custom-call(%update.1), custom_call_target="tpu_custom_call", output_to_operand_aliasing={{}: (0, {})}
+  ROOT %out = f32[2,8,4,16]{3,2,1,0:T(8,128)} get-tuple-element(%update.1), index=0
+}
+'''
+
+
 @pytest.mark.parametrize('text,layer,gathers,want', [
     (_CLEAN, 8 * 4 * 16, False, []),
     (_COPIES, 8 * 4 * 16, False,
@@ -346,7 +362,12 @@ ENTRY %main (a: f32[2,8,2,4,8]) -> f32[2,8,2,4,8] {
     # neither is the gather; the in-place update and the loop still are
     (_CLEAN, 6 * 4 * 16, False, ['reshape.9']),
     (_CLEAN, 6 * 4 * 16, True, ['fusion.3', 'reshape.9']),
-], ids=['in_place', 'parent_shape', 'gather_consumer', 'whole_table'])
+    # a kernel's result that is its operand's buffer moves nothing; one
+    # of that size that is a buffer of its own does
+    (_KERNEL, 8 * 4 * 16, False, ['relaid.1']),
+    (_KERNEL, 3 * 64, False, ['rows', 'update.1', 'relaid.1']),
+], ids=['in_place', 'parent_shape', 'gather_consumer', 'whole_table',
+        'aliased_kernel', 'kernel_own_result'])
 def test_hlo_reader_counts_arena_sized_instructions(text, layer, gathers,
                                                     want):
     # ``layer``: one layer's arena elements in both modules, or the

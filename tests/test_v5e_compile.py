@@ -443,8 +443,12 @@ def test_the_state_arenas_are_written_where_they_lie(one_chip):
     rows and re-laid the arena around every program), and outside the
     entry computation, which here copies every undonated argument, no
     instruction materialises a layer of the state arena and no ``copy``
-    has an arena's shape: a row's state is sliced, advanced and written
-    back where it lies (ops/ssm_ops.py)."""
+    has an arena's shape. The decode step holds one kernel a Mamba layer
+    (ops/pallas/ssm_state_update.py) with both arenas among its operands,
+    aliased to its results and kept in HBM, and no loop over the rows of
+    a Mamba layer (the two loops left are the attention layer's); the chunk
+    slices its one slot, advances it and writes it back where it lies
+    (ops/ssm_ops.py)."""
     from jax.extend.core import jaxpr_as_fun
     from paddle_tpu.serving.decode import DecodeEngine, LMSpec
     from paddle_tpu.serving.decode.hlo_check import arena_sized_instructions
@@ -461,9 +465,10 @@ def test_the_state_arenas_are_written_where_they_lie(one_chip):
                        kv_dtype='bfloat16')
     try:
         state, conv = 'f32[9,17,128,4096]', 'bf16[9,17,13056]'
+        hlos = {}
         for which in ('decode', 512):
             closed = eng.trace_program(which).jaxpr
-            hlo = jax.jit(jaxpr_as_fun(closed)).lower(
+            hlo = hlos[which] = jax.jit(jaxpr_as_fun(closed)).lower(
                 *[_shaped(one_chip, a.shape, a.dtype)
                   for a in closed.in_avals]).compile().as_text()
             assert set(re.findall(re.escape(state) + r'\{([\d,]+)', hlo)) \
@@ -481,3 +486,33 @@ def test_the_state_arenas_are_written_where_they_lie(one_chip):
             assert copies == [], which
     finally:
         eng.shutdown(drain=False)
+    kernels = [line for line in _outside_fusions(hlos['decode'])
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 9 and 'tpu_custom_call' not in hlos[512]
+    typed = re.compile(r'\w+\[[\d,]*\]')
+    for line in kernels:
+        # %ssm_state_update.N = (results) custom-call(operands), ...,
+        # operand_layout_constraints={types}, output_to_operand_aliasing=
+        # {{0}: (5, {}), {2}: (10, {})}: each arena its operand's buffer
+        head = re.match(r'\s+%ssm_state_update[.\d]* = \((.*?)\) '
+                        r'custom-call\(', line)
+        assert head, line[:120]
+        results = typed.findall(head.group(1))
+        operands = typed.findall(line.split(
+            'operand_layout_constraints={', 1)[1].split('}, output_to')[0])
+        alias = {int(out): int(op) for out, op in re.findall(
+            r'\{(\d+)\}: \((\d+), \{\}\)', line)}
+        assert sorted(alias) == [0, 2], line[:200]
+        assert results[0] == operands[alias[0]] == state
+        assert results[2] == operands[alias[2]] == conv
+        # and stays in HBM: left to choose, the compiler moved the
+        # convolution's arena into VMEM before a period's first kernel
+        # and back behind its last, whole and every step (PERF.md, PR 46)
+        assert not re.search(r'(%s|%s)\{[^}]*S\(1\)' % (
+            re.escape(state), re.escape(conv)), head.group(1)), line[:200]
+    # the row loop is gone from the Mamba layers: what loops is the
+    # attention layer's pair loop and the loop over its pairs' pages
+    loops = [line for line in _outside_fusions(hlos['decode'])
+             if re.match(r'\s+(ROOT )?%[\w.\-]+ = .* while\(', line)]
+    assert len(loops) == 2, [line[:120] for line in loops]
+    assert not any(state in line or conv in line for line in loops)
