@@ -83,10 +83,10 @@ def _error_clip_grad(x, lo, hi):
 
 def _default_prng():
     """Dropout-mask PRNG implementation. On TPU the hardware
-    RngBitGenerator ('rbg') is the default: measured +62% transformer
-    tok/s over threefry (174.8k vs 108.2k, bench r3 rehearsal) — the
-    counter-based threefry mask generation was the single largest
-    non-matmul cost of the step. rbg is deterministic for a fixed
+    RngBitGenerator ('rbg') is the default: counter-based threefry
+    mask generation is arithmetic the step pays for every mask (one
+    against the other on the chip: not measured; PERF.md section 5
+    has dropout's share of the train cell's step). rbg is deterministic for a fixed
     (seed, step) on a given backend/version; threefry remains the
     default off-TPU and the cross-backend-reproducible choice
     (PADDLE_TPU_PRNG=threefry2x32|rbg overrides)."""
@@ -985,7 +985,7 @@ class Executor(object):
         """AOT path: compile a (program, feed-spec) pair and return
         ``(step_fn, scope_vals, feed_vals)`` where ``step_fn(scope_vals,
         feed_vals, step_i)`` is a pure jittable function returning
-        ``(fetches, new_scope)``. Used by bench/__graft_entry__ and the
+        ``(fetches, new_scope)``. Used by __graft_entry__ and the
         inference predictor; ``Executor.run`` callers never need this."""
         program, scope, fetch_names, feed_vals, qpolicy, bpolicy = \
             self._resolve_call(program, feed, fetch_list, scope)

@@ -146,8 +146,8 @@ def kill_replica(engine, drain=False):
     shape — queued-but-unbatched requests fail with the typed
     EngineClosedError, which the router's failover resubmits
     elsewhere; batches already handed to dispatch still complete).
-    The flight event makes the kill findable in postmortems and the
-    chaos bench's assertion windows. Returns the engine."""
+    The flight event makes the kill findable in postmortems and in a
+    chaos scenario's record. Returns the engine."""
     name = getattr(engine, 'name', None) or type(engine).__name__
     try:
         from .. import observe as _obs

@@ -14,7 +14,7 @@ enforced by :func:`assert_memory_contract`.
 
 See docs/linalg.md for the panel schedule diagrams, the memory
 contract, the autotuner key family, and the quantized-reduction
-ablation (``bench.py --workload linalg``).
+ablation (``tests/test_linalg.py``).
 """
 
 from .api import (MemoryContractError, assert_memory_contract,  # noqa: F401

@@ -53,8 +53,8 @@ def assert_memory_contract(op, mesh, dims, dtype='float32', panel=None,
                            block=None, factor=1.5):
     """Check the analytic per-shard peak against `factor` x the evenly
     divided operand+result footprint; raises MemoryContractError on
-    violation, returns the model dict otherwise. bench.py asserts this
-    for the largest SUMMA shape; builders call it with a loose factor
+    violation, returns the model dict otherwise. tests/test_linalg.py
+    asserts this for a large SUMMA shape; builders call it with a loose factor
     as a construction-time guard."""
     model = kernels.per_shard_peak_bytes(op, mesh, dims, dtype=dtype,
                                          panel=panel, block=block)

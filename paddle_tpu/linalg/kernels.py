@@ -35,7 +35,7 @@ dp x tp mesh:
 Per-shard peak memory stays O(N^2/P) everywhere: the only cross-shard
 temporaries are panels (O(N b / P_axis)) and the QR/Cholesky gathered
 panel (O(N b)). :func:`paddle_tpu.linalg.per_shard_peak_bytes` is the
-analytic model bench.py asserts against.
+analytic model tests/test_linalg.py asserts against.
 
 Panel/block sizes: explicit argument > ``PADDLE_TPU_SUMMA_PANEL`` /
 ``PADDLE_TPU_LINALG_BLOCK`` env knobs (read per call) > the PR 8
@@ -146,7 +146,7 @@ def _itemsize(dtype):
 def per_shard_peak_bytes(op, mesh, dims, dtype='float32', panel=None,
                          block=None):
     """Analytic per-shard peak resident bytes for one linalg op — the
-    memory contract ``bench.py --workload linalg`` asserts. Returns
+    memory contract ``tests/test_linalg.py`` asserts. Returns
     ``{'peak', 'ideal', 'factor', 'participants'}`` where `ideal` is
     the operand+result footprint divided evenly over the participating
     shards (the O(N^2/P) floor) and `factor` = peak/ideal. The model

@@ -5,7 +5,7 @@ image_classification book chapter).
 TPU notes: data_format='NHWC' keeps every activation channels-last IN THE
 IR — zero layout transposes between ops (one transpose of the NCHW input
 feed at the stem); filters stay OIHW so checkpoints are layout-free.
-bf16 casting is applied by the bench/entry harness via Program.amp, not
+bf16 casting is applied by the caller via Program.amp, not
 baked into the model.
 """
 

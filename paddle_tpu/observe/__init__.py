@@ -44,8 +44,8 @@ nothing else. Turn it on with::
     observe.disable()          # final snapshot + trace export
 
 or ``PADDLE_TPU_METRICS_JSONL=... PADDLE_TPU_TRACE_JSON=...`` with
-``observe.enable_from_env()`` (bench.py does
-exactly this). See docs/observability.md for the metric catalog.
+``observe.enable_from_env()``. See docs/observability.md for the
+metric catalog.
 """
 
 import atexit

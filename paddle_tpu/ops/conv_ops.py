@@ -3,12 +3,11 @@
 Reference: paddle/fluid/operators/{conv_op,conv_transpose_op,pool_op}.cc.
 IR semantics stay NCHW for reference-parity; the layout knob only
 changes the lax.conv dimension numbers inside the lowering (boundary
-transposes cancel in XLA). On TPU the default is NHWC: with the
-bf16-elementwise BN it measured +8% ResNet-50 img/s (2,436 vs ~2,257,
-r3 rehearsal) — channels-last matches the (8,128) vector tiling.
+transposes cancel in XLA). On TPU the default is NHWC: channels-last
+matches the (8,128) vector tiling (one layout against the other on the
+chip: not measured, no cell trains a convolutional model; ROADMAP S8).
 PADDLE_TPU_CONV_LAYOUT=NCHW|NHWC overrides; numerics are identical
-either way (tests/test_amp.py::test_nhwc_conv_layout_matches_nchw) and
-the bench records both, the faster one winning the headline.
+either way (tests/test_amp.py::test_nhwc_conv_layout_matches_nchw).
 """
 
 import os

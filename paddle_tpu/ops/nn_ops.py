@@ -16,8 +16,8 @@ from ..core.registry import register
 
 def _fused_ce_enabled():
     # Read at TRACE time: the leg is frozen into the compiled graph, so
-    # flipping it needs a fresh process (bench A/Bs run workload
-    # children) or a program-version bump — same contract as the other
+    # flipping it needs a fresh process (an A/B runs one a leg) or a
+    # program-version bump — same contract as the other
     # env knobs (PADDLE_TPU_BN_COMPUTE, PADDLE_TPU_CONV_LAYOUT).
     return os.environ.get('PADDLE_TPU_FUSED_CE', '1') != '0'
 

@@ -95,7 +95,7 @@ def quantized_all_reduce(x, axis_name='dp', op='sum', block=256,
     Wire bytes per device ≈ 2·(n-1)/n·nelem·(1 + 4/block) vs the fp32
     ring's 2·(n-1)/n·nelem·4 — ~3.94x less at block=256 (the analytic
     model in quant.core.quantized_allreduce_wire_bytes, asserted by
-    bench.py --workload quant). The result is identical on every
+    tests/test_quant.py). The result is identical on every
     device (rounding keys fold the sender's axis index, and the final
     gather is of already-rounded shards).
 

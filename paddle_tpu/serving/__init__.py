@@ -22,11 +22,11 @@ in `serving.handoff`, with per-phase autoscaling policies
 token-bucket quotas charged at admission (`QuotaExceededError`),
 priority-aware decode preemption/eviction, and a co-location policy
 (`colocation_yield`) that pauses a background fine-tuning Trainer
-under SLO pressure. See docs/serving.md; load-test with
-tools/serving_bench.py, chaos-test the fleet with `bench.py
---workload fleet`, the autoscaler with `--workload autoscale`, the
-disaggregated fleet with `--workload disagg`, and the multi-tenant
-policies with `--workload multitenant`.
+under SLO pressure. See docs/serving.md; the chaos scenarios of the
+fleet, the autoscaler, the disaggregated fleet and the multi-tenant
+policies are tests/chaos.py, asserted in counts by tests/test_fleet.py,
+test_autoscale.py, test_handoff.py and test_tenancy.py. A speed is
+stated by benchmark/run.py alone.
 """
 
 from .buckets import BatchInfo, BucketLadder, pow2_ladder  # noqa: F401

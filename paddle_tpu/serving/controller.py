@@ -18,7 +18,8 @@ replica list into a self-healing, autoscaling fleet:
   ``trough_s``), pick the least-loaded replica, deregister it from the
   router (no new work from that instant), ``drain()`` every accepted
   request to completion, THEN ``shutdown()`` — zero request loss by
-  construction, asserted by the chaos bench.
+  construction, asserted by the trough chaos scenario
+  (tests/test_autoscale.py).
 - **self-heal** — a replica whose ``ready()`` flips or that dies
   mid-flight is detected on the next tick, deregistered, and replaced
   automatically. Restarts back off exponentially per lineage

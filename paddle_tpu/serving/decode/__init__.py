@@ -6,7 +6,8 @@ decode batch as others finish, KV pages come from a shared HBM pool
 (`KVPool`) addressed through per-sequence block tables, and the
 attention (ops/pallas/paged_attention.py) reads exactly the
 pages each sequence owns at its true length. See docs/serving.md
-(decode engine section); load-test with tools/decode_bench.py.
+(decode engine section); its speed is the benchmark's serving cells'
+(benchmark/run.py, PERF_LEDGER.jsonl).
 """
 
 from .engine import DecodeEngine  # noqa: F401

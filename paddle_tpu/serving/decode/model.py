@@ -807,8 +807,8 @@ def kv_page_bytes(spec, block_size, kv_dtype='float32'):
 
 def num_blocks_for_budget(budget_bytes, spec, block_size,
                           kv_dtype='float32'):
-    """Pages an arena byte budget buys at ``kv_dtype`` — how bench.py
-    sizes the equal-bytes capacity ablation."""
+    """Pages an arena byte budget buys at ``kv_dtype``: the
+    equal-bytes capacity count (tests/test_quant.py)."""
     return max(1, int(budget_bytes)
                // kv_page_bytes(spec, block_size, kv_dtype))
 

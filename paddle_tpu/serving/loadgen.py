@@ -1,9 +1,9 @@
-"""Shared load-generator driver for the serving benches.
+"""Shared load-generator driver for the fleet chaos scenarios.
 
-tools/serving_bench.py (micro-batch engine), tools/decode_bench.py
-(decode engine), and the fleet chaos scenario (``bench.py --workload
-fleet``) drive different request shapes through the same two loop
-disciplines, so the loop logic lives here once:
+The scenarios of tests/chaos.py (micro-batch engines behind a router,
+decode engines behind a phase router, tenants) drive different request
+shapes through the same two loop disciplines, so the loop logic lives
+here once:
 
 - **closed loop** — ``clients`` threads each keep exactly one request
   in flight (latency under a fixed concurrency).
@@ -14,7 +14,7 @@ disciplines, so the loop logic lives here once:
   list of ``(t_s, qps)`` breakpoints (step-hold) — the scenario
   harness builds diurnal curves and flash crowds out of this.
 
-The bench adapts its engine through two callables:
+A scenario adapts its engine through two callables:
 
     do_request(rng) -> rows          # closed loop: submit AND wait
     submit_request(rng) -> (future, rows) | None   # open loop
@@ -154,7 +154,7 @@ def phase_mix(rng, long_prompt_frac=0.3, short_prompt=(4, 16),
               long_prompt=(48, 96), short_new=(4, 8),
               long_new=(24, 48)):
     """One ``(prompt_len, max_new_tokens)`` draw of the mixed
-    long-prompt/long-decode chaos mix the disaggregated-fleet bench
+    long-prompt/long-decode chaos mix the disaggregated-fleet scenario
     drives: a ``long_prompt_frac`` minority of requests are prefill-
     heavy (long prompt, few new tokens), the rest are decode-heavy
     (short prompt, many new tokens). On a colocated replica every
@@ -180,9 +180,7 @@ def tenant_mix(rng, tenants, sessions_per_tenant=4, rows=(4, 64),
     ``heavy_tailed_rows`` draw over the ``rows=(lo, hi)`` range (the
     micro-batch benches' request size); with ``phases=True`` returns
     ``(tenant, session, prompt_len, max_new_tokens)`` from a
-    ``phase_mix`` draw (the decode benches' shape). Reused by
-    ``bench.py --workload multitenant`` and tools/serving_bench.py
-    ``--tenant-mix``."""
+    ``phase_mix`` draw (the decode scenarios' shape)."""
     names = [t[0] for t in tenants]
     weights = np.asarray([float(t[1]) for t in tenants])
     weights = weights / weights.sum()

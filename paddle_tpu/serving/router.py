@@ -38,8 +38,8 @@ endpoint:
   gets a second dispatch to an *untried* replica; first completion
   wins, the loser is cancelled/ignored. When both complete, their
   results are compared — ``router.hedge_mismatch_total`` stays 0 for
-  a deterministic model, the bit-identity contract the chaos bench
-  asserts.
+  a deterministic model, the bit-identity contract the autoscale
+  chaos scenarios assert.
 - **retry budget** — hedges and failovers share one token bucket that
   refills at ``retry_budget`` tokens per accepted request (burst
   ``retry_budget_burst``), so retries are capped at a small fraction
@@ -787,8 +787,9 @@ class PhaseRouter(object):
 
     ``colocated=True`` degenerates to single-pool serving (each
     request prefills AND decodes on one decode-pool replica, no
-    handoff) — the A/B baseline ``bench.py --workload disagg``
-    compares against at equal chip count, and the right choice when
+    handoff) — the colocated leg ``tests/chaos.py::disagg_chaos`` runs
+    beside the split one at an equal count of engines, and the right
+    choice when
     prompts are short or the fleet is tiny (docs/serving.md).
 
     Per-phase membership is dynamic (``add_replica(r, phase=...)`` /

@@ -46,9 +46,9 @@ def record_allreduce_overlap(step_seconds, compute_seconds,
     the gradient-allreduce leg hidden behind backward compute, from
     three wall-clock measurements (the bucketed step, the compute-only
     step, and the collective-only leg; see observe.overlap_fraction).
-    Sits alongside ``trainer.pipeline_overlap_fraction``; the bench
-    `trainspeed` workload measures the legs and asserts it > 0 on the
-    dp mesh. Returns the fraction (or None on degenerate inputs)."""
+    Sits alongside ``trainer.pipeline_overlap_fraction``. Nothing in
+    the tree times the three legs: on the chip the fraction is not
+    measured. Returns the fraction (or None on degenerate inputs)."""
     frac = _obs.overlap_fraction(step_seconds, compute_seconds,
                                  comm_seconds)
     if frac is not None and _obs.enabled():

@@ -87,7 +87,7 @@ def device_kind():
 
 def reset():
     """Drop every cached decision and the in-memory table (tests, and
-    bench legs that re-point PADDLE_TPU_TUNING_TABLE mid-process)."""
+    callers that re-point PADDLE_TPU_TUNING_TABLE mid-process)."""
     _STATE['table'] = None
     _STATE['table_path'] = None
     _STATE['memo'] = {}

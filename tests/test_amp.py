@@ -1,5 +1,5 @@
-"""amp='bf16' end-to-end: the exact codepath the headline bench runs
-(bench.py:92,112). Whitelist ops (mul/conv/attention) compute in
+"""amp='bf16' end-to-end: the codepath the benchmark's train cell runs
+(benchmark/configs/tbig_nmt.json: "amp": "bf16"). Whitelist ops (mul/conv/attention) compute in
 bfloat16 on the MXU; blacklist ops (softmax/norms/losses) stay fp32;
 master weights stay fp32 in the scope (registry.py AMP policy)."""
 
@@ -164,7 +164,7 @@ def test_bn_bf16_tracks_fp32_compute(monkeypatch):
 
 
 def test_nhwc_conv_layout_matches_nchw(monkeypatch):
-    """PADDLE_TPU_CONV_LAYOUT=NHWC is numerics-identical (the bench
+    """PADDLE_TPU_CONV_LAYOUT=NHWC is numerics-identical (the
     ablation flag, SURVEY §5)."""
     l_nchw, _ = _train('bf16', steps=5)
     monkeypatch.setenv('PADDLE_TPU_CONV_LAYOUT', 'NHWC')

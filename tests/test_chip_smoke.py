@@ -1,10 +1,8 @@
-"""chip_smoke.py's and bench.py's contract off the chip: the rehearsal
-runs every leg at tiny size on the CPU, the real commands refuse a
-machine without a TPU, and TPUPlace never silently resolves to whatever
-device exists."""
+"""chip_smoke.py's contract off the chip: the rehearsal runs every leg
+at tiny size on the CPU, the real command refuses a machine without a
+TPU, and TPUPlace never silently resolves to whatever device exists."""
 
 import collections
-import glob
 import json
 import os
 import subprocess
@@ -70,57 +68,3 @@ def test_tpuplace_raises_when_no_tpu_and_cpu_not_asked_for(monkeypatch):
     # asked for by name (the suite's own setting), the CPU is TPUPlace(0)
     monkeypatch.undo()
     assert fluid.TPUPlace(0).jax_device().platform == 'cpu'
-
-
-# ------------------------------------------------------------ bench.py
-BENCH = os.path.join(REPO, 'bench.py')
-
-
-def _bench(env):
-    return subprocess.run([sys.executable, BENCH], env=env, cwd=REPO,
-                          capture_output=True, text=True, timeout=300)
-
-
-def test_bench_refuses_the_cpu_before_any_child_starts():
-    r = _bench(dict(os.environ, JAX_PLATFORMS='cpu'))
-    assert r.returncode != 0
-    assert 'needs a TPU' in r.stderr
-    assert r.stdout == ''           # no DEVICE stamp: no child ran
-
-
-@pytest.mark.skipif(bool(glob.glob('/dev/accel*') or
-                         glob.glob('/dev/vfio/[0-9]*')),
-                    reason='this machine has a TPU: bench.py would run')
-def test_bench_without_a_tpu_fails_before_any_step():
-    env = dict(os.environ)
-    env.pop('JAX_PLATFORMS', None)          # platform unset
-    r = _bench(env)
-    assert r.returncode != 0
-    assert 'jax found no TPU' in r.stderr   # the child's TPUPlace
-    assert 'RESULT' not in r.stdout and '"metric"' not in r.stdout
-
-
-def test_bench_functions_name_only_what_exists():
-    """Most of bench.py's workloads are too big for tier-1 and nothing
-    else reads their bodies: a name that resolves nowhere would only
-    surface after a full timed leg on the chip."""
-    import builtins
-    import dis
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO)
-    with open(BENCH) as f:
-        todo = [compile(f.read(), BENCH, 'exec')]
-    missing = set()
-    while todo:
-        code = todo.pop()
-        todo.extend(c for c in code.co_consts if hasattr(c, 'co_code'))
-        for ins in dis.get_instructions(code):
-            if ins.opname == 'LOAD_GLOBAL' and \
-                    not hasattr(bench, ins.argval) and \
-                    not hasattr(builtins, ins.argval):
-                missing.add('%s (in %s)' % (ins.argval, code.co_name))
-    assert not missing, sorted(missing)
-    assert all(callable(fn) for fn in bench.WORKLOADS.values())

@@ -5,10 +5,6 @@ bit-identical to sequential single-request decode, zero executor cache
 misses after warmup, KV pages fully reclaimed after drain, preemption
 (evict-and-requeue) preserving streams."""
 
-import json
-import os
-import subprocess
-import sys
 import threading
 
 import numpy as np
@@ -19,7 +15,6 @@ from paddle_tpu.serving import QueueFullError, EngineClosedError
 from paddle_tpu.serving.decode import (BlockTable, DecodeEngine, KVPool,
                                        LMSpec, random_weights)
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 SPEC = LMSpec(vocab_size=60, n_layer=2, n_head=2, d_key=8, d_value=8,
               d_model=16, d_inner=32)
@@ -298,50 +293,6 @@ def test_statusz_decode_panel():
         doc['finished_total'].get('eos', 0) >= 1
     eng.shutdown()
     assert doc['running_seqs'] is not None
-
-
-def test_decode_bench_json_schema(tmp_path):
-    """The --json schema decode_bench promises (and bench.py's
-    decode_transformer scenario builds on) cannot rot."""
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, 'tools', 'decode_bench.py'),
-         '--duration', '1.0', '--clients', '2', '--vocab', '60',
-         '--n-layer', '1', '--n-head', '2', '--d-model', '16',
-         '--d-inner', '32', '--block-size', '4', '--num-blocks', '32',
-         '--pages-per-seq', '6', '--prompt-lo', '1', '--prompt-hi', '12',
-         '--max-new', '8', '--prefix-cache', '--spec-k', '2',
-         '--shared-prefix', '0.9', '--shared-prefix-len', '9',
-         '--kv-dtype', 'int8', '--json'],
-        capture_output=True, text=True, timeout=300,
-        env=dict(os.environ, JAX_PLATFORMS='cpu'))
-    assert out.returncode == 0, out.stderr[-2000:]
-    doc = json.loads(out.stdout.strip().splitlines()[-1])
-    for key in ('tokens_per_s', 'inter_token_ms', 'request_ms',
-                'requests_ok', 'preemptions', 'warmup', 'executor',
-                'engine', 'kv_blocks_free_end', 'cache_hit_rate',
-                'prefill_tokens_skipped', 'accepted_draft_length',
-                'ttft_ms', 'spec_steps', 'resident_seqs_peak',
-                'kv_bytes_per_token'):
-        assert key in doc, key
-    assert doc['requests_ok'] > 0
-    assert doc['inter_token_ms']['p99'] is not None
-    assert doc['executor']['cache_misses'] <= \
-        doc['warmup']['signatures'] + 1   # +1: startup program compile
-    assert doc['kv_blocks_free_end'] == doc['engine']['num_blocks']
-    # the shared-prefix mix must actually exercise the new machinery
-    assert doc['cache_hit_rate'] > 0
-    assert doc['prefill_tokens_skipped'] > 0
-    assert doc['ttft_ms']['cached'] is not None
-    for k in ('p50', 'mean'):
-        assert k in doc['accepted_draft_length'], k
-    assert doc['engine']['prefix_cache'] is True
-    assert doc['engine']['spec_k'] == 2
-    # the int8 arena: 1 byte/elem + per-row fp32 scale pair, and the
-    # whole prefix-cache/spec path ran over it (asserts above)
-    assert doc['engine']['kv_dtype'] == 'int8'
-    spec_bytes = 1 * 2 * (8 + 8) + 1 * 2 * 2 * 4   # L*H*(dk+dv) + scales
-    assert doc['kv_bytes_per_token'] == spec_bytes
-    assert doc['resident_seqs_peak'] >= 1
 
 
 @pytest.mark.slow

@@ -469,12 +469,8 @@ def test_flight_postmortem_string_host_survives():
 
 # --------------------------------------------- real worker process tests
 def _chaos_model():
-    sys.path.insert(0, REPO)
-    try:
-        from bench import _save_chaos_model
-    finally:
-        sys.path.pop(0)
-    return _save_chaos_model(4)
+    from chaos import save_chaos_model
+    return save_chaos_model(4)
 
 
 def test_worker_cross_process_trace_and_clock(tmp_path):

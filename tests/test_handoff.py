@@ -5,7 +5,7 @@ fragmentation + alloc-stall observability, the PhaseRouter pipeline
 (prefill replica -> zero-copy handoff -> decode replica) bit-identical
 to single-replica decode with zero post-warmup executor cache misses,
 preempt-and-resume after a handoff, per-phase autoscaling policies,
-and the disagg chaos-bench acceptance."""
+and the disagg chaos acceptance (tests/chaos.py)."""
 
 import json
 import os
@@ -594,24 +594,19 @@ def test_metrics_report_fleet_phase_split(tmp_path):
 
 
 def test_bench_disagg_acceptance():
-    """ISSUE 14 headline: under the mixed long-prompt/long-decode
-    chaos schedule, the disaggregated fleet's inter-token p99 beats
-    the colocated fleet at equal chip count, TTFT stays in budget,
-    lost == 0, and the zero-recompile invariant holds on both fleets
-    — bench_disagg asserts all of it internally."""
+    """ISSUE 14 headline, in counts: under the mixed
+    long-prompt/long-decode chaos schedule (``chaos.disagg_chaos``)
+    neither the colocated nor the disaggregated fleet of equal size
+    loses a request or compiles anything after warm-up, and the
+    disaggregated one hands pages off and deduplicates shared ones.
+    Which of the two has the shorter inter-token tail is a speed: not
+    measured here."""
+    from chaos import disagg_chaos
     from paddle_tpu import observe
     observe.enable()
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-        out = bench.bench_disagg(duration=2.5, clients=6, vocab=2048,
-                                 n_layer=2, n_head=4, d_model=64,
-                                 d_inner=128, pages_per_seq=32,
-                                 num_blocks=256)
-    finally:
-        sys.path.remove(REPO)
-    assert out['workload'] == 'disagg'
-    assert out['inter_token_p99_improvement'] > 1.0
+    out = disagg_chaos()
+    assert out['colocated']['accepted'] > 0
+    assert out['disaggregated']['accepted'] > 0
     assert out['colocated']['lost'] == 0
     assert out['disaggregated']['lost'] == 0
     assert out['disaggregated']['post_warmup_cache_misses'] == 0

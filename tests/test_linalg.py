@@ -147,19 +147,52 @@ def test_power_iteration_quantized_reduction():
 
 
 # ------------------------------------------- executor cache + memory
-def test_zero_cache_misses_after_warmup():
+def _cache_misses():
+    return sum(v for k, v in observe.snapshot()['counters'].items()
+               if k.startswith('executor.cache_miss_total'))
+
+
+def _summa_runs(exe):
     rng = np.random.RandomState(6)
     a = rng.randn(32, 32).astype('float32')
     b = rng.randn(32, 32).astype('float32')
-    exe = fluid.Executor(fluid.CPUPlace())
     prog, out = linalg.build_matmul_program(
         32, 32, 32, mesh=make_mesh(dp=2, tp=2), panel=8)
-    exe.run(prog, feed={'summa_x': a, 'summa_y': b}, fetch_list=[out])
-    assert exe.last_cache_miss
-    for _ in range(3):
-        exe.run(prog, feed={'summa_x': a, 'summa_y': b},
-                fetch_list=[out])
-        assert not exe.last_cache_miss
+    for _ in range(4):
+        yield exe.run(prog, feed={'summa_x': a, 'summa_y': b},
+                      fetch_list=[out])
+
+
+def _power_iteration_runs(exe):
+    s = _gapped_symmetric(48)
+    for quantized in (False, True):
+        # a whole 10-step iteration a run. A call builds its program, so
+        # a call compiles once: the loop inside must hit the entry its
+        # first step made
+        yield linalg.power_iteration(s, iters=10, mesh=make_mesh(dp=4),
+                                     quantized=quantized, qblock=16,
+                                     executor=exe)
+
+
+@pytest.mark.parametrize('runs,missed', [
+    (_summa_runs, [True, False, False, False]),
+    (_power_iteration_runs, [False, False])],   # a call's last step hit
+    ids=['summa', 'power_iteration'])
+def test_zero_cache_misses_after_warmup(runs, missed):
+    """One compile a program (power iteration: one a call, the exact
+    and the quantized reduction), by the executor's flag after each run
+    and by its counter; every later dispatch hits."""
+    compiles = {_summa_runs: 1, _power_iteration_runs: 2}[runs]
+    observe.enable()
+    try:
+        exe = fluid.Executor(fluid.CPUPlace())
+        m0 = _cache_misses()
+        flags = [exe.last_cache_miss for _ in runs(exe)]
+        misses = _cache_misses() - m0
+    finally:
+        observe.disable()
+    assert flags == missed
+    assert misses == compiles
 
 
 def test_memory_contract_model():

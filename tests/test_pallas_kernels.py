@@ -1,7 +1,7 @@
 """Pallas kernel parity in interpret mode (CPU): flash attention
 forward AND the new FA2 backward kernels vs the XLA reference VJP, and
 the fused layer_norm kernel. On-chip parity of the compiled kernels is
-additionally checked every bench run (bench.pallas_parity)."""
+chip_smoke.py's kernels leg."""
 
 import numpy as np
 import pytest

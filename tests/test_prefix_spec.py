@@ -328,6 +328,45 @@ def test_both_features_bit_identical_with_sampling():
     assert eng.pool.free_blocks() == eng.pool.num_blocks
 
 
+def test_both_features_over_an_int8_arena():
+    """The three options at once (the combination only the deleted
+    decode load tool ran): prefix cache and speculation over int8
+    pages give the streams of plain sequential int8 decode, hit the
+    cache, verify drafts, compile nothing after warm-up, pay one byte
+    an element plus a row's two float32 scales, and free every page
+    once drained."""
+    from paddle_tpu import observe
+    reqs = _shared_prefix_requests(seed=0)
+    want = []
+    for r in reqs:
+        e = _engine(kv_dtype='int8')
+        e.start()
+        want.append(e.generate(timeout=120, **r))
+        e.shutdown()
+    observe.enable()
+    eng = _engine(kv_dtype='int8', prefix_cache=True, spec_k=2)
+    eng.warmup()
+    m0 = _misses(observe.snapshot())
+    eng.start()
+    streams = [eng.submit(**r) for r in reqs]
+    got = [s.result(timeout=120) for s in streams]
+    eng.shutdown()
+    counters = observe.snapshot()['counters']
+    assert got == want
+    assert _misses(observe.snapshot()) == m0
+    assert counters.get(
+        'decode.prefix_cache_lookups_total{outcome=hit}', 0) > 0
+    assert counters.get('decode.prefix_tokens_reused_total', 0) > 0
+    assert counters.get('decode.spec_steps_total', 0) > 0
+    assert eng.kv_dtype == 'int8'
+    # L * H * (dk + dv) elements a token + (k, v) scales a head, f32
+    assert eng.kv_bytes_per_token == \
+        SPEC.n_layer * SPEC.n_head * (SPEC.d_key + SPEC.d_value) + \
+        SPEC.n_layer * SPEC.n_head * 2 * 4
+    assert eng.resident_seqs_peak >= 1
+    assert eng.pool.free_blocks() == eng.pool.num_blocks
+
+
 def test_env_knobs_read_per_call(monkeypatch):
     """PADDLE_TPU_PREFIX_CACHE / PADDLE_TPU_SPEC_K are read at engine
     construction (per call), never frozen at import."""

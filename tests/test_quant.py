@@ -115,7 +115,7 @@ def test_broadcast_ppermute_formulation():
 
 
 def test_wire_bytes_model():
-    # the >=3x headline the bench asserts, straight from the model
+    # the >=3x fewer wire bytes, straight from the model
     fp32 = qcore.allreduce_wire_bytes(1 << 20, 8)
     q = qcore.quantized_allreduce_wire_bytes(1 << 20, 8, block=256)
     assert fp32 / q >= 3.0
@@ -170,6 +170,57 @@ def test_int8_allreduce_convergence_fit_a_line():
     assert loss_q[-1] < 0.05, loss_q[-5:]
     assert abs(loss_q[-1] - loss_f[-1]) < 0.05
     assert not np.array_equal(w_q, w_f)       # the wire format ran
+
+
+def test_executor_publishes_the_wire_model_of_the_traced_step():
+    """The executor counts the gradient elements of the step it traced
+    and publishes what the two-leg int8 schedule would put on the wire
+    beside the fp32 ring: at 64x256 + 256x64 weights on dp=4 the model
+    says 3x fewer bytes or better, and nothing is published for a
+    program that did not ask for quantization."""
+    from paddle_tpu import observe
+    from paddle_tpu.parallel.mesh import make_mesh
+    from paddle_tpu.parallel.transpiler import (ParallelStrategy,
+                                                transpile)
+
+    def gauges(quant_on):
+        fluid.reset_default_programs()
+        fluid.global_scope().clear()
+        observe.reset()
+        observe.enable()
+        x = fluid.layers.data(name='x', shape=[64], dtype='float32')
+        y = fluid.layers.data(name='y', shape=[1], dtype='float32')
+        h = fluid.layers.fc(input=x, size=256, act='relu')
+        h = fluid.layers.fc(input=h, size=64, act='relu')
+        pred = fluid.layers.fc(input=h, size=1, act=None)
+        cost = fluid.layers.mean(
+            fluid.layers.square_error_cost(pred, y))
+        fluid.optimizer.SGD(learning_rate=0.02).minimize(cost)
+        transpile(fluid.default_main_program(), make_mesh(dp=DP),
+                  ParallelStrategy(data_parallel=True,
+                                   quantized_allreduce=quant_on))
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(fluid.default_startup_program())
+        rng = np.random.RandomState(0)
+        exe.run(feed={'x': rng.randn(8 * DP, 64).astype('float32'),
+                      'y': rng.randn(8 * DP, 1).astype('float32')},
+                fetch_list=[cost])
+        try:
+            return observe.snapshot()['gauges']
+        finally:
+            observe.disable()
+            observe.reset()
+
+    g = gauges(True)
+    n_elems = 64 * 256 + 256 + 256 * 64 + 64 + 64 + 1
+    assert g['quant.allreduce_grad_elements'] == n_elems
+    assert g['quant.allreduce_bytes_fp32'] == \
+        qcore.allreduce_wire_bytes(n_elems, DP)
+    assert g['quant.allreduce_bytes_quant'] == \
+        qcore.quantized_allreduce_wire_bytes(n_elems, DP, 256)
+    assert g['quant.allreduce_compression'] >= 3.0
+    assert not [k for k in gauges(False)
+                if k.startswith('quant.allreduce')]
 
 
 def test_quant_allreduce_env_knob_per_call():
@@ -468,7 +519,7 @@ def test_kv_dtype_env_knob_per_call():
 
 def test_paged_attention_quantized_parity():
     """The dequantizing gather path vs fp32 on ragged mixed lengths —
-    the parity bound the bench asserts, in unit form."""
+    the parity bound of a quantized arena, in unit form."""
     from paddle_tpu.ops.pallas.paged_attention import (
         paged_attention_blocked, paged_attention_reference)
     rng = np.random.RandomState(7)

@@ -389,15 +389,11 @@ def test_worker_subprocess_end_to_end(tmp_path):
     tools/replica_worker.py, /readyz flips over plain HTTP, submit
     round-trips, shutdown reaps the PID, and the worker's metrics
     JSONL landed beside the parent's with the replica name as host."""
-    sys.path.insert(0, REPO)
-    try:
-        from bench import _save_chaos_model
-    finally:
-        sys.path.pop(0)
+    from chaos import save_chaos_model
     parent_jsonl = tmp_path / 'run.jsonl'
     observe.enable(jsonl=str(parent_jsonl))
     fac = ProcessReplicaFactory(
-        {'kind': 'serving', 'model_dir': _save_chaos_model(4),
+        {'kind': 'serving', 'model_dir': save_chaos_model(4),
          'backend': 'cpu',
          'engine': {'max_batch_size': 2, 'max_queue_depth': 4}},
         workdir=str(tmp_path), spawn_timeout_s=120.0,
@@ -421,6 +417,79 @@ def test_worker_subprocess_end_to_end(tmp_path):
     recs = [json.loads(ln) for ln in
             worker_jsonl.read_text().splitlines() if ln.strip()]
     assert any(r.get('host') == 'w0' for r in recs)
+
+
+def _http_readyz(url):
+    """GET /readyz over plain HTTP: the status, or None when the TCP
+    layer already says dead — the flip a real balancer sees."""
+    import http.client
+    host, _, port = url.split('://', 1)[-1].rpartition(':')
+    try:
+        conn = http.client.HTTPConnection(host, int(port), timeout=1.0)
+        conn.request('GET', '/readyz')
+        resp = conn.getresponse()
+        resp.read()
+        conn.close()
+        return resp.status
+    except Exception:
+        return None
+
+
+def test_worker_identical_bits_then_wedged_then_killed(tmp_path):
+    """One real worker process through the cross-host faults, in
+    counts and events: the same requests through the worker and
+    through an in-process engine give byte-identical results; SIGSTOP
+    (alive but wedged) is declared not ready by the heartbeat while the
+    PID lives; SIGKILL to the live PID flips /readyz over plain HTTP
+    and every later submit settles with the typed EngineClosedError,
+    never hanging."""
+    from chaos import save_chaos_model
+    from paddle_tpu.fault import inject
+    from paddle_tpu.inference import create_predictor
+    model_dir = save_chaos_model(4)
+    engine_kw = {'max_batch_size': 2, 'max_queue_depth': 4}
+    fac = ProcessReplicaFactory(
+        {'kind': 'serving', 'model_dir': model_dir, 'backend': 'cpu',
+         'engine': engine_kw},
+        workdir=str(tmp_path), spawn_timeout_s=120.0,
+        heartbeat_timeout_s=0.5)
+    rep = fac.create('v0')
+    local = ServingEngine(create_predictor(model_dir), name='v-local',
+                          **engine_kw)
+    local.warmup()
+    local.start()
+    try:
+        rng = np.random.RandomState(1234)
+        for i in range(6):
+            feed = {'x': rng.rand(i % 2 + 1, 4).astype('float32')}
+            r_out = rep.submit(dict(feed)).result(30)
+            l_out = local.submit(dict(feed)).result(30)
+            for a, b in zip(r_out, l_out):
+                a, b = np.asarray(a), np.asarray(b)
+                assert a.dtype == b.dtype and a.shape == b.shape
+                assert a.tobytes() == b.tobytes(), i
+        assert _http_readyz(rep.url) == 200 and rep.ready()
+
+        pid = inject.kill_process(rep, sig=signal.SIGSTOP)
+        assert pid == rep.pid
+        deadline = time.time() + 20
+        while rep.ready() and time.time() < deadline:
+            time.sleep(0.05)
+        assert not rep.ready()              # by the heartbeat alone:
+        assert rep.proc.poll() is None      # the PID is still alive
+
+        assert inject.kill_process(rep) == pid      # a live PID died
+        assert rep.proc.wait(timeout=10) == -signal.SIGKILL
+        assert _http_readyz(rep.url) != 200
+        assert not rep.ready()
+        with pytest.raises(EngineClosedError):
+            rep.submit({'x': np.ones((1, 4), np.float32)}).result(30)
+        assert inject.kill_process(rep) is None     # no second victim
+    finally:
+        local.shutdown(drain=True)
+        if rep.proc.poll() is None:
+            rep.proc.kill()
+        fac.close()
 
 
 # ------------------------------------------ merged multi-process report

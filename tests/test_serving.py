@@ -1,13 +1,10 @@
 """paddle_tpu.serving: shape-bucket ladder math, the micro-batching
 engine under concurrency (bit-identical to sequential Predictor.predict,
 zero executor cache misses after warmup), QueueFullError backpressure,
-drain/shutdown semantics, the thread-safe executor cache, and the
-serving_bench load generator's --json schema."""
+drain/shutdown semantics, and the thread-safe executor cache."""
 
 import json
 import os
-import subprocess
-import sys
 import threading
 import time
 
@@ -18,8 +15,6 @@ import paddle_tpu as fluid
 from paddle_tpu.serving import (BucketLadder, EngineClosedError,
                                 QueueFullError, ServingEngine,
                                 pow2_ladder)
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -409,44 +404,6 @@ def test_save_inference_model_atomic(tmp_path, monkeypatch):
     leftovers = [f for f in os.listdir(d)
                  if f.startswith('__model__.json.')]
     assert leftovers == [], 'torn tmp files left behind: %s' % leftovers
-
-
-# ----------------------------------------------------------- bench tool
-def test_serving_bench_smoke(tmp_path):
-    """tools/serving_bench.py: ~1s closed-loop run, --json schema."""
-    tool = os.path.join(REPO, 'tools', 'serving_bench.py')
-    env = dict(os.environ, JAX_PLATFORMS='cpu')
-    jsonl = str(tmp_path / 'bench.jsonl')
-    r = subprocess.run(
-        [sys.executable, tool, '--duration', '0.4', '--clients', '2',
-         '--max-batch-size', '4', '--batch-timeout-ms', '1', '--json',
-         '--metrics-jsonl', jsonl],
-        capture_output=True, text=True, timeout=300, env=env, cwd=REPO)
-    assert r.returncode == 0, r.stderr
-    doc = json.loads(r.stdout)
-    for key in ('mode', 'duration_s', 'requests_ok', 'requests_rejected',
-                'rows', 'throughput_rps', 'throughput_rows_per_s',
-                'latency_ms', 'warmup', 'executor', 'engine'):
-        assert key in doc, key
-    assert doc['mode'] == 'closed'
-    assert doc['requests_ok'] >= 1
-    lat = doc['latency_ms']
-    for q in ('p50', 'p95', 'p99', 'mean', 'max'):
-        assert lat[q] is not None and lat[q] > 0
-    assert lat['p50'] <= lat['p95'] <= lat['p99'] <= lat['max']
-    assert doc['warmup']['signatures'] == 3        # rungs [1, 2, 4]
-    # the zero-live-compile invariant, via the executor's own counters
-    assert doc['executor']['cache_misses'] == doc['warmup']['signatures']
-    assert doc['executor']['cache_hits'] >= doc['requests_ok'] // 4
-    assert doc['engine']['buckets'] == [1, 2, 4]
-    # metrics landed in the standard pipeline and the report reads them
-    report = os.path.join(REPO, 'tools', 'metrics_report.py')
-    r2 = subprocess.run([sys.executable, report, jsonl, '--json'],
-                        capture_output=True, text=True, timeout=60)
-    assert r2.returncode == 0, r2.stderr
-    doc2 = json.loads(r2.stdout)
-    assert any(k.startswith('serving.batch_size')
-               for k in doc2['histograms'])
 
 
 # ------------------------------------------------------------------ soak

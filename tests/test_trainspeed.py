@@ -332,6 +332,44 @@ def test_record_allreduce_overlap_gauge():
     assert record_allreduce_overlap(0.0, 1.0, 1.0) is None
 
 
+def test_step_flops_gauge_agrees_with_the_analytic_count(monkeypatch):
+    """What ``trainer.mfu`` and the benchmark's ``train.mfu`` divide:
+    with PADDLE_TPU_OBSERVE_COST=1 a cache miss publishes XLA's
+    cost-analysis FLOPs of the step it compiled
+    (``executor.step_flops``), and the analytic matmul count the
+    benchmark multiplies the token rate by
+    (``benchmark/shape_fns/transformer_train_flops.py``) agrees with it
+    within 3x: the analytic side counts matmuls only (x3 for the
+    backward pass), XLA the whole program."""
+    import os
+    from benchmark import manifest
+    from paddle_tpu.models import transformer as T
+    shape_fn = manifest.load_module(os.path.join(
+        os.path.dirname(manifest.__file__), 'shape_fns',
+        'transformer_train_flops.py'))
+
+    batch, seq, vocab = 2, 16, 512
+    fluid.reset_default_programs()
+    fluid.global_scope().clear()
+    avg_cost, _ = T.transformer_base(
+        src_vocab_size=vocab, trg_vocab_size=vocab,
+        src_seq_len=seq, trg_seq_len=seq, max_length=256)
+    fluid.optimizer.Adam(learning_rate=1e-4).minimize(avg_cost)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    observe.enable()
+    feed = T.make_fake_batch(batch, seq, seq, vocab, vocab)
+    monkeypatch.setenv('PADDLE_TPU_OBSERVE_COST', '1')
+    exe.run(feed=feed, fetch_list=[avg_cost])
+    xla_flops = observe.snapshot()['gauges'].get('executor.step_flops')
+    assert xla_flops, 'a cache miss must publish its step\'s FLOPs'
+    # transformer_base: 6 layers, 8 heads of 64, d_model 512, FFN 2048
+    analytic = shape_fn.step_flops(batch, seq, seq, vocab, 6, 8, 64,
+                                   512, 2048)
+    assert 1.0 / 3.0 <= analytic / xla_flops <= 3.0, (analytic,
+                                                       xla_flops)
+
+
 def test_quantized_plus_bucketed_composition():
     """EQuARX int8 gradient compression rides inside the buckets; the
     composed run must train to the same neighborhood as exact."""

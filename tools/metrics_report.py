@@ -1,8 +1,8 @@
 """Summarize a paddle_tpu.observe metrics JSONL.
 
 Reads the snapshot/summary lines written by ``observe.enable(jsonl=...)``
-(one JSON object per line; bench.py workloads append here,
-pid-tagged) and prints a human summary: p50/p95/max per
+(one JSON object per line, pid-tagged: several processes may append
+to one file) and prints a human summary: p50/p95/max per
 histogram, final counter/gauge values, and the MFU/goodput headline.
 
     python tools/metrics_report.py run.jsonl
@@ -304,7 +304,7 @@ _FLEET_STATES = {0: 'UP', 1: 'DRAINING', 2: 'QUARANTINED', 3: 'DEAD'}
 def derive_fleet(records):
     """Fleet-controller timeline from a metrics JSONL: the replica
     census over time (from the periodic snapshot records the autoscale
-    bench flushes), scale-out/in/heal/quarantine counter deltas per
+    chaos scenarios flush), scale-out/in/heal/quarantine counter deltas per
     snapshot, the final per-replica state machine, and the hedge
     ledger (hedge+failover dispatch rate vs the retry budget). Works
     on counters/gauges alone — no flight ring needed offline."""
@@ -638,8 +638,8 @@ def main(argv=None):
                    help='use the newest record of any kind, not just '
                         'the newest end-of-run summary')
     p.add_argument('--all-pids', action='store_true',
-                   help='report the newest record per pid (multi-child '
-                        'bench runs)')
+                   help='report the newest record per pid (several '
+                        'processes, one file)')
     p.add_argument('--per-host', action='store_true',
                    help='report the newest record per host '
                         '(jax.process_index() — merged multihost '

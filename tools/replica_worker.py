@@ -56,9 +56,9 @@ def _publish_port_file(path, doc):
 
 
 class _DelayPredictor(object):
-    """Fixed per-batch compute floor (same duck-type as bench.py's
-    chaos predictor) so cross-host chaos scenarios keep machine-
-    independent overload arithmetic."""
+    """Fixed per-batch compute floor (same duck-type as
+    tests/chaos.py's ChaosPredictor) so a worker's capacity does not
+    depend on the machine."""
 
     def __init__(self, inner, delay_s):
         self._inner = inner
@@ -87,8 +87,8 @@ def _build_engine(cfg):
         spec = LMSpec(**(cfg.get('spec') or {}))
         if cfg.get('weights_seed') is not None:
             # deterministic init: every process seeding the same way
-            # holds bit-identical params (the bit-identity assertion
-            # in bench crosshost rides on this)
+            # holds bit-identical params (a subprocess replica's
+            # bit identity with an in-process engine rides on this)
             engine_kw.setdefault(
                 'weights', random_weights(spec,
                                           seed=int(cfg['weights_seed'])))
