@@ -211,6 +211,11 @@ class LatentMoEBlock(object):
 
     all_arena_slots = ('LatentFull', 'IndexFull', 'LatentSliding')
     pools = (('', 0),)          # every arena under the one block table
+    # what a subclass with another FFN arrangement changes: the routed
+    # layers' slots beside the stacked experts, and whether only the
+    # leading layers have a dense FFN
+    routed_slots = _ROUTED
+    dense_everywhere = False
 
     def __init__(self, ctx):
         self.emb = ctx.input('Emb')
@@ -249,8 +254,8 @@ class LatentMoEBlock(object):
                 self.w[_TAG[kind] + slot] = ctx.input(_TAG[kind] + slot)
         lead, period, n_periods, tail = self.plan
         slots = (_INDEX if FULL in kinds and self.index_topk else ()) + \
-            (_DENSE if lead else ()) + \
-            (_ROUTED if period or tail else ())
+            (_DENSE if lead or self.dense_everywhere else ()) + \
+            (self.routed_slots if period or tail else ())
         for slot in slots:
             self.w[slot] = ctx.input(slot)
         # the routed experts stay stacked: each row tile of their product

@@ -4,9 +4,13 @@ One chip of an expert-parallel deployment holds ``E`` consecutive
 experts of the ``n_experts`` the router scores (``first .. first + E -
 1``). Every row is routed over all of them (sigmoid scores or a
 softmax over them, by the block; the ``top_k`` largest, weights
-normalised over all that were chosen, wherever they live), and this
+normalised over all that were chosen, wherever they live, or the
+chosen scores themselves times a factor), and this
 chip adds what its own experts give: the
-partial result that the exchange between chips would sum. There is no
+partial result that the exchange between chips would sum. A router may
+be wider than the experts: its outputs past ``n_experts`` are identity
+experts (``identity_weight``), which match no held index, make no
+assignment and cost a row one multiply. There is no
 capacity and nothing is dropped, so ``ops/moe_ops.py``'s ``[S, E, C]``
 dispatch (Switch/GShard, for the training programs) has no part here.
 
@@ -93,6 +97,7 @@ import jax.numpy as jnp
 from .pallas.moe_routed_product import TILE_ROWS, routed_product, serves
 
 __all__ = ['route_sigmoid_topk', 'route_softmax_topk', 'held_gates',
+           'identity_weight',
            'gated_experts', 'routed_experts', 'row_tiles', 'load_stats',
            'TILE_ROWS']
 
@@ -105,15 +110,33 @@ def _router_logits(x, router):
                       precision=jax.lax.Precision.HIGHEST)
 
 
-def route_softmax_topk(x, router, top_k):
-    """``route_sigmoid_topk`` for a softmax router (mellum): the scores
-    are the softmax of the logits over every expert, the ``top_k``
-    largest are chosen and the weights are those scores normalised over
-    the chosen (``norm_topk_prob``), which is the softmax over the
-    chosen experts' logits alone. float32 at the highest precision."""
+def route_softmax_topk(x, router, top_k, bias=None, scale=None):
+    """``route_sigmoid_topk`` for a softmax router: the scores are the
+    softmax of the logits over every output of the router, float32 at
+    the highest precision. As mellum has it (``scale`` None): the
+    ``top_k`` largest are chosen and the weights are those scores
+    normalised over the chosen (``norm_topk_prob``), which is the
+    softmax over the chosen experts' logits alone. As longcat_flash has
+    it (``scale`` given): the ``top_k`` largest of score + ``bias``
+    [outputs] are chosen (ties to the lower index) and weigh their own
+    scores times ``scale``, **not** normalised over the chosen: how much
+    of a row goes through the experts at all is the router's to say."""
     scores = jax.nn.softmax(_router_logits(x, router), axis=-1)
-    top, chosen = jax.lax.top_k(scores, top_k)
-    return chosen, top / jnp.sum(top, axis=-1, keepdims=True)
+    if scale is None:
+        top, chosen = jax.lax.top_k(scores, top_k)
+        return chosen, top / jnp.sum(top, axis=-1, keepdims=True)
+    picked = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, chosen = jax.lax.top_k(picked, top_k)
+    return chosen, jnp.take_along_axis(scores, chosen, axis=1) * scale
+
+
+def identity_weight(chosen, weights, n_real):
+    """float32 [N]: the sum of each row's weights on identity experts,
+    the router's outputs at and past ``n_real``. An identity expert
+    returns its input, so all of a row's together are this one factor
+    times the row: they make no assignment, no row tile and read no
+    weight, wherever the real experts live."""
+    return jnp.sum(jnp.where(chosen >= n_real, weights, 0.0), axis=1)
 
 
 def route_sigmoid_topk(x, router, top_k, bias=None, scale=1.0):
@@ -137,7 +160,9 @@ def route_sigmoid_topk(x, router, top_k, bias=None, scale=1.0):
 
 def held_gates(chosen, weights, first, n_held):
     """(gate [N, E] float32, hit [N, E] bool): each row's weight for
-    the experts ``first .. first + n_held - 1``, and which it chose."""
+    the experts ``first .. first + n_held - 1``, and which it chose. A
+    chosen index outside them (an expert of another chip, an identity
+    expert past the real ones) is nobody's here."""
     held = first + jnp.arange(n_held, dtype=chosen.dtype)
     match = chosen[:, :, None] == held[None, None, :]      # [N, k, E]
     gate = jnp.sum(jnp.where(match, weights[:, :, None], 0.0), axis=1)
@@ -338,12 +363,22 @@ def _grouped_by_loop(most, rows, gate, live, w_gate, w_up, w_down, layer):
                              jnp.zeros((n, d), jnp.float32))
 
 
-def load_stats(hit, valid):
+def load_stats(hit, valid, chosen=None, n_real=None):
     """int32 [4] over the rows that are ``valid``: choices that landed
     on an expert held here, rows on the busiest of them, how many of
     them any row chose, and the row tiles ``routed_experts`` runs for
-    them (its loop's trip count)."""
+    them (its loop's trip count). Under a router with identity experts
+    (``chosen`` [N, k], ``n_real``) k + 1 more: the valid rows that
+    chose 0, 1 .. k real experts (held here or not), from which the
+    real and the identity assignments follow."""
     load = jnp.sum(hit & valid[:, None], axis=0, dtype=jnp.int32)  # [E]
-    return jnp.stack([jnp.sum(load), jnp.max(load),
-                      jnp.sum(load > 0, dtype=jnp.int32),
-                      jnp.sum(row_tiles(load))])
+    out = jnp.stack([jnp.sum(load), jnp.max(load),
+                     jnp.sum(load > 0, dtype=jnp.int32),
+                     jnp.sum(row_tiles(load))])
+    if chosen is None:
+        return out
+    real = jnp.sum(chosen < n_real, axis=1, dtype=jnp.int32)       # [N]
+    counts = jnp.arange(chosen.shape[1] + 1, dtype=jnp.int32)
+    return jnp.concatenate([out, jnp.sum(
+        (real[:, None] == counts[None, :]) & valid[:, None], axis=0,
+        dtype=jnp.int32)])

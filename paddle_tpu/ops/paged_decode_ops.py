@@ -45,7 +45,10 @@ their K and V in page pools of their own, each under its own block
 table: an op then has a table input a pool of its block, ``pools``;
 ``SsmHybridBlock`` in ops/ssm_hybrid_ops.py: block='ssm_hybrid', whose
 Mamba-2 layers keep a state a sequence in arenas indexed by a slot and
-not by pages, read and written where it lies by ops/ssm_ops.py):
+not by pages, read and written where it lies by ops/ssm_ops.py;
+``ShortcutMoEBlock`` in ops/shortcut_moe_ops.py: block='shortcut_moe',
+a layer of two latent attentions with cache rows of their own and an
+expert branch beside them):
 embedding, the q/k/v
 projections, what follows attention, the per-layer lower bound on the
 columns a row sees, and the logits. Everything else — placement, the
@@ -413,6 +416,9 @@ def _block_of(ctx):
     if kind == 'latent_moe':
         from .latent_moe_ops import LatentMoEBlock
         return LatentMoEBlock(ctx)
+    if kind == 'shortcut_moe':
+        from .shortcut_moe_ops import ShortcutMoEBlock
+        return ShortcutMoEBlock(ctx)
     if kind == 'gqa_moe':
         from .gqa_moe_ops import GqaMoEBlock
         return GqaMoEBlock(ctx)

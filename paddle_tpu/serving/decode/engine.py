@@ -49,10 +49,13 @@ workload — over a decoder-only LM with a paged KV cache:
   pool, ``self.pools``) and the state-space hybrid (Mamba-2 layers whose
   state is one slot a sequence, a cache kind with a size a sequence in
   a pool of slots, beside attention layers in pages: the programs are
-  fed a slot a row, ``_table_widths``) run through this same engine. For
-  the latter four speculation and quantized arenas raise rather than run
+  fed a slot a row, ``_table_widths``) and the shortcut block (two
+  latent attentions a layer, so a token keeps two cache layers a layer
+  of the one kind, and a router a third of whose outputs are identity
+  experts) run through this same engine. For
+  the latter five speculation and quantized arenas raise rather than run
   untested,
-  and for the latent and the grouped block the page handoff too. The prefix cache runs
+  and for the latent, the shortcut and the grouped block the page handoff too. The prefix cache runs
   for a spec whose frozen pages another sequence may map
   (``LMSpec.shares_frozen_pages``: 'post_ln', and a latent block all
   of whose layers read every cached position); the others raise: their
@@ -1487,7 +1490,10 @@ class DecodeEngine(object):
         them, experts any row chose, row tiles the routed product ran)
         into the counters the benchmark reads: of rows x
         experts_per_token choices a layer, the local ones; the busiest
-        expert's load against the mean, per layer-step."""
+        expert's load against the mean, per layer-step; and, where the
+        router has identity experts (``moe_held_ops.load_stats``' wider
+        form), the choices that were real experts and those that were
+        identities, and a (row, layer)'s real experts."""
         held = self.spec.experts_held
         self._record_moe_tiles(stats, self.max_batch)
         _obs.inc('decode.moe_assignments',
@@ -1499,6 +1505,20 @@ class DecodeEngine(object):
             if local:
                 _obs.record('decode.moe_load_max_over_mean',
                             busiest * held / float(local))
+        if stats.shape[1] > 4:
+            # a router with identity experts: per layer, the live rows
+            # by the real experts each chose (0 .. experts_per_token);
+            # the rest of a row's choices computed nothing
+            by_real = stats[:, 4:].sum(axis=0)
+            real = np.arange(len(by_real))
+            _obs.inc('decode.moe_real_assignments',
+                     int((by_real * real).sum()))
+            _obs.inc('decode.moe_zero_assignments',
+                     int((by_real * (len(by_real) - 1 - real)).sum()))
+            for n_real, n_rows in zip(real, by_real):
+                for _ in range(int(n_rows)):
+                    _obs.record('decode.moe_real_experts_per_token',
+                                int(n_real))
 
     def _record_moe_tiles(self, stats, program_rows):
         """The row tiles the routed experts' product ran in one program

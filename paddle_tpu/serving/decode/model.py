@@ -63,7 +63,9 @@ class CacheKind(collections.namedtuple(
         'CacheKind', ['name', 'slot', 'layers', 'width', 'reads',
                       'shared', 'pool', 'keeps', 'per_seq', 'dtype'])):
     """One arena of the cache: its name, the op's input slot, the
-    layers that keep it (in order), the elements its unit holds in one
+    cache layers that keep it (in order; a layer that attends
+    ``LMSpec.sublayers`` times keeps as many, each with rows of its
+    own), the elements its unit holds in one
     layer (``width``: a token's row; or, for a kind whose size is a
     sequence's whatever its length, ``per_seq``: the shape of the one
     slot a sequence holds, and ``width`` its elements),
@@ -270,7 +272,7 @@ class LatentShape(object):
 
 
 class LMSpec(object):
-    """Decoder-only LM hyperparameters: a family of five blocks.
+    """Decoder-only LM hyperparameters: a family of six blocks.
 
     ``block='post_ln'`` (the default; every argument after ``d_inner``
     unused): the 2017 decoder block — embedding scaled by sqrt(d_model)
@@ -354,7 +356,30 @@ class LMSpec(object):
     with a size a sequence (``CacheKind.per_seq``) in a pool of their
     own whose unit is one sequence's slot. Such a state cannot be
     mapped from a page boundary nor rewound past a rejected draft: the
-    prefix cache and speculation are refused."""
+    prefix cache and speculation are refused.
+
+    ``block='shortcut_moe'`` (longcat_flash): a layer of two sublayers
+    ``j``, each with its own latent attention (the one ``full_attention``
+    shape of ``latent``, dense: no indexer, no window), two RMSNorms and
+    a dense gated SiLU FFN of ``d_inner_dense``, and one expert branch
+    beside them that leaves from the first sublayer and rejoins at the
+    layer's end: ``a0 = x + Attn0(RMSNorm(x))``, ``n0 = RMSNorm(a0)``,
+    ``s = MoE(n0)``, ``b0 = a0 + FFN0(n0)``, ``a1 = b0 +
+    Attn1(RMSNorm(b0))``, ``y = a1 + FFN1(RMSNorm(a1)) + s``. A token
+    therefore caches ``sublayers`` = 2 latent rows a layer: the one
+    kind's arena has ``2 n_layer`` cache layers, sublayer ``j`` of layer
+    ``l`` at ``2 l + j``, as have the attention, norm and dense FFN
+    stacks. The router is a softmax over ``n_experts + zero_experts``
+    outputs; the ``experts_per_token`` largest of score + a
+    selection-only bias are chosen and weigh their own scores times
+    ``routed_scale``, not normalised over the chosen. An index below
+    ``n_experts`` is a gated SiLU FFN of ``d_inner`` (``experts_held``
+    from ``first_expert`` are computed here); one at or above it is an
+    identity expert, which returns its input: a row's identity choices
+    cost one multiply by the sum of their weights and never enter the
+    routed product. No shared expert; an untied head behind a final
+    RMSNorm; ``lora_rescale`` as ``latent_moe``. The prefix cache,
+    speculation, quantized arenas and the page handoff are refused."""
 
     def __init__(self, vocab_size, n_layer=2, n_head=2, d_key=16,
                  d_value=16, d_model=32, d_inner=64, block='post_ln',
@@ -367,7 +392,7 @@ class LMSpec(object):
                  lora_rescale=True, attn_gate=True, routed_scale=1.0,
                  rope_parameters=None, ssm_heads=0, ssm_head_dim=0,
                  ssm_state=0, ssm_conv=4, ssm_chunk=256, embed_scale=1.0,
-                 residual_scale=1.0, attn_scale=None):
+                 residual_scale=1.0, attn_scale=None, zero_experts=0):
         self.vocab_size = int(vocab_size)
         self.n_layer = int(n_layer)
         self.n_head = int(n_head)
@@ -382,6 +407,7 @@ class LMSpec(object):
         self.sliding_window = int(sliding_window)
         self.rope_theta = float(rope_theta)
         self.n_experts = int(n_experts)
+        self.zero_experts = int(zero_experts)
         self.experts_held = self.n_experts if experts_held is None \
             else int(experts_held)
         self.first_expert = int(first_expert)
@@ -419,10 +445,11 @@ class LMSpec(object):
                 raise ValueError("LMSpec: block='post_ln' has one KV head "
                                  "per query head")
             return
-        if self.block not in ('parallel_moe', 'latent_moe', 'gqa_moe'):
+        if self.block not in ('parallel_moe', 'latent_moe', 'gqa_moe',
+                              'shortcut_moe'):
             raise ValueError('LMSpec: unknown block %r (post_ln, '
                              'parallel_moe, latent_moe, gqa_moe, '
-                             'ssm_hybrid)' % self.block)
+                             'ssm_hybrid, shortcut_moe)' % self.block)
         if self.block in ('parallel_moe', 'gqa_moe') and (
                 self.n_head % self.n_kv_head or self.d_key != self.d_value):
             raise ValueError('LMSpec: %d query heads over %d KV heads of '
@@ -435,18 +462,25 @@ class LMSpec(object):
         if SLIDING in self.layer_types and self.sliding_window < 1:
             raise ValueError('LMSpec: sliding layers need a window')
         # a block has shared experts or has none: the other is refused
-        shared_ok = self.n_shared_experts == 0 if self.block == 'gqa_moe' \
+        shared_ok = self.n_shared_experts == 0 \
+            if self.block in ('gqa_moe', 'shortcut_moe') \
             else self.n_shared_experts > 0
-        if not (0 < self.experts_per_token <= self.n_experts and
-                0 < self.experts_held and shared_ok and
+        # identity experts are the router's outputs past the real ones:
+        # only the block that adds their term has them
+        zero_ok = self.zero_experts > 0 if self.block == 'shortcut_moe' \
+            else self.zero_experts == 0
+        if not (0 < self.experts_per_token
+                <= self.n_experts + self.zero_experts and
+                0 < self.experts_held and shared_ok and zero_ok and
                 self.first_expert + self.experts_held <= self.n_experts):
             raise ValueError(
-                'LMSpec: experts %d..%d of %d, %d per token, %d shared'
+                'LMSpec: experts %d..%d of %d (+ %d identity), %d per '
+                'token, %d shared'
                 % (self.first_expert,
                    self.first_expert + self.experts_held - 1,
-                   self.n_experts, self.experts_per_token,
-                   self.n_shared_experts))
-        if self.block == 'latent_moe':
+                   self.n_experts, self.zero_experts,
+                   self.experts_per_token, self.n_shared_experts))
+        if self.block in ('latent_moe', 'shortcut_moe'):
             self._check_latent()
         if self.block == 'gqa_moe':
             self._check_rope()
@@ -511,6 +545,29 @@ class LMSpec(object):
                 'LMSpec: full layers select by an indexer: index_topk %d, '
                 '%d heads of %d' % (self.index_topk, self.index_n_heads,
                                     self.index_head_dim))
+        if self.block == 'shortcut_moe' and (
+                kinds != {FULL} or self.index_topk or self.dense_layers
+                or self.attn_gate or self.d_inner_dense < 1):
+            raise ValueError(
+                "LMSpec: block='shortcut_moe' has dense latent attention "
+                "without a gate and a dense FFN of d_inner_dense in both "
+                "sublayers of every layer (kinds %s, index_topk %d, "
+                "dense_layers %d, attn_gate %s, d_inner_dense %d)"
+                % (sorted(kinds), self.index_topk, self.dense_layers,
+                   self.attn_gate, self.d_inner_dense))
+
+    @property
+    def sublayers(self):
+        """Attention sublayers a layer, each with cache rows of its own:
+        the cache layers of a kind are ``sublayers`` times its layers."""
+        return 2 if self.block == 'shortcut_moe' else 1
+
+    def cache_layers_of(self, kind):
+        """The cache layers of one kind of layer, in the order its arena
+        stacks them: sublayer ``j`` of layer ``l`` is ``l x sublayers +
+        j`` (``layers_of(kind)`` where a layer attends once)."""
+        return tuple(l * self.sublayers + j for l in self.layers_of(kind)
+                     for j in range(self.sublayers))
 
     def layers_of(self, kind):
         """The layers of one kind, in order."""
@@ -594,7 +651,7 @@ class LMSpec(object):
                                       self.n_kv_head * self.d_value, reads,
                                       False, pool, reads[0])]
             return tuple(out)
-        if self.block != 'latent_moe':
+        if self.block not in ('latent_moe', 'shortcut_moe'):
             reads = tuple(self.windows())
             return (CacheKind('lm_kcache', 'KCache', every,
                               self.n_kv_head * self.d_key, reads, False),
@@ -602,7 +659,7 @@ class LMSpec(object):
                               self.n_kv_head * self.d_value, reads, False))
         out = []
         if FULL in self.latent:
-            full = self.layers_of(FULL)
+            full = self.cache_layers_of(FULL)
             out.append(CacheKind('lm_latent_full', 'LatentFull', full,
                                  self.latent[FULL].row_width,
                                  (self.index_topk,) * len(full), True))
@@ -686,9 +743,10 @@ class LMSpec(object):
                                'reference yet'}[what]
 
     def attn_windows(self):
-        """``windows()`` of the layers that attend at all."""
+        """``windows()`` of the layers that attend at all, once for
+        each time a layer attends (``sublayers``)."""
         return [w for w, t in zip(self.windows(), self.layer_types)
-                if t != MAMBA]
+                if t != MAMBA for _ in range(self.sublayers)]
 
     def per_head_cache(self):
         """Whether a cached row is ``n_kv_head`` heads of K (or V)."""
@@ -869,6 +927,62 @@ def moe_param_shapes(spec):
     ])
 
 
+def _latent_attention_shapes(spec, kind, tag, slot, n):
+    """The eight attention entries of ``n`` stacked latent attentions of
+    ``kind`` (``latent_param_shapes`` has the layouts and the fan-ins)."""
+    a, d = spec.latent[kind], spec.d_model
+    qk = a.d_nope + a.d_rope
+    from_q = d if spec.lora_rescale else a.q_rank
+    from_kv = d if spec.lora_rescale else a.kv_rank
+    return [
+        ('lm_%s_q_a.w' % tag, ([n, d, a.q_rank], d, slot + 'QA')),
+        ('lm_%s_q_ln.w' % tag, ([n, a.q_rank], None, slot + 'QLn')),
+        ('lm_%s_q_b.w' % tag, ([n, a.q_rank, a.n_head * qk],
+                               from_q, slot + 'QB')),
+        ('lm_%s_kv_a.w' % tag, ([n, d, a.row_width], d, slot + 'KvA')),
+        ('lm_%s_kv_ln.w' % tag, ([n, a.kv_rank], None, slot + 'KvLn')),
+        ('lm_%s_kv_bk.w' % tag, ([n, a.n_head, a.d_nope, a.kv_rank],
+                                 from_kv, slot + 'KvBK')),
+        ('lm_%s_kv_bv.w' % tag, ([n, a.n_head, a.kv_rank, a.d_v],
+                                 from_kv, slot + 'KvBV')),
+        ('lm_%s_o.w' % tag, ([n, a.n_head * a.d_v, d],
+                             a.n_head * a.d_v, slot + 'O')),
+    ]
+
+
+def shortcut_param_shapes(spec):
+    """``moe_param_shapes`` of the shortcut_moe block: the two norms,
+    the latent attention (``latent_param_shapes``' entries and layouts)
+    and the dense gated FFN are stacks over the ``2 n_layer`` sublayers,
+    sublayer ``j`` of layer ``l`` at ``2 l + j``; the router (as wide as
+    the real and the identity experts together), its selection-only bias
+    and the real experts held here are stacks over the layers. An
+    identity expert has no weights and there is no shared expert."""
+    L, d, f = spec.n_layer, spec.d_model, spec.d_inner
+    e, fd, sub = spec.experts_held, spec.d_inner_dense, \
+        spec.n_layer * spec.sublayers
+    wide = spec.n_experts + spec.zero_experts
+    out = collections.OrderedDict([
+        ('lm_emb', ([spec.vocab_size, d], d, 'Emb')),
+        ('lm_head.w', ([spec.vocab_size, d], d, 'Head')),
+        ('lm_final_ln.w', ([d], None, 'FinalLN')),
+        ('lm_stack_ln1.w', ([sub, d], None, 'Ln1W')),
+        ('lm_stack_ln2.w', ([sub, d], None, 'Ln2W')),
+    ])
+    out.update(_latent_attention_shapes(spec, FULL, 'full', 'Full', sub))
+    out.update([
+        ('lm_dense_gate.w', ([sub, d, fd], d, 'DenseGate')),
+        ('lm_dense_up.w', ([sub, d, fd], d, 'DenseUp')),
+        ('lm_dense_down.w', ([sub, fd, d], fd, 'DenseDown')),
+        ('lm_moe_router.w', ([L, d, wide], d, 'Router')),
+        ('lm_moe_router.b', ([L, wide], 0, 'RouterBias')),
+        ('lm_moe_exp_gate.w', ([L, e, d, f], d, 'ExpGate')),
+        ('lm_moe_exp_up.w', ([L, e, d, f], d, 'ExpUp')),
+        ('lm_moe_exp_down.w', ([L, e, f, d], f, 'ExpDown')),
+    ])
+    return out
+
+
 def latent_param_shapes(spec):
     """``moe_param_shapes`` of the latent_moe block. Stacks are per
     kind, over the layers of that kind in order: ``lm_full_*`` the full
@@ -903,23 +1017,8 @@ def latent_param_shapes(spec):
         if kind not in spec.latent:
             continue
         a, n = spec.latent[kind], len(spec.layers_of(kind))
-        qk = a.d_nope + a.d_rope
         from_q = d if spec.lora_rescale else a.q_rank
-        from_kv = d if spec.lora_rescale else a.kv_rank
-        out.update([
-            ('lm_%s_q_a.w' % tag, ([n, d, a.q_rank], d, slot + 'QA')),
-            ('lm_%s_q_ln.w' % tag, ([n, a.q_rank], None, slot + 'QLn')),
-            ('lm_%s_q_b.w' % tag, ([n, a.q_rank, a.n_head * qk],
-                                   from_q, slot + 'QB')),
-            ('lm_%s_kv_a.w' % tag, ([n, d, a.row_width], d, slot + 'KvA')),
-            ('lm_%s_kv_ln.w' % tag, ([n, a.kv_rank], None, slot + 'KvLn')),
-            ('lm_%s_kv_bk.w' % tag, ([n, a.n_head, a.d_nope, a.kv_rank],
-                                     from_kv, slot + 'KvBK')),
-            ('lm_%s_kv_bv.w' % tag, ([n, a.n_head, a.kv_rank, a.d_v],
-                                     from_kv, slot + 'KvBV')),
-            ('lm_%s_o.w' % tag, ([n, a.n_head * a.d_v, d],
-                                 a.n_head * a.d_v, slot + 'O')),
-        ])
+        out.update(_latent_attention_shapes(spec, kind, tag, slot, n))
         if spec.attn_gate:
             out['lm_%s_gate.w' % tag] = ([n, d, a.n_head], d, slot + 'Gate')
         if kind == FULL and spec.index_topk:
@@ -1039,6 +1138,7 @@ def block_param_shapes(spec):
     (float32 ones), of 0 a bias (float32 zeros), anything else a matrix
     kept at ``spec.dtype`` and drawn N(0, 1 / fan-in)."""
     return {'latent_moe': latent_param_shapes,
+            'shortcut_moe': shortcut_param_shapes,
             'gqa_moe': gqa_param_shapes,
             'ssm_hybrid': ssm_param_shapes}.get(spec.block,
                                                 moe_param_shapes)(spec)
@@ -1065,8 +1165,10 @@ def _block_attrs(spec, block_size):
             'top_k': spec.experts_per_token,
             'first_expert': spec.first_expert,
             'logit_scale': spec.logit_scale})
-    if spec.block == 'latent_moe':
+    if spec.block in ('latent_moe', 'shortcut_moe'):
         lead, period, n_periods, tail = spec.layer_plan()
+        if spec.zero_experts:
+            attrs['zero_experts'] = spec.zero_experts
         attrs.update({
             'block': spec.block, 'norm_eps': spec.norm_eps,
             'top_k': spec.experts_per_token,
@@ -1168,12 +1270,16 @@ def _arena_outputs(arenas):
 def _moe_stats_output(helper, spec, outputs):
     """Give a routed block's program its MoeStats output (per routed layer:
     choices that landed on an expert held here, rows on the busiest of
-    them, experts any row chose, row tiles the routed product ran) and
+    them, experts any row chose, row tiles the routed product ran; and,
+    where the router has identity experts, the live rows by how many
+    real experts each chose, 0 .. ``experts_per_token``) and
     return its name; None for a block that routes nothing."""
     if not spec.n_experts:
         return None
     stats = helper.create_variable_for_type_inference('int32')
-    stats.shape = (spec.n_layer - spec.dense_layers, 4)
+    stats.shape = (spec.n_layer - spec.dense_layers,
+                   4 + (spec.experts_per_token + 1
+                        if spec.zero_experts else 0))
     outputs['MoeStats'] = [stats]
     return stats.name
 

@@ -568,3 +568,117 @@ def test_the_state_arenas_are_written_where_they_lie(one_chip):
              if re.match(r'\s+(ROOT )?%[\w.\-]+ = .* while\(', line)]
     assert len(loops) == 2, [line[:120] for line in loops]
     assert not any(state in line or conv in line for line in loops)
+
+
+# ------------------------------------- a whole program at published size
+class _OpInputs(object):
+    """What a paged op's lowering reads of its context, over abstract
+    values: the op at a configuration's full size without an engine (an
+    engine draws its weights and zeroes its arenas, 13 GB here)."""
+
+    def __init__(self, attrs, inputs):
+        self._attrs, self._inputs, self.outputs = attrs, inputs, {}
+
+    def attr(self, name, default=None):
+        return self._attrs.get(name, default)
+
+    def input(self, slot):
+        return self._inputs[slot]
+
+    def has_input(self, slot):
+        return slot in self._inputs
+
+    def out_dtype(self, slot, default):
+        return 'int32'
+
+    def set_output(self, slot, value):
+        self.outputs[slot] = value
+
+
+def _paged_op(spec, geometry, op, rows):
+    """(function of (weights, arenas, feeds) -> (tokens, arenas), the
+    three arguments' shapes) of ``paged_decode_step`` over ``rows`` slots
+    or ``paged_prefill`` of a chunk of ``rows``, for a spec with one page
+    pool, from the program builder's own tables."""
+    from paddle_tpu.core.registry import get_lowering
+    from paddle_tpu.serving.decode import model as lm
+    import paddle_tpu.ops.paged_decode_ops  # noqa: F401  (registers)
+    attrs = lm._block_attrs(spec, geometry['block_size'])
+    weights = {slot: (tuple(shape), spec.dtype if fan_in else 'float32')
+               for shape, fan_in, slot in
+               lm.block_param_shapes(spec).values()}
+    arenas = {k.slot: ((len(k.layers), geometry['num_blocks'])
+                       + tuple(k.unit_shape(geometry['block_size'])),
+                       geometry['kv_dtype']) for k in spec.cache_kinds()}
+    pages = geometry['pages_per_seq']
+    if op == 'paged_decode_step':
+        feeds = {'Tokens': ((rows,), 'int32'), 'SeqLens': ((rows,), 'int32'),
+                 'BlockTables': ((rows, pages), 'int32'),
+                 'Temps': ((rows,), 'float32'), 'Seeds': ((rows,), 'int32')}
+        out = 'NextTokens'
+    else:
+        feeds = {'Ids': ((rows,), 'int32'), 'Len': ((), 'int32'),
+                 'Cached': ((), 'int32'), 'BlockTable': ((pages,), 'int32'),
+                 'Temp': ((), 'float32'), 'Seed': ((), 'int32')}
+        out = 'NextToken'
+    lowering = get_lowering(op)
+
+    def fn(w, a, f):
+        ctx = _OpInputs(attrs, dict(w, **dict(a, **f)))
+        lowering(ctx)
+        return ctx.outputs[out], {slot: ctx.outputs[slot + 'Out']
+                                  for slot in a}
+    return fn, (weights, arenas, feeds)
+
+
+def test_the_shortcut_block_loads_at_its_published_geometry(one_chip):
+    """longcat_flash_chat as the benchmark runs it (every published
+    width, layers 0-3, 16 of 512 experts, 1/8 vocabulary; 64 slots,
+    8,192 pages of 32, tables of 192 pages): the decode step and the 512
+    chunk compile for the v5e, the arena donated as the executor donates
+    it, with the argument bytes the configuration states: 10.35 GB of
+    weights + 2.68 GB of arena = 13.0 GB, and the whole program under
+    the chip's 15.75 GiB. One moe_routed_product kernel a program (in the
+    layers' scan) whose operands are the stacked experts; the arena
+    stays row-major with eight cache layers."""
+    import math
+    import os
+    from paddle_tpu.serving.decode import LMSpec
+    from benchmark import manifest
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    resolved = manifest.resolve(manifest.load(root),
+                                'longcat_flash_chat.chat_decode_heavy')
+    config = {k: v for k, v in resolved['config'].items()
+              if k != 'rehearsal'}
+    spec = manifest.load_module(resolved['runner']).spec_of(config)
+    assert isinstance(spec, LMSpec) and spec.d_model == 6144
+    geometry = config['engine']
+    weights_b = arena_b = None
+    for op, rows in (('paged_decode_step', geometry['max_batch']),
+                     ('paged_prefill', geometry['prefill_chunk'])):
+        fn, shapes = _paged_op(spec, geometry, op, rows)
+        args = [{slot: _shaped(one_chip, shape, dtype)
+                 for slot, (shape, dtype) in group.items()}
+                for group in shapes]
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+        memory = compiled.memory_analysis()
+        weights_b, arena_b = (
+            sum(jnp.dtype(d).itemsize * math.prod(s)
+                for s, d in group.values()) for group in shapes[:2])
+        assert abs(memory.argument_size_in_bytes
+                   - weights_b - arena_b) < 1 << 20, op
+        # the arena is aliased to its output, and what the program keeps
+        # beside its arguments fits the chip with them
+        assert memory.alias_size_in_bytes >= arena_b
+        assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes \
+            < 15.75 * (1 << 30), op
+        hlo = compiled.as_text()
+        assert set(re.findall(r'bf16\[8,8192,32,640\]\{([\d,]+)', hlo)) \
+            == {'3,2,1,0'}, op
+        kernels = [line for line in _outside_fusions(hlo)
+                   if 'custom_call_target="tpu_custom_call"' in line]
+        assert len(kernels) == 1 and '%moe_routed_product' in kernels[0], op
+        assert 'bf16[4,16,6144,2048]' in kernels[0]
+    assert round(weights_b / 1e9, 2) == 10.35
+    assert round(arena_b / 1e9, 2) == 2.68
