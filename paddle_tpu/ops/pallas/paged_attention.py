@@ -10,11 +10,15 @@ FLOPs and, worse, padding HBM. Pages decouple cache capacity from
 per-sequence reservation: a 17-token sequence holds ceil(17/bs) pages,
 not Tmax slots.
 
-The attention reads the pages its rows hold and no others, in plain
-XLA on every backend, in whole pages of about BLOCK_COLS columns
-gathered through the table at (layer, table) and consumed where they
-are gathered (``_block_products``: float32 scores, normaliser and
-accumulator). Two forms:
+The attention reads the pages its rows hold and no others, in whole
+pages of about BLOCK_COLS columns gathered through the table at (layer,
+table) and consumed where they are gathered (``_block_products``:
+float32 scores, normaliser and accumulator): in plain XLA on every
+backend, but for the many-tables latent form on a TPU, whose pair list
+goes through one Pallas kernel (``paged_decode_attention.py``;
+``pairs_form`` is the static rule, ``jax.lax.platform_dependent`` the
+choice; the XLA loop is what the kernel is held to,
+tests/test_latent_decode_kernel.py). Two forms:
 ``paged_attention_blocked`` — many tables, one query each (decode
 step, spec verify). Its unit of work is a *pair* (row, column block):
 a live row holds one pair for each column block between its bounds,
@@ -23,7 +27,11 @@ BLOCK_ROWS consecutive pairs an iteration, each through its own
 table at its own block, as so many independent problems, and a row's
 pairs advance its softmax state one after another in column order,
 within an iteration as across two, so a short row beside a long one
-costs its own blocks and a row's result is its own columns' alone;
+costs its own blocks and a row's result is its own columns' alone
+(the kernel takes the same list a pair a grid step, the next pair's
+pages on their way in while this one multiplies, and merges by the
+same rule: 3.1-3.8 us a pair in the loop, 1.5-2.1 in the kernel at
+longcat_flash_chat's and kimi_k2_6's shape, PERF.md section 6, PR 51);
 ``paged_attention_one_table`` — one table, many queries (every
 prefill): the chunk's rows as one group over the blocks the chunk can
 see, under one running softmax (``_attend_blocks``).
@@ -88,8 +96,12 @@ Layouts:
     seq_lens     [B]  int32     live tokens (this token included)
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
+
+from .paged_decode_attention import pair_attention
 
 _NEG_INF = -1e9
 
@@ -168,6 +180,17 @@ def pages_covered(lo, hi, n_pages, bs, xp=jnp):
     pages, the fill of the last included."""
     taken = BLOCK_ROWS * pages_per_block(n_pages, bs)
     return -(-pages_held(lo, hi, n_pages, bs, xp) // taken) * taken
+
+
+def pairs_form(platform, latent, quantized=False):
+    """The form ``paged_attention_blocked`` takes over its pair list, a
+    static rule of what the call is handed and the platform its program
+    is lowered for: 'kernel' (paged_decode_attention.py) for the latent
+    form over an unquantized arena on a TPU, 'loop' for everything
+    else. The engine counts a step's pairs under it
+    (``decode.attn_pairs``)."""
+    return 'kernel' if platform == 'tpu' and latent and not quantized \
+        else 'loop'
 
 
 def _block_products(q, arenas, layer, per, latent=None, expand=None):
@@ -431,6 +454,38 @@ def paged_attention_blocked(q, k_pages, v_pages, block_tables, seq_lens,
         b, n_blocks, per)[at, block]
     if chosen is not None:
         chosen = jnp.asarray(chosen).reshape(b, n_blocks, bk)
+    if pairs_form('tpu', latent, k_scales is not None) == 'kernel':
+        # the form follows the platform the program is lowered for
+        out = jax.lax.platform_dependent(
+            q, arenas, jnp.asarray(layer, jnp.int32), listed, tables,
+            ends[-1], chosen,
+            tpu=functools.partial(_pairs_by_kernel, per, latent),
+            default=functools.partial(_pairs_by_loop, per, latent))
+        # the kernel writes no row that holds no pair
+        return jnp.where((hi > lo)[:, None, None], out, 0.0)
+    return _pairs_by_loop(per, latent, q, arenas, layer, listed, tables,
+                          ends[-1], chosen)
+
+
+def _pairs_by_kernel(per, latent, q, arenas, layer, listed, tables, count,
+                     chosen):
+    """The list's pairs through the kernel, which takes a pair's ``goes``
+    as whether it closes its row and leaves a row that holds no pair
+    unwritten."""
+    return pair_attention(
+        q, arenas[0], layer,
+        listed.T.at[5].set(listed[:, 5] < q.shape[0]), tables.reshape(-1),
+        count, chosen, per=per, rank=latent)
+
+
+def _pairs_by_loop(per, latent, q, arenas, layer, listed, tables, count,
+                   chosen):
+    """The list's first ``count`` pairs, BLOCK_ROWS an iteration
+    (``paged_attention_blocked`` says how). ``listed`` [N, 6]: a pair's
+    row, block, bounds, whether it opens its row, and where its result
+    goes (its row if it closes it, else past the rows)."""
+    b, h, d = q.shape
+    bk = per * arenas[0].shape[2]
 
     def pairs(i, state):
         def mine(x):
@@ -478,14 +533,14 @@ def paged_attention_blocked(q, k_pages, v_pages, block_tables, seq_lens,
         return (row_top, row_norm, row_acc), out.at[goes].set(
             jnp.stack(done).reshape(BLOCK_ROWS, h, -1))
 
-    n_kv = k_pages.shape[-1] // d
+    n_kv = arenas[0].shape[-1] // d
     d_v = latent or d
     shape = (n_kv, h // n_kv, 1)
     row_state = (jnp.full(shape, _NEG_INF, jnp.float32),
                  jnp.zeros(shape, jnp.float32),
                  jnp.zeros(shape + (d_v,), jnp.float32))
     _, out = jax.lax.fori_loop(
-        0, -(-ends[-1] // BLOCK_ROWS), pairs,
+        0, -(-count // BLOCK_ROWS), pairs,
         (row_state, jnp.zeros((b + BLOCK_ROWS, h, d_v), jnp.float32)))
     return out[:b]
 

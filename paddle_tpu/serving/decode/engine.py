@@ -332,6 +332,12 @@ class DecodeEngine(object):
 
         self._scope = Scope()
         self._exe = Executor(place if place is not None else TPUPlace(0))
+        # the form a step's attention pairs run in, for the counters
+        from ...ops.pallas.paged_attention import pairs_form
+        from ...quant.core import kv_quantized
+        self._attn_form = pairs_form(
+            self._exe.place.jax_device().platform, bool(spec.latent),
+            kv_quantized(self.kv_dtype))
         with scope_guard(self._scope):
             self._exe.run(program=self._progs.startup)
         if weights:
@@ -1322,24 +1328,34 @@ class DecodeEngine(object):
 
     def _count_attn_pages(self, lens, rows, k1):
         """How far the attention's bounds engage, summed over the
-        layers: the pages this step's loops gather (``read``: its
-        (row, column block) pairs, eight an iteration, the fill of a
-        last iteration included), the pages those pairs hold (``held``:
-        the live rows' own column blocks, the least a blocked form
-        gathers), both from the functions of the lengths that give the
-        program its loop bounds (ops/pallas/paged_attention.py), beside
-        the pages its tables can address."""
-        from ...ops.pallas.paged_attention import pages_covered, pages_held
+        layers: the step's (row, column block) pairs by the form that
+        runs them (``pairs_form``: the kernel on a TPU for a latent
+        kind, else the loop), the pages they hold (``held``: the live
+        rows' own column blocks, the least a blocked form gathers) and
+        the pages the forms gather (``read``: the kernel's pairs as
+        they are; the loop's eight an iteration, the fill of a last
+        iteration included), all from the functions of the lengths
+        that give the program its bounds (ops/pallas/paged_attention.py),
+        beside the pages its tables can address."""
+        from ...ops.pallas.paged_attention import (
+            pages_covered, pages_held, pages_per_block)
         pos = (lens[:, None] + np.arange(k1, dtype='int32')).reshape(-1)
         live = (np.arange(len(pos)) < rows * k1) & (pos < self.capacity)
         hi = np.where(live, pos + 1, 0)
         read = held = 0
+        form = self._attn_form
+        per = pages_per_block(self.pages_per_seq, self.block_size)
         windows = self.spec.attn_windows()
         for window, layers in collections.Counter(windows).items():
             lo = np.maximum(hi - window, 0) if window else np.zeros_like(hi)
             bounds = (lo, hi, self.pages_per_seq, self.block_size, np)
-            read += layers * int(pages_covered(*bounds))
-            held += layers * int(pages_held(*bounds))
+            mine = layers * int(pages_held(*bounds))
+            held += mine
+            read += mine if form == 'kernel' \
+                else layers * int(pages_covered(*bounds))
+        for which in ('kernel', 'loop'):
+            _obs.inc('decode.attn_pairs',
+                     held // per if which == form else 0, form=which)
         _obs.inc('decode.attn_pages_read', read)
         _obs.inc('decode.attn_pages_held', held)
         _obs.inc('decode.attn_pages_reachable',

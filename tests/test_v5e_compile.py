@@ -316,6 +316,30 @@ def test_no_serving_program_gathers_a_whole_table(one_chip, engine, which):
     assert max(i.elements for i in gathers) <= pa.BLOCK_ROWS * per * page
 
 
+def test_a_decode_program_traces_one_kernel_a_head_shape(engine,
+                                                         monkeypatch):
+    """What a warm start pays for the kernel, held by a count and not by
+    a clock: building a decode program, the kernel's body is traced
+    once for each latent kind's head shape, not once a call site (the
+    lead layers one by one and the scanned periods hand ``layer`` as an
+    operand to one jitted wrapper), and not at all where no layer is
+    latent."""
+    from paddle_tpu.ops.pallas import paged_decode_attention as kernel
+    traces = []
+    body = kernel._kernel
+    monkeypatch.setattr(kernel, '_kernel', lambda *refs, **static: (
+        traces.append(static), body(*refs, **static))[1])
+    kernel._pair_attention.clear_cache()
+    spec = engine.spec
+    lead, period, n_periods, tail = spec.layer_plan()
+    sites = len(lead) + len(period) * bool(n_periods) + len(tail)
+    engine.trace_program('decode')
+    kernel._pair_attention.clear_cache()
+    assert len(traces) == len(spec.latent)
+    if spec.latent and lead and n_periods:
+        assert sites * spec.sublayers > len(traces)
+
+
 def _while_bodies(hlo):
     """{computation name: its lines} of the computations some ``while``
     runs as its body."""
@@ -631,6 +655,50 @@ def _paged_op(spec, geometry, op, rows):
     return fn, (weights, arenas, feeds)
 
 
+def _published(cell):
+    """(spec, engine geometry) of a cell as the benchmark runs it."""
+    import os
+    from benchmark import manifest
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    resolved = manifest.resolve(manifest.load(root), cell)
+    config = {k: v for k, v in resolved['config'].items()
+              if k != 'rehearsal'}
+    return (manifest.load_module(resolved['runner']).spec_of(config),
+            config['engine'])
+
+
+def _compiled_at_published_size(one_chip, spec, geometry, op, rows):
+    """``op`` over ``rows`` compiled for the v5e, the arenas donated as
+    the executor donates them: (its HLO, the weights' bytes, the arenas'
+    bytes), the argument bytes held to their sum and the whole program
+    to the chip's 15.75 GiB."""
+    import math
+    fn, shapes = _paged_op(spec, geometry, op, rows)
+    args = [{slot: _shaped(one_chip, shape, dtype)
+             for slot, (shape, dtype) in group.items()}
+            for group in shapes]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    memory = compiled.memory_analysis()
+    weights_b, arena_b = (
+        sum(jnp.dtype(d).itemsize * math.prod(s)
+            for s, d in group.values()) for group in shapes[:2])
+    assert abs(memory.argument_size_in_bytes
+               - weights_b - arena_b) < 1 << 20, op
+    # the arenas are aliased to their outputs, and what the program
+    # keeps beside its arguments fits the chip with them
+    assert memory.alias_size_in_bytes >= arena_b
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes \
+        < 15.75 * (1 << 30), op
+    return compiled.as_text(), weights_b, arena_b
+
+
+def _kernels(hlo, name):
+    return [line for line in _outside_fusions(hlo)
+            if 'custom_call_target="tpu_custom_call"' in line
+            and '%' + name in line]
+
+
 def test_the_shortcut_block_loads_at_its_published_geometry(one_chip):
     """longcat_flash_chat as the benchmark runs it (every published
     width, layers 0-3, 16 of 512 experts, 1/8 vocabulary; 64 slots,
@@ -639,46 +707,51 @@ def test_the_shortcut_block_loads_at_its_published_geometry(one_chip):
     it, with the argument bytes the configuration states: 10.35 GB of
     weights + 2.68 GB of arena = 13.0 GB, and the whole program under
     the chip's 15.75 GiB. One moe_routed_product kernel a program (in the
-    layers' scan) whose operands are the stacked experts; the arena
-    stays row-major with eight cache layers."""
-    import math
-    import os
+    layers' scan) whose operands are the stacked experts; in the decode
+    step the layer's two attentions are two paged_decode_attention
+    kernels over the arena, which stays row-major with eight cache
+    layers, and a chunk's attention stays the one-table loop."""
     from paddle_tpu.serving.decode import LMSpec
-    from benchmark import manifest
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    resolved = manifest.resolve(manifest.load(root),
-                                'longcat_flash_chat.chat_decode_heavy')
-    config = {k: v for k, v in resolved['config'].items()
-              if k != 'rehearsal'}
-    spec = manifest.load_module(resolved['runner']).spec_of(config)
+    spec, geometry = _published('longcat_flash_chat.chat_decode_heavy')
     assert isinstance(spec, LMSpec) and spec.d_model == 6144
-    geometry = config['engine']
     weights_b = arena_b = None
     for op, rows in (('paged_decode_step', geometry['max_batch']),
                      ('paged_prefill', geometry['prefill_chunk'])):
-        fn, shapes = _paged_op(spec, geometry, op, rows)
-        args = [{slot: _shaped(one_chip, shape, dtype)
-                 for slot, (shape, dtype) in group.items()}
-                for group in shapes]
-        compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
-        memory = compiled.memory_analysis()
-        weights_b, arena_b = (
-            sum(jnp.dtype(d).itemsize * math.prod(s)
-                for s, d in group.values()) for group in shapes[:2])
-        assert abs(memory.argument_size_in_bytes
-                   - weights_b - arena_b) < 1 << 20, op
-        # the arena is aliased to its output, and what the program keeps
-        # beside its arguments fits the chip with them
-        assert memory.alias_size_in_bytes >= arena_b
-        assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
-            + memory.output_size_in_bytes - memory.alias_size_in_bytes \
-            < 15.75 * (1 << 30), op
-        hlo = compiled.as_text()
+        hlo, weights_b, arena_b = _compiled_at_published_size(
+            one_chip, spec, geometry, op, rows)
         assert set(re.findall(r'bf16\[8,8192,32,640\]\{([\d,]+)', hlo)) \
             == {'3,2,1,0'}, op
-        kernels = [line for line in _outside_fusions(hlo)
-                   if 'custom_call_target="tpu_custom_call"' in line]
-        assert len(kernels) == 1 and '%moe_routed_product' in kernels[0], op
-        assert 'bf16[4,16,6144,2048]' in kernels[0]
+        kernels = _kernels(hlo, 'moe_routed_product')
+        assert len(kernels) == 1 and 'bf16[4,16,6144,2048]' in kernels[0], op
+        attends = _kernels(hlo, 'paged_decode_attention')
+        assert len(attends) == (2 if op == 'paged_decode_step' else 0), op
+        assert all('bf16[8,8192,32,640]' in line for line in attends)
     assert round(weights_b / 1e9, 2) == 10.35
     assert round(arena_b / 1e9, 2) == 2.68
+
+
+@pytest.mark.parametrize('cell,arenas,calls', [
+    # the lead layer's call and the scanned layers' are the one kernel
+    ('kimi_k2_6.doc_qa_sessions', ['bf16[6,16384,32,640]'], 2),
+    # a full layer's (chosen columns) and a sliding layer's (a lower
+    # bound, another head shape) in the lead, the period and the tail
+    ('dots3_note.long_ctx_steady',
+     ['bf16[2,12288,32,640]', 'bf16[3,12288,32,1152]'], None),
+])
+def test_a_latent_decode_step_attends_by_the_kernel_at_published_size(
+        one_chip, cell, arenas, calls):
+    """The other two latent cells' decode steps as the benchmark runs
+    them compile for the v5e with the argument bytes their
+    configurations state, their attention the paged_decode_attention
+    kernel over each latent arena where it lies."""
+    spec, geometry = _published(cell)
+    hlo, _, _ = _compiled_at_published_size(
+        one_chip, spec, geometry, 'paged_decode_step',
+        geometry['max_batch'])
+    attends = _kernels(hlo, 'paged_decode_attention')
+    for arena in arenas:
+        assert any(arena in line for line in attends), arena
+        assert set(re.findall(re.escape(arena) + r'\{([\d,]+)', hlo)) \
+            == {'3,2,1,0'}, arena
+    if calls:
+        assert len(attends) == calls
