@@ -42,7 +42,7 @@ import jax.numpy as jnp
 
 from . import moe_held_ops as moe
 from .latent_moe_ops import _at, rms_norm
-from .paged_decode_ops import (_attention_of, _mm, _write_in_place,
+from .paged_decode_ops import (_attention_of, _mm, _mm_t, _write_in_place,
                                period_segments)
 
 FULL, SLIDING = 'full_attention', 'sliding_attention'
@@ -113,10 +113,7 @@ class GqaMoEBlock(object):
         return jnp.take(self.emb, tokens, axis=0).astype(jnp.float32)
 
     def logits(self, h):
-        y = rms_norm(h, self.final_ln, self.eps).astype(self.head.dtype)
-        return jax.lax.dot_general(
-            y, self.head, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        return _mm_t(rms_norm(h, self.final_ln, self.eps), self.head)
 
     # ---------------------------------------------------- the layer loop
     def segments(self, step):
@@ -131,10 +128,8 @@ class GqaMoEBlock(object):
         n1 = rms_norm(h, w['Ln1W'], self.eps)
         d = w['SlfQ'].shape[0] // self.n_head
         # the query projection is kept transposed (gqa_param_shapes)
-        q = jax.lax.dot_general(
-            n1.astype(w['SlfQ'].dtype), w['SlfQ'], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        q = rope_half_at(q.reshape(rows, -1, d), pos, self.freq[kind])
+        q = rope_half_at(_mm_t(n1, w['SlfQ']).reshape(rows, -1, d), pos,
+                         self.freq[kind])
         k = rope_half_at(_mm(n1, w['SlfK']).reshape(rows, -1, d), pos,
                          self.freq[kind])
         a = self.arena_of[kind]

@@ -10,7 +10,9 @@ cache row is ``[c_kv ; k_rope]``: ``c_kv = s_kv RMSNorm(x W_kva[:, :r])``
 and ``k_rope`` the last ``d_rope`` columns of ``x W_kva`` rotated
 (interleaved pairs); one row a token a layer, which every head reads.
 Queries go through a rank-``q_rank`` bottleneck, ``c_q = s_q RMSNorm(x
-W_qa)``, ``[q_nope ; q_rope]_h = c_q W_qb``.
+W_qa)``, ``[q_nope ; q_rope]_h = c_q W_qb`` (``W_qb`` and the indexer's
+``W^I_q`` are held ``[out, q_rank]`` and contracted over their last
+axis: ``latent_param_shapes``).
 
 The attention takes one of two forms, by how many query rows share a
 block table (``serving/decode/model.py``: ``latent_expands``, a function
@@ -106,7 +108,7 @@ import jax.numpy as jnp
 
 from ..serving.decode.model import latent_expands
 from . import moe_held_ops as moe
-from .paged_decode_ops import (_attention_of, _mm, _rope_gptj_at,
+from .paged_decode_ops import (_attention_of, _mm, _mm_t, _rope_gptj_at,
                                _write_in_place, period_segments)
 from .pallas.paged_attention import (paged_attention_one_table,
                                      pages_per_block)
@@ -269,10 +271,7 @@ class LatentMoEBlock(object):
         return jnp.take(self.emb, tokens, axis=0).astype(jnp.float32)
 
     def logits(self, h):
-        y = rms_norm(h, self.final_ln, self.eps).astype(self.head.dtype)
-        return jax.lax.dot_general(
-            y, self.head, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        return _mm_t(rms_norm(h, self.final_ln, self.eps), self.head)
 
     # ---------------------------------------------------- the layer loop
     def segments(self, step):
@@ -311,7 +310,9 @@ class LatentMoEBlock(object):
         s_kv = (d_model / rank) ** 0.5 if self.rescale else 1.0
 
         c_q = rms_norm(_mm(n, w['QA']), w['QLn'], self.eps) * s_q
-        q = _mm(c_q, w['QB']).reshape(rows, heads, d_nope + d_rope)
+        # the projections out of the query's rank are held transposed
+        # (latent_param_shapes)
+        q = _mm_t(c_q, w['QB']).reshape(rows, heads, d_nope + d_rope)
         down = _mm(n, w['KvA'])
         c_kv = rms_norm(down[:, :rank], w['KvLn'], self.eps) * s_kv
         k_rope = turned(down[:, None, rank:])[:, 0]
@@ -376,7 +377,7 @@ class LatentMoEBlock(object):
         rows' own index keys [N, Di]), float32."""
         w = {slot: _at(self.w[slot], i) for slot in _INDEX}
         rows, heads = n.shape[0], self.index_heads
-        q = _mm(c_q, w['IdxQ']).reshape(rows, heads, -1)
+        q = _mm_t(c_q, w['IdxQ']).reshape(rows, heads, -1)
         q = jnp.concatenate([rope_half(q[..., :d_rope], pos, theta),
                              q[..., d_rope:]], -1)
         k = _mm(n, w['IdxK'])

@@ -299,6 +299,15 @@ def _mm(x, w):
                       preferred_element_type=jnp.float32)
 
 
+def _mm_t(x, w):
+    """``_mm`` with a weight held transposed, ``[out, in]``: contracted
+    over its last axis, which is where the v5e reads it (serving/decode/
+    model.py: ``HeldTransposed``, ``gqa_param_shapes``)."""
+    return jax.lax.dot_general(
+        x.astype(w.dtype), w, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
 def _rope_gptj(x, pos, theta):
     """x [N, heads, D] float32 at positions ``pos`` [N]: interleaved
     pairs (2i, 2i+1) turned by pos * theta^(-2i/D)."""
@@ -403,10 +412,8 @@ class _ParallelMoEBlock(_UniformBlock):
         return h + a + m, moe.load_stats(hit, valid)
 
     def logits(self, h):
-        y = self._norm(h, self.final_ln).astype(self.emb.dtype)
-        return jax.lax.dot_general(
-            y, self.emb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * self.logit_scale
+        return _mm_t(self._norm(h, self.final_ln), self.emb) \
+            * self.logit_scale
 
 
 def _block_of(ctx):

@@ -42,7 +42,7 @@ import jax.numpy as jnp
 
 from . import ssm_ops
 from .latent_moe_ops import _at, rms_norm
-from .paged_decode_ops import (_attention_of, _mm, _write_in_place,
+from .paged_decode_ops import (_attention_of, _mm, _mm_t, _write_in_place,
                                period_segments)
 
 MAMBA, ATTENTION = 'mamba', 'attention'
@@ -98,10 +98,8 @@ class SsmHybridBlock(object):
             * self.embed_scale
 
     def logits(self, h):
-        y = rms_norm(h, self.final_ln, self.eps).astype(self.emb.dtype)
-        return jax.lax.dot_general(
-            y, self.emb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * self.logit_scale
+        return _mm_t(rms_norm(h, self.final_ln, self.eps), self.emb) \
+            * self.logit_scale
 
     # ---------------------------------------------------- the layer loop
     def segments(self, step):

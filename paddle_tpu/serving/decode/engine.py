@@ -160,7 +160,7 @@ from ...core.scope import Scope, scope_guard
 from ..buckets import pow2_ladder
 from ..engine import EngineClosedError, QueueFullError
 from .kv_pool import KVPool
-from .model import FULL, LMSpec, build_lm_programs
+from .model import FULL, LMSpec, build_lm_programs, held_transposed
 from .prefix_cache import PrefixCache, prefix_cache_enabled
 from .scheduler import RUNNING, Scheduler, Sequence
 from .spec import NgramDraft, accept_drafts, spec_k_from_env
@@ -182,6 +182,14 @@ def _merge_tokens(prev, src, host):
     import jax.numpy as jnp
     return jnp.where(src >= 0, prev[jnp.maximum(src, 0)],
                      host.astype(prev.dtype))
+
+
+def _swapped(arr):
+    """``arr`` with its last two axes swapped: a parameter's held layout
+    from its declared one and back (``model.HeldTransposed``)."""
+    import jax.numpy as jnp
+    return jnp.swapaxes(arr, -1, -2)
+
 
 # the worker thread's four states: their spans feed one histogram whose
 # label sums partition the thread's wall time between start() and
@@ -332,6 +340,10 @@ class DecodeEngine(object):
 
         self._scope = Scope()
         self._exe = Executor(place if place is not None else TPUPlace(0))
+        # the parameters held with their last two axes swapped, and the
+        # swap on the way in (load_weights), which donates what it is given
+        self._transposed = held_transposed(spec)
+        self._swap_in = jax.jit(_swapped, donate_argnums=0)
         # the form a step's attention pairs run in, for the counters
         from ...ops.pallas.paged_attention import pairs_form
         from ...quant.core import kv_quantized
@@ -415,9 +427,14 @@ class DecodeEngine(object):
     # ----------------------------------------------------------- weights
     def load_weights(self, weights):
         """Install a {param name: array} dict (names per
-        model.DecodePrograms.param_names). Each parameter keeps the
-        dtype it was declared with; a jax array is cast on its device
-        and never copied through the host."""
+        model.DecodePrograms.param_names), each array in its parameter's
+        declared layout (``model.block_param_shapes``). Each parameter
+        keeps the dtype it was declared with; a jax array is cast on its
+        device and never copied through the host, and is the engine's
+        from here on (the programs donate what the scope holds). A
+        parameter held transposed (``model.held_transposed``) has its
+        last two axes swapped on the device, once, in a call that donates
+        the array it was given: no second copy of it stays behind."""
         import jax
         import jax.numpy as jnp
         unknown = sorted(set(weights) - set(self._progs.param_names))
@@ -427,18 +444,31 @@ class DecodeEngine(object):
         for name, arr in weights.items():
             dtype = self._scope.get(name).dtype
             if isinstance(arr, jax.Array):
-                self._scope.set(name, arr.astype(dtype))
+                arr = arr.astype(dtype)
             else:
-                self._scope.set(name, jnp.asarray(np.asarray(arr), dtype))
+                arr = jnp.asarray(np.asarray(arr), dtype)
+            if name in self._transposed:
+                arr = self._swap_in(arr)
+            self._scope.set(name, arr)
 
     def export_weights(self):
-        return {n: self._scope.numpy(n) for n in self._progs.param_names}
+        """{param name: numpy array} in the declared layout (a parameter
+        held transposed as a view with its axes swapped back)."""
+        return {n: np.swapaxes(self._scope.numpy(n), -1, -2)
+                if n in self._transposed else self._scope.numpy(n)
+                for n in self._progs.param_names}
 
     def device_weights(self):
-        """{param name: the array the programs read}, where it lives:
-        nothing is copied. The arrays are replaced, not written, by
-        ``load_weights``."""
-        return {n: self._scope.get(n) for n in self._progs.param_names}
+        """{param name: the parameter on its device, in the declared
+        layout}. A parameter held as declared is the array the programs
+        read, where it lives, and nothing is copied; one held transposed
+        (``model.held_transposed``: the latent blocks' ``q_b`` and
+        ``idx_q``) is swapped back into a new array at each call, the
+        only arrays this copies, so keep the dict no longer than it is
+        read. The arrays are replaced, not written, by ``load_weights``."""
+        return {n: _swapped(self._scope.get(n))
+                if n in self._transposed else self._scope.get(n)
+                for n in self._progs.param_names}
 
     # ------------------------------------------------------------ intake
     def submit(self, prompt_ids, max_new_tokens=16, temperature=0.0,

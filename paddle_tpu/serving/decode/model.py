@@ -34,7 +34,12 @@ Scope, all sharing one parameter namespace (prefix ``lm_``):
 
 A scope trained elsewhere can be served by passing its weights to
 ``DecodeEngine(weights=...)`` — names here are stable and listed in
-``DecodePrograms.param_names``.
+``DecodePrograms.param_names``, shapes are the declared ones of
+``block_param_shapes``. A few parameters live in the scope with their
+last two axes swapped (``HeldTransposed``); the engine's
+``load_weights`` / ``export_weights`` / ``device_weights`` swap them on
+the way in and out, so only code that reads the scope itself sees the
+held layout.
 """
 
 import collections
@@ -927,9 +932,36 @@ def moe_param_shapes(spec):
     ])
 
 
+class HeldTransposed(list):
+    """The declared shape ``[..., a, b]`` of a parameter that the
+    programs hold as ``[..., b, a]``. The declared shape is what
+    ``load_weights``, ``export_weights``, ``device_weights``,
+    ``random_weights`` and every draw by the table see; ``held`` is the
+    shape the parameter is created with and the ops multiply
+    (``_moe_params``, ``ops/paged_decode_ops.py::_mm_t``), and the
+    engine swaps the two axes once, on the device, on the way in
+    (``DecodeEngine.load_weights``)."""
+
+    @property
+    def held(self):
+        return list(self[:-2]) + [self[-1], self[-2]]
+
+
+def held_transposed(spec):
+    """The names of a block's weights that the programs hold with their
+    last two axes swapped (``HeldTransposed`` entries of its table)."""
+    if spec.block == 'post_ln':
+        return frozenset()
+    return frozenset(
+        name for name, (shape, _, _) in block_param_shapes(spec).items()
+        if isinstance(shape, HeldTransposed))
+
+
 def _latent_attention_shapes(spec, kind, tag, slot, n):
     """The eight attention entries of ``n`` stacked latent attentions of
-    ``kind`` (``latent_param_shapes`` has the layouts and the fan-ins)."""
+    ``kind`` (``latent_param_shapes`` has the layouts and the fan-ins).
+    ``q_b`` is held transposed, ``[n, heads x (nope + rope), q_rank]``:
+    ``latent_param_shapes`` says why."""
     a, d = spec.latent[kind], spec.d_model
     qk = a.d_nope + a.d_rope
     from_q = d if spec.lora_rescale else a.q_rank
@@ -937,7 +969,7 @@ def _latent_attention_shapes(spec, kind, tag, slot, n):
     return [
         ('lm_%s_q_a.w' % tag, ([n, d, a.q_rank], d, slot + 'QA')),
         ('lm_%s_q_ln.w' % tag, ([n, a.q_rank], None, slot + 'QLn')),
-        ('lm_%s_q_b.w' % tag, ([n, a.q_rank, a.n_head * qk],
+        ('lm_%s_q_b.w' % tag, (HeldTransposed([n, a.q_rank, a.n_head * qk]),
                                from_q, slot + 'QB')),
         ('lm_%s_kv_a.w' % tag, ([n, d, a.row_width], d, slot + 'KvA')),
         ('lm_%s_kv_ln.w' % tag, ([n, a.kv_rank], None, slot + 'KvLn')),
@@ -1002,7 +1034,23 @@ def latent_param_shapes(spec):
     (drawn by the rank, scores have a deviation of 6 at the published
     widths, every head attends to one key, and the 0.5% of the selected
     set that bfloat16 flips at the selection's boundary moves the
-    logits by 0.4 rms: PERF.md section 6, PR 34)."""
+    logits by 0.4 rms: PERF.md section 6, PR 34).
+
+    **Declared layout, held layout.** The two matrices that project out
+    of the query's rank, ``q_b`` of each kind and the indexer's
+    ``idx_q``, are declared ``[n, q_rank, out]`` (what weights are loaded
+    and handed back as) and held ``[n, out, q_rank]``
+    (``HeldTransposed``), contracted over their last axis. Held as
+    declared, the v5e's compiler wanted the rank minor and re-laid the
+    matrix before it multiplied: longcat_flash_chat's whole stack at
+    the entry of every program (``copy bf16[8,1536,12288]{1,2,0}``, 302
+    MB read and written: 0.173 s of a 3 s traced tail, the cell's
+    second-longest device op; ledger, PR 51), kimi_k2_6's 37.7 MB a
+    layer and 235 MB of dots3_note's step (compiled for the v5e at the
+    published geometry, PERF.md section 6, PR 52). gqa_moe's query
+    projection is kept transposed for the same reason
+    (``gqa_param_shapes``); these keep their declared shape because the
+    references read it."""
     L, d, f = spec.n_layer, spec.d_model, spec.d_inner
     e, sh = spec.experts_held, spec.n_shared_experts
     n_dense, n_moe = spec.dense_layers, spec.n_layer - spec.dense_layers
@@ -1024,8 +1072,8 @@ def latent_param_shapes(spec):
         if kind == FULL and spec.index_topk:
             hi, di = spec.index_n_heads, spec.index_head_dim
             out.update([
-                ('lm_full_idx_q.w', ([n, a.q_rank, hi * di], from_q,
-                                     'IdxQ')),
+                ('lm_full_idx_q.w', (HeldTransposed([n, a.q_rank, hi * di]),
+                                     from_q, 'IdxQ')),
                 ('lm_full_idx_k.w', ([n, d, di], d, 'IdxK')),
                 ('lm_full_idx_k_ln.w', ([n, di], None, 'IdxKLnW')),
                 ('lm_full_idx_k_ln.b', ([n, di], 0, 'IdxKLnB')),
@@ -1150,7 +1198,8 @@ def _moe_params(spec):
         init = Constant(1.0) if fan_in is None else \
             Constant(0.0) if fan_in == 0 else Normal(0., fan_in ** -0.5)
         inputs[slot] = [layers.create_parameter(
-            shape=shape, dtype=spec.dtype if fan_in else 'float32',
+            shape=shape.held if isinstance(shape, HeldTransposed) else shape,
+            dtype=spec.dtype if fan_in else 'float32',
             name=name, attr=ParamAttr(name=name, initializer=init))]
     return inputs
 

@@ -31,6 +31,7 @@ from paddle_tpu.ops import moe_held_ops as moe
 from paddle_tpu.ops import paged_decode_ops as pdo
 from paddle_tpu.serving.decode import DecodeEngine, LMSpec, random_weights
 from paddle_tpu.serving.decode import model as lm
+from util import as_held, weights_round_trip
 
 TOL = 5e-5
 BS, PAGES, NB = 4, 12, 40            # 48 positions a sequence
@@ -72,8 +73,10 @@ class _Ctx(object):
         self._attrs = lm._block_attrs(spec, BS)
         self.env = {}
         slots = {}
+        # an op reads a weight as the programs hold it
+        held = as_held(spec, weights)
         for name, (_, _, slot) in lm.block_param_shapes(spec).items():
-            self.env[name] = jnp.asarray(weights[name])
+            self.env[name] = held[name]
             slots[slot] = name
         self.op = _Op(slots)
 
@@ -667,3 +670,30 @@ def test_programs_write_every_arena_in_place():
             assert arena_sized_instructions(hlo, smallest) == []
     finally:
         eng.shutdown(drain=False)
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+def test_weights_go_in_and_come_out_in_the_declared_layout(dtype):
+    """``q_b`` of both kinds and the indexer's ``idx_q`` are held ``[n,
+    out, q_rank]`` (model.HeldTransposed) and loaded, exported and
+    handed out on the device as declared, ``[n, q_rank, out]``, bit for
+    bit; every other parameter is the array the programs read
+    (util.weights_round_trip)."""
+    weights_round_trip(
+        _spec(dtype=dtype), WEIGHTS,
+        {'lm_full_q_b.w', 'lm_swa_q_b.w', 'lm_full_idx_q.w'},
+        max_batch=2, block_size=BS, num_blocks=NB, pages_per_seq=PAGES)
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+def test_a_product_with_a_held_matrix_is_the_declared_product(dtype):
+    """``_mm_t`` over a matrix held ``[out, in]`` is ``_mm`` over the
+    declared ``[in, out]``: rows at the weight's dtype, the same
+    contraction, accumulated in float32 (to the order of the sum)."""
+    rng = np.random.RandomState(3)
+    x = jnp.asarray(rng.randn(7, 16), jnp.float32)
+    w = jnp.asarray(rng.randn(16, 48) / 4, dtype)
+    got, want = pdo._mm_t(x, w.T), pdo._mm(x, w)
+    assert got.dtype == want.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)

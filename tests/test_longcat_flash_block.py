@@ -31,6 +31,7 @@ from paddle_tpu.ops import paged_decode_ops as pdo
 from paddle_tpu.ops.shortcut_moe_ops import ShortcutMoEBlock
 from paddle_tpu.serving.decode import DecodeEngine, LMSpec, random_weights
 from paddle_tpu.serving.decode import model as lm
+from util import as_held, weights_round_trip
 
 TOL = 5e-5
 BS, PAGES, NB = 4, 16, 64            # 64 positions a sequence
@@ -72,8 +73,10 @@ class _Ctx(object):
         self._attrs = lm._block_attrs(spec, BS)
         self.env = {}
         slots = {}
+        # an op reads a weight as the programs hold it
+        held = as_held(spec, weights)
         for name, (_, _, slot) in lm.block_param_shapes(spec).items():
-            self.env[name] = jnp.asarray(weights[name])
+            self.env[name] = held[name]
             slots[slot] = name
         self.op = _Op(slots)
 
@@ -503,3 +506,14 @@ def test_programs_write_the_arena_in_place():
             assert arena_sized_instructions(hlo, smallest) == []
     finally:
         eng.shutdown(drain=False)
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+def test_weights_go_in_and_come_out_in_the_declared_layout(dtype):
+    """``q_b`` of the sublayers' stack is held ``[n, out, q_rank]``
+    (model.HeldTransposed) and loaded, exported and handed out on the
+    device as declared, ``[n, q_rank, out]``, bit for bit; every other
+    parameter is the array the programs read (util.weights_round_trip)."""
+    weights_round_trip(
+        _spec(dtype=dtype), WEIGHTS, {'lm_full_q_b.w'},
+        max_batch=2, block_size=BS, num_blocks=NB, pages_per_seq=PAGES)

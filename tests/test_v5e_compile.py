@@ -628,7 +628,9 @@ def _paged_op(spec, geometry, op, rows):
     from paddle_tpu.serving.decode import model as lm
     import paddle_tpu.ops.paged_decode_ops  # noqa: F401  (registers)
     attrs = lm._block_attrs(spec, geometry['block_size'])
-    weights = {slot: (tuple(shape), spec.dtype if fan_in else 'float32')
+    # a weight at the shape the programs hold it in
+    weights = {slot: (tuple(getattr(shape, 'held', shape)),
+                      spec.dtype if fan_in else 'float32')
                for shape, fan_in, slot in
                lm.block_param_shapes(spec).values()}
     arenas = {k.slot: ((len(k.layers), geometry['num_blocks'])
@@ -693,6 +695,23 @@ def _compiled_at_published_size(one_chip, spec, geometry, op, rows):
     return compiled.as_text(), weights_b, arena_b
 
 
+_PUBLISHED = {}
+
+
+def _published_program(one_chip, cell, op):
+    """(spec, geometry, HLO, weights' bytes, arenas' bytes) of a cell's
+    decode step over its slots or prefill of its longest chunk
+    (``_compiled_at_published_size``), compiled once for the tests that
+    read it."""
+    if (cell, op) not in _PUBLISHED:
+        spec, geometry = _published(cell)
+        rows = geometry['max_batch' if op == 'paged_decode_step'
+                        else 'prefill_chunk']
+        _PUBLISHED[cell, op] = (spec, geometry) + \
+            _compiled_at_published_size(one_chip, spec, geometry, op, rows)
+    return _PUBLISHED[cell, op]
+
+
 def _kernels(hlo, name):
     return [line for line in _outside_fusions(hlo)
             if 'custom_call_target="tpu_custom_call"' in line
@@ -712,13 +731,11 @@ def test_the_shortcut_block_loads_at_its_published_geometry(one_chip):
     kernels over the arena, which stays row-major with eight cache
     layers, and a chunk's attention stays the one-table loop."""
     from paddle_tpu.serving.decode import LMSpec
-    spec, geometry = _published('longcat_flash_chat.chat_decode_heavy')
-    assert isinstance(spec, LMSpec) and spec.d_model == 6144
     weights_b = arena_b = None
-    for op, rows in (('paged_decode_step', geometry['max_batch']),
-                     ('paged_prefill', geometry['prefill_chunk'])):
-        hlo, weights_b, arena_b = _compiled_at_published_size(
-            one_chip, spec, geometry, op, rows)
+    for op in ('paged_decode_step', 'paged_prefill'):
+        spec, _, hlo, weights_b, arena_b = _published_program(
+            one_chip, 'longcat_flash_chat.chat_decode_heavy', op)
+        assert isinstance(spec, LMSpec) and spec.d_model == 6144
         assert set(re.findall(r'bf16\[8,8192,32,640\]\{([\d,]+)', hlo)) \
             == {'3,2,1,0'}, op
         kernels = _kernels(hlo, 'moe_routed_product')
@@ -744,10 +761,7 @@ def test_a_latent_decode_step_attends_by_the_kernel_at_published_size(
     them compile for the v5e with the argument bytes their
     configurations state, their attention the paged_decode_attention
     kernel over each latent arena where it lies."""
-    spec, geometry = _published(cell)
-    hlo, _, _ = _compiled_at_published_size(
-        one_chip, spec, geometry, 'paged_decode_step',
-        geometry['max_batch'])
+    hlo = _published_program(one_chip, cell, 'paged_decode_step')[2]
     attends = _kernels(hlo, 'paged_decode_attention')
     for arena in arenas:
         assert any(arena in line for line in attends), arena
@@ -755,3 +769,39 @@ def test_a_latent_decode_step_attends_by_the_kernel_at_published_size(
             == {'3,2,1,0'}, arena
     if calls:
         assert len(attends) == calls
+
+
+@pytest.mark.parametrize('op', ['paged_decode_step', 'paged_prefill'])
+@pytest.mark.parametrize('cell', [
+    'longcat_flash_chat.chat_decode_heavy', 'kimi_k2_6.doc_qa_sessions',
+    'dots3_note.long_ctx_steady'])
+def test_no_latent_program_relays_a_projection_out_of_the_query_rank(
+        one_chip, cell, op):
+    """The three latent cells' decode step and 512 chunk as the benchmark
+    runs them, compiled for the v5e with ``q_b`` of every kind and the
+    indexer's ``idx_q`` held ``[n, out, q_rank]``
+    (``model.HeldTransposed``): outside fused computations no ``copy``
+    or ``transpose`` writes one of those stacks or one layer's slice of
+    it, in any order of its axes. Held as declared, ``[n, q_rank,
+    out]``, the compiler re-laid them before it multiplied (PERF.md,
+    PR 52): longcat's whole stack at each program's entry (``copy
+    bf16[8,1536,12288]{1,2,0}``, 302 MB), kimi's ``bf16[1,1536,12288]``
+    in the scan and ``[12288,1536]`` in the lead layer, dots3's
+    ``[1,1024,24576]``, ``[16384,1024]`` and the indexer's
+    ``[8192,1024]``."""
+    from paddle_tpu.serving.decode import model as lm
+    spec, _, hlo, _, _ = _published_program(one_chip, cell, op)
+    table = lm.block_param_shapes(spec)
+    marked = lm.held_transposed(spec)
+    assert marked and all(n.endswith(('_q_b.w', '_idx_q.w')) for n in marked)
+
+    def extents(dims):
+        return tuple(sorted(int(d) for d in dims if int(d) != 1))
+    # a stack's extents or one layer's, in whatever order
+    theirs = set()
+    for name in marked:
+        theirs |= {extents(table[name][0]), extents(table[name][0][1:])}
+    relaid = [(kind, shape) for kind, shape, _ in _materialized(hlo, 1 << 22)
+              if kind in ('copy', 'transpose') and extents(
+                  shape.split('[')[1].rstrip(']').split(',')) in theirs]
+    assert relaid == [], relaid

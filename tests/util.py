@@ -18,3 +18,64 @@ def rand(*shape, dtype='float32', seed=None, low=None, high=None):
     return rng.uniform(low if low is not None else -1.0,
                        high if high is not None else 1.0,
                        shape).astype(dtype)
+
+
+def as_held(spec, weights):
+    """``weights`` ({name: array}, declared layout) as jax arrays in the
+    layout the programs hold them in (model.HeldTransposed): what an op
+    driven without an engine is fed."""
+    import jax.numpy as jnp
+    from paddle_tpu.serving.decode import model as lm
+    swapped = lm.held_transposed(spec)
+    return {name: jnp.swapaxes(jnp.asarray(w), -1, -2) if name in swapped
+            else jnp.asarray(w) for name, w in weights.items()}
+
+
+def weights_round_trip(spec, weights, marked, **engine_kw):
+    """``weights`` ({name: float32 array}, declared layout) loaded into
+    a DecodeEngine of ``spec``: ``export_weights`` and ``device_weights``
+    hand every parameter back in its declared shape, bit for bit what
+    was loaded at the declared dtype; the parameters named ``marked``
+    are held with their last two axes swapped (model.HeldTransposed)
+    and every other is handed out as the array the programs read; a jax
+    array given for a marked parameter is donated to the swap; what was
+    exported loads again to the same."""
+    import jax.numpy as jnp
+    from paddle_tpu.serving.decode import DecodeEngine
+    from paddle_tpu.serving.decode import model as lm
+    assert lm.held_transposed(spec) == set(marked)
+    eng = DecodeEngine(spec, weights=weights, place=fluid.CPUPlace(),
+                       **engine_kw)
+    try:
+        table = lm.block_param_shapes(spec)
+        assert sorted(table) == sorted(eng.device_weights())
+
+        def same_as_loaded(out):
+            for name, (shape, fan_in, _) in table.items():
+                want = jnp.asarray(weights[name]).astype(
+                    spec.dtype if fan_in else 'float32')
+                assert out[name].shape == tuple(shape), name
+                assert str(out[name].dtype) == str(want.dtype), name
+                np.testing.assert_array_equal(
+                    np.asarray(out[name]), np.asarray(want), err_msg=name)
+        exported, on_device = eng.export_weights(), eng.device_weights()
+        same_as_loaded(exported)
+        same_as_loaded(on_device)
+        for name, (shape, _, _) in table.items():
+            held = eng._scope.get(name)
+            if name in marked:
+                assert held.shape == tuple(shape[:-2]) + (
+                    shape[-1], shape[-2]) == tuple(shape.held), name
+            else:
+                assert on_device[name] is held, name
+        name = sorted(marked)[0]
+        mine = jnp.asarray(weights[name]).astype(spec.dtype) * 2
+        eng.load_weights({name: mine})
+        assert mine.is_deleted()
+        np.testing.assert_array_equal(
+            np.asarray(eng.export_weights()[name]),
+            np.asarray(on_device[name] * 2))
+        eng.load_weights(exported)
+        same_as_loaded(eng.export_weights())
+    finally:
+        eng.shutdown(drain=False)
