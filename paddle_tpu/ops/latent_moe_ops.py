@@ -85,6 +85,21 @@ multiplies it (what a gather of the chosen rows would save is PERF.md's
 to measure). Scores, their weights and the selection are float32; the
 products take bfloat16 operands where the weights are bfloat16.
 
+**A carried selection** (glm_5_2's IndexShare: ``LMSpec.indexer_types``).
+Only some full layers score; a layer of the plan's kind ``CARRIED`` has
+no indexer and no index key and attends over the selection that the
+nearest scoring layer below it made: ``_attention`` returns the
+selection it made or was given, and the layer loop carries it beside the
+arenas (``segments``: a value handed from the leading layers into the
+first period and a ``lax.scan`` carry from one period to the next, bool
+``[rows, positions a table addresses]``; it never leaves the device).
+The full layers' attention stacks and latent arena hold scoring and
+carried layers alike, in order; the indexer's stacks and the index arena
+hold the scoring layers alone, so a layer's place in each is read off
+the plan (``_places``). The indexer rotates in half-split pairs or, where
+the spec says so (``index_rope_interleave``), in interleaved pairs as
+the attention does.
+
 **The layer loop.** The kinds have different weight shapes, so each
 kind's weights are a stack of their own (serving/decode/model.py:
 ``latent_param_shapes``), and so are the arenas: the full layers' latent
@@ -106,15 +121,18 @@ shared experts added at weight 1.
 import jax
 import jax.numpy as jnp
 
-from ..serving.decode.model import latent_expands
+from ..serving.decode.model import CARRIED, latent_expands
 from . import moe_held_ops as moe
-from .paged_decode_ops import (_attention_of, _mm, _mm_t, _rope_gptj_at,
-                               _write_in_place, period_segments)
+from .paged_decode_ops import (_attention_of, _mm, _mm_t, _rope_gptj,
+                               _rope_gptj_at, _write_in_place,
+                               period_segments)
 from .pallas.paged_attention import (paged_attention_one_table,
                                      pages_per_block)
 
 FULL, SLIDING = 'full_attention', 'sliding_attention'
-_TAG = {FULL: 'Full', SLIDING: 'Swa'}
+# a layer of the plan's kind CARRIED attends as a full layer does, in the
+# full layers' stacks and arena, over a selection made below it
+_TAG = {FULL: 'Full', SLIDING: 'Swa', CARRIED: 'Full'}
 # op input slots: the attention of a kind (prefixed Full / Swa), the
 # full layers' indexer, the two FFNs
 _ATTN = ('QA', 'QLn', 'QB', 'KvA', 'KvLn', 'KvBK', 'KvBV', 'O')
@@ -236,7 +254,22 @@ class LatentMoEBlock(object):
         self.routed_scale = float(ctx.attr('routed_scale', 1.0))
         self.plan = (tuple(ctx.attr('lead')), tuple(ctx.attr('period')),
                      int(ctx.attr('n_periods')), tuple(ctx.attr('tail')))
-        kinds = set(self.plan[0] + self.plan[1] + self.plan[3])
+        self.index_interleaved = bool(ctx.attr('index_rope_interleave', 0))
+        self.block_size = int(ctx.attr('block_size'))
+        order = self.plan[0] + self.plan[1] * self.plan[2] + self.plan[3]
+        # the attention's kinds; a carried selection is a full layer's
+        kinds = set(FULL if k == CARRIED else k for k in order)
+        # where some layer attends over a selection made below it, each
+        # full layer's place in the attention stacks (scoring and carried
+        # layers in order) and in the indexer's (the scoring ones)
+        self.carries = CARRIED in order
+        self._places = None
+        if self.carries:
+            attends = [k in (FULL, CARRIED) for k in order]
+            scores = [k == FULL for k in order]
+            self._places = tuple(
+                [sum(which[:i]) for i in range(len(order))]
+                for which in (attends, scores))
         self.arena_slots = tuple(
             s for s in self.all_arena_slots
             if (FULL if 'Full' in s else SLIDING) in kinds
@@ -275,16 +308,43 @@ class LatentMoEBlock(object):
 
     # ---------------------------------------------------- the layer loop
     def segments(self, step):
-        return period_segments(
+        out = period_segments(
             self.plan, lambda h, arenas, kind, layer, of_kind:
             self._layer(h, arenas, step, kind, layer, of_kind))
+        if not self.carries:
+            return out
+        # the selection rides the layer loop beside the arenas: nothing
+        # is chosen before the first layer, which scores
+        columns = step.tables.shape[-1] * self.block_size
+        blank = jnp.zeros((step.pos.shape[0], columns), bool)
+        return [(lambda c, _: ((c[0], (c[1], blank)), None), None)] + out \
+            + [(lambda c, _: ((c[0], c[1][0]), None), None)]
+
+    def _place(self, which, layer):
+        """Layer ``layer``'s (an int or a traced scalar) place in the
+        full layers' attention stacks (``which`` 0) or in the indexer's
+        (1), under a carried selection."""
+        table = self._places[which]
+        return table[layer] if isinstance(layer, int) \
+            else jnp.asarray(table, jnp.int32)[layer]
 
     def _layer(self, h, arenas, step, kind, layer, of_kind):
         """Layer ``layer`` (of all; ``of_kind`` among its kind's), with
         static or traced indices: (h, arenas, router statistics or
-        None)."""
+        None). Under a carried selection ``arenas`` is (the arenas, the
+        selection in force)."""
+        chosen = scored = None
+        if self.carries:
+            arenas, chosen = arenas
+            if kind != SLIDING:
+                of_kind = self._place(0, layer)
+            if kind == FULL:
+                scored = self._place(1, layer)
         n1 = rms_norm(h, _at(self.ln1, layer), self.eps)
-        attn, arenas = self._attention(n1, arenas, step, kind, of_kind)
+        attn, arenas, chosen = self._attention(n1, arenas, step, kind,
+                                               of_kind, chosen, scored)
+        if self.carries:
+            arenas = (arenas, chosen)
         h = h + attn
         n2 = rms_norm(h, _at(self.ln2, layer), self.eps)
         n_lead = len(self.plan[0])
@@ -294,9 +354,22 @@ class LatentMoEBlock(object):
         return h + m, arenas, stats
 
     # --------------------------------------------------------- attention
-    def _attention(self, n, arenas, step, kind, i):
+    def _attention(self, n, arenas, step, kind, i, chosen=None, scored=None):
+        """The attention of a layer of ``kind`` at place ``i`` of its
+        kind's stacks and arena, over the rows' normed inputs ``n``:
+        (its output, the arenas, the selection it made or, where it
+        made none, the one it was given). A ``CARRIED`` layer attends
+        over ``chosen`` as given; a scoring layer writes its index keys
+        at place ``scored`` of the indexer's stacks (``i`` where every
+        full layer scores)."""
+        carried = kind == CARRIED
+        kind = FULL if carried else kind
+        # the layer of each arena written: one for all, or the latent
+        # arena's and the index arena's
+        at = i if scored is None else (i, scored)
+        scored = i if scored is None else scored
         heads, d_nope, d_rope = self.shape[kind]
-        theta, pos = self.theta[kind], step.pos
+        pos = step.pos
         w = {slot: _at(self.w[_TAG[kind] + slot], i)
              for slot in self.attn_slots}
 
@@ -323,31 +396,32 @@ class LatentMoEBlock(object):
         spare = arenas[mine[0]].shape[-1] - rank - d_rope
         new = [jnp.concatenate(
             [c_kv, k_rope, jnp.zeros((rows, spare), jnp.float32)], -1)]
-        chosen, lo = None, None
-        selects = kind == FULL and self.index_topk > 0
+        lo = None
+        selects = kind == FULL and self.index_topk > 0 and not carried
         if selects:
             mine.append(self.arena_slots.index('IndexFull'))
-            q_i, w_i, k_i = self._index_rows(n, c_q, i, pos, theta, d_rope)
+            q_i, w_i, k_i = self._index_rows(n, c_q, scored, pos, kind)
             new.append(k_i)
         elif kind == SLIDING:
             # a query at position pos sees keys pos - window < j <= pos
             lo = jnp.maximum(pos + 1 - self.window, 0)
         held = tuple(arenas[a] for a in mine)
         held = _write_in_place(
-            held, [r.astype(a.dtype) for r, a in zip(new, held)], i,
-            step.place)
+            held, [r.astype(a.dtype) for r, a in zip(new, held)],
+            at, step.place)
         arenas = list(arenas)
         for a, arena in zip(mine, held):
             arenas[a] = arena
         if selects:
             per = pages_per_block(step.tables.shape[-1], held[1].shape[2])
             chosen = select_topk(
-                index_scores(q_i, w_i, held[1], i, step.tables, step.lens,
-                             per), self.index_topk)
+                index_scores(q_i, w_i, held[1], scored, step.tables,
+                             step.lens, per), self.index_topk)
         q_rope = turned(q[..., d_nope:])
         attend = dict(
             sm_scale=(d_nope + d_rope) ** -0.5 * self.softmax_mult[kind],
-            layer=i, lo=lo, latent=rank, chosen=chosen)
+            layer=i, lo=lo, latent=rank,
+            chosen=chosen if selects or carried else None)
         # the form follows the rows that share a table (module docstring)
         if step.tables.ndim == 1 and latent_expands(
                 rank, d_nope, w['KvBV'].shape[-1], rows):
@@ -370,15 +444,20 @@ class LatentMoEBlock(object):
                              w['KvBV'], preferred_element_type=jnp.float32)
         if self.gated:
             out = out * jax.nn.sigmoid(_mm(n, w['Gate']))[:, :, None]
-        return _mm(out.reshape(rows, -1), w['O']), tuple(arenas)
+        return _mm(out.reshape(rows, -1), w['O']), tuple(arenas), chosen
 
-    def _index_rows(self, n, c_q, i, pos, theta, d_rope):
+    def _index_rows(self, n, c_q, i, pos, kind):
         """(index queries [N, Hi, Di], their heads' weights [N, Hi], the
-        rows' own index keys [N, Di]), float32."""
+        rows' own index keys [N, Di]), float32, of the indexer at place
+        ``i`` of its stacks. The first ``d_rope`` columns of queries and
+        keys are rotated by the kind's theta, in half-split pairs or
+        (``index_rope_interleave``) in interleaved ones."""
+        theta, d_rope = self.theta[kind], self.shape[kind][2]
+        turn = _rope_gptj if self.index_interleaved else rope_half
         w = {slot: _at(self.w[slot], i) for slot in _INDEX}
         rows, heads = n.shape[0], self.index_heads
         q = _mm_t(c_q, w['IdxQ']).reshape(rows, heads, -1)
-        q = jnp.concatenate([rope_half(q[..., :d_rope], pos, theta),
+        q = jnp.concatenate([turn(q[..., :d_rope], pos, theta),
                              q[..., d_rope:]], -1)
         k = _mm(n, w['IdxK'])
         mean = jnp.mean(k, -1, keepdims=True)
@@ -387,7 +466,7 @@ class LatentMoEBlock(object):
             * w['IdxKLnW'].astype(jnp.float32) + \
             w['IdxKLnB'].astype(jnp.float32)
         k = jnp.concatenate(
-            [rope_half(k[:, None, :d_rope], pos, theta)[:, 0],
+            [turn(k[:, None, :d_rope], pos, theta)[:, 0],
              k[:, d_rope:]], -1)
         weight = _mm(n, w['IdxW']) * (heads * q.shape[-1]) ** -0.5
         return q, weight, k

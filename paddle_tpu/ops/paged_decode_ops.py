@@ -486,7 +486,10 @@ def _page_runs(table, cached, length, n_rows, nb, bs):
 
 def _write_in_place(arenas, rows, layer, place):
     """Write each ``rows[a]`` [N, W] into ``arenas[a]`` [L, NB, bs, W]
-    at ``layer`` where ``place`` says, run by run with
+    at ``layer`` (one for all, or a tuple with each arena's own: an
+    index arena holds fewer layers than the latent arena beside it
+    where some layers attend over a carried selection) where ``place``
+    says, run by run with
     ``dynamic_update_slice`` (which clamps and never drops, so a slot
     that must not be written gets the value that is there). Runs go in
     order, each reading the arena the one before it left, up to the
@@ -495,10 +498,11 @@ def _write_in_place(arenas, rows, layer, place):
     of arena size: a scatter would have the TPU re-lay its whole
     operand."""
     blocks = [place.blocks(r) for r in rows]
+    layers = layer if isinstance(layer, tuple) else (layer,) * len(blocks)
 
     def one(i, arenas):
         out = []
-        for arena, new in zip(arenas, blocks):
+        for arena, new, layer in zip(arenas, blocks, layers):
             at = (layer, place.phys[i], place.off[i], 0)
             run = (1, 1) + new.shape[1:]
             there = jax.lax.dynamic_slice(arena, at, run)

@@ -76,7 +76,7 @@ class ShortcutMoEBlock(LatentMoEBlock):
         s = stats = None
         for j in range(SUBLAYERS):
             sub = layer * SUBLAYERS + j
-            attn, arenas = self._attention(
+            attn, arenas, _ = self._attention(
                 rms_norm(h, _at(self.ln1, sub), self.eps), arenas, step,
                 kind, sub)
             h = h + attn

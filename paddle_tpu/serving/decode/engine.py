@@ -1339,7 +1339,10 @@ class DecodeEngine(object):
         what each kind says a layer reads of them (``CacheKind.reads``:
         the positions a selection keeps, a window, or all), at the row's
         own width; and, where full layers select, how far the selection
-        is live."""
+        is live. The layers that attend under a selection (every
+        full_attention layer) and those that score one
+        (``LMSpec.scoring_layers``) are counted apart: under a carried
+        selection they are two counts."""
         total = int(seen.sum())
         for kind, row_bytes, caps in self._kind_reads:
             positions = sum(
@@ -1350,9 +1353,15 @@ class DecodeEngine(object):
         topk = self.spec.index_topk
         if topk:
             n_full = len(self.spec.layers_of(FULL))
+            n_scoring = len(self.spec.scoring_layers())
             _obs.inc('decode.sparse_positions_seen', n_full * total)
             _obs.inc('decode.sparse_positions_selected',
                      n_full * int(np.minimum(seen, topk).sum()))
+            _obs.inc('decode.index_positions_scored', n_scoring * total)
+            _obs.inc('decode.selection_layer_calls', n_scoring,
+                     how='scored')
+            _obs.inc('decode.selection_layer_calls', n_full - n_scoring,
+                     how='carried')
             _obs.inc('decode.sparse_rows', len(seen))
             _obs.inc('decode.sparse_rows_live', int((seen > topk).sum()))
 

@@ -59,6 +59,11 @@ __all__ = ['LMSpec', 'DecodePrograms', 'build_lm_programs']
 
 
 SLIDING, FULL = 'sliding_attention', 'full_attention'
+# a full_attention layer of a latent block that scores no positions of
+# its own: it attends over the selection of the nearest scoring layer
+# below it (``LMSpec.indexer_types`` 'shared'); a kind of ``layer_plan``
+# only, never of ``layer_types``
+CARRIED = 'carried_selection'
 # the layer kinds of block='ssm_hybrid': a Mamba-2 mixer, or attention
 # that sees every position and carries none
 MAMBA, ATTENTION = 'mamba', 'attention'
@@ -323,7 +328,14 @@ class LMSpec(object):
     ``attn_gate`` (a sigmoid gate a head on the attention's output) and
     ``lora_rescale`` (the normed latents times sqrt(d_model / rank)).
     ``n_head``, ``n_kv_head``, ``d_key``, ``d_value`` and ``rope_theta``
-    are unused: the shapes are per kind.
+    are unused: the shapes are per kind. **A carried selection**
+    (glm_5_2): ``indexer_types`` gives every layer as 'full' (it has an
+    indexer, caches an index key and makes the selection) or 'shared'
+    (it has neither and attends over the positions that the nearest
+    'full' layer below it chose); without it every full_attention layer
+    under ``index_topk`` scores for itself. ``index_rope_interleave``:
+    the indexer rotates its queries and keys in interleaved pairs as the
+    attention does, not in half-split pairs.
 
     ``block='gqa_moe'`` (mellum): the serial pre-norm block of
     ``latent_moe`` over per-head keys and values: ``n_head`` query heads
@@ -397,7 +409,8 @@ class LMSpec(object):
                  lora_rescale=True, attn_gate=True, routed_scale=1.0,
                  rope_parameters=None, ssm_heads=0, ssm_head_dim=0,
                  ssm_state=0, ssm_conv=4, ssm_chunk=256, embed_scale=1.0,
-                 residual_scale=1.0, attn_scale=None, zero_experts=0):
+                 residual_scale=1.0, attn_scale=None, zero_experts=0,
+                 indexer_types=None, index_rope_interleave=False):
         self.vocab_size = int(vocab_size)
         self.n_layer = int(n_layer)
         self.n_head = int(n_head)
@@ -428,6 +441,8 @@ class LMSpec(object):
         self.index_n_heads = int(index_n_heads)
         self.index_head_dim = int(index_head_dim)
         self.index_topk = int(index_topk)
+        self.indexer_types = tuple(indexer_types or ())
+        self.index_rope_interleave = bool(index_rope_interleave)
         self.lora_rescale = bool(lora_rescale)
         self.attn_gate = bool(attn_gate)
         self.routed_scale = float(routed_scale)
@@ -550,6 +565,19 @@ class LMSpec(object):
                 'LMSpec: full layers select by an indexer: index_topk %d, '
                 '%d heads of %d' % (self.index_topk, self.index_n_heads,
                                     self.index_head_dim))
+        if self.indexer_types or self.index_rope_interleave:
+            # a layer that shares needs a selection made below it
+            kept = [t for t, kind in zip(self.indexer_types,
+                                         self.layer_types) if kind == FULL]
+            if not self.index_topk or (self.indexer_types and (
+                    len(self.indexer_types) != self.n_layer or
+                    set(self.indexer_types) - {'full', 'shared'} or
+                    kept[:1] != ['full'])):
+                raise ValueError(
+                    'LMSpec: indexer_types %r (full, shared; one a '
+                    'layer, the first full_attention layer full) under '
+                    'index_topk %d' % (self.indexer_types,
+                                       self.index_topk))
         if self.block == 'shortcut_moe' and (
                 kinds != {FULL} or self.index_topk or self.dense_layers
                 or self.attn_gate or self.d_inner_dense < 1):
@@ -578,15 +606,40 @@ class LMSpec(object):
         """The layers of one kind, in order."""
         return tuple(i for i, t in enumerate(self.layer_types) if t == kind)
 
+    def scoring_layers(self):
+        """The layers whose attention makes a selection of its own: the
+        full_attention layers under ``index_topk``, less those that
+        ``indexer_types`` gives as 'shared'. They alone have an
+        indexer's weights and cache an index key."""
+        if not self.index_topk:
+            return ()
+        return tuple(i for i in self.layers_of(FULL)
+                     if not self.indexer_types
+                     or self.indexer_types[i] == 'full')
+
+    def plan_kinds(self):
+        """Per layer the kind the layer loop runs it as:
+        ``layer_types``, with ``CARRIED`` for a full_attention layer that
+        attends over a selection made below it (``indexer_types``
+        'shared'). Without ``indexer_types`` it is ``layer_types``."""
+        if not self.indexer_types:
+            return self.layer_types
+        scoring = set(self.scoring_layers())
+        return tuple(CARRIED if kind == FULL and i not in scoring else kind
+                     for i, kind in enumerate(self.layer_types))
+
     def layer_plan(self):
         """(lead, period, n_periods, tail) of the latent_moe layer loop:
         ``lead`` the leading dense layers' kinds, ``period`` the
         shortest run of kinds that the routed layers repeat, how many
         whole periods there are, and ``tail`` the kinds of the routed
         layers left over (a prefix of a period). The published 46
-        layers are 1 + 11 x (full, sliding, sliding, sliding) + 1."""
-        lead = self.layer_types[:self.dense_layers]
-        rest = self.layer_types[self.dense_layers:]
+        layers are 1 + 11 x (full, sliding, sliding, sliding) + 1. Under
+        ``indexer_types`` the kinds are ``plan_kinds()``: glm_5_2's 78
+        are 3 + 18 x (carried, carried, carried, full) + 3 carried."""
+        kinds = self.plan_kinds()
+        lead = kinds[:self.dense_layers]
+        rest = kinds[self.dense_layers:]
         size = next(p for p in range(1, len(rest) + 2)
                     if all(rest[i] == rest[i % p]
                            for i in range(len(rest)))) if rest else 1
@@ -605,7 +658,10 @@ class LMSpec(object):
         page id is a page of every arena of it. A full
         layer of the latent block keeps its latent rows, of which a
         step reads ``index_topk`` (0, no selection: all of them), and
-        with a selection the indexer's keys beside them."""
+        with a selection the indexer's keys beside them, in the layers
+        that score (``scoring_layers``): a layer that attends over a
+        carried selection keeps no index key, so the index arena may
+        hold fewer layers than the latent arena it selects from."""
         every = tuple(range(self.n_layer))
         if self.block == 'ssm_hybrid':
             # K and V of the attention layers in the pool that keeps
@@ -669,10 +725,13 @@ class LMSpec(object):
                                  self.latent[FULL].row_width,
                                  (self.index_topk,) * len(full), True))
             if self.index_topk:
-                # the indexer scores every position to choose
-                out.append(CacheKind('lm_index_full', 'IndexFull', full,
+                # the indexer scores every position to choose, in the
+                # layers that have one (``scoring_layers``: all of them
+                # but under ``indexer_types``)
+                scoring = self.scoring_layers()
+                out.append(CacheKind('lm_index_full', 'IndexFull', scoring,
                                      self.index_head_dim,
-                                     (0,) * len(full), True))
+                                     (0,) * len(scoring), True))
         if SLIDING in self.latent:
             sliding = self.layers_of(SLIDING)
             out.append(CacheKind('lm_latent_sliding', 'LatentSliding',
@@ -1071,6 +1130,8 @@ def latent_param_shapes(spec):
             out['lm_%s_gate.w' % tag] = ([n, d, a.n_head], d, slot + 'Gate')
         if kind == FULL and spec.index_topk:
             hi, di = spec.index_n_heads, spec.index_head_dim
+            # the indexer's stacks hold the layers that score
+            n = len(spec.scoring_layers())
             out.update([
                 ('lm_full_idx_q.w', (HeldTransposed([n, a.q_rank, hi * di]),
                                      from_q, 'IdxQ')),
@@ -1230,6 +1291,9 @@ def _block_attrs(spec, block_size):
             'lora_rescale': int(spec.lora_rescale),
             'attn_gate': int(spec.attn_gate),
             'routed_scale': spec.routed_scale})
+        if spec.index_rope_interleave:
+            # the other form is the default and has no attr
+            attrs['index_rope_interleave'] = 1
         for kind, tag in ((FULL, 'full'), (SLIDING, 'swa')):
             if kind in spec.latent:
                 a = spec.latent[kind]

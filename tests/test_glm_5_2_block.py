@@ -1,0 +1,625 @@
+"""The latent_moe block under a carried selection (LMSpec
+block='latent_moe' with ``indexer_types``: glm_5_2's IndexShare) against
+its plain reference, at a tiny size on the CPU in float32: five layers of
+one attention shape (4 heads over a rank-12 latent, nope 6, rope 4, v 8:
+values wider than the unrotated keys, as published), the first dense and
+scoring, then (shared, shared, shared, full); an indexer of 3 heads of 8
+in the two scoring layers, rotated in interleaved pairs, that keeps 8
+positions; 8 experts of which 4 are held, 3 per token, times 2.5, one
+shared; no gate, no rescale. Every sequence runs past the 8 selected
+positions, so a carried selection differs from "all".
+
+The comparisons are of logits, not tokens. Tolerance: both sides are
+float32 on the CPU; they differ in the order of their sums (absorbed
+against expanded attention, column blocks under a running softmax
+against one dense softmax, the routed product's row tiles against a
+loop over experts), which at these widths gives differences of a few
+1e-6 on logits of order 1. 5e-5 leaves a margin and is two orders and
+more under what a dropped carry, an unapplied selection, the indexer's
+other rotary form or bfloat16 state gives (checked below by breaking
+each)."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu as fluid
+from paddle_tpu.models.reference import glm_5_2 as ref
+from paddle_tpu.ops import latent_moe_ops as lmo
+from paddle_tpu.ops import moe_held_ops as moe
+from paddle_tpu.ops import paged_decode_ops as pdo
+from paddle_tpu.serving.decode import DecodeEngine, LMSpec, random_weights
+from paddle_tpu.serving.decode import model as lm
+from util import as_held, weights_round_trip
+
+TOL = 5e-5
+BS, PAGES, NB = 4, 12, 40            # 48 positions a sequence
+F, S, C = lm.FULL, lm.SLIDING, lm.CARRIED
+SHAPE = dict(n_head=4, q_rank=16, kv_rank=12, d_nope=6, d_rope=4, d_v=8,
+             rope_theta=8e6)
+# the published list: layers 0-2 score, then (shared x 3, full) x 18 and
+# three shared over
+PUBLISHED = ['full'] * 3 + ['shared', 'shared', 'shared', 'full'] * 18 + \
+    ['shared'] * 3
+
+
+def _spec(**over):
+    kw = dict(
+        vocab_size=64, n_layer=5, d_model=32, d_inner=24,
+        block='latent_moe', latent={F: SHAPE}, dense_layers=1,
+        d_inner_dense=40, index_n_heads=3, index_head_dim=8, index_topk=8,
+        indexer_types=PUBLISHED[2:7], index_rope_interleave=True,
+        n_experts=8, experts_held=4, first_expert=2, experts_per_token=3,
+        n_shared_experts=1, routed_scale=2.5, lora_rescale=False,
+        attn_gate=False)
+    kw.update(over)
+    return LMSpec(**kw)
+
+
+SPEC = _spec()
+WEIGHTS = random_weights(SPEC, seed=54)
+
+
+class _Op(object):
+    def __init__(self, slots):
+        self._slots = slots
+
+    def input(self, slot):
+        return self._slots[slot]
+
+
+class _Ctx(object):
+    """What a paged op's lowering reads of its context, for driving the
+    block's row function without a Program."""
+
+    def __init__(self, spec, weights):
+        self._attrs = lm._block_attrs(spec, BS)
+        self.env = {}
+        slots = {}
+        held = as_held(spec, weights)
+        for name, (_, _, slot) in lm.block_param_shapes(spec).items():
+            self.env[name] = held[name]
+            slots[slot] = name
+        self.op = _Op(slots)
+
+    def attr(self, name, default=None):
+        return self._attrs.get(name, default)
+
+    def input(self, slot):
+        return self.env[self.op.input(slot)]
+
+
+def _block(spec=SPEC, weights=WEIGHTS):
+    return lmo.LatentMoEBlock(_Ctx(spec, weights))
+
+
+def _arenas(spec=SPEC):
+    return tuple(jnp.zeros((len(k.layers), NB, BS, k.stored), jnp.float32)
+                 for k in spec.cache_kinds())
+
+
+_JITTED = {}
+
+
+def _jitted(block, fn):
+    key = (id(block), fn.__name__)
+    if key not in _JITTED:
+        _JITTED[key] = (block, jax.jit(lambda *a: fn(block, *a)))
+    return _JITTED[key][1]
+
+
+def _chunk_rows(block, arenas, table, tokens, start):
+    s = tokens.shape[0]
+    pos = start + jnp.arange(s, dtype=jnp.int32)
+    place = pdo._page_runs(table, start, jnp.int32(s), s, NB, BS)
+    h, arenas, stats = pdo._extend_rows(
+        block, arenas, tokens, pos, table, place, valid=jnp.ones((s,), bool))
+    return block.logits(h), arenas, stats
+
+
+def _prefill_chunk(block, arenas, table, tokens, start):
+    return _jitted(block, _chunk_rows)(
+        arenas, table, jnp.asarray(tokens, jnp.int32), jnp.int32(start))
+
+
+def _step_rows(block, arenas, tables, tokens, lens):
+    place = pdo._single_rows(tables, lens, NB, BS)
+    h, arenas, stats = pdo._extend_rows(
+        block, arenas, tokens, lens, tables, place, valid=place.ok[:, 0])
+    return block.logits(h), arenas, stats
+
+
+def _decode(block, arenas, tables, tokens, lens):
+    return _jitted(block, _step_rows)(arenas, tables, tokens, lens)
+
+
+def _reference_logits(tokens, spec=SPEC, weights=WEIGHTS, **lowered):
+    return np.asarray(ref.logits(
+        weights, np.asarray(tokens, np.int32),
+        dict(ref.arch_of(spec), **lowered), ref.held_of(spec)))
+
+
+# ------------------------------------------- (d) the plan, (c) the caches
+def test_layer_plan_of_the_published_list_and_of_the_cut():
+    """The published 78 layers: three leading dense layers that score,
+    18 periods of (carried x 3, scoring) and three carried layers over;
+    the cut runs layers 2-6: the last leading dense layer and the whole
+    period that follows it. The period is found among layers of one
+    attention shape by their indexer kinds."""
+    spec = _spec(n_layer=78, dense_layers=3, indexer_types=PUBLISHED)
+    assert spec.layer_types == (F,) * 78
+    assert spec.layer_plan() == ((F, F, F), (C, C, C, F), 18, (C, C, C))
+    assert len(spec.scoring_layers()) == 21
+    assert spec.scoring_layers()[:5] == (0, 1, 2, 6, 10)
+    assert SPEC.layer_plan() == ((F,), (C, C, C, F), 1, ())
+    assert SPEC.scoring_layers() == (0, 4)
+    assert SPEC.plan_kinds() == (F, C, C, C, F)
+    kinds = {k.name: k for k in spec.cache_kinds()}
+    assert kinds['lm_latent_full'].layers == tuple(range(78))
+    assert kinds['lm_index_full'].layers == spec.scoring_layers()
+    # a layer's place in each stack, as the block reads it off the plan
+    block = _block()
+    assert block.carries and block._places == (
+        [0, 1, 2, 3, 4], [0, 1, 1, 1, 1])
+
+
+def test_the_index_arena_holds_the_scoring_layers_only():
+    """Two arenas under one table: every layer keeps a latent row, the
+    two scoring layers an index key beside it; every function of a
+    token's bytes reads the same list."""
+    kinds = SPEC.cache_kinds()
+    assert [(k.name, k.slot, k.layers, k.width, k.reads) for k in kinds] == [
+        ('lm_latent_full', 'LatentFull', (0, 1, 2, 3, 4), 16, (8,) * 5),
+        ('lm_index_full', 'IndexFull', (0, 4), 8, (0, 0))]
+    per_token = 5 * 16 + 2 * 8
+    assert lm.kv_bytes_per_token(SPEC) == per_token * 4
+    assert lm.kv_bytes_per_kind(SPEC, 'bfloat16') == {
+        'lm_latent_full': 160, 'lm_index_full': 32}
+    assert lm.arena_bytes(SPEC, NB, BS) == per_token * 4 * BS * NB
+    assert [a.shape for a in _arenas()] == [(5, NB, BS, 16), (2, NB, BS, 8)]
+    # the published widths: 5 x 576 + 2 x 128 values a token, the latent
+    # row stored in whole lane tiles (576 -> 640): 6,912 B in bfloat16
+    big = _spec(latent={F: dict(n_head=64, q_rank=2048, kv_rank=512,
+                                d_nope=192, d_rope=64, d_v=256,
+                                rope_theta=8e6)}, index_head_dim=128)
+    assert [k.stored for k in big.cache_kinds()] == [640, 128]
+    assert lm.kv_bytes_per_token(big, 'bfloat16') == 6912
+    # the shared layers hold no indexer weights
+    shapes = lm.block_param_shapes(SPEC)
+    for name, (shape, _, _) in shapes.items():
+        if name.startswith('lm_full_idx_'):
+            assert shape[0] == 2, name
+        elif name.startswith('lm_full_'):
+            assert shape[0] == 5, name
+
+
+@pytest.mark.parametrize('bad', [
+    dict(indexer_types=['shared', 'full', 'full', 'full', 'full']),
+    dict(indexer_types=['full', 'shared']),
+    dict(indexer_types=['full', 'shared', 'shared', 'shared', 'window']),
+    dict(index_topk=0, index_n_heads=0)])
+def test_spec_refuses_what_it_cannot_run(bad):
+    with pytest.raises(ValueError):
+        _spec(**bad)
+
+
+# --------------- (e) a spec without indexer kinds is what it always was
+DOTS = dict(
+    vocab_size=64, n_layer=5, d_model=32, d_inner=24, block='latent_moe',
+    layer_types=[F, F, S, S, S], sliding_window=5,
+    latent={F: dict(n_head=4, q_rank=16, kv_rank=12, d_nope=8, d_rope=4,
+                    d_v=8, rope_theta=8e7),
+            S: dict(n_head=2, q_rank=16, kv_rank=20, d_nope=12, d_rope=4,
+                    d_v=8, rope_theta=5e4)},
+    dense_layers=1, d_inner_dense=40, index_n_heads=3, index_head_dim=8,
+    index_topk=8, n_experts=8, experts_held=4, first_expert=2,
+    experts_per_token=3, n_shared_experts=1)
+KIMI = dict(
+    vocab_size=64, n_layer=3, d_model=32, d_inner=24, block='latent_moe',
+    latent={F: dict(n_head=4, q_rank=16, kv_rank=12, d_nope=8, d_rope=4,
+                    d_v=8, rope_theta=5e4)},
+    dense_layers=1, d_inner_dense=40, n_experts=8, experts_held=4,
+    experts_per_token=3, n_shared_experts=1, lora_rescale=False,
+    attn_gate=False, routed_scale=2.0)
+
+
+@pytest.mark.parametrize('kw', [DOTS, KIMI], ids=['dots3_note', 'kimi_k2_6'])
+def test_a_spec_without_indexer_kinds_is_unchanged(kw):
+    """dots3_note's and kimi_k2_6's form of the spec: every full layer
+    under ``index_topk`` scores for itself, the plan's kinds are
+    ``layer_types``, the ops get no new attribute, the indexer's stacks
+    and the index arena are the full layers', and the block carries
+    nothing. Giving every layer as 'full' lowers to the same program,
+    text for text: the carry exists only where a layer shares."""
+    spec = LMSpec(**kw)
+    assert spec.indexer_types == () and not spec.index_rope_interleave
+    assert spec.plan_kinds() is spec.layer_types
+    full = spec.layers_of(F)
+    assert spec.scoring_layers() == (full if spec.index_topk else ())
+    attrs = lm._block_attrs(spec, BS)
+    assert 'index_rope_interleave' not in attrs
+    assert set(attrs['lead'] + attrs['period'] + attrs['tail']) <= {F, S}
+    shapes = lm.block_param_shapes(spec)
+    kinds = {k.name: k for k in spec.cache_kinds()}
+    if spec.index_topk:
+        assert kinds['lm_index_full'].layers == full
+        assert shapes['lm_full_idx_q.w'][0][0] == len(full)
+    else:
+        assert 'lm_index_full' not in kinds
+        assert 'lm_full_idx_q.w' not in shapes
+    block = _block(spec, random_weights(spec, seed=1))
+    assert not block.carries and block._places is None
+    if not spec.index_topk:
+        return
+    texts = []
+    for types in (None, ['full'] * spec.n_layer):
+        eng = DecodeEngine(LMSpec(**dict(kw, indexer_types=types)),
+                           max_batch=2, block_size=BS, num_blocks=NB,
+                           pages_per_seq=PAGES, prefill_chunk=8,
+                           min_prompt_bucket=8, place=fluid.CPUPlace())
+        try:
+            texts.append([eng.trace_program(which).lower().as_text()
+                          for which in ('decode', 8)])
+        finally:
+            eng.shutdown(drain=False)
+    assert texts[0] == texts[1]
+
+
+# ---------------------------------------------------- the indexer's form
+def test_the_indexer_rotates_in_the_form_the_spec_states():
+    """Interleaved pairs here, half-split pairs without the option (as
+    dots3_note): index keys as cached, against the reference's."""
+    rng = np.random.RandomState(3)
+    tokens = rng.randint(0, SPEC.vocab_size, 9)
+    block, table = _block(), jnp.arange(PAGES, dtype=jnp.int32)
+    _, arenas, _ = _prefill_chunk(block, _arenas(), table, tokens, 0)
+    x = jnp.take(jnp.asarray(WEIGHTS['lm_emb']), jnp.asarray(tokens), axis=0)
+    n = ref.rms_norm(x, WEIGHTS['lm_stack_ln1.w'][0], SPEC.norm_eps)
+    want = ref.sequence_keys(n, 0, WEIGHTS, 0, ref.arch_of(SPEC))[2]
+    got = np.asarray(arenas[1])[0].reshape(NB * BS, -1)[:9]
+    np.testing.assert_allclose(got, np.asarray(want), atol=1e-5)
+    other = _block(_spec(index_rope_interleave=False))
+    _, arenas, _ = _prefill_chunk(other, _arenas(), table, tokens, 0)
+    moved = np.asarray(arenas[1])[0].reshape(NB * BS, -1)[:9] - got
+    assert np.abs(moved[1:, :4]).max() > 1e-2 and not moved[:, 4:].any()
+
+
+# ----------------------------------------------------- (b) shares add up
+def test_shares_add_up_to_the_uncut_layer():
+    """The eight shares of a routed layer (8 experts, one a share), the
+    shared expert counted once, are the uncut layer's FFN times the
+    routed scale: in the reference, and between the block's product and
+    the reference. Attention, indexer and router are replicated: a
+    share's are the uncut model's own arrays."""
+    whole = _spec(experts_held=8, first_expert=0)
+    w = random_weights(whole, seed=11)
+    rng = np.random.RandomState(1)
+    n = jnp.asarray(rng.randn(7, whole.d_model), jnp.float32)
+    arch = ref.arch_of(whole)
+    layer = 2
+    uncut = np.asarray(ref.experts(n, w, layer, arch, (0, 8)))
+
+    def cut(first):
+        out = dict(w)
+        for part in ('gate', 'up', 'down'):
+            name = 'lm_moe_exp_%s.w' % part
+            out[name] = w[name][:, first:first + 1]
+        return out
+
+    shared = np.asarray(ref.expert(
+        n, w['lm_moe_shr_gate.w'][layer, 0], w['lm_moe_shr_up.w'][layer, 0],
+        w['lm_moe_shr_down.w'][layer, 0]))
+    from_reference, from_block = shared.copy(), shared.copy()
+    for first in range(8):
+        share = cut(first)
+        from_reference += np.asarray(
+            ref.experts(n, share, layer, arch, (first, 1))) - shared
+        chosen, weight = moe.route_sigmoid_topk(
+            n, share['lm_moe_router.w'][layer], whole.experts_per_token,
+            bias=share['lm_moe_router.b'][layer], scale=whole.routed_scale)
+        gate, _ = moe.held_gates(chosen, weight, first, 1)
+        from_block += np.asarray(moe.gated_experts(
+            n, gate, *(jnp.asarray(share['lm_moe_exp_%s.w' % p][layer])
+                       for p in ('gate', 'up', 'down'))))
+    np.testing.assert_allclose(from_reference, uncut, atol=TOL)
+    np.testing.assert_allclose(from_block, uncut, atol=TOL)
+    assert np.abs(np.asarray(ref.experts(n, cut(0), layer, arch, (0, 1)))
+                  - uncut).max() > 1e-2
+    # the chosen experts' weights sum to the routed scale
+    _, weight = ref.route(n, w['lm_moe_router.w'][layer],
+                          w['lm_moe_router.b'][layer], 3, 2.5)
+    np.testing.assert_allclose(np.asarray(weight).sum(1), 2.5, rtol=1e-6)
+    # everything else of a share is the uncut model's
+    held = lm.block_param_shapes(_spec(experts_held=1, first_expert=3))
+    full = lm.block_param_shapes(whole)
+    assert {k for k in full if full[k][0] != held[k][0]} == {
+        'lm_moe_exp_gate.w', 'lm_moe_exp_up.w', 'lm_moe_exp_down.w'}
+
+
+# --------------------------------- (a) prefill in chunks, then decode
+@pytest.mark.parametrize('prompt_len,chunk', [(13, 8), (21, 16), (6, 8),
+                                              (30, 30)])
+def test_chunked_prefill_then_decode_matches_full_forward(prompt_len,
+                                                          chunk):
+    """A sequence that passes index_topk (8): its prompt prefilled in
+    chunks (or in one) through the two arenas, then decoded a token at a
+    time, row by row against the reference's one full forward. Past
+    position 8 the three shared layers attend over what layer 0 chose."""
+    rng = np.random.RandomState(prompt_len)
+    total = prompt_len + 12
+    tokens = rng.randint(0, SPEC.vocab_size, total)
+    want = _reference_logits(tokens)
+    block = _block()
+    arenas = _arenas()
+    table = jnp.asarray(rng.permutation(NB)[:PAGES], jnp.int32)
+    for start in range(0, prompt_len, chunk):
+        piece = tokens[start:min(start + chunk, prompt_len)]
+        got, arenas, stats = _prefill_chunk(block, arenas, table, piece,
+                                            start)
+        np.testing.assert_allclose(
+            np.asarray(got), want[start:start + len(piece)], atol=TOL)
+        assert np.asarray(stats).shape == (4, 4)     # the routed layers
+    for t in range(prompt_len, total):
+        got, arenas, _ = _decode(
+            block, arenas, table[None, :],
+            jnp.asarray(tokens[t:t + 1], jnp.int32),
+            jnp.asarray([t], jnp.int32))
+        np.testing.assert_allclose(np.asarray(got)[0], want[t], atol=TOL)
+
+
+def test_the_published_order_with_leading_layers_periods_and_a_remainder():
+    """Thirteen layers: three leading dense layers that score, two whole
+    periods in the scan (the selection a scan carry from one to the
+    next) and two carried layers over, the attention's stacks indexed
+    over all layers and the indexer's over the five that score: logits
+    against the reference."""
+    types = ['full'] * 3 + ['shared', 'shared', 'shared', 'full'] * 2 + \
+        ['shared'] * 2
+    spec = _spec(n_layer=13, dense_layers=3, indexer_types=types)
+    assert spec.layer_plan() == ((F, F, F), (C, C, C, F), 2, (C, C))
+    assert spec.scoring_layers() == (0, 1, 2, 6, 10)
+    w = random_weights(spec, seed=2)
+    rng = np.random.RandomState(2)
+    tokens = rng.randint(0, spec.vocab_size, 22)
+    want = _reference_logits(tokens, spec, w)
+    block, table = _block(spec, w), jnp.arange(PAGES, dtype=jnp.int32)
+    got, arenas, stats = _prefill_chunk(block, _arenas(spec), table,
+                                        tokens[:16], 0)
+    np.testing.assert_allclose(np.asarray(got), want[:16], atol=TOL)
+    assert np.asarray(stats).shape == (10, 4)
+    got, arenas, _ = _prefill_chunk(block, arenas, table, tokens[16:], 16)
+    np.testing.assert_allclose(np.asarray(got), want[16:], atol=TOL)
+
+
+def test_a_period_without_leading_layers_carries_from_its_own_first():
+    """No dense layer: the scan is the first segment, and its first
+    layer scores (full, shared) x 2."""
+    spec = _spec(n_layer=4, dense_layers=0,
+                 indexer_types=['full', 'shared'] * 2)
+    assert spec.layer_plan() == ((), (F, C), 2, ())
+    w = random_weights(spec, seed=3)
+    tokens = np.random.RandomState(5).randint(0, spec.vocab_size, 20)
+    got, _, _ = _prefill_chunk(_block(spec, w), _arenas(spec),
+                               jnp.arange(PAGES, dtype=jnp.int32), tokens, 0)
+    np.testing.assert_allclose(np.asarray(got),
+                               _reference_logits(tokens, spec, w), atol=TOL)
+
+
+def test_decode_batch_of_mixed_lengths_matches_reference():
+    """Four sequences of lengths on both sides of index_topk in one
+    decode batch, an empty slot among them: every row's logits are the
+    reference's for that sequence."""
+    rng = np.random.RandomState(7)
+    lengths = [3, 9, 17, 30]
+    seqs = [rng.randint(0, SPEC.vocab_size, n + 1) for n in lengths]
+    block = _block()
+    arenas = _arenas()
+    pages = rng.permutation(NB)
+    tables = np.full((5, PAGES), NB, np.int32)
+    used = 0
+    for i, seq in enumerate(seqs):
+        need = -(-len(seq) // BS)
+        tables[i, :need] = pages[used:used + need]
+        used += need
+        _, arenas, _ = _prefill_chunk(block, arenas, jnp.asarray(tables[i]),
+                                      seq[:-1], 0)
+    got, arenas, stats = _decode(
+        block, arenas, jnp.asarray(tables),
+        jnp.asarray([s[-1] for s in seqs] + [0], jnp.int32),
+        jnp.asarray(lengths + [0], jnp.int32))
+    for i, seq in enumerate(seqs):
+        np.testing.assert_allclose(
+            np.asarray(got)[i], _reference_logits(seq)[-1], atol=TOL)
+    assert np.asarray(stats).shape == (4, 4)
+
+
+# ------------------------------------- (f) what the tolerance is for
+@pytest.mark.parametrize('broken', ['carry', 'select', 'state'])
+def test_the_tolerance_catches_what_it_is_for(broken):
+    """The planted faults, in the reference: the shared layers scoring
+    for themselves with the nearest scoring layer's weights (no carry);
+    every layer attending to all it holds; bfloat16 for the residual
+    stream, scores, softmax and logits. Each moves the logits by far
+    more than the tolerance the sound block is held to, and only past
+    index_topk where the fault is in the selection."""
+    lowered = {'carry': dict(carry=False), 'select': dict(select=False),
+               'state': dict(state_dtype='bfloat16')}[broken]
+    rng = np.random.RandomState(4)
+    tokens = rng.randint(0, SPEC.vocab_size, 30)
+    sound = _reference_logits(tokens)
+    moved = np.abs(_reference_logits(tokens, **lowered) - sound)
+    assert moved.max() > 100 * TOL
+    if broken != 'state':
+        # the rows below index_topk are untouched: the selection is all
+        assert moved[:SPEC.index_topk].max() < TOL
+        assert moved[SPEC.index_topk:].max() > 100 * TOL
+
+
+@pytest.mark.parametrize('broken', ['no_carry', 'all_positions',
+                                    'half_split', 'routed_scale'])
+def test_the_tolerance_catches_a_wrong_block(broken):
+    """And the block itself, broken: every layer scoring with an indexer
+    of its own (the selection not carried), no selection in the shared
+    layers' place (a block that attends over all positions there cannot
+    be built from the spec, so the reference's ``select`` off stands in:
+    the sound block is far from it), the indexer's other rotary form,
+    the routed scale dropped."""
+    rng = np.random.RandomState(2)
+    tokens = rng.randint(0, SPEC.vocab_size, 24)
+    table = jnp.arange(PAGES, dtype=jnp.int32)
+    if broken == 'all_positions':
+        got, _, _ = _prefill_chunk(_block(), _arenas(), table, tokens, 0)
+        want = _reference_logits(tokens, select=False)
+    else:
+        over = {'no_carry': dict(indexer_types=['full'] * 5),
+                'half_split': dict(index_rope_interleave=False),
+                'routed_scale': dict(routed_scale=1.0)}[broken]
+        spec = _spec(**over)
+        weights = WEIGHTS
+        if broken == 'no_carry':
+            # the indexer of the nearest scoring layer below, in every
+            # layer: what a program that dropped the carry would hold
+            weights = dict(WEIGHTS)
+            for name in WEIGHTS:
+                if name.startswith('lm_full_idx_'):
+                    weights[name] = WEIGHTS[name][[0, 0, 0, 0, 1]]
+        got, _, _ = _prefill_chunk(_block(spec, weights), _arenas(spec),
+                                   table, tokens, 0)
+        want = _reference_logits(tokens)
+        if broken == 'no_carry':
+            # and that is the reference with its carry off, to rounding
+            np.testing.assert_allclose(
+                np.asarray(got), _reference_logits(tokens, carry=False),
+                atol=TOL)
+    assert np.abs(np.asarray(got) - want)[SPEC.index_topk:].max() > 1e-3
+
+
+# ------------------------------------------------------------ the engine
+def _engine(spec=SPEC, **kw):
+    kw.setdefault('max_batch', 4)
+    kw.setdefault('block_size', BS)
+    kw.setdefault('num_blocks', 64)
+    kw.setdefault('pages_per_seq', PAGES)
+    kw.setdefault('prefill_chunk', 8)
+    kw.setdefault('min_prompt_bucket', 4)
+    kw.setdefault('weights', WEIGHTS)
+    kw.setdefault('place', fluid.CPUPlace())
+    return DecodeEngine(spec, **kw)
+
+
+@pytest.fixture(scope='module')
+def engine():
+    eng = _engine()
+    eng.warmup()
+    eng.start()
+    yield eng
+    eng.shutdown(drain=False)
+
+
+def test_engine_serves_the_reference_tokens_batched_and_alone(engine):
+    """Through DecodeEngine's normal path (scheduler, pool, one block
+    table, chunked prefill, the one decode signature): every request's
+    greedy tokens are the reference's own choices, and the same served
+    concurrently and one at a time."""
+    rng = np.random.RandomState(0)
+    requests = [(rng.randint(0, SPEC.vocab_size,
+                             int(rng.randint(9, 34))).tolist(),
+                 int(rng.randint(3, 12))) for _ in range(6)]
+    streams = [engine.submit(p, max_new_tokens=n) for p, n in requests]
+    together = [s.result(300) for s in streams]
+    arch, held = ref.arch_of(SPEC), ref.held_of(SPEC)
+    for (prompt, n), tokens in zip(requests, together):
+        assert len(tokens) == n
+        gaps, _ = ref.token_gaps(WEIGHTS, arch, held, prompt, tokens, 8)
+        assert max(gaps) <= TOL
+    alone = [engine.generate(p, max_new_tokens=n, timeout=300)
+             for p, n in requests[:3]]
+    assert alone == together[:3]
+
+
+def test_engine_counts_scored_and_attended_layers_apart(engine):
+    """The counters the benchmark reads: the positions attended under a
+    selection over all five layers, the positions scored and the index
+    keys read over the two scoring layers, and a step's attention
+    layer-calls split into scored and carried."""
+    from paddle_tpu import observe
+    prompt = list(range(20))
+    observe.enable()
+    try:
+        before = observe.snapshot()
+        engine.generate(prompt, max_new_tokens=4, timeout=300)
+        after = observe.snapshot()
+    finally:
+        observe.disable()
+        observe.reset()
+
+    def grown(name, **labels):
+        key = name + ('{%s}' % ','.join(
+            '%s=%s' % kv for kv in sorted(labels.items())) if labels else '')
+        return after['counters'].get(key, 0) - before['counters'].get(key, 0)
+
+    # three decode steps at lengths 20, 21, 22 (+1: the new token)
+    seen = sum(n + 1 for n in (20, 21, 22))
+    assert grown('decode.sparse_positions_seen') == 5 * seen
+    assert grown('decode.sparse_positions_selected') == 5 * 3 * 8
+    assert grown('decode.index_positions_scored') == 2 * seen
+    assert grown('decode.selection_layer_calls', how='scored') == 3 * 2
+    assert grown('decode.selection_layer_calls', how='carried') == 3 * 3
+    item = 4
+    assert grown('decode.cache_bytes_read', kind='lm_latent_full') == \
+        5 * 3 * 8 * 16 * item
+    assert grown('decode.cache_bytes_read', kind='lm_index_full') == \
+        2 * seen * 8 * item
+    assert grown('decode.moe_layer_steps') == 3 * 4
+    assert engine.kv_bytes_per_token == lm.kv_bytes_per_token(SPEC)
+
+
+@pytest.mark.parametrize('kw,error', [
+    (dict(prefix_cache=True), NotImplementedError),
+    (dict(spec_k=2), NotImplementedError),
+    (dict(kv_dtype='int8'), NotImplementedError)])
+def test_engine_refuses_what_it_refuses_for_every_selected_cache(kw, error):
+    """By the spec's properties, not by name: a kind that reads a
+    selection shares no frozen pages, and no block but 'post_ln' has a
+    test for speculation or quantized arenas."""
+    assert not SPEC.shares_frozen_pages()
+    with pytest.raises(error):
+        _engine(**kw)
+
+
+def test_handoff_is_refused_for_a_cache_that_is_not_per_head_rows(engine):
+    from paddle_tpu.serving import handoff
+    with pytest.raises(handoff.CacheKindError):
+        engine.read_pages([0])
+    with pytest.raises(handoff.CacheKindError):
+        handoff._geometry_header(engine)
+
+
+def test_programs_write_every_arena_in_place_and_keep_the_selection():
+    """The decode step and a prefill chunk as the executor jits them,
+    over a pool far larger than a block of the attention's gathers: no
+    instruction of the compiled program materialises a layer of either
+    arena, and the selection is no output: it never leaves the
+    device's program."""
+    from paddle_tpu.serving.decode.hlo_check import arena_sized_instructions
+    pool = 2048
+    eng = _engine(num_blocks=pool)
+    try:
+        smallest = min(pool * BS * k.width for k in SPEC.cache_kinds())
+        for which in ('decode', 8):
+            traced = eng.trace_program(which)
+            hlo = traced.lower().compile().as_text()
+            assert arena_sized_instructions(hlo, smallest) == []
+            outs = jax.tree_util.tree_leaves(traced.out_info)
+            assert not any(o.dtype == jnp.bool_ for o in outs)
+    finally:
+        eng.shutdown(drain=False)
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+def test_weights_go_in_and_come_out_in_the_declared_layout(dtype):
+    weights_round_trip(
+        _spec(dtype=dtype), WEIGHTS, {'lm_full_q_b.w', 'lm_full_idx_q.w'},
+        max_batch=2, block_size=BS, num_blocks=NB, pages_per_seq=PAGES)

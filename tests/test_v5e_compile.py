@@ -747,6 +747,48 @@ def test_the_shortcut_block_loads_at_its_published_geometry(one_chip):
     assert round(arena_b / 1e9, 2) == 2.68
 
 
+def test_the_carried_selection_loads_at_its_published_geometry(one_chip):
+    """glm_5_2 as the benchmark runs it (every published width, layers
+    2-6 of 78, 16 of 256 experts, 1/8 vocabulary; 16 slots, 17,408 pages
+    of 32, tables of 1,088 pages = 34,816 positions): the decode step
+    and the 512 chunk compile for the v5e with the argument bytes the
+    configuration states: 7.76 GB of weights + 3.85 GB of arenas = 11.6
+    GB, and the whole program under the chip's 15.75 GiB. The latent
+    arena holds five layers and the index arena two, both row-major; the
+    selection is carried on the device: bool [rows, 34,816] in the
+    layers' loop and in no output; a decode step attends by the
+    paged_decode_attention kernel in the lead layer and in the period's
+    layers (the scoring and the carried sites alike), and the
+    moe_routed_product kernel serves the routed layers."""
+    cell = 'glm_5_2.long_ctx_long_answers'
+    weights_b = arena_b = None
+    for op in ('paged_decode_step', 'paged_prefill'):
+        spec, geometry, hlo, weights_b, arena_b = _published_program(
+            one_chip, cell, op)
+        assert spec.layer_plan()[1:3] == (
+            ('carried_selection',) * 3 + ('full_attention',), 1)
+        for arena in ('bf16[5,17408,32,640]', 'bf16[2,17408,32,128]'):
+            assert set(re.findall(re.escape(arena) + r'\{([\d,]+)', hlo)) \
+                == {'3,2,1,0'}, (op, arena)
+        rows = geometry['max_batch' if op == 'paged_decode_step'
+                        else 'prefill_chunk']
+        assert 'pred[%d,34816]' % rows in hlo, op
+        # the cut's scan runs one period, so the compiler inlines it: the
+        # period's four routed layers are four sites of the one kernel
+        kernels = _kernels(hlo, 'moe_routed_product')
+        assert len(kernels) == 4, op
+        assert all('bf16[4,16,6144,2048]' in line for line in kernels), op
+        attends = _kernels(hlo, 'paged_decode_attention')
+        if op == 'paged_decode_step':
+            # the lead layer's call and the period's four
+            assert len(attends) == 5, len(attends)
+            assert all('bf16[5,17408,32,640]' in line for line in attends)
+        else:
+            assert attends == []
+    assert round(weights_b / 1e9, 2) == 7.76
+    assert round(arena_b / 1e9, 2) == 3.85
+
+
 @pytest.mark.parametrize('cell,arenas,calls', [
     # the lead layer's call and the scanned layers' are the one kernel
     ('kimi_k2_6.doc_qa_sessions', ['bf16[6,16384,32,640]'], 2),
