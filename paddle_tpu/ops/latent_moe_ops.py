@@ -12,7 +12,11 @@ and ``k_rope`` the last ``d_rope`` columns of ``x W_kva`` rotated
 Queries go through a rank-``q_rank`` bottleneck, ``c_q = s_q RMSNorm(x
 W_qa)``, ``[q_nope ; q_rope]_h = c_q W_qb`` (``W_qb`` and the indexer's
 ``W^I_q`` are held ``[out, q_rank]`` and contracted over their last
-axis: ``latent_param_shapes``).
+axis: ``latent_param_shapes``). Their stacks are shaped to heads, ``[n,
+heads, d, q_rank]``, before a layer is taken (``_heads_at``), because
+the v5e's compiler moves a split of the product's output onto the weight
+and copies a layer's slice with a reshape behind it out of its stack,
+where it reads a slice of the stack so shaped in place.
 
 The attention takes one of two forms, by how many query rows share a
 block table (``serving/decode/model.py``: ``latent_expands``, a function
@@ -225,6 +229,20 @@ def _at(stack, i):
     return jax.lax.dynamic_index_in_dim(stack, i, axis=0, keepdims=False)
 
 
+def _heads_at(c_q, stack, i, heads):
+    """``c_q`` [N, q_rank] times layer ``i`` (an int or a traced scalar)
+    of a stack held transposed, ``[n, heads x d, q_rank]``: float32 [N,
+    heads, d], which is ``_mm_t(c_q, _at(stack, i))`` split into heads.
+    The stack is shaped to its heads (a bitcast of the parameter) and
+    the layer taken after, never the other way round (module
+    docstring)."""
+    n, out, q_rank = stack.shape
+    w = _at(stack.reshape(n, heads, out // heads, q_rank), i)
+    return jax.lax.dot_general(
+        c_q.astype(w.dtype), w, (((1,), (2,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
 class LatentMoEBlock(object):
     """What ``_extend_rows`` asks of a block (embed, segments, logits)
     for LMSpec block='latent_moe'; module docstring."""
@@ -370,8 +388,9 @@ class LatentMoEBlock(object):
         scored = i if scored is None else scored
         heads, d_nope, d_rope = self.shape[kind]
         pos = step.pos
+        # QB is sliced where it is multiplied (_heads_at)
         w = {slot: _at(self.w[_TAG[kind] + slot], i)
-             for slot in self.attn_slots}
+             for slot in self.attn_slots if slot != 'QB'}
 
         def turned(x):
             return _rope_gptj_at(
@@ -385,7 +404,7 @@ class LatentMoEBlock(object):
         c_q = rms_norm(_mm(n, w['QA']), w['QLn'], self.eps) * s_q
         # the projections out of the query's rank are held transposed
         # (latent_param_shapes)
-        q = _mm_t(c_q, w['QB']).reshape(rows, heads, d_nope + d_rope)
+        q = _heads_at(c_q, self.w[_TAG[kind] + 'QB'], i, heads)
         down = _mm(n, w['KvA'])
         c_kv = rms_norm(down[:, :rank], w['KvLn'], self.eps) * s_kv
         k_rope = turned(down[:, None, rank:])[:, 0]
@@ -454,9 +473,9 @@ class LatentMoEBlock(object):
         (``index_rope_interleave``) in interleaved ones."""
         theta, d_rope = self.theta[kind], self.shape[kind][2]
         turn = _rope_gptj if self.index_interleaved else rope_half
-        w = {slot: _at(self.w[slot], i) for slot in _INDEX}
-        rows, heads = n.shape[0], self.index_heads
-        q = _mm_t(c_q, w['IdxQ']).reshape(rows, heads, -1)
+        w = {slot: _at(self.w[slot], i) for slot in _INDEX if slot != 'IdxQ'}
+        heads = self.index_heads
+        q = _heads_at(c_q, self.w['IdxQ'], i, heads)
         q = jnp.concatenate([turn(q[..., :d_rope], pos, theta),
                              q[..., d_rope:]], -1)
         k = _mm(n, w['IdxK'])

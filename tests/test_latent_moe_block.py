@@ -31,7 +31,7 @@ from paddle_tpu.ops import moe_held_ops as moe
 from paddle_tpu.ops import paged_decode_ops as pdo
 from paddle_tpu.serving.decode import DecodeEngine, LMSpec, random_weights
 from paddle_tpu.serving.decode import model as lm
-from util import as_held, weights_round_trip
+from util import as_held, cell_spec, heads_of_held, weights_round_trip
 
 TOL = 5e-5
 BS, PAGES, NB = 4, 12, 40            # 48 positions a sequence
@@ -697,3 +697,79 @@ def test_a_product_with_a_held_matrix_is_the_declared_product(dtype):
     assert got.dtype == want.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
+
+
+# ---------------------------- the heads taken from the stack as it is shaped
+LATENT_CELLS = ['dots3_note.long_ctx_steady', 'kimi_k2_6.doc_qa_sessions',
+                'longcat_flash_chat.chat_decode_heavy',
+                'glm_5_2.long_ctx_long_answers']
+
+
+def _sliced_then_split(c_q, stack, i, heads):
+    """``_heads_at`` as the block wrote it before: the layer sliced out
+    of the stack as it is held, the product's output split into heads."""
+    return pdo._mm_t(c_q, lmo._at(stack, i)).reshape(c_q.shape[0], heads, -1)
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+@pytest.mark.parametrize('cell', LATENT_CELLS)
+def test_heads_out_of_the_shaped_stack_are_the_split_product(
+        cell, dtype, monkeypatch):
+    """The four latent configurations at their rehearsal sizes, weights
+    in float32 and in bfloat16. ``_heads_at`` shapes a stack held ``[n,
+    heads x d, q_rank]`` to its heads before it takes the layer (so that
+    the v5e reads the slice where it lies: tests/test_v5e_compile.py);
+    that is ``_mm_t`` over the layer's slice with its output split into
+    heads: the same operands at the same precision, equal to the order
+    of the sum (this CPU sums a product over ``[heads, d, q_rank]`` and
+    one over ``[heads x d, q_rank]`` in whatever order suits each: the
+    indexer's three heads differ in the last bit, the others in none),
+    for every marked stack of the configuration (``q_b`` of each kind,
+    the indexer's ``idx_q``), every layer, the index an int or a traced
+    scalar. And an engine whose block is written the one way serves the
+    tokens of an engine whose block is written the other, through a
+    prompt's chunks and its decode steps, and leaves the same arenas
+    (float32 weights: this CPU has no bfloat16 product for a whole
+    program, written either way)."""
+    spec, sizes = cell_spec(cell, rehearsal=True, dtype=dtype)
+    weights = random_weights(spec, seed=55)
+    held = as_held(spec, {k: jnp.asarray(v, dtype)
+                          for k, v in weights.items()})
+    marked = sorted(lm.held_transposed(spec))
+    assert marked
+    rng = np.random.RandomState(5)
+    forms = [jax.jit(f, static_argnums=3)
+             for f in (lmo._heads_at, _sliced_then_split)]
+    for name in marked:
+        stack, heads = held[name], heads_of_held(spec, name)
+        c_q = jnp.asarray(rng.randn(5, stack.shape[-1]), jnp.float32)
+        for i in range(stack.shape[0]):
+            want = forms[1](c_q, stack, i, heads)
+            for index in (i, jnp.int32(i)):
+                got = forms[0](c_q, stack, index, heads)
+                assert got.dtype == want.dtype == jnp.float32
+                np.testing.assert_allclose(
+                    np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-6)
+    if dtype != 'float32':
+        return
+    prompt = rng.randint(0, spec.vocab_size,
+                         sizes['prefill_chunk'] + 5).tolist()
+    served = []
+    for form in (lmo._heads_at, _sliced_then_split):
+        monkeypatch.setattr(lmo, '_heads_at', form)
+        eng = DecodeEngine(spec, weights=weights, place=fluid.CPUPlace(),
+                           **sizes)
+        try:
+            eng.start()
+            tokens = eng.generate(prompt, max_new_tokens=4, timeout=300)
+            with eng._arena_mu:
+                arenas = [np.asarray(eng._scope.get(name))
+                          for name in eng._progs.arena_names]
+        finally:
+            eng.shutdown(drain=False)
+        served.append((tokens, arenas))
+    (tokens, arenas), (theirs, their_arenas) = served
+    assert len(tokens) == 4 and tokens == theirs
+    assert any(a.any() for a in arenas)
+    for a, b in zip(arenas, their_arenas):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from util import cell_spec, heads_of_held
+
 SIZE = {'bf16': 2, 'f32': 4, 's32': 4, 'pred': 1, 'u32': 4}
 PASSIVE = ('parameter', 'get-tuple-element', 'bitcast', 'tuple', 'while')
 
@@ -46,19 +48,31 @@ def _outside_fusions(hlo):
 
 
 def _materialized(hlo, at_least):
-    """(op, type[dims], bytes) of every instruction outside a fused
-    computation whose result is ``at_least`` bytes or more."""
+    """(op, type[dims], bytes, memory space) of every result outside a
+    fused computation that is ``at_least`` bytes or more: an
+    instruction's one array or each element of its tuple (a fusion with
+    several outputs, an asynchronous copy's or slice's destination and
+    source), each with the space its own layout names ('S(1)' for the
+    chip's fast memory, '' for HBM). A ``ConcatBitcast`` call writes
+    nothing: it names two asynchronous slices that lie end to end."""
     out = []
     for line in _outside_fusions(hlo):
-        m = re.match(r'^\s+(ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* '
-                     r'([\w\-]+)\(', line)
-        if not m or m.group(2) not in SIZE:
+        m = re.match(r'^\s+(ROOT )?%[\w.\-]+ = (\(.*?\)|\S+) ([\w\-]+)\(',
+                     line)
+        if not m or m.group(3) in PASSIVE or \
+                'custom_call_target="ConcatBitcast"' in line:
             continue
-        n = SIZE[m.group(2)]
-        for d in m.group(3).split(','):
-            n *= int(d)
-        if n >= at_least and m.group(4) not in PASSIVE:
-            out.append((m.group(4), '%s[%s]' % (m.group(2), m.group(3)), n))
+        for dtype, dims, layout in re.findall(
+                r'(\w+)\[([\d,]+)\](\{[^}]*\})?', m.group(2)):
+            if dtype not in SIZE:
+                continue
+            n = SIZE[dtype]
+            for d in dims.split(','):
+                n *= int(d)
+            if n >= at_least:
+                space = re.search(r'S\(\d+\)', layout)
+                out.append((m.group(3), '%s[%s]' % (dtype, dims), n,
+                            space.group(0) if space else ''))
     return out
 
 
@@ -657,18 +671,6 @@ def _paged_op(spec, geometry, op, rows):
     return fn, (weights, arenas, feeds)
 
 
-def _published(cell):
-    """(spec, engine geometry) of a cell as the benchmark runs it."""
-    import os
-    from benchmark import manifest
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    resolved = manifest.resolve(manifest.load(root), cell)
-    config = {k: v for k, v in resolved['config'].items()
-              if k != 'rehearsal'}
-    return (manifest.load_module(resolved['runner']).spec_of(config),
-            config['engine'])
-
-
 def _compiled_at_published_size(one_chip, spec, geometry, op, rows):
     """``op`` over ``rows`` compiled for the v5e, the arenas donated as
     the executor donates them: (its HLO, the weights' bytes, the arenas'
@@ -704,7 +706,7 @@ def _published_program(one_chip, cell, op):
     (``_compiled_at_published_size``), compiled once for the tests that
     read it."""
     if (cell, op) not in _PUBLISHED:
-        spec, geometry = _published(cell)
+        spec, geometry = cell_spec(cell)
         rows = geometry['max_batch' if op == 'paged_decode_step'
                         else 'prefill_chunk']
         _PUBLISHED[cell, op] = (spec, geometry) + \
@@ -813,37 +815,70 @@ def test_a_latent_decode_step_attends_by_the_kernel_at_published_size(
         assert len(attends) == calls
 
 
+# what moves a result without a program's compute waiting on it
+ASYNC = ('slice-start', 'slice-done', 'copy-start', 'copy-done')
+# what the one form leaves, in both programs of the cell: dots3_note's
+# full kind has a layer in the lead and one in the inlined period, the
+# compiler brings the whole stack of two into the fast memory and slices
+# the period's layer back out of it into HBM ('') (PERF.md section 7,
+# after PR 55)
+LEFT = {'dots3_note.long_ctx_steady': [
+    ('slice', 'bf16[1,128,192,1024]', 128 * 192 * 1024 * 2, '')]}
+
+
 @pytest.mark.parametrize('op', ['paged_decode_step', 'paged_prefill'])
 @pytest.mark.parametrize('cell', [
     'longcat_flash_chat.chat_decode_heavy', 'kimi_k2_6.doc_qa_sessions',
-    'dots3_note.long_ctx_steady'])
+    'dots3_note.long_ctx_steady', 'glm_5_2.long_ctx_long_answers'])
 def test_no_latent_program_relays_a_projection_out_of_the_query_rank(
         one_chip, cell, op):
-    """The three latent cells' decode step and 512 chunk as the benchmark
+    """The four latent cells' decode step and 512 chunk as the benchmark
     runs them, compiled for the v5e with ``q_b`` of every kind and the
     indexer's ``idx_q`` held ``[n, out, q_rank]``
-    (``model.HeldTransposed``): outside fused computations no ``copy``
-    or ``transpose`` writes one of those stacks or one layer's slice of
-    it, in any order of its axes. Held as declared, ``[n, q_rank,
-    out]``, the compiler re-laid them before it multiplied (PERF.md,
-    PR 52): longcat's whole stack at each program's entry (``copy
-    bf16[8,1536,12288]{1,2,0}``, 302 MB), kimi's ``bf16[1,1536,12288]``
-    in the scan and ``[12288,1536]`` in the lead layer, dots3's
-    ``[1,1024,24576]``, ``[16384,1024]`` and the indexer's
-    ``[8192,1024]``."""
+    (``model.HeldTransposed``) and shaped to their heads before a layer
+    is taken (``latent_moe_ops._heads_at``): outside fused computations
+    nothing but an asynchronous slice or copy has a result, a tuple's
+    elements included, with the extents of one of those stacks or of one
+    layer's slice of it, as held or as shaped to heads, in any order of
+    its axes: no ``fusion``, ``slice``, ``copy`` or ``transpose`` writes
+    one; the products read the parameter where it lies. Held as
+    declared, ``[n, q_rank, out]``, the compiler re-laid them before it
+    multiplied (PERF.md, PR 52): longcat's whole stack at each program's
+    entry (``copy bf16[8,1536,12288]{1,2,0}``, 302 MB), kimi's
+    ``bf16[1,1536,12288]`` in the scan and ``[12288,1536]`` in the lead
+    layer, dots3's ``[1,1024,24576]``, ``[16384,1024]`` and the
+    indexer's ``[8192,1024]``. Sliced before it was shaped, each layer's
+    slice was copied out of its stack at the program's entry (PERF.md,
+    PR 55): glm_5_2's ``fusion (bf16[1,16384,2048] x 5)``, four of them
+    into HBM, 335 MB read and 268 written a program, and the indexer's
+    ``(bf16[1,4096,2048] x 2)``; the other three cells' into the fast
+    memory. What stays is ``LEFT``."""
     from paddle_tpu.serving.decode import model as lm
     spec, _, hlo, _, _ = _published_program(one_chip, cell, op)
     table = lm.block_param_shapes(spec)
     marked = lm.held_transposed(spec)
-    assert marked and all(n.endswith(('_q_b.w', '_idx_q.w')) for n in marked)
+    assert marked
 
     def extents(dims):
         return tuple(sorted(int(d) for d in dims if int(d) != 1))
-    # a stack's extents or one layer's, in whatever order
+    # a stack's extents or one layer's, held or shaped, in whatever order
     theirs = set()
     for name in marked:
-        theirs |= {extents(table[name][0]), extents(table[name][0][1:])}
-    relaid = [(kind, shape) for kind, shape, _ in _materialized(hlo, 1 << 22)
-              if kind in ('copy', 'transpose') and extents(
-                  shape.split('[')[1].rstrip(']').split(',')) in theirs]
-    assert relaid == [], relaid
+        n, q_rank, out = table[name][0]
+        heads = heads_of_held(spec, name)
+        for layer in ((out, q_rank), (heads, out // heads, q_rank)):
+            theirs |= {extents(layer), extents((n,) + layer)}
+    # an unmarked parameter's layer in its own order of axes is that
+    # parameter's: the value up-projection of dots3's sliding kind,
+    # [64, 1024, 128] a layer, has the extents of the indexer's queries
+    # shaped to heads, [64, 128, 1024]
+    unmarked = {tuple(d for d in shape[1:] if d != 1)
+                for name, (shape, _, _) in table.items()
+                if name not in marked}
+
+    def dims(shape):
+        return [int(d) for d in shape.split('[')[1].rstrip(']').split(',')]
+    written = [r for r in _materialized(hlo, 1 << 22)
+               if r[0] not in ASYNC and extents(dims(r[1])) in theirs
+               and tuple(d for d in dims(r[1]) if d != 1) not in unmarked]
+    assert written == LEFT.get(cell, []), written

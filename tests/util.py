@@ -31,6 +31,34 @@ def as_held(spec, weights):
             else jnp.asarray(w) for name, w in weights.items()}
 
 
+def cell_spec(cell, rehearsal=False, **over):
+    """(spec, engine arguments) of a benchmark cell's configuration as
+    the benchmark runs it, or with its ``rehearsal`` sizes laid over
+    (what ``--rehearsal`` runs); ``over`` replaces keys of the config."""
+    import os
+    from benchmark import manifest
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    resolved = manifest.resolve(manifest.load(root), cell)
+    config = dict(resolved['config'])
+    small = config.pop('rehearsal')
+    if rehearsal:
+        config.update(small)
+    config.update(over)
+    return (manifest.load_module(resolved['runner']).spec_of(config),
+            config['engine'])
+
+
+def heads_of_held(spec, name):
+    """The heads a latent block splits the product with the parameter
+    ``name`` into, for a name of ``model.held_transposed(spec)``: a
+    kind's ``q_b`` or the indexer's ``idx_q``."""
+    from paddle_tpu.serving.decode import model as lm
+    if name.endswith('_idx_q.w'):
+        return spec.index_n_heads
+    assert name.endswith('_q_b.w'), name
+    return spec.latent[lm.FULL if '_full_' in name else lm.SLIDING].n_head
+
+
 def weights_round_trip(spec, weights, marked, **engine_kw):
     """``weights`` ({name: float32 array}, declared layout) loaded into
     a DecodeEngine of ``spec``: ``export_weights`` and ``device_weights``
