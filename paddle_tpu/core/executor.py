@@ -388,7 +388,11 @@ class Executor(object):
         multi = steps is not None
         kind = 'multi' if multi else 'single'
         n_steps = steps if multi else 1
-        with _obs.span('executor.lookup', record='executor.lookup_seconds'):
+        # the five children below tile the call's ``executor.run`` span;
+        # their histograms say which program it was
+        labels = self._phase_labels(program) if _obs.enabled() else None
+        with _obs.span('executor.lookup', record='executor.lookup_seconds',
+                       labels=labels):
             program, scope, fetch_names, feed_vals, qpolicy, bpolicy = \
                 self._resolve_call(program, feed, fetch_list, scope)
             if stacked_feed:
@@ -423,24 +427,59 @@ class Executor(object):
             self.last_cache_miss = missed
             kid = self._account_lookup(kind, key, missed, run_span)
 
-        with self._dispatch_lock:
+        locked = False
+        try:
+            # the flag follows the acquire with nothing between: whatever
+            # the span's exit raises, ``finally`` gives the lock back
+            with _obs.span('executor.lock', record='executor.lock_seconds',
+                           labels=labels):
+                self._dispatch_lock.acquire()
+                locked = True
             with _obs.span('executor.prepare',
-                           record='executor.prepare_seconds'):
+                           record='executor.prepare_seconds', labels=labels):
                 scope_vals, feed_vals = self._prepare_inputs(
                     'Executor.run_steps' if multi else 'Executor.run',
                     program, compiled, scope, feed_vals,
                     feed_stack_axis=stacked_feed)
-            if _obs.enabled() and compiled.flops is None:
-                one_feed = {n: v[0] for n, v in feed_vals.items()} \
-                    if stacked_feed else feed_vals
-                self._cost_account(compiled, key, scope_vals, one_feed)
-            step0 = self._next_steps(n_steps)
+                if _obs.enabled() and compiled.flops is None:
+                    one_feed = {n: v[0] for n, v in feed_vals.items()} \
+                        if stacked_feed else feed_vals
+                    self._cost_account(compiled, key, scope_vals, one_feed)
+                step0 = self._next_steps(n_steps)
             fetches, new_scope = self._enqueue(
-                kind, kid, missed, compiled, scope_vals, feed_vals, step0)
-            for name, value in new_scope.items():
-                scope.set(name, value)
-        return self._hand_back(fetches, n_steps, return_numpy,
-                               return_handle)
+                kind, kid, missed, compiled, scope_vals, feed_vals, step0,
+                labels)
+            with _obs.span('executor.writeback',
+                           record='executor.writeback_seconds',
+                           labels=labels):
+                for name, value in new_scope.items():
+                    scope.set(name, value)
+                # a return_numpy call waits for the device in _hand_back
+                # (``executor.fetch``, a child of this span): other
+                # threads dispatch meanwhile
+                self._dispatch_lock.release()
+                locked = False
+                # the step's inputs were donated and the scope now holds
+                # their successors: this dict is the last holder of the
+                # old arrays, and letting go of a training step's
+                # thousand takes most of a millisecond. Here it has a
+                # name; left to the frame's end it has none
+                del scope_vals
+                return self._hand_back(fetches, n_steps, return_numpy,
+                                       return_handle)
+        finally:
+            if locked:
+                self._dispatch_lock.release()
+
+    @staticmethod
+    def _phase_labels(program):
+        """The one label of the five phases' histograms: the Program's
+        name (``decode_step``, ``prefill_<bucket>``; the decode engine
+        sets them), ``main`` for one without. Built once a call, and
+        only with observe on."""
+        if program is None:
+            program = default_main_program()
+        return {'program': program.name or 'main'}
 
     def _compile_multi(self, program, feed_names, fetch_names, qpolicy,
                        bpolicy, steps, stacked_feed):
@@ -505,18 +544,31 @@ class Executor(object):
         return kid
 
     def _enqueue(self, kind, kid, missed, compiled, scope_vals, feed_vals,
-                 step_i):
+                 step_i, labels):
         """Hand the step to the device. The first dispatch of a key is
         the XLA compile plus one step: a near-free compile-time signal
         even when the AOT cost probe is off (PADDLE_TPU_OBSERVE_COST=0),
-        so it is recorded apart from the enqueues of a warm key."""
-        if missed:
-            record, labels = ('executor.first_dispatch_seconds',
-                              {'kind': kind, 'key': kid})
-        else:
-            record, labels = 'executor.enqueue_seconds', None
-        with _obs.span('executor.enqueue', record=record, labels=labels):
+        so it is recorded apart from the enqueues of a warm key. A warm
+        key's call is also read on the thread's CPU clock
+        (``executor.enqueue_cpu_seconds``): where that falls short of
+        the wall time the thread was off the CPU (the runtime, the GIL,
+        the scheduler), not computing. Only on a host whose thread clock
+        is finer than a call: one that ticks at 10 ms reads 0 or a tick
+        (docs/observability.md)."""
+        if labels is None:       # observe is off
             return compiled.fn(scope_vals, feed_vals, step_i)
+        if missed:
+            with _obs.span('executor.enqueue',
+                           record='executor.first_dispatch_seconds',
+                           labels={'kind': kind, 'key': kid}):
+                return compiled.fn(scope_vals, feed_vals, step_i)
+        with _obs.span('executor.enqueue',
+                       record='executor.enqueue_seconds', labels=labels):
+            cpu0 = time.thread_time()
+            out = compiled.fn(scope_vals, feed_vals, step_i)
+            _obs.record('executor.enqueue_cpu_seconds',
+                        time.thread_time() - cpu0, **labels)
+            return out
 
     def _hand_back(self, fetches, steps, return_numpy, return_handle):
         if return_handle:
@@ -648,8 +700,6 @@ class Executor(object):
         all_ops = list(block.ops)
         reads_cache = {}  # amortizes the sub-block walk across the 3 passes
         ops = _prune_ops(block, all_ops, fetch_names, reads_cache)
-        if _obs.enabled():
-            _obs.inc('executor.ops_pruned_total', len(all_ops) - len(ops))
 
         # Data vars actually consumed must be fed.
         consumed = set()

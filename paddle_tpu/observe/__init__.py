@@ -50,6 +50,7 @@ metric catalog.
 
 import atexit
 import contextlib
+import gc
 import json
 import os
 import sys
@@ -129,6 +130,8 @@ def enable(jsonl=None, trace=None, every_secs=30.0):
     if not _atexit_armed:
         _atexit_armed.append(True)
         atexit.register(_atexit_flush)
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
 
 
 def jsonl_path():
@@ -171,12 +174,15 @@ def disable():
         export_trace()
     _enabled = False
     _flight_on = _flight_armed
+    if _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
 
 
 def reset():
     """Clear every metric, span, flight event, anomaly baseline, and the
-    goodput ledger (sink config and the enabled flag survive).
-    profiler.reset_profiler() calls this."""
+    goodput ledger (sink config and the enabled flag survive, and with
+    the flag the collector's callback). profiler.reset_profiler() calls
+    this."""
     _REG.clear()
     _SPANS.clear()
     _GOODPUT.reset()
@@ -195,6 +201,37 @@ def _atexit_flush():
             export_trace()
         except Exception:
             pass
+
+
+# ------------------------------------------------ the interpreter's pauses
+_gc_began = None
+
+
+def _on_gc(phase, info):
+    """In ``gc.callbacks`` while telemetry is on: a collection stops
+    every Python thread wherever it strikes, so each is a ``host.gc``
+    span on the ring's clock, on the thread it struck (the latest to
+    start, hence the innermost over whatever span was open there), a
+    record of ``host.gc_seconds{generation}`` and one more in
+    ``host.gc_total{generation}``; ``host.gc_seconds_total`` sums the
+    three generations. It runs inside the collector: explicit bounds, no
+    profiler annotation, nothing but the ring's and the registry's own
+    appends (both locks are re-entrant: the collection may have struck
+    this thread inside either)."""
+    global _gc_began
+    if phase == 'start':
+        _gc_began = time.perf_counter()
+        return
+    t1 = time.perf_counter()
+    t0, _gc_began = _gc_began, None
+    if t0 is None or not _enabled:
+        return
+    generation = info['generation']
+    _SPANS.add_span('host.gc', t0, t1, info)
+    _REG.histogram('host.gc_seconds').observe(t1 - t0,
+                                              generation=generation)
+    _REG.counter('host.gc_total').inc(generation=generation)
+    _REG.counter('host.gc_seconds_total').inc(t1 - t0)
 
 
 # --------------------------------------------------------------- access

@@ -209,7 +209,7 @@ class Counter(_Metric):
             return self._values.get(_label_key(labels), 0)
 
     def _snapshot_into(self, out):
-        for lk, v in self._values.items():
+        for lk, v in list(self._values.items()):
             out[_render(self.name, lk)] = v
 
 
@@ -317,11 +317,14 @@ class Histogram(_Metric):
         """(count, sum) across every label set — the profiler's
         summarize() substrate."""
         with self._lock:
-            return (sum(st.count for st in self._values.values()),
-                    sum(st.total for st in self._values.values()))
+            # a copy: a collection's first record of a generation lands
+            # here through the same (reentrant) lock, mid-iteration
+            states = list(self._values.values())
+            return (sum(st.count for st in states),
+                    sum(st.total for st in states))
 
     def _snapshot_into(self, out):
-        for lk, st in self._values.items():
+        for lk, st in list(self._values.items()):
             out[_render(self.name, lk)] = st.stats()
 
 
@@ -368,7 +371,9 @@ class Registry(object):
         'histograms': {rendered_name: stats_dict}} — JSON-ready."""
         out = {'counters': {}, 'gauges': {}, 'histograms': {}}
         with self._lock:
-            for m in self._metrics.values():
+            # copies: a collection that strikes this thread here records
+            # its pause (observe._on_gc), which may add a series
+            for m in list(self._metrics.values()):
                 m._snapshot_into(out[m.kind + 's'])
         return out
 

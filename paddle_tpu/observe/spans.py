@@ -55,6 +55,20 @@ MAX_EVENTS = 200000
 VOTE_US = 25.0
 PAIR_US = 500.0
 
+# every event carries the process id. ``os.getpid()`` is a system call
+# an event (6 us on the benchmark's sandboxed host, PERF.md section 6,
+# PR 56: as much as the rest of a span), so it is read once here and
+# again in a forked child
+_pid = os.getpid()
+
+
+def _refresh_pid():
+    global _pid
+    _pid = os.getpid()
+
+
+os.register_at_fork(after_in_child=_refresh_pid)
+
 
 class _Span(object):
     __slots__ = ('name', 'attrs', 't0', 'ann')
@@ -136,7 +150,10 @@ class StateClock(object):
 
 class SpanRecorder(object):
     def __init__(self):
-        self._lock = threading.Lock()
+        # re-entrant: a garbage collection can strike a thread that
+        # holds it, and its ``host.gc`` span is added from inside the
+        # collector (observe._on_gc)
+        self._lock = threading.RLock()
         self._events = collections.deque()
         self._dropped = 0
         # observe.__init__ points this at the registry's
@@ -190,7 +207,7 @@ class SpanRecorder(object):
                 top.ann.__exit__(None, None, None)
             except Exception:
                 pass
-        ev = {'name': top.name, 'ph': 'X', 'pid': os.getpid(),
+        ev = {'name': top.name, 'ph': 'X', 'pid': _pid,
               'tid': threading.get_ident(),
               'ts': (self._epoch0 + top.t0) * 1e6,
               'dur': (t1 - top.t0) * 1e6}
@@ -224,7 +241,7 @@ class SpanRecorder(object):
         was clocked on another thread (e.g. a request's queue wait,
         started at submit() but observed ending in the batcher) still
         renders with exact bounds."""
-        ev = {'name': name, 'ph': 'X', 'pid': os.getpid(),
+        ev = {'name': name, 'ph': 'X', 'pid': _pid,
               'tid': threading.get_ident() if tid is None else tid,
               'ts': (self._epoch0 + t0) * 1e6,
               'dur': max(0.0, t1 - t0) * 1e6}
@@ -235,7 +252,7 @@ class SpanRecorder(object):
     def add_instant(self, name, attrs=None):
         """Record a zero-duration mark on the calling thread (scope
         't'): per-token decode events, admission decisions, kills."""
-        ev = {'name': name, 'ph': 'i', 's': 't', 'pid': os.getpid(),
+        ev = {'name': name, 'ph': 'i', 's': 't', 'pid': _pid,
               'tid': threading.get_ident(),
               'ts': (self._epoch0 + time.perf_counter()) * 1e6}
         if attrs:
@@ -266,7 +283,7 @@ class SpanRecorder(object):
 
     def _flow_event(self, ph, handle, attrs, bind_enclosing=False):
         ev = {'name': handle.name, 'cat': 'flow', 'ph': ph,
-              'id': handle.flow_id, 'pid': os.getpid(),
+              'id': handle.flow_id, 'pid': _pid,
               'tid': threading.get_ident(),
               'ts': (self._epoch0 + time.perf_counter()) * 1e6}
         if bind_enclosing:
@@ -287,7 +304,7 @@ class SpanRecorder(object):
                 return
             self._proc_labels.add(label)
         self._append({'name': 'process_name', 'ph': 'M',
-                      'pid': os.getpid(), 'tid': threading.get_ident(),
+                      'pid': _pid, 'tid': threading.get_ident(),
                       'args': {'name': str(label)}})
 
     # ---------------------------------------------------------- export

@@ -31,7 +31,9 @@ NEW_NAMES = ('decode.worker_seconds', 'decode.step_build_seconds',
              'decode.step_emit_seconds', 'decode.step_live_tokens',
              'executor.run_seconds', 'executor.lookup_seconds',
              'executor.prepare_seconds', 'executor.enqueue_seconds',
-             'executor.fetch_seconds')
+             'executor.fetch_seconds', 'executor.lock_seconds',
+             'executor.writeback_seconds', 'executor.enqueue_cpu_seconds')
+PHASES = ('lookup', 'lock', 'prepare', 'enqueue', 'writeback')
 PROMPTS = [[1, 2, 3], [4, 5, 6, 7, 8, 9, 10], [11], [12, 13, 14, 15]]
 
 
@@ -84,7 +86,7 @@ def test_worker_and_executor_spans_nest_on_one_host_line(tmp_path):
     runs = [r for r in by_name['executor.run']
             if any(_inside(r, d) for d in dispatches)]
     assert len(runs) == len(dispatches)
-    for part in ('lookup', 'prepare', 'enqueue'):
+    for part in PHASES:
         inner = by_name['executor.' + part]
         assert sum(1 for c in inner
                    if any(_inside(c, r) for r in runs)) == len(runs)
@@ -179,6 +181,152 @@ def test_speculative_steps_take_the_same_spans():
         observe.get_counter('decode.steps_total')
 
 
+def _fit_a_line():
+    """(executor, program, scope, feed, loss) of a small training
+    program whose startup has run."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name='x', shape=[13], dtype='float32')
+        y = fluid.layers.data(name='y', shape=[1], dtype='float32')
+        cost = fluid.layers.mean(fluid.layers.square_error_cost(
+            fluid.layers.fc(input=x, size=1), y))
+        fluid.optimizer.SGD(learning_rate=0.05).minimize(cost)
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = fluid.core.scope.Scope()
+    exe.run(startup, scope=scope)
+    feed = {'x': np.zeros((4, 13), 'float32'),
+            'y': np.zeros((4, 1), 'float32')}
+    return exe, main, scope, feed, cost
+
+
+def _samples(name, **labels):
+    """A histogram's records in the order they were made (under the
+    reservoir's cap nothing is sampled away)."""
+    from paddle_tpu.observe.registry import _label_key
+    return list(observe.histogram(name)._values[_label_key(labels)].samples)
+
+
+def test_the_five_children_of_a_run_tile_it():
+    exe, main, scope, feed, cost = _fit_a_line()
+    exe.run(main, feed=feed, fetch_list=[cost], scope=scope)   # compiles
+    observe.enable()
+    calls = 20
+    for i in range(calls):
+        # one in two waits for its fetch: executor.fetch then lies under
+        # executor.writeback, a grandchild
+        exe.run(main, feed=feed, fetch_list=[cost], scope=scope,
+                return_numpy=bool(i % 2))
+    evs = [e for e in observe.spans().events() if e['ph'] == 'X']
+    runs = [e for e in evs if e['name'] == 'executor.run']
+    assert len(runs) == calls
+    gaps = []
+    for run in runs:
+        lo, hi = run['ts'], run['ts'] + run['dur']
+        kids = [e for e in evs if e['name'] in
+                {'executor.' + p for p in PHASES}
+                and lo <= e['ts'] and e['ts'] + e['dur'] <= hi]
+        # each once, in this order, none over the next
+        assert [e['name'] for e in sorted(kids, key=lambda e: e['ts'])] \
+            == ['executor.' + p for p in PHASES]
+        kids.sort(key=lambda e: e['ts'])
+        for a, b in zip(kids, kids[1:]):
+            assert a['ts'] + a['dur'] <= b['ts']
+        gaps.append(run['dur'] - sum(e['dur'] for e in kids))
+    fetches = [e for e in evs if e['name'] == 'executor.fetch']
+    backs = [e for e in evs if e['name'] == 'executor.writeback']
+    assert len(fetches) == calls // 2
+    assert all(any(b['ts'] <= f['ts'] and f['ts'] + f['dur']
+                   <= b['ts'] + b['dur'] for b in backs) for f in fetches)
+    # what lies under no child is the span machinery's own microseconds
+    # (the least of twenty: a pause between two children is not the
+    # executor's)
+    assert 0 <= min(gaps) < 100.0, gaps
+
+
+def test_every_phase_says_which_program():
+    exe, main, scope, feed, cost = _fit_a_line()
+    twin = main.clone()
+    twin.name = 'twin_step'
+    observe.enable()
+    for prog, calls in ((main, 3), (twin, 4)):
+        for _ in range(calls):
+            exe.run(prog, feed=feed, fetch_list=[cost], scope=scope,
+                    return_numpy=False)
+    hists = observe.snapshot()['histograms']
+    for phase in PHASES + ('enqueue_cpu',):
+        keys = {k: v['count'] for k, v in hists.items()
+                if k.startswith('executor.%s_seconds' % phase)}
+        warm = 1 if phase.startswith('enqueue') else 0   # the first compiles
+        assert keys == {
+            'executor.%s_seconds{program=main}' % phase: 3 - warm,
+            'executor.%s_seconds{program=twin_step}' % phase: 4 - warm}, phase
+    # the first dispatch of a key keeps its own series and labels
+    assert sum(v['count'] for k, v in hists.items() if k.startswith(
+        'executor.first_dispatch_seconds{')) == 2
+    # the thread's CPU time of a call lies inside its wall time, record
+    # by record, wherever the thread's clock is finer than a call (a
+    # sandboxed host's may tick at 10 ms: a record is then 0 or a tick)
+    seen, until = set(), time.perf_counter() + 0.02
+    while time.perf_counter() < until:
+        seen.add(time.thread_time())
+    tick = 0.02 / max(len(seen) - 1, 1)
+    for prog in ('main', 'twin_step'):
+        wall = _samples('executor.enqueue_seconds', program=prog)
+        cpu = _samples('executor.enqueue_cpu_seconds', program=prog)
+        assert len(wall) == len(cpu) >= 2
+        assert all(0 <= c <= w + 2e-6 + tick for c, w in zip(cpu, wall)), \
+            (cpu, wall, tick)
+
+
+def test_a_decode_step_and_a_prefill_feed_different_series():
+    observe.enable()
+    _serve()
+    hists = observe.snapshot()['histograms']
+    steps = observe.get_counter('decode.steps_total')
+    for phase in PHASES + ('enqueue_cpu',):
+        by_program = {k[k.index('{'):]: v['count'] for k, v in hists.items()
+                      if k.startswith('executor.%s_seconds{' % phase)}
+        assert '{program=decode_step}' in by_program, (phase, by_program)
+        assert any(k.startswith('{program=prefill_') for k in by_program)
+        assert all(k.startswith('{program=') and ',' not in k
+                   for k in by_program)
+    # warm-up compiled the step, so every step of the worker is a record
+    assert hists['executor.lookup_seconds{program=decode_step}']['count'] \
+        >= steps > 0
+
+
+def test_observe_off_builds_no_label_dict(monkeypatch):
+    exe, main, scope, feed, cost = _fit_a_line()
+    built = []
+    real = fluid.Executor._phase_labels
+    monkeypatch.setattr(fluid.Executor, '_phase_labels', staticmethod(
+        lambda program: built.append(program) or real(program)))
+    exe.run(main, feed=feed, fetch_list=[cost], scope=scope)
+    exe.run_steps(2, main, feed=feed, fetch_list=[cost], scope=scope)
+    assert built == []
+    assert observe.spans().events() == []
+    observe.enable()
+    exe.run(main, feed=feed, fetch_list=[cost], scope=scope)
+    assert built == [main]
+    assert real(None) == {'program': 'main'}        # the default program
+
+
+def test_a_span_that_fails_at_its_exit_does_not_keep_the_dispatch_lock():
+    """``executor.lock``'s exit records a histogram; a series of that
+    name under another type raises there, after the acquire. The lock
+    comes back all the same, so the next dispatch does not hang."""
+    exe, main, scope, feed, cost = _fit_a_line()
+    exe.run(main, feed=feed, fetch_list=[cost], scope=scope)   # compiles
+    observe.enable()
+    observe.inc('executor.lock_seconds')        # a counter of that name
+    with pytest.raises(TypeError):
+        exe.run(main, feed=feed, fetch_list=[cost], scope=scope)
+    assert exe._dispatch_lock.acquire(blocking=False)
+    exe._dispatch_lock.release()
+    observe.disable()
+    assert exe.run(main, feed=feed, fetch_list=[cost], scope=scope)
+
+
 def _lowered(exe, program, feed, fetch, scope=None):
     import jax
     fn, scope_vals, feed_vals = exe.compile_step(
@@ -226,18 +374,7 @@ def test_programs_and_ops_carry_names_a_trace_can_be_searched_by():
     assert 'jit(prefill_8)/paged_prefill/' in text
 
     # a training program: forward, backward and optimizer ops
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        x = fluid.layers.data(name='x', shape=[13], dtype='float32')
-        y = fluid.layers.data(name='y', shape=[1], dtype='float32')
-        cost = fluid.layers.mean(fluid.layers.square_error_cost(
-            fluid.layers.fc(input=x, size=1), y))
-        fluid.optimizer.SGD(learning_rate=0.05).minimize(cost)
-    exe = fluid.Executor(fluid.CPUPlace())
-    scope = fluid.core.scope.Scope()
-    exe.run(startup, scope=scope)
-    feed = {'x': np.zeros((4, 13), 'float32'),
-            'y': np.zeros((4, 1), 'float32')}
+    exe, main, scope, feed, cost = _fit_a_line()
     text = _lowered(exe, main, feed, cost, scope)
     assert 'module @jit_train_step' in text
     for scope_name in ('jvp(mul)/dot_general',
