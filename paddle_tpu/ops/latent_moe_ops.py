@@ -81,7 +81,15 @@ heads (``q^I = c_q W^I_q``, ``w = x W^I_w / sqrt(heads x width)``), and
 sees the ``index_topk`` positions ``s <= t`` of largest ``I``: exactly
 those ``lax.top_k`` returns (ties to the lower position), found without
 a sort (``select_topk``: the k-th largest score by bisection over the
-scores' bit patterns, 32 counting passes). The choice reaches the
+scores' bit patterns, 32 counting passes, and the last tie it has room
+for). Both halves take their bounds from what the rows hold
+(``step.lens``), not from what a table addresses: a decode step's
+scores run the (row, column block) pairs of its live rows, as its
+attention does (``index_scores``), and the counting runs over a row
+tile's live column blocks with the keys in VMEM
+(``pallas/selection_kth.py``), not at all where no row of the tile
+holds more than ``index_topk`` positions: such a row sees every
+position it holds. The choice reaches the
 attention as a mask over its column blocks: a position left out
 contributes exactly 0, and the blocks are still the pages the row
 holds, so the program reads every cached row of a full layer and
@@ -122,6 +130,8 @@ expert, the experts held here computed (ops/moe_held_ops.py) and the
 shared experts added at weight 1.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -130,8 +140,10 @@ from . import moe_held_ops as moe
 from .paged_decode_ops import (_attention_of, _mm, _mm_t, _rope_gptj,
                                _rope_gptj_at, _write_in_place,
                                period_segments)
-from .pallas.paged_attention import (paged_attention_one_table,
-                                     pages_per_block)
+from .pallas import selection_kth
+from .pallas.paged_attention import (BLOCK_ROWS, paged_attention_one_table,
+                                     pages_held, pages_per_block, pairs_at,
+                                     row_pairs)
 
 FULL, SLIDING = 'full_attention', 'sliding_attention'
 # a layer of the plan's kind CARRIED attends as a full layer does, in the
@@ -162,29 +174,53 @@ def rope_half(x, pos, theta):
     return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], -1)
 
 
-def select_topk(scores, k):
-    """``scores`` [N, C] float32 (-inf: not a candidate) -> bool [N, C]:
-    the ``k`` largest of each row, ties to the lower column, which is
-    the set ``lax.top_k(scores, k)`` indexes; every column where C <= k.
-    No sort: the k-th largest value is built bit by bit over an
-    order-preserving map of the floats onto uint32 (32 passes that
-    count), then the ties at it are taken from the left."""
+def kth_and_cut_dense(scores, lens, k):
+    """What ``pallas/selection_kth.py::kth_and_cut`` returns, by passes
+    over every column whatever the rows hold: the form of every platform
+    but the TPU, and what the kernel is held to."""
     n, c = scores.shape
-    if c <= k:
-        return jnp.ones((n, c), bool)
-    bits = jax.lax.bitcast_convert_type(scores, jnp.uint32)
-    key = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+    col = jnp.arange(c, dtype=jnp.int32)[None, :]
+    lowest = selection_kth.LOWEST
+    key = jnp.where(col < lens[:, None], selection_kth.ordered_keys(scores),
+                    lowest)
 
     def grow(i, kth):
-        cand = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        cand = kth ^ (jnp.int32(1) << (31 - i))
         enough = jnp.sum(key >= cand[:, None], axis=1) >= k
         return jnp.where(enough, cand, kth)
-    kth = jax.lax.fori_loop(0, 32, grow, jnp.zeros((n,), jnp.uint32))
-    above = key > kth[:, None]
-    ties = key == kth[:, None]
-    room = k - jnp.sum(above, axis=1, dtype=jnp.int32)
-    return above | (ties & (jnp.cumsum(ties, axis=1, dtype=jnp.int32)
-                            <= room[:, None]))
+    kth = jax.lax.fori_loop(0, 32, grow, jnp.full((n,), lowest, jnp.int32))
+    room = k - jnp.sum(key > kth[:, None], axis=1, dtype=jnp.int32)
+    ties = jnp.cumsum(key == kth[:, None], axis=1, dtype=jnp.int32)
+    cut = jnp.argmax(ties >= room[:, None], axis=1).astype(jnp.int32)
+    counts = lens > k
+    return jnp.where(counts, kth, lowest), \
+        jnp.where(counts & (ties[:, -1] > room), cut, c)
+
+
+def select_topk(scores, k, lens=None):
+    """``scores`` [N, C] float32, ``lens`` [N] (the columns a row holds,
+    from the left; None: all) -> bool [N, C]: of a row that holds more
+    than ``k`` columns the ``k`` largest among them, ties to the lower
+    column, which is the set ``lax.top_k(scores[:lens], k)`` indexes;
+    of any other row every column it holds. Nothing at or past a row's
+    length is chosen. No sort: the k-th largest key and the last tie the
+    choice has room for are found by counting (``kth_and_cut``: on a TPU
+    one kernel over the row tiles' live columns, in VMEM;
+    ``kth_and_cut_dense`` elsewhere), and the choice is one pass over
+    the scores against the two."""
+    n, c = scores.shape
+    col = jnp.arange(c, dtype=jnp.int32)[None, :]
+    if lens is None:
+        lens = jnp.full((n,), c, jnp.int32)
+    held = col < lens[:, None]
+    if c <= k:
+        return held
+    kth, cut = jax.lax.platform_dependent(
+        scores, lens, tpu=functools.partial(selection_kth.kth_and_cut, k=k),
+        default=functools.partial(kth_and_cut_dense, k=k))
+    key = selection_kth.ordered_keys(scores)
+    return held & ((key > kth[:, None])
+                   | ((key == kth[:, None]) & (col <= cut[:, None])))
 
 
 def index_scores(q, w, arena, layer, tables, lens, per):
@@ -192,35 +228,87 @@ def index_scores(q, w, arena, layer, tables, lens, per):
     and ``w`` [N, Hi] float32, the index keys' ``arena`` [layers, NB, bs,
     Di], ``tables`` [N, P] (one query each) or [P] (N queries of one
     sequence), ``lens`` [N] -> float32 [N, P * bs], -inf at and past a
-    row's length. Column blocks of ``per`` pages, from the first to the
-    one that holds the largest length; the rest stays -inf."""
+    row's length. Column blocks of ``per`` pages. One table: from the
+    first block to the one that holds the largest length, each block's
+    keys gathered once for all N rows. Many tables: the (row, column
+    block) pairs the live rows hold (``row_pairs``, the list a step's
+    attention runs), ``BLOCK_ROWS`` of them an iteration, each pair's
+    keys gathered through its own row's table and scored against that
+    row's query alone: a row that is not live costs nothing and a short
+    row its own blocks. The rest stays -inf."""
     nb, bs = arena.shape[1], arena.shape[2]
     n = q.shape[0]
-    one_table = tables.ndim == 1
     pages = tables.shape[-1]
     bk = per * bs
     tables = jnp.clip(tables, 0, nb - 1)
     qi = q.astype(arena.dtype)
     exact = jax.lax.Precision.HIGHEST if qi.dtype == jnp.float32 else None
 
-    def block(j, out):
-        at = jax.lax.dynamic_slice_in_dim(tables, j * per, per, -1)
-        keys = arena[layer, at]              # [(N,) per, bs, Di]
-        if one_table:
-            dots = jnp.einsum('nhd,kd->nhk', qi, keys.reshape(bk, -1),
-                              precision=exact,
-                              preferred_element_type=jnp.float32)
-        else:
-            dots = jnp.einsum('nhd,nkd->nhk', qi, keys.reshape(n, bk, -1),
-                              precision=exact,
-                              preferred_element_type=jnp.float32)
-        got = jnp.sum(jax.nn.relu(dots) * w[:, :, None], axis=1)
-        return jax.lax.dynamic_update_slice(out, got, (0, j * bk))
-    blocks = (jnp.max(lens) + bk - 1) // bk
-    out = jax.lax.fori_loop(0, blocks, block,
-                            jnp.full((n, pages * bs), _NEG, jnp.float32))
+    def scored(qs, ws, keys):
+        """[R, Hi, Di] queries each against its own [R, bk, Di] keys (or
+        all against the one [bk, Di]) -> [R, bk]."""
+        dots = jnp.einsum('nhd,kd->nhk' if keys.ndim == 2 else
+                          'nhd,nkd->nhk', qs, keys, precision=exact,
+                          preferred_element_type=jnp.float32)
+        return jnp.sum(jax.nn.relu(dots) * ws[:, :, None], axis=1)
+
+    if tables.ndim == 1:
+        def block(j, out):
+            at = jax.lax.dynamic_slice_in_dim(tables, j * per, per)
+            got = scored(qi, w, arena[layer, at].reshape(bk, -1))
+            return jax.lax.dynamic_update_slice(out, got, (0, j * bk))
+        out = jax.lax.fori_loop(
+            0, (jnp.max(lens) + bk - 1) // bk, block,
+            jnp.full((n, pages * bs), _NEG, jnp.float32))
+    else:
+        n_blocks = pages // per
+        _, last, ends = row_pairs(jnp.zeros_like(lens), lens, bk, n_blocks)
+        tables = tables.reshape(n, n_blocks, per)
+
+        def pairs(i, out):
+            # this iteration's pairs of the list: a pair's row (past the
+            # rows: the fill of a last iteration, which writes nothing),
+            # its column block and its pages through its row's table
+            row, block = pairs_at(
+                i * BLOCK_ROWS + jnp.arange(BLOCK_ROWS), last, ends)
+            at = jnp.minimum(row, n - 1)
+            block = jnp.where(row < n, block, 0)
+            got = scored(qi[at], w[at], arena[layer, tables[at, block]]
+                         .reshape(BLOCK_ROWS, bk, -1))
+            return out.at[row, block].set(got, mode='drop')
+        out = jax.lax.fori_loop(
+            0, -(-ends[-1] // BLOCK_ROWS), pairs,
+            jnp.full((n, n_blocks, bk), _NEG, jnp.float32)
+        ).reshape(n, pages * bs)
     return jnp.where(jnp.arange(pages * bs)[None, :] < lens[:, None],
                      out, _NEG)
+
+
+def selection_reach(lens, one_table, pages, bs, k, by_kernel, xp=jnp):
+    """How far one scoring layer's bounds engage for rows of lengths
+    ``lens`` [N] under tables of ``pages`` pages of ``bs``, from the
+    functions of the lengths that bound its loops: (the positions whose
+    index keys ``index_scores`` gathers: one table's column blocks up to
+    the longest length, or the column blocks of many tables' pairs (a
+    last iteration's fill, under ``BLOCK_ROWS`` pairs of a clipped page
+    whose scores go nowhere, is not counted); the (row, column) keys a
+    counting pass of ``select_topk`` reads: ``columns_counted`` where
+    the kernel counts (``by_kernel``), every column of every row in the
+    dense form; the columns the rows' tables address)."""
+    columns = pages * bs
+    if one_table:
+        bk = pages_per_block(pages, bs) * bs
+        gathered = -(-lens.max() // bk) * bk
+    else:
+        gathered = pages_held(xp.zeros_like(lens), lens, pages, bs, xp) * bs
+    addressed = len(lens) * columns
+    if columns <= k:
+        counted = 0
+    elif by_kernel:
+        counted = selection_kth.columns_counted(lens, k, columns, xp)
+    else:
+        counted = addressed
+    return int(gathered), int(counted), addressed
 
 
 def _at(stack, i):
@@ -435,7 +523,7 @@ class LatentMoEBlock(object):
             per = pages_per_block(step.tables.shape[-1], held[1].shape[2])
             chosen = select_topk(
                 index_scores(q_i, w_i, held[1], scored, step.tables,
-                             step.lens, per), self.index_topk)
+                             step.lens, per), self.index_topk, step.lens)
         q_rope = turned(q[..., d_nope:])
         attend = dict(
             sm_scale=(d_nope + d_rope) ** -0.5 * self.softmax_mult[kind],

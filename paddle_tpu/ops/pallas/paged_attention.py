@@ -115,11 +115,13 @@ BLOCK_ROWS = 8
 BLOCK_COLS = 512
 
 
+@functools.lru_cache(maxsize=None)
 def pages_per_block(n_pages, bs, block_cols=BLOCK_COLS):
     """Whole pages a column block: the largest divisor of the table's
     ``n_pages`` that holds at most ``block_cols`` columns, so every
     block is full and block j covers the absolute columns
-    [j * pages * bs, (j + 1) * pages * bs)."""
+    [j * pages * bs, (j + 1) * pages * bs). Kept once found: the
+    engine's counters ask for it every step."""
     return max(p for p in range(1, n_pages + 1)
                if n_pages % p == 0 and p * bs <= max(block_cols, bs))
 

@@ -347,9 +347,12 @@ class DecodeEngine(object):
         # the form a step's attention pairs run in, for the counters
         from ...ops.pallas.paged_attention import pairs_form
         from ...quant.core import kv_quantized
-        self._attn_form = pairs_form(
-            self._exe.place.jax_device().platform, bool(spec.latent),
-            kv_quantized(self.kv_dtype))
+        platform = self._exe.place.jax_device().platform
+        self._attn_form = pairs_form(platform, bool(spec.latent),
+                                     kv_quantized(self.kv_dtype))
+        # whether a selection's counting runs in the kernel, over the
+        # live columns (ops/latent_moe_ops.py::select_topk), likewise
+        self._selects_by_kernel = platform == 'tpu'
         with scope_guard(self._scope):
             self._exe.run(program=self._progs.startup)
         if weights:
@@ -1178,6 +1181,14 @@ class DecodeEngine(object):
             chunk_pairs = [self._attn_pairs(a, min(a + top, s))
                            if _obs.enabled() else 0 for a in starts]
             pairs = sum(chunk_pairs)
+            if _obs.enabled() and self.spec.index_topk:
+                for a in starts:
+                    # a chunk's rows as its program holds them: one
+                    # position more each, none past the piece
+                    rows = min(top, s - a)
+                    held = np.zeros(self._bucket(rows), 'int64')
+                    held[:rows] = np.arange(a + 1, a + rows + 1)
+                    self._count_selection_reach(held, 'prefill')
             seq.stream.cached_tokens = cached
             # with layers that keep a state: the (row, layer) steps of
             # the recurrence a span's programs take (what a FLOP count
@@ -1330,7 +1341,29 @@ class DecodeEngine(object):
                 _obs.record('decode.step_window_tokens',
                             int(np.minimum(lens, window).sum()))
             self._count_cache_reads(lens[:len(batch)] + 1)
+            if self.spec.index_topk:
+                self._count_selection_reach(
+                    np.where(np.arange(mb) < len(batch), lens + 1, 0),
+                    'decode')
         return (lens, tables, temps, seeds, *more)
+
+    def _count_selection_reach(self, held, kind):
+        """How far the learned selection's bounds engage in one program
+        (``kind``: a decode step, a prefill chunk) whose rows hold
+        ``held`` positions, over the layers that score: the positions
+        whose index keys are gathered, and the columns a counting pass
+        of the top-k runs over beside those the rows' tables address
+        (``ops/latent_moe_ops.py::selection_reach``: the functions of
+        the lengths that bound the program's loops)."""
+        from ...ops.latent_moe_ops import selection_reach
+        n = len(self.spec.scoring_layers())
+        gathered, counted, addressed = selection_reach(
+            held, kind == 'prefill', self.pages_per_seq, self.block_size,
+            self.spec.index_topk, self._selects_by_kernel, np)
+        _obs.inc('decode.index_positions_gathered', n * gathered, kind=kind)
+        _obs.inc('decode.selection_columns_counted', n * counted, kind=kind)
+        _obs.inc('decode.selection_columns_addressed', n * addressed,
+                 kind=kind)
 
     def _count_cache_reads(self, seen):
         """What one decode step's attention has to read of the paged

@@ -32,7 +32,7 @@ from paddle_tpu.ops import moe_held_ops as moe
 from paddle_tpu.ops import paged_decode_ops as pdo
 from paddle_tpu.serving.decode import DecodeEngine, LMSpec, random_weights
 from paddle_tpu.serving.decode import model as lm
-from util import as_held, weights_round_trip
+from util import as_held, platform_forms, weights_round_trip
 
 TOL = 5e-5
 BS, PAGES, NB = 4, 12, 40            # 48 positions a sequence
@@ -407,19 +407,26 @@ def test_a_period_without_leading_layers_carries_from_its_own_first():
                                _reference_logits(tokens, spec, w), atol=TOL)
 
 
-def test_decode_batch_of_mixed_lengths_matches_reference():
-    """Four sequences of lengths on both sides of index_topk in one
-    decode batch, an empty slot among them: every row's logits are the
-    reference's for that sequence."""
+@pytest.mark.parametrize('lengths,forms', [
+    ([3, 9, 17, 30, 0], 'default'), ([5, 0, 20], 'default'),
+    ([5, 0, 20], 'tpu')])
+def test_decode_batch_of_mixed_lengths_matches_reference(
+        monkeypatch, lengths, forms):
+    """Sequences of lengths on both sides of index_topk in one decode
+    batch, an empty slot (0) after them or between two of them: every
+    live row's logits are the reference's for that sequence; also in
+    the forms a TPU's programs take (the selection's, the attention's
+    and the routed product's kernels, interpreted here)."""
+    platform_forms(monkeypatch, forms)
     rng = np.random.RandomState(7)
-    lengths = [3, 9, 17, 30]
-    seqs = [rng.randint(0, SPEC.vocab_size, n + 1) for n in lengths]
+    seqs = {i: rng.randint(0, SPEC.vocab_size, n + 1)
+            for i, n in enumerate(lengths) if n}
     block = _block()
     arenas = _arenas()
     pages = rng.permutation(NB)
-    tables = np.full((5, PAGES), NB, np.int32)
+    tables = np.full((len(lengths), PAGES), NB, np.int32)
     used = 0
-    for i, seq in enumerate(seqs):
+    for i, seq in seqs.items():
         need = -(-len(seq) // BS)
         tables[i, :need] = pages[used:used + need]
         used += need
@@ -427,9 +434,10 @@ def test_decode_batch_of_mixed_lengths_matches_reference():
                                       seq[:-1], 0)
     got, arenas, stats = _decode(
         block, arenas, jnp.asarray(tables),
-        jnp.asarray([s[-1] for s in seqs] + [0], jnp.int32),
-        jnp.asarray(lengths + [0], jnp.int32))
-    for i, seq in enumerate(seqs):
+        jnp.asarray([seqs[i][-1] if n else 0
+                     for i, n in enumerate(lengths)], jnp.int32),
+        jnp.asarray(lengths, jnp.int32))
+    for i, seq in seqs.items():
         np.testing.assert_allclose(
             np.asarray(got)[i], _reference_logits(seq)[-1], atol=TOL)
     assert np.asarray(stats).shape == (4, 4)
@@ -574,6 +582,87 @@ def test_engine_counts_scored_and_attended_layers_apart(engine):
         2 * seen * 8 * item
     assert grown('decode.moe_layer_steps') == 3 * 4
     assert engine.kv_bytes_per_token == lm.kv_bytes_per_token(SPEC)
+
+
+def test_a_rehearsed_step_gathers_the_blocks_its_live_rows_hold():
+    """The benchmark cell's engine at its rehearsal geometry (pages of
+    8, tables of 8 pages: one column block of 64 positions; 8 kept; 4
+    slots), the prompt bound widened to the table: a prompt of 62
+    tokens and one decode step, whose one live row holds 63 of its
+    block's 64 positions (a sequence may not fill its table). The step
+    gathers that row's one block in each scoring layer, where every
+    table to the longest length was four tables' blocks; fed two rows
+    that fill their blocks with an empty slot between, the step's
+    counters read scored over gathered 1.0. Its counting reaches the
+    live row's tile: on a TPU by the kernel's bounds, and in the dense
+    form that runs here over every column the four tables address. The
+    prompt's chunks gather up to their own last block, and a tile of
+    rows at or under the 8 kept counts nothing."""
+    from paddle_tpu import observe
+    from util import cell_spec
+    spec, geometry = cell_spec('glm_5_2.long_ctx_long_answers',
+                               rehearsal=True)
+    assert (geometry['block_size'], geometry['pages_per_seq'],
+            geometry['max_batch'], spec.index_topk) == (8, 8, 4, 8)
+    engine = DecodeEngine(spec, weights=random_weights(spec, seed=57),
+                          place=fluid.CPUPlace(),
+                          **dict(geometry, max_prompt_len=62))
+    engine.warmup()
+    engine.start()
+    scoring, columns = 2, 64
+    names = ('decode.index_positions', 'decode.selection_columns')
+
+    def grown(run):
+        before = observe.snapshot()['counters']
+        run()
+        after = observe.snapshot()['counters']
+        return {key: after[key] - before.get(key, 0) for key in after
+                if key.startswith(names)}
+
+    observe.enable()
+    try:
+        counted = {}
+        for by_kernel in (False, True):
+            engine._selects_by_kernel = by_kernel
+            counted[by_kernel] = grown(lambda: engine.generate(
+                list(range(62)), max_new_tokens=2, timeout=300))
+        whole = grown(lambda: (
+            engine._count_cache_reads(np.asarray([64, 64])),
+            engine._count_selection_reach(np.asarray([64, 0, 64, 0]),
+                                          'decode')))
+    finally:
+        observe.disable()
+        observe.reset()
+        engine.shutdown(drain=False)
+    assert whole['decode.index_positions_scored'] == \
+        whole['decode.index_positions_gathered{kind=decode}'] == \
+        scoring * 2 * columns
+    for got in counted.values():
+        assert got['decode.index_positions_scored'] == scoring * 63
+        assert got['decode.index_positions_gathered{kind=decode}'] == \
+            scoring * columns
+        # chunks of 16 rows at 0, 16 and 32 and one of 14: a block each
+        assert got['decode.index_positions_gathered{kind=prefill}'] == \
+            scoring * 4 * columns
+        assert got['decode.selection_columns_addressed{kind=decode}'] == \
+            scoring * 4 * columns
+        assert got['decode.selection_columns_addressed{kind=prefill}'] == \
+            scoring * 4 * 16 * columns
+    # the dense form counts over all it addresses; the kernel over the
+    # tiles that hold a row past the 8 kept: the step's one tile of 4
+    # rows and each chunk's one tile of 16 (a first chunk of 8 rows or
+    # fewer would count nothing)
+    dense, kernel = counted[False], counted[True]
+    for kind in ('decode', 'prefill'):
+        assert dense['decode.selection_columns_counted{kind=%s}' % kind] == \
+            dense['decode.selection_columns_addressed{kind=%s}' % kind]
+    assert kernel['decode.selection_columns_counted{kind=decode}'] == \
+        scoring * 4 * columns
+    assert kernel['decode.selection_columns_counted{kind=prefill}'] == \
+        scoring * 4 * 16 * columns
+    from paddle_tpu.ops.pallas.selection_kth import columns_counted
+    assert columns_counted(np.arange(1, 9), 8, columns, np) == 0
+    assert columns_counted(np.arange(1, 17), 8, columns, np) == 16 * columns
 
 
 @pytest.mark.parametrize('kw,error', [

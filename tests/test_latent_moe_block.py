@@ -31,7 +31,8 @@ from paddle_tpu.ops import moe_held_ops as moe
 from paddle_tpu.ops import paged_decode_ops as pdo
 from paddle_tpu.serving.decode import DecodeEngine, LMSpec, random_weights
 from paddle_tpu.serving.decode import model as lm
-from util import as_held, cell_spec, heads_of_held, weights_round_trip
+from util import (as_held, cell_spec, heads_of_held, platform_forms,
+                  weights_round_trip)
 
 TOL = 5e-5
 BS, PAGES, NB = 4, 12, 40            # 48 positions a sequence
@@ -222,6 +223,126 @@ def test_selection_is_the_dense_top_k(rows, cols, k):
     assert (got.sum(axis=1) == k).all()
 
 
+# name -> (columns, k): column blocks of whole lane tiles, 9 and 16 to a
+# block; columns that are no lane tile, in one block
+SELECTIONS = {'two_blocks_of_9_lane_tiles': (2304, 300),
+              'five_blocks_of_16': (10240, 2048),
+              'no_lane_tile': (200, 24)}
+
+
+def _rows_under_lengths(columns, k, seed):
+    """(scores [24, columns], lens [24]): a tile of rows of length 0,
+    under k, exactly k, one over, with ties at the k-th value, with a
+    -inf tail inside its length, the whole extent, and many ties; a
+    tile none of whose rows is live; a tile of live rows none of which
+    holds more than k."""
+    rng = np.random.RandomState(seed)
+    scores = rng.randn(24, columns).astype('float32')
+    long = max(2 * k + k // 2, min(columns, 3 * k))
+    lens = np.zeros(24, 'int32')
+    lens[:8] = [0, k // 3, k, k + 1, long, long, columns, columns - 7]
+    scores[4, 0:long:2] = 0.25            # the k-th value is one of these
+    assert np.sort(scores[4, :long])[-k] == 0.25
+    scores[5, k // 2:] = -np.inf          # fewer than k finite candidates
+    scores[7] = np.round(scores[7])
+    lens[16:] = rng.randint(0, k + 1, 8)
+    lens[16] = k
+    # what lies at and past a row's length is the score buffer's -inf
+    scores[np.arange(columns)[None, :] >= lens[:, None]] = -np.inf
+    return scores, lens
+
+
+@pytest.mark.parametrize('form', ['dense', 'kernel'])
+@pytest.mark.parametrize('case', sorted(SELECTIONS))
+def test_selection_under_lengths_is_the_top_k_of_what_a_row_holds(
+        monkeypatch, form, case):
+    """The choice of rows of every kind of length in one batch, through
+    the dense form and through the kernel's row tiles: of a row that
+    holds more than k positions the set lax.top_k indexes among them
+    (ties to the lower column, -inf a value like another), of any other
+    row every position it holds, and nothing at or past a length; and
+    the two forms return the same k-th key and the same last tie."""
+    from paddle_tpu.ops.pallas import selection_kth
+    columns, k = SELECTIONS[case]
+    scores, lens = _rows_under_lengths(columns, k, seed=columns)
+    platform_forms(monkeypatch, 'tpu' if form == 'kernel' else 'default')
+    got = np.asarray(lmo.select_topk(jnp.asarray(scores), k,
+                                     jnp.asarray(lens)))
+    want = np.zeros_like(got)
+    for r, held in enumerate(lens):
+        if held <= k:
+            want[r, :held] = True
+        else:
+            _, at = jax.lax.top_k(jnp.asarray(scores[r, :held]), k)
+            want[r, np.asarray(at)] = True
+    assert np.array_equal(got, want)
+    assert np.array_equal(got.sum(axis=1), np.minimum(lens, k))
+    if form == 'kernel':
+        dense = lmo.kth_and_cut_dense(jnp.asarray(scores),
+                                      jnp.asarray(lens), k)
+        kernel = selection_kth.kth_and_cut(jnp.asarray(scores),
+                                           jnp.asarray(lens), k=k)
+        for a, b in zip(kernel, dense):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+    # the counting runs over the first tile's blocks alone
+    block = selection_kth.block_columns(columns)
+    assert selection_kth.columns_counted(lens, k, columns, np) == \
+        8 * -(-columns // block) * block
+
+
+def _scores_of_every_table(q, w, arena, layer, tables, lens, per):
+    """The many-table scores as they were made before the pair list:
+    column block after column block to the longest length, every
+    table's keys gathered for each and every row scored against its
+    own."""
+    n, bk = q.shape[0], per * arena.shape[2]
+    tables = jnp.clip(tables, 0, arena.shape[1] - 1)
+
+    def block(j, out):
+        at = jax.lax.dynamic_slice_in_dim(tables, j * per, per, -1)
+        dots = jnp.einsum('nhd,nkd->nhk', q,
+                          arena[layer, at].reshape(n, bk, -1),
+                          precision=jax.lax.Precision.HIGHEST,
+                          preferred_element_type=jnp.float32)
+        got = jnp.sum(jax.nn.relu(dots) * w[:, :, None], axis=1)
+        return jax.lax.dynamic_update_slice(out, got, (0, j * bk))
+    out = jax.lax.fori_loop(
+        0, (jnp.max(lens) + bk - 1) // bk, block,
+        jnp.full((n, tables.shape[1] * arena.shape[2]), -jnp.inf))
+    return jnp.where(jnp.arange(out.shape[1])[None, :] < lens[:, None],
+                     out, -jnp.inf)
+
+
+@pytest.mark.parametrize('lens', [
+    [0, 48, 0, 0, 3, 0, 17, 8, 0, 9],          # dead rows between live ones
+    [1, 0, 47],                                # very different lengths
+    [0, 0, 0, 0],                              # nothing live
+    [12, 11, 13, 24, 25, 36, 5, 16, 40, 48, 2, 1, 30],   # two iterations
+])
+@pytest.mark.parametrize('per', [1, 3])
+def test_index_scores_of_many_tables_run_the_live_rows_pairs(lens, per):
+    """Many tables through the (row, column block) pair list: a live
+    row's scores are the dense form's over its own columns, whatever
+    rows lie between and however long its neighbours are, and -inf
+    stands at and past every length and all along a dead row, whose
+    table names no page."""
+    rng = np.random.RandomState(len(lens))
+    heads, width, n = 3, 8, len(lens)
+    arena = jnp.asarray(rng.randn(2, NB, BS, width), jnp.float32)
+    q = jnp.asarray(rng.randn(n, heads, width), jnp.float32)
+    w = jnp.asarray(rng.randn(n, heads), jnp.float32)
+    tables = np.full((n, PAGES), NB, np.int32)
+    for i, held in enumerate(lens):
+        need = -(-held // BS)
+        tables[i, :need] = rng.permutation(NB)[:need]
+    lens = jnp.asarray(lens, jnp.int32)
+    got = np.asarray(lmo.index_scores(q, w, arena, 1, jnp.asarray(tables),
+                                      lens, per))
+    want = np.asarray(_scores_of_every_table(
+        q, w, arena, 1, jnp.asarray(tables), lens, per))
+    assert np.array_equal(got, want)
+
+
 def test_index_scores_of_both_table_forms_are_the_reference():
     """The indexer's scores over cached keys, through many tables (one
     query each) and through one table (many queries): the reference's
@@ -372,20 +493,22 @@ def test_the_published_order_with_periods_and_a_remainder():
     np.testing.assert_allclose(np.asarray(got), want[16:], atol=TOL)
 
 
-def test_decode_batch_of_mixed_lengths_matches_reference():
-    """Four sequences of lengths on both sides of index_topk and the
-    window in one decode batch, an empty slot among them: every row's
-    logits are the reference's for that sequence, and the statistics
-    count the live rows of the four routed layers only."""
+@pytest.mark.parametrize('lengths', [[3, 9, 17, 30, 0], [5, 0, 20]])
+def test_decode_batch_of_mixed_lengths_matches_reference(lengths):
+    """Sequences of lengths on both sides of index_topk and the
+    window in one decode batch, an empty slot (0) after them or between
+    two of them: every live row's logits are the reference's for that
+    sequence, and the statistics count the live rows of the four routed
+    layers only."""
     rng = np.random.RandomState(7)
-    lengths = [3, 9, 17, 30]
-    seqs = [rng.randint(0, SPEC.vocab_size, n + 1) for n in lengths]
+    seqs = {i: rng.randint(0, SPEC.vocab_size, n + 1)
+            for i, n in enumerate(lengths) if n}
     block = _block()
     arenas = _arenas()
     pages = rng.permutation(NB)
-    tables = np.full((5, PAGES), NB, np.int32)
+    tables = np.full((len(lengths), PAGES), NB, np.int32)
     used = 0
-    for i, seq in enumerate(seqs):
+    for i, seq in seqs.items():
         need = -(-len(seq) // BS)
         tables[i, :need] = pages[used:used + need]
         used += need
@@ -393,14 +516,16 @@ def test_decode_batch_of_mixed_lengths_matches_reference():
                                       seq[:-1], 0)
     got, arenas, stats = _decode(
         block, arenas, jnp.asarray(tables),
-        jnp.asarray([s[-1] for s in seqs] + [0], jnp.int32),
-        jnp.asarray(lengths + [0], jnp.int32))
-    for i, seq in enumerate(seqs):
+        jnp.asarray([seqs[i][-1] if n else 0
+                     for i, n in enumerate(lengths)], jnp.int32),
+        jnp.asarray(lengths, jnp.int32))
+    for i, seq in seqs.items():
         np.testing.assert_allclose(
             np.asarray(got)[i], _reference_logits(seq)[-1], atol=TOL)
     stats = np.asarray(stats)
     assert stats.shape == (4, 4)
-    assert (stats[:, 0] <= 12).all() and (stats[:, 1] <= 4).all()
+    assert (stats[:, 0] <= 3 * len(seqs)).all()
+    assert (stats[:, 1] <= len(seqs)).all()
     assert (stats[:, 2] <= SPEC.experts_held).all()
 
 

@@ -791,6 +791,60 @@ def test_the_carried_selection_loads_at_its_published_geometry(one_chip):
     assert round(arena_b / 1e9, 2) == 3.85
 
 
+def _whiles_to(hlo, trips):
+    """The ``while`` instructions outside fused computations whose
+    condition holds its counter to the constant ``trips``."""
+    counted = set()
+    for head, body in re.findall(r'^(%[\w.\-]+) \([^\n]*\n(.*?)^}', hlo,
+                                 re.M | re.S):
+        if re.search(r's32\[\]\S* constant\(%d\)' % trips, body) and \
+                'direction=LT' in body:
+            counted.add(head)
+    return [line for line in _outside_fusions(hlo)
+            if re.search(r' while\(', line) and
+            re.search(r'condition=(%[\w.\-]+)', line).group(1) in counted]
+
+
+@pytest.mark.parametrize('cell,index_arena', [
+    ('glm_5_2.long_ctx_long_answers', 'bf16[2,17408,32,128]'),
+    ('dots3_note.long_ctx_steady', 'bf16[2,12288,32,128]')])
+def test_the_selection_works_over_what_the_rows_hold(one_chip, cell,
+                                                     index_arena):
+    """The two cells under a learned selection as the benchmark runs
+    them, compiled for the v5e. A decode step gathers index keys for its
+    pair list, ``BLOCK_ROWS`` (row, column block) pairs an iteration,
+    and nowhere a column block of every slot's table (``bf16[slots, 16,
+    32, 128]`` each iteration to the longest row, before PR 57). In both
+    programs the k-th key of a row is found by the selection_kth kernel,
+    one call a scoring layer, over the score buffer where it lies; no
+    loop of 32 trips carries an array of the scores' extent, as the
+    counting passes' ``u32[rows, columns]`` keys were carried through
+    32 passes, whatever the rows held."""
+    from paddle_tpu.ops.pallas.paged_attention import BLOCK_ROWS
+    for op in ('paged_decode_step', 'paged_prefill'):
+        spec, geometry, hlo, _, _ = _published_program(one_chip, cell, op)
+        rows = geometry['max_batch' if op == 'paged_decode_step'
+                        else 'prefill_chunk']
+        columns = geometry['pages_per_seq'] * geometry['block_size']
+        kernels = _kernels(hlo, 'selection_kth')
+        assert len(kernels) == len(spec.scoring_layers()), op
+        assert all('f32[%d,%d]' % (rows, columns) in line
+                   for line in kernels), op
+        extent = r'\[%d,(%d|%d,128)\]' % (rows, columns, columns // 128)
+        assert [line for line in _whiles_to(hlo, 32)
+                if re.search(extent, line)] == [], op
+        if op == 'paged_decode_step':
+            block = '16,%d,128]' % geometry['block_size']
+            assert 'bf16[%d,%s' % (BLOCK_ROWS, block) in hlo
+            assert 'bf16[%d,%s' % (rows, block) not in hlo
+            # the pairs' gather is a fusion over the index arena
+            reads = re.findall(
+                r'^%%fused_computation[^\n]*%s[^\n]*\n(.*?)^}'
+                % re.escape(index_arena), hlo, re.M | re.S)
+            assert any('bf16[%d,%s' % (BLOCK_ROWS, block) in body
+                       for body in reads)
+
+
 @pytest.mark.parametrize('cell,arenas,calls', [
     # the lead layer's call and the scanned layers' are the one kernel
     ('kimi_k2_6.doc_qa_sessions', ['bf16[6,16384,32,640]'], 2),

@@ -107,3 +107,15 @@ def weights_round_trip(spec, weights, marked, **engine_kw):
         same_as_loaded(eng.export_weights())
     finally:
         eng.shutdown(drain=False)
+
+
+def platform_forms(monkeypatch, which):
+    """Make ``jax.lax.platform_dependent``'s choice here: the forms a
+    TPU's programs take (``which`` 'tpu': the Pallas kernels,
+    interpreted on the CPU) or every other platform's ('default')."""
+    import jax
+    monkeypatch.setenv('PADDLE_TPU_PALLAS_INTERPRET', '1')
+    monkeypatch.setattr(
+        jax.lax, 'platform_dependent',
+        lambda *args, tpu, default: (
+            tpu if which == 'tpu' else default)(*args))
