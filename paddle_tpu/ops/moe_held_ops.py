@@ -21,8 +21,12 @@ rows are grouped by expert and the three products
     hidden[i, :] = silu(x[n_i] Wg[e]) * (x[n_i] Wu[e]) * gate[n_i, e]
     y[i]         = hidden[i, :] Wd[e]
 
-run over row tiles of ``TILE_ROWS`` rows of one expert each, as many
-tiles as the step has (the sum over the experts of ceil(rows that chose
+(or, for an expert of two matrices and no gate matrix, nemotron_h's:
+``hidden[i, :] = relu(x[n_i] W1[e])^2 * gate[n_i, e]``, ``y[i] =
+hidden[i, :] W2[e]``; ``routed_experts`` with ``w_gate`` None, a static
+form of the same function through the same tile list, kernel and
+``serves`` rule) run over row tiles of ``TILE_ROWS`` rows of one expert
+each, as many tiles as the step has (the sum over the experts of ceil(rows that chose
 it / TILE_ROWS)): an expert no live row chose costs nothing and none of
 its bytes is read. A tile takes its expert out of the layer-stacked
 weights where they lie. A row's result is the float32 sum of its own
@@ -202,12 +206,13 @@ def routed_experts(x, gate, hit, valid, most, w_gate, w_up, w_down, layer):
     ``valid`` [N] bool (a row that is not live makes no assignment);
     ``most`` the held experts one row can choose, min(top_k, E);
     ``w_gate`` / ``w_up`` [L, E, D, F] and ``w_down`` [L, E, F, D]
-    stacked over the layers, of which ``layer`` is this one ->
+    stacked over the layers, of which ``layer`` is this one (``w_gate``
+    None: the expert is ``relu(x w_up)^2`` then ``w_down``) ->
     float32 [N, D]. ``sum(row_tiles(load))`` tiles are run: the
     kernel's grid on a TPU, the loop's trip count elsewhere.
     Operand dtypes as ``gated_experts``."""
     live = hit & valid[:, None]
-    args = (x.astype(w_gate.dtype), jnp.where(live, gate, 0.0), live,
+    args = (x.astype(w_up.dtype), jnp.where(live, gate, 0.0), live,
             w_gate, w_up, w_down, layer)
     if x.shape[0] <= TILE_ROWS:
         by_loop, by_kernel = _in_place_by_loop, _in_place_by_kernel
@@ -216,24 +221,31 @@ def routed_experts(x, gate, hit, valid, most, w_gate, w_up, w_down, layer):
             _grouped_by_loop, _grouped_by_kernel))
     # the kernel keeps the rows and their result in VMEM: a chunk too
     # long for that keeps the loop (``serves`` has the lengths)
-    if not serves(*x.shape, w_gate.shape[3], w_gate.shape[1],
-                  w_gate.dtype.itemsize):
+    if not serves(*x.shape, w_up.shape[3], w_up.shape[1],
+                  w_up.dtype.itemsize, 2 if w_gate is None else 3):
         return by_loop(*args)
     return jax.lax.platform_dependent(*args, tpu=by_kernel, default=by_loop)
 
 
 def _product_of(w_gate, w_up, w_down, layer):
-    """The loop's three products of one row tile under one expert."""
+    """The loop's products of one row tile under one expert: three, or
+    two where the expert has no gate matrix (``w_gate`` None)."""
     def of(stack, expert):
         # sliced where it lies: the compiler fuses it into the product
         return jax.lax.dynamic_slice(
             stack, (layer, expert, 0, 0), (1, 1) + stack.shape[2:])[0, 0]
 
     def product(tile, tile_gate, expert):
-        hidden = jax.nn.silu(jnp.matmul(
-            tile, of(w_gate, expert),
-            preferred_element_type=jnp.float32)) * jnp.matmul(
-                tile, of(w_up, expert), preferred_element_type=jnp.float32)
+        if w_gate is None:
+            hidden = jnp.square(jax.nn.relu(jnp.matmul(
+                tile, of(w_up, expert),
+                preferred_element_type=jnp.float32)))
+        else:
+            hidden = jax.nn.silu(jnp.matmul(
+                tile, of(w_gate, expert),
+                preferred_element_type=jnp.float32)) * jnp.matmul(
+                    tile, of(w_up, expert),
+                    preferred_element_type=jnp.float32)
         hidden = hidden * tile_gate[:, None]
         return jnp.matmul(hidden.astype(w_down.dtype), of(w_down, expert),
                           preferred_element_type=jnp.float32)

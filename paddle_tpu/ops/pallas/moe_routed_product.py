@@ -49,6 +49,13 @@ the chunk the kernel takes (``serves``):
 
 With no tile at all the one grid step multiplies nothing: the result is
 zeros.
+
+**Two forms of the expert**, a static choice of the caller's: the three
+matrices above, or (``w_gate`` None: nemotron_h's experts) two, ``relu(x
+W1)^2 * gate`` then ``W2``, with no gate matrix. The tile list, the
+pipeline, both layouts of rows and ``serves`` are the same; a grid step
+brings in two blocks where the other brings three, and ``block_width``
+and ``vmem_bytes`` count the matrices an expert has (``mats``).
 """
 
 import functools
@@ -80,19 +87,19 @@ TILE_ROWS = 128
 VMEM_BYTES = 100 << 20
 
 
-def block_width(d, f, itemsize):
+def block_width(d, f, itemsize, mats=3):
     """The columns of ``F`` one grid step takes: all of them where the
-    expert's three matrices fit ``BLOCK_BYTES``, else the widest divisor
-    of ``F`` in whole lanes that does (the narrowest there is, if none
-    does)."""
-    if 3 * d * f * itemsize <= BLOCK_BYTES or f % LANES:
+    expert's ``mats`` matrices fit ``BLOCK_BYTES``, else the widest
+    divisor of ``F`` in whole lanes that does (the narrowest there is,
+    if none does)."""
+    if mats * d * f * itemsize <= BLOCK_BYTES or f % LANES:
         return f
     widths = [w for w in range(LANES, f, LANES) if f % w == 0]
-    fit = [w for w in widths if 3 * d * w * itemsize <= BLOCK_BYTES]
+    fit = [w for w in widths if mats * d * w * itemsize <= BLOCK_BYTES]
     return max(fit) if fit else min(widths)
 
 
-def vmem_bytes(n, d, f, experts, itemsize, grouped):
+def vmem_bytes(n, d, f, experts, itemsize, grouped, mats=3):
     """What a call over ``n`` rows keeps in VMEM: two of every weight
     block (the pipeline's), one of what stays for the whole call (the
     rows, their float32 result, the gates and, grouped, the rows' places
@@ -102,14 +109,14 @@ def vmem_bytes(n, d, f, experts, itemsize, grouped):
     compiler's own scratch. Compiled for a described v5e the kernel
     used 8 to 13 MiB less than this says at every shape tried (32 to
     4,096 rows at the four configurations' widths; PR 47)."""
-    wide = block_width(d, f, itemsize)
+    wide = block_width(d, f, itemsize, mats)
     r = TILE_ROWS if grouped else n
-    return (2 * 3 * d * wide * itemsize + n * d * (itemsize + 4)
+    return (2 * mats * d * wide * itemsize + n * d * (itemsize + 4)
             + (2 if grouped else 1) * experts * n * 4
             + r * (3 * d + 4 * wide + 2 * n) * 4 + (8 << 20))
 
 
-def serves(n, d, f, experts, itemsize):
+def serves(n, d, f, experts, itemsize, mats=3):
     """Whether the kernel takes a program of ``n`` rows: where what it
     keeps in VMEM fits ``VMEM_BYTES``. At the published widths in
     bfloat16 that is a chunk of up to 4,096 rows of mellum2_12b's
@@ -121,7 +128,7 @@ def serves(n, d, f, experts, itemsize):
     times at these lengths: the kernel is the shorter up to each, so it
     is VMEM and not the time that sets them)."""
     return vmem_bytes(n, d, f, experts, itemsize,
-                      n > TILE_ROWS) <= VMEM_BYTES
+                      n > TILE_ROWS, mats) <= VMEM_BYTES
 
 
 def _column(row):
@@ -134,7 +141,11 @@ def _column(row):
                    keepdims=True)
 
 
-def _kernel(*refs, grouped, blocks):
+def _kernel(*refs, grouped, blocks, gated):
+    refs = list(refs)
+    if not gated:
+        # no gate matrix among the operands: none stands at its place
+        refs.insert(10 if grouped else 5, None)
     if grouped:
         (layer_ref, expert_ref, count_ref, ahead_ref, first_ref, held_ref,
          row_ref, x_ref, gates_ref, nth_ref, wg_ref, wu_ref, wd_ref,
@@ -184,9 +195,15 @@ def _kernel(*refs, grouped, blocks):
             tile, gate = tile_ref[...], gate_ref[...]
         else:
             tile, gate = x_ref[...], _column(gates_ref[its, :])
-        hidden = jax.nn.silu(jnp.dot(
-            tile, wg_ref[...], preferred_element_type=jnp.float32)) * \
-            jnp.dot(tile, wu_ref[...], preferred_element_type=jnp.float32)
+        if gated:
+            hidden = jax.nn.silu(jnp.dot(
+                tile, wg_ref[...], preferred_element_type=jnp.float32)) * \
+                jnp.dot(tile, wu_ref[...],
+                        preferred_element_type=jnp.float32)
+        else:
+            hidden = jnp.square(jnp.maximum(jnp.dot(
+                tile, wu_ref[...], preferred_element_type=jnp.float32),
+                0.0))
         hidden = hidden * gate
         y = jnp.dot(hidden.astype(wd_ref.dtype), wd_ref[...],
                     preferred_element_type=jnp.float32)
@@ -209,7 +226,9 @@ def routed_product(x, gates, expert_of_tile, count, w_gate, w_up, w_down,
     """The kernel over ``count`` tiles, tile ``t`` under the expert
     ``expert_of_tile[t]`` -> float32 [N, D], zeros with no tile. ``x``
     [N, D] at the weights' dtype and ``gates`` float32 [E, N] (a row's gate under each expert, 0 where it did not
-    choose it) stay in VMEM whole. Without ``groups`` a tile is all of
+    choose it) stay in VMEM whole. ``w_gate`` None: the expert of two
+    matrices, ``relu(x w_up)^2 * gate`` then ``w_down``. Without
+    ``groups`` a tile is all of
     ``x`` (in place). With it (grouped) a tile is ``TILE_ROWS`` rows of
     one expert: ``groups`` is (``nth`` int32 [E, N]: row n is its
     expert's nth[e, n]-th, -1 where it did not choose it; ``ahead``
@@ -218,9 +237,11 @@ def routed_product(x, gates, expert_of_tile, count, w_gate, w_up, w_down,
     how many they are; ``row_at`` int32 [A]: the rows in order of
     (expert, row))."""
     n, d = x.shape
-    f = w_gate.shape[3]
-    item = w_gate.dtype.itemsize
-    wide = block_width(d, f, item)
+    f = w_up.shape[3]
+    item = w_up.dtype.itemsize
+    gated = w_gate is not None
+    mats = 3 if gated else 2
+    wide = block_width(d, f, item, mats)
     grouped = groups is not None
     r = TILE_ROWS if grouped else n
 
@@ -244,11 +265,12 @@ def routed_product(x, gates, expert_of_tile, count, w_gate, w_up, w_down,
         scratch = [pltpu.VMEM((r, d), x.dtype),
                    pltpu.VMEM((r, 1), jnp.float32),
                    pltpu.VMEM((r, d), jnp.float32)]
-    vmem = vmem_bytes(n, d, f, gates.shape[0], item, grouped)
+    vmem = vmem_bytes(n, d, f, gates.shape[0], item, grouped, mats)
 
     blocks = f // wide
     return pl.pallas_call(
-        functools.partial(_kernel, grouped=grouped, blocks=blocks),
+        functools.partial(_kernel, grouped=grouped, blocks=blocks,
+                          gated=gated),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             # a step a (tile, block of F); with no tile, one step, which
@@ -264,7 +286,7 @@ def routed_product(x, gates, expert_of_tile, count, w_gate, w_up, w_down,
             grid=(jnp.maximum(count, 1), jnp.where(count > 0, blocks, 1)),
             in_specs=[whole(a) for a in resident] + [
                 pl.BlockSpec((None, None, d, wide), up,
-                             memory_space=pltpu.VMEM)] * 2 + [
+                             memory_space=pltpu.VMEM)] * (mats - 1) + [
                 pl.BlockSpec((None, None, wide, d), down,
                              memory_space=pltpu.VMEM)],
             out_specs=pl.BlockSpec((n, d), lambda *_: (0, 0),
@@ -277,4 +299,4 @@ def routed_product(x, gates, expert_of_tile, count, w_gate, w_up, w_down,
         name='moe_routed_product',
         interpret=interpret_mode(),
     )(*[s.astype(jnp.int32) for s in scalars], *resident,
-      w_gate, w_up, w_down)
+      *([w_gate] if gated else []), w_up, w_down)

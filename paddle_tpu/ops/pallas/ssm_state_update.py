@@ -31,7 +31,9 @@ result (``carried``).
 With no live row at all the one step hands its blocks back as they came.
 
 The arithmetic is the loop's, in float32 on the vector unit: ``S <- S
-keep + B[:, None] xdt``, ``y = sum_n S[n, :] C[n]`` summed tile by tile.
+keep + B[:, None] xdt``, ``y = sum_n S[n, :] C[n]`` summed tile by tile;
+with G groups of heads (nemotron_h: 8) a group at a time, its block of
+``H P / G`` lanes (whole lane tiles) under its own ``B`` and ``C``.
 A row's ``B`` and ``C`` lie along the lanes and the state's rows want
 them along the sublanes; they are turned by a masked sum along the lanes
 against the identity (one term is not zero, so it is exact). Handed over
@@ -41,6 +43,8 @@ copies four arrays a layer back (5% of the device's time: my chip run,
 PR 46). A row of ``kept`` is picked out of its ``PACK`` aligned rows
 the same way, along the sublanes.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -70,7 +74,7 @@ def _tile_rows(n_state, width):
 
 def _kernel(layer_ref, slots_ref, order_ref, n_ref, state_ref, keep_ref,
             xdt_ref, b_ref, c_ref, kept_ref, held_ref, out_ref, y_ref,
-            conv_ref):
+            conv_ref, *, groups):
     j, t = pl.program_id(0), pl.program_id(1)
     n = n_ref[0]
     rows = state_ref.shape[0]
@@ -81,25 +85,37 @@ def _kernel(layer_ref, slots_ref, order_ref, n_ref, state_ref, keep_ref,
         row = pl.ds(i, 1)
         # B and C of the tile's state rows as columns: the row of the
         # batch laid along the sublanes by a masked sum along the lanes
-        n_state = b_ref.shape[1]
+        n_state = b_ref.shape[-1]
+        wide = state_ref.shape[1] // groups
         own = jax.lax.broadcasted_iota(jnp.int32, (rows, n_state), 0) \
             + t * rows == jax.lax.broadcasted_iota(
                 jnp.int32, (rows, n_state), 1)
-        b = jnp.sum(jnp.where(own, b_ref[row, :], 0.0), axis=1,
-                    keepdims=True)
-        c = jnp.sum(jnp.where(own, c_ref[row, :], 0.0), axis=1,
-                    keepdims=True)
-        s = state_ref[...] * keep_ref[row, :] + b * xdt_ref[row, :]
-        out_ref[...] = s
-        part = jnp.sum(s * c, axis=0, keepdims=True)
+        # a group at a time: its heads' lanes under its own B and C
+        # (one group: every lane under the row's one B and C)
+        for g in range(groups):
+            # [1, N]: with groups the batch's row is a leading index and
+            # the group a whole sublane row of its (G, N) tile (a lane
+            # offset into a row picked by a traced index does not lower)
+            its = (row, slice(None)) if groups == 1 \
+                else (i, pl.ds(g, 1), slice(None))
+            lanes = slice(None) if groups == 1 else pl.ds(g * wide, wide)
+            tile = Ellipsis if groups == 1 else (slice(None), lanes)
+            b = jnp.sum(jnp.where(own, b_ref[its], 0.0), axis=1,
+                        keepdims=True)
+            c = jnp.sum(jnp.where(own, c_ref[its], 0.0), axis=1,
+                        keepdims=True)
+            s = state_ref[tile] * keep_ref[row, lanes] \
+                + b * xdt_ref[row, lanes]
+            out_ref[tile] = s
+            part = jnp.sum(s * c, axis=0, keepdims=True)
 
-        @pl.when(t == 0)
-        def _():
-            y_ref[row, :] = part
+            @pl.when(t == 0)
+            def _():
+                y_ref[row, lanes] = part
 
-        @pl.when(t > 0)
-        def _():
-            y_ref[row, :] += part
+            @pl.when(t > 0)
+            def _():
+                y_ref[row, lanes] += part
 
         @pl.when(t == 0)
         def _():
@@ -138,11 +154,13 @@ def _kernel(layer_ref, slots_ref, order_ref, n_ref, state_ref, keep_ref,
 def state_update(state, conv, layer, slots, n, keep, xdt, b, c, kept):
     """Rows ``0 .. n - 1`` of the batch, each in ``state[layer,
     slots[i]]`` and ``conv[layer, slots[i]]``: ``keep`` and ``xdt`` [B,
-    H P] and ``b``, ``c`` [B, N] float32, ``kept`` [B, (K - 1) C] at
+    H P] and ``b``, ``c`` [B, N] (or [B, G, N]: group ``g``'s are those of
+    lanes ``g H P / G`` on) float32, ``kept`` [B, (K - 1) C] at
     ``conv``'s dtype. Returns (y [B, H P] float32, zeros from row ``n``
     on as the loop leaves them; state; conv)."""
     batch, width = keep.shape
     n_state = state.shape[2]
+    groups = 1 if b.ndim == 2 else b.shape[1]
     rows = _tile_rows(n_state, width)
     tiles = n_state // rows
     slots = slots.astype(jnp.int32)
@@ -173,7 +191,7 @@ def state_update(state, conv, layer, slots, n, keep, xdt, b, c, kept):
                    for a in (keep, xdt, keep, b, c, kept)) \
         + 4 * rows * width * 4 + (8 << 20)
     state, y, conv = pl.pallas_call(
-        _kernel,
+        functools.partial(_kernel, groups=groups),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             # a row a live row: with none, one step hands its blocks back

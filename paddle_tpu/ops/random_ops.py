@@ -5,10 +5,21 @@ Each op instance folds the step key with its static op index, so runs are
 reproducible under jit and across replicas without a mutable global state.
 """
 
+import math
+
 import jax
 import jax.numpy as jnp
 
 from ..core.registry import register
+
+
+# A stacked parameter of more elements than this is drawn a slice of its
+# leading axis at a time: drawn at once, the random bits and the float32
+# values of 1.34 G elements asked the chip for 5 GB of scratch beside
+# the model (PERF.md, PR 45), and nemotron_3_super's expert stack is
+# 1.76 G. No other configuration has a parameter this large, so theirs
+# are drawn as they were.
+SLICED_DRAW = 1 << 30
 
 
 def _shape_from(ctx):
@@ -45,8 +56,16 @@ def _gaussian_random(ctx):
     std = ctx.attr('std', 1.0)
     seed = ctx.attr('seed', 0)
     key = ctx.rng_key() if not seed else jax.random.PRNGKey(seed)
-    out = mean + std * jax.random.normal(key, shape, dtype=jnp.float32)
-    ctx.set_output('Out', out.astype(ctx.out_dtype('Out')))
+    dtype = ctx.out_dtype('Out')
+
+    def drawn(key, shape):
+        return (mean + std * jax.random.normal(
+            key, shape, dtype=jnp.float32)).astype(dtype)
+    if len(shape) > 2 and math.prod(shape) > SLICED_DRAW:
+        ctx.set_output('Out', jax.lax.map(
+            lambda k: drawn(k, shape[1:]), jax.random.split(key, shape[0])))
+        return
+    ctx.set_output('Out', drawn(key, shape))
 
 
 @register('truncated_gaussian_random')

@@ -2,11 +2,16 @@
 its chunked form for a prefill chunk and its one-step form for a decode
 batch, both reading and writing a sequence's state where it lies.
 
-The recurrence, a head ``h`` of width P over a state of N, one group
-(every head reads the row's one ``B`` and ``C``)::
+The recurrence, a head ``h`` of width P over a state of N, its ``B``
+and ``C`` those of its group ``g = h // (H / G)`` (granite: one group,
+every head reads the row's one ``B`` and ``C``; nemotron_h: 8 groups of
+16 heads)::
 
-    S_t = exp(dt_t,h A_h) S_{t-1} + dt_t,h (x_t,h outer B_t)
-    y_t,h = S_t C_t                       (the skip term is the caller's)
+    S_t = exp(dt_t,h A_h) S_{t-1} + dt_t,h (x_t,h outer B_t,g)
+    y_t,h = S_t C_t,g                     (the skip term is the caller's)
+
+``b`` and ``c`` are ``[rows, N]`` with one group and ``[rows, G, N]``
+with more: a static form of the one function, not a second one.
 
 **Where the state lives.** ``state`` is the arena ``[layers, slots + 1,
 N, H P]`` float32 and ``conv`` ``[layers, slots + 1, (K - 1) C]`` (a
@@ -18,7 +23,10 @@ With one group that is the layout in which each product of the chunked
 form is one matmul for all heads (``B^T`` against the chunk's weighted
 inputs ``[Q, H P]``; ``C`` against the carried state ``[N, H P]``), the
 decode step's read-out is a sum down the rows, and a row is ``H P``
-lane-dense elements, so the compiler keeps the arena row-major.
+lane-dense elements, so the compiler keeps the arena row-major. With G
+groups a group is a block of ``H P / G`` lanes (16 heads x 64 = 1,024 of
+nemotron_h's 8,192), whole lane tiles, and each of those products is
+one matmul a group, batched over the groups.
 
 The ops touch the arenas at ``(layer, slot)`` alone, as
 ``paged_decode_ops._write_in_place`` writes K and V: nothing of arena
@@ -132,20 +140,27 @@ def causal_conv(window, taps, bias, rows):
 def ssm_chunk_scan(state, layer, slot, x, b, c, dt, a, fresh, chunk,
                    mm_dtype):
     """The chunked scan of one sequence's rows: ``x`` [S, H, P], ``b``
-    and ``c`` [S, N], ``dt`` [S, H] (after softplus; 0 on padded rows),
-    ``a`` [H] (negative), all float32; seeded from ``state[layer,
-    slot]`` (zeros where ``fresh``) and leaving the final state there.
-    Returns (y [S, H, P] float32 without the skip term, the arena)."""
+    and ``c`` [S, N] (or [S, G, N]), ``dt`` [S, H] (after softplus; 0 on
+    padded rows), ``a`` [H] (negative), all float32; seeded from
+    ``state[layer, slot]`` (zeros where ``fresh``) and leaving the final
+    state there. Returns (y [S, H, P] float32 without the skip term, the
+    arena)."""
     rows, heads, width = x.shape
+    # ``g``: the group axis of the products' operands, absent with one
+    g, groups = ('', ()) if b.ndim == 2 else ('g', b.shape[1:2])
     carried = _slot_of(state, layer, slot)                    # [N, H P]
     carried = jnp.where(fresh, jnp.zeros_like(carried), carried)
     q = min(int(chunk), rows)
     tril = jnp.tril(jnp.ones((q, q), bool))
 
     def mm(spec, left, right):
-        return jnp.einsum(spec, left.astype(mm_dtype),
+        return jnp.einsum(spec % {'g': g}, left.astype(mm_dtype),
                           right.astype(mm_dtype),
                           preferred_element_type=jnp.float32)
+
+    def by_group(lanes):
+        """[..., H P] with the lanes split by group: [..., G, H P / G]."""
+        return lanes.reshape(lanes.shape[:-1] + groups + (-1,))
 
     out = []
     with jax.named_scope('ssm_chunk_scan'):
@@ -157,25 +172,31 @@ def ssm_chunk_scan(state, layer, slot, x, b, c, dt, a, fresh, chunk,
             # inside the chunk: (C B^T * L) (dt x), a head at a time
             seg = cum.T[:, :, None] - cum.T[:, None, :]           # [H, Q, Q]
             decay = jnp.exp(jnp.where(tril[None], seg, -jnp.inf))
-            scores = mm('tn,sn->ts', cq, bq)                      # [Q, Q]
-            y = mm('hts,shp->thp', scores[None] * decay, xdt)
+            scores = mm('t%(g)sn,s%(g)sn->%(g)sts', cq, bq)   # [(G,) Q, Q]
+            # a head's scores are its group's
+            scores = jnp.repeat(scores, heads // groups[0], axis=0) \
+                if groups else scores[None]
+            y = mm('hts,shp->thp', scores * decay, xdt)
             # the carried state's part
             y += jnp.exp(cum)[:, :, None] * mm(
-                'tn,nf->tf', cq, carried).reshape(q, heads, width)
+                't%(g)sn,n%(g)sf->t%(g)sf', cq, by_group(carried)
+            ).reshape(q, heads, width)
             out.append(y)
             # the state the chunk leaves
             to_end = jnp.exp(cum[-1:, :] - cum)                   # [Q, H]
-            grown = mm('tn,tf->nf', bq,
-                       (xdt * to_end[:, :, None]).reshape(q, -1))
+            grown = mm('t%(g)sn,t%(g)sf->n%(g)sf', bq, by_group(
+                (xdt * to_end[:, :, None]).reshape(q, -1)))
             carried = carried * jnp.repeat(
-                jnp.exp(cum[-1]), width)[None, :] + grown
+                jnp.exp(cum[-1]), width)[None, :] + grown.reshape(
+                    carried.shape)
     return jnp.concatenate(out), _put_slot(state, layer, slot, carried)
 
 
 def ssm_decode_update(state, conv, layer, slots, live, x, b, c, dt, a,
                       window):
     """One step of the recurrence a row: ``x`` [B, H, P], ``b`` and
-    ``c`` [B, N], ``dt`` [B, H], ``a`` [H], float32; row ``i``'s state in
+    ``c`` [B, N] (or [B, G, N]), ``dt`` [B, H], ``a`` [H], float32; row
+    ``i``'s state in
     ``state[layer, slots[i]]``, read, advanced and written back where it
     lies, and the convolution's last K - 1 inputs (``window[:, 1:]``,
     [B, K, C]) written to ``conv[layer, slots[i]]``. Rows past the last
@@ -199,14 +220,21 @@ def _update_row_by_row(state, conv, layer, slots, upper, keep, xdt, b, c,
     """The update as a loop over rows ``0 .. upper - 1``, one row's slot
     sliced, advanced and written back after another: the form of every
     platform but the TPU, and what the kernel is held to."""
+    def column(of, i):
+        """Row ``i`` of ``b`` or ``c`` down the state's rows: [N, 1], or
+        with groups each group's over its own lanes, [N, H P]."""
+        own = jax.lax.dynamic_index_in_dim(of, i, keepdims=False)
+        if of.ndim == 2:
+            return own[:, None]
+        return jnp.repeat(own.T, keep.shape[1] // of.shape[1], axis=1)
+
     def one(i, carry):
         state, conv, ys = carry
         s = _slot_of(state, layer, slots[i])
         s = s * jax.lax.dynamic_index_in_dim(keep, i, keepdims=True) + \
-            jax.lax.dynamic_index_in_dim(b, i, keepdims=False)[:, None] * \
+            column(b, i) * \
             jax.lax.dynamic_index_in_dim(xdt, i, keepdims=True)
-        y = jnp.sum(s * jax.lax.dynamic_index_in_dim(
-            c, i, keepdims=False)[:, None], axis=0, keepdims=True)
+        y = jnp.sum(s * column(c, i), axis=0, keepdims=True)
         state = _put_slot(state, layer, slots[i], s)
         conv = _put_slot(conv, layer, slots[i],
                          jax.lax.dynamic_index_in_dim(kept, i))

@@ -160,7 +160,7 @@ from ...core.scope import Scope, scope_guard
 from ..buckets import pow2_ladder
 from ..engine import EngineClosedError, QueueFullError
 from .kv_pool import KVPool
-from .model import FULL, LMSpec, build_lm_programs, held_transposed
+from .model import FULL, MOE, LMSpec, build_lm_programs, held_transposed
 from .prefix_cache import PrefixCache, prefix_cache_enabled
 from .scheduler import RUNNING, Scheduler, Sequence
 from .spec import NgramDraft, accept_drafts, spec_k_from_env
@@ -373,6 +373,10 @@ class DecodeEngine(object):
         # step's state update and a chunk's scan are counted by
         self._state_layers = max(
             [len(k.layers) for k in spec.cache_kinds() if k.per_seq] or [0])
+        # the expert layers whose experts sit inside a latent: every row
+        # of a program takes their projection in and out (0: none)
+        self._latent_layers = len(spec.layers_of(MOE)) \
+            if spec.moe_latent else 0
         if _obs.enabled():
             _obs.set_gauge('decode.kv_bytes_per_token',
                            self.kv_bytes_per_token,
@@ -1246,6 +1250,9 @@ class DecodeEngine(object):
         _obs.inc('decode.prefill_chunks_expanded', expanded)
         _obs.inc('decode.prompt_tokens_total', s)
         _obs.inc('decode.prefill_attn_pairs', pairs)
+        if self._latent_layers:
+            _obs.inc('decode.moe_latent_rows_total',
+                     (s - cached) * self._latent_layers)
         if self._state_layers:
             # a first chunk starts its slot from zeros; the scan runs
             # in chunks of ``ssm_chunk`` rows of each program's bucket
@@ -1331,6 +1338,9 @@ class DecodeEngine(object):
                 # the states this step reads, advances and writes back
                 _obs.inc('decode.step_state_rows_total',
                          len(batch) * self._state_layers)
+            if self._latent_layers:
+                _obs.inc('decode.moe_latent_rows_total',
+                         len(batch) * self._latent_layers)
             self._count_attn_pages(lens, len(batch), tokens_per_row)
             window = self.spec.sliding_window
             if window:

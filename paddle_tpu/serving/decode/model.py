@@ -64,9 +64,10 @@ SLIDING, FULL = 'sliding_attention', 'full_attention'
 # below it (``LMSpec.indexer_types`` 'shared'); a kind of ``layer_plan``
 # only, never of ``layer_types``
 CARRIED = 'carried_selection'
-# the layer kinds of block='ssm_hybrid': a Mamba-2 mixer, or attention
-# that sees every position and carries none
-MAMBA, ATTENTION = 'mamba', 'attention'
+# the layer kinds of block='ssm_hybrid': a Mamba-2 mixer, attention that
+# sees every position and carries none, or (where a layer is one
+# sublayer, ``LMSpec.mixer_only``) an expert layer, which caches nothing
+MAMBA, ATTENTION, MOE = 'mamba', 'attention', 'moe'
 
 
 class CacheKind(collections.namedtuple(
@@ -373,7 +374,22 @@ class LMSpec(object):
     with a size a sequence (``CacheKind.per_seq``) in a pool of their
     own whose unit is one sequence's slot. Such a state cannot be
     mapped from a page boundary nor rewound past a rejected draft: the
-    prefix cache and speculation are refused.
+    prefix cache and speculation are refused. Three more forms, each
+    off by default (nemotron_h): ``ssm_groups`` > 1 gives ``B`` and
+    ``C`` a group of ``ssm_heads / ssm_groups`` heads each (head ``h``
+    reads group ``h // (heads / groups)``) and the gated norm its
+    statistics a group; ``tie_embeddings`` False an output head of its
+    own; and ``mixer_only``: **a layer is one sublayer**, ``h = x + r
+    Mixer(RMSNorm(x))`` with no MLP behind it, and ``layer_types`` has a
+    third kind, 'moe', an expert layer that owns no cache: a sigmoid
+    router over ``n_experts`` on the hidden width with a selection-only
+    bias, the ``experts_per_token`` chosen scores normalised and times
+    ``routed_scale``; the experts inside a latent of ``moe_latent``
+    (``u = n W_in``, ``r = sum_e w_e relu(u W1_e)^2 W2_e`` over the
+    ``experts_held`` from ``first_expert``, width ``d_inner``, two
+    matrices an expert and no gate matrix, ``r W_out``), and one shared
+    expert ``relu(n V1)^2 V2`` of ``d_inner_shared`` on the hidden width
+    at weight 1.
 
     ``block='shortcut_moe'`` (longcat_flash): a layer of two sublayers
     ``j``, each with its own latent attention (the one ``full_attention``
@@ -410,7 +426,9 @@ class LMSpec(object):
                  rope_parameters=None, ssm_heads=0, ssm_head_dim=0,
                  ssm_state=0, ssm_conv=4, ssm_chunk=256, embed_scale=1.0,
                  residual_scale=1.0, attn_scale=None, zero_experts=0,
-                 indexer_types=None, index_rope_interleave=False):
+                 indexer_types=None, index_rope_interleave=False,
+                 ssm_groups=1, mixer_only=False, tie_embeddings=True,
+                 moe_latent=0, d_inner_shared=0):
         self.vocab_size = int(vocab_size)
         self.n_layer = int(n_layer)
         self.n_head = int(n_head)
@@ -453,6 +471,11 @@ class LMSpec(object):
         self.ssm_state = int(ssm_state)
         self.ssm_conv = int(ssm_conv)
         self.ssm_chunk = int(ssm_chunk)
+        self.ssm_groups = int(ssm_groups)
+        self.mixer_only = bool(mixer_only)
+        self.tie_embeddings = bool(tie_embeddings)
+        self.moe_latent = int(moe_latent)
+        self.d_inner_shared = int(d_inner_shared)
         self.embed_scale = float(embed_scale)
         self.residual_scale = float(residual_scale)
         self.attn_scale = float(attn_scale) if attn_scale \
@@ -506,25 +529,46 @@ class LMSpec(object):
             self._check_rope()
 
     def _check_ssm(self):
+        kinds = {MAMBA, ATTENTION} | ({MOE} if self.mixer_only else set())
         if len(self.layer_types) != self.n_layer or \
-                set(self.layer_types) - {MAMBA, ATTENTION}:
-            raise ValueError('LMSpec: layer_types %r for %d layers (mamba, '
-                             'attention)' % (self.layer_types, self.n_layer))
+                set(self.layer_types) - kinds:
+            raise ValueError('LMSpec: layer_types %r for %d layers (%s)'
+                             % (self.layer_types, self.n_layer,
+                                ', '.join(sorted(kinds))))
         if self.n_head % self.n_kv_head or self.d_key != self.d_value:
             raise ValueError('LMSpec: %d query heads over %d KV heads of '
                              '%d/%d' % (self.n_head, self.n_kv_head,
                                         self.d_key, self.d_value))
-        if MAMBA in self.layer_types and min(
+        if MAMBA in self.layer_types and (min(
                 self.ssm_heads, self.ssm_head_dim, self.ssm_state,
-                self.ssm_conv - 1, self.ssm_chunk) < 1:
+                self.ssm_conv - 1, self.ssm_chunk, self.ssm_groups) < 1
+                or self.ssm_heads % self.ssm_groups):
             raise ValueError(
-                'LMSpec: mamba layers of %d heads of %d, state %d, %d taps, '
-                'chunks of %d' % (self.ssm_heads, self.ssm_head_dim,
-                                  self.ssm_state, self.ssm_conv,
-                                  self.ssm_chunk))
-        if self.n_experts or self.dense_layers:
-            raise ValueError("LMSpec: block='ssm_hybrid' has a dense MLP in "
-                             "every layer and no experts")
+                'LMSpec: mamba layers of %d heads of %d in %d groups, state '
+                '%d, %d taps, chunks of %d'
+                % (self.ssm_heads, self.ssm_head_dim, self.ssm_groups,
+                   self.ssm_state, self.ssm_conv, self.ssm_chunk))
+        if MOE not in self.layer_types:
+            if self.n_experts or self.dense_layers:
+                raise ValueError(
+                    "LMSpec: block='ssm_hybrid' has a dense MLP in every "
+                    "layer and no experts, or (mixer_only) layers of one "
+                    "sublayer of which the 'moe' ones hold the experts")
+            return
+        if not (0 < self.experts_per_token <= self.n_experts and
+                0 < self.experts_held and
+                self.first_expert + self.experts_held <= self.n_experts
+                and self.n_shared_experts == 1 and not self.zero_experts
+                and min(self.moe_latent, self.d_inner,
+                        self.d_inner_shared) > 0):
+            raise ValueError(
+                'LMSpec: moe layers of experts %d..%d of %d, %d per token, '
+                'width %d inside a latent of %d, %d shared of %d'
+                % (self.first_expert,
+                   self.first_expert + self.experts_held - 1,
+                   self.n_experts, self.experts_per_token, self.d_inner,
+                   self.moe_latent, self.n_shared_experts,
+                   self.d_inner_shared))
 
     @property
     def ssm_inner(self):
@@ -533,8 +577,9 @@ class LMSpec(object):
 
     @property
     def ssm_conv_width(self):
-        """What the convolution runs over: x, B and C of the one group."""
-        return self.ssm_inner + 2 * self.ssm_state
+        """What the convolution runs over: x, and B and C of every
+        group."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     def _check_rope(self):
         kinds = set(self.layer_types)
@@ -1203,18 +1248,32 @@ def ssm_param_shapes(spec):
     taps ``[K, H P + 2 N]`` (tap K - 1 reads the row's own input) and
     their bias, ``dt.b`` and ``a_log`` a head each (biases: float32,
     zero at start, so A = -1 until weights are loaded), ``d`` the skip
-    gain a head and ``norm`` the gated norm's (ones)."""
+    gain a head and ``norm`` the gated norm's (ones). With
+    ``ssm_groups`` G the projection and the convolution carry ``B`` and
+    ``C`` of every group, ``[... B (G N); C (G N) ...]``. Where a layer
+    is one sublayer (``mixer_only``) there is one norm a layer and no
+    MLP, and ``lm_moe_*`` are stacks over the expert layers: the router
+    on the hidden width and its selection-only bias, the projection into
+    the latent and out of it, an expert's two matrices inside the latent
+    (``exp_up`` ``[e, latent, f]``, ``exp_down`` ``[e, f, latent]``) and
+    the one shared expert's two on the hidden width."""
     L, d, f = spec.n_layer, spec.d_model, spec.d_inner
     q, kv = spec.n_head * spec.d_key, spec.n_kv_head * spec.d_key
     out = collections.OrderedDict([
         ('lm_emb', ([spec.vocab_size, d], d, 'Emb')),
         ('lm_final_ln.w', ([d], None, 'FinalLN')),
         ('lm_stack_ln1.w', ([L, d], None, 'Ln1W')),
-        ('lm_stack_ln2.w', ([L, d], None, 'Ln2W')),
-        ('lm_stack_mlp_gate.w', ([L, d, f], d, 'MlpGate')),
-        ('lm_stack_mlp_up.w', ([L, d, f], d, 'MlpUp')),
-        ('lm_stack_mlp_down.w', ([L, f, d], f, 'MlpDown')),
     ])
+    if not spec.tie_embeddings:
+        # held [V, d] like the embedding (gqa_param_shapes)
+        out['lm_head.w'] = ([spec.vocab_size, d], d, 'Head')
+    if not spec.mixer_only:
+        out.update([
+            ('lm_stack_ln2.w', ([L, d], None, 'Ln2W')),
+            ('lm_stack_mlp_gate.w', ([L, d, f], d, 'MlpGate')),
+            ('lm_stack_mlp_up.w', ([L, d, f], d, 'MlpUp')),
+            ('lm_stack_mlp_down.w', ([L, f, d], f, 'MlpDown')),
+        ])
     n = len(spec.layers_of(ATTENTION))
     if n:
         out.update([
@@ -1237,6 +1296,19 @@ def ssm_param_shapes(spec):
             ('lm_mamba_d', ([n, heads], None, 'SsmD')),
             ('lm_mamba_norm.w', ([n, inner], None, 'SsmNorm')),
             ('lm_mamba_out.w', ([n, inner, d], inner, 'SsmOut')),
+        ])
+    n = len(spec.layers_of(MOE))
+    if n:
+        e, lat, sh = spec.experts_held, spec.moe_latent, spec.d_inner_shared
+        out.update([
+            ('lm_moe_router.w', ([n, d, spec.n_experts], d, 'Router')),
+            ('lm_moe_router.b', ([n, spec.n_experts], 0, 'RouterBias')),
+            ('lm_moe_lat_in.w', ([n, d, lat], d, 'LatIn')),
+            ('lm_moe_lat_out.w', ([n, lat, d], lat, 'LatOut')),
+            ('lm_moe_exp_up.w', ([n, e, lat, f], lat, 'ExpUp')),
+            ('lm_moe_exp_down.w', ([n, e, f, lat], f, 'ExpDown')),
+            ('lm_moe_shr_up.w', ([n, d, sh], d, 'ShrUp')),
+            ('lm_moe_shr_down.w', ([n, sh, d], sh, 'ShrDown')),
         ])
     return out
 
@@ -1328,7 +1400,12 @@ def _block_attrs(spec, block_size):
             'ssm_chunk': spec.ssm_chunk, 'embed_scale': spec.embed_scale,
             'residual_scale': spec.residual_scale,
             'attn_scale': spec.attn_scale,
-            'logit_scale': spec.logit_scale})
+            'logit_scale': spec.logit_scale,
+            'ssm_groups': spec.ssm_groups,
+            'mixer_only': int(spec.mixer_only),
+            'top_k': spec.experts_per_token,
+            'first_expert': spec.first_expert,
+            'routed_scale': spec.routed_scale})
     return attrs
 
 
@@ -1390,7 +1467,8 @@ def _moe_stats_output(helper, spec, outputs):
     if not spec.n_experts:
         return None
     stats = helper.create_variable_for_type_inference('int32')
-    stats.shape = (spec.n_layer - spec.dense_layers,
+    stats.shape = (len(spec.layers_of(MOE)) if spec.block == 'ssm_hybrid'
+                   else spec.n_layer - spec.dense_layers,
                    4 + (spec.experts_per_token + 1
                         if spec.zero_experts else 0))
     outputs['MoeStats'] = [stats]

@@ -637,7 +637,8 @@ def _paged_op(spec, geometry, op, rows):
     """(function of (weights, arenas, feeds) -> (tokens, arenas), the
     three arguments' shapes) of ``paged_decode_step`` over ``rows`` slots
     or ``paged_prefill`` of a chunk of ``rows``, for a spec with one page
-    pool, from the program builder's own tables."""
+    pool (and, where it keeps a state, its pool of slots), from the
+    program builder's own tables."""
     from paddle_tpu.core.registry import get_lowering
     from paddle_tpu.serving.decode import model as lm
     import paddle_tpu.ops.paged_decode_ops  # noqa: F401  (registers)
@@ -647,9 +648,13 @@ def _paged_op(spec, geometry, op, rows):
                       spec.dtype if fan_in else 'float32')
                for shape, fan_in, slot in
                lm.block_param_shapes(spec).values()}
-    arenas = {k.slot: ((len(k.layers), geometry['num_blocks'])
+    # a kind with a size a sequence: a slot a batch row and the spare,
+    # at its own dtype (``model._arenas``)
+    arenas = {k.slot: ((len(k.layers), geometry['max_batch'] + 1
+                        if k.per_seq else geometry['num_blocks'])
                        + tuple(k.unit_shape(geometry['block_size'])),
-                       geometry['kv_dtype']) for k in spec.cache_kinds()}
+                       k.dtype or geometry['kv_dtype'])
+              for k in spec.cache_kinds()}
     pages = geometry['pages_per_seq']
     if op == 'paged_decode_step':
         feeds = {'Tokens': ((rows,), 'int32'), 'SeqLens': ((rows,), 'int32'),
@@ -661,6 +666,11 @@ def _paged_op(spec, geometry, op, rows):
                  'Cached': ((), 'int32'), 'BlockTable': ((pages,), 'int32'),
                  'Temp': ((), 'float32'), 'Seed': ((), 'int32')}
         out = 'NextToken'
+    if spec.keeps_state():
+        # the state pool's table: the one slot index a row
+        feeds.update({'BlockTablesState': ((rows, 1), 'int32')}
+                     if op == 'paged_decode_step'
+                     else {'BlockTableState': ((1,), 'int32')})
     lowering = get_lowering(op)
 
     def fn(w, a, f):
@@ -671,11 +681,13 @@ def _paged_op(spec, geometry, op, rows):
     return fn, (weights, arenas, feeds)
 
 
-def _compiled_at_published_size(one_chip, spec, geometry, op, rows):
+def _compiled_at_published_size(one_chip, spec, geometry, op, rows,
+                                slack=1 << 20):
     """``op`` over ``rows`` compiled for the v5e, the arenas donated as
     the executor donates them: (its HLO, the weights' bytes, the arenas'
-    bytes), the argument bytes held to their sum and the whole program
-    to the chip's 15.75 GiB."""
+    bytes), the argument bytes held to their sum (to ``slack``: the
+    feeds, and what the chip's tiling pads) and the whole program to the
+    chip's 15.75 GiB."""
     import math
     fn, shapes = _paged_op(spec, geometry, op, rows)
     args = [{slot: _shaped(one_chip, shape, dtype)
@@ -687,7 +699,7 @@ def _compiled_at_published_size(one_chip, spec, geometry, op, rows):
         sum(jnp.dtype(d).itemsize * math.prod(s)
             for s, d in group.values()) for group in shapes[:2])
     assert abs(memory.argument_size_in_bytes
-               - weights_b - arena_b) < 1 << 20, op
+               - weights_b - arena_b) < slack, op
     # the arenas are aliased to their outputs, and what the program
     # keeps beside its arguments fits the chip with them
     assert memory.alias_size_in_bytes >= arena_b
@@ -936,3 +948,64 @@ def test_no_latent_program_relays_a_projection_out_of_the_query_rank(
                if r[0] not in ASYNC and extents(dims(r[1])) in theirs
                and tuple(d for d in dims(r[1]) if d != 1) not in unmarked]
     assert written == LEFT.get(cell, []), written
+
+
+def test_the_one_sublayer_block_loads_at_its_published_geometry(one_chip):
+    """nemotron_3_super as the benchmark runs it (every published width,
+    the period *EMEMEMEMEM = layers 25-35 of 88, 128 of 512 experts, 1/4
+    vocabulary; 64 slots, 36,864 pages of 32, tables of 576 pages): the
+    decode step and the 512 chunk compile for the v5e (the 128 and 256
+    chunks are the 512's program at another extent: compiled once by
+    hand, PR 58, not here), the arenas donated, with the argument bytes the configuration's
+    ``geometry`` states (9.30 GB of weights + 2.59 GB of arenas = 11.9
+    GB) and the whole program under the chip's 15.75 GiB. By
+    ``serving/decode/hlo_check.py`` no instruction outside the entry
+    computation materialises a layer of the state arena or the K/V
+    arena, and every arena stays row-major. The decode step holds one
+    ssm_state_update kernel a Mamba-2 layer (8 state groups: B and C
+    ``[64, 8, 128]`` among its operands) with both state arenas aliased
+    to its results; every program one moe_routed_product kernel an
+    expert layer whose weight operands are the two stacks of the
+    two-matrix expert and no third."""
+    from paddle_tpu.serving.decode.hlo_check import arena_sized_instructions
+    cell = 'nemotron_3_super.agent_ctx_long_answers'
+    import json
+    import os
+    spec, geometry = cell_spec(cell)
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), 'benchmark', 'configs',
+            'nemotron_3_super.json')) as f:
+        stated = json.load(f)['geometry']
+    state, conv = 'f32[5,65,128,8192]', 'bf16[5,65,30720]'
+    pages = 'bf16[1,36864,32,256]'
+    up, down = 'bf16[5,128,1024,2688]', 'bf16[5,128,2688,1024]'
+    programs = [('paged_decode_step', 64), ('paged_prefill', 512)]
+    for op, rows in programs:
+        # the convolution rows' 65 slots are padded to whole tiles: 2.6 MB
+        hlo, weights_b, arena_b = _compiled_at_published_size(
+            one_chip, spec, geometry, op, rows, slack=4 << 20)
+        assert weights_b == stated['weights_bytes'], op
+        assert arena_b == stated['arena_bytes'], op
+        for arena, order in ((state, '3,2,1,0'), (conv, '2,1,0'),
+                             (pages, '3,2,1,0')):
+            assert set(re.findall(re.escape(arena) + r'\{([\d,]+)', hlo)) \
+                == {order}, (op, rows, arena)
+        for layer in (65 * 128 * 8192, 36864 * 32 * 256):
+            inner = [i for i in arena_sized_instructions(hlo, layer)
+                     if not i.computation.startswith('main')]
+            assert inner == [], (op, rows)
+        routed = _kernels(hlo, 'moe_routed_product')
+        assert len(routed) == 5, (op, rows)
+        for line in routed:
+            operands = line.split('operand_layout_constraints={', 1)[1]
+            assert up in operands and down in operands
+            assert len(re.findall(r'bf16\[5,128,\d+,\d+\]', operands)) == 2
+        updates = _kernels(hlo, 'ssm_state_update')
+        assert len(updates) == (5 if op == 'paged_decode_step' else 0)
+        for line in updates:
+            alias = {int(out): int(at) for out, at in re.findall(
+                r'\{(\d+)\}: \((\d+), \{\}\)', line)}
+            assert sorted(alias) == [0, 2], line[:200]
+            assert 'f32[64,8,128]' in line
+    assert round(stated['weights_bytes'] / 1e9, 2) == 9.30
+    assert round(stated['arena_bytes'] / 1e9, 2) == 2.59
