@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import register
+from .random_ops import keep_mask
 
 _NEG_INF = -1e9
 
@@ -178,9 +179,8 @@ def _attend(q, k, v, causal, key_length, query_length, dropout_rate, rng,
     if dropout_rate and not is_test:
         # dropout on attention output (weights-dropout would block the
         # flash/ring paths; output-dropout is the TPU-friendly equivalent)
-        keep = 1.0 - dropout_rate
-        mask = jax.random.bernoulli(rng, keep, out.shape)
-        out = jnp.where(mask, out / keep, 0.0)
+        mask, kept = keep_mask(rng, 1.0 - dropout_rate, out.shape)
+        out = jnp.where(mask, out / kept, 0.0)
     return out
 
 

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.registry import register
+from .random_ops import keep_mask
 
 
 def _fused_ce_enabled():
@@ -160,11 +161,11 @@ def _dropout(ctx):
         out = x * (1.0 - p) if impl == 'downgrade_in_infer' else x
         mask = jnp.ones_like(x)
     else:
-        keep = jax.random.bernoulli(ctx.rng_key(), 1.0 - p, x.shape)
-        mask = keep.astype(x.dtype)
+        mask, kept = keep_mask(ctx.rng_key(), 1.0 - p, x.shape)
+        mask = mask.astype(x.dtype)
         out = x * mask
         if impl == 'upscale_in_train' and p < 1.0:
-            out = out / (1.0 - p)
+            out = out / kept
     ctx.set_output('Mask', mask)
     ctx.set_output('Out', out)
 

@@ -22,6 +22,30 @@ from ..core.registry import register
 SLICED_DRAW = 1 << 30
 
 
+def keep_mask(key, keep, shape):
+    """A dropout site's keep-mask and the probability it really keeps.
+    An element is kept where 16 random bits read under ``round(keep *
+    65536)``: the draw is what a mask costs on the chip (the generator
+    writes it to HBM and the mask's fusion reads it back; PERF.md, PR
+    59), and 16 bits resolve a rate to 1.5e-5, so the kept probability
+    ``t / 65536`` is returned for the sites that divide by it. The bits
+    are 32-bit words over half the last axis, the low halves masking
+    its first half and the high halves its second: the chip's generator
+    makes words, and a ``uint16`` draw of the whole shape took it longer
+    and the train step longer than the float draw had (same place). A
+    rate within 2**-17 of either end has no such threshold and takes the
+    32-bit draw of ``jax.random.bernoulli``."""
+    t = int(round(keep * 65536))
+    shape = tuple(shape)
+    if not (0 < t < 65536 and shape):
+        return jax.random.bernoulli(key, keep, shape), keep
+    n = shape[-1]
+    words = jax.random.bits(key, shape[:-1] + ((n + 1) // 2,), jnp.uint32)
+    mask = jnp.concatenate([(words & 0xFFFF) < t, (words >> 16) < t],
+                           axis=-1)
+    return mask[..., :n], t / 65536.0
+
+
 def _shape_from(ctx):
     return [int(s) for s in ctx.attr('shape')]
 

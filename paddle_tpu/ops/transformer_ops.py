@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from ..core.registry import register
 from .attention_ops import attention_sublayer
+from .random_ops import keep_mask
 
 
 def _dropout(x, rate, key, is_test):
@@ -27,7 +28,7 @@ def _dropout(x, rate, key, is_test):
         return x
     if is_test:
         return x * (1.0 - rate)
-    mask = jax.random.bernoulli(key, 1.0 - rate, x.shape)
+    mask, _ = keep_mask(key, 1.0 - rate, x.shape)
     return x * mask.astype(x.dtype)
 
 

@@ -9,6 +9,7 @@ its queries, keys, values and their cotangents in passes of their own
 (PERF.md, PR 44). One file, the topology in a fixture
 (on-chip-measurement guide, 2)."""
 
+import math
 import re
 
 import pytest
@@ -462,28 +463,28 @@ def _entry_copies(hlo, under):
     return out
 
 
-@pytest.mark.parametrize('kind', ['self', 'causal', 'cross'])
-def test_a_train_layer_relays_nothing_around_its_attention(
-        one_chip, monkeypatch, kind):
+# batch, tokens, heads, head size, d_model, FFN, dropout rate
+TRAIN_LAYER = (128, 128, 16, 64, 1024, 4096, 0.3)
+
+
+@pytest.fixture(scope='module', params=['self', 'causal', 'cross'])
+def train_layer_hlo(request, one_chip):
     """One layer of `tbig_nmt.train_seq128`'s step as the executor jits
     it (128 x 128 tokens, 16 heads of 64, d_model 1,024, FFN 4,096,
     dropout 0.3, bf16 matmuls, forward, backward and Adam) with each of
-    the three attentions the model has: ``self`` is the encoder layer,
-    ``causal`` and ``cross`` the decoder layer's two, each with the
-    layer's FFN behind it. The ENTRY computation holds no ``copy`` of
-    B x T x H x D elements under ``fused_attention``: when the model
-    projected q, k and v itself and split the heads by a reshape and a
-    transpose, each attention cost four such passes (one forward, three
-    backward: 72 a step of the cell, 33.5 MB each). Whatever re-tiling
-    is left happens where a matmul stores its result."""
+    the three attentions the model has, compiled for the described
+    chip: ``self`` is the encoder layer, ``causal`` and ``cross`` the
+    decoder layer's two, each with the layer's FFN behind it."""
     import numpy as np
     import paddle_tpu as fluid
     from paddle_tpu import layers
     from paddle_tpu.models import transformer as T
-    b, t, h, d, m, ffn, rate = 128, 128, 16, 64, 1024, 4096, 0.3
-    monkeypatch.setenv('PADDLE_TPU_PRNG', 'rbg')   # as on the chip
-    with fluid.program_guard(fluid.Program(), fluid.Program()), \
+    kind = request.param
+    b, t, h, d, m, ffn, rate = TRAIN_LAYER
+    with pytest.MonkeyPatch.context() as patch, \
+            fluid.program_guard(fluid.Program(), fluid.Program()), \
             fluid.scope_guard(fluid.Scope()):
+        patch.setenv('PADDLE_TPU_PRNG', 'rbg')   # as on the chip
         x = layers.data(name='x', shape=[t, m], dtype='float32')
         mem = layers.data(name='mem', shape=[t, m], dtype='float32')
         length = layers.data(name='length', shape=[], dtype='int64')
@@ -510,16 +511,43 @@ def test_a_train_layer_relays_nothing_around_its_attention(
                         'length': np.full((b,), t, 'int64')},
             fetch_list=[loss])
 
-    def shaped(tree):
-        return jax.tree_util.tree_map(
-            lambda a: _shaped(one_chip, np.shape(a), a.dtype), tree)
-    hlo = jax.jit(step, donate_argnums=(0,)).lower(
-        shaped(scope_vals), shaped(feed_vals),
-        _shaped(one_chip, (), jnp.int32)).compile().as_text()
-    assert 'fused_attention' in hlo
-    relaid = [c for c in _entry_copies(hlo, 'fused_attention')
+        def shaped(tree):
+            return jax.tree_util.tree_map(
+                lambda a: _shaped(one_chip, np.shape(a), a.dtype), tree)
+        return jax.jit(step, donate_argnums=(0,)).lower(
+            shaped(scope_vals), shaped(feed_vals),
+            _shaped(one_chip, (), jnp.int32)).compile().as_text()
+
+
+def test_a_train_layer_relays_nothing_around_its_attention(train_layer_hlo):
+    """The ENTRY computation holds no ``copy`` of B x T x H x D elements
+    under ``fused_attention``: when the model projected q, k and v
+    itself and split the heads by a reshape and a transpose, each
+    attention cost four such passes (one forward, three backward: 72 a
+    step of the cell, 33.5 MB each). Whatever re-tiling is left happens
+    where a matmul stores its result."""
+    b, t, h, d = TRAIN_LAYER[:4]
+    assert 'fused_attention' in train_layer_hlo
+    relaid = [c for c in _entry_copies(train_layer_hlo, 'fused_attention')
               if c[0] >= b * t * h * d]
     assert relaid == []
+
+
+def test_a_train_layer_draws_16_bits_a_dropped_element(train_layer_hlo):
+    """The layer's four dropout sites (the attention's output, the two
+    post-process sites, the FFN's hidden) each draw their mask from
+    ``u32`` words of half the site's elements: no ``rng-bit-generator``
+    writes a 32-bit word an element, which was half of what dropout
+    cost the step (PERF.md, PR 59)."""
+    b, t, h, d, m, ffn, _ = TRAIN_LAYER
+    drawn = sorted(
+        (dtype, math.prod(int(n) for n in dims.split(',')))
+        for dtype, dims in re.findall(
+            r' = (?:\(u64\[2\]\S*, )?(\w+)\[([\d,]+)\]\S* '
+            r'rng-bit-generator\(', train_layer_hlo))
+    assert drawn == sorted(
+        ('u32', n // 2)
+        for n in (b * h * t * d, b * t * m, b * t * m, b * t * ffn))
 
 
 def test_the_state_arenas_are_written_where_they_lie(one_chip):
