@@ -17,6 +17,15 @@ asks for the host CPU by name and takes the tiny sizes of the config's
 and the traffic's ``rehearsal`` blocks: it exists to debug the harness
 without a chip, stamps ``platform=cpu`` and reports every time, rate
 and share as null.
+
+A traced run on the chip is made by a child of this process, which
+itself never starts jax (``supervise``): where the profiler hands back
+no device line for the traced tail, or the tail was never reached, the
+child leaves with ``TRACE_LOST`` as the window closes, before its
+checks, and the whole run is made once more, in a new process on the
+same seed. A second loss prints the line as it is, with the reason
+under ``device.trace_lost``. Untraced runs and rehearsals are one
+process, as they were.
 """
 
 import time
@@ -27,7 +36,10 @@ import contextlib          # noqa: E402
 import json                # noqa: E402
 import os                  # noqa: E402
 import shutil              # noqa: E402
+import signal              # noqa: E402
+import subprocess          # noqa: E402
 import sys                 # noqa: E402
+import tempfile            # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 if os.path.dirname(HERE) not in sys.path:
@@ -47,6 +59,13 @@ GAP_LABELS = (r'^bench\.(dispatch|wait_oldest)$',
 COMPILE_EVENTS = ('/jax/compilation_cache/cache_hits',
                   '/jax/compilation_cache/cache_misses')
 COMPILE_DURATION = '/jax/core/compile/backend_compile_duration'
+TRACE_LOST = 75            # a child's exit code: make the run again
+ATTEMPTS = 2               # of a traced run on the chip, at the most
+
+
+class TraceLost(Exception):
+    """The traced tail holds nothing of the device, and the run may be
+    made again."""
 
 
 class Context(object):
@@ -61,6 +80,9 @@ class Context(object):
         self.seconds = float(args.seconds)
         self.trace = bool(args.trace)
         self.rehearsal = bool(args.rehearsal)
+        # a supervised child that is not the last gives up a lost trace
+        self.may_retry = 0 < getattr(args, 'attempt', 0) < ATTEMPTS
+        self.trace_lost = None         # why, where the tail gave no device line
         self.root = root
         self.spans = {}
         self.samples = {}
@@ -68,7 +90,10 @@ class Context(object):
         self.setup_s = None
         self.t_window = None
         self.t_trace = None            # perf_counter when tracing began
-        self._trace_dir = os.path.join(root, '.bench_trace')
+        # the trace's directory is this run's alone (under TMPDIR, made as
+        # the tail begins): two runs of one checkout at a time, as the
+        # tests' workers make them, must not remove or read each other's
+        self._trace_dir = None
         self._window_span = None
         self._compiles = 0
         self.compiles_in_window = None
@@ -135,12 +160,18 @@ class Context(object):
         if self.window_left() > tail:
             return
         import jax
-        shutil.rmtree(self._trace_dir, ignore_errors=True)
+        if self._trace_dir is None:
+            # a name only: the profiler makes the directory it writes to
+            self._trace_dir = os.path.join(
+                tempfile.gettempdir(), 'bench_trace_%d_%d'
+                % (os.getpid(), time.perf_counter_ns()))
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         options.enable_hlo_proto = False
+        t0 = time.perf_counter()
         jax.profiler.start_trace(self._trace_dir, profiler_options=options)
         self.t_trace = time.perf_counter()
+        self._trace_start_s = self.t_trace - t0
         self.registry_tail = self._snapshot()
         self._window_span = jax.profiler.TraceAnnotation(WINDOW_SPAN)
         self._window_span.__enter__()
@@ -152,10 +183,42 @@ class Context(object):
         self.memory = [d.memory_stats() or {}
                        for d in jax.devices()[:self.cell['chips']]]
         if self._window_span is not None:
-            import jax
             self._window_span.__exit__(None, None, None)
             self._window_span = None
+            t0 = time.perf_counter()
             jax.profiler.stop_trace()
+            self._trace_stop_s = time.perf_counter() - t0
+        if self.trace and not self.rehearsal:
+            self.trace_lost = self._why_lost()
+        if self.trace_lost:
+            say('TRACE_LOST', why=self.trace_lost, may_retry=self.may_retry)
+            if self.may_retry:
+                shutil.rmtree(self._trace_dir or '', ignore_errors=True)
+                raise TraceLost(self.trace_lost)
+
+    def _why_lost(self):
+        """None where the traced tail holds ops of every chip the cell
+        runs on, else what it lacks, in words. A look at the file's
+        planes and lines, not a reading: it is made as the window
+        closes, on the thread that still polls the requests in flight."""
+        if self.t_trace is None:
+            return ('the window closed before tick() could start the '
+                    'profiler: the runner\'s thread stood still through '
+                    'its last %.1f s' % min(TRACE_SECONDS, self.seconds / 2))
+        t0 = time.perf_counter()
+        path = tracelib.find_xplane(self._trace_dir)
+        chips = tracelib.chips_with_ops(path) if path else []
+        say('TRACE_SESSION', start_s=self._trace_start_s,
+            stop_s=self._trace_stop_s, look_s=time.perf_counter() - t0,
+            file_bytes=os.path.getsize(path) if path else None,
+            chips_with_ops=chips)
+        if len(chips) >= self.cell['chips']:
+            return None
+        return ('the profiler took %.3f s to start and %.3f s to stop, and '
+                '%s' % (self._trace_start_s, self._trace_stop_s,
+                        'its trace holds device ops of chips %s where the '
+                        'cell runs on %d' % (chips, self.cell['chips'])
+                        if path else 'wrote no trace'))
 
     def _snapshot(self):
         if not self.trace:
@@ -165,6 +228,8 @@ class Context(object):
 
     def take_trace(self):
         """The reduced trace, or None; the directory is removed."""
+        if self._trace_dir is None:
+            return None
         try:
             path = tracelib.find_xplane(self._trace_dir)
             return tracelib.read_xplane(path) if path else None
@@ -258,7 +323,7 @@ def named_idle_gaps(sources):
                               GAP_LABELS, 5)
 
 
-def main(argv=None, root=None):
+def arguments(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split('\n')[0])
     ap.add_argument('--workload', required=True)
     ap.add_argument('--seed', type=int, default=0)
@@ -266,7 +331,50 @@ def main(argv=None, root=None):
     ap.add_argument('--trace', type=int, choices=(0, 1), default=0)
     ap.add_argument('--rehearsal', action='store_true',
                     help='tiny sizes on the host CPU; times are null')
-    args = ap.parse_args(argv)
+    ap.add_argument('--attempt', type=int, default=0, help=argparse.SUPPRESS)
+    return ap.parse_args(argv)
+
+
+def supervise(argv, spawn=subprocess.Popen):
+    """A traced run on the chip, made by a child and made again, once,
+    where the child leaves with TRACE_LOST; the exit code of the last.
+    This process never starts jax, so the chip is the child's alone; the
+    child writes to this process's own stdout and stderr, and is ended
+    and waited for on every way out of here."""
+    def leave(signum, _):
+        sys.exit(128 + signum)
+    signal.signal(signal.SIGTERM, leave)
+    code = TRACE_LOST
+    for attempt in range(1, ATTEMPTS + 1):
+        child = spawn([sys.executable, os.path.abspath(__file__)]
+                      + list(argv) + ['--attempt', str(attempt)])
+        try:
+            code = child.wait()
+        finally:
+            if child.poll() is None:
+                child.kill()
+                child.wait()
+        if code != TRACE_LOST:
+            break
+        sys.stderr.write('benchmark: attempt %d lost its trace; the run is '
+                         'made again\n' % attempt)
+    return code if code >= 0 else 128 - code
+
+
+def die_with_parent():
+    """A supervised child is not left behind where its parent is killed
+    outright (prctl PR_SET_PDEATHSIG, Linux; elsewhere nothing)."""
+    try:
+        import ctypes
+        ctypes.CDLL(None).prctl(1, int(signal.SIGKILL))
+    except (OSError, AttributeError):
+        pass
+
+
+def main(argv=None, root=None):
+    args = arguments(argv)
+    if args.attempt:
+        die_with_parent()
 
     root = root or os.path.dirname(HERE)
     m = manifest.load(root)
@@ -331,6 +439,9 @@ def main(argv=None, root=None):
         t_reduce = time.perf_counter()
         fields, breakdown, trace = reduce_trace(ctx, chips)
         device.update(fields)
+        if not fields and not args.rehearsal:
+            # the check refuses such a line; it says why it is so
+            device['trace_lost'] = ctx.trace_lost or 'no window in the trace'
         sources = dict(
             ctx.sources, spans=ctx.spans, samples=ctx.samples,
             registry_before=ctx.registry[0], registry_after=ctx.registry[1],
@@ -369,8 +480,17 @@ def main(argv=None, root=None):
 
 
 if __name__ == '__main__':
+    given = arguments()
+    if given.trace and not given.rehearsal and not given.attempt:
+        sys.exit(supervise(sys.argv[1:]))
     try:
         sys.exit(main())
+    except TraceLost:
+        # the engine's threads and the programs in flight go with the
+        # process; the parent makes the run again
+        sys.stdout.flush()
+        sys.stderr.flush()
+        os._exit(TRACE_LOST)
     except Exception:
         # a program the chip refuses to load leaves the TPU runtime unable
         # to shut down: the interpreter then hangs at exit until the

@@ -1,10 +1,10 @@
 """Model FLOPs per second per chip of the encoder-decoder Transformer's
 train step: the matrix multiplications and attention products the
 forward and backward passes require, at the padded shapes, times the
-measured tokens per second, over the chips. Copied from bench.py's
-``_transformer_train_flops`` (PERF.md, open questions: delete the
-original). 1 forward + 2 backward; recomputation, the optimizer,
-dropout, softmax and layer norm are not counted."""
+measured tokens per second, over the chips (the arithmetic of the
+repository's first bench.py, which went at PR 48; this file is the one
+copy). 1 forward + 2 backward; recomputation, the optimizer, dropout,
+softmax and layer norm are not counted."""
 
 
 def step_flops(batch, src_len, trg_len, vocab, n_layer, n_head, d_key,

@@ -180,6 +180,21 @@ def find_xplane(trace_dir):
     return found[-1] if found else None
 
 
+def chips_with_ops(path):
+    """The chips whose plane has an ``XLA Ops`` line with an event in
+    it, sorted: whether a trace holds device work at all, found without
+    reading its events into lists."""
+    from jax.profiler import ProfileData
+    found = []
+    for plane in ProfileData.from_file(path).planes:
+        dev = DEVICE_PLANE.match(plane.name)
+        if dev and any(line.name == OPS_LINE and
+                       next(iter(line.events), None) is not None
+                       for line in plane.lines):
+            found.append(int(dev.group(1)))
+    return sorted(found)
+
+
 def read_xplane(path):
     """``{'devices': {chip index: [ops events]}, 'host': [events],
     'lines': {plane name: {line name: event count}}}`` of one trace."""

@@ -81,46 +81,19 @@ def pair_resolves_and_moves_what_the_cell_reports(m, name, cell, resolved):
     assert 'bound' not in metric['entry']
 
 
-# The copies PR 42 could not take out: ``tests/test_latent_expanded_
-# prefill.py`` (outside the benchmark's paths, so not a benchmark PR's to
-# edit) pins ``serve.mla_prefill_expanded_chunk_share`` at index 84 of
-# ``per_layer`` with its cell alone, so 84 entries have to stand before
-# it. Each is a shared entry's reader and arguments over again and keeps
-# the one cell it had, which is therefore NOT on the shared entry's list:
-# a cell reports a value under one name. They go when that pin does, and
-# their cells join the shared lists then; none may be added.
-KEPT_COPIES = {'serve.latent_' + x for x in (
-    'decode_step_ms', 'queue_wait_ms', 'recompiles', 'kv_pool_used_pct',
-    'prefill_chunks_per_prompt', 'moe_local_assignment_pct',
-    'worker_prefill_share', 'worker_step_share', 'worker_idle_share',
-    'batch_occupancy', 'ttft_p90_ms', 'itl_p95_ms', 'tokens_per_s',
-    'moe_load_max_over_mean', 'attn_pages_read_share')} | {
-    'serve.mla_' + x for x in (
-        'moe_local_assignment_pct', 'moe_load_max_over_mean',
-        'prefill_chunk_ms', 'decode_step_ms', 'queue_wait_ms',
-        'worker_prefill_share', 'kv_pool_used_pct', 'recompiles')}
-
-
 def shape_one_entry_a_reader(m):
-    """No entry is another's copy under a second name, but the
-    ``KEPT_COPIES``: two entries whose data files are equal but for
-    their ``doc`` move different end-to-end metrics, which the contract
-    wants split (``train.recompiles`` / ``serve.recompiles``). A kept
-    copy and the entry it copies share no cell, so no cell's line holds
-    one value twice. An entry whose ``args`` carry one configuration's
-    shapes stays that configuration's: a data file is found by the
-    metric's name."""
+    """No entry is another's copy under a second name: two entries whose
+    data files are equal but for their ``doc`` move different end-to-end
+    metrics, which the contract wants split (``train.recompiles`` /
+    ``serve.recompiles``). An entry whose ``args`` carry one
+    configuration's shapes stays that configuration's: a data file is
+    found by the metric's name."""
     seen = {}
     for p in m['per_layer']:
         spec = manifest.read_json(os.path.join(
             m['_dir'], 'layer_metrics', p['name'] + '.json'))
         key = (json.dumps({k: v for k, v in spec.items() if k != 'doc'},
                           sort_keys=True), p['moves'])
-        if p['name'] in KEPT_COPIES:
-            (shared,) = [q for q in m['per_layer'] if seen.get(key) ==
-                         q['name']]
-            assert not set(p['workloads']) & set(shared['workloads'])
-            continue
         assert key not in seen, (p['name'], seen[key])
         seen[key] = p['name']
 
@@ -851,3 +824,145 @@ def test_main_hands_the_tail_snapshot_to_the_readers(
                        '--trace', '1', '--rehearsal'], root=root) == 0
     last = json.loads(capsys.readouterr().out.splitlines()[-1])
     assert last['metrics']['toy.order']['value'] == [1, 2, 3]
+
+
+# ------------------------------------------------ a traced tail that is lost
+def _chip_context(attempt, seconds=0.2):
+    """A traced Context as a run on the chip has it (no rehearsal), the
+    ``attempt`` as the supervisor hands it down (0: no supervisor)."""
+    import argparse
+    args = argparse.Namespace(seed=0, seconds=seconds, trace=1,
+                              rehearsal=False, attempt=attempt)
+    return bench.Context(manifest.resolve(MANIFEST, TRAIN_CELL), args, REPO)
+
+
+@pytest.mark.parametrize('attempt,gives_up', [(1, True), (2, False),
+                                              (0, False)],
+                         ids=['first', 'last', 'unsupervised'])
+@pytest.mark.parametrize('how', ['never_started', 'no_file',
+                                 'no_device_line'])
+def test_a_lost_trace_is_given_up_only_where_the_run_is_made_again(
+        monkeypatch, tmp_path, capsys, attempt, gives_up, how):
+    """The three ways a traced tail comes back without the device: the
+    runner's thread never reached ``tick()`` inside it, the profiler
+    wrote no file, the file has no ``XLA Ops`` line of the cell's chip.
+    The first of two supervised attempts leaves by ``TraceLost`` as the
+    window closes; the last, and a run with no supervisor, go on and
+    carry the reason."""
+    ctx = _chip_context(attempt)
+    ctx._trace_dir = str(tmp_path / 'trace')
+    if how != 'no_device_line':
+        _Profiler(monkeypatch)              # starts and stops nothing
+    ctx.begin_window()
+    if how != 'never_started':
+        ctx.t_window -= 0.15
+        ctx.tick()
+        import jax.numpy as jnp
+        jnp.ones((8, 8)).sum().block_until_ready()
+    if gives_up:
+        with pytest.raises(bench.TraceLost):
+            ctx.end_window()
+    else:
+        ctx.end_window()
+    want = {'never_started': 'stood still', 'no_file': 'wrote no trace',
+            'no_device_line': 'device ops of chips []'}[how]
+    assert want in ctx.trace_lost
+    said = [ln for ln in capsys.readouterr().out.splitlines()
+            if ln.startswith('TRACE_LOST ')]
+    assert len(said) == 1
+    assert json.loads(said[0][11:]) == {'why': ctx.trace_lost,
+                                       'may_retry': gives_up}
+    # what the window has to leave behind either way
+    assert ctx.compiles_in_window == 0 and ctx.memory is not None
+
+
+def test_a_trace_with_the_cells_chips_in_it_is_not_lost(monkeypatch, capsys):
+    _Profiler(monkeypatch)
+    monkeypatch.setattr(tracelib, 'find_xplane', lambda d: __file__)
+    monkeypatch.setattr(tracelib, 'chips_with_ops', lambda p: [0])
+    ctx = _chip_context(1)
+    ctx.begin_window()
+    ctx.t_window -= 0.15
+    ctx.tick()
+    ctx.end_window()
+    assert ctx.trace_lost is None
+    out = capsys.readouterr().out
+    assert 'TRACE_LOST' not in out
+    session = json.loads([ln for ln in out.splitlines()
+                          if ln.startswith('TRACE_SESSION ')][0][14:])
+    assert session['chips_with_ops'] == [0]
+    assert session['start_s'] >= 0 and session['stop_s'] >= 0
+
+
+class _Child(object):
+    def __init__(self, code, log, argv):
+        self.code = code
+        log.append(argv)
+
+    def wait(self):
+        return self.code
+
+    def poll(self):
+        return self.code
+
+
+@pytest.mark.parametrize('codes,runs,leaves', [
+    ([0], 1, 0), ([bench.TRACE_LOST, 0], 2, 0), ([2], 1, 2),
+    ([bench.TRACE_LOST, 1], 2, 1), ([-9], 1, 137),
+    ([bench.TRACE_LOST, bench.TRACE_LOST, 0], 2, bench.TRACE_LOST)],
+    ids=['kept', 'lost_once', 'no_chip', 'lost_then_failed', 'killed',
+         'never_a_third'])
+def test_supervise_makes_a_run_that_lost_its_trace_again_once(
+        monkeypatch, codes, runs, leaves):
+    import signal
+    monkeypatch.setattr(signal, 'signal', lambda *a: None)
+    spawned, left = [], list(codes)
+    argv = ['--workload', 'x.y', '--seed', '7', '--seconds', '51',
+            '--trace', '1']
+    got = bench.supervise(
+        argv, spawn=lambda cmd: _Child(left.pop(0), spawned, cmd))
+    assert got == leaves and len(spawned) == runs
+    for n, cmd in enumerate(spawned, 1):
+        assert cmd == [sys.executable, RUN] + argv + ['--attempt', str(n)]
+
+
+def test_a_traced_run_goes_through_a_child_that_finds_no_tpu():
+    """``--trace 1`` with no ``--rehearsal``: the parent starts no jax,
+    its child looks for the chip, finds a CPU and leaves with 2; no
+    second attempt, no result line. ``--attempt`` is not in the help."""
+    r = _run('--workload', TRAIN_CELL, '--seed', '1', '--seconds', '1',
+             '--trace', '1')
+    assert r.returncode == 2
+    assert r.stderr.count("found platform 'cpu'") == 1
+    assert '"metrics"' not in r.stdout and 'made again' not in r.stderr
+    assert '--attempt' not in _run('--help').stdout
+
+
+def test_chips_with_ops_of_a_cpu_trace_is_empty(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    jax.profiler.start_trace(str(tmp_path))
+    jnp.ones((64, 64)).sum().block_until_ready()
+    jax.profiler.stop_trace()
+    assert tracelib.chips_with_ops(tracelib.find_xplane(str(tmp_path))) == []
+
+
+def test_a_traced_tail_has_a_directory_of_its_own(monkeypatch):
+    """Two runs of one checkout at a time (the tests' workers) do not
+    share a trace directory: it is made as the tail begins, outside the
+    checkout, and gone once the trace is taken."""
+    _Profiler(monkeypatch)
+    dirs = []
+    for _ in range(2):
+        ctx = _context(1)
+        assert ctx._trace_dir is None and ctx.take_trace() is None
+        ctx.begin_window()
+        ctx.t_window -= 0.15
+        ctx.tick()
+        ctx.end_window()
+        assert not os.path.abspath(ctx._trace_dir).startswith(REPO + os.sep)
+        dirs.append(ctx._trace_dir)
+        os.makedirs(ctx._trace_dir)             # as the profiler would
+        assert ctx.take_trace() is None         # with no file in it
+        assert not os.path.exists(ctx._trace_dir)
+    assert dirs[0] != dirs[1]

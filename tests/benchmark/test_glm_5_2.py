@@ -68,12 +68,14 @@ SHARED_METRICS = {
     'serve.moe_local_assignment_pct', 'serve.moe_load_max_over_mean',
     'serve.prefill_chunks_per_prompt', 'serve.prefill_chunk_ms',
     'serve.attn_pages_read_share', 'serve.attn_pages_held_share',
-    'serve.moe_row_tiles_run_share', 'serve.steps_ahead_share'}
+    'serve.moe_row_tiles_run_share', 'serve.steps_ahead_share',
+    # the selection's two counters, which this cell's engine feeds as
+    # dots3_note's does
+    'serve.sparse_selected_share', 'serve.sparse_live_row_share'}
 # the mechanisms the configuration lacks, and the other configurations'
-# own entries and kept copies: left off
+# own entries: left off
 ABSENT = ('serve.window_', 'serve.prefix_', 'serve.ssm_', 'serve.indexer_',
-          'serve.mla_', 'serve.latent_', 'serve.gqa_', 'serve.scmoe_',
-          'serve.sparse_')
+          'serve.mla_', 'serve.latent_', 'serve.gqa_', 'serve.scmoe_')
 
 
 def _module(kind, name):
@@ -625,18 +627,18 @@ def test_counter_entries_read_the_programs_counters(resolved):
 
 @pytest.mark.parametrize('shared', ['serve.sparse_selected_share',
                                     'serve.sparse_live_row_share'])
-def test_the_selection_shares_stay_dots3_notes(shared):
+def test_the_selection_shares_list_this_cell_beside_dots3_notes(shared):
     """The engine feeds these two entries' counters in this cell too
-    (``decode.sparse_positions_*``, ``decode.sparse_rows*``), but
-    ``tests/benchmark/test_dots3_note.py`` holds their lists to
-    dots3_note's cells and ``test_benchmark.py`` allows no copy under a
-    second name: the cell is on neither until a benchmark PR opens them
-    (PERF.md section 7). ``serve.dsa_decode_attn_roofline_share`` carries
-    the selected bytes against the masked read meanwhile."""
+    (``decode.sparse_positions_*``, ``decode.sparse_rows*``), and
+    ``test_benchmark.py`` allows no copy under a second name: both cells
+    are on the one entry, which reads the program's own counters and
+    carries no configuration's shapes."""
     (entry,) = [e for e in MANIFEST['per_layer'] if e['name'] == shared]
-    assert CELL not in entry['workloads']
+    assert {CELL, 'dots3_note.long_ctx_steady'} <= set(entry['workloads'])
+    assert entry['moves'] == 'itl_mean_ms'
     spec = manifest.read_json(os.path.join(
         BENCH, 'layer_metrics', shared + '.json'))
+    assert spec['reader'] == 'registry_ratio'
     assert spec['args']['counter'].startswith('decode.sparse_')
 
 
@@ -658,7 +660,10 @@ def test_the_cell_rehearses_end_to_end_on_the_cpu():
     assert line['rehearsal'] is True and line['attempted'] >= 6
     metrics = line['metrics']
     assert metrics['serve.dsa_carried_selection_share']['value'] == 60.0
-    assert 'serve.sparse_selected_share' not in metrics
+    # every toy prompt is past the toy index_topk: every live row is
+    # sparse, and the selection keeps under all it holds
+    assert metrics['serve.sparse_live_row_share']['value'] == 100.0
+    assert 0 < metrics['serve.sparse_selected_share']['value'] < 100
     assert metrics['serve.recompiles']['value'] == 0
     # a time is never reported from a CPU
     assert metrics['serve.decode_step_ms']['value'] is None
