@@ -48,7 +48,10 @@ Mamba-2 layers keep a state a sequence in arenas indexed by a slot and
 not by pages, read and written where it lies by ops/ssm_ops.py;
 ``ShortcutMoEBlock`` in ops/shortcut_moe_ops.py: block='shortcut_moe',
 a layer of two latent attentions with cache rows of their own and an
-expert branch beside them):
+expert branch beside them; ``DeltaHybridBlock`` in
+ops/delta_hybrid_ops.py: block='delta_hybrid', linear-attention layers
+under the gated delta rule, whose state is a matrix a head in the same
+pool of slots, beside gated attention in pages):
 embedding, the q/k/v
 projections, what follows attention, the per-layer lower bound on the
 columns a row sees, and the logits. Everything else — placement, the
@@ -432,6 +435,9 @@ def _block_of(ctx):
     if kind == 'ssm_hybrid':
         from .ssm_hybrid_ops import SsmHybridBlock
         return SsmHybridBlock(ctx)
+    if kind == 'delta_hybrid':
+        from .delta_hybrid_ops import DeltaHybridBlock
+        return DeltaHybridBlock(ctx)
     return _PostLNBlock(ctx)
 
 

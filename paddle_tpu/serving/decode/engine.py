@@ -52,10 +52,13 @@ workload — over a decoder-only LM with a paged KV cache:
   fed a slot a row, ``_table_widths``) and the shortcut block (two
   latent attentions a layer, so a token keeps two cache layers a layer
   of the one kind, and a router a third of whose outputs are identity
-  experts) run through this same engine. For
-  the latter five speculation and quantized arenas raise rather than run
+  experts) and the delta hybrid (linear-attention layers under the gated
+  delta rule, whose state is a matrix a head in the same pool of slots,
+  beside gated attention in pages) run through this same engine. For
+  the latter six speculation and quantized arenas raise rather than run
   untested,
-  and for the latent, the shortcut and the grouped block the page handoff too. The prefix cache runs
+  and for the latent, the shortcut and the grouped block, and any that
+  keeps a state, the page handoff too. The prefix cache runs
   for a spec whose frozen pages another sequence may map
   (``LMSpec.shares_frozen_pages``: 'post_ln', and a latent block all
   of whose layers read every cached position); the others raise: their
@@ -1258,6 +1261,8 @@ class DecodeEngine(object):
             # in chunks of ``ssm_chunk`` rows of each program's bucket
             _obs.inc('decode.state_resets_total')
             _obs.inc('decode.prefill_scan_chunks_total', scan_chunks)
+            _obs.inc('decode.prefill_scan_rows_total',
+                     (s - cached) * self._state_layers)
             if seq.preemptions:
                 _obs.inc('decode.state_recomputed_tokens_total', s)
         with _obs.span('decode.prefill.emit'):

@@ -6,15 +6,16 @@ Scope, all sharing one parameter namespace (prefix ``lm_``):
 - **startup** — initializes the block's weights (``LMSpec.block``:
   'post_ln' — models.transformer._stacked_layer_params layout,
   ENC_SLOTS, causal self-attention + FFN + 2 LNs per layer, token
-  embedding, sinusoid position table, output projection; 'parallel_moe',
-  'latent_moe', 'gqa_moe' and 'ssm_hybrid' — ``block_param_shapes``, at
-  ``LMSpec.dtype``) and the zeroed arenas of the block's cache
+  embedding, sinusoid position table, output projection; every other
+  block — ``block_param_shapes``, at ``LMSpec.dtype``) and the zeroed
+  arenas of the block's cache
   kinds (``LMSpec.cache_kinds``: K and V ``[L, NB, bs, Hkv*d]``, a
   latent row and an index key per kind of layer, or K and V per kind of
   layer, each kind in a page pool of its own: ``LMSpec.page_pools``;
   and, for a kind whose size is a sequence's and not a token's, the
-  recurrent state and the convolution's last inputs of the state-space
-  layers, ``[L, slots + 1, ...]``: one slot a sequence, the last one a
+  recurrent state and the convolution's last inputs of the layers that
+  keep a state (Mamba-2, the gated delta rule), ``[L, slots + 1,
+  ...]``: one slot a sequence, the last one a
   spare that rows past the batch read and write). Arenas are persistable scope state: every
   prefill/decode run reads them from scope and writes them back
   through executor donation — in-place HBM updates, the same
@@ -68,6 +69,10 @@ CARRIED = 'carried_selection'
 # sees every position and carries none, or (where a layer is one
 # sublayer, ``LMSpec.mixer_only``) an expert layer, which caches nothing
 MAMBA, ATTENTION, MOE = 'mamba', 'attention', 'moe'
+# the other layer kind of block='delta_hybrid' beside full_attention: a
+# linear-attention layer under the gated delta rule, whose cache is a
+# matrix a head in one slot a sequence
+LINEAR = 'linear_attention'
 
 
 class CacheKind(collections.namedtuple(
@@ -283,7 +288,7 @@ class LatentShape(object):
 
 
 class LMSpec(object):
-    """Decoder-only LM hyperparameters: a family of six blocks.
+    """Decoder-only LM hyperparameters: a family of seven blocks.
 
     ``block='post_ln'`` (the default; every argument after ``d_inner``
     unused): the 2017 decoder block — embedding scaled by sqrt(d_model)
@@ -412,7 +417,38 @@ class LMSpec(object):
     cost one multiply by the sum of their weights and never enter the
     routed product. No shared expert; an untied head behind a final
     RMSNorm; ``lora_rescale`` as ``latent_moe``. The prefix cache,
-    speculation, quantized arenas and the page handoff are refused."""
+    speculation, quantized arenas and the page handoff are refused.
+
+    ``block='delta_hybrid'`` (qwen3_next): a serial pre-norm block under
+    the zero-centred norm ``RMSNorm0(x) = x / rms(x) (1 + w)``, ``h = x
+    + Mixer(RMSNorm0(x))``, ``y = h + MoE(RMSNorm0(h))``, the mixer by
+    layer kind (``layer_types``: 'linear_attention' / 'full_attention').
+    A **linear-attention layer** runs the gated delta rule
+    (``ops/gated_delta_ops.py``): ``ssm_groups`` key heads of
+    ``ssm_state`` for q and k and ``ssm_heads`` value heads of
+    ``ssm_head_dim`` for v and the gate z (value head ``h`` reads key
+    head ``h // (heads / groups)``), a causal depthwise convolution of
+    ``ssm_conv`` taps without a bias over ``[q; k; v]``, l2-normalised q
+    and k, a write strength ``sigmoid(b)`` and a decay ``exp(-exp(A_log)
+    softplus(a + dt_bias))`` a head, scan chunks of ``ssm_chunk`` rows
+    in prefill, a plain-gain RMSNorm over each head and then the gate
+    ``silu(z)``. It keeps per sequence a state ``[ssm_heads, ssm_state,
+    ssm_head_dim]`` in float32 and the convolution's last ``ssm_conv -
+    1`` inputs: cache kinds with a size a sequence, as ``ssm_hybrid``'s,
+    in the same pool of slots. A **full-attention layer** is ``n_head``
+    query heads over ``n_kv_head`` KV heads of ``d_key``, paged like
+    every other block's: a query and an output gate a head
+    (``sigmoid(gate)`` on the attention's result), q and k through
+    ``RMSNorm0`` over each head with gains of their own, the first
+    ``rotary_dim`` columns of a head rotated in half-split pairs by the
+    plain powers of ``rope_theta`` and the others not. Every layer has
+    the routed experts of ``gqa_moe`` (softmax router over ``n_experts``,
+    the ``experts_per_token`` largest normalised over those,
+    ``experts_held`` from ``first_expert`` computed here, width
+    ``d_inner``) plus one shared gated SiLU expert of ``d_inner_shared``
+    times ``sigmoid(n w_sg)``, a gate of its own; an untied head behind
+    a final ``RMSNorm0``. The prefix cache, speculation, quantized arenas
+    and the page handoff are refused (``refusal``)."""
 
     def __init__(self, vocab_size, n_layer=2, n_head=2, d_key=16,
                  d_value=16, d_model=32, d_inner=64, block='post_ln',
@@ -428,7 +464,7 @@ class LMSpec(object):
                  residual_scale=1.0, attn_scale=None, zero_experts=0,
                  indexer_types=None, index_rope_interleave=False,
                  ssm_groups=1, mixer_only=False, tie_embeddings=True,
-                 moe_latent=0, d_inner_shared=0):
+                 moe_latent=0, d_inner_shared=0, rotary_dim=0):
         self.vocab_size = int(vocab_size)
         self.n_layer = int(n_layer)
         self.n_head = int(n_head)
@@ -476,12 +512,16 @@ class LMSpec(object):
         self.tie_embeddings = bool(tie_embeddings)
         self.moe_latent = int(moe_latent)
         self.d_inner_shared = int(d_inner_shared)
+        self.rotary_dim = int(rotary_dim) or self.d_key
         self.embed_scale = float(embed_scale)
         self.residual_scale = float(residual_scale)
         self.attn_scale = float(attn_scale) if attn_scale \
             else self.d_key ** -0.5
         if self.block == 'ssm_hybrid':
             self._check_ssm()
+            return
+        if self.block == 'delta_hybrid':
+            self._check_delta()
             return
         if self.block == 'post_ln':
             if self.n_kv_head != self.n_head:
@@ -492,7 +532,8 @@ class LMSpec(object):
                               'shortcut_moe'):
             raise ValueError('LMSpec: unknown block %r (post_ln, '
                              'parallel_moe, latent_moe, gqa_moe, '
-                             'ssm_hybrid, shortcut_moe)' % self.block)
+                             'ssm_hybrid, shortcut_moe, delta_hybrid)'
+                             % self.block)
         if self.block in ('parallel_moe', 'gqa_moe') and (
                 self.n_head % self.n_kv_head or self.d_key != self.d_value):
             raise ValueError('LMSpec: %d query heads over %d KV heads of '
@@ -570,15 +611,53 @@ class LMSpec(object):
                    self.moe_latent, self.n_shared_experts,
                    self.d_inner_shared))
 
+    def _check_delta(self):
+        if len(self.layer_types) != self.n_layer or \
+                set(self.layer_types) - {LINEAR, FULL}:
+            raise ValueError('LMSpec: layer_types %r for %d layers (%s, %s)'
+                             % (self.layer_types, self.n_layer, LINEAR,
+                                FULL))
+        if self.n_head % self.n_kv_head or self.d_key != self.d_value or \
+                self.rotary_dim % 2 or not 0 < self.rotary_dim <= self.d_key:
+            raise ValueError(
+                'LMSpec: %d query heads over %d KV heads of %d/%d, %d '
+                'columns rotated' % (self.n_head, self.n_kv_head,
+                                     self.d_key, self.d_value,
+                                     self.rotary_dim))
+        if LINEAR in self.layer_types and (min(
+                self.ssm_heads, self.ssm_head_dim, self.ssm_state,
+                self.ssm_conv - 1, self.ssm_chunk, self.ssm_groups) < 1
+                or self.ssm_heads % self.ssm_groups):
+            raise ValueError(
+                'LMSpec: linear-attention layers of %d value heads of %d '
+                'over %d key heads of %d, %d taps, chunks of %d'
+                % (self.ssm_heads, self.ssm_head_dim, self.ssm_groups,
+                   self.ssm_state, self.ssm_conv, self.ssm_chunk))
+        if not (0 < self.experts_per_token <= self.n_experts and
+                0 < self.experts_held and
+                self.first_expert + self.experts_held <= self.n_experts
+                and self.n_shared_experts == 1 and not self.zero_experts
+                and not self.dense_layers
+                and min(self.d_inner, self.d_inner_shared) > 0):
+            raise ValueError(
+                'LMSpec: experts %d..%d of %d in every layer, %d per '
+                'token, width %d, %d shared of %d behind a gate'
+                % (self.first_expert,
+                   self.first_expert + self.experts_held - 1,
+                   self.n_experts, self.experts_per_token, self.d_inner,
+                   self.n_shared_experts, self.d_inner_shared))
+
     @property
     def ssm_inner(self):
-        """The width a Mamba-2 layer works at: heads x head width."""
+        """The width a layer that keeps a state works at: (value) heads
+        x head width."""
         return self.ssm_heads * self.ssm_head_dim
 
     @property
     def ssm_conv_width(self):
         """What the convolution runs over: x, and B and C of every
-        group."""
+        group (Mamba-2); v, and q and k of every key head (the delta
+        rule)."""
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     def _check_rope(self):
@@ -708,12 +787,13 @@ class LMSpec(object):
         carried selection keeps no index key, so the index arena may
         hold fewer layers than the latent arena it selects from."""
         every = tuple(range(self.n_layer))
-        if self.block == 'ssm_hybrid':
+        if self.block in ('ssm_hybrid', 'delta_hybrid'):
             # K and V of the attention layers in the pool that keeps
-            # every page; the Mamba layers' state and convolution rows
-            # in a pool whose unit is a sequence's slot
+            # every page; the state and convolution rows of the layers
+            # that keep a state in a pool whose unit is a sequence's slot
             out = []
-            held = self.layers_of(ATTENTION)
+            delta = self.block == 'delta_hybrid'
+            held = self.layers_of(FULL if delta else ATTENTION)
             if held:
                 reads = (0,) * len(held)
                 out += [CacheKind('lm_kcache', 'KCache', held,
@@ -721,17 +801,22 @@ class LMSpec(object):
                         CacheKind('lm_vcache', 'VCache', held,
                                   self.n_kv_head * self.d_value, reads,
                                   False)]
-            held = self.layers_of(MAMBA)
+            held = self.layers_of(LINEAR if delta else MAMBA)
             if held:
                 # the convolution's K - 1 rows lie end to end in one
                 # lane-dense row a slot: kept [K - 1, C] the v5e's
                 # compiler laid the slot axis inside the three rows and
                 # re-laid the arena around every program (compiled here
-                # for a described chip, PR 45)
-                state = (self.ssm_state, self.ssm_inner)
+                # for a described chip, PR 45). Mamba-2's state is
+                # state-major over every head's lanes; the delta rule's
+                # a [keys, values] matrix a value head, so that a tile
+                # of it is whole heads (ops/pallas/ssm_state_update.py)
+                state = (self.ssm_heads, self.ssm_state,
+                         self.ssm_head_dim) if delta \
+                    else (self.ssm_state, self.ssm_inner)
                 conv = ((self.ssm_conv - 1) * self.ssm_conv_width,)
                 out += [CacheKind('lm_ssm_state', 'SsmState', held,
-                                  state[0] * state[1], (), True, 'state',
+                                  int(np.prod(state)), (), True, 'state',
                                   per_seq=state, dtype='float32'),
                         CacheKind('lm_ssm_conv', 'SsmConv', held, conv[0],
                                   (), True, 'state', per_seq=conv,
@@ -839,11 +924,12 @@ class LMSpec(object):
         if self.keeps_state():
             return {
                 'prefix_cache': (
-                    'a state-space layer keeps one state a sequence, not '
-                    'rows a token: there is no page of it to map from a '
-                    'page boundary, and no checkpoint of it is kept there'),
+                    'a layer with a recurrent state keeps one state a '
+                    'sequence, not rows a token: there is no page of it '
+                    'to map from a page boundary, and no checkpoint of '
+                    'it is kept there'),
                 'speculation': (
-                    'a state-space layer\'s state is updated in place and '
+                    'a recurrent layer\'s state is updated in place and '
                     'cannot be rewound past a rejected draft'),
             }[what]
         return {'prefix_cache': 'its logits with shared pages are held to '
@@ -855,7 +941,8 @@ class LMSpec(object):
         """``windows()`` of the layers that attend at all, once for
         each time a layer attends (``sublayers``)."""
         return [w for w, t in zip(self.windows(), self.layer_types)
-                if t != MAMBA for _ in range(self.sublayers)]
+                if t not in (MAMBA, LINEAR)
+                for _ in range(self.sublayers)]
 
     def per_head_cache(self):
         """Whether a cached row is ``n_kv_head`` heads of K (or V)."""
@@ -1313,6 +1400,72 @@ def ssm_param_shapes(spec):
     return out
 
 
+def delta_param_shapes(spec):
+    """``moe_param_shapes`` of the delta_hybrid block. The two norms,
+    the router, the routed experts and the shared expert with its gate
+    are stacks over all layers; ``lm_attn_*`` over the full-attention
+    layers in order (``q`` and ``gate``, a query and an output gate a
+    head, kept as their transposes ``[n, heads x d, d_model]`` as
+    ``gqa_param_shapes`` keeps its query projection; ``q_ln`` and
+    ``k_ln`` the per-head norms' gains), ``lm_gdn_*`` over the
+    linear-attention layers: ``in`` projects to ``[q (G K); k (G K); v
+    (H V); z (H V)]`` (the published ``in_proj_qkvz`` interleaves the
+    four by key head: a layout of trained weights, the same matrix under
+    a permutation of its columns), ``ba`` to ``[b (H); a (H)]``,
+    ``conv`` the depthwise taps ``[taps, 2 G K + H V]`` (no bias),
+    ``dt.b`` and ``a_log`` a head each, ``norm`` the gated norm's plain
+    gain over a head's V and ``out`` the output projection. A
+    zero-centred gain is a fan-in of 0: a float32 vector that starts at
+    zero (the norm multiplies by 1 + it); the gated norm's plain gain a
+    fan-in of None (ones)."""
+    L, d, f = spec.n_layer, spec.d_model, spec.d_inner
+    q, kv = spec.n_head * spec.d_key, spec.n_kv_head * spec.d_key
+    e, sh = spec.experts_held, spec.d_inner_shared
+    out = collections.OrderedDict([
+        ('lm_emb', ([spec.vocab_size, d], d, 'Emb')),
+        ('lm_head.w', ([spec.vocab_size, d], d, 'Head')),
+        ('lm_final_ln.w', ([d], 0, 'FinalLN')),
+        ('lm_stack_ln1.w', ([L, d], 0, 'Ln1W')),
+        ('lm_stack_ln2.w', ([L, d], 0, 'Ln2W')),
+    ])
+    n = len(spec.layers_of(FULL))
+    if n:
+        out.update([
+            ('lm_attn_q.w', ([n, q, d], d, 'SlfQ')),
+            ('lm_attn_gate.w', ([n, q, d], d, 'SlfGate')),
+            ('lm_attn_k.w', ([n, d, kv], d, 'SlfK')),
+            ('lm_attn_v.w', ([n, d, kv], d, 'SlfV')),
+            ('lm_attn_o.w', ([n, q, d], q, 'SlfO')),
+            ('lm_attn_q_ln.w', ([n, spec.d_key], 0, 'SlfQLn')),
+            ('lm_attn_k_ln.w', ([n, spec.d_key], 0, 'SlfKLn')),
+        ])
+    n = len(spec.layers_of(LINEAR))
+    if n:
+        heads, inner, conv = spec.ssm_heads, spec.ssm_inner, \
+            spec.ssm_conv_width
+        out.update([
+            ('lm_gdn_in.w', ([n, d, conv + inner], d, 'GdnIn')),
+            ('lm_gdn_ba.w', ([n, d, 2 * heads], d, 'GdnBA')),
+            ('lm_gdn_conv.w', ([n, spec.ssm_conv, conv], spec.ssm_conv,
+                               'GdnConvW')),
+            ('lm_gdn_dt.b', ([n, heads], 0, 'GdnDtB')),
+            ('lm_gdn_a_log', ([n, heads], 0, 'GdnALog')),
+            ('lm_gdn_norm.w', ([n, spec.ssm_head_dim], None, 'GdnNorm')),
+            ('lm_gdn_out.w', ([n, inner, d], inner, 'GdnOut')),
+        ])
+    out.update([
+        ('lm_moe_router.w', ([L, d, spec.n_experts], d, 'Router')),
+        ('lm_moe_exp_gate.w', ([L, e, d, f], d, 'ExpGate')),
+        ('lm_moe_exp_up.w', ([L, e, d, f], d, 'ExpUp')),
+        ('lm_moe_exp_down.w', ([L, e, f, d], f, 'ExpDown')),
+        ('lm_moe_shr_gate.w', ([L, d, sh], d, 'ShrGate')),
+        ('lm_moe_shr_up.w', ([L, d, sh], d, 'ShrUp')),
+        ('lm_moe_shr_down.w', ([L, sh, d], sh, 'ShrDown')),
+        ('lm_moe_shr_sg.w', ([L, d], d, 'ShrSg')),
+    ])
+    return out
+
+
 def block_param_shapes(spec):
     """{name: (shape, fan-in, op input slot)} of a block's weights
     (every block but 'post_ln'): a fan-in of None is a norm's gain
@@ -1321,7 +1474,8 @@ def block_param_shapes(spec):
     return {'latent_moe': latent_param_shapes,
             'shortcut_moe': shortcut_param_shapes,
             'gqa_moe': gqa_param_shapes,
-            'ssm_hybrid': ssm_param_shapes}.get(spec.block,
+            'ssm_hybrid': ssm_param_shapes,
+            'delta_hybrid': delta_param_shapes}.get(spec.block,
                                                 moe_param_shapes)(spec)
 
 
@@ -1406,6 +1560,18 @@ def _block_attrs(spec, block_size):
             'top_k': spec.experts_per_token,
             'first_expert': spec.first_expert,
             'routed_scale': spec.routed_scale})
+    if spec.block == 'delta_hybrid':
+        lead, period, n_periods, tail = spec.layer_plan()
+        attrs.update({
+            'block': spec.block, 'norm_eps': spec.norm_eps,
+            'lead': list(lead), 'period': list(period),
+            'n_periods': n_periods, 'tail': list(tail),
+            'ssm_heads': spec.ssm_heads, 'ssm_state': spec.ssm_state,
+            'ssm_chunk': spec.ssm_chunk, 'ssm_groups': spec.ssm_groups,
+            'top_k': spec.experts_per_token,
+            'first_expert': spec.first_expert,
+            'rope_freq': [float(f) for f in yarn_frequencies(
+                spec.rotary_dim, spec.rope_theta)]})
     return attrs
 
 

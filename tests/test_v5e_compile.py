@@ -1037,3 +1037,68 @@ def test_the_one_sublayer_block_loads_at_its_published_geometry(one_chip):
             assert 'f32[64,8,128]' in line
     assert round(stated['weights_bytes'] / 1e9, 2) == 9.30
     assert round(stated['arena_bytes'] / 1e9, 2) == 2.59
+
+
+def test_the_delta_hybrid_block_loads_at_its_published_geometry(one_chip):
+    """qwen3_next as the benchmark runs it (every published width, layers
+    0-7 of 48 = two periods of three Gated-DeltaNet layers to a gated
+    attention layer, 128 of 512 experts, 1/4 vocabulary; 32 slots,
+    34,816 pages of 32, tables of 1,088 pages): the decode step and the
+    512 chunk compile for the v5e, the arenas donated, with the argument
+    bytes the configuration's ``geometry`` states (7.33 GB of weights +
+    4.99 GB of arenas = 12.3 GB) and the whole program under the chip's
+    15.75 GiB. By ``serving/decode/hlo_check.py`` no instruction outside
+    the entry computation materialises a layer of the state arena or of
+    the K/V arena, and every arena stays row-major. The decode step
+    holds one gdn_state_update kernel a linear-attention layer of a
+    period (the second body of the pipeline over live rows' slots: a row's decay,
+    write strength, q, k and v ``[32, 32, 128]`` among its operands) with
+    both state arenas aliased to its results, and no ssm_state_update
+    kernel; every program one moe_routed_product kernel a layer over the
+    three stacks of the gated expert, and heads of 256 go through the
+    paged attention every per-head block uses."""
+    from paddle_tpu.serving.decode.hlo_check import arena_sized_instructions
+    import json
+    import os
+    cell = 'qwen3_next.long_ctx_chat'
+    spec, geometry = cell_spec(cell)
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), 'benchmark', 'configs',
+            'qwen3_next.json')) as f:
+        stated = json.load(f)['geometry']
+    state, conv = 'f32[6,33,32,128,128]', 'bf16[6,33,24576]'
+    pages = 'bf16[2,34816,32,512]'
+    stacks = ('bf16[8,128,2048,512]', 'bf16[8,128,512,2048]')
+    for op, rows in (('paged_decode_step', 32), ('paged_prefill', 512)):
+        # the convolution rows' 33 slots are padded to whole tiles
+        hlo, weights_b, arena_b = _compiled_at_published_size(
+            one_chip, spec, geometry, op, rows, slack=4 << 20)
+        assert weights_b == stated['weights_bytes'], op
+        assert arena_b == stated['arena_bytes'], op
+        for arena, order in ((state, '4,3,2,1,0'), (conv, '2,1,0'),
+                             (pages, '3,2,1,0')):
+            assert set(re.findall(re.escape(arena) + r'\{([\d,]+)', hlo)) \
+                == {order}, (op, rows, arena)
+        for layer in (33 * 32 * 128 * 128, 34816 * 32 * 512):
+            # but for the whole stack of output projections (100 MB),
+            # which the compiler stages into fast memory in the loop
+            inner = [i for i in arena_sized_instructions(hlo, layer)
+                     if not i.computation.startswith('main')
+                     and not i.shape.startswith('bf16[6,4096,2048]')]
+            assert inner == [], (op, rows)
+        # a period's four layers are the body of one loop over the two
+        routed = _kernels(hlo, 'moe_routed_product')
+        assert len(routed) == 4, (op, rows)
+        for line in routed:
+            operands = line.split('operand_layout_constraints={', 1)[1]
+            assert all(stack in operands for stack in stacks)
+        assert not _kernels(hlo, 'ssm_state_update')
+        updates = _kernels(hlo, 'gdn_state_update')
+        assert len(updates) == (3 if op == 'paged_decode_step' else 0)
+        for line in updates:
+            alias = {int(out): int(at) for out, at in re.findall(
+                r'\{(\d+)\}: \((\d+), \{\}\)', line)}
+            assert sorted(alias) == [0, 2], line[:200]
+            assert line.count('f32[32,32,128]') >= 5
+    assert round(stated['weights_bytes'] / 1e9, 2) == 7.33
+    assert round(stated['arena_bytes'] / 1e9, 2) == 4.99
