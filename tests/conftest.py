@@ -16,11 +16,13 @@ def fresh_programs():
     yield
 
 
-# ---- fast/slow partition (VERDICT r4 next-#8: the full suite is ~20
-# min; `-m fast` is the <5-min gate for iterating). Slow = whole-model
-# e2e, mesh/multihost, amp sweeps, compiled-C clients; everything else
-# is fast by default so NEW test files land in the fast gate unless
-# explicitly listed here.
+# ---- fast/slow partition. The files named here are marked ``slow``
+# by name and every other file ``fast``, so a new test file is run
+# unless it is listed. The driver's tier-1 command deselects ``slow``
+# (``-m 'not slow'``, /root/TESTS_LAST_RUN.json) and no other tier runs
+# it: these fourteen files (whole-model e2e, mesh/multihost, amp sweeps,
+# compiled-C clients) run on no PR and nobody knows whether they pass
+# (ROADMAP.md, Design D22).
 import os as _os
 
 _SLOW_FILES = {

@@ -58,6 +58,15 @@ def _misses(snap):
                if k.startswith('executor.cache_miss_total'))
 
 
+@pytest.fixture(scope='module')
+def plain():
+    """One engine of the default arguments, built once and started, for
+    the two tests of a stream's own properties, which serve a few
+    requests through it one at a time and leave it drained."""
+    with _engine() as eng:
+        yield eng
+
+
 _SEQ_REF = {}
 
 
@@ -222,27 +231,21 @@ def test_preemption_requeue_preserves_streams():
     assert 'decode_preempt' in kinds
 
 
-def test_streaming_tokens_arrive_incrementally():
-    eng = _engine()
-    eng.start()
-    stream = eng.submit([5, 9, 2], max_new_tokens=8)
+def test_streaming_tokens_arrive_incrementally(plain):
+    stream = plain.submit([5, 9, 2], max_new_tokens=8)
     got = []
     for tok in stream:
         got.append(tok)
         assert isinstance(tok, int)
     assert got == stream.result()
     assert stream.done()
-    eng.shutdown()
 
 
-def test_sampled_streams_deterministic_per_seed():
-    eng = _engine()
-    eng.start()
+def test_sampled_streams_deterministic_per_seed(plain):
     kw = dict(max_new_tokens=8, temperature=0.9)
-    a = eng.generate([4, 4, 4], seed=11, **kw)
-    b = eng.generate([4, 4, 4], seed=11, **kw)
-    c = eng.generate([4, 4, 4], seed=12, **kw)
-    eng.shutdown()
+    a = plain.generate([4, 4, 4], seed=11, **kw)
+    b = plain.generate([4, 4, 4], seed=11, **kw)
+    c = plain.generate([4, 4, 4], seed=12, **kw)
     assert a == b
     assert a != c   # astronomically unlikely to collide over 8 tokens
 

@@ -27,12 +27,11 @@ import jax.numpy as jnp
 
 import paddle_tpu as fluid
 from paddle_tpu.models.reference import glm_5_2 as ref
-from paddle_tpu.ops import latent_moe_ops as lmo
-from paddle_tpu.ops import moe_held_ops as moe
-from paddle_tpu.ops import paged_decode_ops as pdo
 from paddle_tpu.serving.decode import DecodeEngine, LMSpec, random_weights
 from paddle_tpu.serving.decode import model as lm
-from util import as_held, platform_forms, weights_round_trip
+import block_harness
+from block_harness import Driver
+from util import platform_forms, weights_round_trip
 
 TOL = 5e-5
 BS, PAGES, NB = 4, 12, 40            # 48 positions a sequence
@@ -62,83 +61,11 @@ SPEC = _spec()
 WEIGHTS = random_weights(SPEC, seed=54)
 
 
-class _Op(object):
-    def __init__(self, slots):
-        self._slots = slots
-
-    def input(self, slot):
-        return self._slots[slot]
+DRIVER = Driver(SPEC, WEIGHTS, BS, NB, pages=PAGES)
 
 
-class _Ctx(object):
-    """What a paged op's lowering reads of its context, for driving the
-    block's row function without a Program."""
-
-    def __init__(self, spec, weights):
-        self._attrs = lm._block_attrs(spec, BS)
-        self.env = {}
-        slots = {}
-        held = as_held(spec, weights)
-        for name, (_, _, slot) in lm.block_param_shapes(spec).items():
-            self.env[name] = held[name]
-            slots[slot] = name
-        self.op = _Op(slots)
-
-    def attr(self, name, default=None):
-        return self._attrs.get(name, default)
-
-    def input(self, slot):
-        return self.env[self.op.input(slot)]
-
-
-def _block(spec=SPEC, weights=WEIGHTS):
-    return lmo.LatentMoEBlock(_Ctx(spec, weights))
-
-
-def _arenas(spec=SPEC):
-    return tuple(jnp.zeros((len(k.layers), NB, BS, k.stored), jnp.float32)
-                 for k in spec.cache_kinds())
-
-
-_JITTED = {}
-
-
-def _jitted(block, fn):
-    key = (id(block), fn.__name__)
-    if key not in _JITTED:
-        _JITTED[key] = (block, jax.jit(lambda *a: fn(block, *a)))
-    return _JITTED[key][1]
-
-
-def _chunk_rows(block, arenas, table, tokens, start):
-    s = tokens.shape[0]
-    pos = start + jnp.arange(s, dtype=jnp.int32)
-    place = pdo._page_runs(table, start, jnp.int32(s), s, NB, BS)
-    h, arenas, stats = pdo._extend_rows(
-        block, arenas, tokens, pos, table, place, valid=jnp.ones((s,), bool))
-    return block.logits(h), arenas, stats
-
-
-def _prefill_chunk(block, arenas, table, tokens, start):
-    return _jitted(block, _chunk_rows)(
-        arenas, table, jnp.asarray(tokens, jnp.int32), jnp.int32(start))
-
-
-def _step_rows(block, arenas, tables, tokens, lens):
-    place = pdo._single_rows(tables, lens, NB, BS)
-    h, arenas, stats = pdo._extend_rows(
-        block, arenas, tokens, lens, tables, place, valid=place.ok[:, 0])
-    return block.logits(h), arenas, stats
-
-
-def _decode(block, arenas, tables, tokens, lens):
-    return _jitted(block, _step_rows)(arenas, tables, tokens, lens)
-
-
-def _reference_logits(tokens, spec=SPEC, weights=WEIGHTS, **lowered):
-    return np.asarray(ref.logits(
-        weights, np.asarray(tokens, np.int32),
-        dict(ref.arch_of(spec), **lowered), ref.held_of(spec)))
+def _reference_logits(tokens, **lowered):
+    return DRIVER.reference_logits(ref, tokens, **lowered)
 
 
 # ------------------------------------------- (d) the plan, (c) the caches
@@ -160,7 +87,7 @@ def test_layer_plan_of_the_published_list_and_of_the_cut():
     assert kinds['lm_latent_full'].layers == tuple(range(78))
     assert kinds['lm_index_full'].layers == spec.scoring_layers()
     # a layer's place in each stack, as the block reads it off the plan
-    block = _block()
+    block = DRIVER.block()
     assert block.carries and block._places == (
         [0, 1, 2, 3, 4], [0, 1, 1, 1, 1])
 
@@ -178,7 +105,8 @@ def test_the_index_arena_holds_the_scoring_layers_only():
     assert lm.kv_bytes_per_kind(SPEC, 'bfloat16') == {
         'lm_latent_full': 160, 'lm_index_full': 32}
     assert lm.arena_bytes(SPEC, NB, BS) == per_token * 4 * BS * NB
-    assert [a.shape for a in _arenas()] == [(5, NB, BS, 16), (2, NB, BS, 8)]
+    assert [a.shape for a in DRIVER.arenas()] == [(5, NB, BS, 16),
+                                                  (2, NB, BS, 8)]
     # the published widths: 5 x 576 + 2 x 128 values a token, the latent
     # row stored in whole lane tiles (576 -> 640): 6,912 B in bfloat16
     big = _spec(latent={F: dict(n_head=64, q_rank=2048, kv_rank=512,
@@ -249,7 +177,7 @@ def test_a_spec_without_indexer_kinds_is_unchanged(kw):
     else:
         assert 'lm_index_full' not in kinds
         assert 'lm_full_idx_q.w' not in shapes
-    block = _block(spec, random_weights(spec, seed=1))
+    block = Driver(spec, random_weights(spec, seed=1), BS, NB).block()
     assert not block.carries and block._places is None
     if not spec.index_topk:
         return
@@ -273,15 +201,16 @@ def test_the_indexer_rotates_in_the_form_the_spec_states():
     dots3_note): index keys as cached, against the reference's."""
     rng = np.random.RandomState(3)
     tokens = rng.randint(0, SPEC.vocab_size, 9)
-    block, table = _block(), jnp.arange(PAGES, dtype=jnp.int32)
-    _, arenas, _ = _prefill_chunk(block, _arenas(), table, tokens, 0)
+    table = jnp.arange(PAGES, dtype=jnp.int32)
+    _, arenas, _ = DRIVER.prefill_chunk(DRIVER.arenas(), table, tokens, 0)
     x = jnp.take(jnp.asarray(WEIGHTS['lm_emb']), jnp.asarray(tokens), axis=0)
     n = ref.rms_norm(x, WEIGHTS['lm_stack_ln1.w'][0], SPEC.norm_eps)
     want = ref.sequence_keys(n, 0, WEIGHTS, 0, ref.arch_of(SPEC))[2]
     got = np.asarray(arenas[1])[0].reshape(NB * BS, -1)[:9]
     np.testing.assert_allclose(got, np.asarray(want), atol=1e-5)
-    other = _block(_spec(index_rope_interleave=False))
-    _, arenas, _ = _prefill_chunk(other, _arenas(), table, tokens, 0)
+    # a driver of its own: the spec in the other form
+    other = Driver(_spec(index_rope_interleave=False), WEIGHTS, BS, NB)
+    _, arenas, _ = other.prefill_chunk(other.arenas(), table, tokens, 0)
     moved = np.asarray(arenas[1])[0].reshape(NB * BS, -1)[:9] - got
     assert np.abs(moved[1:, :4]).max() > 1e-2 and not moved[:, 4:].any()
 
@@ -293,49 +222,14 @@ def test_shares_add_up_to_the_uncut_layer():
     routed scale: in the reference, and between the block's product and
     the reference. Attention, indexer and router are replicated: a
     share's are the uncut model's own arrays."""
-    whole = _spec(experts_held=8, first_expert=0)
-    w = random_weights(whole, seed=11)
-    rng = np.random.RandomState(1)
-    n = jnp.asarray(rng.randn(7, whole.d_model), jnp.float32)
-    arch = ref.arch_of(whole)
-    layer = 2
-    uncut = np.asarray(ref.experts(n, w, layer, arch, (0, 8)))
-
-    def cut(first):
-        out = dict(w)
-        for part in ('gate', 'up', 'down'):
-            name = 'lm_moe_exp_%s.w' % part
-            out[name] = w[name][:, first:first + 1]
-        return out
-
-    shared = np.asarray(ref.expert(
-        n, w['lm_moe_shr_gate.w'][layer, 0], w['lm_moe_shr_up.w'][layer, 0],
-        w['lm_moe_shr_down.w'][layer, 0]))
-    from_reference, from_block = shared.copy(), shared.copy()
-    for first in range(8):
-        share = cut(first)
-        from_reference += np.asarray(
-            ref.experts(n, share, layer, arch, (first, 1))) - shared
-        chosen, weight = moe.route_sigmoid_topk(
-            n, share['lm_moe_router.w'][layer], whole.experts_per_token,
-            bias=share['lm_moe_router.b'][layer], scale=whole.routed_scale)
-        gate, _ = moe.held_gates(chosen, weight, first, 1)
-        from_block += np.asarray(moe.gated_experts(
-            n, gate, *(jnp.asarray(share['lm_moe_exp_%s.w' % p][layer])
-                       for p in ('gate', 'up', 'down'))))
-    np.testing.assert_allclose(from_reference, uncut, atol=TOL)
-    np.testing.assert_allclose(from_block, uncut, atol=TOL)
-    assert np.abs(np.asarray(ref.experts(n, cut(0), layer, arch, (0, 1)))
+    n, w, arch, uncut, _, cut = block_harness.shares_of_one_expert_add_up(
+        ref, _spec, 2, TOL, scale=SPEC.routed_scale)
+    assert np.abs(np.asarray(ref.experts(n, cut(0), 2, arch, (0, 1)))
                   - uncut).max() > 1e-2
     # the chosen experts' weights sum to the routed scale
-    _, weight = ref.route(n, w['lm_moe_router.w'][layer],
-                          w['lm_moe_router.b'][layer], 3, 2.5)
+    _, weight = ref.route(n, w['lm_moe_router.w'][2],
+                          w['lm_moe_router.b'][2], 3, 2.5)
     np.testing.assert_allclose(np.asarray(weight).sum(1), 2.5, rtol=1e-6)
-    # everything else of a share is the uncut model's
-    held = lm.block_param_shapes(_spec(experts_held=1, first_expert=3))
-    full = lm.block_param_shapes(whole)
-    assert {k for k in full if full[k][0] != held[k][0]} == {
-        'lm_moe_exp_gate.w', 'lm_moe_exp_up.w', 'lm_moe_exp_down.w'}
 
 
 # --------------------------------- (a) prefill in chunks, then decode
@@ -347,26 +241,9 @@ def test_chunked_prefill_then_decode_matches_full_forward(prompt_len,
     chunks (or in one) through the two arenas, then decoded a token at a
     time, row by row against the reference's one full forward. Past
     position 8 the three shared layers attend over what layer 0 chose."""
-    rng = np.random.RandomState(prompt_len)
-    total = prompt_len + 12
-    tokens = rng.randint(0, SPEC.vocab_size, total)
-    want = _reference_logits(tokens)
-    block = _block()
-    arenas = _arenas()
-    table = jnp.asarray(rng.permutation(NB)[:PAGES], jnp.int32)
-    for start in range(0, prompt_len, chunk):
-        piece = tokens[start:min(start + chunk, prompt_len)]
-        got, arenas, stats = _prefill_chunk(block, arenas, table, piece,
-                                            start)
-        np.testing.assert_allclose(
-            np.asarray(got), want[start:start + len(piece)], atol=TOL)
+    for _, stats in block_harness.chunked_prefill_then_decode(
+            DRIVER, ref, prompt_len, chunk, 12, TOL):
         assert np.asarray(stats).shape == (4, 4)     # the routed layers
-    for t in range(prompt_len, total):
-        got, arenas, _ = _decode(
-            block, arenas, table[None, :],
-            jnp.asarray(tokens[t:t + 1], jnp.int32),
-            jnp.asarray([t], jnp.int32))
-        np.testing.assert_allclose(np.asarray(got)[0], want[t], atol=TOL)
 
 
 def test_the_published_order_with_leading_layers_periods_and_a_remainder():
@@ -380,16 +257,17 @@ def test_the_published_order_with_leading_layers_periods_and_a_remainder():
     spec = _spec(n_layer=13, dense_layers=3, indexer_types=types)
     assert spec.layer_plan() == ((F, F, F), (C, C, C, F), 2, (C, C))
     assert spec.scoring_layers() == (0, 1, 2, 6, 10)
-    w = random_weights(spec, seed=2)
+    # a driver of its own: another spec
+    deep = Driver(spec, random_weights(spec, seed=2), BS, NB)
     rng = np.random.RandomState(2)
     tokens = rng.randint(0, spec.vocab_size, 22)
-    want = _reference_logits(tokens, spec, w)
-    block, table = _block(spec, w), jnp.arange(PAGES, dtype=jnp.int32)
-    got, arenas, stats = _prefill_chunk(block, _arenas(spec), table,
-                                        tokens[:16], 0)
+    want = deep.reference_logits(ref, tokens)
+    table = jnp.arange(PAGES, dtype=jnp.int32)
+    got, arenas, stats = deep.prefill_chunk(deep.arenas(), table,
+                                            tokens[:16], 0)
     np.testing.assert_allclose(np.asarray(got), want[:16], atol=TOL)
     assert np.asarray(stats).shape == (10, 4)
-    got, arenas, _ = _prefill_chunk(block, arenas, table, tokens[16:], 16)
+    got, arenas, _ = deep.prefill_chunk(arenas, table, tokens[16:], 16)
     np.testing.assert_allclose(np.asarray(got), want[16:], atol=TOL)
 
 
@@ -399,12 +277,13 @@ def test_a_period_without_leading_layers_carries_from_its_own_first():
     spec = _spec(n_layer=4, dense_layers=0,
                  indexer_types=['full', 'shared'] * 2)
     assert spec.layer_plan() == ((), (F, C), 2, ())
-    w = random_weights(spec, seed=3)
+    # a driver of its own: another spec
+    bare = Driver(spec, random_weights(spec, seed=3), BS, NB)
     tokens = np.random.RandomState(5).randint(0, spec.vocab_size, 20)
-    got, _, _ = _prefill_chunk(_block(spec, w), _arenas(spec),
-                               jnp.arange(PAGES, dtype=jnp.int32), tokens, 0)
+    got, _, _ = bare.prefill_chunk(
+        bare.arenas(), jnp.arange(PAGES, dtype=jnp.int32), tokens, 0)
     np.testing.assert_allclose(np.asarray(got),
-                               _reference_logits(tokens, spec, w), atol=TOL)
+                               bare.reference_logits(ref, tokens), atol=TOL)
 
 
 @pytest.mark.parametrize('lengths,forms', [
@@ -418,28 +297,16 @@ def test_decode_batch_of_mixed_lengths_matches_reference(
     the forms a TPU's programs take (the selection's, the attention's
     and the routed product's kernels, interpreted here)."""
     platform_forms(monkeypatch, forms)
+    # the forms a TPU's programs take are traced by a driver of their
+    # own: the module's holds the programs traced in this platform's
+    driver = DRIVER if forms == 'default' else \
+        Driver(SPEC, WEIGHTS, BS, NB, pages=PAGES)
     rng = np.random.RandomState(7)
-    seqs = {i: rng.randint(0, SPEC.vocab_size, n + 1)
-            for i, n in enumerate(lengths) if n}
-    block = _block()
-    arenas = _arenas()
-    pages = rng.permutation(NB)
-    tables = np.full((len(lengths), PAGES), NB, np.int32)
-    used = 0
-    for i, seq in seqs.items():
-        need = -(-len(seq) // BS)
-        tables[i, :need] = pages[used:used + need]
-        used += need
-        _, arenas, _ = _prefill_chunk(block, arenas, jnp.asarray(tables[i]),
-                                      seq[:-1], 0)
-    got, arenas, stats = _decode(
-        block, arenas, jnp.asarray(tables),
-        jnp.asarray([seqs[i][-1] if n else 0
-                     for i, n in enumerate(lengths)], jnp.int32),
-        jnp.asarray(lengths, jnp.int32))
-    for i, seq in seqs.items():
-        np.testing.assert_allclose(
-            np.asarray(got)[i], _reference_logits(seq)[-1], atol=TOL)
+    seqs = [rng.randint(0, SPEC.vocab_size, n + 1) if n else None
+            for n in lengths]
+    _, stats = block_harness.decode_batch_of_mixed_lengths(
+        driver, ref, seqs, driver.packed_tables(rng.permutation(NB), seqs),
+        TOL)
     assert np.asarray(stats).shape == (4, 4)
 
 
@@ -478,7 +345,7 @@ def test_the_tolerance_catches_a_wrong_block(broken):
     tokens = rng.randint(0, SPEC.vocab_size, 24)
     table = jnp.arange(PAGES, dtype=jnp.int32)
     if broken == 'all_positions':
-        got, _, _ = _prefill_chunk(_block(), _arenas(), table, tokens, 0)
+        got, _, _ = DRIVER.prefill_chunk(DRIVER.arenas(), table, tokens, 0)
         want = _reference_logits(tokens, select=False)
     else:
         over = {'no_carry': dict(indexer_types=['full'] * 5),
@@ -493,8 +360,9 @@ def test_the_tolerance_catches_a_wrong_block(broken):
             for name in WEIGHTS:
                 if name.startswith('lm_full_idx_'):
                     weights[name] = WEIGHTS[name][[0, 0, 0, 0, 1]]
-        got, _, _ = _prefill_chunk(_block(spec, weights), _arenas(spec),
-                                   table, tokens, 0)
+        # a driver of its own: the broken spec
+        wrong = Driver(spec, weights, BS, NB)
+        got, _, _ = wrong.prefill_chunk(wrong.arenas(), table, tokens, 0)
         want = _reference_logits(tokens)
         if broken == 'no_carry':
             # and that is the reference with its carry off, to rounding
@@ -692,15 +560,9 @@ def test_programs_write_every_arena_in_place_and_keep_the_selection():
     instruction of the compiled program materialises a layer of either
     arena, and the selection is no output: it never leaves the
     device's program."""
-    from paddle_tpu.serving.decode.hlo_check import arena_sized_instructions
-    pool = 2048
-    eng = _engine(num_blocks=pool)
+    eng = _engine(num_blocks=2048)
     try:
-        smallest = min(pool * BS * k.width for k in SPEC.cache_kinds())
-        for which in ('decode', 8):
-            traced = eng.trace_program(which)
-            hlo = traced.lower().compile().as_text()
-            assert arena_sized_instructions(hlo, smallest) == []
+        for traced in block_harness.programs_write_arenas_in_place(eng):
             outs = jax.tree_util.tree_leaves(traced.out_info)
             assert not any(o.dtype == jnp.bool_ for o in outs)
     finally:

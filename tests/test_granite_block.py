@@ -22,16 +22,15 @@ import jax.numpy as jnp
 
 from paddle_tpu import observe
 from paddle_tpu.models.reference import granite_4_0_h_micro as ref
-from paddle_tpu.ops import paged_decode_ops as pdo
-from paddle_tpu.ops import ssm_hybrid_ops as sho
 from paddle_tpu.ops import ssm_ops
 from paddle_tpu.serving.decode import DecodeEngine, LMSpec, random_weights
 from paddle_tpu.serving.decode import model as lm
 from paddle_tpu.serving.decode.kv_pool import KVPool
 from paddle_tpu.serving.decode.scheduler import Scheduler, Sequence
+import block_harness
+from block_harness import BS, NB, PAGES, SLOTS, Driver, tokens as _tokens
 
 TOL = 5e-5
-BS, PAGES, NB, SLOTS = 4, 24, 64, 4      # 96 positions a sequence
 CHUNK = 16                               # the engine's prefill chunk
 M, A = lm.MAMBA, lm.ATTENTION
 
@@ -59,107 +58,11 @@ SPEC = _spec()
 WEIGHTS = random_weights(SPEC, seed=11)
 
 
-class _Op(object):
-    def __init__(self, slots):
-        self._slots = slots
-
-    def input(self, slot):
-        return self._slots[slot]
-
-
-class _Ctx(object):
-    """What a paged op's lowering reads of its context, for driving the
-    block's row function without a Program; ``feeds``: the op inputs
-    the block reads itself (the slot a row; a prefill's cached span)."""
-
-    def __init__(self, spec, weights, feeds):
-        self._attrs = lm._block_attrs(spec, BS)
-        self._feeds = feeds
-        self.env = {}
-        slots = {}
-        for name, (_, _, slot) in lm.block_param_shapes(spec).items():
-            self.env[name] = jnp.asarray(weights[name])
-            slots[slot] = name
-        self.op = _Op(slots)
-
-    def attr(self, name, default=None):
-        return self._attrs.get(name, default)
-
-    def has_input(self, slot):
-        return slot in self._feeds
-
-    def input(self, slot):
-        if slot in self._feeds:
-            return self._feeds[slot]
-        return self.env[self.op.input(slot)]
-
-
-def _arenas(spec=SPEC, slots=SLOTS):
-    return tuple(
-        jnp.zeros((len(k.layers), (slots + 1) if k.per_seq else NB)
-                  + tuple(k.unit_shape(BS)), jnp.float32)
-        for k in spec.cache_kinds())
-
-
-@jax.jit
-def _chunk(arenas, table, slot, tokens, start, length):
-    """A prefill chunk as ``paged_prefill`` runs it: the logits of every
-    row and the arenas it leaves."""
-    block = sho.SsmHybridBlock(_Ctx(SPEC, WEIGHTS, {
-        'BlockTableState': slot, 'Cached': start}))
-    rows = tokens.shape[0]
-    pos = start + jnp.arange(rows, dtype=jnp.int32)
-    place = pdo._page_runs(table, start, length, rows, NB, BS)
-    h, arenas, _ = pdo._extend_rows(block, arenas, tokens, pos, table, place,
-                                    valid=jnp.arange(rows) < length)
-    return block.logits(h), arenas
-
-
-def _step_rows(arenas, tables, slots, tokens, lens):
-    """A decode step as ``paged_decode_step`` runs it."""
-    block = sho.SsmHybridBlock(_Ctx(SPEC, WEIGHTS, {
-        'BlockTablesState': slots}))
-    place = pdo._single_rows(tables, lens, NB, BS)
-    h, arenas, _ = pdo._extend_rows(block, arenas, tokens, lens, tables,
-                                    place, valid=place.ok[:, 0])
-    return block.logits(h), arenas
-
-
-_step = jax.jit(_step_rows)
+DRIVER = Driver(SPEC, WEIGHTS, BS, NB, slots=SLOTS, pages=PAGES)
 
 
 def _reference_logits(tokens, **lowered):
-    w = {k: jnp.asarray(v) for k, v in WEIGHTS.items()}
-    return np.asarray(ref.logits(w, np.asarray(tokens, np.int32),
-                                 dict(ref.arch_of(SPEC), **lowered)))
-
-
-def _tokens(n, seed=0):
-    return np.random.RandomState(seed).randint(0, 64, n).astype(np.int32)
-
-
-def _table(first, n_tokens):
-    """A block table whose pages start at page ``first``."""
-    row = np.full((PAGES,), NB, np.int32)
-    pages = -(-n_tokens // BS)
-    row[:pages] = first + np.arange(pages)
-    return jnp.asarray(row)
-
-
-def _prefill(arenas, table, slot, tokens, pieces):
-    """``tokens`` prefilled in chunks of the given lengths, each padded
-    to the next power of two of at least 4: [(logits of its valid rows)],
-    arenas."""
-    out, start = [], 0
-    for n in pieces:
-        bucket = max(4, 1 << (n - 1).bit_length())
-        ids = np.zeros((bucket,), np.int32)
-        ids[:n] = tokens[start:start + n]
-        lg, arenas = _chunk(arenas, table, jnp.asarray([slot], jnp.int32),
-                            jnp.asarray(ids), jnp.int32(start), jnp.int32(n))
-        out.append(np.asarray(lg)[:n])
-        start += n
-    return np.concatenate(out), arenas
+    return DRIVER.reference_logits(ref, tokens, **lowered)
 
 
 # ------------------------------------------------------- the cache's terms
@@ -440,7 +343,8 @@ def test_the_decode_update_steps_each_live_row_s_own_slot(form, case,
 # ------------------------------------------- the block against the reference
 def test_a_whole_prompt_prefill_matches_the_full_forward():
     tokens = _tokens(16, 1)
-    got, _ = _prefill(_arenas(), _table(0, 16), 1, tokens, [16])
+    got, _, _ = DRIVER.prefill(DRIVER.arenas(), DRIVER.table(0, 16), tokens,
+                               [16], slot=1)
     np.testing.assert_allclose(got, _reference_logits(tokens), atol=TOL)
 
 
@@ -452,7 +356,8 @@ def test_a_whole_prompt_prefill_matches_the_full_forward():
 ])
 def test_prefill_in_chunks_matches_the_full_forward(pieces):
     tokens = _tokens(sum(pieces), 2)
-    got, _ = _prefill(_arenas(), _table(3, len(tokens)), 2, tokens, pieces)
+    got, _, _ = DRIVER.prefill(DRIVER.arenas(), DRIVER.table(3, len(tokens)),
+                               tokens, pieces, slot=2)
     np.testing.assert_allclose(got, _reference_logits(tokens), atol=TOL)
 
 
@@ -464,38 +369,13 @@ def test_prefill_then_decode_through_the_cache_matches_the_full_forward(
     rows change places (the engine compacts its batch every step: a row
     index is no home for state, the slot is). With the state update in
     either form."""
-    _step = globals()['_step']
+    stepper = None
     if form == 'the kernel':
         request.getfixturevalue('in_a_kernel')
-        _step = jax.jit(_step_rows)     # traced again, with the kernel
-    seqs = [_tokens(n, 10 + n) for n in (29, 42, 22)]
-    prompts = (17, 30, 9)
-    slots, firsts = (2, 0, 3), (0, 12, 30)
-    arenas = _arenas()
-    tables = [_table(f, len(s)) for f, s in zip(firsts, seqs)]
-    for seq, p, slot, table in zip(seqs, prompts, slots, tables):
-        _, arenas = _prefill(arenas, table, slot, seq[:p],
-                             [CHUNK] * (p // CHUNK) + [p % CHUNK])
-    want = [_reference_logits(s) for s in seqs]
-    order = [0, 1, 2]
-    for step in range(12):
-        if step % 3 == 2:
-            order = order[1:] + order[:1]       # rows move, slots stay
-        rows = [i for i in order if prompts[i] + step < len(seqs[i])]
-        pad = 4 - len(rows)
-        lens = [prompts[i] + step for i in rows]
-        lg, arenas = _step(
-            arenas,
-            jnp.stack([tables[i] for i in rows]
-                      + [jnp.full((PAGES,), NB, jnp.int32)] * pad),
-            jnp.asarray([slots[i] for i in rows] + [SLOTS] * pad,
-                        jnp.int32),
-            jnp.asarray([seqs[i][n] for i, n in zip(rows, lens)]
-                        + [0] * pad, jnp.int32),
-            jnp.asarray(lens + [0] * pad, jnp.int32))
-        for r, (i, n) in enumerate(zip(rows, lens)):
-            np.testing.assert_allclose(np.asarray(lg)[r], want[i][n],
-                                       atol=TOL)
+        # a driver of its own: the step traced again, with the kernel
+        stepper = Driver(SPEC, WEIGHTS, BS, NB, slots=SLOTS, pages=PAGES)
+    block_harness.prefill_then_decode_through_the_cache(
+        DRIVER, ref, 12, CHUNK, TOL, stepper)
 
 
 @pytest.mark.parametrize('lowered,what', [

@@ -50,6 +50,15 @@ def _engine(**kw):
                         place=fluid.CPUPlace(), **kw)
 
 
+@pytest.fixture(scope='module')
+def never_started():
+    """One engine, built once, for the cases that only build a step's
+    feeds from a batch made by hand."""
+    eng = _engine()
+    yield eng
+    eng.shutdown(drain=False)
+
+
 def _serve(prompts=PROMPTS, new=5, **kw):
     with _engine(**kw) as eng:
         streams = [eng.submit(p, max_new_tokens=new) for p in prompts]
@@ -407,26 +416,24 @@ def test_programs_and_ops_carry_names_a_trace_can_be_searched_by():
     ((5, 9, 2), 1, 0, 0, True),           # observe off: nothing counted
 ])
 def test_attn_pages_of_a_hand_built_batch(lengths, per_row, read, held,
-                                          off_counts):
+                                          off_counts, never_started):
     """``decode.attn_pages_read`` is what the step's pairs of (row,
     column block) gather, eight an iteration, and ``_held`` what they
     hold, summed over the layers, beside the pages its tables can
     address."""
     if not off_counts:
         observe.enable()
-    eng = _engine()
     batch = []
     for i, length in enumerate(lengths):
         seq = Sequence(i + 1, [7] * length, 4, 0.0, i, None)
         seq.cache_len = length
         batch.append(seq)
-    eng._step_feeds(batch, per_row)
+    never_started._step_feeds(batch, per_row)
     counters = observe.snapshot()['counters']
     assert counters.get('decode.attn_pages_read', 0) == read
     assert counters.get('decode.attn_pages_held', 0) == held
     assert counters.get('decode.attn_pages_reachable', 0) == \
         (0 if off_counts else 2 * 4 * per_row * 4)
-    eng.shutdown(drain=False)
 
 
 def test_live_tokens_of_a_hand_built_batch():

@@ -21,16 +21,14 @@ from 0 gives (checked below by breaking each)."""
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 from paddle_tpu.models.reference import kimi_k2_6 as ref
-from paddle_tpu.ops import latent_moe_ops as lmo
-from paddle_tpu.ops import moe_held_ops as moe
-from paddle_tpu.ops import paged_decode_ops as pdo
 from paddle_tpu.serving.decode import DecodeEngine, LMSpec, random_weights
 from paddle_tpu.serving.decode import model as lm
-from util import as_held, weights_round_trip
+import block_harness
+from block_harness import Driver
+from util import weights_round_trip
 
 TOL = 5e-5
 BS, PAGES, NB = 4, 16, 64            # 64 positions a sequence
@@ -61,91 +59,11 @@ SPEC = _spec()
 WEIGHTS = random_weights(SPEC, seed=7)
 
 
-class _Op(object):
-    def __init__(self, slots):
-        self._slots = slots
-
-    def input(self, slot):
-        return self._slots[slot]
+DRIVER = Driver(SPEC, WEIGHTS, BS, NB, pages=PAGES)
 
 
-class _Ctx(object):
-    """What a paged op's lowering reads of its context, for driving the
-    block's row function without a Program."""
-
-    def __init__(self, spec, weights):
-        self._attrs = lm._block_attrs(spec, BS)
-        self.env = {}
-        slots = {}
-        # an op reads a weight as the programs hold it
-        held = as_held(spec, weights)
-        for name, (_, _, slot) in lm.block_param_shapes(spec).items():
-            self.env[name] = held[name]
-            slots[slot] = name
-        self.op = _Op(slots)
-
-    def attr(self, name, default=None):
-        return self._attrs.get(name, default)
-
-    def input(self, slot):
-        return self.env[self.op.input(slot)]
-
-
-_BLOCK = {}
-
-
-def _block(spec=SPEC, weights=WEIGHTS):
-    if id(spec) not in _BLOCK:
-        _BLOCK[id(spec)] = lmo.LatentMoEBlock(_Ctx(spec, weights))
-    return _BLOCK[id(spec)]
-
-
-def _arenas(spec=SPEC):
-    return tuple(jnp.zeros((len(k.layers), NB, BS, k.stored), jnp.float32)
-                 for k in spec.cache_kinds())
-
-
-_JITTED = {}
-
-
-def _jitted(block, fn):
-    key = (id(block), fn.__name__)
-    if key not in _JITTED:
-        _JITTED[key] = (block, jax.jit(lambda *a: fn(block, *a)))
-    return _JITTED[key][1]
-
-
-def _chunk_rows(block, arenas, table, tokens, start):
-    s = tokens.shape[0]
-    pos = start + jnp.arange(s, dtype=jnp.int32)
-    place = pdo._page_runs(table, start, jnp.int32(s), s, NB, BS)
-    h, arenas, stats = pdo._extend_rows(
-        block, arenas, tokens, pos, table, place, valid=jnp.ones((s,), bool))
-    return block.logits(h), arenas, stats
-
-
-def _prefill_chunk(block, arenas, table, tokens, start):
-    """One chunk of one sequence through the one-table path, as the
-    paged_prefill op runs it, from any offset: logits of every row."""
-    return _jitted(block, _chunk_rows)(
-        arenas, table, jnp.asarray(tokens, jnp.int32), jnp.int32(start))
-
-
-def _step_rows(block, arenas, tables, tokens, lens):
-    place = pdo._single_rows(tables, lens, NB, BS)
-    h, arenas, stats = pdo._extend_rows(
-        block, arenas, tokens, lens, tables, place, valid=place.ok[:, 0])
-    return block.logits(h), arenas, stats
-
-
-def _decode(block, arenas, tables, tokens, lens):
-    return _jitted(block, _step_rows)(arenas, tables, tokens, lens)
-
-
-def _reference_logits(tokens, spec=SPEC, weights=WEIGHTS, **lowered):
-    return np.asarray(ref.logits(
-        weights, np.asarray(tokens, np.int32),
-        dict(ref.arch_of(spec), **lowered), ref.held_of(spec)))
+def _reference_logits(tokens, **lowered):
+    return DRIVER.reference_logits(ref, tokens, **lowered)
 
 
 # --------------------------------------------------- spec and positions
@@ -157,7 +75,7 @@ def test_a_dense_full_layer_keeps_one_kind_and_no_indexer():
     names = set(lm.block_param_shapes(SPEC))
     assert not {n for n in names if 'idx' in n or 'gate.w' in n
                 and 'full' in n}
-    assert _block().arena_slots == ('LatentFull',)
+    assert DRIVER.block().arena_slots == ('LatentFull',)
     # the published widths: 6 layers x 576 values, stored 640
     big = _spec(n_layer=6, layer_types=[F] * 6, latent={F: dict(
         n_head=64, q_rank=1536, kv_rank=512, d_nope=128, d_rope=64,
@@ -221,25 +139,9 @@ def test_chunked_prefill_then_decode_matches_full_forward(prompt_len,
     original length (16) at this scale, prefilled through the one arena
     and decoded a token at a time, row by row against the reference's
     one full forward."""
-    rng = np.random.RandomState(prompt_len)
-    total = prompt_len + 10
-    tokens = rng.randint(0, SPEC.vocab_size, total)
-    want = _reference_logits(tokens)
-    block, arenas = _block(), _arenas()
-    table = jnp.asarray(rng.permutation(NB)[:PAGES], jnp.int32)
-    for start in range(0, prompt_len, chunk):
-        piece = tokens[start:min(start + chunk, prompt_len)]
-        got, arenas, stats = _prefill_chunk(block, arenas, table, piece,
-                                            start)
-        np.testing.assert_allclose(
-            np.asarray(got), want[start:start + len(piece)], atol=TOL)
+    for _, stats in block_harness.chunked_prefill_then_decode(
+            DRIVER, ref, prompt_len, chunk, 10, TOL):
         assert np.asarray(stats).shape == (3, 4)     # the routed layers
-    for t in range(prompt_len, total):
-        got, arenas, _ = _decode(
-            block, arenas, table[None, :],
-            jnp.asarray(tokens[t:t + 1], jnp.int32),
-            jnp.asarray([t], jnp.int32))
-        np.testing.assert_allclose(np.asarray(got)[0], want[t], atol=TOL)
 
 
 def test_a_suffix_after_shared_pages_is_the_whole_prompts_logits():
@@ -251,26 +153,24 @@ def test_a_suffix_after_shared_pages_is_the_whole_prompts_logits():
     head = rng.randint(0, SPEC.vocab_size, 24)            # 6 whole pages
     first = np.concatenate([head, rng.randint(0, SPEC.vocab_size, 7)])
     second = np.concatenate([head, rng.randint(0, SPEC.vocab_size, 9)])
-    block, arenas = _block(), _arenas()
+    arenas = DRIVER.arenas()
     pages = rng.permutation(NB)
     table_a = jnp.asarray(pages[:PAGES], jnp.int32)
-    _, arenas, _ = _prefill_chunk(block, arenas, table_a, first, 0)
+    _, arenas, _ = DRIVER.prefill_chunk(arenas, table_a, first, 0)
     # the head's six pages shared, the rest its own
     table_b = jnp.asarray(np.concatenate(
         [pages[:6], pages[PAGES:2 * PAGES - 6]]), jnp.int32)
-    got, arenas, _ = _prefill_chunk(block, arenas, table_b, second[24:], 24)
+    got, arenas, _ = DRIVER.prefill_chunk(arenas, table_b, second[24:], 24)
     want = _reference_logits(second)
     np.testing.assert_allclose(np.asarray(got), want[24:], atol=TOL)
-    whole, _, _ = _prefill_chunk(
-        block, _arenas(), jnp.asarray(pages[2 * PAGES:3 * PAGES], jnp.int32),
+    whole, _, _ = DRIVER.prefill_chunk(
+        DRIVER.arenas(), jnp.asarray(pages[2 * PAGES:3 * PAGES], jnp.int32),
         second, 0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(whole)[24:],
                                atol=TOL)
     # and the first sequence still decodes as the reference says
     nxt = int(np.argmax(_reference_logits(first)[-1]))
-    step, _, _ = _decode(block, arenas, table_a[None, :],
-                         jnp.asarray([nxt], jnp.int32),
-                         jnp.asarray([len(first)], jnp.int32))
+    step, _, _ = DRIVER.decode(arenas, table_a[None, :], [nxt], [len(first)])
     np.testing.assert_allclose(
         np.asarray(step)[0],
         _reference_logits(np.concatenate([first, [nxt]]))[-1], atol=TOL)
@@ -278,23 +178,10 @@ def test_a_suffix_after_shared_pages_is_the_whole_prompts_logits():
 
 def test_decode_batch_of_mixed_lengths_matches_reference():
     rng = np.random.RandomState(9)
-    lens = [5, 18, 33]
-    block, arenas = _block(), _arenas()
     pages = rng.permutation(NB)
-    tables, seqs = [], []
-    for i, n in enumerate(lens):
-        seq = rng.randint(0, SPEC.vocab_size, n + 1)
-        table = jnp.asarray(pages[i * PAGES:(i + 1) * PAGES], jnp.int32)
-        _, arenas, _ = _prefill_chunk(block, arenas, table, seq[:n], 0)
-        tables.append(table)
-        seqs.append(seq)
-    got, _, _ = _decode(
-        block, arenas, jnp.stack(tables),
-        jnp.asarray([s[-1] for s in seqs], jnp.int32),
-        jnp.asarray(lens, jnp.int32))
-    for row, seq in zip(np.asarray(got), seqs):
-        np.testing.assert_allclose(row, _reference_logits(seq)[-1],
-                                   atol=TOL)
+    seqs = [rng.randint(0, SPEC.vocab_size, n + 1) for n in (5, 18, 33)]
+    block_harness.decode_batch_of_mixed_lengths(
+        DRIVER, ref, seqs, pages[:3 * PAGES].reshape(3, PAGES), TOL)
 
 
 @pytest.mark.parametrize('broken', ['yarn', 'softmax_mscale',
@@ -319,47 +206,13 @@ def test_shares_add_up_to_the_uncut_layer():
     the uncut layer's FFN: in the reference, and between the block's
     product and the reference. Attention and router are replicated: a
     share's are the uncut model's own arrays."""
-    whole = _spec(experts_held=8, first_expert=0)
-    w = random_weights(whole, seed=11)
-    n = jnp.asarray(np.random.RandomState(1).randn(7, whole.d_model),
-                    jnp.float32)
-    arch = ref.arch_of(whole)
-    layer = 1
-    uncut = np.asarray(ref.experts(n, w, layer, arch, (0, 8)))
-
-    def cut(first):
-        out = dict(w)
-        for part in ('gate', 'up', 'down'):
-            name = 'lm_moe_exp_%s.w' % part
-            out[name] = w[name][:, first:first + 1]
-        return out
-
-    shared = np.asarray(ref.expert(
-        n, w['lm_moe_shr_gate.w'][layer, 0], w['lm_moe_shr_up.w'][layer, 0],
-        w['lm_moe_shr_down.w'][layer, 0]))
-    from_reference, from_block = shared.copy(), shared.copy()
-    for first in range(8):
-        share = cut(first)
-        from_reference += np.asarray(
-            ref.experts(n, share, layer, arch, (first, 1))) - shared
-        chosen, weight = moe.route_sigmoid_topk(
-            n, share['lm_moe_router.w'][layer], whole.experts_per_token,
-            bias=share['lm_moe_router.b'][layer], scale=whole.routed_scale)
-        gate, _ = moe.held_gates(chosen, weight, first, 1)
-        from_block += np.asarray(moe.gated_experts(
-            n, gate, *(jnp.asarray(share['lm_moe_exp_%s.w' % p][layer])
-                       for p in ('gate', 'up', 'down'))))
-    np.testing.assert_allclose(from_reference, uncut, atol=TOL)
-    np.testing.assert_allclose(from_block, uncut, atol=TOL)
+    n, w, arch, uncut, shared, _ = block_harness.shares_of_one_expert_add_up(
+        ref, _spec, 1, TOL, scale=SPEC.routed_scale)
     # the scale is on the routed sum alone
     unscaled = np.asarray(ref.experts(
-        n, w, layer, dict(arch, scale_routed=False), (0, 8)))
+        n, w, 1, dict(arch, scale_routed=False), (0, 8)))
     np.testing.assert_allclose(uncut - shared,
                                (unscaled - shared) * 2.827, atol=TOL)
-    held = lm.block_param_shapes(_spec(experts_held=1, first_expert=3))
-    full = lm.block_param_shapes(whole)
-    assert {k for k in full if full[k][0] != held[k][0]} == {
-        'lm_moe_exp_gate.w', 'lm_moe_exp_up.w', 'lm_moe_exp_down.w'}
 
 
 # ------------------------------------------------------------ the engine
@@ -524,14 +377,9 @@ def test_engine_refuses_what_has_no_test_for_this_block(spec, kw):
 
 
 def test_programs_write_the_arena_in_place():
-    from paddle_tpu.serving.decode.hlo_check import arena_sized_instructions
-    pool = 2048
-    eng = _engine(num_blocks=pool)
+    eng = _engine(num_blocks=2048)
     try:
-        smallest = min(pool * BS * k.width for k in SPEC.cache_kinds())
-        for which in ('decode', 8):
-            hlo = eng.trace_program(which).lower().compile().as_text()
-            assert arena_sized_instructions(hlo, smallest) == []
+        block_harness.programs_write_arenas_in_place(eng)
     finally:
         eng.shutdown(drain=False)
 

@@ -19,6 +19,8 @@ toy widths:
 - the benchmark's metric of the count resolves and reads.
 """
 
+import collections
+import functools
 import json
 import os
 
@@ -30,7 +32,9 @@ import jax.numpy as jnp
 
 from paddle_tpu.ops import latent_moe_ops as lmo
 from paddle_tpu.ops.pallas import paged_attention as pa
+from paddle_tpu.serving.decode import DecodeEngine, LMSpec, random_weights
 from paddle_tpu.serving.decode import model as lm
+from block_harness import Driver
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NB, BS, P = 48, 8, 16            # pool, page, table: 128 columns a table
@@ -226,14 +230,50 @@ def test_the_rule_at_the_published_widths(shape, least):
     assert not lm.LatentShape(4, 8, 8, 8, 4, 8, 1e4).expands(10 ** 6)
 
 
+def _latent(kind):
+    """A kind of KINDS as LMSpec takes a latent attention."""
+    h, r, d_nope, d_rope, d_v, scaling, _, _ = KINDS[kind]
+    return dict(n_head=h, q_rank=16, kv_rank=r, d_nope=d_nope,
+                d_rope=d_rope, d_v=d_v, rope_scaling=scaling)
+
+
+_Toy = collections.namedtuple('_Toy', 'spec weights ref bs pages nb tol')
+
+
+@functools.lru_cache(maxsize=None)
 def _kimi():
-    import test_kimi_k2_6_block as kimi
-    return kimi
+    """kimi_k2_6 at the size of tests/test_kimi_k2_6_block.py: four
+    full layers of the dense kind under YaRN, the first one dense."""
+    from paddle_tpu.models.reference import kimi_k2_6 as ref
+    spec = LMSpec(
+        vocab_size=64, n_layer=4, d_model=32, d_inner=24,
+        block='latent_moe', layer_types=[lm.FULL] * 4,
+        latent={lm.FULL: dict(_latent('dense_yarn'), rope_theta=100.0)},
+        dense_layers=1, d_inner_dense=40, index_topk=0, n_experts=8,
+        experts_held=3, first_expert=2, experts_per_token=3,
+        n_shared_experts=1, lora_rescale=False, attn_gate=False,
+        routed_scale=2.827)
+    return _Toy(spec, random_weights(spec, seed=7), ref, 4, 16, 64, 5e-5)
 
 
+@functools.lru_cache(maxsize=None)
 def _dots3():
-    import test_latent_moe_block as dots3
-    return dots3
+    """dots3_note at the size of tests/test_latent_moe_block.py: a dense
+    full layer, then (full, sliding x 3); an indexer that keeps 8
+    positions, window 5."""
+    from paddle_tpu.models.reference import dots3_note as ref
+    spec = LMSpec(
+        vocab_size=64, n_layer=5, d_model=32, d_inner=24,
+        block='latent_moe', sliding_window=KINDS['sliding_under_lo'][6],
+        layer_types=[lm.FULL] * 2 + [lm.SLIDING] * 3,
+        latent={lm.FULL: dict(_latent('full_under_chosen'), rope_theta=8e7),
+                lm.SLIDING: dict(_latent('sliding_under_lo'),
+                                 rope_theta=5e4)},
+        dense_layers=1, d_inner_dense=40, index_n_heads=3,
+        index_head_dim=8, index_topk=KINDS['full_under_chosen'][7],
+        n_experts=8, experts_held=4, first_expert=2, experts_per_token=3,
+        n_shared_experts=1)
+    return _Toy(spec, random_weights(spec, seed=5), ref, 4, 12, 40, 5e-5)
 
 
 @pytest.mark.parametrize('which,cached', [
@@ -248,28 +288,30 @@ def test_block_logits_are_the_references_under_either_form(
     both against the plain reference."""
     t = which()
     rng = np.random.RandomState(cached)
-    tokens = rng.randint(0, t.SPEC.vocab_size, cached + 32)
-    table = jnp.asarray(rng.permutation(t.NB)[:t.PAGES], jnp.int32)
+    tokens = rng.randint(0, t.spec.vocab_size, cached + 32)
+    table = jnp.asarray(rng.permutation(t.nb)[:t.pages], jnp.int32)
     assert all(a.expands(32) and not a.expands(cached)
-               for a in t.SPEC.latent.values())
-    want = t._reference_logits(tokens)[cached:]
+               for a in t.spec.latent.values())
+    want = Driver(t.spec, t.weights, t.bs, t.nb).reference_logits(
+        t.ref, tokens)[cached:]
 
-    def chunk(block):
-        arenas = t._arenas()
+    def chunk():
+        # a driver of its own a form: a program reads the rule as it is
+        # traced
+        driver = Driver(t.spec, t.weights, t.bs, t.nb)
+        arenas = driver.arenas()
         if cached:
-            _, arenas, _ = t._chunk_rows(
-                block, arenas, table, jnp.asarray(tokens[:cached]),
-                jnp.int32(0))
-        logits, _, _ = jax.jit(lambda *a: t._chunk_rows(block, *a))(
-            arenas, table, jnp.asarray(tokens[cached:]), jnp.int32(cached))
+            _, arenas, _ = driver.prefill_chunk(arenas, table,
+                                                tokens[:cached], 0)
+        logits, _, _ = driver.prefill_chunk(arenas, table, tokens[cached:],
+                                            cached)
         return np.asarray(logits)
 
-    block = lmo.LatentMoEBlock(t._Ctx(t.SPEC, t.WEIGHTS))
-    expanded = chunk(block)
+    expanded = chunk()
     monkeypatch.setattr(lmo, 'latent_expands', lambda *a: False)
-    absorbed = chunk(block)
-    np.testing.assert_allclose(expanded, want, atol=t.TOL, rtol=t.TOL)
-    np.testing.assert_allclose(absorbed, want, atol=t.TOL, rtol=t.TOL)
+    absorbed = chunk()
+    np.testing.assert_allclose(expanded, want, atol=t.tol, rtol=t.tol)
+    np.testing.assert_allclose(absorbed, want, atol=t.tol, rtol=t.tol)
     assert not np.array_equal(expanded, absorbed)   # two forms did run
 
 
@@ -281,8 +323,11 @@ def test_engine_counts_the_chunks_the_lowering_expands():
     ([1, 1, H, S, d_v]) or the absorbed form's ([1, 1, H, S, rank])."""
     from paddle_tpu import observe
     kimi = _kimi()
-    shape = kimi.SPEC.latent[lm.FULL]
-    eng = kimi._engine(prefill_chunk=32, prefix_cache=False)
+    shape = kimi.spec.latent[lm.FULL]
+    eng = DecodeEngine(
+        kimi.spec, max_batch=4, block_size=kimi.bs, num_blocks=kimi.nb,
+        pages_per_seq=kimi.pages, max_prompt_len=48, prefill_chunk=32,
+        min_prompt_bucket=8, weights=kimi.weights, prefix_cache=False)
     try:
         assert eng.prompt_buckets == [8, 16, 32]
         for bucket in eng.prompt_buckets:

@@ -31,6 +31,8 @@ from paddle_tpu.ops import moe_held_ops as moe
 from paddle_tpu.ops import paged_decode_ops as pdo
 from paddle_tpu.serving.decode import DecodeEngine, LMSpec, random_weights
 from paddle_tpu.serving.decode import model as lm
+import block_harness
+from block_harness import Driver
 from util import (as_held, cell_spec, heads_of_held, platform_forms,
                   weights_round_trip)
 
@@ -58,87 +60,11 @@ SPEC = _spec()
 WEIGHTS = random_weights(SPEC, seed=5)
 
 
-class _Op(object):
-    def __init__(self, slots):
-        self._slots = slots
-
-    def input(self, slot):
-        return self._slots[slot]
+DRIVER = Driver(SPEC, WEIGHTS, BS, NB, pages=PAGES)
 
 
-class _Ctx(object):
-    """What a paged op's lowering reads of its context, for driving the
-    block's row function without a Program."""
-
-    def __init__(self, spec, weights):
-        self._attrs = lm._block_attrs(spec, BS)
-        self.env = {}
-        slots = {}
-        # an op reads a weight as the programs hold it
-        held = as_held(spec, weights)
-        for name, (_, _, slot) in lm.block_param_shapes(spec).items():
-            self.env[name] = held[name]
-            slots[slot] = name
-        self.op = _Op(slots)
-
-    def attr(self, name, default=None):
-        return self._attrs.get(name, default)
-
-    def input(self, slot):
-        return self.env[self.op.input(slot)]
-
-
-def _block(spec=SPEC, weights=WEIGHTS):
-    return lmo.LatentMoEBlock(_Ctx(spec, weights))
-
-
-def _arenas(spec=SPEC):
-    return tuple(jnp.zeros((len(k.layers), NB, BS, k.stored), jnp.float32)
-                 for k in spec.cache_kinds())
-
-
-_JITTED = {}
-
-
-def _jitted(block, fn):
-    """``fn(block, ...)`` compiled once per block and shape."""
-    key = (id(block), fn.__name__)
-    if key not in _JITTED:
-        _JITTED[key] = (block, jax.jit(lambda *a: fn(block, *a)))
-    return _JITTED[key][1]
-
-
-def _chunk_rows(block, arenas, table, tokens, start):
-    s = tokens.shape[0]
-    pos = start + jnp.arange(s, dtype=jnp.int32)
-    place = pdo._page_runs(table, start, jnp.int32(s), s, NB, BS)
-    h, arenas, stats = pdo._extend_rows(
-        block, arenas, tokens, pos, table, place, valid=jnp.ones((s,), bool))
-    return block.logits(h), arenas, stats
-
-
-def _prefill_chunk(block, arenas, table, tokens, start):
-    """One chunk of one sequence through the one-table path, as the
-    paged_prefill op runs it: logits of every row."""
-    return _jitted(block, _chunk_rows)(
-        arenas, table, jnp.asarray(tokens, jnp.int32), jnp.int32(start))
-
-
-def _step_rows(block, arenas, tables, tokens, lens):
-    place = pdo._single_rows(tables, lens, NB, BS)
-    h, arenas, stats = pdo._extend_rows(
-        block, arenas, tokens, lens, tables, place, valid=place.ok[:, 0])
-    return block.logits(h), arenas, stats
-
-
-def _decode(block, arenas, tables, tokens, lens):
-    return _jitted(block, _step_rows)(arenas, tables, tokens, lens)
-
-
-def _reference_logits(tokens, spec=SPEC, weights=WEIGHTS, **lowered):
-    return np.asarray(ref.logits(
-        weights, np.asarray(tokens, np.int32),
-        dict(ref.arch_of(spec), **lowered), ref.held_of(spec)))
+def _reference_logits(tokens, **lowered):
+    return DRIVER.reference_logits(ref, tokens, **lowered)
 
 
 # ------------------------------------------------------ spec and caches
@@ -401,45 +327,10 @@ def test_shares_add_up_to_the_uncut_layer():
     reference, and between the block's product and the reference.
     Attention, indexer and router are replicated: a share's are the
     uncut model's own arrays."""
-    whole = _spec(experts_held=8, first_expert=0)
-    w = random_weights(whole, seed=11)
-    rng = np.random.RandomState(1)
-    n = jnp.asarray(rng.randn(7, whole.d_model), jnp.float32)
-    arch = ref.arch_of(whole)
-    layer = 2
-    uncut = np.asarray(ref.experts(n, w, layer, arch, (0, 8)))
-
-    def cut(first):
-        out = dict(w)
-        for part in ('gate', 'up', 'down'):
-            name = 'lm_moe_exp_%s.w' % part
-            out[name] = w[name][:, first:first + 1]
-        return out
-
-    shared = np.asarray(ref.expert(
-        n, w['lm_moe_shr_gate.w'][layer, 0], w['lm_moe_shr_up.w'][layer, 0],
-        w['lm_moe_shr_down.w'][layer, 0]))
-    from_reference, from_block = shared.copy(), shared.copy()
-    for first in range(8):
-        share = cut(first)
-        from_reference += np.asarray(
-            ref.experts(n, share, layer, arch, (first, 1))) - shared
-        chosen, weight = moe.route_sigmoid_topk(
-            n, share['lm_moe_router.w'][layer], whole.experts_per_token,
-            bias=share['lm_moe_router.b'][layer])
-        gate, _ = moe.held_gates(chosen, weight, first, 1)
-        from_block += np.asarray(moe.gated_experts(
-            n, gate, *(jnp.asarray(share['lm_moe_exp_%s.w' % p][layer])
-                       for p in ('gate', 'up', 'down'))))
-    np.testing.assert_allclose(from_reference, uncut, atol=TOL)
-    np.testing.assert_allclose(from_block, uncut, atol=TOL)
-    assert np.abs(np.asarray(ref.experts(n, cut(0), layer, arch, (0, 1)))
+    n, _, arch, uncut, _, cut = block_harness.shares_of_one_expert_add_up(
+        ref, _spec, 2, TOL)
+    assert np.abs(np.asarray(ref.experts(n, cut(0), 2, arch, (0, 1)))
                   - uncut).max() > 1e-2
-    # everything else of a share is the uncut model's
-    held = lm.block_param_shapes(_spec(experts_held=1, first_expert=3))
-    full = lm.block_param_shapes(whole)
-    assert {k for k in full if full[k][0] != held[k][0]} == {
-        'lm_moe_exp_gate.w', 'lm_moe_exp_up.w', 'lm_moe_exp_down.w'}
 
 
 # ------------------------------------- prefill in chunks, then decode
@@ -451,26 +342,9 @@ def test_chunked_prefill_then_decode_matches_full_forward(prompt_len,
     prompt prefilled in chunks (or in one) through the three arenas,
     then decoded a token at a time, row by row against the reference's
     one full forward."""
-    rng = np.random.RandomState(prompt_len)
-    total = prompt_len + 12
-    tokens = rng.randint(0, SPEC.vocab_size, total)
-    want = _reference_logits(tokens)
-    block = _block()
-    arenas = _arenas()
-    table = jnp.asarray(rng.permutation(NB)[:PAGES], jnp.int32)
-    for start in range(0, prompt_len, chunk):
-        piece = tokens[start:min(start + chunk, prompt_len)]
-        got, arenas, stats = _prefill_chunk(block, arenas, table, piece,
-                                            start)
-        np.testing.assert_allclose(
-            np.asarray(got), want[start:start + len(piece)], atol=TOL)
+    for _, stats in block_harness.chunked_prefill_then_decode(
+            DRIVER, ref, prompt_len, chunk, 12, TOL):
         assert np.asarray(stats).shape == (4, 4)     # the routed layers
-    for t in range(prompt_len, total):
-        got, arenas, _ = _decode(
-            block, arenas, table[None, :],
-            jnp.asarray(tokens[t:t + 1], jnp.int32),
-            jnp.asarray([t], jnp.int32))
-        np.testing.assert_allclose(np.asarray(got)[0], want[t], atol=TOL)
 
 
 def test_the_published_order_with_periods_and_a_remainder():
@@ -480,16 +354,17 @@ def test_the_published_order_with_periods_and_a_remainder():
     types = [F] + [F, S, S, S] * 2 + [F]
     spec = _spec(n_layer=10, layer_types=types)
     assert spec.layer_plan() == ((F,), (F, S, S, S), 2, (F,))
-    w = random_weights(spec, seed=2)
+    # a driver of its own: another spec
+    deep = Driver(spec, random_weights(spec, seed=2), BS, NB, pages=PAGES)
     rng = np.random.RandomState(2)
     tokens = rng.randint(0, spec.vocab_size, 22)
-    want = _reference_logits(tokens, spec, w)
-    block, table = _block(spec, w), jnp.arange(PAGES, dtype=jnp.int32)
-    got, arenas, stats = _prefill_chunk(block, _arenas(spec), table,
-                                        tokens[:16], 0)
+    want = deep.reference_logits(ref, tokens)
+    table = jnp.arange(PAGES, dtype=jnp.int32)
+    got, arenas, stats = deep.prefill_chunk(deep.arenas(), table,
+                                            tokens[:16], 0)
     np.testing.assert_allclose(np.asarray(got), want[:16], atol=TOL)
     assert np.asarray(stats).shape == (9, 4)
-    got, arenas, _ = _prefill_chunk(block, arenas, table, tokens[16:], 16)
+    got, arenas, _ = deep.prefill_chunk(arenas, table, tokens[16:], 16)
     np.testing.assert_allclose(np.asarray(got), want[16:], atol=TOL)
 
 
@@ -501,31 +376,16 @@ def test_decode_batch_of_mixed_lengths_matches_reference(lengths):
     sequence, and the statistics count the live rows of the four routed
     layers only."""
     rng = np.random.RandomState(7)
-    seqs = {i: rng.randint(0, SPEC.vocab_size, n + 1)
-            for i, n in enumerate(lengths) if n}
-    block = _block()
-    arenas = _arenas()
-    pages = rng.permutation(NB)
-    tables = np.full((len(lengths), PAGES), NB, np.int32)
-    used = 0
-    for i, seq in seqs.items():
-        need = -(-len(seq) // BS)
-        tables[i, :need] = pages[used:used + need]
-        used += need
-        _, arenas, _ = _prefill_chunk(block, arenas, jnp.asarray(tables[i]),
-                                      seq[:-1], 0)
-    got, arenas, stats = _decode(
-        block, arenas, jnp.asarray(tables),
-        jnp.asarray([seqs[i][-1] if n else 0
-                     for i, n in enumerate(lengths)], jnp.int32),
-        jnp.asarray(lengths, jnp.int32))
-    for i, seq in seqs.items():
-        np.testing.assert_allclose(
-            np.asarray(got)[i], _reference_logits(seq)[-1], atol=TOL)
+    seqs = [rng.randint(0, SPEC.vocab_size, n + 1) if n else None
+            for n in lengths]
+    _, stats = block_harness.decode_batch_of_mixed_lengths(
+        DRIVER, ref, seqs, DRIVER.packed_tables(rng.permutation(NB), seqs),
+        TOL)
+    live = sum(s is not None for s in seqs)
     stats = np.asarray(stats)
     assert stats.shape == (4, 4)
-    assert (stats[:, 0] <= 3 * len(seqs)).all()
-    assert (stats[:, 1] <= len(seqs)).all()
+    assert (stats[:, 0] <= 3 * live).all()
+    assert (stats[:, 1] <= live).all()
     assert (stats[:, 2] <= SPEC.experts_held).all()
 
 
@@ -536,20 +396,12 @@ def test_a_row_alone_is_the_row_in_a_full_batch_bit_for_bit():
     rng = np.random.RandomState(11)
     lengths = [26, 9, 14, 31]
     seqs = [rng.randint(0, SPEC.vocab_size, n + 1) for n in lengths]
-    block = _block()
-    arenas = _arenas()
-    tables = np.full((4, PAGES), NB, np.int32)
-    pages = rng.permutation(NB)
-    used = 0
-    for i, seq in enumerate(seqs):
-        need = -(-len(seq) // BS)
-        tables[i, :need] = pages[used:used + need]
-        used += need
-        _, arenas, _ = _prefill_chunk(block, arenas, jnp.asarray(tables[i]),
-                                      seq[:-1], 0)
-    among, _, _ = _decode(block, arenas, jnp.asarray(tables),
-                          jnp.asarray([s[-1] for s in seqs], jnp.int32),
-                          jnp.asarray(lengths, jnp.int32))
+    arenas = DRIVER.arenas()
+    tables = DRIVER.packed_tables(rng.permutation(NB), seqs)
+    for seq, table in zip(seqs, tables):
+        _, arenas, _ = DRIVER.prefill_chunk(arenas, table, seq[:-1], 0)
+    among, _, _ = DRIVER.decode(arenas, tables, [s[-1] for s in seqs],
+                                lengths)
     for slot in (0, 3):
         alone_tables = np.full((4, PAGES), NB, np.int32)
         alone_tables[1] = tables[slot]
@@ -557,31 +409,14 @@ def test_a_row_alone_is_the_row_in_a_full_batch_bit_for_bit():
         tokens[1] = seqs[slot][-1]
         lens = np.zeros(4, np.int32)
         lens[1] = lengths[slot]
-        alone, _, _ = _decode(block, arenas, jnp.asarray(alone_tables),
-                              jnp.asarray(tokens), jnp.asarray(lens))
+        alone, _, _ = DRIVER.decode(arenas, alone_tables, tokens, lens)
         assert np.array_equal(np.asarray(alone)[1], np.asarray(among)[slot])
 
 
 def test_padded_chunk_rows_write_nothing():
     """A chunk padded to its bucket: the rows past ``length`` leave all
     three arenas as they were, and the real rows' logits do not move."""
-    rng = np.random.RandomState(3)
-    tokens = rng.randint(0, SPEC.vocab_size, 5)
-    block, table = _block(), jnp.arange(PAGES, dtype=jnp.int32)
-    exact, want, _ = _prefill_chunk(block, _arenas(), table, tokens, 0)
-    padded = np.concatenate([tokens, np.zeros(3, tokens.dtype)])
-    pos = jnp.arange(8, dtype=jnp.int32)
-    place = pdo._page_runs(table, jnp.int32(0), jnp.int32(5), 8, NB, BS)
-    h, got, _ = pdo._extend_rows(
-        block, _arenas(), jnp.asarray(padded, jnp.int32), pos, table, place,
-        valid=pos < 5)
-    np.testing.assert_allclose(np.asarray(block.logits(h))[:5],
-                               np.asarray(exact), atol=TOL)
-    for a, b in zip(want, got):
-        # (one side compiled, the other not: the rows agree to rounding)
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
-        flat = np.asarray(b).reshape(b.shape[0], NB * BS, -1)
-        assert flat[:, :5].any() and not flat[:, 5:].any()
+    block_harness.padded_chunk_rows_write_nothing(DRIVER, TOL)
 
 
 # --------------------------------- the absorbed against the expanded form
@@ -667,8 +502,10 @@ def test_the_tolerance_catches_a_wrong_block(broken):
             'lora_rescale': dict(lora_rescale=False)}[broken]
     rng = np.random.RandomState(2)
     tokens = rng.randint(0, SPEC.vocab_size, 20)
-    got, _, _ = _prefill_chunk(_block(_spec(**over)), _arenas(),
-                               jnp.arange(PAGES, dtype=jnp.int32), tokens, 0)
+    # a driver of its own: the broken spec over the sound weights
+    wrong = Driver(_spec(**over), WEIGHTS, BS, NB, pages=PAGES)
+    got, _, _ = wrong.prefill_chunk(
+        wrong.arenas(), jnp.arange(PAGES, dtype=jnp.int32), tokens, 0)
     assert np.abs(np.asarray(got) - _reference_logits(tokens)).max() > 1e-3
 
 
@@ -784,15 +621,10 @@ def test_programs_write_every_arena_in_place():
     """The decode step and a prefill chunk as the executor jits them,
     over a pool far larger than a block of the attention's gathers: no
     instruction of the compiled program materialises a layer of any of
-    the three arenas (serving/decode/hlo_check.py)."""
-    from paddle_tpu.serving.decode.hlo_check import arena_sized_instructions
-    pool = 2048
-    eng = _engine(num_blocks=pool)
+    the three arenas."""
+    eng = _engine(num_blocks=2048)
     try:
-        smallest = min(pool * BS * k.width for k in SPEC.cache_kinds())
-        for which in ('decode', 8):
-            hlo = eng.trace_program(which).lower().compile().as_text()
-            assert arena_sized_instructions(hlo, smallest) == []
+        block_harness.programs_write_arenas_in_place(eng)
     finally:
         eng.shutdown(drain=False)
 
@@ -879,11 +711,16 @@ def test_heads_out_of_the_shaped_stack_are_the_split_product(
         return
     prompt = rng.randint(0, spec.vocab_size,
                          sizes['prefill_chunk'] + 5).tolist()
+    # the cell's rehearsal spec in the least engine that still runs the
+    # prompt in chunks and four steps: one row, one sequence's pages,
+    # chunks of the least bucket (one prefill program and the step)
+    least = dict(sizes, max_batch=1, num_blocks=sizes['pages_per_seq'],
+                 prefill_chunk=sizes['min_prompt_bucket'])
     served = []
     for form in (lmo._heads_at, _sliced_then_split):
         monkeypatch.setattr(lmo, '_heads_at', form)
         eng = DecodeEngine(spec, weights=weights, place=fluid.CPUPlace(),
-                           **sizes)
+                           **least)
         try:
             eng.start()
             tokens = eng.generate(prompt, max_new_tokens=4, timeout=300)

@@ -22,16 +22,15 @@ each)."""
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 from paddle_tpu.models.reference import longcat_flash_chat as ref
 from paddle_tpu.ops import moe_held_ops as moe
-from paddle_tpu.ops import paged_decode_ops as pdo
-from paddle_tpu.ops.shortcut_moe_ops import ShortcutMoEBlock
 from paddle_tpu.serving.decode import DecodeEngine, LMSpec, random_weights
 from paddle_tpu.serving.decode import model as lm
-from util import as_held, weights_round_trip
+import block_harness
+from block_harness import Driver
+from util import weights_round_trip
 
 TOL = 5e-5
 BS, PAGES, NB = 4, 16, 64            # 64 positions a sequence
@@ -57,91 +56,11 @@ SPEC = _spec()
 WEIGHTS = random_weights(SPEC, seed=49)
 
 
-class _Op(object):
-    def __init__(self, slots):
-        self._slots = slots
-
-    def input(self, slot):
-        return self._slots[slot]
+DRIVER = Driver(SPEC, WEIGHTS, BS, NB, pages=PAGES)
 
 
-class _Ctx(object):
-    """What a paged op's lowering reads of its context, for driving the
-    block's row function without a Program."""
-
-    def __init__(self, spec, weights):
-        self._attrs = lm._block_attrs(spec, BS)
-        self.env = {}
-        slots = {}
-        # an op reads a weight as the programs hold it
-        held = as_held(spec, weights)
-        for name, (_, _, slot) in lm.block_param_shapes(spec).items():
-            self.env[name] = held[name]
-            slots[slot] = name
-        self.op = _Op(slots)
-
-    def attr(self, name, default=None):
-        return self._attrs.get(name, default)
-
-    def input(self, slot):
-        return self.env[self.op.input(slot)]
-
-
-_BLOCK = {}
-
-
-def _block(spec=SPEC, weights=WEIGHTS):
-    if id(spec) not in _BLOCK:
-        _BLOCK[id(spec)] = ShortcutMoEBlock(_Ctx(spec, weights))
-    return _BLOCK[id(spec)]
-
-
-def _arenas(spec=SPEC):
-    return tuple(jnp.zeros((len(k.layers), NB, BS, k.stored), jnp.float32)
-                 for k in spec.cache_kinds())
-
-
-_JITTED = {}
-
-
-def _jitted(block, fn):
-    key = (id(block), fn.__name__)
-    if key not in _JITTED:
-        _JITTED[key] = (block, jax.jit(lambda *a: fn(block, *a)))
-    return _JITTED[key][1]
-
-
-def _chunk_rows(block, arenas, table, tokens, start):
-    s = tokens.shape[0]
-    pos = start + jnp.arange(s, dtype=jnp.int32)
-    place = pdo._page_runs(table, start, jnp.int32(s), s, NB, BS)
-    h, arenas, stats = pdo._extend_rows(
-        block, arenas, tokens, pos, table, place, valid=jnp.ones((s,), bool))
-    return block.logits(h), arenas, stats
-
-
-def _prefill_chunk(block, arenas, table, tokens, start):
-    """One chunk of one sequence through the one-table path, as the
-    paged_prefill op runs it, from any offset: logits of every row."""
-    return _jitted(block, _chunk_rows)(
-        arenas, table, jnp.asarray(tokens, jnp.int32), jnp.int32(start))
-
-
-def _step_rows(block, arenas, tables, tokens, lens):
-    place = pdo._single_rows(tables, lens, NB, BS)
-    h, arenas, stats = pdo._extend_rows(
-        block, arenas, tokens, lens, tables, place, valid=place.ok[:, 0])
-    return block.logits(h), arenas, stats
-
-
-def _decode(block, arenas, tables, tokens, lens):
-    return _jitted(block, _step_rows)(arenas, tables, tokens, lens)
-
-
-def _reference_logits(tokens, spec=SPEC, weights=WEIGHTS, **lowered):
-    return np.asarray(ref.logits(
-        weights, np.asarray(tokens, np.int32),
-        dict(ref.arch_of(spec), **lowered), ref.held_of(spec)))
+def _reference_logits(tokens, **lowered):
+    return DRIVER.reference_logits(ref, tokens, **lowered)
 
 
 # ------------------------------------------------------ spec and table
@@ -152,7 +71,7 @@ def test_a_token_keeps_two_cache_layers_a_layer_of_the_one_kind():
                              (0, 1, 2, 3, 4, 5), 20, (0,) * 6, True)
     assert SPEC.sublayers == 2 and SPEC.cache_layers_of(F) == kind.layers
     assert SPEC.layer_plan() == ((), (F,), 3, ())
-    assert _block().arena_slots == ('LatentFull',)
+    assert DRIVER.block().arena_slots == ('LatentFull',)
     # the published widths: 4 layers x 2 sublayers x 576 values, stored
     # 640, bfloat16
     big = _spec(n_layer=4, layer_types=[F] * 4, latent={F: PUBLISHED},
@@ -220,50 +139,22 @@ def test_chunked_prefill_then_decode_matches_full_forward(prompt_len,
     offset, and chunks of 5 that start inside a page), prefilled through
     the one arena's six cache layers and decoded a token at a time, row
     by row against the reference's one full forward."""
-    rng = np.random.RandomState(prompt_len)
-    total = prompt_len + 10
-    tokens = rng.randint(0, SPEC.vocab_size, total)
-    want = _reference_logits(tokens)
-    block, arenas = _block(), _arenas()
-    table = jnp.asarray(rng.permutation(NB)[:PAGES], jnp.int32)
-    for start in range(0, prompt_len, chunk):
-        piece = tokens[start:min(start + chunk, prompt_len)]
-        got, arenas, stats = _prefill_chunk(block, arenas, table, piece,
-                                            start)
-        np.testing.assert_allclose(
-            np.asarray(got), want[start:start + len(piece)], atol=TOL)
+    for rows, stats in block_harness.chunked_prefill_then_decode(
+            DRIVER, ref, prompt_len, chunk, 10, TOL):
         # the routed layers; 4 of load and the rows by 0 .. 6 real experts
         assert np.asarray(stats).shape == (3, 4 + 7)
-        assert (np.asarray(stats)[:, 4:].sum(axis=1) == len(piece)).all()
-    for t in range(prompt_len, total):
-        got, arenas, _ = _decode(
-            block, arenas, table[None, :],
-            jnp.asarray(tokens[t:t + 1], jnp.int32),
-            jnp.asarray([t], jnp.int32))
-        np.testing.assert_allclose(np.asarray(got)[0], want[t], atol=TOL)
+        assert (np.asarray(stats)[:, 4:].sum(axis=1) == rows).all()
 
 
 def test_decode_batch_of_mixed_lengths_matches_reference():
     rng = np.random.RandomState(9)
-    lens = [5, 18, 33]
-    block, arenas = _block(), _arenas()
     pages = rng.permutation(NB)
-    tables, seqs = [], []
-    for i, n in enumerate(lens):
-        seq = rng.randint(0, SPEC.vocab_size, n + 1)
-        table = jnp.asarray(pages[i * PAGES:(i + 1) * PAGES], jnp.int32)
-        _, arenas, _ = _prefill_chunk(block, arenas, table, seq[:n], 0)
-        tables.append(table)
-        seqs.append(seq)
+    seqs = [rng.randint(0, SPEC.vocab_size, n + 1) for n in (5, 18, 33)]
     # a fourth row that holds no sequence rides along
-    tables.append(jnp.full((PAGES,), NB, jnp.int32))
-    got, _, stats = _decode(
-        block, arenas, jnp.stack(tables),
-        jnp.asarray([s[-1] for s in seqs] + [0], jnp.int32),
-        jnp.asarray(lens + [0], jnp.int32))
-    for row, seq in zip(np.asarray(got), seqs):
-        np.testing.assert_allclose(row, _reference_logits(seq)[-1],
-                                   atol=TOL)
+    tables = np.concatenate([pages[:3 * PAGES].reshape(3, PAGES),
+                             np.full((1, PAGES), NB)])
+    _, stats = block_harness.decode_batch_of_mixed_lengths(
+        DRIVER, ref, seqs + [None], tables, TOL)
     # three live rows in every layer's count
     assert (np.asarray(stats)[:, 4:].sum(axis=1) == 3).all()
 
@@ -337,7 +228,7 @@ def test_a_row_whose_choices_are_all_identity_runs_no_expert():
     w = random_weights(spec, seed=3)
     w['lm_moe_router.b'] = np.where(np.arange(24) >= 16, 1.0, 0.0)[
         None, :].repeat(3, 0).astype('float32')
-    block = ShortcutMoEBlock(_Ctx(spec, w))
+    block = Driver(spec, w, BS, NB).block()
     n = jnp.asarray(rng.randn(5, 32), jnp.float32)
     m, stats = block._routed(n, 1, None)
     chosen, weight = moe.route_softmax_topk(
@@ -395,7 +286,8 @@ def test_shares_add_up_to_the_uncut_branch():
                        for p in ('gate', 'up', 'down'))))
         # and the block's own branch on that share is the reference's
         spec = _spec(experts_held=4, first_expert=first)
-        mine, _ = ShortcutMoEBlock(_Ctx(spec, share))._routed(n, layer, None)
+        mine, _ = Driver(spec, share, BS, NB).block()._routed(
+            n, layer, None)
         np.testing.assert_allclose(
             np.asarray(mine), np.asarray(ref.experts(
                 n, share, layer, arch, (first, 4))), atol=TOL)
@@ -496,14 +388,9 @@ def test_the_page_handoff_is_refused(engine):
 
 
 def test_programs_write_the_arena_in_place():
-    from paddle_tpu.serving.decode.hlo_check import arena_sized_instructions
-    pool = 2048
-    eng = _engine(num_blocks=pool)
+    eng = _engine(num_blocks=2048)
     try:
-        smallest = min(pool * BS * k.width for k in SPEC.cache_kinds())
-        for which in ('decode', 8):
-            hlo = eng.trace_program(which).lower().compile().as_text()
-            assert arena_sized_instructions(hlo, smallest) == []
+        block_harness.programs_write_arenas_in_place(eng)
     finally:
         eng.shutdown(drain=False)
 

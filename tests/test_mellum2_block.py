@@ -20,18 +20,17 @@ each)."""
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 from paddle_tpu import observe
 from paddle_tpu.models.reference import mellum2_12b as ref
 from paddle_tpu.ops import gqa_moe_ops as gmo
 from paddle_tpu.ops import moe_held_ops as moe
-from paddle_tpu.ops import paged_decode_ops as pdo
 from paddle_tpu.serving.decode import DecodeEngine, LMSpec, random_weights
 from paddle_tpu.serving.decode import model as lm
 from paddle_tpu.serving.decode.kv_pool import BlockTable, KVPool
 from paddle_tpu.serving.decode.scheduler import Scheduler, Sequence
+from block_harness import Driver, tokens as _tokens
 
 TOL = 5e-5
 BS, PAGES = 4, 24                    # 96 positions a sequence
@@ -70,81 +69,11 @@ SPEC = _spec()
 WEIGHTS = random_weights(SPEC, seed=11)
 
 
-class _Op(object):
-    def __init__(self, slots):
-        self._slots = slots
-
-    def input(self, slot):
-        return self._slots[slot]
+DRIVER = Driver(SPEC, WEIGHTS, BS, NB, pages=PAGES)
 
 
-class _Ctx(object):
-    """What a paged op's lowering reads of its context, for driving the
-    block's row function without a Program."""
-
-    def __init__(self, spec, weights):
-        self._attrs = lm._block_attrs(spec, BS)
-        self.env = {}
-        slots = {}
-        for name, (_, _, slot) in lm.block_param_shapes(spec).items():
-            self.env[name] = jnp.asarray(weights[name])
-            slots[slot] = name
-        self.op = _Op(slots)
-
-    def attr(self, name, default=None):
-        return self._attrs.get(name, default)
-
-    def input(self, slot):
-        return self.env[self.op.input(slot)]
-
-
-_BLOCK = {}
-
-
-def _block(spec=SPEC, weights=WEIGHTS):
-    if id(spec) not in _BLOCK:
-        _BLOCK[id(spec)] = gmo.GqaMoEBlock(_Ctx(spec, weights))
-    return _BLOCK[id(spec)]
-
-
-def _arenas(spec=SPEC):
-    return tuple(jnp.zeros((len(k.layers), NB[k.pool], BS, k.stored),
-                           jnp.float32) for k in spec.cache_kinds())
-
-
-def _pool_sizes(block):
-    return [NB[suffix.lower()] for suffix, _ in block.pools]
-
-
-_JITTED = {}
-
-
-def _jitted(block, fn):
-    key = (id(block), fn.__name__)
-    if key not in _JITTED:
-        _JITTED[key] = (block, jax.jit(lambda *a: fn(block, *a)))
-    return _JITTED[key][1]
-
-
-def _step_rows(block, arenas, tables, tokens, lens):
-    place = [pdo._single_rows(t, lens, nb, BS)
-             for t, nb in zip(tables, _pool_sizes(block))]
-    h, arenas, stats = pdo._extend_rows(
-        block, arenas, tokens, lens, tables[0], place[0],
-        valid=place[0].ok[:, 0], more=zip(tables[1:], place[1:]))
-    return block.logits(h), arenas, stats
-
-
-def _decode(block, arenas, tables, tokens, lens):
-    return _jitted(block, _step_rows)(
-        arenas, [jnp.asarray(t, jnp.int32) for t in tables],
-        jnp.asarray(tokens, jnp.int32), jnp.asarray(lens, jnp.int32))
-
-
-def _reference_logits(tokens, spec=SPEC, weights=WEIGHTS, **lowered):
-    return np.asarray(ref.logits(
-        weights, np.asarray(tokens, np.int32),
-        dict(ref.arch_of(spec), **lowered), ref.held_of(spec)))
+def _reference_logits(tokens, **lowered):
+    return DRIVER.reference_logits(ref, tokens, **lowered)
 
 
 def _pools(chunk=CHUNK, sliding=NB['sliding']):
@@ -175,7 +104,7 @@ def _serve(tokens, prompt, chunk=CHUNK, pools=None, between=None):
     each, the sliding pool trimmed behind the window before every
     program. Logits of every position, the pools, the tables, the
     arenas. ``between(arenas, pools, tables)`` runs after the prefill."""
-    block, arenas = _block(), _arenas()
+    arenas = DRIVER.arenas()
     pools = pools or _pools(chunk)
     tables = [BlockTable(), BlockTable()]
     out = []
@@ -185,35 +114,18 @@ def _serve(tokens, prompt, chunk=CHUNK, pools=None, between=None):
         padded = np.zeros((chunk,), np.int32)
         padded[:len(piece)] = piece
         # the padded tail is written nowhere and seen by no kept row
-        place_len = len(piece)
-        logits, arenas, _ = _jitted(block, _chunk_rows_len)(
-            arenas, [jnp.asarray(r) for r in _rows(pools, tables)],
-            jnp.asarray(padded), jnp.int32(a), jnp.int32(place_len))
+        logits, arenas, _ = DRIVER.prefill_chunk(
+            arenas, _rows(pools, tables), padded, a, length=len(piece))
         out.append(np.asarray(logits)[:len(piece)])
     if between is not None:
         arenas = between(arenas, pools, tables) or arenas
     for p in range(prompt, len(tokens)):
         _grow(pools, tables, p + 1, p)
-        logits, arenas, _ = _decode(
-            block, arenas, [r[None] for r in _rows(pools, tables)],
-            [tokens[p]], [p])
+        logits, arenas, _ = DRIVER.decode(
+            arenas, [r[None] for r in _rows(pools, tables)], [tokens[p]],
+            [p])
         out.append(np.asarray(logits))
     return np.concatenate(out), pools, tables, arenas
-
-
-def _chunk_rows_len(block, arenas, tables, tokens, start, length):
-    s = tokens.shape[0]
-    pos = start + jnp.arange(s, dtype=jnp.int32)
-    place = [pdo._page_runs(t, start, length, s, nb, BS)
-             for t, nb in zip(tables, _pool_sizes(block))]
-    h, arenas, stats = pdo._extend_rows(
-        block, arenas, tokens, pos, tables[0], place[0],
-        valid=jnp.arange(s) < length, more=zip(tables[1:], place[1:]))
-    return block.logits(h), arenas, stats
-
-
-def _tokens(n, seed=0):
-    return np.random.RandomState(seed).randint(0, 64, n).astype(np.int32)
 
 
 # ------------------------------------------------------ spec and pools
@@ -456,10 +368,9 @@ def test_the_tolerance_catches_a_sigmoid_router():
 def test_a_decode_batch_of_mixed_depths_matches_the_reference():
     """Three sequences of unlike depth in one decode step, each through
     its own tables, and an empty slot beside them."""
-    block = _block()
     lengths = (13, 37, 58)
     seqs = [_tokens(n + 1, 100 + n) for n in lengths]
-    arenas, pools = _arenas(), _pools()
+    arenas, pools = DRIVER.arenas(), _pools()
     tables = []
     for tokens, n in zip(seqs, lengths):
         mine = [BlockTable(), BlockTable()]
@@ -468,17 +379,15 @@ def test_a_decode_batch_of_mixed_depths_matches_the_reference():
             _grow(pools, mine, a + len(piece), a)
             padded = np.zeros((CHUNK,), np.int32)
             padded[:len(piece)] = piece
-            _, arenas, _ = _jitted(block, _chunk_rows_len)(
-                arenas, [jnp.asarray(r) for r in _rows(pools, mine)],
-                jnp.asarray(padded), jnp.int32(a), jnp.int32(len(piece)))
+            _, arenas, _ = DRIVER.prefill_chunk(
+                arenas, _rows(pools, mine), padded, a, length=len(piece))
         _grow(pools, mine, n + 1, n)
         tables.append(mine)
     rows = [np.stack([_rows(pools, t)[i] for t in tables]
                      + [np.full((PAGES,), pools[i].num_blocks, 'int32')])
             for i in range(2)]
-    logits, _, stats = _decode(
-        block, arenas, rows, [int(s[-1]) for s in seqs] + [0],
-        list(lengths) + [0])
+    logits, _, stats = DRIVER.decode(
+        arenas, rows, [int(s[-1]) for s in seqs] + [0], list(lengths) + [0])
     for i, tokens in enumerate(seqs):
         np.testing.assert_allclose(np.asarray(logits)[i],
                                    _reference_logits(tokens)[-1], atol=TOL)
@@ -642,23 +551,29 @@ def _reference_tokens(prompt, answer):
 
 
 @pytest.fixture(scope='module')
-def served():
-    """Six requests of unlike depth through one roomy engine, submitted
-    together: (prompts, answers, the engine's counters after them)."""
-    observe.enable()
+def roomy():
+    """One roomy engine, built once, warmed and started."""
     eng = _engine()
     eng.warmup()
     eng.start()
+    yield eng
+    eng.shutdown()
+
+
+@pytest.fixture(scope='module')
+def served(roomy):
+    """Six requests of unlike depth through the roomy engine, submitted
+    together: (prompts, answers, the engine's counters after them)."""
+    observe.enable()
     rng = np.random.RandomState(4)
     prompts = [rng.randint(0, 64, n).tolist() for n in (5, 23, 41, 60, 33,
                                                         70)]
-    streams = [eng.submit(p, max_new_tokens=18) for p in prompts]
+    streams = [roomy.submit(p, max_new_tokens=18) for p in prompts]
     answers = [s.result(timeout=600) for s in streams]
+    assert roomy.drain(timeout=60)
     counters = dict(observe.snapshot()['counters'])
-    pools = [(p.used_blocks(), p.num_blocks) for p in eng.pools]
-    signatures = eng.warmup_signatures
-    eng.shutdown()
-    return prompts, answers, counters, pools, signatures
+    pools = [(p.used_blocks(), p.num_blocks) for p in roomy.pools]
+    return prompts, answers, counters, pools, roomy.warmup_signatures
 
 
 @pytest.mark.parametrize('i', range(6))
@@ -685,24 +600,30 @@ def test_pages_of_every_kind_return_to_zero_after_release(served):
 
 
 def test_a_sequence_holds_a_window_of_the_sliding_pool_whatever_its_length():
+    observe.enable()
     eng = _engine(max_batch=1)
     eng.warmup()
-    peak = [0, 0]
+    peak = 0
 
-    def watch():
-        for i, pool in enumerate(eng.pools):
-            peak[i] = max(peak[i], pool.used_blocks())
+    def handed():
+        return observe.get_counter('decode.kv_pages_allocated_total',
+                                   kind='full')
+    before = handed()
     eng.start()
     stream = eng.submit(_tokens(70, 3).tolist(), max_new_tokens=20)
     for _ in stream:
-        watch()
-    assert peak[0] == -(-90 // BS)
-    assert 0 < peak[1] <= WINDOW // BS + 2
+        peak = max(peak, eng.pools[1].used_blocks())
+    # the full pool gives nothing back before the end, so its peak is the
+    # pages it handed the one sequence (a reader of the stream misses the
+    # last page where the worker, a step ahead, has let them all go)
+    assert handed() - before == -(-90 // BS)
+    assert 0 < peak <= WINDOW // BS + 2
     assert eng.pools[1].span_pages(90) == (WINDOW + CHUNK) // BS + 2
     eng.shutdown()
 
 
-def test_a_preempted_sequence_reprefills_bit_exact_with_per_kind_tables():
+def test_a_preempted_sequence_reprefills_bit_exact_with_per_kind_tables(
+        roomy):
     """A full pool too small for all three sequences' pages at once: the
     youngest is preempted, its tables of both pools released, and its
     re-prefill (whose sliding layers only ever see a window) continues
@@ -710,11 +631,8 @@ def test_a_preempted_sequence_reprefills_bit_exact_with_per_kind_tables():
     observe.enable()
     rng = np.random.RandomState(6)
     prompts = [rng.randint(0, 64, n).tolist() for n in (30, 26, 22)]
-    roomy = _engine()
-    roomy.start()
     want = [roomy.generate(p, max_new_tokens=24, timeout=600)
             for p in prompts]
-    roomy.shutdown()
     before = observe.get_counter('decode.preemptions_total')
     tight = _engine(num_blocks=30)
     tight.start()
@@ -726,8 +644,8 @@ def test_a_preempted_sequence_reprefills_bit_exact_with_per_kind_tables():
     tight.shutdown()
 
 
-def test_the_programs_take_a_table_a_pool_and_keep_one_signature():
-    eng = _engine()
+def test_the_programs_take_a_table_a_pool_and_keep_one_signature(roomy):
+    eng = roomy
     feeds = {v.name for v in eng._progs.decode.global_block().vars.values()
              if getattr(v, 'is_data', False)}
     assert feeds == {'dec_tokens', 'dec_lens', 'dec_tables',
@@ -747,4 +665,3 @@ def test_the_programs_take_a_table_a_pool_and_keep_one_signature():
     assert len(small.generate([1] * 60, max_new_tokens=8,
                               timeout=600)) == 8
     small.shutdown()
-    eng.shutdown(drain=False)

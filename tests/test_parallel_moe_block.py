@@ -15,17 +15,15 @@ breaking each)."""
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 import paddle_tpu as fluid
 from paddle_tpu.models.reference import command_a_plus as ref
 from paddle_tpu.ops import moe_held_ops as moe
-from paddle_tpu.ops import paged_decode_ops as pdo
 from paddle_tpu.serving.decode import DecodeEngine, LMSpec, random_weights
-from paddle_tpu.serving.decode.model import (arena_bytes,
-                                             kv_bytes_per_token,
-                                             moe_param_shapes)
+from paddle_tpu.serving.decode.model import arena_bytes, kv_bytes_per_token
+import block_harness
+from block_harness import Driver
 
 TOL = 2e-5
 BS, PAGES, NB = 4, 10, 24            # 40 positions a sequence
@@ -49,67 +47,11 @@ WEIGHTS = random_weights(SPEC, seed=5)
 _arch, _held = ref.arch_of, ref.held_of
 
 
-class _Op(object):
-    def __init__(self, slots):
-        self._slots = slots
-
-    def input(self, slot):
-        return self._slots[slot]
+DRIVER = Driver(SPEC, WEIGHTS, BS, NB, pages=PAGES)
 
 
-class _Ctx(object):
-    """What a paged op's lowering reads of its context, for driving the
-    block's row function without a Program."""
-
-    def __init__(self, spec, weights):
-        from paddle_tpu.serving.decode.model import _block_attrs
-        self._attrs = _block_attrs(spec, BS)
-        self.env = {}
-        slots = {}
-        for name, (_, _, slot) in moe_param_shapes(spec).items():
-            self.env[name] = jnp.asarray(weights[name])
-            slots[slot] = name
-        self.op = _Op(slots)
-
-    def attr(self, name, default=None):
-        return self._attrs.get(name, default)
-
-    def input(self, slot):
-        return self.env[self.op.input(slot)]
-
-
-def _block(spec=SPEC, weights=WEIGHTS):
-    return pdo._ParallelMoEBlock(_Ctx(spec, weights))
-
-
-def _arenas(spec=SPEC):
-    # as wide as the engine makes them (model._arenas: CacheKind.stored)
-    return tuple(jnp.zeros((len(kind.layers), NB, BS, kind.stored),
-                           jnp.float32) for kind in spec.cache_kinds())
-
-
-def _prefill_chunk(block, kc, vc, table, tokens, start):
-    """One chunk of one sequence through the one-table path, as the
-    paged_prefill op runs it: logits of every row."""
-    s = len(tokens)
-    pos = start + jnp.arange(s, dtype=jnp.int32)
-    place = pdo._page_runs(table, jnp.int32(start), jnp.int32(s), s, NB, BS)
-    h, (kc, vc), _ = pdo._extend_rows(
-        block, (kc, vc), jnp.asarray(tokens, jnp.int32), pos, table, place,
-        valid=jnp.ones((s,), bool))
-    return block.logits(h), kc, vc
-
-
-def _decode(block, kc, vc, tables, tokens, lens):
-    place = pdo._single_rows(tables, lens, NB, BS)
-    h, (kc, vc), stats = pdo._extend_rows(
-        block, (kc, vc), tokens, lens, tables, place, valid=place.ok[:, 0])
-    return block.logits(h), kc, vc, stats
-
-
-def _reference_logits(tokens, spec=SPEC, weights=WEIGHTS):
-    return np.asarray(ref.logits(weights, np.asarray(tokens, np.int32),
-                                 _arch(spec), _held(spec)))
+def _reference_logits(tokens):
+    return DRIVER.reference_logits(ref, tokens)
 
 
 # ------------------------------------------------------------- the router
@@ -344,24 +286,8 @@ def test_chunked_prefill_then_decode_matches_full_forward(prompt_len,
     """A sequence that crosses the window (8): its prompt prefilled in
     chunks through the arenas, then decoded a token at a time, row by
     row against the reference's one full forward."""
-    rng = np.random.RandomState(prompt_len)
-    total = prompt_len + 12
-    tokens = rng.randint(0, SPEC.vocab_size, total)
-    want = _reference_logits(tokens)
-    block = _block()
-    kc, vc = _arenas()
-    table = jnp.asarray(rng.permutation(NB)[:PAGES], jnp.int32)
-    for start in range(0, prompt_len, chunk):
-        piece = tokens[start:min(start + chunk, prompt_len)]
-        got, kc, vc = _prefill_chunk(block, kc, vc, table, piece, start)
-        np.testing.assert_allclose(
-            np.asarray(got), want[start:start + len(piece)], atol=TOL)
-    for t in range(prompt_len, total):
-        got, kc, vc, _ = _decode(
-            block, kc, vc, table[None, :],
-            jnp.asarray(tokens[t:t + 1], jnp.int32),
-            jnp.asarray([t], jnp.int32))
-        np.testing.assert_allclose(np.asarray(got)[0], want[t], atol=TOL)
+    block_harness.chunked_prefill_then_decode(DRIVER, ref, prompt_len, chunk,
+                                              12, TOL)
 
 
 def test_kv_rows_wider_than_a_lane_tile_keep_their_width():
@@ -375,40 +301,22 @@ def test_kv_rows_wider_than_a_lane_tile_keep_their_width():
     assert [(k.width, k.stored, k.shared) for k in spec.cache_kinds()] \
         == [(192, 192, False)] * 2
     assert kv_bytes_per_token(spec, 'bfloat16') == 2 * 2 * 192 * 2
-    weights = random_weights(spec, seed=7)
+    # a driver of its own: another spec
+    wide = Driver(spec, random_weights(spec, seed=7), BS, NB, pages=PAGES)
     rng = np.random.RandomState(7)
     tokens = rng.randint(0, spec.vocab_size, 17)
-    want = _reference_logits(tokens, spec, weights)
-    block = _block(spec, weights)
-    kc, vc = _arenas(spec)
+    want = wide.reference_logits(ref, tokens)
     table = jnp.asarray(rng.permutation(NB)[:PAGES], jnp.int32)
-    got, kc, vc = _prefill_chunk(block, kc, vc, table, tokens[:16], 0)
+    got, arenas, _ = wide.prefill_chunk(wide.arenas(), table, tokens[:16], 0)
     np.testing.assert_allclose(np.asarray(got), want[:16], atol=TOL)
-    got, kc, vc, _ = _decode(block, kc, vc, table[None, :],
-                             jnp.asarray(tokens[16:], jnp.int32),
-                             jnp.asarray([16], jnp.int32))
+    got, _, _ = wide.decode(arenas, table[None, :], tokens[16:], [16])
     np.testing.assert_allclose(np.asarray(got)[0], want[16], atol=TOL)
 
 
 def test_padded_chunk_rows_write_nothing():
     """A chunk padded to its bucket: the rows past ``length`` leave the
     arenas as they were, and the real rows' logits do not move."""
-    rng = np.random.RandomState(3)
-    tokens = rng.randint(0, SPEC.vocab_size, 5)
-    block = _block()
-    kc, vc = _arenas()
-    table = jnp.arange(PAGES, dtype=jnp.int32)
-    exact, kc1, vc1 = _prefill_chunk(block, kc, vc, table, tokens, 0)
-    padded = np.concatenate([tokens, np.zeros(3, tokens.dtype)])
-    pos = jnp.arange(8, dtype=jnp.int32)
-    place = pdo._page_runs(table, jnp.int32(0), jnp.int32(5), 8, NB, BS)
-    h, (kc2, vc2), _ = pdo._extend_rows(
-        block, (kc, vc), jnp.asarray(padded, jnp.int32), pos, table, place,
-        valid=pos < 5)
-    np.testing.assert_allclose(np.asarray(block.logits(h))[:5],
-                               np.asarray(exact), atol=TOL)
-    assert np.array_equal(np.asarray(kc1), np.asarray(kc2))
-    assert np.array_equal(np.asarray(vc1), np.asarray(vc2))
+    block_harness.padded_chunk_rows_write_nothing(DRIVER, TOL)
 
 
 def test_decode_batch_of_mixed_lengths_matches_reference():
@@ -419,24 +327,9 @@ def test_decode_batch_of_mixed_lengths_matches_reference():
     rng = np.random.RandomState(7)
     lengths = [3, 9, 17, 30]
     seqs = [rng.randint(0, SPEC.vocab_size, n + 1) for n in lengths]
-    block = _block()
-    kc, vc = _arenas()
-    pages = rng.permutation(NB)
-    tables = np.full((5, PAGES), NB, np.int32)
-    used = 0
-    for i, seq in enumerate(seqs):
-        need = -(-len(seq) // BS)
-        tables[i, :need] = pages[used:used + need]
-        used += need
-        _, kc, vc = _prefill_chunk(block, kc, vc, jnp.asarray(tables[i]),
-                                   seq[:-1], 0)
-    got, kc, vc, stats = _decode(
-        block, kc, vc, jnp.asarray(tables),
-        jnp.asarray([s[-1] for s in seqs] + [0], jnp.int32),
-        jnp.asarray(lengths + [0], jnp.int32))
-    for i, seq in enumerate(seqs):
-        np.testing.assert_allclose(
-            np.asarray(got)[i], _reference_logits(seq)[-1], atol=TOL)
+    _, stats = block_harness.decode_batch_of_mixed_lengths(
+        DRIVER, ref, seqs + [None],
+        DRIVER.packed_tables(rng.permutation(NB), seqs + [None]), TOL)
     stats = np.asarray(stats)
     assert stats.shape == (SPEC.n_layer, 4)
     # 5 rows are one tile an expert: the loop runs once for each touched
@@ -465,9 +358,10 @@ def test_the_tolerance_catches_a_wrong_layer(broken):
             'first_expert': dict(first_expert=3)}[broken]
     rng = np.random.RandomState(2)
     tokens = rng.randint(0, SPEC.vocab_size, 20)
-    got, _, _ = _prefill_chunk(_block(_spec(**over)), *_arenas(),
-                               jnp.arange(PAGES, dtype=jnp.int32),
-                               tokens, 0)
+    # a driver of its own: the broken spec over the sound weights
+    wrong = Driver(_spec(**over), WEIGHTS, BS, NB, pages=PAGES)
+    got, _, _ = wrong.prefill_chunk(
+        wrong.arenas(), jnp.arange(PAGES, dtype=jnp.int32), tokens, 0)
     assert np.abs(np.asarray(got) - _reference_logits(tokens)).max() > 1e-2
 
 
@@ -480,10 +374,8 @@ def test_the_tolerance_catches_a_narrower_state():
     move by a hundred times the tolerance and more."""
     rng = np.random.RandomState(4)
     tokens = rng.randint(0, SPEC.vocab_size, 20)
-    narrow = ref.logits(WEIGHTS, tokens, dict(_arch(SPEC),
-                                              state_dtype='bfloat16'),
-                        _held(SPEC))
-    moved = np.abs(np.asarray(narrow) - _reference_logits(tokens))
+    narrow = DRIVER.reference_logits(ref, tokens, state_dtype='bfloat16')
+    moved = np.abs(narrow - _reference_logits(tokens))
     assert moved.max() > 100 * TOL
 
 
@@ -507,7 +399,19 @@ def _requests(n=6, seed=0):
              int(rng.randint(3, 12))) for _ in range(n)]
 
 
-def test_engine_batched_equals_one_at_a_time_and_the_reference():
+@pytest.fixture(scope='module')
+def engine():
+    """One engine of the default arguments, warmed and started, for the
+    tests that read what their own requests add to the counters."""
+    eng = _engine()
+    assert eng.prompt_buckets == [4, 8]
+    eng.warmup()
+    eng.start()
+    yield eng
+    eng.shutdown(drain=False)
+
+
+def test_engine_batched_equals_one_at_a_time_and_the_reference(engine):
     """Through DecodeEngine (scheduler, pool, executor, chunked prefill
     above 8 tokens): the tokens of six requests served together are the
     tokens of each served alone, no signature compiles after warmup, the
@@ -518,25 +422,21 @@ def test_engine_batched_equals_one_at_a_time_and_the_reference():
     assert max(len(p) for p, _ in requests) > 16    # three chunks
     assert max(len(p) + n for p, n in requests) > SPEC.sliding_window
     alone = []
-    eng = _engine()
-    assert eng.prompt_buckets == [4, 8]
-    eng.warmup()
-    eng.start()
     try:
         for prompt, n in requests:
-            alone.append(eng.generate(prompt, max_new_tokens=n,
-                                      timeout=120))
+            alone.append(engine.generate(prompt, max_new_tokens=n,
+                                         timeout=120))
         observe.enable()
         before = observe.snapshot()
-        streams = [eng.submit(p, max_new_tokens=n) for p, n in requests]
+        streams = [engine.submit(p, max_new_tokens=n) for p, n in requests]
         together = [s.result(120) for s in streams]
+        assert engine.drain(timeout=60)
         after = observe.snapshot()
     finally:
-        eng.shutdown()
         observe.disable()
         observe.reset()
     assert together == alone
-    assert eng.free_pages() == 64
+    assert engine.free_pages() == 64
 
     def grown(name):
         return sum(v for k, v in after['counters'].items()
@@ -549,9 +449,9 @@ def test_engine_batched_equals_one_at_a_time_and_the_reference():
     assert grown('decode.prefills_total') == len(requests)
     # a prefill's whole time lies under its largest program's bucket: the
     # top one wherever it was chunked, never the short last chunk's
-    for rung in eng.prompt_buckets:
+    for rung in engine.prompt_buckets:
         want = sum(1 for p, _ in requests
-                   if eng._bucket(min(len(p), 8)) == rung)
+                   if engine._bucket(min(len(p), 8)) == rung)
         key = 'decode.prefill_seconds{bucket=%d}' % rung
         got = after['histograms'].get(key, {}).get('count', 0) - \
             before['histograms'].get(key, {}).get('count', 0)
@@ -567,25 +467,21 @@ def test_engine_batched_equals_one_at_a_time_and_the_reference():
         assert max(gaps) <= TOL
 
 
-def test_engine_counts_the_row_tiles_its_programs_ran():
+def test_engine_counts_the_row_tiles_its_programs_ran(engine):
     """A prefill in chunks and the decode steps behind it feed both
     counters: a tile for every (layer, expert some live row chose) in
     each program, against one for every expert held (all programs here
     are under 128 rows); the decode steps' share is what the touched
     experts counter says."""
     from paddle_tpu import observe
-    eng = _engine()
-    eng.warmup()
-    eng.start()
     observe.enable()
     try:
         before = observe.snapshot()
-        eng.generate(list(range(1, 20)), max_new_tokens=1, timeout=120)
+        engine.generate(list(range(1, 20)), max_new_tokens=1, timeout=120)
         prefill = observe.snapshot()
-        eng.generate(list(range(3, 9)), max_new_tokens=5, timeout=120)
+        engine.generate(list(range(3, 9)), max_new_tokens=5, timeout=120)
         after = observe.snapshot()
     finally:
-        eng.shutdown()
         observe.disable()
         observe.reset()
 
@@ -669,14 +565,14 @@ def test_spec_refuses_what_it_cannot_describe():
     assert _spec().rotary() == [True, True, True, False]
 
 
-def test_kv_bytes_count_kv_heads():
+def test_kv_bytes_count_kv_heads(engine):
     # 4 layers x 2 KV heads x (8 + 8) x 4 B, not the 4 query heads
     assert kv_bytes_per_token(SPEC) == 4 * 2 * 16 * 4
     assert kv_bytes_per_token(SPEC, 'bfloat16') == 4 * 2 * 16 * 2
     assert arena_bytes(SPEC, 10, 4) == 4 * 2 * 16 * 4 * 40
     dense = LMSpec(vocab_size=8, n_layer=2, n_head=2, d_key=8, d_value=8)
     assert kv_bytes_per_token(dense) == 2 * 2 * 16 * 4
-    assert _engine().kv_geometry()['n_kv_head'] == 2
+    assert engine.kv_geometry()['n_kv_head'] == 2
 
 
 def test_long_prefix_of_the_dense_block_prefills_in_chunks():

@@ -66,21 +66,25 @@ def _shared_prefix_requests(n=6, seed=0, vocab=60):
     return reqs
 
 
-_BASELINE = {}
+@pytest.fixture(scope='module')
+def baseline():
+    """``baseline(seed)``: sequential single-request decode on a plain
+    engine (no cache, no speculation), the bit-identity reference. One
+    engine, built once, serves every request of every seed one at a
+    time, each in the pages its predecessor gave back: a stream depends
+    on its request alone (an engine a request against a batch is
+    test_decode_serving.py's baseline)."""
+    eng = _engine()
+    eng.start()
+    served = {}
 
-
-def _baseline(seed):
-    """Sequential single-request decode on a plain engine (no cache,
-    no speculation) — the bit-identity reference."""
-    if seed not in _BASELINE:
-        out = []
-        for r in _shared_prefix_requests(seed=seed):
-            e = _engine()
-            e.start()
-            out.append(e.generate(timeout=120, **r))
-            e.shutdown()
-        _BASELINE[seed] = out
-    return _BASELINE[seed]
+    def of(seed):
+        if seed not in served:
+            served[seed] = [eng.generate(timeout=120, **r)
+                            for r in _shared_prefix_requests(seed=seed)]
+        return served[seed]
+    yield of
+    eng.shutdown()
 
 
 def _misses(snap):
@@ -238,14 +242,14 @@ def test_accept_drafts_longest_prefix_rule():
 
 
 # --------------------------------------------------------------- e2es
-def test_prefix_cache_bit_identical_and_pool_drains():
+def test_prefix_cache_bit_identical_and_pool_drains(baseline):
     """THE cache acceptance e2e: concurrent shared-prefix traffic with
     the cache on yields streams bit-identical to the sequential
     no-cache baseline, actually hits (prefill tokens skipped > 0), and
     the pool drains to its initial free count after shutdown."""
     from paddle_tpu import observe
     observe.enable()
-    want = _baseline(0)
+    want = baseline(0)
     eng = _engine(prefix_cache=True)
     eng.warmup()
     m0 = _misses(observe.snapshot())
@@ -265,14 +269,14 @@ def test_prefix_cache_bit_identical_and_pool_drains():
         'cache.clear() at shutdown must drain the pool to initial'
 
 
-def test_spec_decode_bit_identical_zero_misses():
+def test_spec_decode_bit_identical_zero_misses(baseline):
     """THE speculation acceptance e2e: draft-and-verify decode (greedy
     and sampled rows mixed) emits streams bit-identical to plain
     decode, with the verify signature warmed (zero live misses) and
     accepted drafts actually flowing."""
     from paddle_tpu import observe
     observe.enable()
-    want = _baseline(0)
+    want = baseline(0)
     eng = _engine(spec_k=3)
     sigs = eng.warmup()
     assert sigs == len(eng.prompt_buckets) + 2   # decode + verify keys
@@ -289,7 +293,7 @@ def test_spec_decode_bit_identical_zero_misses():
     assert eng.pool.free_blocks() == eng.pool.num_blocks
 
 
-def test_cache_hit_preempt_requeue_drain_invariant():
+def test_cache_hit_preempt_requeue_drain_invariant(baseline):
     """Satellite: the pool-free-count-returns-to-initial drain
     invariant extended with cache-hit + preempt + requeue
     interleavings — a pool small enough that admission, growth, cache
@@ -298,7 +302,7 @@ def test_cache_hit_preempt_requeue_drain_invariant():
     from paddle_tpu import observe
     observe.enable()
     observe.arm_flight()
-    want = _baseline(0)
+    want = baseline(0)
     eng = _engine(num_blocks=9, prefix_cache=True, spec_k=2)
     eng.start()
     streams = [eng.submit(**r) for r in _shared_prefix_requests(seed=0)]
@@ -315,9 +319,9 @@ def test_cache_hit_preempt_requeue_drain_invariant():
         'every page must return: sequences released, cache cleared'
 
 
-def test_both_features_bit_identical_with_sampling():
+def test_both_features_bit_identical_with_sampling(baseline):
     """Cache + speculation together, mixed greedy/sampled rows."""
-    want = _baseline(3)
+    want = baseline(3)
     eng = _engine(prefix_cache=True, spec_k=3)
     eng.warmup()
     eng.start()

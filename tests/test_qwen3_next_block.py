@@ -41,9 +41,8 @@ from paddle_tpu.ops import moe_held_ops as moe
 from paddle_tpu.ops import paged_decode_ops as pdo
 from paddle_tpu.serving.decode import DecodeEngine, LMSpec, random_weights
 from paddle_tpu.serving.decode import model as lm
-
-from test_granite_block import _table, _tokens, BS, NB, PAGES, SLOTS
-from test_nemotron_3_super_block import _Ctx
+import block_harness
+from block_harness import BS, NB, PAGES, SLOTS, Driver, tokens as _tokens
 
 TOL = 1e-4
 CHUNK = 16                               # the engine's prefill chunk
@@ -86,61 +85,11 @@ SPEC = _spec()
 WEIGHTS = _weights(SPEC, 61)
 
 
-def _arenas(spec=SPEC, slots=SLOTS):
-    return tuple(
-        jnp.zeros((len(k.layers), (slots + 1) if k.per_seq else NB)
-                  + tuple(k.unit_shape(BS)), jnp.float32)
-        for k in spec.cache_kinds())
+DRIVER = Driver(SPEC, WEIGHTS, BS, NB, slots=SLOTS, pages=PAGES)
 
 
-@jax.jit
-def _chunk(arenas, table, slot, tokens, start, length):
-    """A prefill chunk as ``paged_prefill`` runs it: the logits of every
-    row and the arenas it leaves."""
-    block = dho.DeltaHybridBlock(_Ctx(SPEC, WEIGHTS, {
-        'BlockTableState': slot, 'Cached': start}))
-    rows = tokens.shape[0]
-    pos = start + jnp.arange(rows, dtype=jnp.int32)
-    place = pdo._page_runs(table, start, length, rows, NB, BS)
-    h, arenas, stats = pdo._extend_rows(
-        block, arenas, tokens, pos, table, place,
-        valid=jnp.arange(rows) < length)
-    return block.logits(h), arenas, stats
-
-
-@jax.jit
-def _step(arenas, tables, slots, tokens, lens):
-    """A decode step as ``paged_decode_step`` runs it."""
-    block = dho.DeltaHybridBlock(_Ctx(SPEC, WEIGHTS, {
-        'BlockTablesState': slots}))
-    place = pdo._single_rows(tables, lens, NB, BS)
-    h, arenas, _ = pdo._extend_rows(block, arenas, tokens, lens, tables,
-                                    place, valid=place.ok[:, 0])
-    return block.logits(h), arenas
-
-
-def _reference_logits(tokens, held=None, **lowered):
-    w = {k: jnp.asarray(v) for k, v in WEIGHTS.items()}
-    return np.asarray(ref.logits(
-        w, np.asarray(tokens, np.int32), dict(ref.arch_of(SPEC), **lowered),
-        held or ref.held_of(SPEC)))
-
-
-def _prefill(arenas, table, slot, tokens, pieces):
-    """``tokens`` prefilled in chunks of the given lengths, each padded
-    to the next power of two of at least 4: (logits of the valid rows,
-    arenas, the last chunk's router statistics)."""
-    out, start, stats = [], 0, None
-    for n in pieces:
-        bucket = max(4, 1 << (n - 1).bit_length())
-        ids = np.zeros((bucket,), np.int32)
-        ids[:n] = tokens[start:start + n]
-        lg, arenas, stats = _chunk(
-            arenas, table, jnp.asarray([slot], jnp.int32), jnp.asarray(ids),
-            jnp.int32(start), jnp.int32(n))
-        out.append(np.asarray(lg)[:n])
-        start += n
-    return np.concatenate(out), arenas, stats
+def _reference_logits(tokens, **lowered):
+    return DRIVER.reference_logits(ref, tokens, **lowered)
 
 
 # ------------------------------------------------------------- the spec
@@ -397,10 +346,10 @@ def test_the_shares_add_up_to_the_uncut_layer():
     total = shared
     for first in (0, 2, 4, 6):
         share = {name: v[:, first:first + 2] for name, v in full.items()}
-        block = dho.DeltaHybridBlock(_Ctx(
+        block = Driver(
             _spec(first_expert=first, experts_held=2),
-            dict(WEIGHTS, **share),
-            {'BlockTablesState': jnp.zeros((24,), jnp.int32)}))
+            dict(WEIGHTS, **share), BS, NB).block(
+                BlockTablesState=jnp.zeros((24,), jnp.int32))
         layer = {slot: stack[5] for slot, stack in block.w[None].items()}
         out, stats = block._experts(n, step, layer, 5)
         assert stats.shape == (4,)
@@ -412,7 +361,8 @@ def test_the_shares_add_up_to_the_uncut_layer():
 # ------------------------------------------- the block against the reference
 def test_a_whole_prompt_prefill_matches_the_full_forward():
     tokens = _tokens(16, 1)
-    got, _, stats = _prefill(_arenas(), _table(0, 16), 1, tokens, [16])
+    got, _, stats = DRIVER.prefill(DRIVER.arenas(), DRIVER.table(0, 16),
+                                   tokens, [16], slot=1)
     np.testing.assert_allclose(got, _reference_logits(tokens), atol=TOL)
     # a row of statistics a layer: 16 rows x 3 choices, of which those
     # on experts 2..5 are local
@@ -429,8 +379,8 @@ def test_a_whole_prompt_prefill_matches_the_full_forward():
 ])
 def test_prefill_in_chunks_matches_the_full_forward(pieces):
     tokens = _tokens(sum(pieces), 2)
-    got, _, _ = _prefill(_arenas(), _table(3, len(tokens)), 2, tokens,
-                         pieces)
+    got, _, _ = DRIVER.prefill(DRIVER.arenas(), DRIVER.table(3, len(tokens)),
+                               tokens, pieces, slot=2)
     np.testing.assert_allclose(got, _reference_logits(tokens), atol=TOL)
 
 
@@ -441,38 +391,13 @@ def test_prefill_then_decode_through_the_cache_matches_the_full_forward(
     its own slot and pages, then decoded together, the rows changing
     places between steps; with the state update and the routed product
     in either form."""
-    step = _step
+    stepper = None
     if form == 'the kernels':
         request.getfixturevalue('in_a_kernel')
-        step = jax.jit(_step.__wrapped__)   # traced again, with them
-    seqs = [_tokens(n, 10 + n) for n in (29, 42, 22)]
-    prompts = (17, 30, 9)
-    slots, firsts = (2, 0, 3), (0, 12, 30)
-    arenas = _arenas()
-    tables = [_table(f, len(s)) for f, s in zip(firsts, seqs)]
-    for seq, p, slot, table in zip(seqs, prompts, slots, tables):
-        _, arenas, _ = _prefill(arenas, table, slot, seq[:p],
-                                [CHUNK] * (p // CHUNK) + [p % CHUNK])
-    want = [_reference_logits(s) for s in seqs]
-    order = [0, 1, 2]
-    for n_step in range(8):
-        if n_step % 3 == 2:
-            order = order[1:] + order[:1]       # rows move, slots stay
-        rows = [i for i in order if prompts[i] + n_step < len(seqs[i])]
-        pad = 4 - len(rows)
-        lens = [prompts[i] + n_step for i in rows]
-        lg, arenas = step(
-            arenas,
-            jnp.stack([tables[i] for i in rows]
-                      + [jnp.full((PAGES,), NB, jnp.int32)] * pad),
-            jnp.asarray([slots[i] for i in rows] + [SLOTS] * pad,
-                        jnp.int32),
-            jnp.asarray([seqs[i][n] for i, n in zip(rows, lens)]
-                        + [0] * pad, jnp.int32),
-            jnp.asarray(lens + [0] * pad, jnp.int32))
-        for r, (i, n) in enumerate(zip(rows, lens)):
-            np.testing.assert_allclose(np.asarray(lg)[r], want[i][n],
-                                       atol=TOL)
+        # a driver of its own: the step traced again, with them
+        stepper = Driver(SPEC, WEIGHTS, BS, NB, slots=SLOTS, pages=PAGES)
+    block_harness.prefill_then_decode_through_the_cache(
+        DRIVER, ref, 8, CHUNK, TOL, stepper)
 
 
 @pytest.mark.parametrize('lowered,what', [
